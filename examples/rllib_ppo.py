@@ -2,7 +2,7 @@
 import _bootstrap  # noqa: F401  (repo-checkout import shim)
 # sim-env RL is latency-bound: tiny MLP forwards gain nothing from an
 # accelerator (in a cluster, env-runner actors have no TPU chips bound
-# anyway). Force CPU so a tunneled/remote TPU doesn't add per-step RTTs.
+# anyway). Force CPU: this driver then never takes the node's chip.
 import jax
 jax.config.update("jax_platforms", "cpu")
 import ray_tpu

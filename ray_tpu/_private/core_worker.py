@@ -31,7 +31,6 @@ import traceback
 from collections import OrderedDict, deque
 from concurrent.futures import Future as SyncFuture
 from concurrent.futures import ThreadPoolExecutor
-from concurrent.futures import TimeoutError as SyncTimeoutError
 from typing import Any, Dict, List, Optional, Sequence, Tuple
 
 from ray_tpu._private import fault_injection as _fi
@@ -1191,8 +1190,7 @@ class CoreWorker:
             t = None if deadline is None else max(0.0, deadline - time.monotonic())
             try:
                 waiter.result(t)
-            except (SyncTimeoutError, TimeoutError):
-                # distinct types before 3.11 (bpo-44793 unified them)
+            except TimeoutError:
                 raise GetTimeoutError(f"get timed out: {ref}")
         return CoreWorker._FAST_MISS
 

@@ -596,7 +596,17 @@ class GcsServer:
         period = self.config.raylet_heartbeat_period_s
         threshold = self.config.health_check_failure_threshold
         while True:
+            asleep = time.monotonic()
             await asyncio.sleep(period)
+            # Time this process did not run is not time a node stayed
+            # silent: heartbeats sent meanwhile wait unread, and a stall
+            # of the whole host (a TPU runtime starting in a sandboxed VM
+            # freezes every process for 4-8 s) stops the raylets too.
+            # Credit every node with what this sleep overran.
+            stalled = time.monotonic() - asleep - period
+            if stalled > period:
+                for node_id in self._last_heartbeat:
+                    self._last_heartbeat[node_id] += stalled
             if _fi._PLAN is not None:
                 await _fi._PLAN.gcs_health_tick()
             now = time.monotonic()

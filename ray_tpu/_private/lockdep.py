@@ -320,6 +320,10 @@ class TrackedRLock(_TrackedLockBase):
     def _is_owned(self) -> bool:
         return self._ld_inner._is_owned()
 
+    def _recursion_count(self) -> int:
+        # multiprocessing.resource_tracker asks its RLock this (3.12)
+        return self._ld_inner._recursion_count()
+
 
 def _lock_factory() -> TrackedLock:
     return TrackedLock()

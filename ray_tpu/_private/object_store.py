@@ -12,7 +12,6 @@ from __future__ import annotations
 
 import mmap
 import os
-import sys
 
 from ray_tpu._private import serialization
 from ray_tpu._private.ids import ObjectID
@@ -62,18 +61,12 @@ def job_key(job_id_binary: bytes) -> int:
 class PlasmaBuffer:
     """Holds one store reference for the lifetime of its zero-copy views.
 
-    Views are exported through the PEP-688 buffer protocol on 3.12+, so any
+    Views are exported through the PEP-688 buffer protocol, so any
     memoryview slice (and any numpy array reconstructed from one by pickle5)
     keeps this object alive; when the last view is garbage-collected, __del__
     drops the store refcount and the object becomes evictable again. This
     mirrors the reference's plasma client Buffer semantics
     (src/ray/object_manager/plasma/client.cc — release-on-buffer-destruction).
-
-    Interpreters older than 3.12 cannot export a buffer from pure Python
-    (`__buffer__` is ignored and memoryview(self) raises TypeError), so
-    `export()` re-exports the view through a ctypes array: the array pins the
-    underlying view, derived memoryviews pin the array, and an attribute on
-    the array pins this object — the same release-on-last-view lifetime.
     """
 
     __slots__ = ("_store", "_id_bytes", "_view", "__weakref__")
@@ -88,11 +81,7 @@ class PlasmaBuffer:
 
     def export(self) -> memoryview:
         """A memoryview over the object's bytes that holds the store ref."""
-        if sys.version_info >= (3, 12):
-            return memoryview(self)
-        arr = (ctypes.c_char * self._view.nbytes).from_buffer(self._view)
-        arr._plasma_ref = self  # released when the last derived view dies
-        return memoryview(arr)
+        return memoryview(self)
 
     @property
     def nbytes(self) -> int:

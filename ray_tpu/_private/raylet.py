@@ -754,8 +754,8 @@ class Raylet:
         if runtime_env and runtime_env.get("env_vars"):
             env.update(runtime_env["env_vars"])
         if tpu_chips:
-            env["TPU_VISIBLE_CHIPS"] = ",".join(str(c) for c in tpu_chips)
-            env["TPU_CHIPS_PER_PROCESS_BOUNDS"] = "1,1,1"
+            env.update(accelerators.visible_chip_env(
+                tpu_chips, int(self.total.get("TPU", 0))))
             # The raylet daemon runs with JAX_PLATFORMS=cpu; TPU workers
             # must get the machine's original platform back or JAX would
             # silently compute "TPU" tasks on host CPU.

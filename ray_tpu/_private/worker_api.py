@@ -16,6 +16,7 @@ import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence
 
+from ray_tpu._private import accelerators
 from ray_tpu._private import task as task_mod
 from ray_tpu._private.config import Config, global_config
 from ray_tpu._private.core_worker import (
@@ -39,10 +40,14 @@ _exported_config_env: list = []
 
 class GlobalState:
     def __init__(self, cluster: Cluster | None, core_worker: CoreWorker,
-                 owns_cluster: bool, client=None):
+                 owns_cluster: bool, client=None,
+                 node_tpus: float | None = None):
         self.cluster = cluster
         self.core_worker = core_worker
         self.owns_cluster = owns_cluster
+        # chips the one node of an init()-started cluster advertises
+        # (None for a cluster this driver only joined)
+        self.node_tpus = node_tpus
         # Ray-Client mode: a ClientContext proxying every call to a
         # cluster-side ClientServer (reference: python/ray/util/client)
         self.client = client
@@ -146,12 +151,14 @@ def init(
             if num_tpus is not None:
                 node_resources["TPU"] = float(num_tpus)
             else:
-                node_resources.setdefault("TPU", float(_detect_tpu_chips()))
+                node_resources.setdefault(
+                    "TPU", float(accelerators.num_local_chips()))
             cluster = Cluster(
                 head_resources=node_resources,
                 object_store_memory=object_store_memory,
             )
             owns = True
+            node_tpus = node_resources["TPU"]
             gcs_addr = cluster.gcs_addr
             head = cluster.head_node
             raylet_addr = head.raylet_addr
@@ -160,6 +167,7 @@ def init(
         else:
             cluster = None
             owns = False
+            node_tpus = None  # a joined cluster may still grow
             gcs_addr = address
             raylet_addr, store_name, node_id_hex = \
                 _discover_local_raylet(address)
@@ -194,21 +202,9 @@ def init(
             from ray_tpu._private import runtime_env as renv_mod
 
             cw.job_runtime_env = renv_mod.prepare(cw, runtime_env)
-        _global_state = GlobalState(cluster, cw, owns)
+        _global_state = GlobalState(cluster, cw, owns, node_tpus=node_tpus)
         atexit.register(shutdown)
         return _global_state
-
-
-def _detect_tpu_chips() -> int:
-    """TPU chip autodetection (reference:
-    python/ray/_private/accelerators/tpu.py:104-120 — /dev/accel* and vfio)."""
-    import glob
-    chips = len(glob.glob("/dev/accel*"))
-    if chips == 0:
-        chips = len(glob.glob("/dev/vfio/*")) - (
-            1 if glob.glob("/dev/vfio/vfio") else 0
-        )
-    return max(chips, 0)
 
 
 def _discover_local_raylet(gcs_addr: str):
@@ -377,6 +373,15 @@ def _resource_dict(opts: dict, default_cpu: float) -> Dict[str, float]:
     resources["CPU"] = float(num_cpus) if num_cpus is not None else default_cpu
     if num_tpus is not None:
         resources["TPU"] = float(num_tpus)
+    state = _global_state
+    if state is not None and state.node_tpus is not None \
+            and resources.get("TPU", 0.0) > state.node_tpus:
+        # an init()-started cluster is one fixed node: this demand
+        # could only queue forever
+        raise ValueError(
+            f"requested TPU: {resources['TPU']:g} but this node advertises "
+            f"TPU: {state.node_tpus:g} (chip discovery counts /dev/accel* "
+            f"and /dev/vfio/<n>; init(num_tpus=...) overrides it)")
     return resources
 
 

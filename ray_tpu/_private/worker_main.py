@@ -31,12 +31,11 @@ def main():
         format=f"[worker {os.getpid()}] %(levelname)s %(name)s: %(message)s",
     )
 
-    # Honor the raylet's platform assignment (a worker spawned without
-    # TPU chips must not grab the node's chip) even when a site hook
-    # pre-imported jax at interpreter start.
-    from ray_tpu._private.accelerators import apply_jax_platforms
+    # before anything in this process imports jax: every worker of the
+    # node shares one persistent compilation cache
+    from ray_tpu._private.accelerators import configure_compile_cache
 
-    apply_jax_platforms(os.environ.get("JAX_PLATFORMS"))
+    configure_compile_cache()
 
     from ray_tpu._private import fault_injection as _fi
     from ray_tpu._private.core_worker import CoreWorker
@@ -100,14 +99,20 @@ def main():
         # raylet via the unix-socket connection; here we poll).
         from ray_tpu._private.rpc import ConnectionLost, RpcError
 
-        while True:
+        # two polls in a row: one can be lost to a stall of this very
+        # process (a TPU runtime starting freezes the whole sandboxed
+        # host for seconds, and the timeout is due the moment it thaws)
+        missed = 0
+        while missed < 2:
             await asyncio.sleep(2.0)
             try:
                 raylet = await cw._clients.get(args.raylet_addr)
                 await raylet.call("node_info", {}, timeout=5.0)
+                missed = 0
             except (ConnectionLost, RpcError, OSError, asyncio.TimeoutError):
-                logging.warning("raylet unreachable; worker exiting")
-                os._exit(1)
+                missed += 1
+        logging.warning("raylet unreachable; worker exiting")
+        os._exit(1)
 
     asyncio.run_coroutine_threadsafe(raylet_watchdog(), cw._loop)
     try:

@@ -42,6 +42,7 @@ ray_tpu/parallel/ring_attention.py so models can swap it in untouched.
 
 from __future__ import annotations
 
+import collections
 import functools
 from typing import Optional
 
@@ -52,6 +53,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 _NEG_INF = -1e30
 _LANES = 128  # stats scratch is [block_q, _LANES]; only column 0 is real
+
+# Which path `flash_attention` took, counted per call of the wrapper (under
+# jit that is once per trace, not per step): "pallas" is the kernel, "dense"
+# the XLA fallback. A caller that must be on the kernel reads this after
+# building its step (chip_smoke.py fails when "dense" moved).
+_PATH_CALLS = collections.Counter()
+
+
+def path_calls() -> dict:
+    return {"pallas": _PATH_CALLS["pallas"], "dense": _PATH_CALLS["dense"]}
 
 
 def _pick_block_q(t: int) -> int:
@@ -485,7 +496,8 @@ def flash_attention(q, k, v, *, causal: bool = True,
 
     Falls back to the XLA dense path when (a) not running on TPU (the
     interpret-mode kernel is for tests, not speed) or (b) the shape
-    doesn't block evenly — same semantics either way. The chunked-KV
+    doesn't block evenly — same semantics either way, and `path_calls()`
+    says which ran. The chunked-KV
     online softmax has no sequence-length cap (VMEM per step is
     independent of T). For sequence-sharded meshes use ring/Ulysses
     attention (ray_tpu/parallel/ring_attention.py); this kernel is the
@@ -503,7 +515,9 @@ def flash_attention(q, k, v, *, causal: bool = True,
             or bq * bk > _MAX_BLOCK_PRODUCT
             or jax.default_backend() != "tpu"):
         from ray_tpu.parallel.ring_attention import full_attention
+        _PATH_CALLS["dense"] += 1
         return full_attention(q, k, v, causal=causal, scale=scale)
+    _PATH_CALLS["pallas"] += 1
     # kernel layout is [B, H, T, d] so the T dim is block-sliceable
     qt, kt, vt = (x.transpose(0, 2, 1, 3) for x in (q, k, v))
     out = _flash(qt, kt, vt, scale, causal, bq, bk, h // h_kv, False)

@@ -16,9 +16,8 @@ from typing import Sequence
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-from ray_tpu.parallel._shard_map_compat import axis_size, shard_map
 
 
 # --- in-program collectives (use inside shard_map) ---------------------
@@ -46,7 +45,7 @@ def all_to_all(x, axis: str, *, split_dim: int, concat_dim: int):
 
 def ring_permute(x, axis: str, *, shift: int = 1):
     """Rotate shards around the mesh axis ring (ICI neighbor exchange)."""
-    n = axis_size(axis)
+    n = lax.axis_size(axis)
     perm = [(i, (i + shift) % n) for i in range(n)]
     return lax.ppermute(x, axis, perm)
 

@@ -44,6 +44,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 import jax
 from jax import lax
 
+from ray_tpu._private.accelerators import configure_compile_cache
 from ray_tpu.util import metrics as _metrics
 from ray_tpu.util import step_profiler as _sp
 from ray_tpu.util import tracing as _tracing
@@ -71,15 +72,28 @@ class CacheStats:
                 "retraces": self.retraces}
 
 
+def _sharding_key(sharding):
+    """Placement part of a leaf's key. A NamedSharding is keyed by what it
+    places, not how it prints: trailing Nones of a spec say nothing
+    (`P("x", None)` and `P("x")` are one layout) and a jitted step hands
+    back either form, which read as a retrace on the second step."""
+    spec = getattr(sharding, "spec", None)
+    if spec is None:
+        return None if sharding is None else repr(sharding)
+    axes = list(spec)
+    while axes and axes[-1] is None:
+        axes.pop()
+    return (repr(sharding.mesh), tuple(axes), sharding.memory_kind)
+
+
 def _leaf_key(leaf: Any):
     """Abstract (aval) key for one pytree leaf: shape+dtype+sharding for
     arrays, value identity for hashable Python scalars."""
     shape = getattr(leaf, "shape", None)
     dtype = getattr(leaf, "dtype", None)
     if shape is not None and dtype is not None:
-        sharding = getattr(leaf, "sharding", None)
         return ("aval", tuple(shape), str(dtype),
-                None if sharding is None else repr(sharding))
+                _sharding_key(getattr(leaf, "sharding", None)))
     # non-array leaf (python int/float/bool/None): its VALUE is baked
     # into the trace as a weak-typed constant, so it is part of the key
     return ("const", type(leaf).__name__, repr(leaf))
@@ -93,11 +107,8 @@ def _abstract_key(args: tuple, kwargs: dict):
 def _mesh_key(mesh) -> Optional[tuple]:
     if mesh is None:
         return None
-    shape = getattr(mesh, "shape", None)
-    if shape is not None:
-        return (tuple(sorted(dict(shape).items())),
-                tuple(str(d) for d in getattr(mesh, "devices", []) or []))
-    return (repr(mesh),)
+    return (tuple(mesh.shape.items()),
+            tuple(str(d) for d in mesh.devices.flat))
 
 
 class ExecutableCache:
@@ -155,6 +166,9 @@ class ExecutableCache:
             if on_retrace == "error":
                 raise RetraceError(msg)
             logger.warning(msg)
+        # in-process users (no worker_main): executables that outlive
+        # this cache's process go to the placed persistent cache
+        configure_compile_cache()
         t0 = time.perf_counter()
         with _tracing.span("compiled_step.lower", attrs={
                 "fn": getattr(fn, "__name__", "?"),

@@ -89,17 +89,15 @@ def build_mesh(
     axes = config.resolved(len(devices))
     names = tuple(axes.keys())
     shape = tuple(axes.values())
-    try:
+    if devices[0].platform == "tpu":
         from jax.experimental import mesh_utils
 
-        if devices[0].platform == "tpu":
-            # Topology-aware assignment: contiguous mesh axes map to ICI
-            # neighbors so the innermost (most communication-heavy) axes
-            # get the fastest links.
-            device_array = mesh_utils.create_device_mesh(shape, devices)
-        else:
-            device_array = np.asarray(devices).reshape(shape)
-    except Exception:
+        # Topology-aware assignment: contiguous mesh axes map to ICI
+        # neighbors so the innermost (most communication-heavy) axes
+        # get the fastest links. A shape the topology cannot carry
+        # raises here; a silent reshape would hide slow links.
+        device_array = mesh_utils.create_device_mesh(shape, devices)
+    else:
         device_array = np.asarray(devices).reshape(shape)
     return Mesh(device_array, names)
 

@@ -13,8 +13,7 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from ray_tpu.parallel._shard_map_compat import axis_size, shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 
@@ -55,7 +54,7 @@ def moe_layer(
 ):
     """Shard-local MoE body — call inside shard_map with experts sharded
     over `axis_name` and tokens sharded over the data axes."""
-    n_shards = axis_size(axis_name)
+    n_shards = lax.axis_size(axis_name)
     tokens, d_model = x.shape
     experts_local = jax.tree_util.tree_leaves(expert_params)[0].shape[0]
     num_experts = experts_local * n_shards

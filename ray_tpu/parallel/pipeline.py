@@ -33,8 +33,7 @@ from typing import Callable, Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from ray_tpu.parallel._shard_map_compat import shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 

@@ -20,8 +20,7 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from jax import lax
-from ray_tpu.parallel._shard_map_compat import axis_size, shard_map
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
 
 NEG_INF = -1e30
@@ -75,7 +74,7 @@ def _ring_attention_local(q, k, v, *, axis_name: str, causal: bool,
                           scale: float):
     """Per-shard body: rotate KV blocks around the ring with an online
     softmax accumulator."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     tq = q.shape[1]
     b, _, h, d = q.shape

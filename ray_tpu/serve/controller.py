@@ -257,7 +257,7 @@ class ServeController:
                 # startup grace window before concluding it's wedged.
                 now = time.monotonic()
                 grace = float(os.environ.get(
-                    "RAY_TPU_SERVE_STARTUP_GRACE_S", "60"))
+                    "RAY_TPU_SERVE_STARTUP_GRACE_S", "600"))
                 with self._lock:
                     for r in slow:
                         # unknown spawn time -> 0.0: an untracked slow

@@ -16,6 +16,8 @@ node's /metrics scrape via the registry callback it registers.
 
 from __future__ import annotations
 
+import os
+import time
 from typing import Any, Dict, List, Optional
 
 
@@ -51,24 +53,44 @@ class LLMDeployment:
             # determinism contract extends to speculation); without
             # this, spec_k > 0 self-drafts with the target weights
             draft_cfg = _Cfg(**draft_config)
-        store = self._node_store()
+        from ray_tpu._private.object_ref import get_core_worker
+
+        # the worker's shm store attachment, so KV pages live on the
+        # object plane (outside a cluster: plain numpy arena)
+        cw = get_core_worker()
+        self._tpu_chips = []
+        if cw is not None:
+            self._tpu_chips = list(cw.tpu_chips)
+            self._refuse_host_compute_beside_a_chip(cw)
         self.engine = LLMEngine(
             model=model, model_cfg=model_cfg,
             engine_config=EngineConfig(**(engine_config or {})),
-            store=store, seed=seed, draft_cfg=draft_cfg)
+            store=cw.store if cw is not None else None,
+            seed=seed, draft_cfg=draft_cfg)
+        t0 = time.perf_counter()
         self.engine.warmup()
+        self._warmup_s = time.perf_counter() - t0
         self.engine.start()
 
     @staticmethod
-    def _node_store():
-        """The worker's shm store attachment, so KV pages live on the
-        object plane (None outside a cluster: plain numpy arena)."""
-        try:
-            from ray_tpu._private.object_ref import get_core_worker
-            cw = get_core_worker()
-            return cw.store if cw is not None else None
-        except Exception:
-            return None
+    def _refuse_host_compute_beside_a_chip(cw) -> None:
+        """A replica on a node that advertises chips computes on one:
+        landing on the host there (deployed without `build_app`'s chip
+        request, or jax held to the CPU) would be a slow success that
+        nothing reports."""
+        import jax
+
+        import ray_tpu
+
+        node_tpus = next(
+            (n["Resources"].get("TPU", 0.0) for n in ray_tpu.nodes()
+             if n["NodeID"] == cw.node_id_hex), 0.0)
+        if node_tpus and jax.default_backend() != "tpu":
+            raise RuntimeError(
+                f"LLM replica on node {cw.node_id_hex[:8]} (TPU: "
+                f"{node_tpus:g}) would compute on {jax.default_backend()!r}: "
+                f"chips granted to this worker: {list(cw.tpu_chips)}, "
+                f"JAX_PLATFORMS={os.environ.get('JAX_PLATFORMS')!r}")
 
     # -- request path -----------------------------------------------------
 
@@ -126,6 +148,30 @@ class LLMDeployment:
     def engine_metrics(self) -> Dict[str, Any]:
         return self.engine.metrics()
 
+    def replica_info(self) -> Dict[str, Any]:
+        """Where this replica computes, seen from inside its process:
+        jax's device, the chips the raylet granted, the cold-start cost,
+        the KV arena (shipped whole on every decode step) and the
+        executable-cache counters (retraces must stay 0)."""
+        import jax
+
+        from ray_tpu import parallel
+
+        dev = jax.devices()[0]
+        m = self.engine.metrics()
+        return {
+            "pid": os.getpid(),
+            "platform": dev.platform,
+            "device_kind": dev.device_kind,
+            "device_count": jax.device_count(),
+            "tpu_chips": self._tpu_chips,
+            "warmup_s": self._warmup_s,
+            "kv_arena_bytes": self.engine.kv.arena_nbytes,
+            "kv_pages_live": m["kv_pages_live"],
+            "requests_completed": m["requests_completed"],
+            "cache_stats": parallel.cache_stats(),
+        }
+
     def check_health(self) -> bool:
         return self.engine._thread is not None and \
             self.engine._thread.is_alive()
@@ -141,11 +187,17 @@ def build_app(name: str = "llm", num_replicas: int = 1,
               autoscaling_config: Optional[Dict[str, Any]] = None,
               **init_kwargs):
     """Bind LLMDeployment into a deployable app:
-    `serve.run(serve.llm.build_app(...))`."""
+    `serve.run(serve.llm.build_app(...))`. Call after `ray_tpu.init()`:
+    where the cluster advertises TPU chips every replica is granted
+    one, and on a cluster without any the replicas compute on the
+    host."""
+    import ray_tpu
     from ray_tpu import serve
 
+    on_chip = ray_tpu.cluster_resources().get("TPU", 0.0) > 0
     deco = serve.deployment(
         name=name,
         num_replicas=None if autoscaling_config else num_replicas,
-        autoscaling_config=autoscaling_config)
+        autoscaling_config=autoscaling_config,
+        ray_actor_options={"num_tpus": 1} if on_chip else None)
     return deco(LLMDeployment).bind(**init_kwargs)

@@ -26,10 +26,6 @@ class TrainWorker:
     def __init__(self, worker_env: Optional[Dict[str, str]] = None):
         for k, v in (worker_env or {}).items():
             os.environ[k] = v
-        if worker_env and "JAX_PLATFORMS" in worker_env:
-            from ray_tpu._private.accelerators import apply_jax_platforms
-
-            apply_jax_platforms(worker_env["JAX_PLATFORMS"])
         self._thread: Optional[threading.Thread] = None
         self._session: Optional[session_mod._TrainSession] = None
 

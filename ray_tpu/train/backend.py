@@ -83,42 +83,29 @@ class JaxConfig(BackendConfig):
 
 
 def _worker_jax_platform() -> str:
-    import jax
-    try:
-        return jax.default_backend()
-    except Exception:
-        return "cpu"
+    """The platform jax in this worker WILL use, read from the env the
+    raylet spawned it with (chips granted: the machine's own setting or
+    none; no chips: "cpu") plus the backend config's override. Asking
+    jax itself would start the backend, which on TPU must not happen
+    before `jax.distributed.initialize`."""
+    platforms = os.environ.get("JAX_PLATFORMS", "")
+    if platforms:
+        return platforms.split(",")[0]
+    return "tpu" if os.environ.get("TPU_VISIBLE_CHIPS") else "cpu"
 
 
 def _init_jax_distributed(coordinator: str, num_processes: int,
                           process_id: int) -> str:
     import jax
-    if (os.environ.get("JAX_PLATFORMS") or "").startswith("cpu"):
-        # A multi-process gang on the CPU backend needs a cross-process
-        # collectives implementation or XLA refuses every computation on
-        # non-fully-addressable arrays ("Multiprocess computations aren't
-        # implemented on the CPU backend"). Must land before the CPU
-        # client is instantiated; harmless when the jax build lacks the
-        # flag (TPU workers never take this branch).
-        try:
-            jax.config.update("jax_cpu_collectives_implementation", "gloo")
-        except Exception:
-            pass
-    try:
-        jax.distributed.initialize(
-            coordinator_address=coordinator,
-            num_processes=num_processes,
-            process_id=process_id,
-        )
-    except RuntimeError:
-        # a reused process with a stale (dead-coordinator) client:
-        # tear it down and join the new rendezvous
+    if jax.distributed.is_initialized():
+        # a reused process whose client points at a dead coordinator:
+        # leave that rendezvous before joining the new one
         jax.distributed.shutdown()
-        jax.distributed.initialize(
-            coordinator_address=coordinator,
-            num_processes=num_processes,
-            process_id=process_id,
-        )
+    jax.distributed.initialize(
+        coordinator_address=coordinator,
+        num_processes=num_processes,
+        process_id=process_id,
+    )
     return f"{jax.process_index()}/{jax.process_count()}"
 
 
@@ -130,11 +117,9 @@ class _JaxBackend(Backend):
         want = cfg.distributed
         if want == "off" or (want == "auto" and n == 1):
             return
-        if want == "auto":
-            platform = worker_group.execute_single(
-                0, _worker_jax_platform)
-            if platform not in ("tpu",):
-                return
+        if want == "auto" and worker_group.execute_single(
+                0, _worker_jax_platform) != "tpu":
+            return
         # Rendezvous: rank 0 picks the coordinator port, everyone joins.
         port = cfg.coordinator_port or worker_group.execute_single(
             0, _free_port_fn)

@@ -223,22 +223,14 @@ def peak_flops() -> Optional[float]:
 
 
 def _detect_peak_flops() -> Optional[float]:
-    try:
-        import jax
+    import jax
 
-        dev = jax.devices()[0]
-        if dev.platform != "tpu":
-            return None
-        kind = getattr(dev, "device_kind", "").lower()
-        if "v5 lite" in kind or "v5e" in kind or "v5litepod" in kind:
-            return 197e12
-        if "v5p" in kind or "v5" in kind:
-            return 459e12
-        if "v6" in kind:
-            return 918e12
-        return 275e12
-    except Exception:  # noqa: BLE001 — recorder must never raise
+    from ray_tpu._private.accelerators import peak_bf16_flops
+
+    dev = jax.devices()[0]
+    if dev.platform != "tpu":
         return None
+    return peak_bf16_flops(dev.device_kind)
 
 
 # -- recording -----------------------------------------------------------

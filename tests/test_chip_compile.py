@@ -1,0 +1,111 @@
+"""Compile the main path's programs for a described TPU v5e, with no chip.
+
+The TPU compiler is installed beside jax and compiles for a topology that
+is described, not attached (on-chip-measurement guide, section 2). It
+refuses what interpret mode lets through — a slice off the tiling, too much
+VMEM, a program over 16 GB — so these guard every later PR at no chip time.
+Nothing runs: results and times come from `chip_smoke.py` on the chip.
+"""
+
+import os
+from functools import partial
+from unittest import mock
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.experimental.compilation_cache import compilation_cache
+from jax.sharding import SingleDeviceSharding
+
+from ray_tpu.models import llama
+from ray_tpu.ops import flash_attention
+from ray_tpu.parallel import ShardingStrategy
+
+HBM_BYTES = 16 * 1024**3
+
+
+@pytest.fixture(scope="module")
+def topo():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        desc = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler here: skip
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    # a compile for a described device is written to the persistent cache
+    # but cannot be read back without a chip: keep these out of it
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield desc
+    jax.config.update("jax_enable_compilation_cache", True)
+    compilation_cache.reset_cache()
+
+
+def _compile_flash(topo, q_shape, kv_heads, **blocks):
+    """Forward and backward of the public wrapper, steered onto the kernel:
+    it asks `jax.default_backend()`, which here still says cpu."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    b, t, _h, d = q_shape
+    q = jax.ShapeDtypeStruct(q_shape, jnp.bfloat16, sharding=one_chip)
+    kv = jax.ShapeDtypeStruct((b, t, kv_heads, d), jnp.bfloat16,
+                              sharding=one_chip)
+    attend = partial(flash_attention, causal=True, **blocks)
+
+    def loss(q, k, v):
+        return attend(q, k, v).astype(jnp.float32).sum()
+
+    with mock.patch.object(jax, "default_backend", lambda: "tpu"):
+        fwd = jax.jit(attend).lower(q, kv, kv).compile()
+        bwd = jax.jit(jax.grad(loss, argnums=(0, 1, 2))).lower(
+            q, kv, kv).compile()
+    for compiled in (fwd, bwd):
+        assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("q_shape,kv_heads,blocks", [
+    ((16, 1024, 12, 64), 12, {}),                            # GPT-2 MHA
+    ((16, 1024, 12, 64), 4, {}),                             # Llama GQA 12:4
+    ((4, 4096, 12, 64), 12, {"block_q": 512, "block_k": 1024}),  # chunked KV
+], ids=["mha_16x1024", "gqa_12to4_16x1024", "chunked_4x4096"])
+def test_flash_kernel_compiles_for_v5e(topo, q_shape, kv_heads, blocks):
+    _compile_flash(topo, q_shape, kv_heads, **blocks)
+
+
+def test_llama_125m_decode_step_compiles_for_v5e(topo):
+    """One decode bucket at `llama_125m` width in bf16 with the arena the
+    engine sizes for eight running sequences (1,024 pages of 16 tokens)."""
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    cfg = llama.LlamaConfig.llama_125m()
+    batch, block = 8, 16
+    pages_per_seq = cfg.max_seq_len // block
+
+    def on_chip(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: on_chip(a.shape, a.dtype),
+        jax.eval_shape(llama.Llama(cfg).init, jax.random.PRNGKey(0),
+                       jnp.ones((1, 16), jnp.int32)))
+    pages = on_chip((batch * pages_per_seq, cfg.n_layer, block,
+                     cfg.n_kv_head, cfg.head_dim), jnp.bfloat16)
+    compiled = jax.jit(
+        lambda v, tok, pos, k, vv, table: llama.decode_step(
+            v, cfg, tok, pos, k, vv, table)
+    ).lower(params, on_chip((batch,), jnp.int32), on_chip((batch,), jnp.int32),
+            pages, pages, on_chip((batch, pages_per_seq), jnp.int32)).compile()
+    mem = compiled.memory_analysis()
+    assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
+        + mem.temp_size_in_bytes < HBM_BYTES
+
+
+def test_build_mesh_on_tpu_follows_the_topology(topo):
+    """On TPU devices `build_mesh` takes `create_device_mesh`'s assignment
+    (a 2x2 torus orders the ring 0,1,3,2), never a plain reshape."""
+    mesh = ShardingStrategy(fsdp=2, tp=2).build_mesh(topo.devices)
+    assert dict(mesh.shape) == {"fsdp": 2, "tp": 2}
+    assert [[d.id for d in row] for row in mesh.devices] == [[0, 1], [3, 2]]
+    with pytest.raises(Exception):  # noqa: B017 — whatever jax raises
+        # three of four chips form no mesh the topology knows
+        ShardingStrategy(dp=3).build_mesh(topo.devices[:3])

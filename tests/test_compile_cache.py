@@ -205,3 +205,31 @@ def test_python_scalar_is_part_of_the_key():
     np.testing.assert_allclose(np.asarray(a), [2.0, 2.0])
     np.testing.assert_allclose(np.asarray(b), [3.0, 3.0])
     assert cache.size() == 2
+
+
+def test_sharded_carry_steps_twice_without_a_retrace():
+    """A carry placed with `P(None)` comes back from the jitted step as
+    `P()`: one layout, two spellings. The key must see one signature (the
+    second step of every sharded loop was a RetraceError), and a real
+    `jax.sharding.Mesh` must be accepted as the `mesh` key."""
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+
+    mesh = Mesh(np.array(jax.devices()[:4]).reshape(2, 2), ("x", "y"))
+    w = jax.device_put(jnp.ones((8, 4)), NamedSharding(mesh, P("x", None)))
+    b = jax.device_put(jnp.zeros((4,)), NamedSharding(mesh, P(None)))
+
+    def step(carry, lr):
+        w, b = carry
+        return (w - lr * w, b + w.sum(0)), w.sum()
+
+    cache = ExecutableCache()
+    run = compiled_step(step, donate_argnums=(0,), mesh=mesh, cache=cache,
+                        on_retrace="error")
+    carry = (w, b)
+    for _ in range(3):
+        carry, _ = run(carry, jnp.float32(0.5))
+    assert cache.stats.as_dict() == {"hits": 2, "misses": 1, "retraces": 0}
+    # a different layout is still a different signature
+    moved = jax.device_put(carry[0], NamedSharding(mesh, P(None, "y")))
+    with pytest.raises(RetraceError):
+        run((moved, carry[1]), jnp.float32(0.5))
