@@ -93,6 +93,10 @@ def init(
             if ignore_reinit_error:
                 return _global_state
             raise RuntimeError("ray_tpu.init() already called")
+        # a driver that sets RAY_TPU_TRACE after import means it for the
+        # cluster it starts now, itself included
+        from ray_tpu.util import tracing
+        tracing.refresh()
         # copy — mutating the cached global would leak overrides into
         # the next init() in this process after shutdown cleans the env
         cfg = dataclasses.replace(global_config())

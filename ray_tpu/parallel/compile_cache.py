@@ -125,12 +125,17 @@ class ExecutableCache:
         self._entries: Dict[tuple, Any] = {}
         self._fn_signatures: Dict[tuple, set] = {}
         self.stats = CacheStats()
+        # `cache_lookup`: every lookup's own time (the key, the probe),
+        # without the compile nested in it on a miss
+        self.phases = _tracing.PhaseTable(
+            ("cache_lookup", "compiled_step.lower"))
 
     def clear(self) -> None:
         with self._lock:
             self._entries.clear()
             self._fn_signatures.clear()
             self.stats = CacheStats()
+        self.phases.clear()
 
     def size(self) -> int:
         with self._lock:
@@ -142,6 +147,12 @@ class ExecutableCache:
                mesh=None, on_retrace: str = "warn"):
         """Return the compiled executable for this abstract call
         signature, lowering+compiling on first use."""
+        with self.phases.phase("cache_lookup"):
+            return self._lookup(fn, args, kwargs, donate_argnums,
+                                static_argnums, mesh, on_retrace)
+
+    def _lookup(self, fn, args, kwargs, donate_argnums, static_argnums,
+                mesh, on_retrace):
         treedef, avals = _abstract_key(args, kwargs)
         fn_key = (id(fn), getattr(fn, "__qualname__", None))
         key = (fn_key, treedef, avals, _mesh_key(mesh),
@@ -169,19 +180,17 @@ class ExecutableCache:
         # in-process users (no worker_main): executables that outlive
         # this cache's process go to the placed persistent cache
         configure_compile_cache()
-        t0 = time.perf_counter()
-        with _tracing.span("compiled_step.lower", attrs={
+        with self.phases.phase("compiled_step.lower", attrs={
                 "fn": getattr(fn, "__name__", "?"),
-                "retrace": retraced}):
+                "retrace": retraced}) as lowering:
             compiled = jax.jit(
                 fn, donate_argnums=donate_argnums,
                 static_argnums=static_argnums,
             ).lower(*args, **kwargs).compile()
-        lowering_ms = (time.perf_counter() - t0) * 1e3
         with self._lock:
             # keep fn alive alongside its executable (id-key safety)
             self._entries[key] = (fn, compiled)
-            self.stats.lowering_ms += lowering_ms
+            self.stats.lowering_ms += lowering.ns / 1e6
         return compiled
 
 
@@ -195,10 +204,13 @@ def global_cache() -> ExecutableCache:
 def cache_stats() -> Dict[str, int]:
     """Process-wide executable-cache counters (bench `dispatch_overhead`
     and the /metrics scrape read these): hits / misses / retraces /
-    entries / cumulative lowering ms."""
+    entries / cumulative lowering ms, and what the lookups themselves
+    cost (`lookup_ms` over `lookups` calls, compiles not included)."""
     stats = _GLOBAL_CACHE.stats.as_dict()
     stats["entries"] = _GLOBAL_CACHE.size()
     stats["lowering_ms"] = round(_GLOBAL_CACHE.stats.lowering_ms, 3)
+    stats["lookup_ms"] = round(_GLOBAL_CACHE.phases.ms("cache_lookup"), 3)
+    stats["lookups"] = _GLOBAL_CACHE.phases.count("cache_lookup")
     return stats
 
 
@@ -214,7 +226,10 @@ def _metrics_text() -> str:
         "# TYPE compile_cache_entries gauge\n"
         f"compile_cache_entries {s['entries']}\n"
         "# TYPE compile_cache_lowering_ms_total counter\n"
-        f"compile_cache_lowering_ms_total {s['lowering_ms']}\n")
+        f"compile_cache_lowering_ms_total {s['lowering_ms']}\n"
+        "# TYPE compile_cache_lookup_ms_total counter\n"
+        f"compile_cache_lookup_ms_total {s['lookup_ms']}\n"
+        f"compile_cache_lookups_total {s['lookups']}\n")
 
 
 _metrics.DEFAULT_REGISTRY.register_callback("compile_cache", _metrics_text)

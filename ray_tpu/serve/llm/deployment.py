@@ -172,6 +172,20 @@ class LLMDeployment:
             "cache_stats": parallel.cache_stats(),
         }
 
+    def device_trace(self, seconds: float,
+                     log_dir: Optional[str] = None) -> str:
+        """Profile this replica's process for `seconds`
+        (`handle.device_trace.remote(4.0).result()`): only the process
+        that holds a chip can trace it. Returns the directory, on this
+        replica's node, under which `jax.profiler` wrote the
+        `.xplane.pb`; the engine's `rt/` phases are host events of the
+        same file, on the clock of the device's."""
+        from ray_tpu.util import tracing
+
+        with tracing.device_trace(log_dir) as path:
+            time.sleep(float(seconds))
+        return path
+
     def check_health(self) -> bool:
         return self.engine._thread is not None and \
             self.engine._thread.is_alive()

@@ -114,6 +114,20 @@ class RequestRejected(RuntimeError):
     pass
 
 
+# The pump thread's time ledger (util/tracing.PhaseTable): between start()
+# and stop() every nanosecond of the thread is one of these names' self
+# time. `pump_loop` and `engine_step` are the loop's and step()'s own
+# bookkeeping; `llm.prefill*` what lies between the phases of one request's
+# prefill (the spans a request's flow arrow ends on).
+PUMP_PHASES = (
+    "pump_loop", "pump_idle", "intake", "lock_wait", "engine_step", "admit",
+    "llm.prefill", "llm.prefill_chunk",
+    "prefill_assemble", "prefill_dispatch", "prefill_device_wait",
+    "prefill_kv_fetch", "prefill_kv_write", "prefill_sample",
+    "decode_assemble", "decode_dispatch", "decode_device_wait",
+    "decode_fetch", "decode_kv_append", "decode_sample", "finish")
+
+
 _req_counter = itertools.count(1)
 
 
@@ -298,12 +312,20 @@ class LLMEngine:
                 jax.random.PRNGKey(seed),
                 jnp.ones((1, min(cfg.prefill_buckets)), jnp.int32))
         self.params = params
+        self._block_until_ready = jax.block_until_ready
+
+        self._phases = _tracing.PhaseTable(PUMP_PHASES)
+        # metrics() runs on its callers' threads: a ledger of its own
+        self._metrics_phases = _tracing.PhaseTable(("metrics",))
+        self._pump_phase: Optional[_tracing.Phase] = None
+        self._pump_wall_ns = 0  # of pump threads that have ended
 
         self.kv = PagedKVCache(
             cfg.num_pages, self.model_cfg.n_layer, cfg.block_size,
             n_kv_head, head_dim,
             dtype=jnp.dtype(self.model_cfg.dtype),
-            store=store)
+            store=store,
+            lock=_tracing.TimedLock(self._phases, threading.Lock()))
         self.prefix = PrefixCache(self.kv) if cfg.prefix_cache else None
 
         # one compiled_step wrapper per bucket: each sees exactly one
@@ -359,7 +381,8 @@ class LLMEngine:
             self.kv_d = PagedKVCache(
                 cfg.max_running * self.max_pages_per_seq_d,
                 self.draft_cfg.n_layer, cfg.block_size, d_kvh, d_hd,
-                dtype=jnp.dtype(self.draft_cfg.dtype))
+                dtype=jnp.dtype(self.draft_cfg.dtype),
+                lock=_tracing.TimedLock(self._phases, threading.Lock()))
             # verify: one multi-token target forward per batch bucket,
             # window C = K+1 ([last_committed, draft_1..draft_K]) — the
             # accept length varies per round but the window never does,
@@ -388,8 +411,12 @@ class LLMEngine:
         # dispatch plane v2: (ring, sub-ring index, deployment) once a
         # replica attaches its native intake — drained by the pump
         self._intake = None
-        self._lock = threading.Lock()       # guards queues + counters
-        self._step_lock = threading.Lock()  # serializes step()
+        # the pump's waits on these (and on the KV cache's lock) are its
+        # `lock_wait` phase
+        self._lock = _tracing.TimedLock(       # guards queues + counters
+            self._phases, threading.Lock())
+        self._step_lock = _tracing.TimedLock(  # serializes step()
+            self._phases, threading.Lock())
         self._work = threading.Event()
         self._stop = threading.Event()
         self._thread: Optional[threading.Thread] = None
@@ -564,43 +591,52 @@ class LLMEngine:
         """One engine iteration: admit + prefill up to
         `max_prefills_per_step` prompts, then one decode pass over the
         running set. Returns False when there was nothing to do."""
-        with self._step_lock:
-            t0 = time.perf_counter()
-            prefill_ms = decode_ms = 0.0
+        phases = self._phases
+        # bare reads: an iteration with work is a step of a profiler's
+        # overview, a poll of empty queues is not
+        with self._step_lock, phases.phase(
+                "engine_step",
+                step=self._step_no + 1 if self._waiting or self._prefilling
+                or self._running else None) as whole:
+            # a stretch's wall time is what the ledger charged
+            # meanwhile: its phases, and any lock_wait inside them
+            prefill_ns = decode_ns = 0
             tokens_out = 0
             advanced = False
-            self._shed_expired()
+            with phases.phase("admit"):
+                self._shed_expired()
             for _ in range(self.config.max_prefills_per_step):
                 if len(self._prefilling) < \
                         self.config.max_prefills_per_step:
-                    self._admit_one()
+                    with phases.phase("admit"):
+                        self._admit_one()
                 if not self._prefilling:
                     break
-                t1 = time.perf_counter()
+                mark = phases.total_ns()
                 # ONE chunk (or one-shot bucket prefill) per slot per
                 # step: a long prompt spreads across steps while decode
                 # below keeps running — the head-of-line fix
                 tokens_out += self._advance_prefill()
                 advanced = True
-                prefill_ms += (time.perf_counter() - t1) * 1e3
+                prefill_ns += phases.total_ns() - mark
             if self._running:
-                t1 = time.perf_counter()
+                mark = phases.total_ns()
                 if self.kv_d is not None:
                     tokens_out += self._spec_decode_once()
                 else:
                     tokens_out += self._decode_once()
-                decode_ms += (time.perf_counter() - t1) * 1e3
+                decode_ns += phases.total_ns() - mark
             did = bool(tokens_out) or advanced
             if did:
                 self._step_no += 1
+                prefill_ms, decode_ms = prefill_ns / 1e6, decode_ns / 1e6
                 with self._lock:
                     self.counters["prefill_ms"] += prefill_ms
                     self.counters["decode_ms"] += decode_ms
                     self.counters["tokens_generated"] += tokens_out
                 if _sp.enabled():
                     _sp.record_step(
-                        self._step_no,
-                        (time.perf_counter() - t0) * 1e3,
+                        self._step_no, whole.elapsed_ns() / 1e6,
                         tokens=tokens_out, prefill_ms=prefill_ms,
                         decode_ms=decode_ms,
                         running=len(self._running))
@@ -680,33 +716,36 @@ class LLMEngine:
         when target prefill completes: the first token comes from the
         final chunk's logits, so TTFT lands before the draft finishes
         warming)."""
-        seq = self._prefilling[0]
-        req = seq.req
-        s = len(req.prompt)
-        emitted = 0
-        t0 = time.perf_counter()
-        if seq.prefilled < s:
-            oneshot = (seq.prefilled == 0
-                       and s <= max(self.config.prefill_buckets)
-                       and (not self.config.prefill_chunk
-                            or s <= self._chunk_size))
-            if oneshot:
-                emitted = self._prefill_oneshot(seq)
-            else:
-                emitted = self._chunk_advance(seq)
-        elif self.kv_d is not None and seq.d_prefilled < s:
-            self._draft_prefill_advance(seq)
-        req.prefill_ms += (time.perf_counter() - t0) * 1e3
-        ready = seq.prefilled >= s and \
-            (self.kv_d is None or seq.d_prefilled >= s)
-        if ready or seq.req.done.is_set():
-            with self._lock:
-                if seq in self._prefilling:
-                    self._prefilling.remove(seq)
-            if not seq.req.done.is_set():
+        # this unit's own time (what lies between the phases below, the
+        # hand-over to the running set) is `prefill_assemble`
+        with self._phases.phase("prefill_assemble"):
+            seq = self._prefilling[0]
+            req = seq.req
+            s = len(req.prompt)
+            emitted = 0
+            mark = self._phases.total_ns()
+            if seq.prefilled < s:
+                oneshot = (seq.prefilled == 0
+                           and s <= max(self.config.prefill_buckets)
+                           and (not self.config.prefill_chunk
+                                or s <= self._chunk_size))
+                if oneshot:
+                    emitted = self._prefill_oneshot(seq)
+                else:
+                    emitted = self._chunk_advance(seq)
+            elif self.kv_d is not None and seq.d_prefilled < s:
+                self._draft_prefill_advance(seq)
+            req.prefill_ms += (self._phases.total_ns() - mark) / 1e6
+            ready = seq.prefilled >= s and \
+                (self.kv_d is None or seq.d_prefilled >= s)
+            if ready or seq.req.done.is_set():
                 with self._lock:
-                    self._running.append(seq)
-        return emitted
+                    if seq in self._prefilling:
+                        self._prefilling.remove(seq)
+                if not seq.req.done.is_set():
+                    with self._lock:
+                        self._running.append(seq)
+            return emitted
 
     def _emit_first(self, seq: _Sequence, next_logits_row) -> int:
         """Emit the prompt's next token; on finish, release everything
@@ -717,29 +756,63 @@ class LLMEngine:
             self._finish(seq)
         return 1
 
+    def _request_phase(self, name: str, req: Request,
+                       attrs: Dict[str, Any]) -> _tracing.Phase:
+        """The phase one request's prefill work nests in. It carries the
+        request's id (the recorder's, where the request came through a
+        handle), and its JSONL span is where the handle's flow arrow
+        ends."""
+        req_id = req.id
+        if req.ctx:
+            req_id = req.ctx["req_id"]
+            attrs["flow_id"] = f"req:{req_id}"
+        return self._phases.phase(name, req_id=req_id, kind="consumer",
+                                  attrs=attrs)
+
+    def _prefill_forward(self, fn, args, kv: PagedKVCache,
+                         pages: List[int], n: int, start: int = 0,
+                         rows: Optional[int] = None):
+        """What every prefill shares (one-shot, chunk, draft): the call,
+        the wait, K and V to the host, K and V into the pages. Returns the
+        logits, still on the device."""
+        phase = self._phases.phase
+        with phase("prefill_dispatch"):
+            logits, k, v = fn(*args)
+            k, v = (k[0], v[0]) if rows is None \
+                else (k[0, :rows], v[0, :rows])
+        with phase("prefill_device_wait"):
+            # the np.asarray below would block on these anyway
+            self._block_until_ready((k, v))
+        with phase("prefill_kv_fetch"):
+            k, v = np.asarray(k), np.asarray(v)
+        with phase("prefill_kv_write"):
+            kv.write_prefill(pages, k, v, n, start=start)
+        return logits
+
     def _prefill_oneshot(self, seq: _Sequence) -> int:
         req = seq.req
         s = len(req.prompt)
         bucket = min(b for b in self.config.prefill_buckets if b >= s)
-        attrs: Dict[str, Any] = {"bucket": bucket, "tokens_in": s}
-        if req.ctx:
-            attrs["req_id"] = req.ctx["req_id"]
-            attrs["flow_id"] = f"req:{req.ctx['req_id']}"
-        with _tracing.span("llm.prefill", kind="consumer", attrs=attrs):
-            toks = np.zeros((1, bucket), np.int32)
-            toks[0, :s] = req.prompt
-            self._note_call("prefill", bucket)
-            next_logits, k, v = self._prefill_fns[bucket](
-                self.params, toks, np.asarray([s], np.int32))
-            self.kv.write_prefill(seq.pages, np.asarray(k[0]),
-                                  np.asarray(v[0]), s)
-            seq.prefilled = s
-            seq.pos = s
-            if self.prefix is not None:
-                self.prefix.insert(req.prompt, seq.pages)
-            with self._lock:
-                self.counters["prefill_steps"] += 1
-            return self._emit_first(seq, next_logits[0])
+        phase = self._phases.phase
+        with self._request_phase("llm.prefill", req,
+                                 {"bucket": bucket, "tokens_in": s}):
+            with phase("prefill_assemble"):
+                toks = np.zeros((1, bucket), np.int32)
+                toks[0, :s] = req.prompt
+                self._note_call("prefill", bucket)
+            next_logits = self._prefill_forward(
+                self._prefill_fns[bucket],
+                (self.params, toks, np.asarray([s], np.int32)),
+                self.kv, seq.pages, s)
+            with phase("prefill_kv_write"):
+                seq.prefilled = s
+                seq.pos = s
+                if self.prefix is not None:
+                    self.prefix.insert(req.prompt, seq.pages)
+                with self._lock:
+                    self.counters["prefill_steps"] += 1
+            with phase("prefill_sample"):
+                return self._emit_first(seq, next_logits[0])
 
     def _chunk_advance(self, seq: _Sequence) -> int:
         """One target-model chunk: forward the next `_chunk_size`
@@ -750,36 +823,35 @@ class LLMEngine:
         s = len(req.prompt)
         c = self._chunk_size
         take = min(c, s - seq.prefilled)
-        toks = np.zeros((1, c), np.int32)
-        toks[0, :take] = req.prompt[seq.prefilled:seq.prefilled + take]
-        table = np.zeros((1, self.max_pages_per_seq), np.int32)
-        table[0, :len(seq.pages)] = seq.pages
-        attrs: Dict[str, Any] = {"chunk": c, "start": seq.prefilled,
-                                 "tokens_in": take}
-        if req.ctx:
-            attrs["req_id"] = req.ctx["req_id"]
-            attrs["flow_id"] = f"req:{req.ctx['req_id']}"
-        with _tracing.span("llm.prefill_chunk", kind="consumer",
-                           attrs=attrs):
-            self._note_call("chunk", c)
-            logits, k, v = self._chunk_fn(
-                self.params, toks,
-                np.asarray([seq.prefilled], np.int32),
-                self.kv.k_pages, self.kv.v_pages, table)
-            self.kv.write_prefill(seq.pages, np.asarray(k[0, :take]),
-                                  np.asarray(v[0, :take]), take,
-                                  start=seq.prefilled)
-            seq.prefilled += take
-            with self._lock:
-                self.counters["chunk_steps"] += 1
-            if seq.prefilled < s:
-                return 0
-            seq.pos = s
-            if self.prefix is not None:
-                self.prefix.insert(req.prompt, seq.pages)
-            with self._lock:
-                self.counters["prefill_steps"] += 1
-            return self._emit_first(seq, logits[0, take - 1])
+        phase = self._phases.phase
+        with self._request_phase(
+                "llm.prefill_chunk", req,
+                {"chunk": c, "start": seq.prefilled, "tokens_in": take}):
+            with phase("prefill_assemble"):
+                toks = np.zeros((1, c), np.int32)
+                toks[0, :take] = \
+                    req.prompt[seq.prefilled:seq.prefilled + take]
+                table = np.zeros((1, self.max_pages_per_seq), np.int32)
+                table[0, :len(seq.pages)] = seq.pages
+                self._note_call("chunk", c)
+            logits = self._prefill_forward(
+                self._chunk_fn,
+                (self.params, toks, np.asarray([seq.prefilled], np.int32),
+                 self.kv.k_pages, self.kv.v_pages, table),
+                self.kv, seq.pages, take, start=seq.prefilled, rows=take)
+            with phase("prefill_kv_write"):
+                seq.prefilled += take
+                with self._lock:
+                    self.counters["chunk_steps"] += 1
+                if seq.prefilled < s:
+                    return 0
+                seq.pos = s
+                if self.prefix is not None:
+                    self.prefix.insert(req.prompt, seq.pages)
+                with self._lock:
+                    self.counters["prefill_steps"] += 1
+            with phase("prefill_sample"):
+                return self._emit_first(seq, logits[0, take - 1])
 
     def _draft_prefill_advance(self, seq: _Sequence):
         """Warm the draft model's private KV for this sequence. The
@@ -788,70 +860,89 @@ class LLMEngine:
         when the prompt fits, else one chunk per step."""
         req = seq.req
         s = len(req.prompt)
+        phase = self._phases.phase
         if seq.d_prefilled == 0 and \
                 s <= max(self.config.prefill_buckets):
-            bucket = min(b for b in self.config.prefill_buckets
-                         if b >= s)
-            toks = np.zeros((1, bucket), np.int32)
-            toks[0, :s] = req.prompt
-            self._note_call("draft_prefill", bucket)
-            _, k, v = self._d_prefill_fns[bucket](
-                self.draft_params, toks, np.asarray([s], np.int32))
-            self.kv_d.write_prefill(seq.d_pages, np.asarray(k[0]),
-                                    np.asarray(v[0]), s)
+            with phase("prefill_assemble"):
+                bucket = min(b for b in self.config.prefill_buckets
+                             if b >= s)
+                toks = np.zeros((1, bucket), np.int32)
+                toks[0, :s] = req.prompt
+                self._note_call("draft_prefill", bucket)
+            self._prefill_forward(
+                self._d_prefill_fns[bucket],
+                (self.draft_params, toks, np.asarray([s], np.int32)),
+                self.kv_d, seq.d_pages, s)
             seq.d_prefilled = s
         else:
-            c = self._chunk_size
-            take = min(c, s - seq.d_prefilled)
-            toks = np.zeros((1, c), np.int32)
-            toks[0, :take] = \
-                req.prompt[seq.d_prefilled:seq.d_prefilled + take]
-            table = np.zeros((1, self.max_pages_per_seq_d), np.int32)
-            table[0, :len(seq.d_pages)] = seq.d_pages
-            self._note_call("draft_chunk", c)
-            _, k, v = self._d_chunk_fn(
-                self.draft_params, toks,
-                np.asarray([seq.d_prefilled], np.int32),
-                self.kv_d.k_pages, self.kv_d.v_pages, table)
-            self.kv_d.write_prefill(seq.d_pages,
-                                    np.asarray(k[0, :take]),
-                                    np.asarray(v[0, :take]), take,
-                                    start=seq.d_prefilled)
+            with phase("prefill_assemble"):
+                c = self._chunk_size
+                take = min(c, s - seq.d_prefilled)
+                toks = np.zeros((1, c), np.int32)
+                toks[0, :take] = \
+                    req.prompt[seq.d_prefilled:seq.d_prefilled + take]
+                table = np.zeros((1, self.max_pages_per_seq_d), np.int32)
+                table[0, :len(seq.d_pages)] = seq.d_pages
+                self._note_call("draft_chunk", c)
+            self._prefill_forward(
+                self._d_chunk_fn,
+                (self.draft_params, toks,
+                 np.asarray([seq.d_prefilled], np.int32),
+                 self.kv_d.k_pages, self.kv_d.v_pages, table),
+                self.kv_d, seq.d_pages, take, start=seq.d_prefilled,
+                rows=take)
             seq.d_prefilled += take
         seq.d_pos = seq.d_prefilled
 
+    def _decode_forward(self, fn, args) -> Tuple[np.ndarray, ...]:
+        """One decode-shaped call (decode, draft decode, verify): the
+        call, the wait, its outputs to the host."""
+        phase = self._phases.phase
+        with phase("decode_dispatch"):
+            out = fn(*args)
+        with phase("decode_device_wait"):
+            # the np.asarray below would block on these anyway
+            self._block_until_ready(out)
+        with phase("decode_fetch"):
+            return tuple(np.asarray(x) for x in out)
+
     def _decode_once(self) -> int:
-        with self._lock:
-            runs = list(self._running)
-        bb = min(b for b in self.config.batch_buckets
-                 if b >= len(runs))
-        tokens = np.zeros(bb, np.int32)
-        positions = np.zeros(bb, np.int32)
-        page_table = np.zeros((bb, self.max_pages_per_seq), np.int32)
-        for i, seq in enumerate(runs):
-            tokens[i] = seq.last_token
-            positions[i] = seq.pos
-            page_table[i, :len(seq.pages)] = seq.pages
-        self._note_call("decode", bb)
-        logits, new_k, new_v = self._decode_fns[bb](
-            self.params, tokens, positions,
-            self.kv.k_pages, self.kv.v_pages, page_table)
-        logits = np.asarray(logits)
-        new_k = np.asarray(new_k)
-        new_v = np.asarray(new_v)
-        finished = []
-        for i, seq in enumerate(runs):
-            self.kv.append(seq.pages, seq.pos, new_k[i], new_v[i])
-            seq.pos += 1
-            tok = int(np.argmax(logits[i]))
-            seq.req._emit(tok)
-            if self._seq_finished(seq, tok):
-                finished.append(seq)
-        with self._lock:
-            self.counters["decode_steps"] += 1
-        for seq in finished:
-            self._finish(seq)
-        return len(runs)
+        phase = self._phases.phase
+        # the pass's own time (batch assembly, what lies between the
+        # phases below) is `decode_assemble`
+        with phase("decode_assemble"):
+            with self._lock:
+                runs = list(self._running)
+            bb = min(b for b in self.config.batch_buckets
+                     if b >= len(runs))
+            tokens = np.zeros(bb, np.int32)
+            positions = np.zeros(bb, np.int32)
+            page_table = np.zeros((bb, self.max_pages_per_seq), np.int32)
+            for i, seq in enumerate(runs):
+                tokens[i] = seq.last_token
+                positions[i] = seq.pos
+                page_table[i, :len(seq.pages)] = seq.pages
+            self._note_call("decode", bb)
+            logits, new_k, new_v = self._decode_forward(
+                self._decode_fns[bb],
+                (self.params, tokens, positions,
+                 self.kv.k_pages, self.kv.v_pages, page_table))
+            with phase("decode_kv_append"):
+                for i, seq in enumerate(runs):
+                    self.kv.append(seq.pages, seq.pos, new_k[i], new_v[i])
+                    seq.pos += 1
+            finished = []
+            with phase("decode_sample"):
+                for i, seq in enumerate(runs):
+                    tok = int(np.argmax(logits[i]))
+                    seq.req._emit(tok)
+                    if self._seq_finished(seq, tok):
+                        finished.append(seq)
+                with self._lock:
+                    self.counters["decode_steps"] += 1
+            for seq in finished:
+                self._finish(seq)
+            return len(runs)
 
     def _spec_decode_once(self) -> int:
         """One speculative round over the running set (Leviathan et al.
@@ -873,64 +964,79 @@ class LLMEngine:
         length variation can not retrace anything.
         """
         K = self.config.spec_k
-        with self._lock:
-            runs = list(self._running)
-        n = len(runs)
-        bb = min(b for b in self.config.batch_buckets if b >= n)
-        full = [seq.req.prompt + seq.req.tokens for seq in runs]
-        gaps = [seq.pos - seq.d_pos for seq in runs]
-        cur = [seq.d_pos for seq in runs]
-        budget = [g + K for g in gaps]
-        proposals: List[List[int]] = [[] for _ in range(n)]
-        d_table = np.zeros((bb, self.max_pages_per_seq_d), np.int32)
-        for i, seq in enumerate(runs):
-            d_table[i, :len(seq.d_pages)] = seq.d_pages
-        n_steps = max(budget)
-        for t in range(n_steps):
-            toks = np.zeros(bb, np.int32)
-            poss = np.zeros(bb, np.int32)
-            active = []
+        phase = self._phases.phase
+        # as in _decode_once: the round's own time is `decode_assemble`
+        with phase("decode_assemble"):
+            with self._lock:
+                runs = list(self._running)
+            n = len(runs)
+            bb = min(b for b in self.config.batch_buckets if b >= n)
+            full = [seq.req.prompt + seq.req.tokens for seq in runs]
+            gaps = [seq.pos - seq.d_pos for seq in runs]
+            cur = [seq.d_pos for seq in runs]
+            budget = [g + K for g in gaps]
+            proposals: List[List[int]] = [[] for _ in range(n)]
+            d_table = np.zeros((bb, self.max_pages_per_seq_d), np.int32)
             for i, seq in enumerate(runs):
-                if t >= budget[i]:
-                    continue  # lane idle: feed zeros, discard output
-                active.append(i)
-                idx = cur[i]
-                if idx < len(full[i]):
-                    toks[i] = full[i][idx]  # committed token (catch-up
-                    # or the round's first proposal input)
-                else:
-                    toks[i] = proposals[i][idx - len(full[i])]
-                poss[i] = idx
-            self._note_call("draft_decode", bb)
-            d_logits, d_k, d_v = self._d_decode_fns[bb](
-                self.draft_params, toks, poss,
-                self.kv_d.k_pages, self.kv_d.v_pages, d_table)
-            d_logits = np.asarray(d_logits)
-            d_k = np.asarray(d_k)
-            d_v = np.asarray(d_v)
-            for i in active:
-                self.kv_d.append(runs[i].d_pages, cur[i],
-                                 d_k[i], d_v[i])
-                cur[i] += 1
-                if cur[i] > runs[i].pos:  # past catch-up: a proposal
-                    proposals[i].append(int(np.argmax(d_logits[i])))
-        # verify: target scores [last_committed, d_1..d_K] at positions
-        # pos..pos+K in one window
-        v_toks = np.zeros((bb, K + 1), np.int32)
-        v_start = np.zeros(bb, np.int32)
-        v_table = np.zeros((bb, self.max_pages_per_seq), np.int32)
-        for i, seq in enumerate(runs):
-            v_toks[i, 0] = seq.last_token
-            v_toks[i, 1:] = proposals[i][:K]
-            v_start[i] = seq.pos
-            v_table[i, :len(seq.pages)] = seq.pages
-        self._note_call("verify", bb)
-        logits, new_k, new_v = self._verify_fns[bb](
-            self.params, v_toks, v_start,
-            self.kv.k_pages, self.kv.v_pages, v_table)
-        logits = np.asarray(logits)
-        new_k = np.asarray(new_k)
-        new_v = np.asarray(new_v)
+                d_table[i, :len(seq.d_pages)] = seq.d_pages
+            n_steps = max(budget)
+            for t in range(n_steps):
+                toks = np.zeros(bb, np.int32)
+                poss = np.zeros(bb, np.int32)
+                active = []
+                for i, seq in enumerate(runs):
+                    if t >= budget[i]:
+                        continue  # lane idle: feed zeros, discard output
+                    active.append(i)
+                    idx = cur[i]
+                    if idx < len(full[i]):
+                        toks[i] = full[i][idx]  # committed token (catch-up
+                        # or the round's first proposal input)
+                    else:
+                        toks[i] = proposals[i][idx - len(full[i])]
+                    poss[i] = idx
+                self._note_call("draft_decode", bb)
+                d_logits, d_k, d_v = self._decode_forward(
+                    self._d_decode_fns[bb],
+                    (self.draft_params, toks, poss,
+                     self.kv_d.k_pages, self.kv_d.v_pages, d_table))
+                with phase("decode_kv_append"):
+                    for i in active:
+                        self.kv_d.append(runs[i].d_pages, cur[i],
+                                         d_k[i], d_v[i])
+                        cur[i] += 1
+                with phase("decode_sample"):
+                    for i in active:
+                        if cur[i] > runs[i].pos:  # past catch-up
+                            proposals[i].append(
+                                int(np.argmax(d_logits[i])))
+            # verify: target scores [last_committed, d_1..d_K] at
+            # positions pos..pos+K in one window
+            v_toks = np.zeros((bb, K + 1), np.int32)
+            v_start = np.zeros(bb, np.int32)
+            v_table = np.zeros((bb, self.max_pages_per_seq), np.int32)
+            for i, seq in enumerate(runs):
+                v_toks[i, 0] = seq.last_token
+                v_toks[i, 1:] = proposals[i][:K]
+                v_start[i] = seq.pos
+                v_table[i, :len(seq.pages)] = seq.pages
+            self._note_call("verify", bb)
+            logits, new_k, new_v = self._decode_forward(
+                self._verify_fns[bb],
+                (self.params, v_toks, v_start,
+                 self.kv.k_pages, self.kv.v_pages, v_table))
+            with phase("decode_sample"):
+                tokens_out, finished = self._spec_accept(
+                    runs, proposals, logits, new_k, new_v)
+            for seq in finished:
+                self._finish(seq)
+            return tokens_out
+
+    def _spec_accept(self, runs, proposals, logits, new_k, new_v):
+        """The accept loop of a speculative round: emit each lane's
+        accepted tokens and commit their K/V. Returns the tokens emitted
+        and the sequences that finished."""
+        K = self.config.spec_k
         tokens_out = 0
         finished = []
         for i, seq in enumerate(runs):
@@ -959,17 +1065,16 @@ class LLMEngine:
             # committed tokens' K/V ([last, d_1..d_a] == [last,
             # g_0..g_{a-1}]); the draft cache is correct through
             # pos + min(a+1, K) (it never saw g_a when a == K)
-            self.kv.write_prefill(seq.pages, new_k[i, :emitted],
-                                  new_v[i, :emitted], emitted,
-                                  start=seq.pos)
+            with self._phases.phase("decode_kv_append"):
+                self.kv.write_prefill(seq.pages, new_k[i, :emitted],
+                                      new_v[i, :emitted], emitted,
+                                      start=seq.pos)
             seq.d_pos = seq.pos + min(a + 1, K)
             seq.pos += emitted
         with self._lock:
             self.counters["decode_steps"] += 1
             self.counters["spec_rounds"] += 1
-        for seq in finished:
-            self._finish(seq)
-        return tokens_out
+        return tokens_out, finished
 
     def _seq_finished(self, seq: _Sequence, tok: int) -> bool:
         if seq.n_generated >= seq.req.max_new_tokens:
@@ -982,20 +1087,22 @@ class LLMEngine:
         return False
 
     def _finish(self, seq: _Sequence):
-        # refcounted free: pages the prefix cache (or a sibling
-        # sequence) still aliases survive this — only the refcount drops
-        self.kv.free(seq.pages, seq.req)
-        if seq.d_pages is not None:
-            self.kv_d.free(seq.d_pages, seq.req)
-        with self._lock:
-            if seq in self._running:
-                self._running.remove(seq)
-            self.counters["requests_completed"] += 1
-            row = self._tenant_row(seq.req.tenant)
-            row["requests_completed"] += 1
-            row["tokens_generated"] += len(seq.req.tokens)
-        seq.req._finish(seq.req.finish_reason or "length")
-        self._emit_request_record(seq.req, "ok")
+        with self._phases.phase("finish"):
+            # refcounted free: pages the prefix cache (or a sibling
+            # sequence) still aliases survive this — only the refcount
+            # drops
+            self.kv.free(seq.pages, seq.req)
+            if seq.d_pages is not None:
+                self.kv_d.free(seq.d_pages, seq.req)
+            with self._lock:
+                if seq in self._running:
+                    self._running.remove(seq)
+                self.counters["requests_completed"] += 1
+                row = self._tenant_row(seq.req.tenant)
+                row["requests_completed"] += 1
+                row["tokens_generated"] += len(seq.req.tokens)
+            seq.req._finish(seq.req.finish_reason or "length")
+            self._emit_request_record(seq.req, "ok")
 
     def _emit_request_record(self, req: Request, outcome: str):
         """Fold one finished request into the flight recorder: engine
@@ -1126,19 +1233,32 @@ class LLMEngine:
         self._thread.start()
 
     def _pump(self):
-        while not self._stop.is_set():
-            self._pump_probe.beat()
-            drained = self._drain_intake()
-            if not self.step() and not drained:
-                self._work.clear()
-                it = self._intake
-                if it is not None:
-                    # park on the ring's wakeup FIFO so a native enqueue
-                    # wakes the pump without a poll; local submits still
-                    # set _work, observed at the next bounded slice
-                    it[0].wait(it[1], 0.02)
-                else:
-                    self._work.wait(0.02)
+        phase = self._phases.phase
+        with phase("pump_loop") as loop:
+            with self._lock:
+                self._pump_phase = loop
+            try:
+                while not self._stop.is_set():
+                    self._pump_probe.beat()
+                    with phase("intake"):
+                        drained = self._drain_intake()
+                    if self.step() or drained:
+                        continue
+                    with phase("pump_idle"):
+                        self._work.clear()
+                        it = self._intake
+                        if it is not None:
+                            # park on the ring's wakeup FIFO so a native
+                            # enqueue wakes the pump without a poll; local
+                            # submits still set _work, observed at the next
+                            # bounded slice
+                            it[0].wait(it[1], 0.02)
+                        else:
+                            self._work.wait(0.02)
+            finally:
+                with self._lock:
+                    self._pump_wall_ns += loop.elapsed_ns()
+                    self._pump_phase = None
 
     def stop(self):
         self._stop.set()
@@ -1207,8 +1327,19 @@ class LLMEngine:
         return leaked + self.kv.close()
 
     def metrics(self) -> Dict[str, Any]:
+        """Counters and gauges of this engine. `ph_<phase>_ms` is the pump
+        thread's time ledger (self time by `PUMP_PHASES` name, dots
+        written `_`), which sums to `pump_wall_ms`; `metrics_ms` is the
+        time spent in here, over `metrics_calls` finished calls."""
+        with self._metrics_phases.phase("metrics"):
+            return self._metrics()
+
+    def _metrics(self) -> Dict[str, Any]:
         with self._lock:
             out = dict(self.counters)
+            pump = self._pump_phase
+            out["pump_wall_ms"] = (self._pump_wall_ns + (
+                pump.elapsed_ns() if pump is not None else 0)) / 1e6
             out.update(
                 queue_depth=len(self._waiting),
                 prefilling=len(self._prefilling),
@@ -1237,6 +1368,10 @@ class LLMEngine:
                 prefix_cache_entries=ps["entries"],
                 prefix_cache_evicted=ps["evicted"],
             )
+        for name, ns in self._phases.snapshot_ns().items():
+            out[f"ph_{name.replace('.', '_')}_ms"] = ns / 1e6
+        out["metrics_calls"] = self._metrics_phases.count("metrics")
+        out["metrics_ms"] = self._metrics_phases.ms("metrics")
         if out["spec_proposed"]:
             # mean accepted draft tokens per round (<= K); the bench
             # artifact records this next to the A/B throughputs
@@ -1270,7 +1405,11 @@ class LLMEngine:
             f"serve_llm_prefill_ms_total {m['prefill_ms']:.3f}",
             "# TYPE serve_llm_decode_ms_total counter",
             f"serve_llm_decode_ms_total {m['decode_ms']:.3f}",
+            "# TYPE serve_llm_phase_ms_total counter",
         ]
+        lines += [f'serve_llm_phase_ms_total{{phase="{name}"}} '
+                  f"{m['ph_' + name.replace('.', '_') + '_ms']:.3f}"
+                  for name in PUMP_PHASES]
         if "prefix_cache_hit_tokens" in m:
             lines += [
                 "# TYPE serve_llm_prefix_cache_hit_tokens_total counter",

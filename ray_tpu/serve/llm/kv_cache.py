@@ -64,7 +64,7 @@ class PagedKVCache:
 
     def __init__(self, num_pages: int, n_layer: int, block_size: int,
                  n_kv_head: int, head_dim: int, dtype=np.float32,
-                 store=None):
+                 store=None, lock=None):
         if num_pages <= 0 or block_size <= 0:
             raise KVCacheError("num_pages and block_size must be positive")
         self.num_pages = num_pages
@@ -75,7 +75,8 @@ class PagedKVCache:
         self.dtype = np.dtype(dtype)
         self._store = store
         self._arena_id = None
-        self._lock = threading.Lock()
+        # the engine passes a lock whose waits show in its time ledger
+        self._lock = lock if lock is not None else threading.Lock()
         shape = (2, num_pages, n_layer, block_size, n_kv_head, head_dim)
         nbytes = int(np.prod(shape)) * self.dtype.itemsize
         if store is not None:

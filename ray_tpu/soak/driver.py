@@ -305,7 +305,7 @@ class SoakDriver:
             "RAY_TPU_TRACE_DIR": trace_dir,
         }
         saved = {k: os.environ.get(k) for k in env}
-        os.environ.update(env)
+        os.environ.update(env)  # init() reads RAY_TPU_TRACE anew
         try:
             return self._run_inner(workdir, chaos_dir, trace_dir,
                                    storage, stop_file, spec)
@@ -315,6 +315,8 @@ class SoakDriver:
                     os.environ.pop(k, None)
                 else:
                     os.environ[k] = v
+            from ray_tpu.util import tracing
+            tracing.refresh()
             if not cfg.keep_workdir and cfg.workdir is None:
                 shutil.rmtree(workdir, ignore_errors=True)
 

@@ -73,6 +73,7 @@ class TrainStepRunner:
                  peak_flops: Optional[float] = None):
         from ray_tpu.parallel.compile_cache import (compiled_step,
                                                     fold_steps)
+        from ray_tpu.util import tracing
 
         if steps_per_call < 1:
             raise ValueError("steps_per_call must be >= 1")
@@ -85,6 +86,10 @@ class TrainStepRunner:
         self._flops_per_step = flops_per_step
         self._peak_flops = peak_flops
         self._step = 0
+        # self time by phase of run(), while the flight recorder is on
+        self.phases = tracing.PhaseTable(
+            ("train_step", "train_data_wait", "train_dispatch",
+             "train_device_wait"))
         if steps_per_call == 1:
             self._compiled = compiled_step(
                 step_fn, donate_argnums=(0,) if donate_carry else (),
@@ -126,26 +131,28 @@ class TrainStepRunner:
 
         if not step_profiler.enabled():
             return self._compiled(carry, self._prep_batches(batches))
-        import time
-
         import jax
 
-        t0 = time.perf_counter()
-        batches = self._prep_batches(batches)
-        t1 = time.perf_counter()
-        out = self._compiled(carry, batches)
-        t2 = time.perf_counter()
-        device_ms = 0.0
-        if step_profiler.sync_mode():
-            jax.block_until_ready(out)
-            device_ms = (time.perf_counter() - t2) * 1e3
-        self._step += self.steps_per_call
+        phase = self.phases.phase
         k = self.steps_per_call
+        # a step of a profiler's overview (`rt/train_step`) when a
+        # session is on, numbered by the last step it advances to
+        with phase("train_step", step=self._step + k) as whole:
+            with phase("train_data_wait") as data_wait:
+                batches = self._prep_batches(batches)
+            with phase("train_dispatch") as dispatch:
+                out = self._compiled(carry, batches)
+            device_ns = 0
+            if step_profiler.sync_mode():
+                with phase("train_device_wait") as device_wait:
+                    jax.block_until_ready(out)
+                device_ns = device_wait.ns
+        self._step += k
         step_profiler.record_step(
-            self._step, (time.perf_counter() - t0) * 1e3,
-            host_dispatch_ms=(t2 - t1) * 1e3,
-            device_execute_ms=device_ms,
-            data_wait_ms=(t1 - t0) * 1e3,
+            self._step, whole.ns / 1e6,
+            host_dispatch_ms=dispatch.ns / 1e6,
+            device_execute_ms=device_ns / 1e6,
+            data_wait_ms=data_wait.ns / 1e6,
             tokens=self._tokens_per_step * k,
             flops=self._flops_per_step * k,
             steps_per_call=k,

@@ -11,7 +11,20 @@ cost when disabled), trace/parent ids ride `TaskSpec.trace_ctx`, and
 chrome://tracing view.
 
 Enable with `RAY_TPU_TRACE=1` (optionally `RAY_TPU_TRACE_DIR=...`);
-every process of the cluster inherits the env through the daemons.
+every process of the cluster inherits the env through the daemons. The
+switch is read once at import (`refresh()` re-reads it; `ray_tpu.init()`
+calls that).
+
+Phases (`PhaseTable.phase`) are the spans of a hot loop: the engine's
+pump, the train step, the executable cache. A phase always adds its
+*self time* to a table its owner keeps, which is what `engine.metrics()`
+and `parallel.cache_stats()` publish. Where jax is already imported it
+is also a `jax.profiler.TraceAnnotation` named `rt/<name>`, so that any
+profiler session (`device_trace`, or an operator's) holds the program's
+spans in the `.xplane.pb`, on the clock of the device events. With
+`RAY_TPU_TRACE=1` it is written to the JSONL shard like any `span`. This
+module never imports jax itself while it is imported: daemons and drivers
+that must not touch the chip import it.
 """
 
 from __future__ import annotations
@@ -20,10 +33,12 @@ import contextlib
 import contextvars
 import json
 import os
+import sys
+import tempfile
 import threading
 import time
 import uuid
-from typing import Any, Dict, Iterator, List, Optional
+from typing import Any, Dict, Iterable, Iterator, List, Optional
 
 _current: contextvars.ContextVar[Optional[dict]] = contextvars.ContextVar(
     "ray_tpu_trace_span", default=None)
@@ -48,8 +63,22 @@ if hasattr(os, "register_at_fork"):
     os.register_at_fork(after_in_child=_reset_writer)
 
 
-def enabled() -> bool:
+def _env_enabled() -> bool:
     return os.environ.get("RAY_TPU_TRACE", "") in ("1", "true", "on")
+
+
+_ENABLED = _env_enabled()
+
+
+def enabled() -> bool:
+    """Cached switch: an attribute read on every span and phase."""
+    return _ENABLED
+
+
+def refresh() -> None:
+    """Re-read `RAY_TPU_TRACE` (tests and drivers set it after import)."""
+    global _ENABLED
+    _ENABLED = _env_enabled()
 
 
 def trace_dir() -> str:
@@ -143,6 +172,243 @@ def execute_span(spec) -> Iterator:
               attrs={"task_type": spec.task_type,
                      "task_id": spec.task_id.hex()}):
         yield
+
+
+# -- phases --------------------------------------------------------------
+
+_ANNOTATIONS = None  # (TraceAnnotation, StepTraceAnnotation) once jax is in
+
+
+def _annotations():
+    """jax's profiler annotations, only where the process has imported jax
+    already: this module must not be what brings it in."""
+    global _ANNOTATIONS
+    if _ANNOTATIONS is None and "jax" in sys.modules:
+        try:
+            from jax.profiler import StepTraceAnnotation, TraceAnnotation
+        except ImportError:  # another thread is still importing jax
+            return None
+        _ANNOTATIONS = (TraceAnnotation, StepTraceAnnotation)
+    return _ANNOTATIONS
+
+
+class _ThreadPhases:
+    """One thread's open phases in one table, and the instant up to which
+    its time has been charged."""
+
+    __slots__ = ("stack", "mark")
+
+    def __init__(self):
+        self.stack: List["Phase"] = []
+        self.mark = 0
+
+
+class PhaseTable:
+    """Self time and count by phase name, owned by whoever runs the phases
+    (an engine, a step runner, an executable cache).
+
+    Time is charged to the innermost open phase of the thread at every
+    transition, so while a thread keeps one phase open, every nanosecond of
+    it lies in exactly one name's self time and the names sum to the wall
+    time. `names` are listed from the start at 0, so a reader finds every
+    key before the phase first ran."""
+
+    def __init__(self, names: Iterable[str] = ()):
+        self._lock = threading.Lock()
+        self._ns: Dict[str, int] = {n: 0 for n in names}
+        self._counts: Dict[str, int] = {n: 0 for n in names}
+        self._total = 0
+        self._tls = threading.local()
+        # thread ident -> its open phases, for the live remainder
+        self._open: Dict[int, _ThreadPhases] = {}
+
+    def phase(self, name: str, *, step: Optional[int] = None,
+              req_id: Optional[str] = None, kind: str = "internal",
+              attrs: Optional[Dict[str, Any]] = None) -> "Phase":
+        """A context manager. `step` makes it a step of the profiler's
+        overview (`StepTraceAnnotation`); `req_id` marks per-request work
+        and is inherited by the phases nested in it; `kind` and `attrs` go
+        to the JSONL span as `span()` takes them."""
+        return Phase(self, name, step, req_id, kind, attrs)
+
+    def _thread(self) -> _ThreadPhases:
+        try:
+            return self._tls.phases
+        except AttributeError:
+            st = self._tls.phases = _ThreadPhases()
+            return st
+
+    def _charge(self, name: str, ns: int, count: int = 0) -> None:
+        with self._lock:
+            self._ns[name] = self._ns.get(name, 0) + ns
+            self._total += ns
+            if count:
+                self._counts[name] = self._counts.get(name, 0) + count
+
+    def in_phase(self) -> bool:
+        """Whether the calling thread has a phase of this table open."""
+        return bool(self._thread().stack)
+
+    def total_ns(self) -> int:
+        """Everything charged so far, the calling thread's open phase
+        brought up to now: on a thread that keeps a phase open, the
+        difference of two readings is the wall time between them."""
+        st = self._thread()
+        if st.stack:
+            now = time.perf_counter_ns()
+            self._charge(st.stack[-1].name, now - st.mark)
+            st.mark = now
+        return self._total
+
+    def snapshot_ns(self) -> Dict[str, int]:
+        """Self time by name, with what each thread's open phase has run
+        up since its last transition (read from outside those threads, so
+        a transition under way can misplace a few microseconds)."""
+        with self._lock:
+            out = dict(self._ns)
+        now = time.perf_counter_ns()
+        for st in list(self._open.values()):
+            try:
+                name, mark = st.stack[-1].name, st.mark
+            except IndexError:
+                continue
+            out[name] = out.get(name, 0) + max(0, now - mark)
+        return out
+
+    def ms(self, name: str) -> float:
+        with self._lock:
+            return self._ns.get(name, 0) / 1e6
+
+    def count(self, name: str) -> int:
+        with self._lock:
+            return self._counts.get(name, 0)
+
+    def clear(self) -> None:
+        with self._lock:
+            for name in self._ns:
+                self._ns[name] = self._counts[name] = 0
+            self._total = 0
+
+
+class Phase:
+    """One span of a `PhaseTable`; see `PhaseTable.phase`. After it ends
+    `ns` holds its whole duration, nested phases included."""
+
+    __slots__ = ("table", "name", "step", "req_id", "kind", "attrs", "ns",
+                 "_t0", "_annotation", "_span")
+
+    def __init__(self, table, name, step, req_id, kind, attrs):
+        self.table = table
+        self.name = name
+        self.step = step
+        self.req_id = req_id
+        self.kind = kind
+        self.attrs = attrs
+        self.ns = 0
+        self._annotation = self._span = None
+
+    def elapsed_ns(self) -> int:
+        """Duration so far of a phase that is still open."""
+        return self.ns or time.perf_counter_ns() - self._t0
+
+    def __enter__(self) -> "Phase":
+        table = self.table
+        st = table._thread()
+        now = time.perf_counter_ns()
+        if st.stack:
+            outer = st.stack[-1]
+            table._charge(outer.name, now - st.mark)
+            if self.req_id is None:
+                self.req_id = outer.req_id
+        else:
+            table._open[threading.get_ident()] = st
+        st.stack.append(self)
+        st.mark = self._t0 = now
+        annotations = _annotations()
+        if annotations is None and not _ENABLED:
+            return self
+        tags = dict(self.attrs) if self.attrs else {}
+        if self.req_id is not None:
+            tags.setdefault("req_id", self.req_id)
+        if annotations is not None:
+            if self.step is not None:
+                ann = annotations[1](f"rt/{self.name}", step_num=self.step,
+                                     **tags)
+            else:
+                ann = annotations[0](f"rt/{self.name}", **tags)
+            ann.__enter__()
+            self._annotation = ann
+        if _ENABLED:
+            self._span = span(self.name, kind=self.kind, attrs=tags)
+            self._span.__enter__()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        if self._span is not None:
+            self._span.__exit__(*exc)
+        if self._annotation is not None:
+            self._annotation.__exit__(*exc)
+        table = self.table
+        st = table._thread()
+        now = time.perf_counter_ns()
+        table._charge(self.name, now - st.mark, 1)
+        st.mark = now
+        st.stack.pop()
+        if not st.stack:
+            table._open.pop(threading.get_ident(), None)
+        self.ns = now - self._t0
+
+
+class TimedLock:
+    """`lock`, with a thread's contended acquisitions as the `lock_wait`
+    phase of `table`. An uncontended acquire reads no clock; a thread that
+    has no phase of the table open (a caller of `metrics()`, not the pump)
+    is not charged."""
+
+    __slots__ = ("_table", "_lock")
+
+    def __init__(self, table: PhaseTable, lock):
+        self._table = table
+        self._lock = lock
+
+    def acquire(self, blocking: bool = True, timeout: float = -1) -> bool:
+        if self._lock.acquire(False):
+            return True
+        if not blocking:
+            return False
+        if not self._table.in_phase():
+            return self._lock.acquire(True, timeout)
+        with self._table.phase("lock_wait"):
+            return self._lock.acquire(True, timeout)
+
+    def release(self) -> None:
+        self._lock.release()
+
+    def locked(self) -> bool:
+        return self._lock.locked()
+
+    def __enter__(self) -> "TimedLock":
+        self.acquire()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        self._lock.release()
+
+
+@contextlib.contextmanager
+def device_trace(log_dir: Optional[str] = None) -> Iterator[str]:
+    """A `jax.profiler` session around the block, in the process that holds
+    the device; yields the directory the `.xplane.pb` is written under
+    (`<dir>/plugins/profile/<time>/`). The `rt/` phases that run meanwhile
+    are host events of the same file."""
+    import jax
+
+    log_dir = log_dir or tempfile.mkdtemp(prefix="rt_device_trace_")
+    jax.profiler.start_trace(log_dir)
+    try:
+        yield log_dir
+    finally:
+        jax.profiler.stop_trace()
 
 
 # -- aggregation ---------------------------------------------------------

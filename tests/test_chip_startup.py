@@ -40,6 +40,9 @@ def live_cache_config():
 def test_compile_cache_placed_from_outside_is_left_alone(
         monkeypatch, live_cache_config):
     monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/placed/elsewhere")
+    # an earlier test of this worker may have placed the cache itself
+    monkeypatch.delenv("JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS",
+                       raising=False)
     before = jax.config.jax_compilation_cache_dir
     accelerators.configure_compile_cache()
     assert jax.config.jax_compilation_cache_dir == before

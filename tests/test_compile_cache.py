@@ -154,6 +154,9 @@ def test_train_step_runner_equivalence_and_stats():
         w_ref, loss = _sgd_step(w_ref, b)
         ref_losses.append(float(loss))
 
+    # the runner steps through the process-wide cache, which every earlier
+    # test of this worker has used: count from here
+    before = cache_stats()
     runner = TrainStepRunner(_sgd_step, steps_per_call=k)
     w = jnp.zeros(4)
     it = iter(batches)
@@ -165,7 +168,8 @@ def test_train_step_runner_equivalence_and_stats():
     np.testing.assert_allclose(np.asarray(w), np.asarray(w_ref),
                                rtol=1e-5)
     stats = runner.cache_stats()
-    assert stats["misses"] == 1 and stats["hits"] == 1
+    assert stats["misses"] - before["misses"] == 1
+    assert stats["hits"] - before["hits"] == 1
 
     # steps_per_call=1 path: plain per-batch stepping, same trajectory
     runner1 = TrainStepRunner(_sgd_step)
@@ -179,7 +183,7 @@ def test_train_step_runner_equivalence_and_stats():
 def test_global_cache_stats_shape():
     before = cache_stats()
     assert set(before) == {"hits", "misses", "retraces", "entries",
-                           "lowering_ms"}
+                           "lowering_ms", "lookup_ms", "lookups"}
 
     @compiled_step
     def bump(x):
@@ -190,6 +194,11 @@ def test_global_cache_stats_shape():
     after = cache_stats()
     assert after["misses"] >= before["misses"] + 1
     assert after["hits"] >= before["hits"] + 1
+    # every lookup times itself, hit or miss; the compile is not in it
+    assert after["lookups"] >= before["lookups"] + 2
+    assert after["lookup_ms"] > before["lookup_ms"]
+    assert after["lookup_ms"] - before["lookup_ms"] < \
+        after["lowering_ms"] - before["lowering_ms"]
     global_cache().clear()
     cleared = cache_stats()
     assert cleared["entries"] == 0

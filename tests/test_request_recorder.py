@@ -16,6 +16,8 @@ import pytest
 from ray_tpu.util import metrics as metrics_mod
 from ray_tpu.util import request_recorder as rr
 from ray_tpu.util import tsdb as tsdb_mod
+from ray_tpu.util import tracing as _tracing
+
 
 
 @pytest.fixture(autouse=True)
@@ -223,6 +225,7 @@ def test_request_spans_stitch_across_processes(tmp_path):
     process boundary."""
     trace_dir = str(tmp_path / "traces")
     os.environ["RAY_TPU_TRACE"] = "1"
+    _tracing.refresh()  # read once at import
     os.environ["RAY_TPU_TRACE_DIR"] = trace_dir
     import ray_tpu
     from ray_tpu import serve
@@ -243,6 +246,7 @@ def test_request_spans_stitch_across_processes(tmp_path):
         serve.shutdown()
         ray_tpu.shutdown()
         os.environ.pop("RAY_TPU_TRACE", None)
+        _tracing.refresh()  # read once at import
         os.environ.pop("RAY_TPU_TRACE_DIR", None)
         tracing._reset_writer()
         rr._reset_shard_writer()
@@ -361,6 +365,7 @@ def test_scrape_local_feeds_request_histograms():
 def test_cli_requests_offline(tmp_path, capsys):
     trace_dir = str(tmp_path / "traces")
     os.environ["RAY_TPU_TRACE"] = "1"
+    _tracing.refresh()  # read once at import
     os.environ["RAY_TPU_TRACE_DIR"] = trace_dir
     rr._reset_shard_writer()
     try:
@@ -373,6 +378,7 @@ def test_cli_requests_offline(tmp_path, capsys):
                              ttft_ms=7.0, tpot_ms=1.5, tokens_out=4)
     finally:
         os.environ.pop("RAY_TPU_TRACE", None)
+        _tracing.refresh()  # read once at import
         os.environ.pop("RAY_TPU_TRACE_DIR", None)
         rr._reset_shard_writer()
 

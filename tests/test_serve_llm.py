@@ -264,6 +264,213 @@ def test_engine_metrics_text(llama_engine):
         assert name in text
 
 
+# ---------------------------------------------------------------------------
+# the pump's time ledger (util/tracing phases)
+# ---------------------------------------------------------------------------
+
+
+def _delta(after, before):
+    return {k: after[k] - before.get(k, 0) for k in after
+            if isinstance(after[k], (int, float))}
+
+
+def _phase_sum(delta, prefix="ph_"):
+    return sum(v for k, v in delta.items() if k.startswith(prefix))
+
+
+@pytest.fixture(scope="module")
+def ledger_engine():
+    """Wide enough that a step is milliseconds: the ledger's own
+    bookkeeping (microseconds a step) stays far inside the 2% it is held
+    to. No prefix cache: every prompt prefills."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.llama import LlamaConfig
+    from ray_tpu.serve.llm import EngineConfig, LLMEngine
+    eng = LLMEngine(
+        model="llama",
+        model_cfg=LlamaConfig(
+            vocab_size=512, max_seq_len=128, n_layer=4, n_head=8,
+            n_kv_head=2, d_model=512, dtype=jnp.float32),
+        engine_config=EngineConfig(
+            batch_buckets=(4,), prefill_buckets=(32,), prefix_cache=0),
+        seed=0)
+    eng.warmup()
+    yield eng
+    assert eng.shutdown() == 0
+
+
+def test_pump_ledger_sums_to_the_pump_wall_time(ledger_engine):
+    """Driven for a second with nothing else contending: the phases' self
+    times add up to the pump thread's wall time, and the decode and
+    prefill phases to the two counters that used to lump them."""
+    from ray_tpu.serve.llm.engine import PUMP_PHASES
+
+    eng = ledger_engine
+    eng.start()
+    try:
+        time.sleep(0.1)                         # some idle time in it too
+        before = eng.metrics()
+        assert {f"ph_{n.replace('.', '_')}_ms" for n in PUMP_PHASES} \
+            <= set(before)                      # listed before they ran
+        t0 = time.monotonic()
+        while time.monotonic() - t0 < 1.0:
+            # long answers: a finishing request wakes its caller, and the
+            # pump then waits its turn for the interpreter wherever it is
+            reqs = [eng.submit([3 + i] * 20, 40) for i in range(4)]
+            for r in reqs:
+                assert len(r.result(timeout=60)) == 40
+        eng.quiesce()
+        after = eng.metrics()
+    finally:
+        eng.stop()
+    d = _delta(after, before)
+    assert d["pump_wall_ms"] >= 1000
+    assert abs(_phase_sum(d) - d["pump_wall_ms"]) <= 0.02 * d["pump_wall_ms"]
+    assert d["decode_steps"] > 20 and d["prefill_steps"] >= 8
+    inside = d["ph_lock_wait_ms"] + d["ph_finish_ms"]
+    assert inside <= 0.02 * d["decode_ms"]
+    assert abs(_phase_sum(d, "ph_decode_") - d["decode_ms"]) \
+        <= 0.02 * d["decode_ms"]
+    # a request's span holds what lies between its prefill phases
+    prefill = _phase_sum(d, "ph_prefill_") + d["ph_llm_prefill_ms"]
+    assert abs(prefill - d["prefill_ms"]) <= 0.02 * d["prefill_ms"]
+    assert d["ph_llm_prefill_ms"] <= 0.02 * d["prefill_ms"]
+    for key in ("ph_decode_dispatch_ms", "ph_decode_device_wait_ms",
+                "ph_decode_fetch_ms", "ph_decode_kv_append_ms",
+                "ph_decode_sample_ms", "ph_prefill_dispatch_ms",
+                "ph_prefill_kv_fetch_ms", "ph_prefill_kv_write_ms",
+                "ph_pump_idle_ms", "ph_admit_ms", "ph_finish_ms"):
+        assert d[key] > 0, key
+    # metrics() times itself: calls that had finished when it was read
+    assert d["metrics_calls"] == 1 and d["metrics_ms"] > 0
+    # a stopped pump's wall time stands still; the operator's family
+    assert eng.metrics()["pump_wall_ms"] == eng.metrics()["pump_wall_ms"]
+    text = eng._metrics_text()
+    for name in PUMP_PHASES:
+        assert f'serve_llm_phase_ms_total{{phase="{name}"}}' in text
+
+
+def test_pump_lock_wait_shows_a_held_engine_lock(ledger_engine):
+    eng = ledger_engine
+    eng.start()
+    try:
+        before = eng.metrics()
+        reqs = [eng.submit([5 + i] * 20, 8) for i in range(4)]
+        for r in reqs:
+            r.result(timeout=60)
+        quiet = _delta(eng.metrics(), before)
+        assert quiet["ph_lock_wait_ms"] < 1.0   # uncontended
+        before = eng.metrics()
+        reqs = [eng.submit([9 + i] * 20, 24) for i in range(4)]
+        time.sleep(0.02)                        # the pump is stepping
+        with eng._lock:
+            time.sleep(0.05)
+        for r in reqs:
+            r.result(timeout=60)
+        held = _delta(eng.metrics(), before)
+    finally:
+        eng.stop()
+    assert 30 <= held["ph_lock_wait_ms"] < 500
+
+
+def _rt_events(log_dir):
+    """[(name, stats)] of the `rt/` host events of a profiler session."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    paths = glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    assert paths, f"no .xplane.pb under {log_dir}"
+    return [(e.name, dict(e.stats))
+            for plane in ProfileData.from_file(paths[-1]).planes
+            for line in plane.lines for e in line.events
+            if e.name.startswith("rt/")]
+
+
+def test_profiler_session_holds_the_engine_phases(ledger_engine, tmp_path):
+    """Whatever profiler session is on, the pump's phases are host events
+    of its `.xplane.pb`, and a request's prefill phases carry its id."""
+    from ray_tpu.util import request_recorder as rr
+    from ray_tpu.util import tracing
+
+    eng = ledger_engine
+    ctxs = [rr.new_context("llm") for _ in range(3)]
+    with tracing.device_trace(str(tmp_path / "trace")) as log_dir:
+        for i, ctx in enumerate(ctxs):
+            with rr.serving(ctx):
+                eng.submit([7 + i] * 20, 4)
+        direct = eng.submit([2] * 20, 4, request_id="direct-1")
+        eng.run_until_idle()
+    assert len(direct.tokens) == 4
+    events = _rt_events(log_dir)
+    names = {name for name, _ in events}
+    assert {"rt/engine_step", "rt/admit", "rt/llm.prefill",
+            "rt/prefill_dispatch", "rt/prefill_device_wait",
+            "rt/prefill_kv_fetch", "rt/prefill_kv_write",
+            "rt/prefill_sample", "rt/decode_assemble",
+            "rt/decode_dispatch", "rt/decode_device_wait",
+            "rt/decode_fetch", "rt/decode_kv_append", "rt/decode_sample",
+            "rt/finish", "rt/cache_lookup"} <= names
+    # busy iterations are numbered steps of the profiler's overview
+    steps = [st["step_num"] for name, st in events
+             if name == "rt/engine_step" and "step_num" in st]
+    assert steps == sorted(steps) and len(steps) >= 4
+    want = {ctx["req_id"] for ctx in ctxs} | {"direct-1"}
+    for phase in ("rt/llm.prefill", "rt/prefill_dispatch",
+                  "rt/prefill_device_wait", "rt/prefill_kv_fetch",
+                  "rt/prefill_kv_write", "rt/prefill_sample"):
+        got = [st.get("req_id") for name, st in events if name == phase]
+        assert set(got) == want and len(got) >= 4, (phase, got)
+
+
+def test_trace_shard_still_holds_llm_prefill_with_its_flow(ledger_engine,
+                                                          tmp_path):
+    """`RAY_TPU_TRACE=1`: the request span the handle's flow arrow ends on
+    is a phase now, under its old name and with its old attributes."""
+    from ray_tpu.util import request_recorder as rr
+    from ray_tpu.util import tracing
+    from ray_tpu.util.timeline import unified_timeline
+
+    eng = ledger_engine
+    trace_dir = str(tmp_path / "traces")
+    os.environ["RAY_TPU_TRACE"] = "1"
+    os.environ["RAY_TPU_TRACE_DIR"] = trace_dir
+    tracing.refresh()
+    tracing._reset_writer()
+    ctx = rr.new_context("llm")
+    try:
+        with tracing.span("serve.llm.request", kind="producer",
+                          attrs={"req_id": ctx["req_id"],
+                                 "flow_id": f"req:{ctx['req_id']}"}):
+            with rr.serving(ctx):
+                req = eng.submit([11] * 20, 3)
+        eng.run_until_idle()
+    finally:
+        os.environ.pop("RAY_TPU_TRACE", None)
+        os.environ.pop("RAY_TPU_TRACE_DIR", None)
+        tracing.refresh()
+        tracing._reset_writer()
+    assert len(req.tokens) == 3
+    spans = tracing.collect(trace_dir)
+    prefill = [s for s in spans if s["name"] == "llm.prefill"]
+    assert len(prefill) == 1
+    assert prefill[0]["kind"] == "consumer"
+    assert prefill[0]["attrs"] == {
+        "bucket": 32, "tokens_in": 20, "req_id": ctx["req_id"],
+        "flow_id": f"req:{ctx['req_id']}"}
+    inner = [s for s in spans if s["name"] == "prefill_dispatch"]
+    assert inner and inner[0]["parent_id"] == prefill[0]["span_id"]
+    assert inner[0]["attrs"]["req_id"] == ctx["req_id"]
+    assert any(s["name"] == "decode_dispatch" for s in spans)
+    # the arrow from the producer span still ends on it
+    events = unified_timeline(trace_dir=trace_dir, include_tasks=False)
+    flow = f"req:{ctx['req_id']}"
+    assert [e for e in events if e.get("ph") == "s" and e.get("id") == flow]
+    assert [e for e in events if e.get("ph") == "f" and e.get("id") == flow]
+
+
 def test_gpt_decode_matches_full_forward():
     """The GPT decode path (LayerNorm + learned positions + biases) is
     bit-compatible with the full forward too."""
@@ -397,6 +604,27 @@ def test_handle_timeout_s_sheds_expired(clean_deployments):
     assert shed_count() == before + 1
     # a sane deadline still dispatches
     assert handle.options(timeout_s=30.0).remote(3).result(timeout=30) == 3
+
+
+def test_llm_deployment_device_trace_through_the_handle(clean_deployments):
+    """Only the replica's process can trace its device: the handle asks it
+    to, and gets back where the `.xplane.pb` lies, the engine's phases in
+    it."""
+    from ray_tpu import serve
+
+    handle = serve.run(serve.llm.build_app(name="llm", num_replicas=1))
+    pending = handle.device_trace.remote(0.2)
+    assert len(handle.generate_once.remote([5, 9, 3], 4).result(
+        timeout=60)) == 4
+    path = pending.result(timeout=60)
+    assert os.path.isdir(path)
+    names = {name for name, _ in _rt_events(path)}
+    assert "rt/pump_idle" in names
+    m = handle.engine_metrics.remote().result(timeout=60)
+    assert m["pump_wall_ms"] > 200 and m["ph_pump_idle_ms"] > 0
+    import shutil
+
+    shutil.rmtree(path, ignore_errors=True)
 
 
 def test_serve_llm_end_to_end(clean_deployments):

@@ -16,6 +16,8 @@ import pytest
 
 from ray_tpu.util import metrics as metrics_mod
 from ray_tpu.util import step_profiler as sp
+from ray_tpu.util import tracing as _tracing
+
 
 
 @pytest.fixture(autouse=True)
@@ -249,6 +251,7 @@ def test_flow_arrows_survive_merge_across_processes(tmp_path):
     child's step record — all across pid boundaries."""
     trace_dir = str(tmp_path / "traces")
     os.environ["RAY_TPU_TRACE"] = "1"
+    _tracing.refresh()  # read once at import
     os.environ["RAY_TPU_TRACE_DIR"] = trace_dir
     from ray_tpu.util import tracing
     from ray_tpu.util.timeline import unified_timeline
@@ -294,6 +297,7 @@ def test_flow_arrows_survive_merge_across_processes(tmp_path):
             assert json.load(f) == merged
     finally:
         os.environ.pop("RAY_TPU_TRACE", None)
+        _tracing.refresh()  # read once at import
         os.environ.pop("RAY_TPU_TRACE_DIR", None)
         tracing._reset_writer()
         sp._reset_shard_writer()
@@ -304,6 +308,7 @@ def test_fork_resets_shard_writers(tmp_path):
     (the inherited parent handles are dropped by the at-fork hooks)."""
     trace_dir = str(tmp_path / "traces")
     os.environ["RAY_TPU_TRACE"] = "1"
+    _tracing.refresh()  # read once at import
     os.environ["RAY_TPU_TRACE_DIR"] = trace_dir
     from ray_tpu.util import tracing
 
@@ -337,6 +342,7 @@ def test_fork_resets_shard_writers(tmp_path):
         assert names == ["parent.span"]
     finally:
         os.environ.pop("RAY_TPU_TRACE", None)
+        _tracing.refresh()  # read once at import
         os.environ.pop("RAY_TPU_TRACE_DIR", None)
         tracing._reset_writer()
         sp._reset_shard_writer()
@@ -376,6 +382,7 @@ def test_cli_profile_prints_step_table(tmp_path, capsys):
     the step shards, offline (no cluster)."""
     trace_dir = str(tmp_path / "traces")
     os.environ["RAY_TPU_TRACE"] = "1"
+    _tracing.refresh()  # read once at import
     os.environ["RAY_TPU_TRACE_DIR"] = trace_dir
     sp._reset_shard_writer()
     try:
@@ -385,6 +392,7 @@ def test_cli_profile_prints_step_table(tmp_path, capsys):
                            flops=1e9, peak=1e12)
     finally:
         os.environ.pop("RAY_TPU_TRACE", None)
+        _tracing.refresh()  # read once at import
         os.environ.pop("RAY_TPU_TRACE_DIR", None)
         sp._reset_shard_writer()
 
@@ -404,6 +412,7 @@ def test_cli_profile_prints_step_table(tmp_path, capsys):
 def test_cli_timeline_unified_offline(tmp_path, capsys):
     trace_dir = str(tmp_path / "traces")
     os.environ["RAY_TPU_TRACE"] = "1"
+    _tracing.refresh()  # read once at import
     os.environ["RAY_TPU_TRACE_DIR"] = trace_dir
     os.environ.pop("RAY_TPU_ADDRESS", None)
     # point the CLI at an empty state file: a stale machine-global
@@ -418,6 +427,7 @@ def test_cli_timeline_unified_offline(tmp_path, capsys):
             pass
         sp.record_step(1, 3.0)
         os.environ.pop("RAY_TPU_TRACE", None)
+        _tracing.refresh()  # read once at import
         os.environ.pop("RAY_TPU_TRACE_DIR", None)
 
         from ray_tpu.scripts.cli import main
@@ -431,6 +441,7 @@ def test_cli_timeline_unified_offline(tmp_path, capsys):
         assert any(e["name"] == "work" for e in events)
     finally:
         os.environ.pop("RAY_TPU_TRACE", None)
+        _tracing.refresh()  # read once at import
         os.environ.pop("RAY_TPU_TRACE_DIR", None)
         os.environ.pop("RAY_TPU_CLI_STATE_FILE", None)
         tracing._reset_writer()

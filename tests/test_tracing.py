@@ -9,10 +9,13 @@ import os
 
 import pytest
 
+from ray_tpu.util import tracing as _tracing
+
 
 def test_task_and_actor_spans(tmp_path):
     trace_dir = str(tmp_path / "traces")
     os.environ["RAY_TPU_TRACE"] = "1"
+    _tracing.refresh()  # read once at import
     os.environ["RAY_TPU_TRACE_DIR"] = trace_dir
     import ray_tpu
     from ray_tpu.util import tracing
@@ -39,6 +42,7 @@ def test_task_and_actor_spans(tmp_path):
     finally:
         ray_tpu.shutdown()
         os.environ.pop("RAY_TPU_TRACE", None)
+        _tracing.refresh()  # read once at import
         os.environ.pop("RAY_TPU_TRACE_DIR", None)
 
     spans = tracing.collect(trace_dir)
@@ -75,6 +79,7 @@ def test_tracing_disabled_is_free(tmp_path):
     from ray_tpu.util import tracing
 
     os.environ.pop("RAY_TPU_TRACE", None)
+    _tracing.refresh()  # read once at import
     os.environ["RAY_TPU_TRACE_DIR"] = str(tmp_path / "none")
     try:
         with tracing.span("x") as s:
@@ -83,3 +88,211 @@ def test_tracing_disabled_is_free(tmp_path):
         assert not os.path.exists(str(tmp_path / "none"))
     finally:
         os.environ.pop("RAY_TPU_TRACE_DIR", None)
+
+
+# ---------------------------------------------------------------------------
+# phases: the self-time ledger, the profiler annotation, the JSONL span
+# ---------------------------------------------------------------------------
+
+def test_nested_phases_self_times_sum_to_the_outer_duration():
+    import time
+
+    from ray_tpu.util import tracing
+
+    table = tracing.PhaseTable(("outer", "inner", "leaf", "never"))
+    with table.phase("outer") as outer:
+        time.sleep(0.01)
+        with table.phase("inner") as inner:
+            time.sleep(0.01)
+            with table.phase("leaf") as leaf:
+                time.sleep(0.01)
+        with table.phase("inner"):
+            time.sleep(0.005)
+    ns = table.snapshot_ns()
+    # every nanosecond of the outer phase is one name's self time
+    assert sum(ns.values()) == outer.ns
+    assert ns["never"] == 0 and table.count("never") == 0
+    assert ns["leaf"] == leaf.ns >= 10_000_000
+    assert inner.ns >= leaf.ns + 10_000_000    # a phase's `ns` is whole
+    assert 10_000_000 <= ns["outer"] < outer.ns - inner.ns
+    assert table.count("inner") == 2 and table.count("outer") == 1
+    assert table.ms("leaf") == ns["leaf"] / 1e6
+    assert not table.in_phase()
+    table.clear()
+    assert sum(table.snapshot_ns().values()) == 0
+
+
+def test_total_ns_is_the_wall_time_of_a_stretch_and_reads_are_live():
+    import time
+
+    from ray_tpu.util import tracing
+
+    table = tracing.PhaseTable(("base", "work"))
+    with table.phase("base"):
+        mark = table.total_ns()
+        t0 = time.perf_counter_ns()
+        with table.phase("work"):
+            time.sleep(0.01)
+        time.sleep(0.005)
+        stretch = table.total_ns() - mark
+        wall = time.perf_counter_ns() - t0
+        assert abs(stretch - wall) < 1_000_000
+        # another thread's reading includes the open phase's remainder
+        seen = {}
+        import threading
+
+        time.sleep(0.01)
+        t = threading.Thread(
+            target=lambda: seen.update(table.snapshot_ns()))
+        t.start()
+        t.join(timeout=10)
+        assert seen["base"] >= 14_000_000
+        assert seen["base"] > table.ms("base") * 1e6  # charged + live
+
+
+def test_timed_lock_charges_only_contended_waits_of_phase_threads():
+    import threading
+    import time
+
+    from ray_tpu.util import tracing
+
+    table = tracing.PhaseTable(("run", "lock_wait"))
+    lock = tracing.TimedLock(table, threading.Lock())
+    with table.phase("run"):
+        for _ in range(100):
+            with lock:
+                pass
+    assert table.count("lock_wait") == 0       # uncontended: no clock
+    # a thread with no phase open is never charged
+    holder_has_it = threading.Event()
+    release = threading.Event()
+
+    def hold():
+        with lock:
+            holder_has_it.set()
+            release.wait(10)
+
+    t = threading.Thread(target=hold)
+    t.start()
+    assert holder_has_it.wait(10)
+    assert not lock.acquire(False)
+    threading.Timer(0.05, release.set).start()
+    with table.phase("run"):
+        with lock:
+            pass
+    t.join(timeout=10)
+    assert not t.is_alive()
+    assert table.count("lock_wait") == 1
+    assert 30 <= table.ms("lock_wait") < 2000
+    assert not lock.locked()
+
+
+def test_phase_writes_the_jsonl_span_only_when_tracing_is_on(tmp_path):
+    from ray_tpu.util import tracing
+
+    table = tracing.PhaseTable()
+    trace_dir = str(tmp_path / "traces")
+    os.environ["RAY_TPU_TRACE_DIR"] = trace_dir
+    tracing._reset_writer()
+    try:
+        with table.phase("quiet"):
+            pass
+        assert not os.path.exists(trace_dir)
+        os.environ["RAY_TPU_TRACE"] = "1"
+        assert not tracing.enabled()           # cached until refresh()
+        tracing.refresh()
+        assert tracing.enabled()
+        with table.phase("llm.outer", req_id="r-1", kind="consumer",
+                         attrs={"flow_id": "req:r-1", "bucket": 8}):
+            with table.phase("inner"):
+                pass
+    finally:
+        os.environ.pop("RAY_TPU_TRACE", None)
+        os.environ.pop("RAY_TPU_TRACE_DIR", None)
+        tracing.refresh()
+        tracing._reset_writer()
+    spans = {s["name"]: s for s in tracing.collect(trace_dir)}
+    assert set(spans) == {"llm.outer", "inner"}
+    outer, inner = spans["llm.outer"], spans["inner"]
+    assert outer["kind"] == "consumer"
+    assert outer["attrs"] == {"flow_id": "req:r-1", "bucket": 8,
+                              "req_id": "r-1"}
+    # nested in it, and carrying the request's id
+    assert inner["parent_id"] == outer["span_id"]
+    assert inner["attrs"]["req_id"] == "r-1"
+    assert table.count("quiet") == 1 and table.count("inner") == 1
+
+
+def test_tracing_and_a_phase_leave_jax_out_of_the_process():
+    """The benchmark's driver and the daemons import this module and must
+    not take the chip: jax is used only where it is already imported."""
+    import subprocess
+    import sys
+
+    code = (
+        "import sys\n"
+        "from ray_tpu.util import tracing\n"
+        "t = tracing.PhaseTable(('a',))\n"
+        "with t.phase('a', step=1, req_id='r'):\n"
+        "    pass\n"
+        "assert t.count('a') == 1\n"
+        "assert 'jax' not in sys.modules, 'jax imported'\n")
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    env = dict(os.environ, PYTHONPATH=root)
+    out = subprocess.run([sys.executable, "-c", code], env=env, cwd=root,
+                         capture_output=True, text=True, timeout=120)
+    assert out.returncode == 0, out.stderr
+
+
+def _host_events(log_dir):
+    """{event name: [its stats as a dict]} of a profiler session's host
+    planes."""
+    import glob
+
+    from jax.profiler import ProfileData
+
+    paths = glob.glob(os.path.join(log_dir, "plugins", "profile", "*",
+                                   "*.xplane.pb"))
+    assert paths, f"no .xplane.pb under {log_dir}"
+    events = {}
+    for plane in ProfileData.from_file(paths[-1]).planes:
+        for line in plane.lines:
+            for e in line.events:
+                if e.name.startswith("rt/"):
+                    events.setdefault(e.name, []).append(dict(e.stats))
+    return events
+
+
+def test_device_trace_holds_the_phases_and_the_train_step(tmp_path):
+    """Under a profiler session the phases are host events of the
+    `.xplane.pb`: `rt/train_step` as a numbered step with its three phases
+    and the executable cache's lookup inside."""
+    import jax.numpy as jnp
+
+    from ray_tpu.train import TrainStepRunner
+    from ray_tpu.util import step_profiler, tracing
+
+    def step(w, batch):
+        return w + batch.sum(), w.sum()
+
+    runner = TrainStepRunner(step, donate_carry=False)
+    w = jnp.zeros(4)
+    w, _ = runner.run(w, jnp.ones(4))           # compiles outside the trace
+    n0 = step_profiler.recent()[-1]["step"]
+    with tracing.device_trace(str(tmp_path / "trace")) as log_dir:
+        for _ in range(3):
+            w, _ = runner.run(w, jnp.ones(4))
+    events = _host_events(log_dir)
+    assert len(events["rt/train_step"]) == 3
+    assert sorted(e["step_num"] for e in events["rt/train_step"]) == \
+        [n0 + 1, n0 + 2, n0 + 3]
+    for name in ("rt/train_data_wait", "rt/train_dispatch",
+                 "rt/train_device_wait", "rt/cache_lookup"):
+        assert len(events[name]) == 3, (name, sorted(events))
+    # what went to the flight recorder is what the phases measured
+    row = step_profiler.recent()[-1]
+    assert row["host_dispatch_ms"] > 0 and row["device_execute_ms"] >= 0
+    assert runner.phases.count("train_step") == 4
+    total = sum(runner.phases.snapshot_ns().values()) / 1e6
+    rows = step_profiler.recent()[-4:]
+    assert abs(total - sum(r["total_ms"] for r in rows)) < 0.05 * total + 0.5
