@@ -404,8 +404,8 @@ def serve_phase(size: dict, seed: int, replicas: int = 1) -> dict:
         "max_new_tokens": max_new, "stream_of_prompt_0": again,
         "ready_s": round(ready_s, 2),
         "warmup_s": [round(i["warmup_s"], 2) for i in infos.values()],
-        # ROADMAP S2: the whole arena crosses the host link on each step
-        "kv_arena_bytes_per_decode_step": first.get("kv_arena_bytes"),
+        # the arena is device memory: a step reads and writes it in place
+        "kv_arena_bytes_on_device": first.get("kv_arena_bytes"),
         "cache_stats": [i["cache_stats"] for i in after.values()],
         "failures": failures,
     }

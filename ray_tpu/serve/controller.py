@@ -78,10 +78,8 @@ class ServeController:
         # from another process tears the whole instance down
         self._proxies: List[Any] = []
         # last-known get_metrics payload per replica (keyed by actor
-        # identity): a replica that dies between polls is reclaimed from
-        # this cache — e.g. its serve.llm KV arena (kv_arena_id) is
-        # force-deleted from the node's shm store so the dead process's
-        # pages don't leak until eviction pressure
+        # identity): a replica in it has answered a poll, so it is past
+        # its start-up
         self._replica_metrics: Dict[int, Dict[str, Any]] = {}
         # spawn timestamps (actor identity -> monotonic): a replica that
         # has never answered a poll gets a startup grace window
@@ -89,8 +87,6 @@ class ServeController:
         # counts as death — long warmups (serve.llm AOT compiles) must
         # not be reaped mid-__init__
         self._replica_spawned: Dict[int, float] = {}
-        self._reclaimed_arenas: List[str] = []
-        self._arenas_reclaimed_total = 0
         # dispatch plane v2: per-deployment native segments (created on
         # first sync when RAY_TPU_NATIVE_DISPATCH=1), router-wake FIFOs
         # (posted on EVERY version bump, native or not), and the set of
@@ -268,7 +264,6 @@ class ServeController:
                             dead.append(r)
                 for r in dead:
                     self._kill(r)
-                    self._reclaim_dead_replica(r)
                 with self._lock:
                     self._replica_metrics.update(polled)
                     for r in dead:
@@ -294,7 +289,7 @@ class ServeController:
         by replica identity); total_load folds deployment-reported queue
         depth (serve.llm engine backlog) into the ongoing count so
         autoscaling sees queued work, not just dispatched work. `dead`
-        holds replicas whose actor is GONE (kill + reclaim immediately);
+        holds replicas whose actor is GONE (kill immediately);
         `slow` holds replicas that exist but didn't answer in time — the
         caller decides whether that's a hung replica (kill) or one still
         warming up (a serve.llm replica compiling its decode/verify fns
@@ -318,33 +313,6 @@ class ServeController:
             except Exception:
                 slow.append(r)
         return alive, dead, slow, total_load, polled
-
-    def _reclaim_dead_replica(self, replica: Any) -> None:
-        """Release node-side resources a dead replica can no longer
-        release itself, using its last polled metrics. Today: the
-        serve.llm KV arena (the dead process never dropped its creator
-        reference on the shm allocation). Single-node semantics — the
-        arena lives in this node's store; a multi-node controller would
-        route the delete through the owning raylet."""
-        with self._lock:
-            m = self._replica_metrics.pop(id(replica), None)
-        arena = (m or {}).get("kv_arena_id")
-        if not arena:
-            return
-        try:
-            from ray_tpu.serve.llm.kv_cache import reclaim_arena
-            if reclaim_arena(arena):
-                logger.warning(
-                    "reclaimed KV arena %s from dead replica", arena)
-                with self._lock:
-                    self._reclaimed_arenas.append(arena)
-                    self._arenas_reclaimed_total += 1
-        except Exception:
-            pass
-
-    def get_reclaimed_arenas(self) -> List[str]:
-        with self._lock:
-            return list(self._reclaimed_arenas)
 
     # -- dispatch plane v2 -------------------------------------------------
 
@@ -438,13 +406,10 @@ class ServeController:
 
     def _metrics_text(self) -> str:
         with self._lock:
-            reclaimed = self._arenas_reclaimed_total
             deployments = len(self._deployments)
             draining = len(self._draining)
             rings = dict(self._rings)
         out = "\n".join([
-            "# TYPE serve_llm_arenas_reclaimed_total counter",
-            f"serve_llm_arenas_reclaimed_total {reclaimed}",
             "# TYPE serve_controller_deployments gauge",
             f"serve_controller_deployments {deployments}",
             "# TYPE serve_controller_draining_replicas gauge",

@@ -1,6 +1,6 @@
 """serve.llm — decode-optimized LLM inference plane.
 
-Paged shm KV-cache (`kv_cache.py`) + continuous-batching engine on the
+Paged KV-cache on the device (`kv_cache.py`) + continuous-batching engine on the
 AOT compile cache (`engine.py`) + a Serve deployment streaming tokens
 over `handle_request_streaming` (`deployment.py`). See the README
 "Inference plane" section for the engine loop and env knobs.
@@ -11,7 +11,6 @@ from ray_tpu.serve.llm.kv_cache import (
     OutOfPagesError,
     PagedKVCache,
     PrefixCache,
-    reclaim_arena,
 )
 from ray_tpu.serve.llm.engine import (
     EngineConfig,
@@ -32,5 +31,4 @@ __all__ = [
     "Request",
     "RequestRejected",
     "build_app",
-    "reclaim_arena",
 ]

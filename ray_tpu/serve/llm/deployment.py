@@ -1,7 +1,7 @@
 """serve.llm.LLMDeployment — an LLMEngine behind the Serve stack.
 
-Each replica hosts one engine (pump thread + paged shm KV arena) and
-streams tokens over the existing `handle_request_streaming` path:
+Each replica hosts one engine (pump thread + paged KV arena on its
+device) and streams tokens over the existing `handle_request_streaming` path:
 
     app = serve.llm.build_app(name="llm", num_replicas=2)
     handle = serve.run(app)
@@ -9,9 +9,9 @@ streams tokens over the existing `handle_request_streaming` path:
         ...
 
 The replica exports `get_autoscaling_metrics` so the controller's poll
-sees queue depth + KV-page occupancy (autoscaling pressure) and the KV
-arena id (dead-replica reclaim); the engine's own counters join the
-node's /metrics scrape via the registry callback it registers.
+sees queue depth + KV-page occupancy (autoscaling pressure); the
+engine's own counters join the node's /metrics scrape via the registry
+callback it registers.
 """
 
 from __future__ import annotations
@@ -55,8 +55,6 @@ class LLMDeployment:
             draft_cfg = _Cfg(**draft_config)
         from ray_tpu._private.object_ref import get_core_worker
 
-        # the worker's shm store attachment, so KV pages live on the
-        # object plane (outside a cluster: plain numpy arena)
         cw = get_core_worker()
         self._tpu_chips = []
         if cw is not None:
@@ -65,7 +63,6 @@ class LLMDeployment:
         self.engine = LLMEngine(
             model=model, model_cfg=model_cfg,
             engine_config=EngineConfig(**(engine_config or {})),
-            store=cw.store if cw is not None else None,
             seed=seed, draft_cfg=draft_cfg)
         t0 = time.perf_counter()
         self.engine.warmup()
@@ -130,7 +127,6 @@ class LLMDeployment:
             "kv_pages_live": float(m["kv_pages_live"]),
             "kv_pages_cached": float(m.get("kv_pages_cached", 0)),
             "kv_pages_total": float(m["kv_pages_total"]),
-            "kv_arena_id": m["kv_arena_id"],
         }
         # perf-plane rollups for the dashboard /api/serve_llm panel:
         # prefix-cache hit rate and mean speculative accept length
@@ -151,7 +147,7 @@ class LLMDeployment:
     def replica_info(self) -> Dict[str, Any]:
         """Where this replica computes, seen from inside its process:
         jax's device, the chips the raylet granted, the cold-start cost,
-        the KV arena (shipped whole on every decode step) and the
+        the size of the KV arena (resident on the device) and the
         executable-cache counters (retraces must stay 0)."""
         import jax
 

@@ -16,10 +16,12 @@ per executable, so steady-state serving can never silently retrace
 (`parallel.cache_stats()` proves it; the bench asserts retraces == 0
 across the run).
 
-The KV plane is a `PagedKVCache` (see kv_cache.py): decode dispatch
-hands the kernel the whole arena + per-sequence page-table rows; the
-host appends each new token's K/V into the sequence's tail page
-in place (a [n_layer, n_kv_head, head_dim] write per token).
+The KV plane is a `PagedKVCache` (see kv_cache.py) whose pages are device
+arrays. Every compiled program takes the arena donated, reads it through
+per-sequence page-table rows, scatters its new K/V rows into their pages
+at coordinates the host computed, and returns the arena, which the engine
+stores back for the next call: no K or V crosses the host link. The host
+keeps the allocator, the page tables and the positions.
 
 Greedy (argmax) sampling keeps generation deterministic — the property
 the continuous-batching equivalence test and the mid-stream chaos
@@ -39,7 +41,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from ray_tpu.serve.llm.kv_cache import (OutOfPagesError, PagedKVCache,
-                                        PrefixCache)
+                                        PrefixCache, scatter_rows)
 from ray_tpu.util import metrics as _metrics
 from ray_tpu.util import request_recorder as _rr
 from ray_tpu.util import step_profiler as _sp
@@ -118,7 +120,11 @@ class RequestRejected(RuntimeError):
 # and stop() every nanosecond of the thread is one of these names' self
 # time. `pump_loop` and `engine_step` are the loop's and step()'s own
 # bookkeeping; `llm.prefill*` what lies between the phases of one request's
-# prefill (the spans a request's flow arrow ends on).
+# prefill (the spans a request's flow arrow ends on). Nothing enters
+# `prefill_kv_fetch` since the arena lives on the device, and
+# `prefill_kv_write` / `decode_kv_append` hold the host's share of a write
+# (the rows' arena coordinates, positions, the prefix cache); the names stay
+# because the benchmark's metric files read them.
 PUMP_PHASES = (
     "pump_loop", "pump_idle", "intake", "lock_wait", "engine_step", "admit",
     "llm.prefill", "llm.prefill_chunk",
@@ -270,9 +276,8 @@ class LLMEngine:
     `model` selects the decode path ("llama" | "gpt"); `model_cfg`
     defaults to the family's tiny config in float32 (the 1-core build
     box target — a real deployment passes its own config + params).
-    `store=None` keeps the KV arena in process-local numpy; passing the
-    node's shm ObjectStore puts the pages on the object plane where a
-    controller can reclaim them if this replica dies.
+    `store` is accepted and unused: the KV arena is device memory of
+    this process (it once could live in the node's shm ObjectStore).
     """
 
     def __init__(self, model: str = "llama", model_cfg=None, params=None,
@@ -324,28 +329,27 @@ class LLMEngine:
             cfg.num_pages, self.model_cfg.n_layer, cfg.block_size,
             n_kv_head, head_dim,
             dtype=jnp.dtype(self.model_cfg.dtype),
-            store=store,
             lock=_tracing.TimedLock(self._phases, threading.Lock()))
         self.prefix = PrefixCache(self.kv) if cfg.prefix_cache else None
 
         # one compiled_step wrapper per bucket: each sees exactly one
         # abstract signature, so on_retrace="error" turns any shape
-        # drift in steady-state serving into a loud failure
-        self._prefill_fns = {
-            s: compiled_step(self._make_prefill_fn(s),
-                             on_retrace="error")
-            for s in cfg.prefill_buckets}
-        self._decode_fns = {
-            b: compiled_step(self._make_decode_fn(b),
-                             on_retrace="error")
-            for b in cfg.batch_buckets}
+        # drift in steady-state serving into a loud failure. Every one
+        # takes the arena as arguments 3 and 4, donated.
+        def program(fn):
+            return compiled_step(fn, donate_argnums=(3, 4),
+                                 on_retrace="error")
+
+        self._prefill_fns = {s: program(self._make_prefill_fn(s))
+                             for s in cfg.prefill_buckets}
+        self._decode_fns = {b: program(self._make_decode_fn(b))
+                            for b in cfg.batch_buckets}
         # one chunk executable (B=1, C=_chunk_size) covers both chunked
         # prefill windows and prefix-cache-hit suffixes: every window
         # pads to the same width, so a chunk is a bucket by construction
         self._chunk_size = cfg.prefill_chunk or max(cfg.prefill_buckets)
-        self._chunk_fn = compiled_step(
-            self._make_chunk_fn(self._chunk_size, "chunk"),
-            on_retrace="error")
+        self._chunk_fn = program(
+            self._make_chunk_fn(f"llm_chunk_c{self._chunk_size}"))
 
         # speculative decoding: the draft model defaults to the target
         # itself (self-draft — the 1-core build box's determinism rig);
@@ -373,8 +377,7 @@ class LLMEngine:
             d_hd = self.draft_cfg.d_model // self.draft_cfg.n_head
             # the draft frontier can run up to K tokens past the target
             # (a fully-accepted round), so its per-seq reservation is
-            # K tokens wider; the draft arena is never on the object
-            # plane — it is reconstructible state, not survivor truth
+            # K tokens wider
             self.max_pages_per_seq_d = -(-(self.model_cfg.max_seq_len
                                            + cfg.spec_k)
                                          // cfg.block_size)
@@ -388,22 +391,17 @@ class LLMEngine:
             # accept length varies per round but the window never does,
             # so accept-length variation can't retrace by construction
             self._verify_fns = {
-                b: compiled_step(
-                    self._make_verify_fn(b, cfg.spec_k + 1),
-                    on_retrace="error")
+                b: program(self._make_chunk_fn(
+                    f"llm_verify_b{b}_c{cfg.spec_k + 1}"))
                 for b in cfg.batch_buckets}
             self._d_decode_fns = {
-                b: compiled_step(self._make_decode_fn(b, draft=True),
-                                 on_retrace="error")
+                b: program(self._make_decode_fn(b, draft=True))
                 for b in cfg.batch_buckets}
             self._d_prefill_fns = {
-                s: compiled_step(self._make_prefill_fn(s, draft=True),
-                                 on_retrace="error")
+                s: program(self._make_prefill_fn(s, draft=True))
                 for s in cfg.prefill_buckets}
-            self._d_chunk_fn = compiled_step(
-                self._make_chunk_fn(self._chunk_size, "draft_chunk",
-                                    draft=True),
-                on_retrace="error")
+            self._d_chunk_fn = program(self._make_chunk_fn(
+                f"llm_draft_chunk_c{self._chunk_size}", draft=True))
 
         self._waiting: List[Request] = []
         self._prefilling: List[_Sequence] = []
@@ -428,6 +426,10 @@ class LLMEngine:
             "decode_steps": 0, "prefill_ms": 0.0, "decode_ms": 0.0,
             "chunk_steps": 0, "spec_rounds": 0,
             "spec_proposed": 0, "spec_accepted": 0,
+            # what crosses the host link: bytes of the host arrays handed
+            # to a prefill-shaped / decode-shaped call and of the outputs
+            # fetched to numpy (no K or V: token ids, tables, logits)
+            "prefill_link_bytes": 0, "decode_link_bytes": 0,
         }
         # per-bucket compiled_step dispatch counts: (kind, bucket) ->
         # calls. Every entry maps 1:1 onto one AOT executable, so the
@@ -443,12 +445,19 @@ class LLMEngine:
 
     # -- compiled kernels -------------------------------------------------
 
+    # Each program is the model's step, then `scatter_rows` of the step's
+    # new K/V into the donated arena, which it returns after the logits.
+
     def _make_prefill_fn(self, bucket: int, draft: bool = False):
         mod = self._mod
         cfg = self.draft_cfg if draft else self.model_cfg
 
-        def fn(variables, tokens, true_len):
-            return mod.prefill_step(variables, cfg, tokens, true_len)
+        def fn(variables, tokens, true_len, k_pages, v_pages, w_page,
+               w_off):
+            logits, k, v = mod.prefill_step(variables, cfg, tokens,
+                                            true_len)
+            return (logits,) + scatter_rows(k_pages, v_pages, k[0], v[0],
+                                            w_page, w_off)
 
         fn.__name__ = f"llm_{'draft_' if draft else ''}prefill_s{bucket}"
         return fn
@@ -458,32 +467,32 @@ class LLMEngine:
         cfg = self.draft_cfg if draft else self.model_cfg
 
         def fn(variables, tokens, positions, k_pages, v_pages,
-               page_table):
-            return mod.decode_step(variables, cfg, tokens, positions,
-                                   k_pages, v_pages, page_table)
+               page_table, w_page, w_off):
+            logits, k, v = mod.decode_step(variables, cfg, tokens,
+                                           positions, k_pages, v_pages,
+                                           page_table)
+            return (logits,) + scatter_rows(k_pages, v_pages, k, v,
+                                            w_page, w_off)
 
         fn.__name__ = f"llm_{'draft_' if draft else ''}decode_b{batch}"
         return fn
 
-    def _make_chunk_fn(self, width: int, tag: str, draft: bool = False):
+    def _make_chunk_fn(self, name: str, draft: bool = False):
+        """A window of C tokens a lane (chunked prefill, a prefix-cache
+        suffix, speculative verify): `w_page` / `w_off` are [B, C]."""
         mod = self._mod
         cfg = self.draft_cfg if draft else self.model_cfg
 
-        def fn(variables, tokens, start, k_pages, v_pages, page_table):
-            return mod.chunk_step(variables, cfg, tokens, start,
-                                  k_pages, v_pages, page_table)
+        def fn(variables, tokens, start, k_pages, v_pages, page_table,
+               w_page, w_off):
+            logits, k, v = mod.chunk_step(variables, cfg, tokens, start,
+                                          k_pages, v_pages, page_table)
+            rows = (-1,) + k.shape[2:]
+            return (logits,) + scatter_rows(
+                k_pages, v_pages, k.reshape(rows), v.reshape(rows),
+                w_page.reshape(-1), w_off.reshape(-1))
 
-        fn.__name__ = f"llm_{tag}_c{width}"
-        return fn
-
-    def _make_verify_fn(self, batch: int, width: int):
-        mod, cfg = self._mod, self.model_cfg
-
-        def fn(variables, tokens, start, k_pages, v_pages, page_table):
-            return mod.chunk_step(variables, cfg, tokens, start,
-                                  k_pages, v_pages, page_table)
-
-        fn.__name__ = f"llm_verify_b{batch}_c{width}"
+        fn.__name__ = name
         return fn
 
     def _note_call(self, kind: str, bucket: int):
@@ -495,44 +504,44 @@ class LLMEngine:
 
     def warmup(self):
         """Compile every bucket up front so steady state is all cache
-        hits (the bench snapshots `cache_stats()` after this). All call
-        sites feed numpy host arrays — the cache keys on leaf avals
-        including sharding, so mixing numpy and device arrays for the
-        same bucket would read as a retrace."""
-        for s, fn in self._prefill_fns.items():
-            fn(self.params, np.zeros((1, s), np.int32),
-               np.ones((1,), np.int32))
-        for b, fn in self._decode_fns.items():
-            fn(self.params,
-               np.zeros(b, np.int32), np.zeros(b, np.int32),
-               self.kv.k_pages, self.kv.v_pages,
-               np.zeros((b, self.max_pages_per_seq), np.int32))
-        self._chunk_fn(
-            self.params, np.zeros((1, self._chunk_size), np.int32),
-            np.zeros((1,), np.int32), self.kv.k_pages, self.kv.v_pages,
-            np.zeros((1, self.max_pages_per_seq), np.int32))
+        hits (the bench snapshots `cache_stats()` after this). The calls
+        have the serving path's abstract signature (the cache keys on
+        leaf avals including placement: numpy for what the host makes,
+        the device arena donated) and write nothing: every row's page id
+        is the dropped one."""
+        self._warm(self.kv, self.params, self.max_pages_per_seq,
+                   self._prefill_fns, self._decode_fns, self._chunk_fn)
         if self.kv_d is None:
             return
-        K = self.config.spec_k
-        for s, fn in self._d_prefill_fns.items():
-            fn(self.draft_params, np.zeros((1, s), np.int32),
-               np.ones((1,), np.int32))
-        for b, fn in self._d_decode_fns.items():
-            fn(self.draft_params,
-               np.zeros(b, np.int32), np.zeros(b, np.int32),
-               self.kv_d.k_pages, self.kv_d.v_pages,
-               np.zeros((b, self.max_pages_per_seq_d), np.int32))
+        self._warm(self.kv_d, self.draft_params, self.max_pages_per_seq_d,
+                   self._d_prefill_fns, self._d_decode_fns,
+                   self._d_chunk_fn)
         for b, fn in self._verify_fns.items():
-            fn(self.params, np.zeros((b, K + 1), np.int32),
-               np.zeros((b,), np.int32), self.kv.k_pages,
-               self.kv.v_pages,
-               np.zeros((b, self.max_pages_per_seq), np.int32))
-        self._d_chunk_fn(
-            self.draft_params,
-            np.zeros((1, self._chunk_size), np.int32),
-            np.zeros((1,), np.int32), self.kv_d.k_pages,
-            self.kv_d.v_pages,
-            np.zeros((1, self.max_pages_per_seq_d), np.int32))
+            self._warm_call(self.kv, fn, (b, self.config.spec_k + 1),
+                            self.params, self.max_pages_per_seq)
+
+    def _warm(self, kv: PagedKVCache, params, table_width: int,
+              prefill_fns, decode_fns, chunk_fn):
+        for s, fn in prefill_fns.items():
+            _, kv.k_pages, kv.v_pages = fn(
+                params, np.zeros((1, s), np.int32), np.ones((1,), np.int32),
+                kv.k_pages, kv.v_pages,
+                np.full(s, kv.num_pages, np.int32), np.zeros(s, np.int32))
+        for b, fn in decode_fns.items():
+            self._warm_call(kv, fn, (b,), params, table_width)
+        self._warm_call(kv, chunk_fn, (1, self._chunk_size), params,
+                        table_width)
+
+    @staticmethod
+    def _warm_call(kv: PagedKVCache, fn, rows: Tuple[int, ...], params,
+                   table_width: int):
+        """One decode- or chunk-shaped call: tokens and write coordinates
+        are `rows`-shaped, positions and the page table one a lane."""
+        b = rows[0]
+        _, kv.k_pages, kv.v_pages = fn(
+            params, np.zeros(rows, np.int32), np.zeros(b, np.int32),
+            kv.k_pages, kv.v_pages, np.zeros((b, table_width), np.int32),
+            np.full(rows, kv.num_pages, np.int32), np.zeros(rows, np.int32))
 
     # -- submission -------------------------------------------------------
 
@@ -750,7 +759,9 @@ class LLMEngine:
     def _emit_first(self, seq: _Sequence, next_logits_row) -> int:
         """Emit the prompt's next token; on finish, release everything
         (a one-token request never reaches the running set)."""
-        tok = int(np.argmax(np.asarray(next_logits_row)))
+        row = np.asarray(next_logits_row)
+        self._count_link("prefill_link_bytes", row)
+        tok = int(np.argmax(row))
         seq.req._emit(tok)
         if self._seq_finished(seq, tok):
             self._finish(seq)
@@ -769,24 +780,24 @@ class LLMEngine:
         return self._phases.phase(name, req_id=req_id, kind="consumer",
                                   attrs=attrs)
 
-    def _prefill_forward(self, fn, args, kv: PagedKVCache,
-                         pages: List[int], n: int, start: int = 0,
-                         rows: Optional[int] = None):
+    def _count_link(self, counter: str, *arrays) -> None:
+        """Add the bytes of the numpy arrays among `arrays` (a call's host
+        arguments, an output fetched to the host) to a link counter."""
+        n = sum(a.nbytes for a in arrays if isinstance(a, np.ndarray))
+        with self._lock:
+            self.counters[counter] += n
+
+    def _prefill_forward(self, fn, args, kv: PagedKVCache):
         """What every prefill shares (one-shot, chunk, draft): the call,
-        the wait, K and V to the host, K and V into the pages. Returns the
-        logits, still on the device."""
+        which leaves the rows' K and V in `kv`'s pages, and the wait.
+        `args` hold `kv`'s arena, donated: its successor goes back into
+        `kv`. Returns the logits, still on the device."""
         phase = self._phases.phase
         with phase("prefill_dispatch"):
-            logits, k, v = fn(*args)
-            k, v = (k[0], v[0]) if rows is None \
-                else (k[0, :rows], v[0, :rows])
+            self._count_link("prefill_link_bytes", *args)
+            logits, kv.k_pages, kv.v_pages = fn(*args)
         with phase("prefill_device_wait"):
-            # the np.asarray below would block on these anyway
-            self._block_until_ready((k, v))
-        with phase("prefill_kv_fetch"):
-            k, v = np.asarray(k), np.asarray(v)
-        with phase("prefill_kv_write"):
-            kv.write_prefill(pages, k, v, n, start=start)
+            self._block_until_ready((logits, kv.k_pages, kv.v_pages))
         return logits
 
     def _prefill_oneshot(self, seq: _Sequence) -> int:
@@ -800,10 +811,12 @@ class LLMEngine:
                 toks = np.zeros((1, bucket), np.int32)
                 toks[0, :s] = req.prompt
                 self._note_call("prefill", bucket)
+            with phase("prefill_kv_write"):
+                w_page, w_off = self.kv.write_index(seq.pages, 0, s, bucket)
             next_logits = self._prefill_forward(
                 self._prefill_fns[bucket],
-                (self.params, toks, np.asarray([s], np.int32)),
-                self.kv, seq.pages, s)
+                (self.params, toks, np.asarray([s], np.int32),
+                 self.kv.k_pages, self.kv.v_pages, w_page, w_off), self.kv)
             with phase("prefill_kv_write"):
                 seq.prefilled = s
                 seq.pos = s
@@ -834,11 +847,14 @@ class LLMEngine:
                 table = np.zeros((1, self.max_pages_per_seq), np.int32)
                 table[0, :len(seq.pages)] = seq.pages
                 self._note_call("chunk", c)
+            with phase("prefill_kv_write"):
+                w_page, w_off = self.kv.write_index(
+                    seq.pages, seq.prefilled, take, c)
             logits = self._prefill_forward(
                 self._chunk_fn,
                 (self.params, toks, np.asarray([seq.prefilled], np.int32),
-                 self.kv.k_pages, self.kv.v_pages, table),
-                self.kv, seq.pages, take, start=seq.prefilled, rows=take)
+                 self.kv.k_pages, self.kv.v_pages, table,
+                 w_page[None], w_off[None]), self.kv)
             with phase("prefill_kv_write"):
                 seq.prefilled += take
                 with self._lock:
@@ -869,10 +885,13 @@ class LLMEngine:
                 toks = np.zeros((1, bucket), np.int32)
                 toks[0, :s] = req.prompt
                 self._note_call("draft_prefill", bucket)
+                w_page, w_off = self.kv_d.write_index(
+                    seq.d_pages, 0, s, bucket)
             self._prefill_forward(
                 self._d_prefill_fns[bucket],
-                (self.draft_params, toks, np.asarray([s], np.int32)),
-                self.kv_d, seq.d_pages, s)
+                (self.draft_params, toks, np.asarray([s], np.int32),
+                 self.kv_d.k_pages, self.kv_d.v_pages, w_page, w_off),
+                self.kv_d)
             seq.d_prefilled = s
         else:
             with phase("prefill_assemble"):
@@ -884,27 +903,32 @@ class LLMEngine:
                 table = np.zeros((1, self.max_pages_per_seq_d), np.int32)
                 table[0, :len(seq.d_pages)] = seq.d_pages
                 self._note_call("draft_chunk", c)
+                w_page, w_off = self.kv_d.write_index(
+                    seq.d_pages, seq.d_prefilled, take, c)
             self._prefill_forward(
                 self._d_chunk_fn,
                 (self.draft_params, toks,
                  np.asarray([seq.d_prefilled], np.int32),
-                 self.kv_d.k_pages, self.kv_d.v_pages, table),
-                self.kv_d, seq.d_pages, take, start=seq.d_prefilled,
-                rows=take)
+                 self.kv_d.k_pages, self.kv_d.v_pages, table,
+                 w_page[None], w_off[None]), self.kv_d)
             seq.d_prefilled += take
         seq.d_pos = seq.d_prefilled
 
-    def _decode_forward(self, fn, args) -> Tuple[np.ndarray, ...]:
+    def _decode_forward(self, fn, args, kv: PagedKVCache) -> np.ndarray:
         """One decode-shaped call (decode, draft decode, verify): the
-        call, the wait, its outputs to the host."""
+        call, which leaves the written rows' K and V in `kv`'s pages, the
+        wait, the logits to the host. `args` hold `kv`'s arena, donated:
+        its successor goes back into `kv`."""
         phase = self._phases.phase
         with phase("decode_dispatch"):
-            out = fn(*args)
+            logits, kv.k_pages, kv.v_pages = fn(*args)
         with phase("decode_device_wait"):
-            # the np.asarray below would block on these anyway
-            self._block_until_ready(out)
+            # the np.asarray below would block on the logits anyway
+            self._block_until_ready((logits, kv.k_pages, kv.v_pages))
         with phase("decode_fetch"):
-            return tuple(np.asarray(x) for x in out)
+            logits = np.asarray(logits)
+            self._count_link("decode_link_bytes", logits, *args)
+            return logits
 
     def _decode_once(self) -> int:
         phase = self._phases.phase
@@ -922,14 +946,22 @@ class LLMEngine:
                 tokens[i] = seq.last_token
                 positions[i] = seq.pos
                 page_table[i, :len(seq.pages)] = seq.pages
+            with phase("decode_kv_append"):
+                # a lane beyond the running set computes on a page table
+                # of zeros and writes nowhere: its page id is dropped
+                w_page = np.full(bb, self.kv.num_pages, np.int32)
+                w_off = np.zeros(bb, np.int32)
+                for i, seq in enumerate(runs):
+                    slot, w_off[i] = divmod(seq.pos, self.kv.block_size)
+                    w_page[i] = seq.pages[slot]
             self._note_call("decode", bb)
-            logits, new_k, new_v = self._decode_forward(
+            logits = self._decode_forward(
                 self._decode_fns[bb],
                 (self.params, tokens, positions,
-                 self.kv.k_pages, self.kv.v_pages, page_table))
+                 self.kv.k_pages, self.kv.v_pages, page_table,
+                 w_page, w_off), self.kv)
             with phase("decode_kv_append"):
-                for i, seq in enumerate(runs):
-                    self.kv.append(seq.pages, seq.pos, new_k[i], new_v[i])
+                for seq in runs:
                     seq.pos += 1
             finished = []
             with phase("decode_sample"):
@@ -958,10 +990,19 @@ class LLMEngine:
         deficit (0 or 1 — a fully-accepted round leaves the draft one
         committed token behind). Lanes past their own `gap + K` budget
         idle inside the batch (their lane computes garbage that is
-        neither appended nor read), so the dispatch count varies only
+        neither written nor read), so the dispatch count varies only
         host-side — every dispatch is the same (batch-bucket) decode
         executable and the verify window is always K+1 wide: accept-
         length variation can not retrace anything.
+
+        The verify program writes the K/V of its whole window, before the
+        host knows how many proposals stand. Rule: a row is written if its
+        position lies in the sequence's own reserved pages, else dropped.
+        A rejected row's position is at or past the new `seq.pos`, so
+        every later read masks it (`valid` stops at the frontier) and the
+        next round or decode step overwrites it before the frontier
+        passes; shared prefix pages end before the prompt does and are
+        never written.
         """
         K = self.config.spec_k
         phase = self._phases.phase
@@ -980,9 +1021,12 @@ class LLMEngine:
             for i, seq in enumerate(runs):
                 d_table[i, :len(seq.d_pages)] = seq.d_pages
             n_steps = max(budget)
+            bs = self.kv_d.block_size
             for t in range(n_steps):
                 toks = np.zeros(bb, np.int32)
                 poss = np.zeros(bb, np.int32)
+                w_page = np.full(bb, self.kv_d.num_pages, np.int32)
+                w_off = np.zeros(bb, np.int32)
                 active = []
                 for i, seq in enumerate(runs):
                     if t >= budget[i]:
@@ -995,15 +1039,16 @@ class LLMEngine:
                     else:
                         toks[i] = proposals[i][idx - len(full[i])]
                     poss[i] = idx
+                    slot, w_off[i] = divmod(idx, bs)
+                    w_page[i] = seq.d_pages[slot]
                 self._note_call("draft_decode", bb)
-                d_logits, d_k, d_v = self._decode_forward(
+                d_logits = self._decode_forward(
                     self._d_decode_fns[bb],
                     (self.draft_params, toks, poss,
-                     self.kv_d.k_pages, self.kv_d.v_pages, d_table))
+                     self.kv_d.k_pages, self.kv_d.v_pages, d_table,
+                     w_page, w_off), self.kv_d)
                 with phase("decode_kv_append"):
                     for i in active:
-                        self.kv_d.append(runs[i].d_pages, cur[i],
-                                         d_k[i], d_v[i])
                         cur[i] += 1
                 with phase("decode_sample"):
                     for i in active:
@@ -1015,27 +1060,34 @@ class LLMEngine:
             v_toks = np.zeros((bb, K + 1), np.int32)
             v_start = np.zeros(bb, np.int32)
             v_table = np.zeros((bb, self.max_pages_per_seq), np.int32)
+            v_page = np.full((bb, K + 1), self.kv.num_pages, np.int32)
+            v_off = np.zeros((bb, K + 1), np.int32)
             for i, seq in enumerate(runs):
                 v_toks[i, 0] = seq.last_token
                 v_toks[i, 1:] = proposals[i][:K]
                 v_start[i] = seq.pos
                 v_table[i, :len(seq.pages)] = seq.pages
+                with phase("decode_kv_append"):
+                    v_page[i], v_off[i] = self.kv.write_index(
+                        seq.pages, seq.pos, K + 1)
             self._note_call("verify", bb)
-            logits, new_k, new_v = self._decode_forward(
+            logits = self._decode_forward(
                 self._verify_fns[bb],
                 (self.params, v_toks, v_start,
-                 self.kv.k_pages, self.kv.v_pages, v_table))
+                 self.kv.k_pages, self.kv.v_pages, v_table,
+                 v_page, v_off), self.kv)
             with phase("decode_sample"):
                 tokens_out, finished = self._spec_accept(
-                    runs, proposals, logits, new_k, new_v)
+                    runs, proposals, logits)
             for seq in finished:
                 self._finish(seq)
             return tokens_out
 
-    def _spec_accept(self, runs, proposals, logits, new_k, new_v):
+    def _spec_accept(self, runs, proposals, logits):
         """The accept loop of a speculative round: emit each lane's
-        accepted tokens and commit their K/V. Returns the tokens emitted
-        and the sequences that finished."""
+        accepted tokens and move its frontiers past them (their K/V are
+        in the pages already). Returns the tokens emitted and the
+        sequences that finished."""
         K = self.config.spec_k
         tokens_out = 0
         finished = []
@@ -1061,14 +1113,10 @@ class LLMEngine:
             if fin:
                 finished.append(seq)
                 continue
-            # commit KV: verify rows 0..emitted-1 hold exactly the
-            # committed tokens' K/V ([last, d_1..d_a] == [last,
-            # g_0..g_{a-1}]); the draft cache is correct through
-            # pos + min(a+1, K) (it never saw g_a when a == K)
-            with self._phases.phase("decode_kv_append"):
-                self.kv.write_prefill(seq.pages, new_k[i, :emitted],
-                                      new_v[i, :emitted], emitted,
-                                      start=seq.pos)
+            # verify rows 0..emitted-1 hold exactly the committed
+            # tokens' K/V ([last, d_1..d_a] == [last, g_0..g_{a-1}]);
+            # the draft cache is correct through pos + min(a+1, K) (it
+            # never saw g_a when a == K)
             seq.d_pos = seq.pos + min(a + 1, K)
             seq.pos += emitted
         with self._lock:
@@ -1348,7 +1396,6 @@ class LLMEngine:
                 kv_pages_cached=self.kv.cached_pages,
                 kv_pages_total=self.kv.num_pages,
                 kv_page_utilization=self.kv.utilization(),
-                kv_arena_id=self.kv.arena_id_hex,
                 model=self.model_name,
                 spec_k=self.config.spec_k,
                 compiled_step_calls={

@@ -1,13 +1,18 @@
-"""Paged KV-cache backed by the shm object plane.
+"""Paged KV-cache whose pages live on the device.
 
 vLLM-style paged attention (reference: vllm `block_manager.py` /
-`PagedAttention`), mapped onto this repo's primitives: the backing arena
-is ONE shm-store allocation (`ObjectStore.create_buffer`) sliced into
-fixed-size pages of shape [n_layer, block_size, n_kv_head, head_dim] per
-K and V. The engine hands the kernel the whole arena plus per-sequence
-page tables (gather indices) — growing a sequence never moves bytes,
-only appends a page id, so decode dispatch is copy-free on the host
-side.
+`PagedAttention`): the arena is two device arrays, K and V, of shape
+[num_pages, n_layer, block_size, n_kv_head, head_dim]. The engine's
+compiled programs take both as donated arguments, gather a sequence's
+history through its page-table row, scatter the new K/V rows into their
+pages (`scatter_rows`) and hand the arena back: it is updated in place
+and never crosses the host link. Growing a sequence never moves bytes,
+only appends a page id.
+
+What stays on the host is the control plane: the free list, the holders
+of every page, the prefix cache, and the arena coordinates of the rows a
+program is to write (`write_index`: a page id and an offset per row, an
+out-of-range page id for a row that must not be written).
 
 Pages are REFCOUNTED: a page can be held by several sequences at once
 (copy-on-write shared-prefix reuse — see `PrefixCache`), and it returns
@@ -24,15 +29,13 @@ page's tail is still being appended to), so a shared page is immutable
 by construction — aliasing is a page-table row edit plus a refcount,
 never a byte copy, and no writer ever touches a shared page.
 
-On a dead replica the arena is reclaimed store-side by id
-(`reclaim_arena`): the arena object is sealed at creation so peers on
-the node can see it via `contains` and force-delete it even though the
-dead process never released its creator reference (single-node reclaim;
-a multi-node controller would route this through the owning raylet).
+A dead replica's arena dies with its process: the device memory is the
+process's own, so there is nothing for a peer to reclaim.
 """
 
 from __future__ import annotations
 
+import functools
 import threading
 from collections import OrderedDict
 from typing import Dict, List, Optional, Tuple
@@ -48,23 +51,53 @@ class OutOfPagesError(KVCacheError):
     """Allocation would exceed the arena; caller should queue, not crash."""
 
 
+def scatter_rows(k_pages, v_pages, k_rows, v_rows, w_page, w_off):
+    """Row n of `k_rows`/`v_rows` ([N, n_layer, n_kv_head, head_dim]) goes
+    to `pages[w_page[n], :, w_off[n]]`; returns the updated arena. A row
+    whose `w_page` is `num_pages` or more is dropped: nothing is read or
+    written for it, which is how lanes, padding rows and speculative rows
+    that own no page cost nothing. Traced inside the engine's programs,
+    with the arena donated, this is an update in place.
+
+    The layer is an index of its own, so that the scattered unit is one
+    [n_kv_head, head_dim] tile where it lies: indexed by page and offset
+    alone (`.at[w_page, :, w_off]`), a prefill's scatter has the TPU
+    compiler re-lay the whole arena out and back, two copies of it a
+    call (compiled for a described v5e, PR 25)."""
+    at = (w_page[:, None], np.arange(k_pages.shape[1])[None, :],
+          w_off[:, None])
+    k_pages = k_pages.at[at].set(k_rows.astype(k_pages.dtype), mode="drop")
+    v_pages = v_pages.at[at].set(v_rows.astype(v_pages.dtype), mode="drop")
+    return k_pages, v_pages
+
+
+@functools.lru_cache(maxsize=None)
+def _scatter_rows_jit():
+    """`scatter_rows` as a program of its own, for callers that hold K/V
+    themselves (`write_prefill`, `append`); the engine's programs trace
+    it after the model's step instead. jax is imported on first use: a
+    process that only drives replicas imports this module too."""
+    import jax
+
+    return jax.jit(scatter_rows, donate_argnums=(0, 1))
+
+
 class PagedKVCache:
-    """Fixed-size K/V page allocator over a contiguous arena.
+    """Fixed-size K/V page allocator over an arena on the device.
 
-    Arena layout: float array [2, num_pages, n_layer, block_size,
-    n_kv_head, head_dim]; index 0 is K, 1 is V. `k_pages`/`v_pages` are
-    zero-copy numpy views handed to the decode kernel together with
-    per-sequence gather indices (`page table` rows).
-
-    `store=None` backs the arena with plain process-local numpy (unit
-    tests, in-process bench); otherwise the arena lives in the shm
-    object store and is visible to — and reclaimable by — other workers
-    on the node.
+    `k_pages` / `v_pages` are device arrays [num_pages, n_layer,
+    block_size, n_kv_head, head_dim] in `dtype`. A compiled program that
+    is given them donated returns their successors, and whoever made the
+    call stores those back here before the next one; the old handles are
+    dead from the call on. `store` is accepted for callers of the host
+    arena this replaced and backs nothing.
     """
 
     def __init__(self, num_pages: int, n_layer: int, block_size: int,
                  n_kv_head: int, head_dim: int, dtype=np.float32,
                  store=None, lock=None):
+        import jax.numpy as jnp
+
         if num_pages <= 0 or block_size <= 0:
             raise KVCacheError("num_pages and block_size must be positive")
         self.num_pages = num_pages
@@ -73,28 +106,13 @@ class PagedKVCache:
         self.n_kv_head = n_kv_head
         self.head_dim = head_dim
         self.dtype = np.dtype(dtype)
-        self._store = store
-        self._arena_id = None
         # the engine passes a lock whose waits show in its time ledger
         self._lock = lock if lock is not None else threading.Lock()
-        shape = (2, num_pages, n_layer, block_size, n_kv_head, head_dim)
-        nbytes = int(np.prod(shape)) * self.dtype.itemsize
-        if store is not None:
-            from ray_tpu._private.ids import ObjectID
-            self._arena_id = ObjectID.from_random()
-            buf = store.create_buffer(self._arena_id, nbytes)
-            # Seal immediately (contents stay mutable through our view —
-            # seal here only publishes the id so `contains`/`delete`
-            # work from peer processes for dead-replica reclaim). The
-            # creator reference is kept until close(), pinning the
-            # arena against eviction.
-            store.seal(self._arena_id)
-            self._arena = np.frombuffer(buf, dtype=self.dtype).reshape(shape)
-        else:
-            self._arena = np.zeros(shape, dtype=self.dtype)
-        self._arena[:] = 0
-        self.k_pages = self._arena[0]
-        self.v_pages = self._arena[1]
+        shape = (num_pages, n_layer, block_size, n_kv_head, head_dim)
+        # from the shape: other threads ask while a step holds the handles
+        self.arena_nbytes = 2 * int(np.prod(shape)) * self.dtype.itemsize
+        self.k_pages = jnp.zeros(shape, self.dtype)
+        self.v_pages = jnp.zeros(shape, self.dtype)
         # LIFO free list: recently-freed pages are re-used first (warm)
         self._free: List[int] = list(range(num_pages - 1, -1, -1))
         # page -> holder list (refcount == len). A holder is a request/
@@ -105,14 +123,6 @@ class PagedKVCache:
         self._closed = False
 
     # -- allocation -------------------------------------------------------
-
-    @property
-    def arena_id_hex(self) -> Optional[str]:
-        return self._arena_id.hex() if self._arena_id is not None else None
-
-    @property
-    def arena_nbytes(self) -> int:
-        return int(self._arena.nbytes)
 
     @property
     def free_pages(self) -> int:
@@ -214,20 +224,37 @@ class PagedKVCache:
 
     # -- data plane -------------------------------------------------------
 
-    def append(self, pages: List[int], pos: int, k, v) -> None:
-        """Write one token's K/V ([n_layer, n_kv_head, head_dim]) at
-        logical position `pos` of a sequence holding `pages`."""
-        page = pages[pos // self.block_size]
-        off = pos % self.block_size
+    def write_index(self, pages: List[int], start: int, n: int,
+                    rows: Optional[int] = None
+                    ) -> Tuple[np.ndarray, np.ndarray]:
+        """Arena coordinates (page id, offset in the page; int32, one per
+        row) of positions [start, start + n) of a sequence that holds
+        `pages`, padded to `rows` rows. A padding row, and a position
+        past the last of `pages`, gets the page id `num_pages`, which
+        `scatter_rows` drops."""
+        rows = n if rows is None else rows
+        pos = start + np.arange(rows)
+        slot = pos // self.block_size
+        held = np.asarray(pages, np.int32)
+        own = (np.arange(rows) < n) & (slot < len(held))
+        w_page = np.full(rows, self.num_pages, np.int32)
+        w_page[own] = held[slot[own]]
+        return w_page, (pos % self.block_size).astype(np.int32)
+
+    def _scatter(self, k_rows, v_rows, w_page, w_off) -> None:
         # data-plane writes are lock-free by design: the engine's step
         # thread is the single writer, and an appendable (tail) page
         # belongs to exactly one sequence — shared prefix pages are
         # always full, so no write ever lands on an aliased page (the
         # lock guards only the allocator maps)
         # raylint: disable=lock-discipline
-        self.k_pages[page, :, off] = k
-        # raylint: disable=lock-discipline
-        self.v_pages[page, :, off] = v
+        self.k_pages, self.v_pages = _scatter_rows_jit()(
+            self.k_pages, self.v_pages, k_rows, v_rows, w_page, w_off)
+
+    def append(self, pages: List[int], pos: int, k, v) -> None:
+        """Write one token's K/V ([n_layer, n_kv_head, head_dim]) at
+        logical position `pos` of a sequence holding `pages`."""
+        self._scatter(k[None], v[None], *self.write_index(pages, pos, 1))
 
     def write_prefill(self, pages: List[int], k_seq, v_seq, n: int,
                       start: int = 0) -> None:
@@ -235,23 +262,8 @@ class PagedKVCache:
         head_dim]) for positions [start, start+n) across the sequence's
         pages (chunked prefill passes start > 0, which need not be
         page-aligned)."""
-        bs = self.block_size
-        # arena page layout is [n_layer, block, kvh, hd]; the prefill
-        # slab is [n, n_layer, kvh, hd] -> swap to [n_layer, n, ...]
-        done = 0
-        while done < n:
-            pos = start + done
-            page = pages[pos // bs]
-            off = pos % bs
-            take = min(bs - off, n - done)
-            # single-writer data plane, same as append()
-            # raylint: disable=lock-discipline
-            self.k_pages[page, :, off:off + take] = \
-                np.swapaxes(k_seq[done:done + take], 0, 1)
-            # raylint: disable=lock-discipline
-            self.v_pages[page, :, off:off + take] = \
-                np.swapaxes(v_seq[done:done + take], 0, 1)
-            done += take
+        self._scatter(k_seq[:n], v_seq[:n],
+                      *self.write_index(pages, start, n))
 
     # -- lifecycle --------------------------------------------------------
 
@@ -286,14 +298,10 @@ class PagedKVCache:
             leaked = sum(1 for hs in self._holders.values()
                          if any(not isinstance(h, _PrefixEntry)
                                 for h in hs))
+            for pages in (self.k_pages, self.v_pages):
+                if not pages.is_deleted():
+                    pages.delete()  # the device memory, now
             self.k_pages = self.v_pages = None
-            self._arena = None
-            if self._store is not None and self._arena_id is not None:
-                try:
-                    self._store.release(self._arena_id)
-                    self._store.delete(self._arena_id)
-                except Exception:
-                    pass  # store already torn down
             return leaked
 
     def _check_open(self):
@@ -444,21 +452,3 @@ class PrefixCache:
             out = dict(self.counters)
             out["entries"] = len(self._entries)
             return out
-
-
-def reclaim_arena(arena_id_hex: str, store=None) -> bool:
-    """Force-delete a (possibly dead) replica's KV arena by id from any
-    process attached to the same node store. Returns True when the arena
-    was present and is now gone."""
-    if store is None:
-        from ray_tpu._private.object_ref import get_core_worker
-        cw = get_core_worker()
-        if cw is None or cw.store is None:
-            return False
-        store = cw.store
-    from ray_tpu._private.ids import ObjectID
-    oid = ObjectID.from_hex(arena_id_hex)
-    if not store.contains(oid):
-        return False
-    store.delete(oid)
-    return not store.contains(oid)

@@ -572,12 +572,9 @@ def test_chaos_llm_replica_kill_midstream():
     handle must fail over to the surviving replica and replay-skip the
     already-delivered chunks (greedy decode is deterministic and both
     replicas share a seed, so the resumed stream is the SAME stream) —
-    no accepted request is lost. Afterwards the controller reconciles
-    the death and force-reclaims the dead replica's KV arena from the
-    shm store: a killed replica leaks zero KV pages."""
+    no accepted request is lost. (The dead replica's KV arena was its
+    own device memory and went with the process.)"""
     from ray_tpu import serve
-    from ray_tpu._private.ids import ObjectID
-    from ray_tpu._private.object_ref import get_core_worker
     from ray_tpu.serve.llm import LLMDeployment
 
     ray_tpu.init(num_cpus=8, num_tpus=0,
@@ -597,7 +594,7 @@ def test_chaos_llm_replica_kill_midstream():
             SlowLLM).bind(seed=0)
         handle = serve.run(app)
         ctrl = ray_tpu.get_actor("SERVE_CONTROLLER")
-        # prime the controller's metrics cache (arena ids) pre-kill
+        # prime the controller's metrics cache pre-kill
         ray_tpu.get(ctrl.reconcile_now.remote(), timeout=60)
 
         n_tokens = 24
@@ -605,15 +602,15 @@ def test_chaos_llm_replica_kill_midstream():
             [5, 9, 3], n_tokens)
         tokens = [next(gen)["token"] for _ in range(4)]
 
-        # find the replica carrying the stream (ongoing >= 1) and
-        # remember its arena id, then murder it
+        # find the replica carrying the stream (ongoing >= 1), then
+        # murder it
         info = ray_tpu.get(ctrl.get_replicas.remote("llm"), timeout=30)
-        serving = dead_arena = None
+        serving = None
         for r in info["replicas"]:
             m = ray_tpu.get(r.get_metrics.remote(), timeout=30)
             if m["ongoing"] >= 1 and serving is None:
-                serving, dead_arena = r, m["kv_arena_id"]
-        assert serving is not None and dead_arena
+                serving = r
+        assert serving is not None
         ray_tpu.kill(serving)
 
         # the stream completes on the survivor via replay
@@ -645,20 +642,6 @@ def test_chaos_llm_replica_kill_midstream():
         assert crec.attrs["timed_gaps"] == n_tokens - 2
         assert crec.tpot_ms is not None and crec.tpot_ms >= 40.0
 
-        # reconcile notices the death and reclaims the dead arena
-        deadline = time.monotonic() + 60
-        while time.monotonic() < deadline:
-            ray_tpu.get(ctrl.reconcile_now.remote(), timeout=60)
-            reclaimed = ray_tpu.get(
-                ctrl.get_reclaimed_arenas.remote(), timeout=30)
-            if dead_arena in reclaimed:
-                break
-            time.sleep(0.5)
-        else:
-            raise AssertionError("dead replica's KV arena never "
-                                 "reclaimed")
-        store = get_core_worker().store
-        assert not store.contains(ObjectID.from_hex(dead_arena))
     finally:
         serve.shutdown()
         ray_tpu.shutdown()

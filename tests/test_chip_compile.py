@@ -7,6 +7,7 @@ VMEM, a program over 16 GB — so these guard every later PR at no chip time.
 Nothing runs: results and times come from `chip_smoke.py` on the chip.
 """
 
+import math
 import os
 from functools import partial
 from unittest import mock
@@ -98,6 +99,54 @@ def test_llama_125m_decode_step_compiles_for_v5e(topo):
     mem = compiled.memory_analysis()
     assert mem.argument_size_in_bytes + mem.output_size_in_bytes \
         + mem.temp_size_in_bytes < HBM_BYTES
+
+
+@pytest.mark.parametrize("kind, size", [("decode", 16), ("prefill", 2048)])
+def test_engine_program_updates_the_donated_arena_in_place(topo, kind, size):
+    """The engine's own program (the model's step, then the scatter of its
+    new K/V into the donated arena) at the widths and the arena of the
+    benchmark's serving cells (Mistral-7B's, 20 layers, 2,048 pages of 16
+    tokens): both halves of the arena alias their outputs, and no
+    operation copies or re-lays out an array of the arena's whole shape.
+    (With heads of 64, `llama_125m`, the compiler does re-lay it out.)"""
+    import types
+
+    from ray_tpu.serve.llm.engine import LLMEngine
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    cfg = llama.LlamaConfig(
+        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, vocab_size=32000,
+        n_layer=20, n_head=32, n_kv_head=8, d_model=4096, ffn_mult=3.5,
+        max_seq_len=2048)
+    block, num_pages = 16, 2048
+
+    def on_chip(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: on_chip(a.shape, a.dtype),
+        jax.eval_shape(llama.Llama(cfg).init, jax.random.PRNGKey(0),
+                       jnp.ones((1, 16), jnp.int32)))
+    pages = on_chip((num_pages, cfg.n_layer, block, cfg.n_kv_head,
+                     cfg.head_dim), jnp.bfloat16)
+    engine = types.SimpleNamespace(_mod=llama, model_cfg=cfg)
+    if kind == "decode":
+        fn = LLMEngine._make_decode_fn(engine, size)
+        args = (params, on_chip((size,)), on_chip((size,)), pages, pages,
+                on_chip((size, cfg.max_seq_len // block)), on_chip((size,)),
+                on_chip((size,)))
+    else:
+        fn = LLMEngine._make_prefill_fn(engine, size)
+        args = (params, on_chip((1, size)), on_chip((1,)), pages, pages,
+                on_chip((size,)), on_chip((size,)))
+    compiled = jax.jit(fn, donate_argnums=(3, 4)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    assert mem.alias_size_in_bytes == 2 * 2 * math.prod(pages.shape)
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    shape = "bf16[" + ",".join(map(str, pages.shape)) + "]"
+    moved = [line.strip()[:120] for line in compiled.as_text().splitlines()
+             if " copy(" in line and shape in line.split(" copy(")[0]]
+    assert not moved, moved
 
 
 def test_build_mesh_on_tpu_follows_the_topology(topo):

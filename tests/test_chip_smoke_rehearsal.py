@@ -71,7 +71,7 @@ def test_serve_phase_tiny(replicas):
     rec = chip_smoke.serve_phase(TINY_SERVE, 0, replicas)
     assert rec["platform"] == "cpu"
     assert not _not_about_the_device(rec["failures"]), rec
-    assert rec["kv_arena_bytes_per_decode_step"] > 0
+    assert rec["kv_arena_bytes_on_device"] > 0
     if replicas > 1:
         assert rec["replicas_that_answered"] >= 2
 

@@ -105,32 +105,57 @@ def test_append_and_prefill_layout():
     np.testing.assert_array_equal(kv.v_pages[pages[1], :, 2], v7)
 
 
-def test_shm_arena_create_and_reclaim():
-    """The arena is one sealed shm object; `reclaim_arena` force-deletes
-    it by id from any process attached to the store (dead-replica
-    path)."""
-    from ray_tpu._private.ids import ObjectID
-    from ray_tpu._private.object_store import ObjectStore
-    from ray_tpu.serve.llm import reclaim_arena
+def _numpy_arena_write(k_np, v_np, pages, k_seq, v_seq, n, start, block):
+    """The host arena this replaced, kept as the reference: row by row."""
+    for j in range(n):
+        pos = start + j
+        k_np[pages[pos // block], :, pos % block] = k_seq[j]
+        v_np[pages[pos // block], :, pos % block] = v_seq[j]
 
-    name = f"/ray_tpu_test_llmkv_{os.getpid()}"
-    store = ObjectStore.create(name, capacity=16 * 1024 * 1024,
-                               table_size=256)
-    try:
-        kv = _cache(store=store)
-        hex_id = kv.arena_id_hex
-        assert hex_id is not None
-        assert store.contains(ObjectID.from_hex(hex_id))
-        # the arena view really is shm-backed
-        kv.k_pages[0, 0, 0, 0, 0] = 7.0
-        assert kv.arena_nbytes > 0
-        # reclaim-by-id despite the creator's live reference
-        assert reclaim_arena(hex_id, store=store)
-        assert not store.contains(ObjectID.from_hex(hex_id))
-        assert not reclaim_arena(hex_id, store=store)  # already gone
-        kv.close()
-    finally:
-        store.destroy()
+
+@pytest.mark.parametrize("start", [0, 8, 5], ids=["start0", "aligned",
+                                                  "unaligned"])
+@pytest.mark.parametrize("shape", [(2, 2, 16), (2, 2, 32)],
+                         ids=["llama_tiny", "gpt_tiny"])
+def test_device_arena_writes_equal_a_numpy_model(shape, start):
+    """`write_prefill` and `append` on the device arena against a numpy
+    model of it, for the page shapes of both tiny families: the same rows,
+    and not one element besides."""
+    n_layer, n_kv_head, head_dim = shape
+    block = 4
+    kv = _cache(num_pages=8, n_layer=n_layer, block_size=block,
+                n_kv_head=n_kv_head, head_dim=head_dim)
+    rng = np.random.default_rng(start)
+    fill = rng.normal(size=(2,) + kv.k_pages.shape).astype(np.float32)
+    kv.k_pages, kv.v_pages = kv.k_pages + fill[0], kv.v_pages + fill[1]
+    k_np, v_np = fill[0].copy(), fill[1].copy()
+    kv.alloc(2, "other")
+    pages = kv.alloc(5, "s")
+    n = 9
+    k_seq = rng.normal(size=(n + 3, n_layer, n_kv_head, head_dim)) \
+        .astype(np.float32)
+    v_seq = rng.normal(size=k_seq.shape).astype(np.float32)
+    kv.write_prefill(pages, k_seq, v_seq, n, start=start)  # rows [:n] only
+    _numpy_arena_write(k_np, v_np, pages, k_seq, v_seq, n, start, block)
+    kv.append(pages, start + n, k_seq[n], v_seq[n])
+    _numpy_arena_write(k_np, v_np, pages, k_seq[n:], v_seq[n:], 1,
+                       start + n, block)
+    np.testing.assert_array_equal(np.asarray(kv.k_pages), k_np)
+    np.testing.assert_array_equal(np.asarray(kv.v_pages), v_np)
+    assert kv.arena_nbytes == k_np.nbytes + v_np.nbytes
+
+
+def test_write_index_drops_rows_without_a_page():
+    """Padding rows and positions past the sequence's last page get the
+    page id no page has, which the scatter drops."""
+    kv = _cache(num_pages=8, block_size=4)
+    kv.alloc(3, "other")
+    pages = kv.alloc(2, "s")
+    w_page, w_off = kv.write_index(pages, 6, 4, rows=6)
+    # positions 6, 7 lie in the second page; 8, 9 own none; two pad rows
+    assert list(w_page) == [pages[1], pages[1], 8, 8, 8, 8]
+    assert list(w_off) == [2, 3, 0, 1, 2, 3]
+    assert w_page.dtype == w_off.dtype == np.int32
 
 
 # ---------------------------------------------------------------------------
@@ -269,6 +294,159 @@ def test_engine_metrics_text(llama_engine):
 # ---------------------------------------------------------------------------
 
 
+# ---------------------------------------------------------------------------
+# the arena stays on the device: what a program may write, what crosses
+# the host link, and that the update is a donation
+# ---------------------------------------------------------------------------
+
+
+def _fill_arena(kv, seed=0):
+    """Give every element of the arena a value of its own, as device
+    arrays placed like the ones they replace; returns the numpy copies."""
+    rng = np.random.default_rng(seed)
+    fill = rng.normal(size=(2,) + kv.k_pages.shape).astype(np.float32)
+    kv.k_pages, kv.v_pages = kv.k_pages * 0 + fill[0], \
+        kv.v_pages * 0 + fill[1]
+    return fill[0], fill[1]
+
+
+def _assert_rows_written(kv, fill, pages, written, block):
+    """Of the sequence's `pages`, exactly the positions in `written`
+    changed; every other page of the arena is bit-identical."""
+    for got, was in zip((np.asarray(kv.k_pages), np.asarray(kv.v_pages)),
+                        fill):
+        others = [p for p in range(kv.num_pages) if p not in pages]
+        np.testing.assert_array_equal(got[others], was[others])
+        for pos in range(len(pages) * block):
+            page, off = pages[pos // block], pos % block
+            same = np.array_equal(got[page, :, off], was[page, :, off])
+            assert same != (pos in written), (pos, same)
+
+
+def _small_engine(**cfg_kw):
+    from ray_tpu.serve.llm import EngineConfig, LLMEngine
+    base = dict(batch_buckets=(4,), prefill_buckets=(8, 16), block_size=4,
+                prefix_cache=0)
+    base.update(cfg_kw)
+    eng = LLMEngine(model="llama", engine_config=EngineConfig(**base),
+                    seed=0)
+    eng.warmup()
+    return eng
+
+
+def test_decode_lanes_beyond_the_running_set_write_nothing():
+    """One sequence in a bucket of four: the three idle lanes compute on
+    a page table of zeros, and page 0 belongs to somebody else. Only the
+    running sequence's rows change, warm-up included."""
+    eng = _small_engine()
+    try:
+        assert eng.kv.alloc(1, "other") == [0]
+        fill = _fill_arena(eng.kv)
+        eng.warmup()                       # writes nothing either
+        req = eng.submit([5, 9, 3], 6)
+        eng.step()                         # the prefill and a decode step
+        pages = list(eng._running[0].pages)
+        eng.run_until_idle()
+        assert len(req.tokens) == 6 and 0 not in pages and len(pages) == 3
+        # 3 prompt rows, then the 5 tokens that were fed back
+        _assert_rows_written(eng.kv, fill, pages, set(range(8)), 4)
+        assert eng.metrics()["compiled_step_calls"] == {
+            "decode:4": 5, "prefill:8": 1}
+        eng.kv.free([0], "other")
+        eng.quiesce()
+    finally:
+        assert eng.shutdown() == 0
+
+
+@pytest.mark.parametrize("mode, n", [("oneshot", 5), ("chunk", 13)])
+def test_prefill_rows_past_the_true_length_write_nothing(mode, n):
+    """A prompt shorter than its bucket, and a last chunk shorter than
+    the window: the padding rows compute and are written nowhere."""
+    eng = _small_engine(prefill_chunk=8 if mode == "chunk" else 0)
+    try:
+        assert eng.kv.alloc(1, "other") == [0]
+        fill = _fill_arena(eng.kv)
+        req = eng.submit(list(range(1, n + 1)), 1)
+        eng.run_until_idle()
+        assert len(req.tokens) == 1
+        calls = eng.metrics()["compiled_step_calls"]
+        assert calls == ({"prefill:8": 1} if mode == "oneshot"
+                         else {"chunk:8": 2})
+        # pages come off the free list in order, after the other owner's
+        pages = list(range(1, 1 + eng.kv.pages_for_tokens(n + 1)))
+        _assert_rows_written(eng.kv, fill, pages, set(range(n)), 4)
+        eng.kv.free([0], "other")
+        eng.quiesce()
+    finally:
+        assert eng.shutdown() == 0
+
+
+def _backend_donates():
+    import jax
+    import jax.numpy as jnp
+    x = jnp.zeros(8)
+    jax.jit(lambda a: a + 1, donate_argnums=0)(x)
+    return x.is_deleted()
+
+
+def test_steady_state_compiles_nothing_and_donates_the_arena(llama_engine):
+    """After warm-up, 3 prefills and over 20 decode steps are all cache
+    hits, and every step that ran a program consumed the arena handle it
+    was given: the update is a donation, not a copy beside the old one."""
+    from ray_tpu import parallel
+    eng = llama_engine
+    donates = _backend_donates()
+    before, m0 = parallel.cache_stats(), eng.metrics()
+    reqs = [eng.submit([i + 1] * 3, 22) for i in range(3)]
+    while eng.has_work():
+        held = (eng.kv.k_pages, eng.kv.v_pages)
+        assert eng.step()
+        if donates:
+            assert held[0].is_deleted() and held[1].is_deleted()
+        assert not eng.kv.k_pages.is_deleted()
+    assert all(len(r.result(timeout=10)) == 22 for r in reqs)
+    after, m1 = parallel.cache_stats(), eng.metrics()
+    steps = m1["decode_steps"] - m0["decode_steps"]
+    assert m1["prefill_steps"] - m0["prefill_steps"] == 3 and steps >= 20
+    assert after["misses"] == before["misses"]
+    assert after["retraces"] == before["retraces"]
+    assert after["hits"] - before["hits"] == steps + 3
+    eng.quiesce()
+
+
+def test_link_counters_hold_ids_tables_and_logits_only():
+    """`decode_link_bytes` a step and `prefill_link_bytes` a prefill are
+    the host arguments' bytes plus the fetched logits', to the byte: no
+    K or V is among them."""
+    eng = _small_engine(prefill_buckets=(16,))
+    try:
+        m0 = eng.metrics()
+        assert m0["decode_link_bytes"] == m0["prefill_link_bytes"] == 0
+        reqs = [eng.submit([3 + i] * 5, 4) for i in range(3)]
+        eng.run_until_idle()
+        assert all(len(r.tokens) == 4 for r in reqs)
+        m = eng.metrics()
+        vocab_row = eng.model_cfg.vocab_size * 4          # float32 logits
+        lanes, bucket, int32 = 4, 16, 4
+        # token ids, positions, the page table, a page id and an offset a
+        # lane; the lanes' logits back
+        a_step = lanes * int32 * (4 + eng.max_pages_per_seq) \
+            + lanes * vocab_row
+        # token ids, the true length, a page id and an offset a row; one
+        # row of logits back
+        a_prefill = bucket * int32 * 3 + int32 + vocab_row
+        assert m["decode_steps"] > 0 and m["prefill_steps"] == 3
+        assert m["decode_link_bytes"] == m["decode_steps"] * a_step
+        assert m["prefill_link_bytes"] == 3 * a_prefill
+        # a single prompt row's K and V is more than a whole prefill moves
+        kv = eng.kv
+        one_row = 2 * kv.n_layer * kv.n_kv_head * kv.head_dim * 4
+        assert a_prefill < 5 * one_row + vocab_row
+        eng.quiesce()
+    finally:
+        assert eng.shutdown() == 0
+
+
 def _delta(after, before):
     return {k: after[k] - before.get(k, 0) for k in after
             if isinstance(after[k], (int, float))}
@@ -339,9 +517,14 @@ def test_pump_ledger_sums_to_the_pump_wall_time(ledger_engine):
     for key in ("ph_decode_dispatch_ms", "ph_decode_device_wait_ms",
                 "ph_decode_fetch_ms", "ph_decode_kv_append_ms",
                 "ph_decode_sample_ms", "ph_prefill_dispatch_ms",
-                "ph_prefill_kv_fetch_ms", "ph_prefill_kv_write_ms",
+                "ph_prefill_kv_write_ms",
                 "ph_pump_idle_ms", "ph_admit_ms", "ph_finish_ms"):
         assert d[key] > 0, key
+    # K and V stay on the device: nothing is fetched, and what the host
+    # still does for a write (the rows' coordinates, positions) is small
+    assert d["ph_prefill_kv_fetch_ms"] == 0
+    assert d["ph_prefill_kv_write_ms"] <= 0.02 * d["prefill_ms"]
+    assert d["ph_decode_kv_append_ms"] <= 0.02 * d["decode_ms"]
     # metrics() times itself: calls that had finished when it was read
     assert d["metrics_calls"] == 1 and d["metrics_ms"] > 0
     # a stopped pump's wall time stands still; the operator's family
@@ -408,7 +591,7 @@ def test_profiler_session_holds_the_engine_phases(ledger_engine, tmp_path):
     names = {name for name, _ in events}
     assert {"rt/engine_step", "rt/admit", "rt/llm.prefill",
             "rt/prefill_dispatch", "rt/prefill_device_wait",
-            "rt/prefill_kv_fetch", "rt/prefill_kv_write",
+            "rt/prefill_kv_write",
             "rt/prefill_sample", "rt/decode_assemble",
             "rt/decode_dispatch", "rt/decode_device_wait",
             "rt/decode_fetch", "rt/decode_kv_append", "rt/decode_sample",
@@ -419,7 +602,7 @@ def test_profiler_session_holds_the_engine_phases(ledger_engine, tmp_path):
     assert steps == sorted(steps) and len(steps) >= 4
     want = {ctx["req_id"] for ctx in ctxs} | {"direct-1"}
     for phase in ("rt/llm.prefill", "rt/prefill_dispatch",
-                  "rt/prefill_device_wait", "rt/prefill_kv_fetch",
+                  "rt/prefill_device_wait",
                   "rt/prefill_kv_write", "rt/prefill_sample"):
         got = [st.get("req_id") for name, st in events if name == phase]
         assert set(got) == want and len(got) >= 4, (phase, got)
@@ -649,6 +832,5 @@ def test_serve_llm_end_to_end(clean_deployments):
     info = ray_tpu.get(ctrl.get_replicas.remote("llm"), timeout=30)
     rm = ray_tpu.get(info["replicas"][0].get_metrics.remote(), timeout=30)
     for key in ("ongoing", "queue_depth", "kv_pages_live",
-                "kv_pages_total", "kv_arena_id"):
+                "kv_pages_total"):
         assert key in rm
-    assert rm["kv_arena_id"]  # shm arena (replica runs inside a cluster)

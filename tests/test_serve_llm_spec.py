@@ -368,6 +368,52 @@ def test_speculative_bitmatch_plain_greedy(model):
             assert eng.shutdown() == 0
 
 
+def test_speculative_round_at_the_end_writes_only_its_own_pages():
+    """The last verify window of a request reaches past its reserved
+    pages (12 tokens fill 3 pages of 4 exactly): those rows are dropped,
+    no page of another owner or of the free list changes, and the stream
+    is plain greedy's, token for token."""
+    from ray_tpu.serve.llm import EngineConfig, LLMEngine
+
+    prompt, new = [7, 3, 9, 1, 4, 2], 6
+    plain = _engine(spec_k=0, prefix_cache=0)
+    try:
+        req = plain.submit(prompt, new)
+        plain.run_until_idle(timeout=120)
+        want = req.result(timeout=10)
+    finally:
+        assert plain.shutdown() == 0
+
+    eng = LLMEngine(model="llama", engine_config=EngineConfig(
+        batch_buckets=(1, 2), prefill_buckets=(8, 16), block_size=4,
+        spec_k=3, prefix_cache=0), seed=0)
+    eng.warmup()
+    try:
+        kv = eng.kv
+        assert kv.alloc(1, "other") == [0]
+        rng = np.random.default_rng(3)
+        fill = rng.normal(size=(2,) + kv.k_pages.shape).astype(np.float32)
+        kv.k_pages = kv.k_pages * 0 + fill[0]
+        kv.v_pages = kv.v_pages * 0 + fill[1]
+        req = eng.submit(prompt, new)
+        eng.run_until_idle(timeout=180)
+        assert req.result(timeout=10) == want
+        m = eng.metrics()
+        # self-draft: 1 token from the prefill, 4 from the first round,
+        # and a second round whose window covers positions 10..13
+        assert m["spec_rounds"] == 2
+        own = [1, 2, 3]
+        others = [p for p in range(kv.num_pages) if p not in own]
+        for got, was in zip((np.asarray(kv.k_pages),
+                             np.asarray(kv.v_pages)), fill):
+            np.testing.assert_array_equal(got[others], was[others])
+            assert not np.array_equal(got[own], was[own])
+        kv.free([0], "other")
+        eng.quiesce()
+    finally:
+        assert eng.shutdown() == 0
+
+
 def test_spec_zero_retrace_across_accept_lengths():
     """Accept-length variation must bucket, never retrace: after
     warmup, a burst whose accept lengths scatter (independent draft)
