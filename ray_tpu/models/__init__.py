@@ -3,8 +3,10 @@ parameters (DP/FSDP/TP/SP/EP shardings applied by the trainer),
 remat-friendly blocks, pluggable attention (dense / ring / Ulysses).
 
 Families: GPT-2 decoders (`gpt`), Llama-style decoders with
-RoPE/SwiGLU/GQA (`llama`), MoE decoders (`moe_gpt`), ResNet convnets
-(`resnet`), Vision Transformers (`vit`).
+RoPE/SwiGLU/GQA (`llama`), MoE decoders (`moe_gpt`), latent-attention
+decoders with sigmoid-routed experts (`kimi_k2`, serving only; imported
+when first asked for), ResNet convnets (`resnet`), Vision Transformers
+(`vit`).
 """
 
 from ray_tpu.models.bert import (BertConfig, BertEncoder,
@@ -19,4 +21,16 @@ __all__ = [
     "BertConfig", "BertEncoder", "mask_tokens", "mlm_loss",
     "GPT", "GPTConfig", "Llama", "LlamaConfig", "MoEGPT", "MoEGPTConfig",
     "ResNet", "ResNetConfig", "ViT", "ViTConfig",
+    "KimiK2", "KimiK2Config",
 ]
+
+
+def __getattr__(name):
+    # the serving engine imports this package for every family: the one
+    # family it does not run costs it nothing
+    if name in ("KimiK2", "KimiK2Config", "kimi_k2"):
+        import importlib
+
+        module = importlib.import_module("ray_tpu.models.kimi_k2")
+        return module if name == "kimi_k2" else getattr(module, name)
+    raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -1,0 +1,507 @@
+"""Kimi-K2 family decoder (DeepSeek-V3's block): multi-head latent
+attention, one leading dense SwiGLU layer, then layers of sigmoid-routed
+experts beside a shared expert. Serving only: the three step functions the
+paged engine calls, and a flax module that exists to make the weights.
+
+What it asks of the system that `llama.py` does not:
+
+- The cache row is ONE latent a token a layer, `kv_lora_rank +
+  qk_rope_dim` values (the normed compressed key/value and the shared rope
+  key) padded to whole lane tiles, not K and V of `[n_kv_head, head_dim]`:
+  `cache_rows(cfg)` says so and the engine builds its arena from it.
+- Two attention paths for one layer, the same numbers: `decode_step` scores
+  the query against the cached latents themselves (the absorbed form:
+  `q_nope W_uk^T` against `c_kv`, the output `(P c_kv) W_uv`), so nothing of
+  width heads x 256 is made per cached position; `prefill_step` and
+  `chunk_step` expand the latents of the window and of the sequence's pages
+  to per-head keys and values, a group of heads at a time.
+- The expert layers hold `experts_held` of `n_experts` experts, starting at
+  `first_expert`: one chip's share of an expert-parallel deployment
+  (`parallel.moe.expert_shard_layer`). The router keeps all its outputs.
+
+Rope is the half-rotation form on the rope parts only, with YaRN
+frequencies (`yarn_tables`). Parameters: `wte`, `layer<i>/{attn_norm, q_a,
+q_a_norm, q_b, kv_a, kv_a_norm, kv_b, attn_out, mlp_norm, ...}`,
+`final_norm`, `lm_head` (untied); a dense layer has `mlp_gate_up`,
+`mlp_down`, an expert layer `router`, `router_bias`, `experts_gate_up`,
+`experts_down`, `shared_gate_up`, `shared_down` ([gate | up] along the last
+axis, as in `llama.py`).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+import math
+from typing import Any, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from ray_tpu.parallel.moe import MOE_COUNTS, expert_shard_layer
+
+NEG_INF = -1e30
+# what each step returns after the cache rows, an int32 vector summed over
+# the expert layers: the engine adds it to `decode_moe_*` / `prefill_moe_*`
+STEP_COUNTS = tuple(f"moe_{name}" for name in MOE_COUNTS)
+# heads expanded together in the expanded path: a chunk of 1,024 queries
+# against 9,216 keys is 2.4 GB of float32 scores over 64 heads at once
+HEAD_GROUP = 8
+# the TPU tiles an array's last axis by this; the cache row is the latent
+# padded with zeros to a multiple of it (see `cache_rows`)
+LANE_TILE = 128
+
+
+@dataclasses.dataclass(frozen=True)
+class KimiK2Config:
+    vocab_size: int = 163840
+    n_layer: int = 61
+    n_dense_layer: int = 1          # first_k_dense_replace
+    n_head: int = 64
+    d_model: int = 7168
+    q_lora_rank: int = 1536
+    kv_lora_rank: int = 512
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_head_dim: int = 128
+    ffn_dim: int = 18432            # the dense layer's width
+    moe_ffn_dim: int = 2048         # an expert's width
+    n_experts: int = 384            # the router's outputs
+    experts_held: int = 384         # experts whose weights live here ...
+    first_expert: int = 0           # ... from this one on
+    top_k: int = 8
+    n_shared: int = 1
+    routed_scale: float = 2.827
+    max_seq_len: int = 262144
+    rope_theta: float = 50000.0
+    rope_factor: float = 64.0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 1.0
+    rope_original_max: int = 4096
+    norm_eps: float = 1e-5
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+
+    @property
+    def latent_dim(self) -> int:
+        return self.kv_lora_rank + self.qk_rope_dim
+
+    @property
+    def row_dim(self) -> int:
+        return -(-self.latent_dim // LANE_TILE) * LANE_TILE
+
+    @classmethod
+    def tiny(cls, **kw):
+        base = dict(vocab_size=512, n_layer=3, n_head=4, d_model=64,
+                    q_lora_rank=32, kv_lora_rank=16, qk_nope_dim=8,
+                    qk_rope_dim=8, v_head_dim=8, ffn_dim=128,
+                    moe_ffn_dim=32, n_experts=16, experts_held=16,
+                    top_k=4, max_seq_len=128, rope_factor=4.0,
+                    rope_original_max=32)
+        base.update(kw)
+        return cls(**base)
+
+
+def cache_rows(cfg: KimiK2Config) -> Tuple[Tuple[int, ...], ...]:
+    """What a token leaves in the cache, a layer: one latent row, padded
+    to whole lane tiles. The TPU tiles an array's last axis by 128: a row
+    of 576 is four and a half tiles, and the compiler then lays the arena
+    out with the pages innermost and copies the whole of it to a
+    rows-innermost layout and back around every program's scatter (two
+    copies of 1.06 GB a call; compiled for a described v5e, PR 27). A row
+    of 640 is five tiles, what the 576 would take up in a tiled layout
+    anyway, and is updated in place."""
+    return ((cfg.row_dim,),)
+
+
+# -- rope ---------------------------------------------------------------------
+
+def _yarn_mscale(factor: float, mscale: float) -> float:
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def yarn_tables(cfg: KimiK2Config):
+    """(cos, sin) float32 [max_seq_len, qk_rope_dim / 2], YaRN (Peng et al.
+    2023) as DeepSeek-V3's modelling code computes it: the extrapolated
+    frequencies `theta^(-2i/d)` and the interpolated ones (those over
+    `factor`) blended by a linear ramp between the correction dims of
+    `beta_fast` and `beta_slow` over the original context; both scaled by
+    `mscale(factor, mscale) / mscale(factor, mscale_all_dim)`."""
+    dim, base = cfg.qk_rope_dim, cfg.rope_theta
+    extra = 1.0 / base ** (np.arange(0, dim, 2, dtype=np.float64) / dim)
+    inter = extra / cfg.rope_factor
+
+    def correction_dim(rotations):
+        return dim * math.log(cfg.rope_original_max
+                              / (rotations * 2 * math.pi)) \
+            / (2 * math.log(base))
+
+    low = max(math.floor(correction_dim(cfg.rope_beta_fast)), 0)
+    high = min(math.ceil(correction_dim(cfg.rope_beta_slow)), dim - 1)
+    ramp = np.clip((np.arange(dim // 2, dtype=np.float64) - low)
+                   / ((high - low) or 0.001), 0, 1)
+    mask = 1.0 - ramp
+    inv = inter * (1 - mask) + extra * mask
+    ang = np.outer(np.arange(cfg.max_seq_len, dtype=np.float64), inv)
+    m = _yarn_mscale(cfg.rope_factor, cfg.rope_mscale) \
+        / _yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+    return (np.cos(ang) * m).astype(np.float32), \
+        (np.sin(ang) * m).astype(np.float32)
+
+
+def softmax_scale(cfg: KimiK2Config) -> float:
+    """(nope + rope)^-0.5 times the square of `mscale_all_dim`'s YaRN
+    attention factor."""
+    m = _yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim)
+    return (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5 * m * m
+
+
+def _rope(x, cos, sin):
+    """Rotate the halves of the last axis; cos/sin broadcast against
+    x[..., :D/2]."""
+    x32 = x.astype(jnp.float32)
+    x1, x2 = jnp.split(x32, 2, axis=-1)
+    return jnp.concatenate(
+        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
+
+
+def _rms(x, scale, eps, dtype):
+    x32 = x.astype(jnp.float32)
+    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
+    y = x32 * jax.lax.rsqrt(var + eps)
+    return (y * scale.astype(jnp.float32)).astype(dtype)
+
+
+# -- the weights --------------------------------------------------------------
+
+def layer_shapes(cfg: KimiK2Config, i: int) -> dict:
+    """name -> (shape, kind) of layer i's parameters."""
+    d, h = cfg.d_model, cfg.n_head
+    shapes = {
+        "attn_norm": ((d,), "ones"),
+        "q_a": ((d, cfg.q_lora_rank), "w"),
+        "q_a_norm": ((cfg.q_lora_rank,), "ones"),
+        "q_b": ((cfg.q_lora_rank,
+                 h * (cfg.qk_nope_dim + cfg.qk_rope_dim)), "w"),
+        "kv_a": ((d, cfg.latent_dim), "w"),
+        "kv_a_norm": ((cfg.kv_lora_rank,), "ones"),
+        "kv_b": ((cfg.kv_lora_rank,
+                  h * (cfg.qk_nope_dim + cfg.v_head_dim)), "w"),
+        "attn_out": ((h * cfg.v_head_dim, d), "w"),
+        "mlp_norm": ((d,), "ones"),
+    }
+    if i < cfg.n_dense_layer:
+        shapes["mlp_gate_up"] = ((d, 2 * cfg.ffn_dim), "w")
+        shapes["mlp_down"] = ((cfg.ffn_dim, d), "w")
+        return shapes
+    f = cfg.moe_ffn_dim
+    shapes.update({
+        "router": ((d, cfg.n_experts), "w"),
+        "router_bias": ((cfg.n_experts,), "bias"),
+        "experts_gate_up": ((cfg.experts_held, d, 2 * f), "w"),
+        "experts_down": ((cfg.experts_held, f, d), "w"),
+        "shared_gate_up": ((d, 2 * f * cfg.n_shared), "w"),
+        "shared_down": ((f * cfg.n_shared, d), "w"),
+    })
+    return shapes
+
+
+class _Weights(nn.Module):
+    """Declares one group of parameters and returns them as a dict."""
+    shapes: Any
+    param_dtype: Any
+
+    @nn.compact
+    def __call__(self):
+        inits = {
+            "w": (nn.initializers.normal(0.02), self.param_dtype),
+            "ones": (nn.initializers.ones, self.param_dtype),
+            # the selection bias is float32 in the checkpoint. A trained
+            # one balances the experts' load; under a random router a
+            # deviation of 0.01 already changes three in ten tokens' sets
+            # of experts and leaves the load's spread as it is, and 0.1
+            # sends most pairs to the dozen experts with the largest bias
+            # (the top sigmoid scores lie within 0.03 of each other)
+            "bias": (nn.initializers.normal(0.01), jnp.float32),
+        }
+        return {name: self.param(name, inits[kind][0], shape,
+                                 inits[kind][1])
+                for name, (shape, kind) in self.shapes.items()}
+
+
+class KimiK2(nn.Module):
+    """`net.init` makes the weights; `apply` is the full causal forward
+    (no cache), tokens [B, T] -> logits [B, T, V]."""
+    config: KimiK2Config
+
+    @nn.compact
+    def __call__(self, tokens):
+        cfg = self.config
+        top = {"wte": ((cfg.vocab_size, cfg.d_model), "w"),
+               "final_norm": ((cfg.d_model,), "ones"),
+               "lm_head": ((cfg.d_model, cfg.vocab_size), "w")}
+        p = _Weights(top, cfg.param_dtype, name="top")()
+        for i in range(cfg.n_layer):
+            p[f"layer{i}"] = _Weights(layer_shapes(cfg, i), cfg.param_dtype,
+                                      name=f"layer{i}")()
+        logits, _, _ = _window_forward(
+            p, cfg, tokens, jnp.zeros(tokens.shape[:1], jnp.int32), None,
+            None, None)
+        return logits
+
+
+def unboxed_params(variables):
+    p = nn.meta.unbox(variables)
+    p = p.get("params", p)
+    if "top" in p:
+        p = {**{k: v for k, v in p.items() if k != "top"}, **p["top"]}
+    return p
+
+
+# -- the layer's parts --------------------------------------------------------
+
+def _project(lp, cfg: KimiK2Config, h, cos, sin):
+    """h [..., d] -> q_nope [..., H, nope], q_rope [..., H, rope] (rotated),
+    cache row [..., row_dim]: the normed compressed key/value, the one
+    rotated rope key all heads share, zeros up to the row's width.
+    cos/sin [..., rope/2]."""
+    dtype = cfg.dtype
+    with jax.named_scope("mla_project"):
+        c_q = _rms(h @ lp["q_a"].astype(dtype), lp["q_a_norm"],
+                   cfg.norm_eps, dtype)
+        q = (c_q @ lp["q_b"].astype(dtype)).reshape(
+            h.shape[:-1] + (cfg.n_head, cfg.qk_nope_dim + cfg.qk_rope_dim))
+        q_nope, q_rope = jnp.split(q, [cfg.qk_nope_dim], axis=-1)
+        q_rope = _rope(q_rope, cos[..., None, :], sin[..., None, :])
+        kv = h @ lp["kv_a"].astype(dtype)
+        c_kv, k_rope = jnp.split(kv, [cfg.kv_lora_rank], axis=-1)
+        c_kv = _rms(c_kv, lp["kv_a_norm"], cfg.norm_eps, dtype)
+        parts = [c_kv, _rope(k_rope, cos, sin)]
+        if cfg.row_dim > cfg.latent_dim:
+            parts.append(jnp.zeros(
+                c_kv.shape[:-1] + (cfg.row_dim - cfg.latent_dim,), dtype))
+        latent = jnp.concatenate(parts, axis=-1)
+    return q_nope, q_rope, latent
+
+
+def _kv_b(lp, cfg: KimiK2Config):
+    """`kv_b` as [kv_lora, H, nope + v]."""
+    return lp["kv_b"].astype(cfg.dtype).reshape(
+        cfg.kv_lora_rank, cfg.n_head, cfg.qk_nope_dim + cfg.v_head_dim)
+
+
+def _softmax(scores, valid):
+    scores = jnp.where(valid, scores, NEG_INF)
+    p = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
+    return p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-20)
+
+
+def attend_absorbed(lp, cfg: KimiK2Config, q_nope, q_rope, lat_cached,
+                    lat_new, valid):
+    """One token a sequence against its cached latents and itself.
+    q_nope [B, H, nope], q_rope [B, H, rope]; lat_cached [B, T, row]
+    (the sequence's pages, gathered); lat_new [B, row]; valid [B, T + 1]
+    (cached slots below the position, then the token itself). The query is
+    carried into the latent space (`q_nope W_uk^T`), scored against the
+    latents as they lie in the cache, and the weighted latent is expanded
+    once a head (`W_uv`). Returns [B, H * v]."""
+    with jax.named_scope("mla_attend"):
+        w = _kv_b(lp, cfg)
+        w_uk, w_uv = jnp.split(w, [cfg.qk_nope_dim], axis=-1)
+        f32 = jnp.float32
+        q_lat = jnp.einsum("bhn,chn->bhc", q_nope, w_uk)
+        q_cat = jnp.concatenate([q_lat, q_rope], axis=-1)
+        # the row's padding scores nothing: [B, H, row]
+        q_cat = jnp.pad(q_cat, ((0, 0), (0, 0),
+                                (0, cfg.row_dim - cfg.latent_dim)))
+        s_cached = jnp.einsum("bhl,btl->bht", q_cat, lat_cached,
+                              preferred_element_type=f32)
+        s_new = jnp.einsum("bhl,bl->bh", q_cat, lat_new,
+                           preferred_element_type=f32)
+        scores = jnp.concatenate([s_cached, s_new[..., None]], axis=-1)
+        p = _softmax(scores * softmax_scale(cfg), valid[:, None, :])
+        p = p.astype(cfg.dtype)
+        t = lat_cached.shape[1]
+        # over the whole latent and cut afterwards: a slice of the gathered
+        # pages would be another copy of them
+        o_lat = jnp.einsum("bht,btl->bhl", p[..., :t], lat_cached,
+                           preferred_element_type=f32) \
+            + p[..., t:].astype(f32) * lat_new[:, None, :].astype(f32)
+        o_lat = o_lat[..., :cfg.kv_lora_rank].astype(cfg.dtype)
+        out = jnp.einsum("bhc,chv->bhv", o_lat, w_uv)
+    return out.reshape(out.shape[0], cfg.n_head * cfg.v_head_dim)
+
+
+def attend_expanded(lp, cfg: KimiK2Config, q_nope, q_rope, lat_all, valid):
+    """A window of C tokens against K latents (its sequence's cached ones,
+    then the window's own). q_nope [B, C, H, nope], q_rope [B, C, H, rope];
+    lat_all [B, K, row]; valid [B, C, K]. The latents are expanded to
+    keys and values (`c_kv W_ukv`) for `HEAD_GROUP` heads at a time.
+    Returns [B, C, H * v]."""
+    with jax.named_scope("mla_attend"):
+        b, c, h, _ = q_nope.shape
+        g = HEAD_GROUP if h % HEAD_GROUP == 0 else h
+        c_all, k_rope, _ = jnp.split(
+            lat_all, [cfg.kv_lora_rank, cfg.latent_dim], axis=-1)
+        w = _kv_b(lp, cfg).reshape(cfg.kv_lora_rank, h // g, g, -1)
+        scale = softmax_scale(cfg)
+        f32 = jnp.float32
+
+        def group(args):
+            w_g, qn, qr = args   # [c, g, nope+v], [B, C, g, nope|rope]
+            kv = jnp.einsum("bkc,cgn->bkgn", c_all, w_g)
+            k_nope, v = jnp.split(kv, [cfg.qk_nope_dim], axis=-1)
+            scores = jnp.einsum("bqgn,bkgn->bgqk", qn, k_nope,
+                                preferred_element_type=f32) \
+                + jnp.einsum("bqgr,bkr->bgqk", qr, k_rope,
+                             preferred_element_type=f32)
+            p = _softmax(scores * scale, valid[:, None, :, :])
+            return jnp.einsum("bgqk,bkgv->bqgv", p.astype(cfg.dtype), v)
+
+        def by_group(q):
+            return jnp.moveaxis(q.reshape(b, c, h // g, g, -1), 2, 0)
+
+        out = jax.lax.map(group, (jnp.moveaxis(w, 1, 0), by_group(q_nope),
+                                  by_group(q_rope)))
+        out = jnp.moveaxis(out, 0, 2)          # [B, C, H/g, g, v]
+    return out.reshape(b, c, h * cfg.v_head_dim)
+
+
+def _swiglu(x, gate_up, down, dtype):
+    gate, up = jnp.split(x @ gate_up.astype(dtype), 2, axis=-1)
+    return (nn.silu(gate) * up) @ down.astype(dtype)
+
+
+def feed_forward(lp, cfg: KimiK2Config, i: int, h, valid):
+    """Layer i's feed-forward of h [N, d]: the dense SwiGLU, or this
+    chip's experts' part of the routed sum plus the shared expert.
+    Returns (result [N, d], counts int32[len(MOE_COUNTS)])."""
+    if i < cfg.n_dense_layer:
+        with jax.named_scope("dense_mlp"):
+            return _swiglu(h, lp["mlp_gate_up"], lp["mlp_down"],
+                           cfg.dtype), jnp.zeros(len(MOE_COUNTS), jnp.int32)
+    routed, counts = expert_shard_layer(
+        h, lp["router"], lp["router_bias"],
+        {"gate_up": lp["experts_gate_up"], "down": lp["experts_down"]},
+        cfg.first_expert, cfg.n_experts, cfg.top_k, cfg.routed_scale,
+        valid=valid)
+    with jax.named_scope("moe_shared"):
+        shared = _swiglu(h, lp["shared_gate_up"], lp["shared_down"],
+                         cfg.dtype)
+    return routed + shared, counts
+
+
+def _head(p, cfg: KimiK2Config, x):
+    with jax.named_scope("lm_head"):
+        x = _rms(x, p["final_norm"], cfg.norm_eps, cfg.dtype)
+        return x @ p["lm_head"].astype(cfg.dtype)
+
+
+def _gather_pages(pages, page_table, i: int):
+    """Layer i's rows of the sequences' pages: [B, n_pages * block, row].
+    Page and layer are indexed together, so only the sequences' own rows
+    are read (a slice of the layer first would copy a seventh of the
+    arena a layer)."""
+    got = pages[page_table, i]
+    return got.reshape(got.shape[0], -1, got.shape[-1])
+
+
+# -- the three steps ----------------------------------------------------------
+
+def _window_forward(p, cfg: KimiK2Config, tokens, start, pages, page_table,
+                    valid_rows):
+    """C tokens a sequence from position `start` on, against the cached
+    latents of its pages (none when `pages` is None): the expanded path.
+    Returns (logits [B, C, V], latents [B, C, L, row], counts)."""
+    dtype = cfg.dtype
+    b, c = tokens.shape
+    x = p["wte"].astype(dtype)[tokens]
+    positions = jnp.minimum(start[:, None] + jnp.arange(c)[None, :],
+                            cfg.max_seq_len - 1)
+    cos_t, sin_t = yarn_tables(cfg)
+    cos, sin = jnp.asarray(cos_t)[positions], jnp.asarray(sin_t)[positions]
+    valid = jnp.broadcast_to(jnp.tril(jnp.ones((c, c), bool))[None],
+                             (b, c, c))
+    if pages is not None:
+        t_max = page_table.shape[1] * pages.shape[2]
+        cached = jnp.arange(t_max)[None, None, :] < start[:, None, None]
+        valid = jnp.concatenate(
+            [jnp.broadcast_to(cached, (b, c, t_max)), valid], axis=-1)
+    flat_valid = None if valid_rows is None else valid_rows.reshape(-1)
+    latents, counts = [], jnp.zeros(len(MOE_COUNTS), jnp.int32)
+    for i in range(cfg.n_layer):
+        lp = p[f"layer{i}"]
+        h = _rms(x, lp["attn_norm"], cfg.norm_eps, dtype)
+        q_nope, q_rope, lat = _project(lp, cfg, h, cos, sin)
+        lat_all = lat if pages is None else jnp.concatenate(
+            [_gather_pages(pages, page_table, i).astype(dtype), lat], axis=1)
+        att = attend_expanded(lp, cfg, q_nope, q_rope, lat_all, valid)
+        x = x + att @ lp["attn_out"].astype(dtype)
+        h = _rms(x, lp["mlp_norm"], cfg.norm_eps, dtype)
+        y, n = feed_forward(lp, cfg, i, h.reshape(b * c, -1), flat_valid)
+        x = x + y.reshape(b, c, -1)
+        counts = counts + n
+        latents.append(lat)
+    return _head(p, cfg, x), jnp.stack(latents, axis=2), counts
+
+
+def prefill_step(variables, cfg: KimiK2Config, tokens, true_len,
+                 valid=None):
+    """Full forward over a padded prompt batch. tokens [B, S]; true_len
+    [B]; `valid` [B, S] marks the rows that are tokens (for the expert
+    counters; None counts every row). Returns (next_logits [B, V], latents
+    [B, S, L, row], counts); rows past true_len are garbage the caller
+    must not cache."""
+    p = unboxed_params(variables)
+    b = tokens.shape[0]
+    logits, latents, counts = _window_forward(
+        p, cfg, tokens, jnp.zeros((b,), jnp.int32), None, None, valid)
+    idx = jnp.maximum(true_len - 1, 0)
+    next_logits = jnp.take_along_axis(
+        logits, idx[:, None, None], axis=1)[:, 0]
+    return next_logits, latents, counts
+
+
+def chunk_step(variables, cfg: KimiK2Config, tokens, start, pages,
+               page_table, valid=None):
+    """C tokens a sequence against a paged cache that holds its first
+    `start` positions. tokens [B, C]; pages [P, L, block, row];
+    page_table [B, n_pages]. Returns (logits [B, C, V], latents
+    [B, C, L, row], counts)."""
+    return _window_forward(unboxed_params(variables), cfg, tokens, start,
+                           pages, page_table, valid)
+
+
+def decode_step(variables, cfg: KimiK2Config, tokens, positions, pages,
+                page_table, valid=None):
+    """One token a sequence on a paged cache: the absorbed path. tokens
+    [B]; positions [B] (= tokens already cached); `valid` [B] marks the
+    lanes that hold a sequence. Returns (logits [B, V], latents
+    [B, L, row], counts)."""
+    p = unboxed_params(variables)
+    dtype = cfg.dtype
+    x = p["wte"].astype(dtype)[tokens]
+    cos_t, sin_t = yarn_tables(cfg)
+    cos, sin = jnp.asarray(cos_t)[positions], jnp.asarray(sin_t)[positions]
+    t_max = page_table.shape[1] * pages.shape[2]
+    key_idx = jnp.arange(t_max + 1)
+    seen = (key_idx[None, :] < positions[:, None]) | \
+        (key_idx[None, :] == t_max)
+    latents, counts = [], jnp.zeros(len(MOE_COUNTS), jnp.int32)
+    for i in range(cfg.n_layer):
+        lp = p[f"layer{i}"]
+        h = _rms(x, lp["attn_norm"], cfg.norm_eps, dtype)
+        q_nope, q_rope, lat = _project(lp, cfg, h, cos, sin)
+        att = attend_absorbed(
+            lp, cfg, q_nope, q_rope,
+            _gather_pages(pages, page_table, i).astype(dtype), lat, seen)
+        x = x + att @ lp["attn_out"].astype(dtype)
+        h = _rms(x, lp["mlp_norm"], cfg.norm_eps, dtype)
+        y, n = feed_forward(lp, cfg, i, h, valid)
+        x = x + y
+        counts = counts + n
+        latents.append(lat)
+    return _head(p, cfg, x), jnp.stack(latents, axis=1), counts

@@ -134,3 +134,90 @@ def apply_moe(
         check_vma=False,
     )
     return fn(x, router_w, expert_params)
+
+
+# -- one chip's share of an expert-parallel layer -----------------------------
+
+MOE_COUNTS = ("pairs_routed", "pairs_local", "pairs_computed",
+              "expert_calls")
+
+
+def sigmoid_topk_route(x, router_w, bias, top_k: int, scale: float):
+    """Sigmoid-scored top-k routing with a selection bias (DeepSeek-V3's
+    `noaux_tc` with one group, Kimi-K2's). x [N, d]; router_w
+    [d, n_experts]; bias [n_experts]. The experts are the top-k of
+    `sigmoid(x W) + bias`; their weights are the scores WITHOUT the bias,
+    over their sum, times `scale`. Float32 at the highest matmul precision:
+    a rounded score changes which expert a token goes to. Returns
+    (expert ids [N, top_k] int32, weights [N, top_k] float32)."""
+    scores = jax.nn.sigmoid(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST))
+    _, expert = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    weight = jnp.take_along_axis(scores, expert, axis=-1)
+    weight = weight / jnp.sum(weight, axis=-1, keepdims=True) * scale
+    return expert.astype(jnp.int32), weight
+
+
+def expert_shard_layer(x, router_w, bias, experts_held, first_expert: int,
+                       n_experts: int, top_k: int, scale: float,
+                       valid=None):
+    """What the chip that holds experts [first_expert, first_expert + held)
+    of `n_experts` adds to a routed expert layer: every token is routed
+    over ALL the experts, the (token, expert) pairs whose expert lives here
+    are kept, and the result is the weighted sum of the held experts'
+    outputs alone. No capacity: a pair is never dropped. The kept pairs are
+    sorted by expert and each projection is one grouped product
+    (`lax.ragged_dot`) over `N * min(top_k, held)` rows, the most that can
+    be local. On one chip this is the whole layer's local half; under
+    expert parallelism the partial results of the shards add up to the
+    layer (the caller adds what every chip computes alike, a shared
+    expert, once).
+
+    x [N, d]; router_w [d, n_experts]; bias [n_experts]; `experts_held`
+    {"gate_up" [held, d, 2f], "down" [held, f, d]} (SwiGLU, gate | up along
+    the last axis); `valid` [N] bool marks the rows that are tokens (a
+    padded lane routes nowhere and is not counted). Returns (partial
+    result [N, d] in x's type, counts int32[4] as `MOE_COUNTS`)."""
+    if router_w.shape[1] != n_experts:
+        raise ValueError(f"the router has {router_w.shape[1]} outputs for "
+                         f"{n_experts} experts")
+    n, d = x.shape
+    held = experts_held["down"].shape[0]
+    with jax.named_scope("moe_route"):
+        expert, weight = sigmoid_topk_route(x, router_w, bias, top_k, scale)
+        local = (expert >= first_expert) & (expert < first_expert + held)
+        if valid is not None:
+            local = local & valid[:, None]
+        n_valid = n if valid is None else jnp.sum(valid.astype(jnp.int32))
+        # pairs in token order, then sorted by held expert; a pair that is not
+        # local gets the id `held` and sorts behind every group
+        pair_expert = jnp.where(local, expert - first_expert, held).reshape(-1)
+        order = jnp.argsort(pair_expert, stable=True)
+        rows = n * min(top_k, held)
+        take = order[:rows]
+        group_sizes = jnp.sum(
+            pair_expert[:, None] == jnp.arange(held)[None, :], axis=0,
+            dtype=jnp.int32)
+        n_local = jnp.sum(group_sizes)
+    with jax.named_scope("moe_experts"):
+        xs = x[take // top_k]                                    # [rows, d]
+        gu = lax.ragged_dot(xs, experts_held["gate_up"].astype(x.dtype),
+                            group_sizes)
+        gate, up = jnp.split(gu, 2, axis=-1)
+        ys = lax.ragged_dot(jax.nn.silu(gate) * up,
+                            experts_held["down"].astype(x.dtype), group_sizes)
+        # back to token order: pair j lies at row `where[j]` of the sorted
+        # rows; a row past the last group belongs to no expert, and whatever
+        # the grouped product left there is not a result
+        where = jnp.argsort(order)
+        n_computed = jnp.minimum(n_local, rows)
+        mine = jnp.where((where < n_computed)[:, None],
+                         ys[jnp.minimum(where, rows - 1)].astype(jnp.float32),
+                         0.0)
+        out = jnp.sum((mine * weight.reshape(-1, 1)).reshape(n, top_k, d),
+                      axis=1)
+    counts = jnp.stack([
+        n_valid * top_k, jnp.sum(local.astype(jnp.int32)), n_computed,
+        jnp.sum((group_sizes > 0).astype(jnp.int32))]).astype(jnp.int32)
+    return out.astype(x.dtype), counts

@@ -24,7 +24,8 @@ from typing import Any, Dict, List, Optional
 class LLMDeployment:
     """Deployment callable: one continuous-batching engine per replica.
 
-    `model` is the family ("llama" | "gpt"); `model_config` /
+    `model` is the family (a key of `engine.MODEL_FAMILIES`);
+    `model_config` /
     `engine_config` are plain dicts so deployments stay picklable
     (resolved into the real config dataclasses replica-side). `seed`
     fixes the weight init — replicas of one deployment must agree so
@@ -36,14 +37,14 @@ class LLMDeployment:
                  engine_config: Optional[Dict[str, Any]] = None,
                  draft_config: Optional[Dict[str, Any]] = None,
                  seed: int = 0):
-        from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
+        from ray_tpu.serve.llm.engine import (EngineConfig, LLMEngine,
+                                              model_family)
 
         model_cfg = None
         draft_cfg = None
-        if model == "llama":
-            from ray_tpu.models.llama import LlamaConfig as _Cfg
-        else:
-            from ray_tpu.models.gpt import GPTConfig as _Cfg
+        if model_config or draft_config:
+            family, mod = model_family(model)
+            _Cfg = getattr(mod, family.config)
         if model_config:
             model_cfg = _Cfg(**model_config)
         if draft_config:

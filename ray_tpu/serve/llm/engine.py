@@ -31,6 +31,7 @@ replay both lean on.
 from __future__ import annotations
 
 import dataclasses
+import importlib
 import itertools
 import os
 import queue
@@ -41,7 +42,7 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from ray_tpu.serve.llm.kv_cache import (OutOfPagesError, PagedKVCache,
-                                        PrefixCache, scatter_rows)
+                                        PrefixCache, scatter_arena)
 from ray_tpu.util import metrics as _metrics
 from ray_tpu.util import request_recorder as _rr
 from ray_tpu.util import step_profiler as _sp
@@ -114,6 +115,59 @@ class EngineConfig:
 
 class RequestRejected(RuntimeError):
     pass
+
+
+@dataclasses.dataclass(frozen=True)
+class ModelFamily:
+    """What the engine needs to know of a model family: the module under
+    `ray_tpu.models` with its three step functions (imported when the
+    family is selected, never before), the flax module that makes weights
+    and its config class (`.tiny()` is the default model), and what a token
+    leaves in the cache. `cache_rows` names the module's function from a
+    config to the row shapes, one arena array each; None is K and V of
+    [n_kv_head, head_dim]. `step_counts` names the module's tuple of
+    counter names: its steps then take `valid=` (the rows that are tokens)
+    and return an int32 vector of that length after the cache rows, which
+    the engine adds to `decode_<name>` / `prefill_<name>`."""
+
+    module: str
+    net: str
+    config: str
+    cache_rows: Optional[str] = None
+    step_counts: Optional[str] = None
+
+
+MODEL_FAMILIES: Dict[str, ModelFamily] = {
+    "llama": ModelFamily("ray_tpu.models.llama", "Llama", "LlamaConfig"),
+    "gpt": ModelFamily("ray_tpu.models.gpt", "GPT", "GPTConfig"),
+    "kimi_k2": ModelFamily("ray_tpu.models.kimi_k2", "KimiK2",
+                           "KimiK2Config", "cache_rows", "STEP_COUNTS"),
+}
+
+
+def model_family(model: str):
+    """(the registry's row, its module) of a family's name; the module is
+    imported here, when the family is selected."""
+    family = MODEL_FAMILIES.get(model)
+    if family is None:
+        raise ValueError(f"unknown model family {model!r} "
+                         f"(known: {sorted(MODEL_FAMILIES)})")
+    return family, importlib.import_module(family.module)
+
+
+def _valid_rows(counted: bool, w_page, arena) -> dict:
+    """`valid=` for the steps of a family that counts (`step_counts`): a
+    row is a token's where the program is to write it."""
+    return {"valid": w_page < arena[0].shape[0]} if counted else {}
+
+
+def _kv_rows(cfg) -> Tuple[Tuple[int, int], ...]:
+    """K and V of [n_kv_head, head_dim] (a family without grouped-query
+    attention has a key head for every query head)."""
+    n_kv_head = getattr(cfg, "n_kv_head", None)
+    if n_kv_head is None:
+        n_kv_head = cfg.n_head
+    return ((n_kv_head, cfg.d_model // cfg.n_head),) * 2
 
 
 # The pump thread's time ledger (util/tracing.PhaseTable): between start()
@@ -273,7 +327,7 @@ class _Sequence:
 class LLMEngine:
     """Continuous-batching engine for one model replica.
 
-    `model` selects the decode path ("llama" | "gpt"); `model_cfg`
+    `model` selects the family (a key of `MODEL_FAMILIES`); `model_cfg`
     defaults to the family's tiny config in float32 (the 1-core build
     box target — a real deployment passes its own config + params).
     `store` is accepted and unused: the KV arena is device memory of
@@ -288,20 +342,13 @@ class LLMEngine:
         import jax.numpy as jnp
         from ray_tpu.parallel import compiled_step
 
-        if model == "llama":
-            from ray_tpu.models import llama as mod
-            self.model_cfg = model_cfg or mod.LlamaConfig.tiny(
-                dtype=jnp.float32)
-            n_kv_head = self.model_cfg.n_kv_head
-            head_dim = self.model_cfg.head_dim
-        elif model == "gpt":
-            from ray_tpu.models import gpt as mod
-            self.model_cfg = model_cfg or mod.GPTConfig.tiny(
-                dtype=jnp.float32)
-            n_kv_head = self.model_cfg.n_head
-            head_dim = self.model_cfg.d_model // self.model_cfg.n_head
-        else:
-            raise ValueError(f"unknown model family {model!r}")
+        family, mod = model_family(model)
+        self.model_cfg = model_cfg or getattr(mod, family.config).tiny(
+            dtype=jnp.float32)
+        self._cache_rows = getattr(mod, family.cache_rows) \
+            if family.cache_rows else _kv_rows
+        self._step_counts: Tuple[str, ...] = tuple(
+            getattr(mod, family.step_counts)) if family.step_counts else ()
         self.model_name = model
         self._mod = mod
         cfg = (engine_config or EngineConfig()).resolved(
@@ -311,8 +358,7 @@ class LLMEngine:
                                    // cfg.block_size)
 
         if params is None:
-            net = (mod.Llama if model == "llama" else mod.GPT)(
-                self.model_cfg)
+            net = getattr(mod, family.net)(self.model_cfg)
             params = net.init(
                 jax.random.PRNGKey(seed),
                 jnp.ones((1, min(cfg.prefill_buckets)), jnp.int32))
@@ -327,7 +373,7 @@ class LLMEngine:
 
         self.kv = PagedKVCache(
             cfg.num_pages, self.model_cfg.n_layer, cfg.block_size,
-            n_kv_head, head_dim,
+            rows=self._cache_rows(self.model_cfg),
             dtype=jnp.dtype(self.model_cfg.dtype),
             lock=_tracing.TimedLock(self._phases, threading.Lock()))
         self.prefix = PrefixCache(self.kv) if cfg.prefix_cache else None
@@ -335,9 +381,11 @@ class LLMEngine:
         # one compiled_step wrapper per bucket: each sees exactly one
         # abstract signature, so on_retrace="error" turns any shape
         # drift in steady-state serving into a loud failure. Every one
-        # takes the arena as arguments 3 and 4, donated.
+        # takes the arena's arrays from argument 3 on, donated.
+        arena_args = tuple(range(3, 3 + len(self.kv.arena)))
+
         def program(fn):
-            return compiled_step(fn, donate_argnums=(3, 4),
+            return compiled_step(fn, donate_argnums=arena_args,
                                  on_retrace="error")
 
         self._prefill_fns = {s: program(self._make_prefill_fn(s))
@@ -365,16 +413,10 @@ class LLMEngine:
             elif draft_cfg is None:
                 self.draft_params = self.params  # self-draft
             else:
-                net = (mod.Llama if model == "llama" else mod.GPT)(
-                    self.draft_cfg)
+                net = getattr(mod, family.net)(self.draft_cfg)
                 self.draft_params = net.init(
                     jax.random.PRNGKey(seed + 1),
                     jnp.ones((1, min(cfg.prefill_buckets)), jnp.int32))
-            if getattr(self.draft_cfg, "n_kv_head", None) is not None:
-                d_kvh = self.draft_cfg.n_kv_head
-            else:
-                d_kvh = self.draft_cfg.n_head
-            d_hd = self.draft_cfg.d_model // self.draft_cfg.n_head
             # the draft frontier can run up to K tokens past the target
             # (a fully-accepted round), so its per-seq reservation is
             # K tokens wider
@@ -383,7 +425,8 @@ class LLMEngine:
                                          // cfg.block_size)
             self.kv_d = PagedKVCache(
                 cfg.max_running * self.max_pages_per_seq_d,
-                self.draft_cfg.n_layer, cfg.block_size, d_kvh, d_hd,
+                self.draft_cfg.n_layer, cfg.block_size,
+                rows=self._cache_rows(self.draft_cfg),
                 dtype=jnp.dtype(self.draft_cfg.dtype),
                 lock=_tracing.TimedLock(self._phases, threading.Lock()))
             # verify: one multi-token target forward per batch bucket,
@@ -430,7 +473,17 @@ class LLMEngine:
             # to a prefill-shaped / decode-shaped call and of the outputs
             # fetched to numpy (no K or V: token ids, tables, logits)
             "prefill_link_bytes": 0, "decode_link_bytes": 0,
+            # the `llm.prefill_chunk` phase's total, beside `chunk_steps`
+            "chunk_ms": 0.0,
+            # cached positions the decode steps attended over, summed over
+            # lanes and steps (what a step has to read of the cache)
+            "decode_context_tokens": 0,
         }
+        # what the family's steps count on the device (`step_counts`),
+        # fetched with the logits: decode steps and prefill units apart
+        for name in self._step_counts:
+            self.counters[f"decode_{name}"] = 0
+            self.counters[f"prefill_{name}"] = 0
         # per-bucket compiled_step dispatch counts: (kind, bucket) ->
         # calls. Every entry maps 1:1 onto one AOT executable, so the
         # rows in /metrics show exactly which compiled programs serve
@@ -445,34 +498,40 @@ class LLMEngine:
 
     # -- compiled kernels -------------------------------------------------
 
-    # Each program is the model's step, then `scatter_rows` of the step's
-    # new K/V into the donated arena, which it returns after the logits.
+    # Each program is the model's step, then `scatter_arena` of the step's
+    # new cache rows into the donated arena, which it returns after the
+    # logits (and before the step's counts, where the family has any). The
+    # arena's arrays are `rest[:n]`; a row is a token's where it is written.
 
     def _make_prefill_fn(self, bucket: int, draft: bool = False):
-        mod = self._mod
+        mod, n = self._mod, len(self.kv.arena)
+        counted = bool(self._step_counts)
         cfg = self.draft_cfg if draft else self.model_cfg
 
-        def fn(variables, tokens, true_len, k_pages, v_pages, w_page,
-               w_off):
-            logits, k, v = mod.prefill_step(variables, cfg, tokens,
-                                            true_len)
-            return (logits,) + scatter_rows(k_pages, v_pages, k[0], v[0],
-                                            w_page, w_off)
+        def fn(variables, tokens, true_len, *rest):
+            arena, (w_page, w_off) = rest[:n], rest[n:]
+            logits, *out = mod.prefill_step(
+                variables, cfg, tokens, true_len,
+                **_valid_rows(counted, w_page[None], arena))
+            return (logits,) + scatter_arena(
+                arena, [rows[0] for rows in out[:n]], w_page, w_off) \
+                + tuple(out[n:])
 
         fn.__name__ = f"llm_{'draft_' if draft else ''}prefill_s{bucket}"
         return fn
 
     def _make_decode_fn(self, batch: int, draft: bool = False):
-        mod = self._mod
+        mod, n = self._mod, len(self.kv.arena)
+        counted = bool(self._step_counts)
         cfg = self.draft_cfg if draft else self.model_cfg
 
-        def fn(variables, tokens, positions, k_pages, v_pages,
-               page_table, w_page, w_off):
-            logits, k, v = mod.decode_step(variables, cfg, tokens,
-                                           positions, k_pages, v_pages,
-                                           page_table)
-            return (logits,) + scatter_rows(k_pages, v_pages, k, v,
-                                            w_page, w_off)
+        def fn(variables, tokens, positions, *rest):
+            arena, (page_table, w_page, w_off) = rest[:n], rest[n:]
+            logits, *out = mod.decode_step(
+                variables, cfg, tokens, positions, *arena, page_table,
+                **_valid_rows(counted, w_page, arena))
+            return (logits,) + scatter_arena(arena, out[:n], w_page,
+                                             w_off) + tuple(out[n:])
 
         fn.__name__ = f"llm_{'draft_' if draft else ''}decode_b{batch}"
         return fn
@@ -480,17 +539,18 @@ class LLMEngine:
     def _make_chunk_fn(self, name: str, draft: bool = False):
         """A window of C tokens a lane (chunked prefill, a prefix-cache
         suffix, speculative verify): `w_page` / `w_off` are [B, C]."""
-        mod = self._mod
+        mod, n = self._mod, len(self.kv.arena)
+        counted = bool(self._step_counts)
         cfg = self.draft_cfg if draft else self.model_cfg
 
-        def fn(variables, tokens, start, k_pages, v_pages, page_table,
-               w_page, w_off):
-            logits, k, v = mod.chunk_step(variables, cfg, tokens, start,
-                                          k_pages, v_pages, page_table)
-            rows = (-1,) + k.shape[2:]
-            return (logits,) + scatter_rows(
-                k_pages, v_pages, k.reshape(rows), v.reshape(rows),
-                w_page.reshape(-1), w_off.reshape(-1))
+        def fn(variables, tokens, start, *rest):
+            arena, (page_table, w_page, w_off) = rest[:n], rest[n:]
+            logits, *out = mod.chunk_step(
+                variables, cfg, tokens, start, *arena, page_table,
+                **_valid_rows(counted, w_page, arena))
+            return (logits,) + scatter_arena(
+                arena, [r.reshape((-1,) + r.shape[2:]) for r in out[:n]],
+                w_page.reshape(-1), w_off.reshape(-1)) + tuple(out[n:])
 
         fn.__name__ = name
         return fn
@@ -523,25 +583,48 @@ class LLMEngine:
     def _warm(self, kv: PagedKVCache, params, table_width: int,
               prefill_fns, decode_fns, chunk_fn):
         for s, fn in prefill_fns.items():
-            _, kv.k_pages, kv.v_pages = fn(
+            self._call(fn, (
                 params, np.zeros((1, s), np.int32), np.ones((1,), np.int32),
-                kv.k_pages, kv.v_pages,
-                np.full(s, kv.num_pages, np.int32), np.zeros(s, np.int32))
+                *kv.arena,
+                np.full(s, kv.num_pages, np.int32), np.zeros(s, np.int32)),
+                kv)
         for b, fn in decode_fns.items():
             self._warm_call(kv, fn, (b,), params, table_width)
         self._warm_call(kv, chunk_fn, (1, self._chunk_size), params,
                         table_width)
 
     @staticmethod
-    def _warm_call(kv: PagedKVCache, fn, rows: Tuple[int, ...], params,
+    def _call(fn, args, kv: PagedKVCache):
+        """One call of a program. `args` hold `kv`'s arena, donated: its
+        successor, which follows the logits among the outputs, goes back
+        into `kv`. Returns the logits, still on the device, and what the
+        family's step counted (a tuple, empty for most families)."""
+        out = fn(*args)
+        n = len(kv.arena)
+        kv.arena = tuple(out[1:1 + n])
+        return out[0], tuple(out[1 + n:])
+
+    def _add_step_counts(self, kind: str, counts, link: str) -> None:
+        """Fetch what a step counted on the device (`step_counts` of the
+        family: one small int32 vector) and add it to `<kind>_<name>`."""
+        for vector in counts:
+            vector = np.asarray(vector)
+            with self._lock:
+                self.counters[link] += vector.nbytes
+                for name, n in zip(self._step_counts, vector.tolist()):
+                    self.counters[f"{kind}_{name}"] += n
+
+    @classmethod
+    def _warm_call(cls, kv: PagedKVCache, fn, rows: Tuple[int, ...], params,
                    table_width: int):
         """One decode- or chunk-shaped call: tokens and write coordinates
         are `rows`-shaped, positions and the page table one a lane."""
         b = rows[0]
-        _, kv.k_pages, kv.v_pages = fn(
+        cls._call(fn, (
             params, np.zeros(rows, np.int32), np.zeros(b, np.int32),
-            kv.k_pages, kv.v_pages, np.zeros((b, table_width), np.int32),
-            np.full(rows, kv.num_pages, np.int32), np.zeros(rows, np.int32))
+            *kv.arena, np.zeros((b, table_width), np.int32),
+            np.full(rows, kv.num_pages, np.int32), np.zeros(rows, np.int32)),
+            kv)
 
     # -- submission -------------------------------------------------------
 
@@ -742,6 +825,9 @@ class LLMEngine:
                     emitted = self._prefill_oneshot(seq)
                 else:
                     emitted = self._chunk_advance(seq)
+                    with self._lock:
+                        self.counters["chunk_ms"] += \
+                            (self._phases.total_ns() - mark) / 1e6
             elif self.kv_d is not None and seq.d_prefilled < s:
                 self._draft_prefill_advance(seq)
             req.prefill_ms += (self._phases.total_ns() - mark) / 1e6
@@ -795,9 +881,10 @@ class LLMEngine:
         phase = self._phases.phase
         with phase("prefill_dispatch"):
             self._count_link("prefill_link_bytes", *args)
-            logits, kv.k_pages, kv.v_pages = fn(*args)
+            logits, counts = self._call(fn, args, kv)
         with phase("prefill_device_wait"):
-            self._block_until_ready((logits, kv.k_pages, kv.v_pages))
+            self._block_until_ready((logits, kv.arena))
+            self._add_step_counts("prefill", counts, "prefill_link_bytes")
         return logits
 
     def _prefill_oneshot(self, seq: _Sequence) -> int:
@@ -816,7 +903,7 @@ class LLMEngine:
             next_logits = self._prefill_forward(
                 self._prefill_fns[bucket],
                 (self.params, toks, np.asarray([s], np.int32),
-                 self.kv.k_pages, self.kv.v_pages, w_page, w_off), self.kv)
+                 *self.kv.arena, w_page, w_off), self.kv)
             with phase("prefill_kv_write"):
                 seq.prefilled = s
                 seq.pos = s
@@ -853,7 +940,7 @@ class LLMEngine:
             logits = self._prefill_forward(
                 self._chunk_fn,
                 (self.params, toks, np.asarray([seq.prefilled], np.int32),
-                 self.kv.k_pages, self.kv.v_pages, table,
+                 *self.kv.arena, table,
                  w_page[None], w_off[None]), self.kv)
             with phase("prefill_kv_write"):
                 seq.prefilled += take
@@ -890,7 +977,7 @@ class LLMEngine:
             self._prefill_forward(
                 self._d_prefill_fns[bucket],
                 (self.draft_params, toks, np.asarray([s], np.int32),
-                 self.kv_d.k_pages, self.kv_d.v_pages, w_page, w_off),
+                 *self.kv_d.arena, w_page, w_off),
                 self.kv_d)
             seq.d_prefilled = s
         else:
@@ -909,7 +996,7 @@ class LLMEngine:
                 self._d_chunk_fn,
                 (self.draft_params, toks,
                  np.asarray([seq.d_prefilled], np.int32),
-                 self.kv_d.k_pages, self.kv_d.v_pages, table,
+                 *self.kv_d.arena, table,
                  w_page[None], w_off[None]), self.kv_d)
             seq.d_prefilled += take
         seq.d_pos = seq.d_prefilled
@@ -921,13 +1008,14 @@ class LLMEngine:
         its successor goes back into `kv`."""
         phase = self._phases.phase
         with phase("decode_dispatch"):
-            logits, kv.k_pages, kv.v_pages = fn(*args)
+            logits, counts = self._call(fn, args, kv)
         with phase("decode_device_wait"):
             # the np.asarray below would block on the logits anyway
-            self._block_until_ready((logits, kv.k_pages, kv.v_pages))
+            self._block_until_ready((logits, kv.arena))
         with phase("decode_fetch"):
             logits = np.asarray(logits)
             self._count_link("decode_link_bytes", logits, *args)
+            self._add_step_counts("decode", counts, "decode_link_bytes")
             return logits
 
     def _decode_once(self) -> int:
@@ -958,9 +1046,10 @@ class LLMEngine:
             logits = self._decode_forward(
                 self._decode_fns[bb],
                 (self.params, tokens, positions,
-                 self.kv.k_pages, self.kv.v_pages, page_table,
+                 *self.kv.arena, page_table,
                  w_page, w_off), self.kv)
             with phase("decode_kv_append"):
+                context = int(positions.sum())
                 for seq in runs:
                     seq.pos += 1
             finished = []
@@ -972,6 +1061,7 @@ class LLMEngine:
                         finished.append(seq)
                 with self._lock:
                     self.counters["decode_steps"] += 1
+                    self.counters["decode_context_tokens"] += context
             for seq in finished:
                 self._finish(seq)
             return len(runs)
@@ -1045,7 +1135,7 @@ class LLMEngine:
                 d_logits = self._decode_forward(
                     self._d_decode_fns[bb],
                     (self.draft_params, toks, poss,
-                     self.kv_d.k_pages, self.kv_d.v_pages, d_table,
+                     *self.kv_d.arena, d_table,
                      w_page, w_off), self.kv_d)
                 with phase("decode_kv_append"):
                     for i in active:
@@ -1074,7 +1164,7 @@ class LLMEngine:
             logits = self._decode_forward(
                 self._verify_fns[bb],
                 (self.params, v_toks, v_start,
-                 self.kv.k_pages, self.kv.v_pages, v_table,
+                 *self.kv.arena, v_table,
                  v_page, v_off), self.kv)
             with phase("decode_sample"):
                 tokens_out, finished = self._spec_accept(

@@ -1,13 +1,15 @@
 """Paged KV-cache whose pages live on the device.
 
 vLLM-style paged attention (reference: vllm `block_manager.py` /
-`PagedAttention`): the arena is two device arrays, K and V, of shape
-[num_pages, n_layer, block_size, n_kv_head, head_dim]. The engine's
-compiled programs take both as donated arguments, gather a sequence's
-history through its page-table row, scatter the new K/V rows into their
-pages (`scatter_rows`) and hand the arena back: it is updated in place
-and never crosses the host link. Growing a sequence never moves bytes,
-only appends a page id.
+`PagedAttention`): the arena is a tuple of device arrays, one for each
+kind of row a token leaves in the cache, of shape [num_pages, n_layer,
+block_size, *row]. The model family says what the rows are: K and V of
+[n_kv_head, head_dim] for llama and gpt, one latent of [kv_lora_rank +
+rope] for the MLA family. The engine's compiled programs take the arrays
+as donated arguments, gather a sequence's history through its page-table
+row, scatter the new rows into their pages (`scatter_arena`) and hand the
+arena back: it is updated in place and never crosses the host link.
+Growing a sequence never moves bytes, only appends a page id.
 
 What stays on the host is the control plane: the free list, the holders
 of every page, the prefix cache, and the arena coordinates of the rows a
@@ -51,76 +53,112 @@ class OutOfPagesError(KVCacheError):
     """Allocation would exceed the arena; caller should queue, not crash."""
 
 
-def scatter_rows(k_pages, v_pages, k_rows, v_rows, w_page, w_off):
-    """Row n of `k_rows`/`v_rows` ([N, n_layer, n_kv_head, head_dim]) goes
-    to `pages[w_page[n], :, w_off[n]]`; returns the updated arena. A row
-    whose `w_page` is `num_pages` or more is dropped: nothing is read or
-    written for it, which is how lanes, padding rows and speculative rows
-    that own no page cost nothing. Traced inside the engine's programs,
-    with the arena donated, this is an update in place.
+def scatter_arena(arena, rows, w_page, w_off):
+    """Row n of each of `rows` ([N, n_layer, *row], one array for each
+    array of `arena`) goes to `pages[w_page[n], :, w_off[n]]`; returns the
+    updated arena, a tuple. A row whose `w_page` is `num_pages` or more is
+    dropped: nothing is read or written for it, which is how lanes, padding
+    rows and speculative rows that own no page cost nothing. Traced inside
+    the engine's programs, with the arena donated, this is an update in
+    place.
 
     The layer is an index of its own, so that the scattered unit is one
-    [n_kv_head, head_dim] tile where it lies: indexed by page and offset
-    alone (`.at[w_page, :, w_off]`), a prefill's scatter has the TPU
-    compiler re-lay the whole arena out and back, two copies of it a
-    call (compiled for a described v5e, PR 25)."""
-    at = (w_page[:, None], np.arange(k_pages.shape[1])[None, :],
+    row where it lies: indexed by page and offset alone
+    (`.at[w_page, :, w_off]`), a prefill's scatter has the TPU compiler
+    re-lay the whole arena out and back, two copies of it a call (compiled
+    for a described v5e, PR 25)."""
+    at = (w_page[:, None], np.arange(arena[0].shape[1])[None, :],
           w_off[:, None])
-    k_pages = k_pages.at[at].set(k_rows.astype(k_pages.dtype), mode="drop")
-    v_pages = v_pages.at[at].set(v_rows.astype(v_pages.dtype), mode="drop")
-    return k_pages, v_pages
+    return tuple(pages.at[at].set(new.astype(pages.dtype), mode="drop")
+                 for pages, new in zip(arena, rows))
 
 
 @functools.lru_cache(maxsize=None)
-def _scatter_rows_jit():
-    """`scatter_rows` as a program of its own, for callers that hold K/V
-    themselves (`write_prefill`, `append`); the engine's programs trace
+def _scatter_arena_jit():
+    """`scatter_arena` as a program of its own, for callers that hold the
+    rows themselves (`write_rows`, `append`); the engine's programs trace
     it after the model's step instead. jax is imported on first use: a
     process that only drives replicas imports this module too."""
     import jax
 
-    return jax.jit(scatter_rows, donate_argnums=(0, 1))
+    return jax.jit(scatter_arena, donate_argnums=(0,))
 
 
 class PagedKVCache:
-    """Fixed-size K/V page allocator over an arena on the device.
+    """Fixed-size page allocator over an arena on the device.
 
-    `k_pages` / `v_pages` are device arrays [num_pages, n_layer,
-    block_size, n_kv_head, head_dim] in `dtype`. A compiled program that
-    is given them donated returns their successors, and whoever made the
-    call stores those back here before the next one; the old handles are
-    dead from the call on. `store` is accepted for callers of the host
-    arena this replaced and backs nothing.
+    `arena` is a tuple of device arrays [num_pages, n_layer, block_size,
+    *row] in `dtype`, one for each entry of `rows` (the row shapes; K and
+    V of [n_kv_head, head_dim] when only those two are given, and then
+    `k_pages` / `v_pages` name the two arrays). A compiled program that is
+    given them donated returns their successors, and whoever made the call
+    stores those back here before the next one; the old handles are dead
+    from the call on. `store` is accepted for callers of the host arena
+    this replaced and backs nothing.
     """
 
     def __init__(self, num_pages: int, n_layer: int, block_size: int,
-                 n_kv_head: int, head_dim: int, dtype=np.float32,
-                 store=None, lock=None):
+                 n_kv_head: Optional[int] = None,
+                 head_dim: Optional[int] = None, dtype=np.float32,
+                 store=None, lock=None,
+                 rows: Optional[Tuple[Tuple[int, ...], ...]] = None):
         import jax.numpy as jnp
 
         if num_pages <= 0 or block_size <= 0:
             raise KVCacheError("num_pages and block_size must be positive")
+        if rows is None:
+            rows = ((n_kv_head, head_dim),) * 2
         self.num_pages = num_pages
         self.n_layer = n_layer
         self.block_size = block_size
+        self.rows = tuple(tuple(int(n) for n in row) for row in rows)
+        if n_kv_head is None and len(self.rows) == 2 \
+                and self.rows[0] == self.rows[1] and len(self.rows[0]) == 2:
+            n_kv_head, head_dim = self.rows[0]      # an arena of K and V
         self.n_kv_head = n_kv_head
         self.head_dim = head_dim
         self.dtype = np.dtype(dtype)
         # the engine passes a lock whose waits show in its time ledger
         self._lock = lock if lock is not None else threading.Lock()
-        shape = (num_pages, n_layer, block_size, n_kv_head, head_dim)
+        shapes = [(num_pages, n_layer, block_size) + row
+                  for row in self.rows]
         # from the shape: other threads ask while a step holds the handles
-        self.arena_nbytes = 2 * int(np.prod(shape)) * self.dtype.itemsize
-        self.k_pages = jnp.zeros(shape, self.dtype)
-        self.v_pages = jnp.zeros(shape, self.dtype)
+        self.arena_nbytes = sum(int(np.prod(shape)) for shape in shapes) \
+            * self.dtype.itemsize
+        self.arena = tuple(jnp.zeros(shape, self.dtype) for shape in shapes)
         # LIFO free list: recently-freed pages are re-used first (warm)
         self._free: List[int] = list(range(num_pages - 1, -1, -1))
         # page -> holder list (refcount == len). A holder is a request/
         # sequence object, or a _PrefixEntry when the prefix cache
         # pinned the page for reuse.
         self._holders: Dict[int, List[object]] = {}
+        # page -> how many of its holders are not the prefix cache, for
+        # the pages that have one; kept as the holders change: `metrics()`
+        # asks under the engine's lock, and a walk over 8,192 holder lists
+        # there stalls the pump (seconds under a profiler's Python tracer)
+        self._live: Dict[int, int] = {}
         self._prefix_cache: Optional["PrefixCache"] = None
         self._closed = False
+
+    @property
+    def k_pages(self):
+        return self.arena[0]
+
+    # the arena's handles belong to the single writer (the engine's step
+    # thread), as in `_scatter`; the lock guards the allocator's maps
+    @k_pages.setter
+    def k_pages(self, pages):
+        # raylint: disable=lock-discipline
+        self.arena = (pages,) + self.arena[1:]
+
+    @property
+    def v_pages(self):
+        return self.arena[1]
+
+    @v_pages.setter
+    def v_pages(self, pages):
+        # raylint: disable=lock-discipline
+        self.arena = self.arena[:1] + (pages,) + self.arena[2:]
 
     # -- allocation -------------------------------------------------------
 
@@ -134,16 +172,14 @@ class PagedKVCache:
         """Pages held by at least one sequence (prefix-cache-only pages
         are reusable state, not live work — see `cached_pages`)."""
         with self._lock:
-            return sum(1 for hs in self._holders.values()
-                       if any(not isinstance(h, _PrefixEntry) for h in hs))
+            return len(self._live)
 
     @property
     def cached_pages(self) -> int:
         """Pages held ONLY by the prefix cache (reusable on hit,
         evictable under pressure)."""
         with self._lock:
-            return sum(1 for hs in self._holders.values()
-                       if all(isinstance(h, _PrefixEntry) for h in hs))
+            return len(self._holders) - len(self._live)
 
     def utilization(self) -> float:
         with self._lock:
@@ -175,6 +211,8 @@ class PagedKVCache:
         pages = [self._free.pop() for _ in range(n)]
         for p in pages:
             self._holders[p] = [owner]
+        if not isinstance(owner, _PrefixEntry):
+            self._live.update((p, 1) for p in pages)
         return pages
 
     def share(self, pages: List[int], owner) -> None:
@@ -193,8 +231,11 @@ class PagedKVCache:
             if any(h is owner for h in hs):
                 raise KVCacheError(
                     f"share of page {p} already held by this owner")
+        sequence = not isinstance(owner, _PrefixEntry)
         for p in pages:
             self._holders[p].append(owner)
+            if sequence:
+                self._live[p] = self._live.get(p, 0) + 1
 
     def free(self, pages: List[int], owner) -> None:
         """Release `owner`'s hold on each page; a page returns to the
@@ -212,12 +253,18 @@ class PagedKVCache:
                 held = "free" if hs is None else f"held by {hs!r}"
                 raise KVCacheError(
                     f"free of page {p} not held by owner ({held})")
+        sequence = not isinstance(owner, _PrefixEntry)
         for p in pages:
             hs = self._holders[p]
             for i, h in enumerate(hs):
                 if h is owner:
                     del hs[i]
                     break
+            if sequence:
+                if self._live[p] > 1:
+                    self._live[p] -= 1
+                else:
+                    del self._live[p]
             if not hs:
                 del self._holders[p]
                 self._free.append(p)
@@ -231,7 +278,7 @@ class PagedKVCache:
         row) of positions [start, start + n) of a sequence that holds
         `pages`, padded to `rows` rows. A padding row, and a position
         past the last of `pages`, gets the page id `num_pages`, which
-        `scatter_rows` drops."""
+        `scatter_arena` drops."""
         rows = n if rows is None else rows
         pos = start + np.arange(rows)
         slot = pos // self.block_size
@@ -241,29 +288,36 @@ class PagedKVCache:
         w_page[own] = held[slot[own]]
         return w_page, (pos % self.block_size).astype(np.int32)
 
-    def _scatter(self, k_rows, v_rows, w_page, w_off) -> None:
+    def _scatter(self, rows, w_page, w_off) -> None:
         # data-plane writes are lock-free by design: the engine's step
         # thread is the single writer, and an appendable (tail) page
         # belongs to exactly one sequence — shared prefix pages are
         # always full, so no write ever lands on an aliased page (the
         # lock guards only the allocator maps)
         # raylint: disable=lock-discipline
-        self.k_pages, self.v_pages = _scatter_rows_jit()(
-            self.k_pages, self.v_pages, k_rows, v_rows, w_page, w_off)
+        self.arena = _scatter_arena_jit()(self.arena, tuple(rows), w_page,
+                                          w_off)
 
-    def append(self, pages: List[int], pos: int, k, v) -> None:
-        """Write one token's K/V ([n_layer, n_kv_head, head_dim]) at
-        logical position `pos` of a sequence holding `pages`."""
-        self._scatter(k[None], v[None], *self.write_index(pages, pos, 1))
+    def append(self, pages: List[int], pos: int, *rows) -> None:
+        """Write one token's rows (each [n_layer, *row]; K then V for an
+        arena of those) at logical position `pos` of a sequence holding
+        `pages`."""
+        self._scatter([r[None] for r in rows],
+                      *self.write_index(pages, pos, 1))
+
+    def write_rows(self, pages: List[int], rows, n: int,
+                   start: int = 0) -> None:
+        """Bulk-write a prefill's rows (one array [n, n_layer, *row] for
+        each array of the arena) for positions [start, start+n) across the
+        sequence's pages (chunked prefill passes start > 0, which need not
+        be page-aligned)."""
+        self._scatter([r[:n] for r in rows],
+                      *self.write_index(pages, start, n))
 
     def write_prefill(self, pages: List[int], k_seq, v_seq, n: int,
                       start: int = 0) -> None:
-        """Bulk-write a prefill's K/V ([n, n_layer, n_kv_head,
-        head_dim]) for positions [start, start+n) across the sequence's
-        pages (chunked prefill passes start > 0, which need not be
-        page-aligned)."""
-        self._scatter(k_seq[:n], v_seq[:n],
-                      *self.write_index(pages, start, n))
+        """`write_rows` for an arena of K and V."""
+        self.write_rows(pages, (k_seq, v_seq), n, start)
 
     # -- lifecycle --------------------------------------------------------
 
@@ -274,6 +328,10 @@ class PagedKVCache:
         with self._lock:
             live = {p: hs for p, hs in self._holders.items()
                     if any(not isinstance(h, _PrefixEntry) for h in hs)}
+            if set(live) != set(self._live):
+                raise KVCacheError(
+                    f"live-page count out of step: {len(self._live)} "
+                    f"counted, {len(live)} held by a sequence")
             if live:
                 owners = sorted({repr(h) for hs in live.values()
                                  for h in hs
@@ -295,13 +353,11 @@ class PagedKVCache:
             if self._closed:
                 return 0
             self._closed = True
-            leaked = sum(1 for hs in self._holders.values()
-                         if any(not isinstance(h, _PrefixEntry)
-                                for h in hs))
-            for pages in (self.k_pages, self.v_pages):
+            leaked = len(self._live)
+            for pages in self.arena:
                 if not pages.is_deleted():
                     pages.delete()  # the device memory, now
-            self.k_pages = self.v_pages = None
+            self.arena = ()
             return leaked
 
     def _check_open(self):
@@ -390,7 +446,10 @@ class PrefixCache:
                 raise
             if entry is not None:
                 entry.hits += 1
-                self._entries.move_to_end(entry.key)
+                # making room for the remainder may have evicted the very
+                # entry that was hit (its pages live on under `owner`)
+                if entry.key in self._entries:
+                    self._entries.move_to_end(entry.key)
                 self.counters["hits"] += 1
                 self.counters["hit_tokens"] += k * block
             else:

@@ -129,7 +129,9 @@ def test_engine_program_updates_the_donated_arena_in_place(topo, kind, size):
                        jnp.ones((1, 16), jnp.int32)))
     pages = on_chip((num_pages, cfg.n_layer, block, cfg.n_kv_head,
                      cfg.head_dim), jnp.bfloat16)
-    engine = types.SimpleNamespace(_mod=llama, model_cfg=cfg)
+    engine = types.SimpleNamespace(
+        _mod=llama, model_cfg=cfg, _step_counts=(),
+        kv=types.SimpleNamespace(arena=(pages, pages)))
     if kind == "decode":
         fn = LLMEngine._make_decode_fn(engine, size)
         args = (params, on_chip((size,)), on_chip((size,)), pages, pages,
@@ -147,6 +149,68 @@ def test_engine_program_updates_the_donated_arena_in_place(topo, kind, size):
     moved = [line.strip()[:120] for line in compiled.as_text().splitlines()
              if " copy(" in line and shape in line.split(" copy(")[0]]
     assert not moved, moved
+
+
+@pytest.mark.parametrize("kind, size", [("decode", 16), ("chunk", 1024)])
+def test_latent_arena_is_updated_in_place_at_kimi_k2_widths(topo, kind, size):
+    """The engine's decode-16 and chunk-1,024 programs of the Kimi-K2 cell
+    (published widths, 7 layers, 12 of 384 experts held, 8,192 pages of 16
+    tokens): the one latent arena aliases its output, no operation copies
+    or re-lays out an array of its shape, and the program fits the chip
+    beside its 9.7 GB of weights. The row is 640 wide: at the latent's own
+    576 (four and a half lane tiles) the compiler keeps the arena with the
+    pages innermost and copies all of it to a rows-innermost layout and
+    back around the scatter, which a tile of 1 shows."""
+    import types
+
+    from ray_tpu.models import kimi_k2
+    from ray_tpu.serve.llm.engine import LLMEngine
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    cfg = kimi_k2.KimiK2Config(vocab_size=20480, n_layer=7, experts_held=12,
+                               max_seq_len=8192)
+    block, num_pages = 16, 8192
+
+    def on_chip(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: on_chip(a.shape, a.dtype),
+        jax.eval_shape(kimi_k2.KimiK2(cfg).init, jax.random.PRNGKey(0),
+                       jnp.ones((1, 16), jnp.int32)))
+
+    def compile_at(cfg):
+        (row,) = kimi_k2.cache_rows(cfg)
+        pages = on_chip((num_pages, cfg.n_layer, block) + row, jnp.bfloat16)
+        engine = types.SimpleNamespace(
+            _mod=kimi_k2, model_cfg=cfg, _step_counts=kimi_k2.STEP_COUNTS,
+            kv=types.SimpleNamespace(arena=(pages,)))
+        table = on_chip((size if kind == "decode" else 1,
+                         cfg.max_seq_len // block))
+        if kind == "decode":
+            fn = LLMEngine._make_decode_fn(engine, size)
+            args = (params, on_chip((size,)), on_chip((size,)), pages,
+                    table, on_chip((size,)), on_chip((size,)))
+        else:
+            fn = LLMEngine._make_chunk_fn(engine, f"llm_chunk_c{size}")
+            args = (params, on_chip((1, size)), on_chip((1,)), pages, table,
+                    on_chip((1, size)), on_chip((1, size)))
+        compiled = jax.jit(fn, donate_argnums=(3,)).lower(*args).compile()
+        shape = "bf16[" + ",".join(map(str, pages.shape)) + "]"
+        moved = [line.strip()[:120]
+                 for line in compiled.as_text().splitlines()
+                 if " copy(" in line and shape in line.split(" copy(")[0]]
+        return compiled.memory_analysis(), 2 * math.prod(pages.shape), moved
+
+    mem, arena_bytes, moved = compile_at(cfg)
+    assert mem.alias_size_in_bytes == arena_bytes
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    assert not moved, moved
+    if kind == "decode":
+        with mock.patch.object(kimi_k2, "LANE_TILE", 1):
+            assert kimi_k2.cache_rows(cfg) == ((576,),)
+            _, _, moved = compile_at(cfg)
+        assert moved, "a 576-wide arena is in place now: drop the padding"
 
 
 def test_build_mesh_on_tpu_follows_the_topology(topo):

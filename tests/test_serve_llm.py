@@ -580,6 +580,12 @@ def test_profiler_session_holds_the_engine_phases(ledger_engine, tmp_path):
 
     eng = ledger_engine
     ctxs = [rr.new_context("llm") for _ in range(3)]
+    for ctx in ctxs:
+        # the recorder's ids are 16 hex digits, and one in a few dozen is
+        # all decimal digits but a single `e` ("48542e8800000033"): the
+        # profile's reader hands such a stat back as the float inf, which
+        # failed this test whenever the process's id prefix had that form
+        ctx["req_id"] = "req-" + ctx["req_id"]
     with tracing.device_trace(str(tmp_path / "trace")) as log_dir:
         for i, ctx in enumerate(ctxs):
             with rr.serving(ctx):
@@ -834,3 +840,33 @@ def test_serve_llm_end_to_end(clean_deployments):
     for key in ("ongoing", "queue_depth", "kv_pages_live",
                 "kv_pages_total"):
         assert key in rm
+
+
+def test_serve_llm_end_to_end_with_the_latent_cache_family(clean_deployments):
+    """The Kimi-K2 family through the same door: `build_app(model=...)` ->
+    `serve.run` -> `handle.generate`, chunked prefill and decode over the
+    latent arena in the replica, the tokens a local engine of the same
+    seed gives, and the expert counters in the replica's metrics."""
+    from ray_tpu import serve
+    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
+
+    engine_config = {"batch_buckets": (1, 2), "prefill_buckets": (16,),
+                     "prefill_chunk": 16, "num_pages": 32, "block_size": 8}
+    handle = serve.run(serve.llm.build_app(
+        name="llm", num_replicas=1, model="kimi_k2",
+        engine_config=engine_config))
+    prompt = list(range(3, 40))                      # three chunks of 16
+    streamed = [c["token"] for c in
+                handle.generate.options(stream=True).remote(prompt, 6)]
+    local = LLMEngine(model="kimi_k2",
+                      engine_config=EngineConfig(**engine_config))
+    try:
+        want = local.submit(prompt, 6)
+        local.run_until_idle()
+        assert streamed == want.result()
+    finally:
+        local.shutdown()
+    m = handle.engine_metrics.remote().result(timeout=60)
+    assert m["model"] == "kimi_k2" and m["kv_pages_live"] == 0
+    assert m["chunk_steps"] == 3
+    assert m["decode_moe_pairs_routed"] > 0
