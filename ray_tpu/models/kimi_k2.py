@@ -13,8 +13,9 @@ What it asks of the system that `llama.py` does not:
   the query against the cached latents themselves (the absorbed form:
   `q_nope W_uk^T` against `c_kv`, the output `(P c_kv) W_uv`), so nothing of
   width heads x 256 is made per cached position; `prefill_step` and
-  `chunk_step` expand the latents of the window and of the sequence's pages
-  to per-head keys and values, a group of heads at a time.
+  `chunk_step` expand latents to per-head keys and values, a group of heads
+  and a block of keys at a time under one running softmax: the sequence's
+  cached pages block by block as far as `start` reaches, then the window.
 - The expert layers hold `experts_held` of `n_experts` experts, starting at
   `first_expert`: one chip's share of an expert-parallel deployment
   (`parallel.moe.expert_shard_layer`). The router keeps all its outputs.
@@ -43,11 +44,17 @@ from ray_tpu.parallel.moe import MOE_COUNTS, expert_shard_layer
 
 NEG_INF = -1e30
 # what each step returns after the cache rows, an int32 vector summed over
-# the expert layers: the engine adds it to `decode_moe_*` / `prefill_moe_*`
-STEP_COUNTS = tuple(f"moe_{name}" for name in MOE_COUNTS)
+# the layers: the engine adds it to `decode_<name>` / `prefill_<name>`.
+# `attn_key_slots` is the key slots a query row of each sequence was scored
+# against (cached slots visited, padding among them, and the step's own)
+STEP_COUNTS = tuple(f"moe_{name}" for name in MOE_COUNTS) \
+    + ("attn_key_slots",)
 # heads expanded together in the expanded path: a chunk of 1,024 queries
-# against 9,216 keys is 2.4 GB of float32 scores over 64 heads at once
+# against a block of 1,024 keys is 268 MB of float32 scores over 64 heads
 HEAD_GROUP = 8
+# cached key slots the expanded path visits at a time (whole pages of the
+# sequence's table): a chunk of 1,024 is then one block of the same shape
+KEY_BLOCK = 1024
 # the TPU tiles an array's last axis by this; the cache row is the latent
 # padded with zeros to a multiple of it (see `cache_rows`)
 LANE_TILE = 128
@@ -335,39 +342,95 @@ def attend_absorbed(lp, cfg: KimiK2Config, q_nope, q_rope, lat_cached,
     return out.reshape(out.shape[0], cfg.n_head * cfg.v_head_dim)
 
 
-def attend_expanded(lp, cfg: KimiK2Config, q_nope, q_rope, lat_all, valid):
-    """A window of C tokens against K latents (its sequence's cached ones,
-    then the window's own). q_nope [B, C, H, nope], q_rope [B, C, H, rope];
-    lat_all [B, K, row]; valid [B, C, K]. The latents are expanded to
-    keys and values (`c_kv W_ukv`) for `HEAD_GROUP` heads at a time.
-    Returns [B, C, H * v]."""
+def _fold_block(cfg: KimiK2Config, state, w, q, lat_blk, valid):
+    """One block of K latents folded into the running softmax of every
+    head group (the flash kernel's recurrence, in `jax.numpy`): the
+    latents are expanded to keys and values (`c_kv W_ukv`) for `HEAD_GROUP`
+    heads at a time, so no scores wider than the block are ever held.
+    state = (m, l [G, B, g, C], acc [G, B, g, C, v]), float32: the running
+    maximum, sum and weighted values; w [G, kv_lora, g, nope + v];
+    q [G, B, g, C, nope + rope]; lat_blk [B, K, row]; valid [B, C | 1, K].
+    Once a row's maximum is a real score, a masked key weighs
+    exp(NEG_INF - m) = 0 exactly."""
+    c_blk, k_rope, _ = jnp.split(
+        lat_blk, [cfg.kv_lora_rank, cfg.latent_dim], axis=-1)
+    scale = softmax_scale(cfg)
+    valid = valid[:, None]
+    f32 = jnp.float32
+
+    def group(args):
+        w_g, q_g, m, l, acc = args
+        kv = jnp.einsum("bkc,cgn->bgkn", c_blk, w_g)
+        k_nope, v = jnp.split(kv, [cfg.qk_nope_dim], axis=-1)
+        k = jnp.concatenate([k_nope, jnp.broadcast_to(
+            k_rope[:, None], k_nope.shape[:-1] + k_rope.shape[-1:])], axis=-1)
+        s = jnp.einsum("bgqn,bgkn->bgqk", q_g, k,
+                       preferred_element_type=f32) * scale
+        s = jnp.where(valid, s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(p, axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bgqk,bgkv->bgqv", p.astype(cfg.dtype), v,
+            preferred_element_type=f32)
+        return m_new, l, acc
+
+    return jax.lax.map(group, (w, q) + state)
+
+
+def attend_expanded(lp, cfg: KimiK2Config, q_nope, q_rope, lat, start,
+                    pages, page_table, layer: int):
+    """A window of C tokens causally against its own latents and against
+    its sequence's first `start` cached ones. q_nope [B, C, H, nope],
+    q_rope [B, C, H, rope]; lat [B, C, row] (the window's); start [B];
+    pages [P, L, block, row] and page_table [B, n_pages], or None for no
+    cache. One running softmax (`_fold_block`) over blocks of keys: the
+    window first (a row's own key gives it a real maximum from the start),
+    then the cached slots in blocks of `KEY_BLOCK`, whole pages of the
+    table gathered a block at a time, as far as the block that holds the
+    batch's largest `start` and no further (the mask is each row's own).
+    Returns ([B, C, H * v], the key slots a query row was scored against:
+    int32, C + the loop's trips x the block)."""
     with jax.named_scope("mla_attend"):
         b, c, h, _ = q_nope.shape
         g = HEAD_GROUP if h % HEAD_GROUP == 0 else h
-        c_all, k_rope, _ = jnp.split(
-            lat_all, [cfg.kv_lora_rank, cfg.latent_dim], axis=-1)
-        w = _kv_b(lp, cfg).reshape(cfg.kv_lora_rank, h // g, g, -1)
-        scale = softmax_scale(cfg)
-        f32 = jnp.float32
+        w = jnp.moveaxis(_kv_b(lp, cfg).reshape(
+            cfg.kv_lora_rank, h // g, g, -1), 1, 0)
+        q = jnp.concatenate([q_nope, q_rope], axis=-1)
+        q = q.reshape(b, c, h // g, g, -1).transpose(2, 0, 3, 1, 4)
+        stat = jnp.zeros((h // g, b, g, c), jnp.float32)
+        state = _fold_block(
+            cfg, (stat + NEG_INF, stat,
+                  jnp.zeros(stat.shape + (cfg.v_head_dim,), jnp.float32)),
+            w, q, lat, jnp.tril(jnp.ones((c, c), bool))[None])
+        slots = jnp.int32(c)
+        if pages is not None:
+            n_pages, page = page_table.shape[1], pages.shape[2]
+            per_block = max(1, min(KEY_BLOCK // page, n_pages))
+            k_blk = per_block * page
+            n_blocks = -(-n_pages // per_block)
+            table = jnp.pad(page_table,
+                            ((0, 0), (0, n_blocks * per_block - n_pages)))
 
-        def group(args):
-            w_g, qn, qr = args   # [c, g, nope+v], [B, C, g, nope|rope]
-            kv = jnp.einsum("bkc,cgn->bkgn", c_all, w_g)
-            k_nope, v = jnp.split(kv, [cfg.qk_nope_dim], axis=-1)
-            scores = jnp.einsum("bqgn,bkgn->bgqk", qn, k_nope,
-                                preferred_element_type=f32) \
-                + jnp.einsum("bqgr,bkr->bgqk", qr, k_rope,
-                             preferred_element_type=f32)
-            p = _softmax(scores * scale, valid[:, None, :, :])
-            return jnp.einsum("bgqk,bkgv->bqgv", p.astype(cfg.dtype), v)
+            def cached(j, carry):
+                trips, state = carry
+                ids = jax.lax.dynamic_slice_in_dim(
+                    table, j * per_block, per_block, axis=1)
+                blk = pages[ids, layer].reshape(b, k_blk, -1)
+                seen = (j * k_blk + jnp.arange(k_blk))[None, :] \
+                    < start[:, None]
+                return trips + 1, _fold_block(
+                    cfg, state, w, q, blk.astype(cfg.dtype), seen[:, None])
 
-        def by_group(q):
-            return jnp.moveaxis(q.reshape(b, c, h // g, g, -1), 2, 0)
-
-        out = jax.lax.map(group, (jnp.moveaxis(w, 1, 0), by_group(q_nope),
-                                  by_group(q_rope)))
-        out = jnp.moveaxis(out, 0, 2)          # [B, C, H/g, g, v]
-    return out.reshape(b, c, h * cfg.v_head_dim)
+            trips, state = jax.lax.fori_loop(
+                0, jnp.minimum(-(-jnp.max(start) // k_blk), n_blocks),
+                cached, (jnp.int32(0), state))
+            slots = slots + trips * k_blk
+        _, l, acc = state
+        out = (acc / jnp.maximum(l, 1e-20)[..., None]).astype(cfg.dtype)
+        out = out.transpose(1, 3, 0, 2, 4)          # [B, C, H/g, g, v]
+    return out.reshape(b, c, h * cfg.v_head_dim), slots
 
 
 def _swiglu(x, gate_up, down, dtype):
@@ -411,6 +474,13 @@ def _gather_pages(pages, page_table, i: int):
 
 # -- the three steps ----------------------------------------------------------
 
+def _step_counts(moe_counts, key_slots):
+    """The `STEP_COUNTS` vector: the expert layers' counts, then the key
+    slots the sequences' query rows were scored against, over the layers."""
+    return jnp.concatenate(
+        [moe_counts, jnp.asarray(key_slots, jnp.int32)[None]])
+
+
 def _window_forward(p, cfg: KimiK2Config, tokens, start, pages, page_table,
                     valid_rows):
     """C tokens a sequence from position `start` on, against the cached
@@ -423,29 +493,24 @@ def _window_forward(p, cfg: KimiK2Config, tokens, start, pages, page_table,
                             cfg.max_seq_len - 1)
     cos_t, sin_t = yarn_tables(cfg)
     cos, sin = jnp.asarray(cos_t)[positions], jnp.asarray(sin_t)[positions]
-    valid = jnp.broadcast_to(jnp.tril(jnp.ones((c, c), bool))[None],
-                             (b, c, c))
-    if pages is not None:
-        t_max = page_table.shape[1] * pages.shape[2]
-        cached = jnp.arange(t_max)[None, None, :] < start[:, None, None]
-        valid = jnp.concatenate(
-            [jnp.broadcast_to(cached, (b, c, t_max)), valid], axis=-1)
     flat_valid = None if valid_rows is None else valid_rows.reshape(-1)
     latents, counts = [], jnp.zeros(len(MOE_COUNTS), jnp.int32)
+    key_slots = jnp.int32(0)
     for i in range(cfg.n_layer):
         lp = p[f"layer{i}"]
         h = _rms(x, lp["attn_norm"], cfg.norm_eps, dtype)
         q_nope, q_rope, lat = _project(lp, cfg, h, cos, sin)
-        lat_all = lat if pages is None else jnp.concatenate(
-            [_gather_pages(pages, page_table, i).astype(dtype), lat], axis=1)
-        att = attend_expanded(lp, cfg, q_nope, q_rope, lat_all, valid)
+        att, slots = attend_expanded(lp, cfg, q_nope, q_rope, lat, start,
+                                     pages, page_table, i)
         x = x + att @ lp["attn_out"].astype(dtype)
         h = _rms(x, lp["mlp_norm"], cfg.norm_eps, dtype)
         y, n = feed_forward(lp, cfg, i, h.reshape(b * c, -1), flat_valid)
         x = x + y.reshape(b, c, -1)
         counts = counts + n
+        key_slots = key_slots + b * slots
         latents.append(lat)
-    return _head(p, cfg, x), jnp.stack(latents, axis=2), counts
+    return _head(p, cfg, x), jnp.stack(latents, axis=2), \
+        _step_counts(counts, key_slots)
 
 
 def prefill_step(variables, cfg: KimiK2Config, tokens, true_len,
@@ -504,4 +569,6 @@ def decode_step(variables, cfg: KimiK2Config, tokens, positions, pages,
         x = x + y
         counts = counts + n
         latents.append(lat)
-    return _head(p, cfg, x), jnp.stack(latents, axis=1), counts
+    # every lane of the bucket scores all of its table's slots and itself
+    return _head(p, cfg, x), jnp.stack(latents, axis=1), \
+        _step_counts(counts, cfg.n_layer * x.shape[0] * (t_max + 1))

@@ -478,6 +478,9 @@ class LLMEngine:
             # cached positions the decode steps attended over, summed over
             # lanes and steps (what a step has to read of the cache)
             "decode_context_tokens": 0,
+            # the same for the chunks: a chunk's own tokens and the cached
+            # positions before them (the keys its causal attention needs)
+            "chunk_context_tokens": 0,
         }
         # what the family's steps count on the device (`step_counts`),
         # fetched with the logits: decode steps and prefill units apart
@@ -946,6 +949,7 @@ class LLMEngine:
                 seq.prefilled += take
                 with self._lock:
                     self.counters["chunk_steps"] += 1
+                    self.counters["chunk_context_tokens"] += seq.prefilled
                 if seq.prefilled < s:
                     return 0
                 seq.pos = s

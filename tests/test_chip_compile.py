@@ -9,6 +9,7 @@ Nothing runs: results and times come from `chip_smoke.py` on the chip.
 
 import math
 import os
+import re
 from functools import partial
 from unittest import mock
 
@@ -151,6 +152,27 @@ def test_engine_program_updates_the_donated_arena_in_place(topo, kind, size):
     assert not moved, moved
 
 
+def _materialised(hlo_text):
+    """(dtype, dims) of every array that an instruction outside a fused
+    computation defines: what the program holds in memory, not what a
+    fusion computes on the way."""
+    fused = set(re.findall(r"fusion\([^\n]*calls=(%[\w.\-]+)", hlo_text))
+    out, comp = [], None
+    for line in hlo_text.splitlines():
+        opened = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(.*\{\s*$", line)
+        if opened:
+            comp = opened.group(1)
+        elif line.startswith("}"):
+            comp = None
+        elif comp is not None and comp not in fused and " = " in line:
+            head = line.split(" = ", 1)[1]
+            head = head[:head.index(")") + 1] if head.startswith("(") \
+                else head.split(" ", 1)[0]
+            out += [(m.group(1), tuple(map(int, m.group(2).split(","))))
+                    for m in re.finditer(r"(\w+)\[([0-9,]+)\]", head)]
+    return out
+
+
 @pytest.mark.parametrize("kind, size", [("decode", 16), ("chunk", 1024)])
 def test_latent_arena_is_updated_in_place_at_kimi_k2_widths(topo, kind, size):
     """The engine's decode-16 and chunk-1,024 programs of the Kimi-K2 cell
@@ -160,7 +182,10 @@ def test_latent_arena_is_updated_in_place_at_kimi_k2_widths(topo, kind, size):
     beside its 9.7 GB of weights. The row is 640 wide: at the latent's own
     576 (four and a half lane tiles) the compiler keeps the arena with the
     pages innermost and copies all of it to a rows-innermost layout and
-    back around the scatter, which a tile of 1 shows."""
+    back around the scatter, which a tile of 1 shows. The chunk program
+    walks the cached keys a block at a time: it holds no array over the
+    table's 8,192 slots and the chunk's 1,024 together, and no float32
+    array larger than a head group's scores against one key block."""
     import types
 
     from ray_tpu.models import kimi_k2
@@ -197,19 +222,29 @@ def test_latent_arena_is_updated_in_place_at_kimi_k2_widths(topo, kind, size):
                     on_chip((1, size)), on_chip((1, size)))
         compiled = jax.jit(fn, donate_argnums=(3,)).lower(*args).compile()
         shape = "bf16[" + ",".join(map(str, pages.shape)) + "]"
-        moved = [line.strip()[:120]
-                 for line in compiled.as_text().splitlines()
+        text = compiled.as_text()
+        moved = [line.strip()[:120] for line in text.splitlines()
                  if " copy(" in line and shape in line.split(" copy(")[0]]
-        return compiled.memory_analysis(), 2 * math.prod(pages.shape), moved
+        return compiled.memory_analysis(), 2 * math.prod(pages.shape), \
+            moved, text
 
-    mem, arena_bytes, moved = compile_at(cfg)
+    mem, arena_bytes, moved, text = compile_at(cfg)
     assert mem.alias_size_in_bytes == arena_bytes
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
     assert not moved, moved
+    if kind == "chunk":
+        held = _materialised(text)
+        scores = kimi_k2.HEAD_GROUP * size * kimi_k2.KEY_BLOCK
+        assert ("f32", (kimi_k2.HEAD_GROUP, size, kimi_k2.KEY_BLOCK)) in held
+        padded = cfg.max_seq_len + size
+        assert not [a for a in held if padded in a[1]]
+        assert str(padded) not in text
+        assert not [a for a in held
+                    if a[0] == "f32" and math.prod(a[1]) > scores]
     if kind == "decode":
         with mock.patch.object(kimi_k2, "LANE_TILE", 1):
             assert kimi_k2.cache_rows(cfg) == ((576,),)
-            _, _, moved = compile_at(cfg)
+            _, _, moved, _ = compile_at(cfg)
         assert moved, "a 576-wide arena is in place now: drop the padding"
 
 

@@ -124,35 +124,167 @@ def test_prefill_then_paged_decode_match_the_reference(case, how):
     kv.assert_quiesced()
 
 
-def test_absorbed_attention_is_the_expanded_attention():
-    """One layer's attention of one new token over a cache of 20 latents:
-    scored in the latent space (decode) and with expanded keys and values
-    (prefill, chunks), the same numbers."""
-    cfg = tiny()
+def _paged(rng, cfg, starts, block, table_pages, fill=np.nan):
+    """An arena of `fill` in which each sequence's first `start` slots (of
+    layer 1) hold random latents, behind a shuffled page table; returns
+    (pages [P, L, block, row], table [B, table_pages], the latents
+    [B, table_pages * block, row] a dense form would see: `fill` past
+    `start`)."""
+    b = len(starts)
+    ids = rng.permutation(b * table_pages + 3)[:b * table_pages]
+    table = ids.reshape(b, table_pages).astype(np.int32)
+    dense = np.full((b, table_pages * block, cfg.row_dim), fill, np.float32)
+    for i, start in enumerate(starts):
+        dense[i, :start] = rng.normal(size=(start, cfg.row_dim))
+    dense[..., cfg.latent_dim:] = 0
+    pages = np.full((b * table_pages + 3, cfg.n_layer, block, cfg.row_dim),
+                    fill, np.float32)
+    pages[table, 1] = dense.reshape(b, table_pages, block, cfg.row_dim)
+    return pages, table, dense
+
+
+def _dense_attention(lp, cfg, q_nope, q_rope, lat_all, valid):
+    """The expanded attention with nothing blocked: every head's keys and
+    values of all K latents, one softmax a row. lat_all [B, K, row]; valid
+    [B, C, K]."""
+    w = K._kv_b(lp, cfg)
+    c_all, k_rope = lat_all[..., :cfg.kv_lora_rank], \
+        lat_all[..., cfg.kv_lora_rank:cfg.latent_dim]
+    kv = jnp.einsum("bkc,chn->bkhn", c_all, w)
+    k_nope, v = kv[..., :cfg.qk_nope_dim], kv[..., cfg.qk_nope_dim:]
+    scores = (jnp.einsum("bqhn,bkhn->bhqk", q_nope, k_nope)
+              + jnp.einsum("bqhr,bkr->bhqk", q_rope, k_rope)) \
+        * K.softmax_scale(cfg)
+    p = jax.nn.softmax(jnp.where(valid[:, None], scores, -jnp.inf), axis=-1)
+    out = jnp.einsum("bhqk,bkhv->bqhv", p, v)
+    return out.reshape(out.shape[:2] + (-1,))
+
+
+@pytest.fixture(scope="module")
+def one_layer():
+    """(cfg with a table of four key blocks, layer 1's weights). `kv_b` is
+    scaled up from its initial 0.02: a softmax over a thousand keys that
+    score alike would average any fault away."""
+    cfg = tiny(max_seq_len=4 * K.KEY_BLOCK)
     variables = K.KimiK2(cfg).init(jax.random.PRNGKey(0),
                                    jnp.ones((1, 8), jnp.int32))
     lp = K.unboxed_params(variables)["layer1"]
+    return cfg, {**lp, "kv_b": lp["kv_b"] * 20}
+
+
+def test_absorbed_attention_is_the_expanded_attention(one_layer):
+    """One layer's attention of one new token over a paged cache of up to
+    20 latents: scored in the latent space (decode) and with expanded keys
+    and values (prefill, chunks), the same numbers."""
+    cfg, lp = one_layer
     rng = np.random.default_rng(1)
-    b, t = 3, 20
-    lat_cached = rng.normal(size=(b, t, cfg.row_dim)).astype(np.float32)
-    lat_cached[..., cfg.latent_dim:] = 0
+    lengths = [20, 7, 0]
+    b, block = len(lengths), 4
+    pages, table, lat_cached = _paged(rng, cfg, lengths, block, 5, fill=0.0)
     lat_new = rng.normal(size=(b, cfg.row_dim)).astype(np.float32)
     lat_new[..., cfg.latent_dim:] = 0
     q_nope = rng.normal(size=(b, cfg.n_head, cfg.qk_nope_dim)) \
         .astype(np.float32)
     q_rope = rng.normal(size=(b, cfg.n_head, cfg.qk_rope_dim)) \
         .astype(np.float32)
-    lengths = np.asarray([20, 7, 0])
-    seen = np.concatenate([np.arange(t)[None] < lengths[:, None],
-                           np.ones((b, 1), bool)], axis=1)
+    seen = np.concatenate(
+        [np.arange(20)[None] < np.asarray(lengths)[:, None],
+         np.ones((b, 1), bool)], axis=1)
     with jax.default_matmul_precision("highest"):
         absorbed = K.attend_absorbed(lp, cfg, q_nope, q_rope, lat_cached,
                                      lat_new, seen)
-        expanded = K.attend_expanded(
-            lp, cfg, q_nope[:, None], q_rope[:, None],
-            np.concatenate([lat_cached, lat_new[:, None]], axis=1),
-            seen[:, None, :])[:, 0]
-    np.testing.assert_allclose(absorbed, expanded, atol=1e-5, rtol=1e-4)
+        expanded, slots = K.attend_expanded(
+            lp, cfg, q_nope[:, None], q_rope[:, None], lat_new[:, None],
+            np.asarray(lengths, np.int32), jnp.asarray(pages), table, 1)
+    np.testing.assert_allclose(absorbed, expanded[:, 0], atol=1e-5,
+                               rtol=1e-4)
+    assert int(slots) == 20 + 1
+
+
+@pytest.mark.parametrize("starts", [
+    (0,), (1,), (1023,), (1024,), (1025,), (3500,),
+    (1025, 3), (0, 2049), (3500, 1024)], ids=str)
+def test_blocked_attention_is_the_dense_attention(one_layer, starts):
+    """A window of 8 tokens against `start` cached latents in pages of 16,
+    walked in key blocks of 1,024 under a running softmax, equals one
+    dense softmax over the cached latents and the window. In a batch the
+    trip count is the largest start's and the mask each row's own. Every
+    slot past a sequence's `start` is NaN: the visited block's are masked
+    before they weigh anything, the others never read."""
+    cfg, lp = one_layer
+    rng = np.random.default_rng(sum(starts))
+    b, c, block = len(starts), 8, 16
+    pages, table, cached = _paged(rng, cfg, starts, block,
+                                  cfg.max_seq_len // block)
+    # NaN would poison a sum even under a zero weight: the visited blocks'
+    # slots past `start` are made finite
+    visited = -(-max(starts) // K.KEY_BLOCK)
+    head = table[:, :visited * K.KEY_BLOCK // block]
+    pages[head, 1] = np.nan_to_num(pages[head, 1], nan=1e6)
+    lat = rng.normal(size=(b, c, cfg.row_dim)).astype(np.float32)
+    lat[..., cfg.latent_dim:] = 0
+    q_nope = rng.normal(size=(b, c, cfg.n_head, cfg.qk_nope_dim)) \
+        .astype(np.float32)
+    q_rope = rng.normal(size=(b, c, cfg.n_head, cfg.qk_rope_dim)) \
+        .astype(np.float32)
+    t_max = cached.shape[1]
+    valid = np.concatenate(
+        [np.broadcast_to((np.arange(t_max)[None] < np.asarray(starts)[:, None])
+                         [:, None], (b, c, t_max)),
+         np.broadcast_to(np.tril(np.ones((c, c), bool)), (b, c, c))], axis=-1)
+    with jax.default_matmul_precision("highest"):
+        want = _dense_attention(
+            lp, cfg, q_nope, q_rope,
+            np.concatenate([np.nan_to_num(cached), lat], axis=1), valid)
+        got, slots = jax.jit(K.attend_expanded, static_argnums=(1, 8))(
+            lp, cfg, q_nope, q_rope, lat, np.asarray(starts, np.int32),
+            pages, table, 1)
+    assert np.isfinite(np.asarray(want)).all() and np.std(want) > 0.05
+    np.testing.assert_allclose(got, want, atol=1e-5, rtol=1e-4)
+    assert int(slots) == visited * K.KEY_BLOCK + c
+
+
+def test_key_slots_count_blocks_and_nothing_past_start_is_read(case):
+    """`chunk_step`'s `attn_key_slots` grows with `start` a key block at a
+    time, and the logits do not depend on what lies past `start`: large
+    finite garbage in the rest of the last visited block (masked; a NaN
+    there would poison the sum under its zero weight), NaN in every page
+    of the blocks never visited and every page of no sequence."""
+    cfg, variables, ids, want = case
+    cfg = dataclasses.replace(cfg, max_seq_len=4 * K.KEY_BLOCK)
+    c, block, n = 16, 16, 39
+    kv = PagedKVCache(300, cfg.n_layer, block, rows=K.cache_rows(cfg),
+                      dtype=np.float32)
+    owner = object()
+    pages = kv.alloc(cfg.max_seq_len // block, owner)
+    table = np.asarray([pages], np.int32)
+    per_block = K.KEY_BLOCK // block
+    arena = np.full(kv.arena[0].shape, np.nan, np.float32)
+    arena[pages[:per_block]] = 1e6
+    kv.arena = (jnp.asarray(arena),)
+    step = jax.jit(K.chunk_step, static_argnums=1)
+    slots = {}
+    with jax.default_matmul_precision("highest"):
+        for start in range(0, n, c):
+            take = min(c, n - start)
+            toks = np.zeros((1, c), np.int32)
+            toks[0, :take] = ids[start:start + take]
+            logits, lat, counts = step(
+                variables, cfg, toks, np.asarray([start], np.int32),
+                *kv.arena, table)
+            np.testing.assert_allclose(
+                logits[0, :take], want[start:start + take], atol=ATOL)
+            kv.write_rows(pages, (lat[0],), take, start)
+        toks = np.zeros((1, c), np.int32)
+        for start in (0, 1, 1024, 1025, 2048, 2049, 4096):
+            # past 39 the cache is garbage: only the count is looked at
+            counts = step(variables, cfg, toks,
+                          np.asarray([start], np.int32), *kv.arena, table)[2]
+            slots[start] = int(counts[K.STEP_COUNTS.index("attn_key_slots")])
+    assert slots == {start: cfg.n_layer * (-(-start // K.KEY_BLOCK)
+                                           * K.KEY_BLOCK + c)
+                     for start in slots}
+    kv.free(pages, owner)
 
 
 def _moe_weights(rng, d, f, n_experts):

@@ -323,12 +323,12 @@ def _assert_rows_written(kv, fill, pages, written, block):
             assert same != (pos in written), (pos, same)
 
 
-def _small_engine(**cfg_kw):
+def _small_engine(model="llama", **cfg_kw):
     from ray_tpu.serve.llm import EngineConfig, LLMEngine
     base = dict(batch_buckets=(4,), prefill_buckets=(8, 16), block_size=4,
                 prefix_cache=0)
     base.update(cfg_kw)
-    eng = LLMEngine(model="llama", engine_config=EngineConfig(**base),
+    eng = LLMEngine(model=model, engine_config=EngineConfig(**base),
                     seed=0)
     eng.warmup()
     return eng
@@ -414,11 +414,13 @@ def test_steady_state_compiles_nothing_and_donates_the_arena(llama_engine):
     eng.quiesce()
 
 
-def test_link_counters_hold_ids_tables_and_logits_only():
+@pytest.mark.parametrize("model", ["llama", "kimi_k2"])
+def test_link_counters_hold_ids_tables_and_logits_only(model):
     """`decode_link_bytes` a step and `prefill_link_bytes` a prefill are
     the host arguments' bytes plus the fetched logits', to the byte: no
-    K or V is among them."""
-    eng = _small_engine(prefill_buckets=(16,))
+    K or V is among them. A family that counts on the device
+    (`step_counts`) fetches its vector of int32 counts with them."""
+    eng = _small_engine(model, prefill_buckets=(16,))
     try:
         m0 = eng.metrics()
         assert m0["decode_link_bytes"] == m0["prefill_link_bytes"] == 0
@@ -428,20 +430,52 @@ def test_link_counters_hold_ids_tables_and_logits_only():
         m = eng.metrics()
         vocab_row = eng.model_cfg.vocab_size * 4          # float32 logits
         lanes, bucket, int32 = 4, 16, 4
+        counts = int32 * len(eng._step_counts)
+        assert counts == {"llama": 0, "kimi_k2": 20}[model]
         # token ids, positions, the page table, a page id and an offset a
-        # lane; the lanes' logits back
+        # lane; the lanes' logits (and the step's counts) back
         a_step = lanes * int32 * (4 + eng.max_pages_per_seq) \
-            + lanes * vocab_row
+            + lanes * vocab_row + counts
         # token ids, the true length, a page id and an offset a row; one
         # row of logits back
-        a_prefill = bucket * int32 * 3 + int32 + vocab_row
+        a_prefill = bucket * int32 * 3 + int32 + vocab_row + counts
         assert m["decode_steps"] > 0 and m["prefill_steps"] == 3
         assert m["decode_link_bytes"] == m["decode_steps"] * a_step
         assert m["prefill_link_bytes"] == 3 * a_prefill
-        # a single prompt row's K and V is more than a whole prefill moves
-        kv = eng.kv
-        one_row = 2 * kv.n_layer * kv.n_kv_head * kv.head_dim * 4
+        # a single prompt row's K and V (its latent) is more than a whole
+        # prefill moves
+        one_row = sum(a[0, :, 0].nbytes for a in eng.kv.arena)
         assert a_prefill < 5 * one_row + vocab_row
+        eng.quiesce()
+    finally:
+        assert eng.shutdown() == 0
+
+
+@pytest.mark.parametrize("model", ["llama", "kimi_k2"])
+def test_chunk_context_and_key_slot_counters(model):
+    """Three chunks of 16 over a prompt of 39: `chunk_context_tokens` is
+    the keys the causal mathematics needs (16 + 32 + 39), counted on the
+    host for every family. A family whose steps count (`step_counts`)
+    also says what its programs scored, padding included: Kimi's chunk
+    walks the cached slots a key block at a time (here the whole table of
+    128, under the model's 1,024), its decode step all of them."""
+    eng = _small_engine(model, prefill_buckets=(16,), prefill_chunk=16)
+    try:
+        req = eng.submit(list(range(3, 42)), 4)
+        eng.run_until_idle()
+        assert len(req.tokens) == 4
+        m = eng.metrics()
+        assert m["chunk_steps"] == 3 and m["chunk_context_tokens"] == 87
+        slots = eng.max_pages_per_seq * eng.kv.block_size
+        if model == "llama":
+            assert not [k for k in m if "attn_key_slots" in k]
+        else:
+            layers, lanes = eng.model_cfg.n_layer, 4
+            assert slots == 128
+            assert m["prefill_attn_key_slots"] \
+                == layers * (16 + 2 * (slots + 16))
+            assert m["decode_attn_key_slots"] \
+                == m["decode_steps"] * layers * lanes * (slots + 1)
         eng.quiesce()
     finally:
         assert eng.shutdown() == 0

@@ -17,6 +17,9 @@ def test_task_and_actor_spans(tmp_path):
     os.environ["RAY_TPU_TRACE"] = "1"
     _tracing.refresh()  # read once at import
     os.environ["RAY_TPU_TRACE_DIR"] = trace_dir
+    # a shard that an earlier test of this process left open would take
+    # the driver's spans (as the other tracing tests do)
+    _tracing._reset_writer()
     import ray_tpu
     from ray_tpu.util import tracing
 
@@ -44,6 +47,7 @@ def test_task_and_actor_spans(tmp_path):
         os.environ.pop("RAY_TPU_TRACE", None)
         _tracing.refresh()  # read once at import
         os.environ.pop("RAY_TPU_TRACE_DIR", None)
+        _tracing._reset_writer()
 
     spans = tracing.collect(trace_dir)
     by_name = {}
