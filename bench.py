@@ -1368,7 +1368,7 @@ def bench_serve_llm():
         "phase_ms": phase_ms,
         "phase_sum_over_e2e_p50": round(p50_ratio, 4),
     }
-    # -- shared-prefix + speculative A/B (ISSUE 18) ----------------------
+    # -- shared-prefix A/B (ISSUE 18) ------------------------------------
     ab = _serve_llm_shared_prefix_ab(scale)
     detail["shared_prefix_ab"] = ab["detail"]
     # -- native-intake sub-phase (ISSUE 19) ------------------------------
@@ -1376,35 +1376,29 @@ def bench_serve_llm():
 
     return {
         "serve_llm": detail,
-        # value-keyed: the >15% REGRESSION gate watches all four rates
+        # value-keyed: the >15% REGRESSION gate watches all three rates
         "serve_llm_requests_per_s": n_done / elapsed,
         "serve_llm_tokens_per_s_per_chip":
             m["tokens_generated"] / elapsed / chips,
         "serve_llm_shared_prefix_tokens_per_s":
             ab["cache_tokens_per_s"],
-        "serve_llm_shared_prefix_spec_tokens_per_s":
-            ab["spec_tokens_per_s"],
     }
 
 
 def _serve_llm_shared_prefix_ab(scale: dict) -> dict:
     """Shared-prefix workload A/B (ISSUE 18): every request carries the
     same long prompt prefix with a private suffix — the RAG /
-    system-prompt shape the COW prefix cache exists for. Three arms run
+    system-prompt shape the COW prefix cache exists for. Two arms run
     the IDENTICAL workload in one process:
 
-        base        prefix_cache=0, spec_k=0  (the PR-7 engine)
-        cache       prefix_cache=1, spec_k=0  (COW prefix reuse)
-        cache+spec  prefix_cache=1, spec_k=K  (reuse + speculation)
+        base        prefix_cache=0  (the PR-7 engine)
+        cache       prefix_cache=1  (COW prefix reuse)
 
-    Greedy determinism makes the three token streams comparable: the
+    Greedy determinism makes the two token streams comparable: the
     arms must EMIT identical tokens (asserted), so tokens/s is an
-    apples-to-apples rate. The spec arm self-drafts (draft == target
-    weights) — accept length is always K, the upper bound of the
-    speculative win; a production draft supplies its own accept rate.
-    Zero retraces and zero leaked pages are hard gates in every arm.
-    Shape knobs via RAY_TPU_SCALE_SIZES: llm_prefix=96,llm_suffix=16,
-    llm_ab_requests=48,llm_ab_clients=4,llm_spec_k=4."""
+    apples-to-apples rate. Zero retraces and zero leaked pages are hard
+    gates in both arms. Shape knobs via RAY_TPU_SCALE_SIZES:
+    llm_prefix=96,llm_suffix=16,llm_ab_requests=48,llm_ab_clients=4."""
     import numpy as np
 
     from ray_tpu import parallel
@@ -1415,7 +1409,6 @@ def _serve_llm_shared_prefix_ab(scale: dict) -> dict:
     suffix_len = scale.get("llm_suffix", 16)
     n_requests = scale.get("llm_ab_requests", 48)
     n_clients = scale.get("llm_ab_clients", 4)
-    spec_k = scale.get("llm_spec_k", 4)
     max_new = 8
 
     rng = np.random.RandomState(7)
@@ -1428,16 +1421,13 @@ def _serve_llm_shared_prefix_ab(scale: dict) -> dict:
     # ONLY the private suffix, in one suffix-sized chunk (without it
     # the suffix pads to the widest prefill bucket and the win drowns)
     arms = {
-        "base": dict(prefix_cache=0, spec_k=0),
-        "cache": dict(prefix_cache=1, spec_k=0,
-                      prefill_chunk=suffix_len),
-        "cache_spec": dict(prefix_cache=1, spec_k=spec_k,
-                           prefill_chunk=suffix_len),
+        "base": dict(prefix_cache=0),
+        "cache": dict(prefix_cache=1, prefill_chunk=suffix_len),
     }
     out_detail: dict = {
         "prefix_tokens": prefix_len, "suffix_tokens": suffix_len,
         "requests_per_arm": n_requests, "clients": n_clients,
-        "spec_k": spec_k, "max_new_tokens": max_new,
+        "max_new_tokens": max_new,
     }
     emitted: dict = {}
     rates: dict = {}
@@ -1512,27 +1502,16 @@ def _serve_llm_shared_prefix_ab(scale: dict) -> dict:
             arm_detail["prefix_cache_hit_rate"] = round(
                 hit / (hit + miss), 4) if hit + miss else 0.0
             arm_detail["prefix_cache_hit_tokens"] = int(hit)
-        if knobs.get("spec_k"):
-            arm_detail["spec_mean_accept"] = round(
-                m["spec_accepted"] / m["spec_rounds"], 3) \
-                if m["spec_rounds"] else None
-            arm_detail["spec_proposed"] = int(m["spec_proposed"])
-            arm_detail["spec_accepted"] = int(m["spec_accepted"])
         out_detail[arm] = arm_detail
     rr.set_enabled(rec_was_enabled)
 
-    # greedy determinism: all three arms emit the SAME streams
-    for arm in ("cache", "cache_spec"):
-        if emitted[arm] != emitted["base"]:
-            raise RuntimeError(
-                f"{arm} arm diverged from plain greedy output")
+    # greedy determinism: both arms emit the SAME streams
+    if emitted["cache"] != emitted["base"]:
+        raise RuntimeError("cache arm diverged from plain greedy output")
 
     ncpu = os.cpu_count() or 1
-    best = max(rates["cache"], rates["cache_spec"])
     out_detail["speedup_cache"] = round(rates["cache"] / rates["base"], 3)
-    out_detail["speedup_cache_spec"] = round(
-        rates["cache_spec"] / rates["base"], 3)
-    out_detail["two_x_target_met"] = best >= 2.0 * rates["base"]
+    out_detail["two_x_target_met"] = rates["cache"] >= 2.0 * rates["base"]
     if not out_detail["two_x_target_met"] and ncpu <= 2:
         # the 2x acceptance target assumes real accelerator decode
         # (prefill FLOPs dominate); on the 1-core CPU box dispatch
@@ -1543,7 +1522,6 @@ def _serve_llm_shared_prefix_ab(scale: dict) -> dict:
     return {
         "detail": out_detail,
         "cache_tokens_per_s": rates["cache"],
-        "spec_tokens_per_s": rates["cache_spec"],
     }
 
 

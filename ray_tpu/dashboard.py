@@ -331,20 +331,14 @@ class Dashboard:
                 rows = [r for r in rows if "kv_pages_total" in r]
                 if rows:
                     entry = {"deployment": name, "replicas": rows}
-                    # perf rollups across replicas: prefix-cache hit
-                    # rate and mean speculative accept length (absent
-                    # unless the engines run with those knobs on)
+                    # the roll-up across replicas: the prefix cache's
+                    # hit rate (absent where the engines run without it)
                     hit_rates = [r["prefix_cache_hit_rate"]
                                  for r in rows
                                  if "prefix_cache_hit_rate" in r]
                     if hit_rates:
                         entry["prefix_cache_hit_rate"] = (
                             sum(hit_rates) / len(hit_rates))
-                    accepts = [r["spec_mean_accept"] for r in rows
-                               if "spec_mean_accept" in r]
-                    if accepts:
-                        entry["spec_mean_accept"] = (
-                            sum(accepts) / len(accepts))
                     out.append(entry)
             return {"deployments": out}
 

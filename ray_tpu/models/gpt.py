@@ -319,7 +319,7 @@ def decode_step(variables, cfg: GPTConfig, tokens, positions,
 def chunk_step(variables, cfg: GPTConfig, tokens, start,
                k_pages, v_pages, page_table):
     """Forward C tokens per sequence against a paged cache (chunked
-    prefill / speculative verify). Shapes as in `llama.chunk_step`."""
+    prefill, a prefix-cache suffix). Shapes as in `llama.chunk_step`."""
     from ray_tpu.models.llama import (  # avoids import cycle
         chunk_valid_mask, paged_attend_chunk)
 

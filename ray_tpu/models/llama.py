@@ -314,8 +314,8 @@ def paged_attend_chunk(q, k_new, v_new, k_pages_l, v_pages_l, page_table,
     [B, n_pages]; valid: [B, C, T+C] key mask per query position
     (cached positions < that query's global position, plus the causal
     triangle inside the window). Same math as `paged_attend` — C=1
-    reduces to it exactly, which is what makes chunked prefill and
-    speculative verify logit-identical to the one-shot paths.
+    reduces to it exactly, which is what makes chunked prefill
+    logit-identical to the one-shot path.
     """
     b, c, h, d = q.shape
     kvh = k_new.shape[2]
@@ -354,11 +354,11 @@ def chunk_valid_mask(start, positions, c: int, t_max: int):
 def chunk_step(variables, cfg: LlamaConfig, tokens, start,
                k_pages, v_pages, page_table):
     """Forward C tokens per sequence against a paged cache holding each
-    sequence's first `start` positions. One kernel serves two callers:
+    sequence's first `start` positions. The engine calls it at B=1 for
     chunked prefill (the prompt arrives in fixed-size windows
-    interleaved with decode steps) and speculative verify (the window
-    is [last_committed, draft_1..draft_K] and the caller reads a logit
-    row per position).
+    interleaved with decode steps) and for the suffix of a prompt whose
+    prefix the cache held; the caller reads the logit row of the
+    prompt's last token.
 
     tokens: [B, C]; start: [B] tokens already cached per sequence;
     k_pages/v_pages: [P, L, block, KVH, D]; page_table: [B, n_pages].

@@ -3,7 +3,7 @@
 Paged KV-cache on the device (`kv_cache.py`) + continuous-batching engine on the
 AOT compile cache (`engine.py`) + a Serve deployment streaming tokens
 over `handle_request_streaming` (`deployment.py`). See the README
-"Inference plane" section for the engine loop and env knobs.
+"Inference plane" section for the engine loop and `EngineConfig`.
 """
 
 from ray_tpu.serve.llm.kv_cache import (

@@ -35,25 +35,14 @@ class LLMDeployment:
     def __init__(self, model: str = "llama",
                  model_config: Optional[Dict[str, Any]] = None,
                  engine_config: Optional[Dict[str, Any]] = None,
-                 draft_config: Optional[Dict[str, Any]] = None,
                  seed: int = 0):
         from ray_tpu.serve.llm.engine import (EngineConfig, LLMEngine,
                                               model_family)
 
         model_cfg = None
-        draft_cfg = None
-        if model_config or draft_config:
-            family, mod = model_family(model)
-            _Cfg = getattr(mod, family.config)
         if model_config:
-            model_cfg = _Cfg(**model_config)
-        if draft_config:
-            # a small same-family draft for speculative decoding (its
-            # weights init replica-side from `seed`, like the target's,
-            # so every replica drafts identically — the replay
-            # determinism contract extends to speculation); without
-            # this, spec_k > 0 self-drafts with the target weights
-            draft_cfg = _Cfg(**draft_config)
+            family, mod = model_family(model)
+            model_cfg = getattr(mod, family.config)(**model_config)
         from ray_tpu._private.object_ref import get_core_worker
 
         cw = get_core_worker()
@@ -64,7 +53,7 @@ class LLMDeployment:
         self.engine = LLMEngine(
             model=model, model_cfg=model_cfg,
             engine_config=EngineConfig(**(engine_config or {})),
-            seed=seed, draft_cfg=draft_cfg)
+            seed=seed)
         t0 = time.perf_counter()
         self.engine.warmup()
         self._warmup_s = time.perf_counter() - t0
@@ -129,17 +118,14 @@ class LLMDeployment:
             "kv_pages_cached": float(m.get("kv_pages_cached", 0)),
             "kv_pages_total": float(m["kv_pages_total"]),
         }
-        # perf-plane rollups for the dashboard /api/serve_llm panel:
-        # prefix-cache hit rate and mean speculative accept length
+        # the roll-up for the dashboard's /api/serve_llm panel: the
+        # prefix cache's hit rate
         hit = m.get("prefix_cache_hit_tokens")
         if hit is not None:
             total = hit + m.get("prefix_cache_miss_tokens", 0)
             out["prefix_cache_hit_rate"] = hit / total if total else 0.0
             out["prefix_cache_entries"] = float(
                 m.get("prefix_cache_entries", 0))
-        if m.get("spec_k"):
-            out["spec_k"] = float(m["spec_k"])
-            out["spec_mean_accept"] = float(m.get("spec_mean_accept", 0.0))
         return out
 
     def engine_metrics(self) -> Dict[str, Any]:

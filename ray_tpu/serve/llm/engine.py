@@ -1,12 +1,12 @@
 """Continuous-batching LLM engine on the AOT compile cache.
 
 Orca-style iteration-level scheduling (reference: Orca OSDI'22, vllm
-`llm_engine.py`): every `step()` interleaves at most
-`max_prefills_per_step` prompt prefills with one decode iteration over
-the whole running set. Sequences join and leave the decode batch
-*between* steps — a finished sequence frees its KV pages immediately and
-the next step simply assembles a smaller batch; no request ever waits
-for a batch-mate to finish.
+`llm_engine.py`): every `step()` admits at most one request, advances
+one prompt's prefill by one unit (a whole bucket or one chunk) and runs
+one decode iteration over the whole running set. Sequences join and
+leave the decode batch *between* steps — a finished sequence frees its
+KV pages immediately and the next step simply assembles a smaller
+batch; no request ever waits for a batch-mate to finish.
 
 Shape discipline is what makes this serveable on TPU: prompts pad into a
 small set of prefill buckets and the decode batch pads into a small set
@@ -33,7 +33,6 @@ from __future__ import annotations
 import dataclasses
 import importlib
 import itertools
-import os
 import queue
 import threading
 import time
@@ -49,68 +48,45 @@ from ray_tpu.util import step_profiler as _sp
 from ray_tpu.util import tracing as _tracing
 
 
-def _env_int(name: str, default: int) -> int:
-    try:
-        return int(os.environ.get(name, default))
-    except ValueError:
-        return default
-
-
-def _env_tuple(name: str, default: Tuple[int, ...]) -> Tuple[int, ...]:
-    raw = os.environ.get(name)
-    if not raw:
-        return default
-    return tuple(sorted(int(x) for x in raw.split(",") if x.strip()))
-
-
 @dataclasses.dataclass(frozen=True)
 class EngineConfig:
-    """Scheduler + cache knobs (env-overridable; see README)."""
+    """Scheduler and cache options: these fields are their only source."""
 
-    block_size: int = 0            # RAY_TPU_LLM_BLOCK_SIZE (default 16)
+    block_size: int = 16
     num_pages: int = 0             # 0 -> worst case for max_running
-    batch_buckets: Tuple[int, ...] = ()    # RAY_TPU_LLM_BATCH_BUCKETS
-    prefill_buckets: Tuple[int, ...] = ()  # RAY_TPU_LLM_PREFILL_BUCKETS
-    max_running: int = 0           # RAY_TPU_LLM_MAX_RUNNING
-    max_prefills_per_step: int = 1
+    batch_buckets: Tuple[int, ...] = (1, 2, 4, 8)
+    prefill_buckets: Tuple[int, ...] = (16, 32, 64, 128)
+    max_running: int = 0           # 0 -> the largest batch bucket
     eos_token: Optional[int] = None
-    # copy-on-write shared-prefix page reuse (RAY_TPU_LLM_PREFIX_CACHE,
-    # default on; -1 = unset)
-    prefix_cache: int = -1
-    # chunked prefill window (RAY_TPU_LLM_PREFILL_CHUNK, 0 = off: long
-    # prompts then stay capped at the largest prefill bucket)
-    prefill_chunk: int = -1
-    # speculative decoding draft length K (RAY_TPU_LLM_SPEC_K, 0 = off)
-    spec_k: int = -1
+    # copy-on-write shared-prefix page reuse
+    prefix_cache: int = 1
+    # chunked prefill window (0 = off: long prompts then stay capped at
+    # the largest prefill bucket)
+    prefill_chunk: int = 0
+    # accepted at 0 and refused at any other value: speculative decoding
+    # is gone, and files under benchmark/ still pass `"spec_k": 0`
+    # (ROADMAP D2)
+    spec_k: int = 0
 
     def resolved(self, max_seq_len: int) -> "EngineConfig":
-        block = self.block_size or _env_int("RAY_TPU_LLM_BLOCK_SIZE", 16)
-        batch = self.batch_buckets or _env_tuple(
-            "RAY_TPU_LLM_BATCH_BUCKETS", (1, 2, 4, 8))
-        prefill = self.prefill_buckets or _env_tuple(
-            "RAY_TPU_LLM_PREFILL_BUCKETS", (16, 32, 64, 128))
-        prefill = tuple(s for s in prefill if s <= max_seq_len) or \
-            (max_seq_len,)
-        max_running = self.max_running or _env_int(
-            "RAY_TPU_LLM_MAX_RUNNING", max(batch))
-        max_running = min(max_running, max(batch))
-        pages_per_seq = -(-max_seq_len // block)
-        num_pages = self.num_pages or max_running * pages_per_seq
-        prefix = self.prefix_cache
-        if prefix < 0:
-            prefix = _env_int("RAY_TPU_LLM_PREFIX_CACHE", 1)
-        chunk = self.prefill_chunk
-        if chunk < 0:
-            chunk = _env_int("RAY_TPU_LLM_PREFILL_CHUNK", 0)
-        chunk = min(chunk, max_seq_len)
-        spec = self.spec_k
-        if spec < 0:
-            spec = _env_int("RAY_TPU_LLM_SPEC_K", 0)
+        """What the engine derives from the model: the prefill buckets and
+        the chunk clipped to `max_seq_len`, a lane for each row of the
+        largest batch bucket, pages for every lane's worst case."""
+        if self.spec_k:
+            raise ValueError(
+                f"spec_k={self.spec_k}: the engine has one decode path "
+                f"(spec_k is accepted only at 0)")
+        batch = tuple(self.batch_buckets)
+        prefill = tuple(s for s in self.prefill_buckets
+                        if s <= max_seq_len) or (max_seq_len,)
+        max_running = min(self.max_running or max(batch), max(batch))
+        pages_per_seq = -(-max_seq_len // self.block_size)
         return dataclasses.replace(
-            self, block_size=block, num_pages=num_pages,
-            batch_buckets=batch, prefill_buckets=prefill,
-            max_running=max_running, prefix_cache=int(bool(prefix)),
-            prefill_chunk=max(0, chunk), spec_k=max(0, spec))
+            self, batch_buckets=batch, prefill_buckets=prefill,
+            max_running=max_running,
+            num_pages=self.num_pages or max_running * pages_per_seq,
+            prefix_cache=int(bool(self.prefix_cache)),
+            prefill_chunk=max(0, min(self.prefill_chunk, max_seq_len)))
 
 
 class RequestRejected(RuntimeError):
@@ -291,28 +267,20 @@ class Request:
 class _Sequence:
     """A running request's decode state.
 
-    `pos` is the number of tokens in the TARGET KV cache (= prompt +
+    `pos` is the number of tokens in the KV cache (= prompt +
     generated - 1 in steady state: the newest token rides as the next
     dispatch's input). `prefilled`/`cached` track the chunked-prefill
-    frontier (prefilled starts at the prefix-cache hit length);
-    `d_pages`/`d_prefilled`/`d_pos` are the draft model's mirror state
-    when speculative decoding is on — `d_pos` is the draft cache
-    frontier, which can lag `pos` by at most one token after a
-    fully-accepted round (the catch-up loop closes the gap)."""
+    frontier (prefilled starts at the prefix-cache hit length)."""
 
-    __slots__ = ("req", "pages", "pos", "prefilled", "cached",
-                 "d_pages", "d_prefilled", "d_pos")
+    __slots__ = ("req", "pages", "pos", "prefilled", "cached")
 
     def __init__(self, req: Request, pages: List[int], pos: int,
-                 cached: int = 0, d_pages: Optional[List[int]] = None):
+                 cached: int = 0):
         self.req = req
         self.pages = pages
         self.pos = pos  # tokens already written to the KV cache
         self.prefilled = pos or cached
         self.cached = cached
-        self.d_pages = d_pages
-        self.d_prefilled = 0
-        self.d_pos = 0
 
     @property
     def last_token(self) -> int:
@@ -336,8 +304,7 @@ class LLMEngine:
 
     def __init__(self, model: str = "llama", model_cfg=None, params=None,
                  engine_config: Optional[EngineConfig] = None,
-                 store=None, seed: int = 0,
-                 draft_cfg=None, draft_params=None):
+                 store=None, seed: int = 0):
         import jax
         import jax.numpy as jnp
         from ray_tpu.parallel import compiled_step
@@ -396,55 +363,7 @@ class LLMEngine:
         # prefill windows and prefix-cache-hit suffixes: every window
         # pads to the same width, so a chunk is a bucket by construction
         self._chunk_size = cfg.prefill_chunk or max(cfg.prefill_buckets)
-        self._chunk_fn = program(
-            self._make_chunk_fn(f"llm_chunk_c{self._chunk_size}"))
-
-        # speculative decoding: the draft model defaults to the target
-        # itself (self-draft — the 1-core build box's determinism rig);
-        # a real deployment passes a small draft_cfg + draft_params of
-        # the SAME family (vocab/max_seq_len must match the target)
-        self.draft_cfg = None
-        self.draft_params = None
-        self.kv_d: Optional[PagedKVCache] = None
-        if cfg.spec_k > 0:
-            self.draft_cfg = draft_cfg or self.model_cfg
-            if draft_params is not None:
-                self.draft_params = draft_params
-            elif draft_cfg is None:
-                self.draft_params = self.params  # self-draft
-            else:
-                net = getattr(mod, family.net)(self.draft_cfg)
-                self.draft_params = net.init(
-                    jax.random.PRNGKey(seed + 1),
-                    jnp.ones((1, min(cfg.prefill_buckets)), jnp.int32))
-            # the draft frontier can run up to K tokens past the target
-            # (a fully-accepted round), so its per-seq reservation is
-            # K tokens wider
-            self.max_pages_per_seq_d = -(-(self.model_cfg.max_seq_len
-                                           + cfg.spec_k)
-                                         // cfg.block_size)
-            self.kv_d = PagedKVCache(
-                cfg.max_running * self.max_pages_per_seq_d,
-                self.draft_cfg.n_layer, cfg.block_size,
-                rows=self._cache_rows(self.draft_cfg),
-                dtype=jnp.dtype(self.draft_cfg.dtype),
-                lock=_tracing.TimedLock(self._phases, threading.Lock()))
-            # verify: one multi-token target forward per batch bucket,
-            # window C = K+1 ([last_committed, draft_1..draft_K]) — the
-            # accept length varies per round but the window never does,
-            # so accept-length variation can't retrace by construction
-            self._verify_fns = {
-                b: program(self._make_chunk_fn(
-                    f"llm_verify_b{b}_c{cfg.spec_k + 1}"))
-                for b in cfg.batch_buckets}
-            self._d_decode_fns = {
-                b: program(self._make_decode_fn(b, draft=True))
-                for b in cfg.batch_buckets}
-            self._d_prefill_fns = {
-                s: program(self._make_prefill_fn(s, draft=True))
-                for s in cfg.prefill_buckets}
-            self._d_chunk_fn = program(self._make_chunk_fn(
-                f"llm_draft_chunk_c{self._chunk_size}", draft=True))
+        self._chunk_fn = program(self._make_chunk_fn(self._chunk_size))
 
         self._waiting: List[Request] = []
         self._prefilling: List[_Sequence] = []
@@ -467,8 +386,7 @@ class LLMEngine:
             "requests_failed": 0, "requests_timed_out": 0,
             "tokens_generated": 0, "prefill_steps": 0,
             "decode_steps": 0, "prefill_ms": 0.0, "decode_ms": 0.0,
-            "chunk_steps": 0, "spec_rounds": 0,
-            "spec_proposed": 0, "spec_accepted": 0,
+            "chunk_steps": 0,
             # what crosses the host link: bytes of the host arrays handed
             # to a prefill-shaped / decode-shaped call and of the outputs
             # fetched to numpy (no K or V: token ids, tables, logits)
@@ -506,10 +424,10 @@ class LLMEngine:
     # logits (and before the step's counts, where the family has any). The
     # arena's arrays are `rest[:n]`; a row is a token's where it is written.
 
-    def _make_prefill_fn(self, bucket: int, draft: bool = False):
+    def _make_prefill_fn(self, bucket: int):
         mod, n = self._mod, len(self.kv.arena)
         counted = bool(self._step_counts)
-        cfg = self.draft_cfg if draft else self.model_cfg
+        cfg = self.model_cfg
 
         def fn(variables, tokens, true_len, *rest):
             arena, (w_page, w_off) = rest[:n], rest[n:]
@@ -520,13 +438,13 @@ class LLMEngine:
                 arena, [rows[0] for rows in out[:n]], w_page, w_off) \
                 + tuple(out[n:])
 
-        fn.__name__ = f"llm_{'draft_' if draft else ''}prefill_s{bucket}"
+        fn.__name__ = f"llm_prefill_s{bucket}"
         return fn
 
-    def _make_decode_fn(self, batch: int, draft: bool = False):
+    def _make_decode_fn(self, batch: int):
         mod, n = self._mod, len(self.kv.arena)
         counted = bool(self._step_counts)
-        cfg = self.draft_cfg if draft else self.model_cfg
+        cfg = self.model_cfg
 
         def fn(variables, tokens, positions, *rest):
             arena, (page_table, w_page, w_off) = rest[:n], rest[n:]
@@ -536,15 +454,15 @@ class LLMEngine:
             return (logits,) + scatter_arena(arena, out[:n], w_page,
                                              w_off) + tuple(out[n:])
 
-        fn.__name__ = f"llm_{'draft_' if draft else ''}decode_b{batch}"
+        fn.__name__ = f"llm_decode_b{batch}"
         return fn
 
-    def _make_chunk_fn(self, name: str, draft: bool = False):
-        """A window of C tokens a lane (chunked prefill, a prefix-cache
-        suffix, speculative verify): `w_page` / `w_off` are [B, C]."""
+    def _make_chunk_fn(self, size: int):
+        """A window of `size` tokens of one sequence (chunked prefill, a
+        prefix-cache suffix): `w_page` / `w_off` are [1, size]."""
         mod, n = self._mod, len(self.kv.arena)
         counted = bool(self._step_counts)
-        cfg = self.draft_cfg if draft else self.model_cfg
+        cfg = self.model_cfg
 
         def fn(variables, tokens, start, *rest):
             arena, (page_table, w_page, w_off) = rest[:n], rest[n:]
@@ -555,7 +473,7 @@ class LLMEngine:
                 arena, [r.reshape((-1,) + r.shape[2:]) for r in out[:n]],
                 w_page.reshape(-1), w_off.reshape(-1)) + tuple(out[n:])
 
-        fn.__name__ = name
+        fn.__name__ = f"llm_chunk_c{size}"
         return fn
 
     def _note_call(self, kind: str, bucket: int):
@@ -572,39 +490,24 @@ class LLMEngine:
         leaf avals including placement: numpy for what the host makes,
         the device arena donated) and write nothing: every row's page id
         is the dropped one."""
-        self._warm(self.kv, self.params, self.max_pages_per_seq,
-                   self._prefill_fns, self._decode_fns, self._chunk_fn)
-        if self.kv_d is None:
-            return
-        self._warm(self.kv_d, self.draft_params, self.max_pages_per_seq_d,
-                   self._d_prefill_fns, self._d_decode_fns,
-                   self._d_chunk_fn)
-        for b, fn in self._verify_fns.items():
-            self._warm_call(self.kv, fn, (b, self.config.spec_k + 1),
-                            self.params, self.max_pages_per_seq)
-
-    def _warm(self, kv: PagedKVCache, params, table_width: int,
-              prefill_fns, decode_fns, chunk_fn):
-        for s, fn in prefill_fns.items():
+        kv = self.kv
+        for s, fn in self._prefill_fns.items():
             self._call(fn, (
-                params, np.zeros((1, s), np.int32), np.ones((1,), np.int32),
-                *kv.arena,
-                np.full(s, kv.num_pages, np.int32), np.zeros(s, np.int32)),
-                kv)
-        for b, fn in decode_fns.items():
-            self._warm_call(kv, fn, (b,), params, table_width)
-        self._warm_call(kv, chunk_fn, (1, self._chunk_size), params,
-                        table_width)
+                self.params, np.zeros((1, s), np.int32),
+                np.ones((1,), np.int32), *kv.arena,
+                np.full(s, kv.num_pages, np.int32), np.zeros(s, np.int32)))
+        for b, fn in self._decode_fns.items():
+            self._warm_call(fn, (b,))
+        self._warm_call(self._chunk_fn, (1, self._chunk_size))
 
-    @staticmethod
-    def _call(fn, args, kv: PagedKVCache):
-        """One call of a program. `args` hold `kv`'s arena, donated: its
+    def _call(self, fn, args):
+        """One call of a program. `args` hold the arena, donated: its
         successor, which follows the logits among the outputs, goes back
-        into `kv`. Returns the logits, still on the device, and what the
-        family's step counted (a tuple, empty for most families)."""
+        into `self.kv`. Returns the logits, still on the device, and what
+        the family's step counted (a tuple, empty for most families)."""
         out = fn(*args)
-        n = len(kv.arena)
-        kv.arena = tuple(out[1:1 + n])
+        n = len(self.kv.arena)
+        self.kv.arena = tuple(out[1:1 + n])
         return out[0], tuple(out[1 + n:])
 
     def _add_step_counts(self, kind: str, counts, link: str) -> None:
@@ -617,17 +520,14 @@ class LLMEngine:
                 for name, n in zip(self._step_counts, vector.tolist()):
                     self.counters[f"{kind}_{name}"] += n
 
-    @classmethod
-    def _warm_call(cls, kv: PagedKVCache, fn, rows: Tuple[int, ...], params,
-                   table_width: int):
+    def _warm_call(self, fn, rows: Tuple[int, ...]):
         """One decode- or chunk-shaped call: tokens and write coordinates
         are `rows`-shaped, positions and the page table one a lane."""
-        b = rows[0]
-        cls._call(fn, (
-            params, np.zeros(rows, np.int32), np.zeros(b, np.int32),
-            *kv.arena, np.zeros((b, table_width), np.int32),
-            np.full(rows, kv.num_pages, np.int32), np.zeros(rows, np.int32)),
-            kv)
+        b, kv = rows[0], self.kv
+        self._call(fn, (
+            self.params, np.zeros(rows, np.int32), np.zeros(b, np.int32),
+            *kv.arena, np.zeros((b, self.max_pages_per_seq), np.int32),
+            np.full(rows, kv.num_pages, np.int32), np.zeros(rows, np.int32)))
 
     # -- submission -------------------------------------------------------
 
@@ -683,9 +583,9 @@ class LLMEngine:
     # -- scheduler --------------------------------------------------------
 
     def step(self) -> bool:
-        """One engine iteration: admit + prefill up to
-        `max_prefills_per_step` prompts, then one decode pass over the
-        running set. Returns False when there was nothing to do."""
+        """One engine iteration: admit one request, advance one prompt's
+        prefill by one unit, then one decode pass over the running set.
+        Returns False when there was nothing to do."""
         phases = self._phases
         # bare reads: an iteration with work is a step of a profiler's
         # overview, a poll of empty queues is not
@@ -700,26 +600,20 @@ class LLMEngine:
             advanced = False
             with phases.phase("admit"):
                 self._shed_expired()
-            for _ in range(self.config.max_prefills_per_step):
-                if len(self._prefilling) < \
-                        self.config.max_prefills_per_step:
-                    with phases.phase("admit"):
-                        self._admit_one()
-                if not self._prefilling:
-                    break
+            if not self._prefilling:
+                with phases.phase("admit"):
+                    self._admit_one()
+            if self._prefilling:
                 mark = phases.total_ns()
-                # ONE chunk (or one-shot bucket prefill) per slot per
-                # step: a long prompt spreads across steps while decode
-                # below keeps running — the head-of-line fix
+                # ONE chunk (or one-shot bucket prefill) per step: a long
+                # prompt spreads across steps while decode below keeps
+                # running — the head-of-line fix
                 tokens_out += self._advance_prefill()
                 advanced = True
                 prefill_ns += phases.total_ns() - mark
             if self._running:
                 mark = phases.total_ns()
-                if self.kv_d is not None:
-                    tokens_out += self._spec_decode_once()
-                else:
-                    tokens_out += self._decode_once()
+                tokens_out += self._decode_once()
                 decode_ns += phases.total_ns() - mark
             did = bool(tokens_out) or advanced
             if did:
@@ -783,20 +677,9 @@ class LLMEngine:
                     pages = self.kv.alloc(need, req)
             except OutOfPagesError:
                 return None
-            d_pages = None
-            if self.kv_d is not None:
-                try:
-                    d_pages = self.kv_d.alloc(
-                        self.kv_d.pages_for_tokens(
-                            len(req.prompt) + req.max_new_tokens
-                            + self.config.spec_k), req)
-                except OutOfPagesError:
-                    self.kv.free(pages, req)
-                    return None
             req.admit_ts = time.monotonic()
             self._waiting.pop(0)
-            seq = _Sequence(req, pages, pos=0, cached=cached,
-                            d_pages=d_pages)
+            seq = _Sequence(req, pages, pos=0, cached=cached)
             self._prefilling.append(seq)
         return seq
 
@@ -804,39 +687,30 @@ class LLMEngine:
 
     def _advance_prefill(self) -> int:
         """Advance the oldest in-flight prefill by one unit of work:
-        a one-shot bucket prefill when the whole prompt fits (the PR-7
-        fast path, preserved bit-for-bit), otherwise one chunk of the
-        target prompt, then — with speculation on — one chunk of the
-        draft model's own prefill. Returns tokens emitted (1 exactly
-        when target prefill completes: the first token comes from the
-        final chunk's logits, so TTFT lands before the draft finishes
-        warming)."""
+        a one-shot bucket prefill when the whole prompt fits, otherwise
+        one chunk of the prompt. Returns tokens emitted (1 exactly when
+        the prefill completes: the first token comes from the final
+        chunk's logits)."""
         # this unit's own time (what lies between the phases below, the
         # hand-over to the running set) is `prefill_assemble`
         with self._phases.phase("prefill_assemble"):
             seq = self._prefilling[0]
             req = seq.req
             s = len(req.prompt)
-            emitted = 0
             mark = self._phases.total_ns()
-            if seq.prefilled < s:
-                oneshot = (seq.prefilled == 0
-                           and s <= max(self.config.prefill_buckets)
-                           and (not self.config.prefill_chunk
-                                or s <= self._chunk_size))
-                if oneshot:
-                    emitted = self._prefill_oneshot(seq)
-                else:
-                    emitted = self._chunk_advance(seq)
-                    with self._lock:
-                        self.counters["chunk_ms"] += \
-                            (self._phases.total_ns() - mark) / 1e6
-            elif self.kv_d is not None and seq.d_prefilled < s:
-                self._draft_prefill_advance(seq)
+            oneshot = (seq.prefilled == 0
+                       and s <= max(self.config.prefill_buckets)
+                       and (not self.config.prefill_chunk
+                            or s <= self._chunk_size))
+            if oneshot:
+                emitted = self._prefill_oneshot(seq)
+            else:
+                emitted = self._chunk_advance(seq)
+                with self._lock:
+                    self.counters["chunk_ms"] += \
+                        (self._phases.total_ns() - mark) / 1e6
             req.prefill_ms += (self._phases.total_ns() - mark) / 1e6
-            ready = seq.prefilled >= s and \
-                (self.kv_d is None or seq.d_prefilled >= s)
-            if ready or seq.req.done.is_set():
+            if seq.prefilled >= s or seq.req.done.is_set():
                 with self._lock:
                     if seq in self._prefilling:
                         self._prefilling.remove(seq)
@@ -876,17 +750,17 @@ class LLMEngine:
         with self._lock:
             self.counters[counter] += n
 
-    def _prefill_forward(self, fn, args, kv: PagedKVCache):
-        """What every prefill shares (one-shot, chunk, draft): the call,
-        which leaves the rows' K and V in `kv`'s pages, and the wait.
-        `args` hold `kv`'s arena, donated: its successor goes back into
-        `kv`. Returns the logits, still on the device."""
+    def _prefill_forward(self, fn, args):
+        """What every prefill shares (one-shot, chunk): the call, which
+        leaves the rows' K and V in their pages, and the wait. `args` hold
+        the arena, donated: its successor goes back into `self.kv`.
+        Returns the logits, still on the device."""
         phase = self._phases.phase
         with phase("prefill_dispatch"):
             self._count_link("prefill_link_bytes", *args)
-            logits, counts = self._call(fn, args, kv)
+            logits, counts = self._call(fn, args)
         with phase("prefill_device_wait"):
-            self._block_until_ready((logits, kv.arena))
+            self._block_until_ready((logits, self.kv.arena))
             self._add_step_counts("prefill", counts, "prefill_link_bytes")
         return logits
 
@@ -906,7 +780,7 @@ class LLMEngine:
             next_logits = self._prefill_forward(
                 self._prefill_fns[bucket],
                 (self.params, toks, np.asarray([s], np.int32),
-                 *self.kv.arena, w_page, w_off), self.kv)
+                 *self.kv.arena, w_page, w_off))
             with phase("prefill_kv_write"):
                 seq.prefilled = s
                 seq.pos = s
@@ -918,10 +792,10 @@ class LLMEngine:
                 return self._emit_first(seq, next_logits[0])
 
     def _chunk_advance(self, seq: _Sequence) -> int:
-        """One target-model chunk: forward the next `_chunk_size`
-        prompt tokens against the pages filled so far (prefix-cache
-        hits enter here with `prefilled == cached > 0`, so the cached
-        pages are attended but never recomputed)."""
+        """One chunk: forward the next `_chunk_size` prompt tokens
+        against the pages filled so far (prefix-cache hits enter here
+        with `prefilled == cached > 0`, so the cached pages are attended
+        but never recomputed)."""
         req = seq.req
         s = len(req.prompt)
         c = self._chunk_size
@@ -944,7 +818,7 @@ class LLMEngine:
                 self._chunk_fn,
                 (self.params, toks, np.asarray([seq.prefilled], np.int32),
                  *self.kv.arena, table,
-                 w_page[None], w_off[None]), self.kv)
+                 w_page[None], w_off[None]))
             with phase("prefill_kv_write"):
                 seq.prefilled += take
                 with self._lock:
@@ -960,62 +834,16 @@ class LLMEngine:
             with phase("prefill_sample"):
                 return self._emit_first(seq, logits[0, take - 1])
 
-    def _draft_prefill_advance(self, seq: _Sequence):
-        """Warm the draft model's private KV for this sequence. The
-        draft never sees the prefix cache (its pages are per-sequence),
-        so it always processes the full prompt — one bucket forward
-        when the prompt fits, else one chunk per step."""
-        req = seq.req
-        s = len(req.prompt)
-        phase = self._phases.phase
-        if seq.d_prefilled == 0 and \
-                s <= max(self.config.prefill_buckets):
-            with phase("prefill_assemble"):
-                bucket = min(b for b in self.config.prefill_buckets
-                             if b >= s)
-                toks = np.zeros((1, bucket), np.int32)
-                toks[0, :s] = req.prompt
-                self._note_call("draft_prefill", bucket)
-                w_page, w_off = self.kv_d.write_index(
-                    seq.d_pages, 0, s, bucket)
-            self._prefill_forward(
-                self._d_prefill_fns[bucket],
-                (self.draft_params, toks, np.asarray([s], np.int32),
-                 *self.kv_d.arena, w_page, w_off),
-                self.kv_d)
-            seq.d_prefilled = s
-        else:
-            with phase("prefill_assemble"):
-                c = self._chunk_size
-                take = min(c, s - seq.d_prefilled)
-                toks = np.zeros((1, c), np.int32)
-                toks[0, :take] = \
-                    req.prompt[seq.d_prefilled:seq.d_prefilled + take]
-                table = np.zeros((1, self.max_pages_per_seq_d), np.int32)
-                table[0, :len(seq.d_pages)] = seq.d_pages
-                self._note_call("draft_chunk", c)
-                w_page, w_off = self.kv_d.write_index(
-                    seq.d_pages, seq.d_prefilled, take, c)
-            self._prefill_forward(
-                self._d_chunk_fn,
-                (self.draft_params, toks,
-                 np.asarray([seq.d_prefilled], np.int32),
-                 *self.kv_d.arena, table,
-                 w_page[None], w_off[None]), self.kv_d)
-            seq.d_prefilled += take
-        seq.d_pos = seq.d_prefilled
-
-    def _decode_forward(self, fn, args, kv: PagedKVCache) -> np.ndarray:
-        """One decode-shaped call (decode, draft decode, verify): the
-        call, which leaves the written rows' K and V in `kv`'s pages, the
-        wait, the logits to the host. `args` hold `kv`'s arena, donated:
-        its successor goes back into `kv`."""
+    def _decode_forward(self, fn, args) -> np.ndarray:
+        """One decode call: the call, which leaves the written rows' K and
+        V in their pages, the wait, the logits to the host. `args` hold
+        the arena, donated: its successor goes back into `self.kv`."""
         phase = self._phases.phase
         with phase("decode_dispatch"):
-            logits, counts = self._call(fn, args, kv)
+            logits, counts = self._call(fn, args)
         with phase("decode_device_wait"):
             # the np.asarray below would block on the logits anyway
-            self._block_until_ready((logits, kv.arena))
+            self._block_until_ready((logits, self.kv.arena))
         with phase("decode_fetch"):
             logits = np.asarray(logits)
             self._count_link("decode_link_bytes", logits, *args)
@@ -1051,7 +879,7 @@ class LLMEngine:
                 self._decode_fns[bb],
                 (self.params, tokens, positions,
                  *self.kv.arena, page_table,
-                 w_page, w_off), self.kv)
+                 w_page, w_off))
             with phase("decode_kv_append"):
                 context = int(positions.sum())
                 for seq in runs:
@@ -1070,154 +898,6 @@ class LLMEngine:
                 self._finish(seq)
             return len(runs)
 
-    def _spec_decode_once(self) -> int:
-        """One speculative round over the running set (Leviathan et al.
-        '23, greedy case): the draft proposes K tokens per sequence
-        autoregressively, the target scores all K+1 positions in ONE
-        chunk forward, and the longest proposal prefix that matches the
-        target's own argmaxes is accepted — plus the target's next
-        token after the divergence, so every round emits >= 1 token and
-        the emitted stream is exactly plain greedy's, token for token.
-
-        All lanes run the draft loop in lockstep: `max_gap + K` draft
-        decode dispatches per round, where gap is each lane's catch-up
-        deficit (0 or 1 — a fully-accepted round leaves the draft one
-        committed token behind). Lanes past their own `gap + K` budget
-        idle inside the batch (their lane computes garbage that is
-        neither written nor read), so the dispatch count varies only
-        host-side — every dispatch is the same (batch-bucket) decode
-        executable and the verify window is always K+1 wide: accept-
-        length variation can not retrace anything.
-
-        The verify program writes the K/V of its whole window, before the
-        host knows how many proposals stand. Rule: a row is written if its
-        position lies in the sequence's own reserved pages, else dropped.
-        A rejected row's position is at or past the new `seq.pos`, so
-        every later read masks it (`valid` stops at the frontier) and the
-        next round or decode step overwrites it before the frontier
-        passes; shared prefix pages end before the prompt does and are
-        never written.
-        """
-        K = self.config.spec_k
-        phase = self._phases.phase
-        # as in _decode_once: the round's own time is `decode_assemble`
-        with phase("decode_assemble"):
-            with self._lock:
-                runs = list(self._running)
-            n = len(runs)
-            bb = min(b for b in self.config.batch_buckets if b >= n)
-            full = [seq.req.prompt + seq.req.tokens for seq in runs]
-            gaps = [seq.pos - seq.d_pos for seq in runs]
-            cur = [seq.d_pos for seq in runs]
-            budget = [g + K for g in gaps]
-            proposals: List[List[int]] = [[] for _ in range(n)]
-            d_table = np.zeros((bb, self.max_pages_per_seq_d), np.int32)
-            for i, seq in enumerate(runs):
-                d_table[i, :len(seq.d_pages)] = seq.d_pages
-            n_steps = max(budget)
-            bs = self.kv_d.block_size
-            for t in range(n_steps):
-                toks = np.zeros(bb, np.int32)
-                poss = np.zeros(bb, np.int32)
-                w_page = np.full(bb, self.kv_d.num_pages, np.int32)
-                w_off = np.zeros(bb, np.int32)
-                active = []
-                for i, seq in enumerate(runs):
-                    if t >= budget[i]:
-                        continue  # lane idle: feed zeros, discard output
-                    active.append(i)
-                    idx = cur[i]
-                    if idx < len(full[i]):
-                        toks[i] = full[i][idx]  # committed token (catch-up
-                        # or the round's first proposal input)
-                    else:
-                        toks[i] = proposals[i][idx - len(full[i])]
-                    poss[i] = idx
-                    slot, w_off[i] = divmod(idx, bs)
-                    w_page[i] = seq.d_pages[slot]
-                self._note_call("draft_decode", bb)
-                d_logits = self._decode_forward(
-                    self._d_decode_fns[bb],
-                    (self.draft_params, toks, poss,
-                     *self.kv_d.arena, d_table,
-                     w_page, w_off), self.kv_d)
-                with phase("decode_kv_append"):
-                    for i in active:
-                        cur[i] += 1
-                with phase("decode_sample"):
-                    for i in active:
-                        if cur[i] > runs[i].pos:  # past catch-up
-                            proposals[i].append(
-                                int(np.argmax(d_logits[i])))
-            # verify: target scores [last_committed, d_1..d_K] at
-            # positions pos..pos+K in one window
-            v_toks = np.zeros((bb, K + 1), np.int32)
-            v_start = np.zeros(bb, np.int32)
-            v_table = np.zeros((bb, self.max_pages_per_seq), np.int32)
-            v_page = np.full((bb, K + 1), self.kv.num_pages, np.int32)
-            v_off = np.zeros((bb, K + 1), np.int32)
-            for i, seq in enumerate(runs):
-                v_toks[i, 0] = seq.last_token
-                v_toks[i, 1:] = proposals[i][:K]
-                v_start[i] = seq.pos
-                v_table[i, :len(seq.pages)] = seq.pages
-                with phase("decode_kv_append"):
-                    v_page[i], v_off[i] = self.kv.write_index(
-                        seq.pages, seq.pos, K + 1)
-            self._note_call("verify", bb)
-            logits = self._decode_forward(
-                self._verify_fns[bb],
-                (self.params, v_toks, v_start,
-                 *self.kv.arena, v_table,
-                 v_page, v_off), self.kv)
-            with phase("decode_sample"):
-                tokens_out, finished = self._spec_accept(
-                    runs, proposals, logits)
-            for seq in finished:
-                self._finish(seq)
-            return tokens_out
-
-    def _spec_accept(self, runs, proposals, logits):
-        """The accept loop of a speculative round: emit each lane's
-        accepted tokens and move its frontiers past them (their K/V are
-        in the pages already). Returns the tokens emitted and the
-        sequences that finished."""
-        K = self.config.spec_k
-        tokens_out = 0
-        finished = []
-        for i, seq in enumerate(runs):
-            greedy = [int(np.argmax(logits[i, j])) for j in range(K + 1)]
-            a = 0  # accepted proposals: d_{j+1} must equal g_j
-            while a < K and proposals[i][a] == greedy[a]:
-                a += 1
-            # emit g_0..g_a; stop early on EOS / length (plain greedy
-            # would have stopped at the same token)
-            emitted = 0
-            fin = False
-            for j in range(a + 1):
-                seq.req._emit(greedy[j])
-                emitted += 1
-                if self._seq_finished(seq, greedy[j]):
-                    fin = True
-                    break
-            tokens_out += emitted
-            with self._lock:
-                self.counters["spec_proposed"] += K
-                self.counters["spec_accepted"] += a
-            if fin:
-                finished.append(seq)
-                continue
-            # verify rows 0..emitted-1 hold exactly the committed
-            # tokens' K/V ([last, d_1..d_a] == [last, g_0..g_{a-1}]);
-            # the draft cache is correct through pos + min(a+1, K) (it
-            # never saw g_a when a == K)
-            seq.d_pos = seq.pos + min(a + 1, K)
-            seq.pos += emitted
-        with self._lock:
-            self.counters["decode_steps"] += 1
-            self.counters["spec_rounds"] += 1
-        return tokens_out, finished
-
     def _seq_finished(self, seq: _Sequence, tok: int) -> bool:
         if seq.n_generated >= seq.req.max_new_tokens:
             seq.req.finish_reason = "length"
@@ -1234,8 +914,6 @@ class LLMEngine:
             # sequence) still aliases survive this — only the refcount
             # drops
             self.kv.free(seq.pages, seq.req)
-            if seq.d_pages is not None:
-                self.kv_d.free(seq.d_pages, seq.req)
             with self._lock:
                 if seq in self._running:
                     self._running.remove(seq)
@@ -1445,8 +1123,6 @@ class LLMEngine:
         with self._step_lock:
             pass
         self.kv.assert_quiesced()
-        if self.kv_d is not None:
-            self.kv_d.assert_quiesced()
 
     def shutdown(self) -> int:
         """Stop the pump and drop the KV arena; returns leaked pages
@@ -1463,10 +1139,7 @@ class LLMEngine:
             # cached prefixes are reusable state, not leaks: release
             # them so close() reports only true sequence leaks
             self.prefix.drain()
-        leaked = 0
-        if self.kv_d is not None:
-            leaked += self.kv_d.close()
-        return leaked + self.kv.close()
+        return self.kv.close()
 
     def metrics(self) -> Dict[str, Any]:
         """Counters and gauges of this engine. `ph_<phase>_ms` is the pump
@@ -1491,7 +1164,6 @@ class LLMEngine:
                 kv_pages_total=self.kv.num_pages,
                 kv_page_utilization=self.kv.utilization(),
                 model=self.model_name,
-                spec_k=self.config.spec_k,
                 compiled_step_calls={
                     f"{kind}:{bucket}": calls
                     for (kind, bucket), calls in
@@ -1513,12 +1185,6 @@ class LLMEngine:
             out[f"ph_{name.replace('.', '_')}_ms"] = ns / 1e6
         out["metrics_calls"] = self._metrics_phases.count("metrics")
         out["metrics_ms"] = self._metrics_phases.ms("metrics")
-        if out["spec_proposed"]:
-            # mean accepted draft tokens per round (<= K); the bench
-            # artifact records this next to the A/B throughputs
-            out["spec_mean_accept"] = (
-                out["spec_accepted"] / out["spec_rounds"]
-                if out["spec_rounds"] else 0.0)
         return out
 
     def _metrics_text(self) -> str:
@@ -1565,18 +1231,6 @@ class LLMEngine:
                 "# TYPE serve_llm_kv_pages_cached gauge",
                 f"serve_llm_kv_pages_cached "
                 f"{int(m['kv_pages_cached'])}",
-            ]
-        if m.get("spec_k"):
-            lines += [
-                "# TYPE serve_llm_spec_proposed_total counter",
-                f"serve_llm_spec_proposed_total "
-                f"{int(m['spec_proposed'])}",
-                "# TYPE serve_llm_spec_accepted_total counter",
-                f"serve_llm_spec_accepted_total "
-                f"{int(m['spec_accepted'])}",
-                "# TYPE serve_llm_spec_rounds_total counter",
-                f"serve_llm_spec_rounds_total "
-                f"{int(m['spec_rounds'])}",
             ]
         if m.get("compiled_step_calls"):
             lines.append(

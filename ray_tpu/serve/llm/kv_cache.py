@@ -57,9 +57,9 @@ def scatter_arena(arena, rows, w_page, w_off):
     """Row n of each of `rows` ([N, n_layer, *row], one array for each
     array of `arena`) goes to `pages[w_page[n], :, w_off[n]]`; returns the
     updated arena, a tuple. A row whose `w_page` is `num_pages` or more is
-    dropped: nothing is read or written for it, which is how lanes, padding
-    rows and speculative rows that own no page cost nothing. Traced inside
-    the engine's programs, with the arena donated, this is an update in
+    dropped: nothing is read or written for it, which is how idle lanes
+    and padding rows, which own no page, cost nothing. Traced inside the
+    engine's programs, with the arena donated, this is an update in
     place.
 
     The layer is an index of its own, so that the scattered unit is one

@@ -647,14 +647,12 @@ def test_chaos_llm_replica_kill_midstream():
         ray_tpu.shutdown()
 
 
-def test_chaos_llm_replica_kill_midstream_spec_prefix():
-    """Mid-stream replica kill with SPECULATIVE DECODING and the
-    shared-prefix cache both on, drafting with an independent (smaller)
-    model. The failover replay contract must survive the fast path:
-    greedy speculative decode is bit-identical to plain greedy and the
-    draft inits from the shared seed, so the survivor's resumed stream
-    is the SAME stream even though its prefill rides aliased
-    prefix-cache pages and its decode rides the verify window."""
+def test_chaos_llm_replica_kill_midstream_prefix_chunked():
+    """Mid-stream replica kill with the shared-prefix cache and chunked
+    prefill both on. The failover replay contract must survive both:
+    replicas share a seed and greedy decode is deterministic, so the
+    survivor's resumed stream is the SAME stream even though its prefill
+    rides aliased prefix-cache pages and the chunk program."""
     from ray_tpu import serve
     from ray_tpu.serve.llm import LLMDeployment
 
@@ -669,20 +667,16 @@ def test_chaos_llm_replica_kill_midstream_spec_prefix():
                     time.sleep(0.05)
                     yield chunk
 
-        # small buckets keep warmup (target + draft + verify fns) well
-        # under the controller's 10 s liveness-poll timeout; the
-        # chunked-prefill window lets the 36-token prompt through the
-        # 16-token top bucket
+        # small buckets keep warmup well under the controller's 10 s
+        # liveness-poll timeout; the chunked-prefill window lets the
+        # 36-token prompt through the 16-token top bucket
         app = serve.deployment(name="llm", num_replicas=2)(
             SlowLLM).bind(
                 seed=0,
-                engine_config={"spec_k": 2, "prefix_cache": 1,
+                engine_config={"prefix_cache": 1,
                                "prefill_chunk": 8, "block_size": 4,
                                "batch_buckets": (1, 2),
-                               "prefill_buckets": (8, 16)},
-                draft_config={"vocab_size": 512, "max_seq_len": 128,
-                              "n_layer": 1, "n_head": 4,
-                              "n_kv_head": 2, "d_model": 64})
+                               "prefill_buckets": (8, 16)})
         handle = serve.run(app)
         ctrl = ray_tpu.get_actor("SERVE_CONTROLLER")
         ray_tpu.get(ctrl.reconcile_now.remote(), timeout=60)
@@ -704,7 +698,6 @@ def test_chaos_llm_replica_kill_midstream_spec_prefix():
         serving = None
         for r in info["replicas"]:
             m = ray_tpu.get(r.get_metrics.remote(), timeout=30)
-            assert m.get("spec_k") == 2.0  # spec plane live on both
             if m["ongoing"] >= 1 and serving is None:
                 serving = r
         assert serving is not None
@@ -718,10 +711,10 @@ def test_chaos_llm_replica_kill_midstream_spec_prefix():
             timeout=120)
         assert tokens == rerun             # failed-over stream lost nothing
 
-        # the survivor really took the fast path: speculative rounds
-        # ran and its prefill aliased the primed prefix pages (the
-        # controller may not have reconciled the death yet, so polls
-        # can still hit the corpse — skip it)
+        # the survivor's prefill aliased the primed prefix pages: a hit
+        # rate above 0 is hit tokens above 0 (the controller may not have
+        # reconciled the death yet, so polls can still hit the corpse:
+        # skip it)
         info = ray_tpu.get(ctrl.get_replicas.remote("llm"), timeout=30)
         live = []
         for r in info["replicas"]:
@@ -730,8 +723,6 @@ def test_chaos_llm_replica_kill_midstream_spec_prefix():
                                         timeout=30))
             except Exception:
                 pass
-        live = [m for m in live if m.get("spec_k")]
-        assert any(m.get("spec_mean_accept", 0) > 0 for m in live)
         assert any(m.get("prefix_cache_hit_rate", 0) > 0 for m in live)
     finally:
         serve.shutdown()

@@ -217,7 +217,7 @@ def test_latent_arena_is_updated_in_place_at_kimi_k2_widths(topo, kind, size):
             args = (params, on_chip((size,)), on_chip((size,)), pages,
                     table, on_chip((size,)), on_chip((size,)))
         else:
-            fn = LLMEngine._make_chunk_fn(engine, f"llm_chunk_c{size}")
+            fn = LLMEngine._make_chunk_fn(engine, size)
             args = (params, on_chip((1, size)), on_chip((1,)), pages, table,
                     on_chip((1, size)), on_chip((1, size)))
         compiled = jax.jit(fn, donate_argnums=(3,)).lower(*args).compile()

@@ -1,5 +1,5 @@
 """serve.llm perf-plane tests: copy-on-write prefix caching, chunked
-prefill, and speculative decoding.
+prefill, and `EngineConfig` as the engine's one source of options.
 
 The load-bearing properties:
   * shared pages are refcounted — a sequence freeing aliased pages can
@@ -9,10 +9,16 @@ The load-bearing properties:
   * only FULL pages are ever aliased (a partial page's tail is still
     appended to), and the page holding the last prompt token is never
     aliased (its forward pass produces the first output token);
-  * chunked prefill and speculative decoding are INVISIBLE in the
-    output: token streams bit-match plain one-shot greedy for both
-    model families, and accept-length variation never retraces.
+  * chunked prefill is INVISIBLE in the output: token streams bit-match
+    plain one-shot greedy for both model families, and the number of
+    chunks a prompt takes never retraces;
+  * what an engine runs with is what its `EngineConfig` says: no
+    environment variable is read, and `spec_k` is accepted at 0 only.
 """
+
+import dataclasses
+import json
+import pathlib
 
 import numpy as np
 import pytest
@@ -198,56 +204,22 @@ def test_assert_quiesced_with_cached_prefixes():
 
 
 # ---------------------------------------------------------------------------
-# engine: chunked prefill + speculative decoding equivalence (jax cpu)
+# engine: chunked prefill and prefix reuse equal the one-shot path (jax cpu)
 # ---------------------------------------------------------------------------
 
 
-def _perturbed_draft(params, seed=99, scale=1.0):
-    """A draft that mostly-but-not-always agrees with the target:
-    target weights + noise. (Two independently-initialized tiny
-    tied-head models agree on argmax almost everywhere — the embedding
-    similarity term dominates — so disagreement has to be injected
-    around the target's own weights to scatter accept lengths.)"""
-    import jax
-    import jax.numpy as jnp
-    leaves, treedef = jax.tree_util.tree_flatten(params)
-    keys = jax.random.split(jax.random.PRNGKey(seed), len(leaves))
-    pert = [l + scale * jnp.std(l) * jax.random.normal(k, l.shape)
-            for l, k in zip(leaves, keys)]
-    return jax.tree_util.tree_unflatten(treedef, pert)
-
-
-def _adversarial_draft(params):
-    """A draft that structurally DISAGREES with the target: the
-    embedding table is rolled one row, so the draft's tied head scores
-    a shifted vocabulary — rejection-heavy rounds exercise the
-    accept-length-0 path (one target token per round, like plain
-    decode but through the verify window)."""
-    import jax
-    import jax.numpy as jnp
-
-    def roll_wte(path, leaf):
-        if any(getattr(p, "key", None) == "wte" for p in path):
-            return jnp.roll(leaf, 1, axis=0)
-        return leaf
-
-    return jax.tree_util.tree_map_with_path(roll_wte, params)
-
-
-def _reference_greedy(engine, prompt, max_new):
+def _assert_greedy(engine, prompt, tokens):
+    """`tokens` are the greedy continuation of `prompt` under the flax
+    forward: one pass over prompt + tokens (the model is causal), whose
+    argmax at each position from the prompt's last is the next token."""
     import jax.numpy as jnp
     mod = engine._mod
-    cfg = engine.model_cfg
-    net = (mod.Llama if engine.model_name == "llama" else mod.GPT)(cfg)
-    toks = list(prompt)
-    out = []
-    for _ in range(max_new):
-        logits = net.apply(engine.params,
-                           jnp.asarray([toks], jnp.int32))
-        nxt = int(jnp.argmax(logits[0, -1]))
-        out.append(nxt)
-        toks.append(nxt)
-    return out
+    net = (mod.Llama if engine.model_name == "llama" else mod.GPT)(
+        engine.model_cfg)
+    seq = list(prompt) + list(tokens)
+    logits = net.apply(engine.params, jnp.asarray([seq[:-1]], jnp.int32))
+    want = np.asarray(jnp.argmax(logits[0, len(prompt) - 1:], axis=-1))
+    assert list(tokens) == want.tolist()
 
 
 def _engine(model="llama", **cfg_kw):
@@ -261,24 +233,26 @@ def _engine(model="llama", **cfg_kw):
     return eng
 
 
-def test_chunked_prefill_matches_oneshot():
+@pytest.mark.parametrize("model", ["llama", "gpt"])
+def test_chunked_prefill_matches_oneshot(model):
     """A prompt longer than every prefill bucket windows in chunk by
     chunk and yields exactly the one-shot math's tokens (the chunk
     kernel attends cached pages + the causal window — same einsums,
     same mask floor). Short prompts on the same engine still take the
     one-shot bucket path."""
     rng = np.random.RandomState(3)
-    eng = _engine(prefill_chunk=8, prefix_cache=0)
+    eng = _engine(model=model, prefill_chunk=8, prefix_cache=0)
     try:
         long_p = list(rng.randint(1, 500, size=27))   # > max bucket 16
         short_p = list(rng.randint(1, 500, size=5))
         r_long = eng.submit(long_p, 6)
         r_short = eng.submit(short_p, 6)
         eng.run_until_idle(timeout=120)
-        assert r_long.result(timeout=10) == \
-            _reference_greedy(eng, long_p, 6)
-        assert r_short.result(timeout=10) == \
-            _reference_greedy(eng, short_p, 6)
+        long_t, short_t = r_long.result(timeout=10), \
+            r_short.result(timeout=10)
+        assert len(long_t) == len(short_t) == 6
+        _assert_greedy(eng, long_p, long_t)
+        _assert_greedy(eng, short_p, short_t)
         m = eng.metrics()
         assert m["chunk_steps"] >= 4  # 27 tokens / 8-wide windows
         eng.quiesce()
@@ -325,131 +299,112 @@ def test_prefix_cache_reuse_in_engine():
 
 
 @pytest.mark.parametrize("model", ["llama", "gpt"])
-def test_speculative_bitmatch_plain_greedy(model):
-    """Greedy speculative output == plain greedy token-for-token, for
-    both a self-draft (accepts everything) and an INDEPENDENT draft
-    (random weights — most proposals rejected), for both families."""
-    rng = np.random.RandomState(5)
-    prompts = [list(rng.randint(1, 500, size=n)) for n in (4, 9, 14)]
-    plain = _engine(model=model, spec_k=0, prefix_cache=0)
-    try:
-        reqs = [plain.submit(p, 7) for p in prompts]
-        plain.run_until_idle(timeout=120)
-        want = [r.result(timeout=10) for r in reqs]
-        plain.quiesce()
-    finally:
-        assert plain.shutdown() == 0
-
-    for perturbed in (False, True):  # False -> self-draft
-        from ray_tpu.serve.llm import EngineConfig, LLMEngine
-        eng = LLMEngine(model=model, engine_config=EngineConfig(
-            batch_buckets=(1, 2), prefill_buckets=(8, 16),
-            block_size=4, spec_k=3, prefix_cache=0), seed=0)
-        if perturbed:
-            # structurally-disagreeing draft (rolled embedding):
-            # proposals diverge from the target's argmaxes, so rounds
-            # run rejection-heavy — the accept-length-0 path
-            eng.draft_params = _adversarial_draft(eng.params)
-        eng.warmup()
-        try:
-            reqs = [eng.submit(p, 7) for p in prompts]
-            eng.run_until_idle(timeout=180)
-            got = [r.result(timeout=10) for r in reqs]
-            assert got == want, f"perturbed={perturbed}"
-            m = eng.metrics()
-            assert m["spec_rounds"] > 0
-            if not perturbed:
-                # self-draft proposals are the target's own argmaxes
-                assert m["spec_accepted"] == m["spec_proposed"]
-            else:
-                assert m["spec_accepted"] < m["spec_proposed"]
-            eng.quiesce()
-        finally:
-            assert eng.shutdown() == 0
-
-
-def test_speculative_round_at_the_end_writes_only_its_own_pages():
-    """The last verify window of a request reaches past its reserved
-    pages (12 tokens fill 3 pages of 4 exactly): those rows are dropped,
-    no page of another owner or of the free list changes, and the stream
-    is plain greedy's, token for token."""
-    from ray_tpu.serve.llm import EngineConfig, LLMEngine
-
-    prompt, new = [7, 3, 9, 1, 4, 2], 6
-    plain = _engine(spec_k=0, prefix_cache=0)
-    try:
-        req = plain.submit(prompt, new)
-        plain.run_until_idle(timeout=120)
-        want = req.result(timeout=10)
-    finally:
-        assert plain.shutdown() == 0
-
-    eng = LLMEngine(model="llama", engine_config=EngineConfig(
-        batch_buckets=(1, 2), prefill_buckets=(8, 16), block_size=4,
-        spec_k=3, prefix_cache=0), seed=0)
-    eng.warmup()
-    try:
-        kv = eng.kv
-        assert kv.alloc(1, "other") == [0]
-        rng = np.random.default_rng(3)
-        fill = rng.normal(size=(2,) + kv.k_pages.shape).astype(np.float32)
-        kv.k_pages = kv.k_pages * 0 + fill[0]
-        kv.v_pages = kv.v_pages * 0 + fill[1]
-        req = eng.submit(prompt, new)
-        eng.run_until_idle(timeout=180)
-        assert req.result(timeout=10) == want
-        m = eng.metrics()
-        # self-draft: 1 token from the prefill, 4 from the first round,
-        # and a second round whose window covers positions 10..13
-        assert m["spec_rounds"] == 2
-        own = [1, 2, 3]
-        others = [p for p in range(kv.num_pages) if p not in own]
-        for got, was in zip((np.asarray(kv.k_pages),
-                             np.asarray(kv.v_pages)), fill):
-            np.testing.assert_array_equal(got[others], was[others])
-            assert not np.array_equal(got[own], was[own])
-        kv.free([0], "other")
-        eng.quiesce()
-    finally:
-        assert eng.shutdown() == 0
-
-
-def test_spec_zero_retrace_across_accept_lengths():
-    """Accept-length variation must bucket, never retrace: after
-    warmup, a burst whose accept lengths scatter (independent draft)
-    adds ZERO compile-cache misses and zero retraces — the draft loop
-    varies only its host-side dispatch count, and the verify window is
-    always K+1 wide."""
+def test_zero_retrace_across_chunk_counts(model):
+    """How many units a prompt's prefill takes is the host's affair: a
+    prompt of one unit (the one-shot bucket), of two and of three chunks,
+    and the suffix of a prompt whose prefix the cache holds, all run the
+    programs `warmup()` compiled. No retrace, no miss, and no kind of
+    program beside prefill, chunk and decode."""
     from ray_tpu import parallel
-    from ray_tpu.serve.llm import EngineConfig, LLMEngine
 
-    eng = LLMEngine(
-        model="llama",
-        engine_config=EngineConfig(
-            batch_buckets=(1, 2), prefill_buckets=(8, 16),
-            block_size=4, spec_k=3, prefix_cache=1),
-        seed=0)
-    eng.draft_params = _perturbed_draft(eng.params, seed=77)
-    eng.warmup()
+    eng = _engine(model=model, prefill_chunk=8, prefix_cache=1)
     try:
-        rng = np.random.RandomState(6)
-        # shapes seen once -> compiled
-        warm = [eng.submit(list(rng.randint(1, 500, size=5)), 6)
-                for _ in range(3)]
-        eng.run_until_idle(timeout=180)
-        [r.result(timeout=10) for r in warm]
         before = parallel.cache_stats()
-        reqs = [eng.submit(list(rng.randint(1, 500, size=n)), 8)
-                for n in (3, 7, 6, 4)]
-        eng.run_until_idle(timeout=180)
-        [r.result(timeout=10) for r in reqs]
+        rng = np.random.RandomState(6)
+        prompts = [list(rng.randint(1, 500, size=n)) for n in (5, 12, 20)]
+        prompts.append(prompts[2][:14] + [7, 8, 9])   # 3 pages held
+        streams = []
+        for p in prompts:
+            req = eng.submit(p, 4)
+            eng.run_until_idle(timeout=120)
+            streams.append(req.result(timeout=10))
         after = parallel.cache_stats()
         assert after["retraces"] == before["retraces"]
         assert after["misses"] == before["misses"]
         assert after["hits"] > before["hits"]
         m = eng.metrics()
-        # the burst's rounds really did scatter accept lengths
-        assert 0 < m["spec_accepted"] < m["spec_proposed"]
+        assert m["chunk_steps"] == 2 + 3 + 1
+        assert m["prefix_cache_hit_tokens"] == 12
+        assert {key.split(":")[0] for key in m["compiled_step_calls"]} \
+            == {"prefill", "chunk", "decode"}
         eng.quiesce()
+        for p, tokens in zip(prompts, streams):
+            assert len(tokens) == 4
+            _assert_greedy(eng, p, tokens)
     finally:
         assert eng.shutdown() == 0
+
+
+# ---------------------------------------------------------------------------
+# EngineConfig: the one source of the engine's options (no jax)
+# ---------------------------------------------------------------------------
+
+_BENCHMARK_CONFIGS = pathlib.Path(__file__).parent.parent / "benchmark" \
+    / "configs"
+
+
+@pytest.mark.parametrize("name, value", [
+    ("RAY_TPU_LLM_BLOCK_SIZE", "32"),
+    ("RAY_TPU_LLM_BATCH_BUCKETS", "1,2"),
+    ("RAY_TPU_LLM_PREFILL_BUCKETS", "8,16"),
+    ("RAY_TPU_LLM_MAX_RUNNING", "2"),
+    ("RAY_TPU_LLM_PREFIX_CACHE", "0"),
+    ("RAY_TPU_LLM_PREFILL_CHUNK", "8"),
+    ("RAY_TPU_LLM_SPEC_K", "3"),
+])
+def test_engine_config_reads_no_environment(monkeypatch, name, value):
+    """Each of these variables once changed what `resolved()` returned;
+    none is read now."""
+    from ray_tpu.serve.llm import EngineConfig
+
+    monkeypatch.delenv(name, raising=False)
+    want = EngineConfig().resolved(128)
+    monkeypatch.setenv(name, value)
+    assert EngineConfig().resolved(128) == want
+    assert want == EngineConfig(
+        block_size=16, num_pages=64, batch_buckets=(1, 2, 4, 8),
+        prefill_buckets=(16, 32, 64, 128), max_running=8,
+        prefix_cache=1, prefill_chunk=0, spec_k=0)
+
+
+def test_engine_config_takes_spec_k_zero_and_refuses_any_other():
+    """The engine has one decode path. `spec_k` stays a field, at 0, for
+    the files under `benchmark/` that pass it (ROADMAP D2): each engine
+    block there still makes a config."""
+    from ray_tpu.serve.llm import EngineConfig
+
+    assert EngineConfig(spec_k=0).resolved(128).spec_k == 0
+    with pytest.raises(ValueError, match="spec_k"):
+        EngineConfig(spec_k=2).resolved(128)
+    blocks = {path.name: json.loads(path.read_text()).get("engine")
+              for path in sorted(_BENCHMARK_CONFIGS.glob("*.json"))}
+    blocks = {name: block for name, block in blocks.items() if block}
+    assert {"mistral-7b-l20.json", "kimi-k2.6-ep32-l7.json"} <= set(blocks)
+    for name, block in blocks.items():
+        cfg = EngineConfig(**block).resolved(4096)
+        assert cfg.spec_k == 0, name
+        assert cfg.num_pages > 0 and cfg.max_running > 0, name
+
+
+def test_engine_config_derives_pages_and_lanes_from_the_model():
+    """`resolved()` derives and reads nothing: a lane for each row of the
+    largest batch bucket unless fewer are asked for, pages for every
+    lane's longest sequence unless a count is given, prefill buckets and
+    the chunk no longer than the model's context."""
+    from ray_tpu.serve.llm import EngineConfig
+
+    cfg = EngineConfig(block_size=4, batch_buckets=(1, 2, 4)).resolved(30)
+    assert cfg.max_running == 4
+    assert cfg.num_pages == 4 * 8            # ceil(30 / 4) pages a lane
+    assert cfg.prefill_buckets == (16,)      # 32, 64, 128 do not fit
+    assert EngineConfig().resolved(8).prefill_buckets == (8,)
+    capped = EngineConfig(batch_buckets=(1, 2), max_running=6,
+                          prefill_chunk=512).resolved(128)
+    assert capped.max_running == 2 and capped.prefill_chunk == 128
+    fewer = EngineConfig(max_running=3, num_pages=11).resolved(128)
+    assert (fewer.max_running, fewer.num_pages) == (3, 11)
+    assert cfg.resolved(30) == cfg           # a fixed point
+    assert {f.name for f in dataclasses.fields(EngineConfig)} == {
+        "block_size", "num_pages", "batch_buckets", "prefill_buckets",
+        "max_running", "eos_token", "prefix_cache", "prefill_chunk",
+        "spec_k"}
