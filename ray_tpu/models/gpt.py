@@ -268,24 +268,28 @@ def prefill_step(variables, cfg: GPTConfig, tokens, true_len):
     return next_logits, k, v
 
 
+def key_block_trips(positions, n_pages: int, page: int, xp=jnp):
+    """`llama.key_block_trips`: this family's decode step walks the cached
+    keys with `llama.paged_attend` too."""
+    from ray_tpu.models.llama import key_block_trips as trips  # import cycle
+
+    return trips(positions, n_pages, page, xp)
+
+
 def decode_step(variables, cfg: GPTConfig, tokens, positions,
                 k_pages, v_pages, page_table):
     """Single-token decode over a paged KV cache (MHA: kv heads ==
-    query heads). Shapes as in `llama.decode_step`."""
+    query heads, a group of one). Shapes as in `llama.decode_step`: the
+    token's own key, then the cached pages a key block at a time."""
     from ray_tpu.models.llama import paged_attend  # avoids import cycle
 
     p = unboxed_params(variables)
     dtype = cfg.dtype
     hd = cfg.d_model // cfg.n_head
     b = tokens.shape[0]
-    block = k_pages.shape[2]
-    t_max = page_table.shape[1] * block
     wte = p["wte"].astype(dtype)
     x = wte[tokens] + p["wpe"].astype(dtype)[positions]
     scale = hd ** -0.5
-    key_idx = jnp.arange(t_max + 1)
-    valid = (key_idx[None, :] < positions[:, None]) | \
-        (key_idx[None, :] == t_max)
     new_ks, new_vs = [], []
     for i in range(cfg.n_layer):
         lp = p[f"h{i}"]
@@ -296,8 +300,8 @@ def decode_step(variables, cfg: GPTConfig, tokens, positions,
         q = q.reshape(b, cfg.n_head, hd)
         k = k.reshape(b, cfg.n_head, hd)
         v = v.reshape(b, cfg.n_head, hd)
-        att = paged_attend(q, k, v, k_pages[:, i], v_pages[:, i],
-                           page_table, valid, scale)
+        att = paged_attend(q, k, v, k_pages, v_pages, i, page_table,
+                           positions, scale)
         att = att.reshape(b, cfg.d_model) @ \
             lp["attn_out"]["kernel"].astype(dtype) + \
             lp["attn_out"]["bias"].astype(dtype)

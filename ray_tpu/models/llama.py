@@ -9,8 +9,11 @@ so every `parallel/` sharding strategy (DP/FSDP/TP/SP) applies to this
 family unchanged.
 
 Design notes:
-- GQA: `n_kv_head <= n_head`; K/V heads are repeated query-side groups.
-  KV projections shard over the same "heads" logical axis.
+- GQA: `n_kv_head <= n_head`; the query heads are groups of
+  `n_head // n_kv_head` over one K/V head each (the serving decode step
+  scores a group against its K/V head in place; the chunk path still
+  repeats K and V). KV projections shard over the same "heads" logical
+  axis.
 - RoPE is computed in float32 and applied per-head (precision matters
   for long sequences); cos/sin tables are closed-over constants folded
   by XLA, not params.
@@ -205,9 +208,13 @@ class Llama(nn.Module):
 #   prefill_step — full-sequence forward (the flax module itself, so the
 #     math is bit-identical to training) that also returns per-position
 #     K/V slabs for cache seeding, via the kv_cache sow above;
-#   decode_step — single-token forward over a paged KV cache: the kernel
-#     receives the whole page arena plus per-sequence gather indices
-#     (page-table rows) and never materializes a contiguous KV copy.
+#   decode_step — single-token forward over a paged KV cache: every layer's
+#     attention (`paged_attend`) takes the whole page arena, the layer's
+#     index and the per-sequence page-table rows, and reads the K and V of
+#     the live pages once: the token's own key, then the cached slots a key
+#     block at a time as far as the batch's longest sequence reaches. No
+#     layer of the arena is sliced out, no contiguous or repeated KV copy
+#     is made.
 
 NEG_INF = -1e30
 
@@ -237,34 +244,88 @@ def _rope_at(x, cos_p, sin_p):
         [x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(x.dtype)
 
 
-def paged_attend(q, k_new, v_new, k_pages_l, v_pages_l, page_table,
-                 valid, scale):
-    """One decode token attending over its paged KV history + itself.
+# cached keys a trip of `paged_attend`'s loop, whole pages. Settled on the
+# chip at Mistral's widths (PERF.md §6, PR 31): 256 is the quickest of
+# 128 / 256 / 512 / 1,024, by a layer's attention alone and by a whole step
+KEY_BLOCK = 256
+
+
+def key_block_trips(positions, n_pages: int, page: int, xp=jnp):
+    """(trips, keys a block) of `paged_attend`'s walk over the cached keys:
+    blocks of `KEY_BLOCK` keys (whole pages; the whole table where it is
+    shorter), as far as the block that holds the batch's largest position
+    and no further. `positions` [B] with `xp=jnp` inside the program,
+    with `xp=np` on the host, where the engine counts what the program
+    scored (`decode_attn_key_slots`): one function, so the two cannot
+    drift."""
+    per_block = max(1, min(KEY_BLOCK // page, n_pages))
+    keys = per_block * page
+    trips = xp.minimum(-(-xp.max(positions) // keys),
+                       -(-n_pages // per_block))
+    return trips, keys
+
+
+@partial(jax.jit, static_argnames="scale")
+def paged_attend(q, k_new, v_new, k_pages, v_pages, layer, page_table,
+                 positions, scale):
+    """One decode token attending over its paged KV history + itself,
+    reading the K and V of the live pages once.
 
     q: [B, H, D]; k_new/v_new: [B, KVH, D] (this token, post-RoPE);
-    k_pages_l/v_pages_l: [P, block, KVH, D] (one layer's arena);
-    page_table: [B, n_pages] gather indices; valid: [B, T+1] key mask
-    (True for cached positions < seq_len and for the appended self key).
-    Math matches `full_attention` (same einsums, NEG_INF mask, row-max
-    subtraction, 1e-20 sum floor) so decode logits track the full
-    forward to float tolerance.
+    k_pages/v_pages: [P, L, block, KVH, D] (the whole arena); page_table:
+    [B, n_pages] page ids; positions: [B] keys each sequence has cached
+    (0 for a lane that holds none). The query's H heads are viewed as
+    KVH groups of H // KVH and scored against K and V as they lie in the
+    pages (no repeat). One running softmax (maximum, sum, accumulator in
+    float32) over blocks of keys: the token's own key first, which gives
+    every row a real maximum, so a masked key weighs exp(NEG_INF - m) = 0
+    exactly; then `key_block_trips` blocks of cached slots, each gathered
+    from the arena by (page, layer) and masked by each row's own position.
+    Same mathematics as `full_attention` (NEG_INF mask, maximum
+    subtracted, 1e-20 sum floor), so decode logits track the full forward
+    to float tolerance. Returns [B, H, D] in q's dtype.
+
+    Jitted on its own, with the layer index as an operand, so that a
+    decode step traces it once for all its layers: XLA inlines the calls
+    and the program is the same, but a serving cell's five decode programs
+    trace and lower in 1.3 s on the build box where they took 3.3
+    (PERF.md §6, PR 31).
     """
     b, h, d = q.shape
     kvh = k_new.shape[1]
-    kc = k_pages_l[page_table].reshape(b, -1, kvh, d).astype(q.dtype)
-    vc = v_pages_l[page_table].reshape(b, -1, kvh, d).astype(q.dtype)
-    k_all = jnp.concatenate([kc, k_new[:, None]], axis=1)  # [B, T+1, KVH, D]
-    v_all = jnp.concatenate([vc, v_new[:, None]], axis=1)
-    if kvh != h:  # GQA: repeat KV query-side (expand_kv_heads)
-        k_all = jnp.repeat(k_all, h // kvh, axis=2)
-        v_all = jnp.repeat(v_all, h // kvh, axis=2)
-    logits = jnp.einsum("bhd,bkhd->bhk", q, k_all) * scale
-    logits = jnp.where(valid[:, None, :], logits, NEG_INF)
-    row_max = jnp.max(logits, axis=-1, keepdims=True)
-    p = jnp.exp(logits - row_max)
-    row_sum = jnp.sum(p, axis=-1, keepdims=True)
-    out = jnp.einsum("bhk,bkhd->bhd", p, v_all)
-    return out / jnp.maximum(row_sum, 1e-20)
+    n_pages, page = page_table.shape[1], k_pages.shape[2]
+    f32 = jnp.float32
+    qg = q.reshape(b, kvh, h // kvh, d)
+    own = jnp.einsum("bgrd,bgd->bgr", qg, k_new,
+                     preferred_element_type=f32) * scale
+    state = (own, jnp.ones_like(own), jnp.broadcast_to(
+        v_new.astype(f32)[:, :, None], qg.shape))
+    trips, keys = key_block_trips(positions, n_pages, page)
+    per_block = keys // page
+    table = jnp.pad(page_table, ((0, 0), (0, -n_pages % per_block)))
+
+    def cached(j, state):
+        m, l, acc = state
+        ids = jax.lax.dynamic_slice_in_dim(
+            table, j * per_block, per_block, axis=1)
+        k = k_pages[ids, layer].reshape(b, keys, kvh, d).astype(q.dtype)
+        v = v_pages[ids, layer].reshape(b, keys, kvh, d).astype(q.dtype)
+        s = jnp.einsum("bgrd,bkgd->bgrk", qg, k,
+                       preferred_element_type=f32) * scale
+        seen = (j * keys + jnp.arange(keys))[None, :] < positions[:, None]
+        s = jnp.where(seen[:, None, None, :], s, NEG_INF)
+        m_new = jnp.maximum(m, jnp.max(s, axis=-1))
+        p = jnp.exp(s - m_new[..., None])
+        alpha = jnp.exp(m - m_new)
+        l = l * alpha + jnp.sum(p, axis=-1)
+        acc = acc * alpha[..., None] + jnp.einsum(
+            "bgrk,bkgd->bgrd", p.astype(q.dtype), v,
+            preferred_element_type=f32)
+        return m_new, l, acc
+
+    _, l, acc = jax.lax.fori_loop(0, trips, cached, state)
+    out = acc / jnp.maximum(l, 1e-20)[..., None]
+    return out.reshape(b, h, d).astype(q.dtype)
 
 
 def prefill_step(variables, cfg: LlamaConfig, tokens, true_len):
@@ -414,26 +475,26 @@ def decode_step(variables, cfg: LlamaConfig, tokens, positions,
     """One decode iteration for a batch of sequences on a paged cache.
 
     tokens: [B] current token ids; positions: [B] their 0-based
-    positions (== tokens already cached per sequence); k_pages/v_pages:
-    [P, L, block, KVH, D] arena views; page_table: [B, n_pages] page ids
-    per logical block (rows padded with any valid page id — masked).
-    Returns (logits [B, V], new_k [B, L, KVH, D], new_v [B, L, KVH, D]);
-    the caller appends new_k/new_v into each sequence's tail page.
+    positions (== tokens already cached per sequence; 0 for a lane that
+    holds no sequence); k_pages/v_pages: [P, L, block, KVH, D] arena views;
+    page_table: [B, n_pages] page ids per logical block (rows padded with
+    any valid page id — masked). What a layer reads of the cache, in order
+    (`paged_attend`): nothing for the token's own key and value, which are
+    scored first; then `key_block_trips(positions)` blocks of `KEY_BLOCK`
+    slots, the (page, layer) rows of those pages alone, whatever the
+    table's length. Returns (logits [B, V], new_k [B, L, KVH, D], new_v
+    [B, L, KVH, D]); the caller appends new_k/new_v into each sequence's
+    tail page.
     """
     p = unboxed_params(variables)
     dtype = cfg.dtype
     hd = cfg.head_dim
     b = tokens.shape[0]
-    block = k_pages.shape[2]
-    t_max = page_table.shape[1] * block
     wte = p["wte"].astype(dtype)
     x = wte[tokens]  # [B, D]
     cos_t, sin_t = rope_tables(cfg.max_seq_len, hd, cfg.rope_theta)
     cos_p, sin_p = cos_t[positions], sin_t[positions]
     scale = hd ** -0.5
-    key_idx = jnp.arange(t_max + 1)
-    valid = (key_idx[None, :] < positions[:, None]) | \
-        (key_idx[None, :] == t_max)
     new_ks, new_vs = [], []
     for i in range(cfg.n_layer):
         lp = p[f"layer{i}"]
@@ -445,8 +506,8 @@ def decode_step(variables, cfg: LlamaConfig, tokens, positions,
         q = _rope_at(q.reshape(b, cfg.n_head, hd), cos_p, sin_p)
         k = _rope_at(k.reshape(b, cfg.n_kv_head, hd), cos_p, sin_p)
         v = v.reshape(b, cfg.n_kv_head, hd)
-        att = paged_attend(q, k, v, k_pages[:, i], v_pages[:, i],
-                           page_table, valid, scale)
+        att = paged_attend(q, k, v, k_pages, v_pages, i, page_table,
+                           positions, scale)
         x = x + att.reshape(b, cfg.d_model) @ \
             lp["attn_out"]["kernel"].astype(dtype)
         h = _rms(x, lp["mlp_norm"]["scale"], cfg.norm_eps, dtype)

@@ -21,7 +21,12 @@ arrays. Every compiled program takes the arena donated, reads it through
 per-sequence page-table rows, scatters its new K/V rows into their pages
 at coordinates the host computed, and returns the arena, which the engine
 stores back for the next call: no K or V crosses the host link. The host
-keeps the allocator, the page tables and the positions.
+keeps the allocator, the page tables and the positions. A decode program
+of the `llama` and `gpt` families reads, a layer, the (page, layer) rows
+of the key blocks up to the longest position among its lanes, once, and
+nothing of the rest of the table (`models/llama.py` `paged_attend`); what
+it scored is counted here, on the host, from the positions handed to it
+(`decode_attn_key_slots`, beside `decode_context_tokens`, what it had to).
 
 Greedy (argmax) sampling keeps generation deterministic — the property
 the continuous-batching equivalence test and the mid-stream chaos
@@ -316,6 +321,10 @@ class LLMEngine:
             if family.cache_rows else _kv_rows
         self._step_counts: Tuple[str, ...] = tuple(
             getattr(mod, family.step_counts)) if family.step_counts else ()
+        # a family whose decode step is `llama.paged_attend` names the
+        # function that bounds its walk over the cached keys: the engine
+        # calls it on the host to count what the program scored
+        self._key_trips = getattr(mod, "key_block_trips", None)
         self.model_name = model
         self._mod = mod
         cfg = (engine_config or EngineConfig()).resolved(
@@ -405,6 +414,11 @@ class LLMEngine:
         for name in self._step_counts:
             self.counters[f"decode_{name}"] = 0
             self.counters[f"prefill_{name}"] = 0
+        if self._key_trips is not None:
+            # the key slots a decode step's query rows were scored
+            # against, padding included, over lanes and layers: the
+            # token's own and the key blocks the program walked
+            self.counters["decode_attn_key_slots"] = 0
         # per-bucket compiled_step dispatch counts: (kind, bucket) ->
         # calls. Every entry maps 1:1 onto one AOT executable, so the
         # rows in /metrics show exactly which compiled programs serve
@@ -894,6 +908,13 @@ class LLMEngine:
                 with self._lock:
                     self.counters["decode_steps"] += 1
                     self.counters["decode_context_tokens"] += context
+                    if self._key_trips is not None:
+                        trips, keys = self._key_trips(
+                            positions, self.max_pages_per_seq,
+                            self.kv.block_size, np)
+                        self.counters["decode_attn_key_slots"] += \
+                            bb * self.model_cfg.n_layer \
+                            * (int(trips) * keys + 1)
             for seq in finished:
                 self._finish(seq)
             return len(runs)

@@ -102,24 +102,21 @@ def test_llama_125m_decode_step_compiles_for_v5e(topo):
         + mem.temp_size_in_bytes < HBM_BYTES
 
 
-@pytest.mark.parametrize("kind, size", [("decode", 16), ("prefill", 2048)])
-def test_engine_program_updates_the_donated_arena_in_place(topo, kind, size):
-    """The engine's own program (the model's step, then the scatter of its
-    new K/V into the donated arena) at the widths and the arena of the
-    benchmark's serving cells (Mistral-7B's, 20 layers, 2,048 pages of 16
-    tokens): both halves of the arena alias their outputs, and no
-    operation copies or re-lays out an array of the arena's whole shape.
-    (With heads of 64, `llama_125m`, the compiler does re-lay it out.)"""
+MISTRAL_7B_L20 = dict(
+    dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, vocab_size=32000,
+    n_layer=20, n_head=32, n_kv_head=8, d_model=4096, ffn_mult=3.5,
+    max_seq_len=2048)
+
+
+def _compile_engine_program(topo, cfg, kind, size, block=16, num_pages=2048):
+    """The engine's own `llama` program of one kind and bucket (the model's
+    step, then the scatter of its new K/V into the donated arena), compiled
+    for the described chip. Returns (compiled, the arena's shape)."""
     import types
 
     from ray_tpu.serve.llm.engine import LLMEngine
 
     one_chip = SingleDeviceSharding(topo.devices[0])
-    cfg = llama.LlamaConfig(
-        dtype=jnp.bfloat16, param_dtype=jnp.bfloat16, vocab_size=32000,
-        n_layer=20, n_head=32, n_kv_head=8, d_model=4096, ffn_mult=3.5,
-        max_seq_len=2048)
-    block, num_pages = 16, 2048
 
     def on_chip(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
@@ -142,11 +139,24 @@ def test_engine_program_updates_the_donated_arena_in_place(topo, kind, size):
         fn = LLMEngine._make_prefill_fn(engine, size)
         args = (params, on_chip((1, size)), on_chip((1,)), pages, pages,
                 on_chip((size,)), on_chip((size,)))
-    compiled = jax.jit(fn, donate_argnums=(3, 4)).lower(*args).compile()
+    return jax.jit(fn, donate_argnums=(3, 4)).lower(*args).compile(), \
+        pages.shape
+
+
+@pytest.mark.parametrize("kind, size", [("decode", 16), ("prefill", 2048)])
+def test_engine_program_updates_the_donated_arena_in_place(topo, kind, size):
+    """The engine's own program (the model's step, then the scatter of its
+    new K/V into the donated arena) at the widths and the arena of the
+    benchmark's serving cells (Mistral-7B's, 20 layers, 2,048 pages of 16
+    tokens): both halves of the arena alias their outputs, and no
+    operation copies or re-lays out an array of the arena's whole shape.
+    (With heads of 64, `llama_125m`, the compiler does re-lay it out.)"""
+    compiled, arena = _compile_engine_program(
+        topo, llama.LlamaConfig(**MISTRAL_7B_L20), kind, size)
     mem = compiled.memory_analysis()
-    assert mem.alias_size_in_bytes == 2 * 2 * math.prod(pages.shape)
+    assert mem.alias_size_in_bytes == 2 * 2 * math.prod(arena)
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
-    shape = "bf16[" + ",".join(map(str, pages.shape)) + "]"
+    shape = "bf16[" + ",".join(map(str, arena)) + "]"
     moved = [line.strip()[:120] for line in compiled.as_text().splitlines()
              if " copy(" in line and shape in line.split(" copy(")[0]]
     assert not moved, moved
@@ -171,6 +181,41 @@ def _materialised(hlo_text):
             out += [(m.group(1), tuple(map(int, m.group(2).split(","))))
                     for m in re.finditer(r"(\w+)\[([0-9,]+)\]", head)]
     return out
+
+
+@pytest.mark.parametrize("widths", ["mistral_7b_l20", "llama_125m"])
+def test_decode_program_reads_live_pages_once(topo, widths):
+    """What the engine's decode-16 program holds in memory (the arena of
+    2,048 pages of 16 tokens): `paged_attend` gathers a key block's pages
+    by (page, layer) and scores the grouped query heads against K and V as
+    they lie there, so no array is one layer of the whole arena
+    (`[2048, 16, KVH, D]`: the per-layer slice cost a sixth of the device's
+    time, at heads of 64 too), none carries a repeated head axis over
+    cached keys (`[16, keys, KVH, H/KVH, D]`, `[16, keys, H, D]`), and the
+    largest over keys is one block's gather. At Mistral's widths the
+    program's temporaries are 0.04 GB (2.5 GB before: every slot of every
+    table row gathered, repeated four times and read twice)."""
+    cfg = llama.LlamaConfig(**MISTRAL_7B_L20) if widths == "mistral_7b_l20" \
+        else llama.LlamaConfig.llama_125m(dtype=jnp.bfloat16,
+                                          param_dtype=jnp.bfloat16)
+    size = 16
+    compiled, (num_pages, _, block, kvh, d) = _compile_engine_program(
+        topo, cfg, "decode", size)
+    held = _materialised(compiled.as_text())
+    h = cfg.n_head
+    layer_slice = (num_pages, block, kvh, d)
+    assert not [a for a in held if a[1] == layer_slice]
+    # [B, G, R, D] is the accumulator: a key count is what is left of 16
+    # and of the head counts
+    repeated = [a for a in held if a[1][0] == size and (
+        (len(a[1]) == 5 and a[1][2:] == (kvh, h // kvh, d))
+        or (len(a[1]) == 4 and a[1][2:] == (h, d)))]
+    assert not repeated, repeated
+    over_keys = [a for a in held if a[1][0] == size and len(a[1]) >= 4
+                 and a[1][-2:] == (kvh, d)]
+    assert over_keys and max(a[1][1] for a in over_keys) == llama.KEY_BLOCK
+    if widths == "mistral_7b_l20":
+        assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2**20
 
 
 @pytest.mark.parametrize("kind, size", [("decode", 16), ("chunk", 1024)])

@@ -458,7 +458,9 @@ def test_chunk_context_and_key_slot_counters(model):
     host for every family. A family whose steps count (`step_counts`)
     also says what its programs scored, padding included: Kimi's chunk
     walks the cached slots a key block at a time (here the whole table of
-    128, under the model's 1,024), its decode step all of them."""
+    128, under the model's 1,024), its decode step all of them. The
+    `llama` family's decode step walks key blocks too, and the engine
+    counts them on the host (`decode_attn_key_slots`)."""
     eng = _small_engine(model, prefill_buckets=(16,), prefill_chunk=16)
     try:
         req = eng.submit(list(range(3, 42)), 4)
@@ -467,15 +469,164 @@ def test_chunk_context_and_key_slot_counters(model):
         m = eng.metrics()
         assert m["chunk_steps"] == 3 and m["chunk_context_tokens"] == 87
         slots = eng.max_pages_per_seq * eng.kv.block_size
+        layers, lanes = eng.model_cfg.n_layer, 4
+        assert slots == 128
         if model == "llama":
-            assert not [k for k in m if "attn_key_slots" in k]
+            # the host counts the decode steps' slots, nothing of prefill
+            assert "prefill_attn_key_slots" not in m
         else:
-            layers, lanes = eng.model_cfg.n_layer, 4
-            assert slots == 128
             assert m["prefill_attn_key_slots"] \
                 == layers * (16 + 2 * (slots + 16))
-            assert m["decode_attn_key_slots"] \
-                == m["decode_steps"] * layers * lanes * (slots + 1)
+        # one key block of the table's 128 slots (under both families'
+        # KEY_BLOCK) and the token's own key, every lane of the bucket
+        assert m["decode_attn_key_slots"] \
+            == m["decode_steps"] * layers * lanes * (slots + 1)
+        eng.quiesce()
+    finally:
+        assert eng.shutdown() == 0
+
+
+def _paged_attend_reference(q, k_new, v_new, k_pages, v_pages, layer,
+                            page_table, positions, scale):
+    """The decode attention as it was before the key-block walk, kept as
+    the plain reference: one layer of the arena, every slot of every
+    table row gathered, K and V repeated for the query heads, one softmax
+    over all slots and the token's own key. numpy, float32."""
+    b, h, d = q.shape
+    kvh = k_new.shape[1]
+    kc = k_pages[:, layer][page_table].reshape(b, -1, kvh, d)
+    vc = v_pages[:, layer][page_table].reshape(b, -1, kvh, d)
+    k_all = np.repeat(np.concatenate([kc, k_new[:, None]], 1), h // kvh, 2)
+    v_all = np.repeat(np.concatenate([vc, v_new[:, None]], 1), h // kvh, 2)
+    t_max = kc.shape[1]
+    key_idx = np.arange(t_max + 1)
+    valid = (key_idx[None] < positions[:, None]) | (key_idx[None] == t_max)
+    logits = np.einsum("bhd,bkhd->bhk", q, k_all) * scale
+    logits = np.where(valid[:, None, :], logits, -1e30)
+    p = np.exp(logits - logits.max(-1, keepdims=True))
+    out = np.einsum("bhk,bkhd->bhd", p, v_all)
+    return out / np.maximum(p.sum(-1, keepdims=True), 1e-20)
+
+
+# cached keys a lane of four, by the name of what the case is about; `t` is
+# the table's slots, `kb` the key block
+_POSITION_CASES = {
+    "ragged": lambda t, kb: [3, kb + 70, 0, 2 * kb - 5],
+    "idle": lambda t, kb: [0, 0, 0, 0],
+    "one": lambda t, kb: [1, 0, 1, 1],
+    "block": lambda t, kb: [kb, kb, kb, kb],
+    "block_plus_one": lambda t, kb: [kb + 1, 1, kb, 0],
+    "last_slot": lambda t, kb: [t, t - 1, 7, 0],
+}
+
+
+@pytest.mark.parametrize("case", list(_POSITION_CASES))
+@pytest.mark.parametrize("heads", [(32, 8, 128), (12, 4, 64), (12, 12, 64)],
+                         ids=["mistral_32to8x128", "llama125m_12to4x64",
+                              "gpt_12x64"])
+def test_paged_attend_matches_the_gather_all_repeat_reference(heads, case):
+    """`paged_attend` (grouped heads, pages gathered by (page, layer), key
+    blocks up to the longest live position under one running softmax)
+    against the formula it replaced, in float32. Page ids are shuffled, the
+    table is not a whole number of key blocks, and every slot no sequence
+    holds (other pages, other layers, a tail page's rest, the rows a
+    table pads with) is garbage that a wrong mask would let in."""
+    import jax.numpy as jnp
+    from ray_tpu.models import llama
+
+    h, kvh, d = heads
+    kb, page, layers, layer, b = llama.KEY_BLOCK, 16, 3, 1, 4
+    n_pages = (2 * kb + 48) // page          # two blocks and a ragged third
+    t_max = n_pages * page
+    positions = np.asarray(_POSITION_CASES[case](t_max, kb), np.int32)
+    rng = np.random.default_rng(1000 * h + d)
+    num_pages = b * n_pages + 5
+    k_pages, v_pages = (
+        rng.normal(size=(num_pages, layers, page, kvh, d)).astype(np.float32)
+        * 1e3 for _ in range(2))
+    page_table = rng.permutation(num_pages)[:b * n_pages].reshape(
+        b, n_pages).astype(np.int32)
+    for lane, pos in enumerate(positions):   # what the sequences hold
+        for arr in (k_pages, v_pages):
+            rows = rng.normal(size=(pos, kvh, d)).astype(np.float32)
+            for t in range(pos):
+                arr[page_table[lane, t // page], layer, t % page] = rows[t]
+    q, k_new, v_new = (rng.normal(size=(b, n, d)).astype(np.float32)
+                       for n in (h, kvh, kvh))
+    scale = d ** -0.5
+    want = _paged_attend_reference(q, k_new, v_new, k_pages, v_pages, layer,
+                                   page_table, positions, scale)
+    got = llama.paged_attend(
+        jnp.asarray(q), jnp.asarray(k_new), jnp.asarray(v_new),
+        jnp.asarray(k_pages), jnp.asarray(v_pages), layer,
+        jnp.asarray(page_table), jnp.asarray(positions), scale)
+    assert got.shape == (b, h, d) and got.dtype == jnp.float32
+    np.testing.assert_allclose(np.asarray(got), want, rtol=2e-5, atol=2e-5)
+
+
+@pytest.mark.parametrize("position", ["0", "1", "KEY_BLOCK", "KEY_BLOCK+1"])
+def test_key_block_trips_is_one_function_for_host_and_program(position):
+    """The bound of `paged_attend`'s loop, under `numpy` (the engine's
+    count) and under `jax.jit` (the program): the same trips."""
+    import jax
+    import jax.numpy as jnp
+    from ray_tpu.models import gpt, llama
+
+    kb = llama.KEY_BLOCK
+    longest = {"0": 0, "1": 1, "KEY_BLOCK": kb,
+               "KEY_BLOCK+1": kb + 1}[position]
+    positions = np.asarray([0, longest, min(longest, 1)], np.int32)
+    n_pages, page = 3 * kb // 16, 16
+    want = -(-longest // kb)
+    for fn in (llama.key_block_trips, gpt.key_block_trips):
+        host, k_blk = fn(positions, n_pages, page, np)
+        prog, _ = jax.jit(lambda p: fn(p, n_pages, page, jnp))(positions)
+        assert (int(host), int(prog), k_blk) == (want, want, kb)
+    # a table shorter than a key block is one block; the trips stop at it
+    assert llama.key_block_trips(positions, 4, 16, np) \
+        == (min(want, 1), 64)
+
+
+def test_decode_key_slots_counter_follows_the_longest_lane():
+    """Three sequences in a bucket of four, one of them crossing a key
+    block's edge while it decodes: after every decode step
+    `decode_attn_key_slots` has grown by lanes x layers x (trips x
+    KEY_BLOCK + 1), the trips being those of the longest position handed
+    to the program in that step."""
+    import jax.numpy as jnp
+    from ray_tpu.models import llama
+    from ray_tpu.serve.llm import EngineConfig, LLMEngine
+
+    kb = llama.KEY_BLOCK
+    eng = LLMEngine(
+        model="llama",
+        model_cfg=llama.LlamaConfig.tiny(max_seq_len=2 * kb,
+                                         dtype=jnp.float32),
+        engine_config=EngineConfig(batch_buckets=(4,),
+                                   prefill_buckets=(8, kb), prefix_cache=0),
+        seed=0)
+    eng.warmup()
+    try:
+        assert eng.metrics()["decode_attn_key_slots"] == 0   # warm-up apart
+        handed = []
+        forward = eng._decode_forward
+
+        def spy(fn, args):
+            handed.append(np.array(args[2]))
+            return forward(fn, args)
+
+        eng._decode_forward = spy
+        reqs = [eng.submit(list(range(3, 3 + n)), new)
+                for n, new in ((kb - 2, 5), (5, 3), (7, 8))]
+        eng.run_until_idle()
+        assert [len(r.tokens) for r in reqs] == [5, 3, 8]
+        layers = eng.model_cfg.n_layer
+        longest = [int(p.max()) for p in handed]
+        assert min(longest) < kb < max(longest) and len(handed[0]) == 4
+        want = sum(4 * layers * (-(-top // kb) * kb + 1) for top in longest)
+        m = eng.metrics()
+        assert m["decode_steps"] == len(handed)
+        assert m["decode_attn_key_slots"] == want
         eng.quiesce()
     finally:
         assert eng.shutdown() == 0
