@@ -5,8 +5,10 @@ remat-friendly blocks, pluggable attention (dense / ring / Ulysses).
 Families: GPT-2 decoders (`gpt`), Llama-style decoders with
 RoPE/SwiGLU/GQA (`llama`), MoE decoders (`moe_gpt`), latent-attention
 decoders with sigmoid-routed experts (`kimi_k2`, serving only; imported
-when first asked for), ResNet convnets (`resnet`), Vision Transformers
-(`vit`).
+when first asked for), hybrid decoders of Kimi-Delta-Attention layers (one
+state a sequence) beside latent attention with group-limited routing
+(`ling_hybrid`, serving only; imported when first asked for), ResNet
+convnets (`resnet`), Vision Transformers (`vit`).
 """
 
 from ray_tpu.models.bert import (BertConfig, BertEncoder,
@@ -21,16 +23,19 @@ __all__ = [
     "BertConfig", "BertEncoder", "mask_tokens", "mlm_loss",
     "GPT", "GPTConfig", "Llama", "LlamaConfig", "MoEGPT", "MoEGPTConfig",
     "ResNet", "ResNetConfig", "ViT", "ViTConfig",
-    "KimiK2", "KimiK2Config",
+    "KimiK2", "KimiK2Config", "LingHybrid", "LingHybridConfig",
 ]
 
 
 def __getattr__(name):
-    # the serving engine imports this package for every family: the one
-    # family it does not run costs it nothing
-    if name in ("KimiK2", "KimiK2Config", "kimi_k2"):
-        import importlib
+    # the serving engine imports this package for every family: the
+    # families it does not run cost it nothing
+    for family, names in (("kimi_k2", ("KimiK2", "KimiK2Config")),
+                          ("ling_hybrid", ("LingHybrid",
+                                           "LingHybridConfig"))):
+        if name == family or name in names:
+            import importlib
 
-        module = importlib.import_module("ray_tpu.models.kimi_k2")
-        return module if name == "kimi_k2" else getattr(module, name)
+            module = importlib.import_module(f"ray_tpu.models.{family}")
+            return module if name == family else getattr(module, name)
     raise AttributeError(f"module {__name__!r} has no attribute {name!r}")

@@ -1,0 +1,661 @@
+"""Ling hybrid family (`bailing_hybrid`) decoder: periods of Kimi Delta
+Attention (KDA) layers closed by one latent-attention (MLA) layer, a leading
+dense SwiGLU layer, then layers of group-limited sigmoid-routed experts
+beside a shared expert. Serving only, as `kimi_k2.py`: the three step
+functions the paged engine calls, and a flax module that makes the weights.
+
+What it asks of the system that `kimi_k2.py` does not:
+
+- Two kinds of state side by side. An MLA layer leaves a latent row a token
+  in the paged arena (`cache_rows`, Kimi's row; `paged_layers(cfg)` of the
+  layers page). A KDA layer leaves ONE state a sequence, overwritten every
+  step: a float32 `[n_head, d_k, d_v]` matrix a head and the last
+  `conv_width - 1` inputs of its short convolution. `seq_state(cfg)`
+  declares those arrays; the cache manager keeps them a slot a sequence,
+  the steps take the arena's arrays and the lanes' slots (`seq_state=`,
+  `slots=`) and return each sequence's new state after the cache rows.
+- A KDA layer in two forms with the same numbers: `kda_chunk`, a blocked
+  evaluation of the recurrence for prefill and chunks, and `kda_step`, the
+  recurrence itself for one token.
+- Group-limited routing (`parallel.moe.sigmoid_topk_route`, `n_group`).
+
+The recurrence, a head, state S [d_k, d_v] (zero before the first token),
+decay alpha_t = exp(g_t) a channel of d_k, beta_t a scalar:
+
+    S' = diag(alpha_t) S_{t-1}
+    S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
+    o_t = S_t^T q_t
+
+The MLA layer is DeepSeek's without the query bottleneck, with RMSNorm over
+each query head and a per-head sigmoid gate on the output; it calls
+`kimi_k2.attend_absorbed` / `attend_expanded` with its own shapes (`cfg.mla`
+is the `KimiK2Config` they read). Rope: the half-rotation form on the rope
+channels, no scaling.
+
+Parameters: `top/{wte, final_norm, lm_head}`; `layer<i>/{attn_norm,
+attn_out, mlp_norm}` with, in a KDA layer, `kda_qkv` ([q | k | v]),
+`kda_conv` ([channels, width]), `kda_f`, `kda_dt_bias`, `kda_a_log`,
+`kda_beta`, `kda_og`, `kda_o_norm`, and in an MLA layer `q`, `q_norm`,
+`kv_a`, `kv_a_norm`, `kv_b`, `attn_gate`; the feed-forward as Kimi's
+(`mlp_gate_up`, `mlp_down` or `router`, `router_bias`, `experts_gate_up`,
+`experts_down`, `shared_gate_up`, `shared_down`).
+"""
+
+from __future__ import annotations
+
+import dataclasses
+from typing import Any, Tuple
+
+import flax.linen as nn
+import jax
+import jax.numpy as jnp
+
+from ray_tpu.models.kimi_k2 import (KimiK2Config, _gather_pages, _head, _rms,
+                                    _rope, _swiglu, attend_absorbed,
+                                    attend_expanded, unboxed_params,
+                                    yarn_tables)
+from ray_tpu.parallel.moe import MOE_COUNTS, expert_shard_layer
+
+# what each step returns last, an int32 vector summed over the layers:
+# Kimi's expert counts and key slots (the MLA layers), then the KDA states
+# the step read and wrote (sequences x KDA layers, padded lanes not counted)
+STEP_COUNTS = tuple(f"moe_{name}" for name in MOE_COUNTS) \
+    + ("attn_key_slots", "kda_state_rows")
+# tokens the blocked scan folds into the state at a time, and the
+# sub-blocks within which decays are taken against one reference point
+KDA_BLOCK = 64
+KDA_SUB = 16
+# the largest exponent the blocked scan forms: a sub-block's worth of the
+# strongest decay. exp(80) is a float32; exp(89) is not
+KDA_MAX_EXPONENT = 80.0
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+@dataclasses.dataclass(frozen=True)
+class LingHybridConfig:
+    vocab_size: int = 157184
+    n_layer: int = 42
+    n_dense_layer: int = 2          # first_k_dense_replace
+    layer_group_size: int = 6       # the last layer of a period is MLA
+    n_head: int = 32
+    d_model: int = 2560
+    head_dim: int = 128             # KDA's d_k = d_v
+    conv_width: int = 4             # short_conv_kernel_size
+    kda_lower_bound: float = -5.0   # g = lower * sigmoid(.): kda_safe_gate
+    kv_lora_rank: int = 512
+    qk_nope_dim: int = 128
+    qk_rope_dim: int = 64
+    v_head_dim: int = 128
+    ffn_dim: int = 6144             # the dense layers' width
+    moe_ffn_dim: int = 768          # an expert's width
+    n_experts: int = 512            # the router's outputs
+    experts_held: int = 512         # experts whose weights live here ...
+    first_expert: int = 0           # ... from this one on
+    top_k: int = 8
+    n_group: int = 8
+    topk_group: int = 4
+    n_shared: int = 1
+    routed_scale: float = 2.5
+    max_seq_len: int = 131072
+    rope_theta: float = 6000000.0
+    norm_eps: float = 1e-6
+    dtype: Any = jnp.bfloat16
+    param_dtype: Any = jnp.bfloat16
+
+    def __post_init__(self):
+        if -self.kda_lower_bound * KDA_SUB > KDA_MAX_EXPONENT:
+            raise ValueError(
+                f"kda_lower_bound {self.kda_lower_bound} over a sub-block of "
+                f"{KDA_SUB} tokens leaves float32's range")
+
+    def is_mla(self, i: int) -> bool:
+        return (i + 1) % self.layer_group_size == 0
+
+    @property
+    def mla_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i in range(self.n_layer) if self.is_mla(i))
+
+    @property
+    def kda_layers(self) -> Tuple[int, ...]:
+        return tuple(i for i in range(self.n_layer) if not self.is_mla(i))
+
+    @property
+    def conv_channels(self) -> int:
+        return 3 * self.n_head * self.head_dim
+
+    @property
+    def mla(self) -> KimiK2Config:
+        """The MLA layer's shapes as the config `kimi_k2`'s attention
+        functions read; a rope factor of 1 is no scaling."""
+        return KimiK2Config(
+            n_head=self.n_head, d_model=self.d_model,
+            kv_lora_rank=self.kv_lora_rank, qk_nope_dim=self.qk_nope_dim,
+            qk_rope_dim=self.qk_rope_dim, v_head_dim=self.v_head_dim,
+            max_seq_len=self.max_seq_len, rope_theta=self.rope_theta,
+            rope_factor=1.0, norm_eps=self.norm_eps, dtype=self.dtype,
+            param_dtype=self.param_dtype)
+
+    @classmethod
+    def tiny(cls, **kw):
+        base = dict(vocab_size=512, n_layer=7, n_dense_layer=1, n_head=4,
+                    d_model=64, head_dim=16, kv_lora_rank=16, qk_nope_dim=8,
+                    qk_rope_dim=8, v_head_dim=8, ffn_dim=128, moe_ffn_dim=32,
+                    n_experts=16, experts_held=16, top_k=4, n_group=4,
+                    topk_group=2, max_seq_len=128)
+        base.update(kw)
+        return cls(**base)
+
+
+def cache_rows(cfg: LingHybridConfig) -> Tuple[Tuple[int, ...], ...]:
+    """What a token leaves in the paged arena, an MLA layer: Kimi's latent
+    row, padded to whole lane tiles."""
+    return ((cfg.mla.row_dim,),)
+
+
+def paged_layers(cfg: LingHybridConfig) -> int:
+    """Layers that leave rows in the paged arena: the MLA ones."""
+    return len(cfg.mla_layers)
+
+
+def seq_state(cfg: LingHybridConfig):
+    """What a SEQUENCE keeps beside its pages, one (shape, dtype) an array,
+    the KDA layers leading: every head's state, and the convolution's
+    tail (the last `conv_width - 1` inputs of the q, k and v channels,
+    one after the other in a row of whole lane tiles: with an axis of 3
+    next to last the TPU compiler re-lays the array out around every
+    scatter; compiled for a described v5e, PR 33)."""
+    n = len(cfg.kda_layers)
+    return (((n, cfg.n_head, cfg.head_dim, cfg.head_dim), jnp.float32),
+            ((n, (cfg.conv_width - 1) * cfg.conv_channels), cfg.dtype))
+
+
+def _tail_rows(cfg: LingHybridConfig, tail):
+    """A layer's stored tail [B, (W - 1) * ch] as rows [B, W - 1, ch]."""
+    return tail.reshape(tail.shape[0], cfg.conv_width - 1, cfg.conv_channels)
+
+
+# -- the weights --------------------------------------------------------------
+
+def layer_shapes(cfg: LingHybridConfig, i: int) -> dict:
+    """name -> (shape, kind) of layer i's parameters."""
+    d, h, dk = cfg.d_model, cfg.n_head, cfg.head_dim
+    shapes = {"attn_norm": ((d,), "ones"), "mlp_norm": ((d,), "ones")}
+    if cfg.is_mla(i):
+        shapes.update({
+            "q": ((d, h * (cfg.qk_nope_dim + cfg.qk_rope_dim)), "w"),
+            "q_norm": ((cfg.qk_nope_dim + cfg.qk_rope_dim,), "ones"),
+            "kv_a": ((d, cfg.kv_lora_rank + cfg.qk_rope_dim), "w"),
+            "kv_a_norm": ((cfg.kv_lora_rank,), "ones"),
+            "kv_b": ((cfg.kv_lora_rank,
+                      h * (cfg.qk_nope_dim + cfg.v_head_dim)), "w"),
+            "attn_gate": ((d, h), "w"),
+            "attn_out": ((h * cfg.v_head_dim, d), "w"),
+        })
+    else:
+        shapes.update({
+            "kda_qkv": ((d, cfg.conv_channels), "w"),
+            "kda_conv": ((cfg.conv_channels, cfg.conv_width), "conv"),
+            "kda_f": ((d, h * dk), "w"),
+            "kda_dt_bias": ((h * dk,), "dt_bias"),
+            "kda_a_log": ((h,), "a_log"),
+            "kda_beta": ((d, h), "w"),
+            "kda_og": ((d, h * dk), "w"),
+            "kda_o_norm": ((dk,), "ones"),
+            "attn_out": ((h * dk, d), "w"),
+        })
+    if i < cfg.n_dense_layer:
+        shapes["mlp_gate_up"] = ((d, 2 * cfg.ffn_dim), "w")
+        shapes["mlp_down"] = ((cfg.ffn_dim, d), "w")
+        return shapes
+    f = cfg.moe_ffn_dim
+    shapes.update({
+        "router": ((d, cfg.n_experts), "w"),
+        "router_bias": ((cfg.n_experts,), "bias"),
+        "experts_gate_up": ((cfg.experts_held, d, 2 * f), "w"),
+        "experts_down": ((cfg.experts_held, f, d), "w"),
+        "shared_gate_up": ((d, 2 * f * cfg.n_shared), "w"),
+        "shared_down": ((f * cfg.n_shared, d), "w"),
+    })
+    return shapes
+
+
+def _uniform(lo: float, hi: float, log: bool = False):
+    def init(key, shape, dtype):
+        x = jax.random.uniform(key, shape, jnp.float32, lo, hi)
+        return (jnp.log(x) if log else x).astype(dtype)
+    return init
+
+
+class _Weights(nn.Module):
+    """Declares one group of parameters and returns them as a dict."""
+    shapes: Any
+    param_dtype: Any
+
+    @nn.compact
+    def __call__(self):
+        inits = {
+            "w": (nn.initializers.normal(0.02), self.param_dtype),
+            "ones": (nn.initializers.ones, self.param_dtype),
+            # the selection bias, float32 as in the checkpoint: see
+            # `kimi_k2._Weights`
+            "bias": (nn.initializers.normal(0.01), jnp.float32),
+            # four taps a channel, the depth-wise convolution's usual
+            # uniform(+-1/sqrt(width)): activations of order one
+            "conv": (_uniform(-0.5, 0.5), self.param_dtype),
+            # trained tensors that place the decays; drawn so that the
+            # channels spread over both ends of (exp(lower), 1): exp(A_log)
+            # in (1, 16) as Kimi Linear initialises it, the gate's bias in
+            # (-4, 1) around projections of deviation one
+            "a_log": (_uniform(1.0, 16.0, log=True), jnp.float32),
+            "dt_bias": (_uniform(-4.0, 1.0), jnp.float32),
+        }
+        return {name: self.param(name, inits[kind][0], shape,
+                                 inits[kind][1])
+                for name, (shape, kind) in self.shapes.items()}
+
+
+class LingHybrid(nn.Module):
+    """`net.init` makes the weights; `apply` is the full causal forward
+    (no cache, every state from zero), tokens [B, T] -> logits [B, T, V]."""
+    config: LingHybridConfig
+
+    @nn.compact
+    def __call__(self, tokens):
+        cfg = self.config
+        top = {"wte": ((cfg.vocab_size, cfg.d_model), "w"),
+               "final_norm": ((cfg.d_model,), "ones"),
+               "lm_head": ((cfg.d_model, cfg.vocab_size), "w")}
+        p = _Weights(top, cfg.param_dtype, name="top")()
+        for i in range(cfg.n_layer):
+            p[f"layer{i}"] = _Weights(layer_shapes(cfg, i), cfg.param_dtype,
+                                      name=f"layer{i}")()
+        logits, *_ = _window_forward(
+            p, cfg, tokens, jnp.zeros(tokens.shape[:1], jnp.int32), None,
+            None, None, None)
+        return logits
+
+
+# -- Kimi Delta Attention -----------------------------------------------------
+
+def _lower_solve(a):
+    """(I + a)^-1 for strictly lower triangular a [..., C, C], by forward
+    substitution a row at a time (row i of the inverse is e_i minus row i
+    of `a` times the rows above it). Float32 throughout: the powers of `a`
+    a product form would take grow before they vanish."""
+    c = a.shape[-1]
+    eye = jnp.broadcast_to(jnp.eye(c, dtype=a.dtype), a.shape)
+
+    def row(i, inv):
+        a_i = jax.lax.dynamic_slice_in_dim(a, i, 1, axis=-2)
+        new = jax.lax.dynamic_slice_in_dim(eye, i, 1, axis=-2) \
+            - jnp.einsum("...ij,...jk->...ik", a_i, inv, precision=HIGHEST)
+        return jax.lax.dynamic_update_slice_in_dim(inv, new, i, axis=-2)
+
+    # rows from i on are still the identity's when row i is made, and `a`
+    # is zero there
+    return jax.lax.fori_loop(1, c, row, eye)
+
+
+def kda_chunk(q, k, v, g, beta, state):
+    """T tokens a sequence folded into the state `KDA_BLOCK` at a time: the
+    recurrence of the module's docstring in its blocked (WY) form. q, k
+    [B, T, H, dk] (normalised, q scaled), v [B, T, H, dv], g [B, T, H, dk]
+    (log decays, <= 0), beta [B, T, H], state [B, H, dk, dv]; float32. A row
+    with g = 0 and beta = 0 leaves the state as it was (a padded row).
+    Returns (o [B, T, H, dv], the state after the last token).
+
+    Within a block, with G the running sum of g, U the rows `u_t = beta_t
+    (v_t - S'^T k_t)`, S0 the state before the block:
+
+        A_tj = sum_c k_t[c] k_j[c] exp(G_t[c] - G_j[c])     (j < t)
+        B_tj = sum_c q_t[c] k_j[c] exp(G_t[c] - G_j[c])     (j <= t)
+        (I + diag(beta) A) U = diag(beta) (V - (K exp(G)) S0)
+        O = (Q exp(G)) S0 + B U
+        S = diag(exp(G_last)) S0 + (K exp(G_last - G))^T U
+
+    Decays enter only as exp of a difference that is <= 0, or, inside one
+    sub-block of `KDA_SUB` tokens, of one that is at most `KDA_SUB` steps
+    of the strongest decay (`KDA_MAX_EXPONENT`): row t of sub-block i
+    carries exp(G_t - R_i), column j carries exp(R_i - G_j), R_i the sum
+    before the sub-block. Nothing is ever divided by a cumulative decay."""
+    with jax.named_scope("kda_chunk"):
+        b, t, h, dk = k.shape
+        c, s = KDA_BLOCK, KDA_SUB
+        pad = -t % c
+        if pad:
+            q, k, v, g = (jnp.pad(x, ((0, 0), (0, pad), (0, 0), (0, 0)))
+                          for x in (q, k, v, g))
+            beta = jnp.pad(beta, ((0, 0), (0, pad), (0, 0)))
+        n = (t + pad) // c
+
+        def blocks(x):                  # [B, T, H, d] -> [B, H, n, C, d]
+            return x.reshape(b, n, c, h, -1).transpose(0, 3, 1, 2, 4)
+
+        q, k, v, g = blocks(q), blocks(k), blocks(v), blocks(g)
+        beta = blocks(beta[..., None])[..., 0]              # [B, H, n, C]
+        big = jnp.cumsum(g, axis=-2)
+        sub = (b, h, n, c // s, s, dk)
+        big_s = big.reshape(sub)
+        ref = big_s[..., 0, :] - g.reshape(sub)[..., 0, :]  # [B,H,n,C/s,dk]
+        row = jnp.exp(big_s - ref[..., None, :])            # <= 1
+        col = jnp.exp(jnp.minimum(
+            ref[..., None, :] - big[..., None, :, :], KDA_MAX_EXPONENT))
+        k_col = k[..., None, :, :] * col                    # [B,H,n,C/s,C,dk]
+
+        def against_keys(x):
+            return jnp.einsum("...isc,...ijc->...isj", x.reshape(sub) * row,
+                              k_col, precision=HIGHEST).reshape(b, h, n, c, c)
+
+        idx = jnp.arange(c)
+        a = jnp.where(idx[:, None] > idx[None, :], against_keys(k), 0.0) \
+            * beta[..., None]
+        bq = jnp.where(idx[:, None] >= idx[None, :], against_keys(q), 0.0)
+        decay = jnp.exp(big)
+        rhs = jnp.concatenate([k * decay, v], axis=-1) * beta[..., None]
+        w_u = jnp.einsum("...ij,...jd->...id", _lower_solve(a), rhs,
+                         precision=HIGHEST)
+        w, u0 = jnp.split(w_u, [dk], axis=-1)
+        last = big[..., -1:, :]
+        xs = (w, u0, q * decay, bq, k * jnp.exp(last - big),
+              jnp.exp(last[..., 0, :]))
+
+        def fold(state, x):
+            w, u0, q_dec, bq, k_end, end = x
+            u = u0 - jnp.einsum("bhck,bhkv->bhcv", w, state,
+                                precision=HIGHEST)
+            o = jnp.einsum("bhck,bhkv->bhcv", q_dec, state,
+                           precision=HIGHEST) \
+                + jnp.einsum("bhcj,bhjv->bhcv", bq, u, precision=HIGHEST)
+            state = end[..., None] * state + jnp.einsum(
+                "bhck,bhcv->bhkv", k_end, u, precision=HIGHEST)
+            return state, o
+
+        state, o = jax.lax.scan(
+            fold, state, tuple(jnp.moveaxis(x, 2, 0) for x in xs))
+        o = o.transpose(1, 0, 3, 2, 4).reshape(b, n * c, h, -1)
+    return o[:, :t], state
+
+
+def kda_step(q, k, v, g, beta, state):
+    """One token a sequence: the recurrence itself. q, k, g [B, H, dk];
+    v [B, H, dv]; beta [B, H]; state [B, H, dk, dv]; float32. Returns
+    (o [B, H, dv], the new state)."""
+    with jax.named_scope("kda_step"):
+        decayed = jnp.exp(g)[..., None] * state
+        seen = jnp.einsum("bhkv,bhk->bhv", decayed, k, precision=HIGHEST)
+        u = beta[..., None] * (v - seen)
+        state = decayed + k[..., None] * u[..., None, :]
+        o = jnp.einsum("bhkv,bhk->bhv", state, q, precision=HIGHEST)
+    return o, state
+
+
+def _short_conv(x, weight):
+    """Causal depth-wise convolution, then SiLU. x [B, W - 1 + T, ch]: the
+    tail before the window, then the window; weight [ch, W]. Returns
+    [B, T, ch] in x's type: row t is silu(sum_j w[:, j] x[t + j])."""
+    width = weight.shape[1]
+    t = x.shape[1] - (width - 1)
+    w = weight.astype(jnp.float32)
+    y = sum(x[:, j:j + t].astype(jnp.float32) * w[:, j]
+            for j in range(width))
+    return nn.silu(y).astype(x.dtype)
+
+
+def _kda_project(lp, cfg: LingHybridConfig, h):
+    """h [..., d] -> (u [..., 3 H dk] before the convolution, g [..., H, dk]
+    log decays, beta [..., H], output gate [..., H, dk]); the decays and
+    beta float32 from a float32 accumulation: `exp(A_log)` multiplies what
+    rounding the projection to the model's type would leave."""
+    dtype, f32 = cfg.dtype, jnp.float32
+    heads = h.shape[:-1] + (cfg.n_head, cfg.head_dim)
+    u = h @ lp["kda_qkv"].astype(dtype)
+    a = jnp.dot(h, lp["kda_f"].astype(dtype), preferred_element_type=f32) \
+        + lp["kda_dt_bias"].astype(f32)
+    g = cfg.kda_lower_bound * jax.nn.sigmoid(
+        jnp.exp(lp["kda_a_log"].astype(f32))[:, None] * a.reshape(heads))
+    beta = jax.nn.sigmoid(jnp.dot(h, lp["kda_beta"].astype(dtype),
+                                  preferred_element_type=f32))
+    gate = jax.nn.sigmoid(jnp.dot(h, lp["kda_og"].astype(dtype),
+                                  preferred_element_type=f32)).reshape(heads)
+    return u, g, beta, gate
+
+
+def _kda_heads(cfg: LingHybridConfig, y):
+    """The convolved channels [..., 3 H dk] as float32 heads: q (unit
+    length, over sqrt(dk)), k (unit length), v."""
+    y = y.astype(jnp.float32).reshape(
+        y.shape[:-1] + (3, cfg.n_head, cfg.head_dim))
+    q, k, v = y[..., 0, :, :], y[..., 1, :, :], y[..., 2, :, :]
+
+    def unit(x):
+        return x * jax.lax.rsqrt(
+            jnp.sum(jnp.square(x), axis=-1, keepdims=True) + 1e-6)
+
+    return unit(q) * cfg.head_dim ** -0.5, unit(k), v
+
+
+def _kda_out(lp, cfg: LingHybridConfig, o, gate):
+    """Heads' outputs o [..., H, dv] float32 -> [..., d]: RMSNorm a head
+    (one scale for all heads), the sigmoid gate, the output matrix."""
+    y = _rms(o, lp["kda_o_norm"], cfg.norm_eps, jnp.float32) * gate
+    y = y.astype(cfg.dtype).reshape(o.shape[:-2] + (-1,))
+    return y @ lp["attn_out"].astype(cfg.dtype)
+
+
+# -- latent attention ---------------------------------------------------------
+
+def _mla_project(lp, cfg: LingHybridConfig, h, cos, sin):
+    """h [..., d] -> q_nope [..., H, nope], q_rope [..., H, rope] (normed a
+    head, rotated), the cache row [..., row_dim], the heads' output gates
+    [..., H, 1]."""
+    m, dtype = cfg.mla, cfg.dtype
+    q = (h @ lp["q"].astype(dtype)).reshape(
+        h.shape[:-1] + (m.n_head, m.qk_nope_dim + m.qk_rope_dim))
+    q = _rms(q, lp["q_norm"], cfg.norm_eps, dtype)
+    q_nope, q_rope = jnp.split(q, [m.qk_nope_dim], axis=-1)
+    q_rope = _rope(q_rope, cos[..., None, :], sin[..., None, :])
+    kv = h @ lp["kv_a"].astype(dtype)
+    c_kv, k_rope = jnp.split(kv, [m.kv_lora_rank], axis=-1)
+    c_kv = _rms(c_kv, lp["kv_a_norm"], cfg.norm_eps, dtype)
+    latent = jnp.concatenate(
+        [c_kv, _rope(k_rope, cos, sin),
+         jnp.zeros(c_kv.shape[:-1] + (m.row_dim - m.latent_dim,), dtype)],
+        axis=-1)
+    gate = jax.nn.sigmoid(jnp.dot(h, lp["attn_gate"].astype(dtype),
+                                  preferred_element_type=jnp.float32))
+    return q_nope, q_rope, latent, gate[..., None]
+
+
+def _mla_out(lp, cfg: LingHybridConfig, att, gate):
+    """att [..., H * v] from Kimi's attention, gated a head."""
+    heads = att.reshape(att.shape[:-1] + (cfg.n_head, cfg.v_head_dim))
+    y = (heads.astype(jnp.float32) * gate).astype(cfg.dtype)
+    return y.reshape(att.shape) @ lp["attn_out"].astype(cfg.dtype)
+
+
+def _rope_tables(cfg: LingHybridConfig, positions):
+    cos, sin = yarn_tables(cfg.mla)
+    return jnp.asarray(cos)[positions], jnp.asarray(sin)[positions]
+
+
+# -- the feed-forward ---------------------------------------------------------
+
+def feed_forward(lp, cfg: LingHybridConfig, i: int, h, valid):
+    """Layer i's feed-forward of h [N, d]: the dense SwiGLU, or this
+    chip's experts' part of the routed sum plus the shared expert.
+    Returns (result [N, d], counts int32[len(MOE_COUNTS)])."""
+    if i < cfg.n_dense_layer:
+        with jax.named_scope("dense_mlp"):
+            return _swiglu(h, lp["mlp_gate_up"], lp["mlp_down"],
+                           cfg.dtype), jnp.zeros(len(MOE_COUNTS), jnp.int32)
+    routed, counts = expert_shard_layer(
+        h, lp["router"], lp["router_bias"],
+        {"gate_up": lp["experts_gate_up"], "down": lp["experts_down"]},
+        cfg.first_expert, cfg.n_experts, cfg.top_k, cfg.routed_scale,
+        valid=valid, n_group=cfg.n_group, topk_group=cfg.topk_group)
+    with jax.named_scope("moe_shared"):
+        shared = _swiglu(h, lp["shared_gate_up"], lp["shared_down"],
+                         cfg.dtype)
+    return routed + shared, counts
+
+
+# -- the three steps ----------------------------------------------------------
+
+def _step_counts(moe_counts, key_slots, state_rows):
+    return jnp.concatenate([moe_counts, jnp.stack([
+        jnp.asarray(key_slots, jnp.int32),
+        jnp.asarray(state_rows, jnp.int32)])])
+
+
+def _window_forward(p, cfg: LingHybridConfig, tokens, start, pages,
+                    page_table, valid_rows, state):
+    """C tokens a sequence from position `start` on: the MLA layers
+    against the cached latents of its pages (none when `pages` is None),
+    the KDA layers from `state` = (states [B, n_kda, H, dk, dv], tails
+    [B, n_kda, (W - 1) * ch]), or from zero when None. The tokens are the
+    leading rows of the window; `valid_rows` [B, C] marks them (None:
+    all), and the rows after them change no state. Returns (logits
+    [B, C, V], latents [B, C, n_mla, row], (states, tails), counts)."""
+    dtype = cfg.dtype
+    b, c = tokens.shape
+    x = p["wte"].astype(dtype)[tokens]
+    positions = jnp.minimum(start[:, None] + jnp.arange(c)[None, :],
+                            cfg.max_seq_len - 1)
+    cos, sin = _rope_tables(cfg, positions)
+    if valid_rows is None:
+        valid_rows = jnp.ones((b, c), bool)
+    n_valid = jnp.sum(valid_rows.astype(jnp.int32), axis=1)
+    flat_valid = valid_rows.reshape(-1)
+    if state is None:
+        state = tuple(jnp.zeros((b,) + shape, dt)
+                      for shape, dt in seq_state(cfg))
+    tail_rows = n_valid[:, None] + jnp.arange(cfg.conv_width - 1)[None, :]
+    latents, states, tails = [], [], []
+    counts, key_slots = jnp.zeros(len(MOE_COUNTS), jnp.int32), jnp.int32(0)
+    for i in range(cfg.n_layer):
+        lp = p[f"layer{i}"]
+        h = _rms(x, lp["attn_norm"], cfg.norm_eps, dtype)
+        if cfg.is_mla(i):
+            q_nope, q_rope, lat, gate = _mla_project(lp, cfg, h, cos, sin)
+            with jax.named_scope("mla_expanded"):
+                att, slots = attend_expanded(
+                    lp, cfg.mla, q_nope, q_rope, lat, start, pages,
+                    page_table, len(latents))
+            x = x + _mla_out(lp, cfg, att, gate)
+            key_slots = key_slots + b * slots
+            latents.append(lat)
+        else:
+            j = len(states)
+            u, g, beta, gate = _kda_project(lp, cfg, h)
+            seen = jnp.concatenate([_tail_rows(cfg, state[1][:, j]), u],
+                                   axis=1)
+            q, k, v = _kda_heads(cfg, _short_conv(seen, lp["kda_conv"]))
+            o, new = kda_chunk(
+                q, k, v, jnp.where(valid_rows[..., None, None], g, 0.0),
+                jnp.where(valid_rows[..., None], beta, 0.0), state[0][:, j])
+            x = x + _kda_out(lp, cfg, o, gate)
+            states.append(new)
+            # the inputs of the last W - 1 tokens, some of them the old
+            # tail's where the window holds fewer
+            tails.append(jnp.take_along_axis(
+                seen, tail_rows[..., None], axis=1).reshape(b, -1))
+        h = _rms(x, lp["mlp_norm"], cfg.norm_eps, dtype)
+        y, n = feed_forward(lp, cfg, i, h.reshape(b * c, -1), flat_valid)
+        x = x + y.reshape(b, c, -1)
+        counts = counts + n
+    return _head(p, cfg, x), jnp.stack(latents, axis=2), \
+        (jnp.stack(states, axis=1), jnp.stack(tails, axis=1)), \
+        _step_counts(counts, key_slots, b * len(states))
+
+
+def prefill_step(variables, cfg: LingHybridConfig, tokens, true_len,
+                 valid=None):
+    """Full forward over a padded prompt batch, every state from zero.
+    tokens [B, S]; true_len [B]; `valid` [B, S] marks the rows that are
+    tokens (None: the first `true_len`). Returns (next_logits [B, V],
+    latents [B, S, n_mla, row], states, tails, counts); latent rows past
+    true_len are garbage the caller must not cache, the states are those
+    after the last token."""
+    p = unboxed_params(variables)
+    b, s = tokens.shape
+    if valid is None:
+        valid = jnp.arange(s)[None, :] < true_len[:, None]
+    logits, latents, state, counts = _window_forward(
+        p, cfg, tokens, jnp.zeros((b,), jnp.int32), None, None, valid, None)
+    idx = jnp.maximum(true_len - 1, 0)
+    next_logits = jnp.take_along_axis(
+        logits, idx[:, None, None], axis=1)[:, 0]
+    return (next_logits, latents) + state + (counts,)
+
+
+def chunk_step(variables, cfg: LingHybridConfig, tokens, start, pages,
+               page_table, seq_state=None, slots=None, valid=None):
+    """C tokens a sequence against a paged cache that holds its first
+    `start` positions and the state arena's slot that holds its KDA state
+    after them. `seq_state` the arena's arrays ([slots, n_kda, ...]),
+    `slots` [B]. A sequence's first window (`start` 0) starts from zero
+    whatever its slot held. Returns (logits [B, C, V], latents, states,
+    tails, counts)."""
+    first = start == 0
+    state = tuple(jnp.where(first.reshape((-1,) + (1,) * (a.ndim - 1)),
+                            jnp.zeros((), a.dtype), a[slots])
+                  for a in seq_state)
+    logits, latents, state, counts = _window_forward(
+        unboxed_params(variables), cfg, tokens, start, pages, page_table,
+        valid, state)
+    return (logits, latents) + state + (counts,)
+
+
+def decode_step(variables, cfg: LingHybridConfig, tokens, positions, pages,
+                page_table, seq_state=None, slots=None, valid=None):
+    """One token a sequence: the MLA layers' absorbed path over the paged
+    cache, the KDA layers' recurrence on the lanes' slots of the state
+    arena (`seq_state`, gathered a layer at a time). tokens [B]; positions
+    [B]; `valid` [B] marks the lanes that hold a sequence. Returns (logits
+    [B, V], latents [B, n_mla, row], states [B, n_kda, H, dk, dv], tails
+    [B, n_kda, (W - 1) * ch], counts)."""
+    p = unboxed_params(variables)
+    dtype = cfg.dtype
+    b = tokens.shape[0]
+    x = p["wte"].astype(dtype)[tokens]
+    cos, sin = _rope_tables(cfg, positions)
+    t_max = page_table.shape[1] * pages.shape[2]
+    key_idx = jnp.arange(t_max + 1)
+    seen_keys = (key_idx[None, :] < positions[:, None]) | \
+        (key_idx[None, :] == t_max)
+    s_arena, tail_arena = seq_state
+    latents, states, tails = [], [], []
+    counts = jnp.zeros(len(MOE_COUNTS), jnp.int32)
+    for i in range(cfg.n_layer):
+        lp = p[f"layer{i}"]
+        h = _rms(x, lp["attn_norm"], cfg.norm_eps, dtype)
+        if cfg.is_mla(i):
+            q_nope, q_rope, lat, gate = _mla_project(lp, cfg, h, cos, sin)
+            with jax.named_scope("mla_absorbed"):
+                att = attend_absorbed(
+                    lp, cfg.mla, q_nope, q_rope,
+                    _gather_pages(pages, page_table,
+                                  len(latents)).astype(dtype),
+                    lat, seen_keys)
+            x = x + _mla_out(lp, cfg, att, gate)
+            latents.append(lat)
+        else:
+            j = len(states)
+            u, g, beta, gate = _kda_project(lp, cfg, h)
+            seen = jnp.concatenate(
+                [_tail_rows(cfg, tail_arena[slots, j]), u[:, None]], axis=1)
+            q, k, v = _kda_heads(cfg, _short_conv(seen, lp["kda_conv"])[:, 0])
+            o, new = kda_step(q, k, v, g, beta, s_arena[slots, j])
+            x = x + _kda_out(lp, cfg, o, gate)
+            states.append(new)
+            tails.append(seen[:, 1:].reshape(b, -1))
+        h = _rms(x, lp["mlp_norm"], cfg.norm_eps, dtype)
+        y, n = feed_forward(lp, cfg, i, h, valid)
+        x = x + y
+        counts = counts + n
+    lanes = b if valid is None else jnp.sum(valid.astype(jnp.int32))
+    # every lane of the bucket scores all of its table's slots and itself
+    return _head(p, cfg, x), jnp.stack(latents, axis=1), \
+        jnp.stack(states, axis=1), jnp.stack(tails, axis=1), \
+        _step_counts(counts, len(latents) * b * (t_max + 1),
+                     lanes * len(states))

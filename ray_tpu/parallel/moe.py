@@ -142,18 +142,32 @@ MOE_COUNTS = ("pairs_routed", "pairs_local", "pairs_computed",
               "expert_calls")
 
 
-def sigmoid_topk_route(x, router_w, bias, top_k: int, scale: float):
+def sigmoid_topk_route(x, router_w, bias, top_k: int, scale: float,
+                       n_group: int = 1, topk_group: int = 1):
     """Sigmoid-scored top-k routing with a selection bias (DeepSeek-V3's
-    `noaux_tc` with one group, Kimi-K2's). x [N, d]; router_w
+    `noaux_tc`; one group is Kimi-K2's). x [N, d]; router_w
     [d, n_experts]; bias [n_experts]. The experts are the top-k of
     `sigmoid(x W) + bias`; their weights are the scores WITHOUT the bias,
-    over their sum, times `scale`. Float32 at the highest matmul precision:
-    a rounded score changes which expert a token goes to. Returns
-    (expert ids [N, top_k] int32, weights [N, top_k] float32)."""
+    over their sum, times `scale`. With `n_group` > 1 the selection is
+    group-limited: the experts are `n_group` groups of consecutive ones, a
+    group's score is the sum of its two largest biased scores, the
+    `topk_group` best groups stay and the top-k is over their experts
+    alone. Float32 at the highest matmul precision: a rounded score
+    changes which expert a token goes to. Returns (expert ids [N, top_k]
+    int32, weights [N, top_k] float32)."""
     scores = jax.nn.sigmoid(jnp.dot(
         x.astype(jnp.float32), router_w.astype(jnp.float32),
         precision=lax.Precision.HIGHEST))
-    _, expert = lax.top_k(scores + bias.astype(jnp.float32), top_k)
+    choice = scores + bias.astype(jnp.float32)
+    if n_group > 1:
+        grouped = choice.reshape(choice.shape[0], n_group, -1)
+        group_score = jnp.sum(lax.top_k(grouped, 2)[0], axis=-1)
+        _, kept = lax.top_k(group_score, topk_group)
+        keep = jnp.any(kept[:, :, None] == jnp.arange(n_group)[None, None],
+                       axis=1)
+        choice = jnp.where(keep[:, :, None], grouped, -jnp.inf).reshape(
+            choice.shape)
+    _, expert = lax.top_k(choice, top_k)
     weight = jnp.take_along_axis(scores, expert, axis=-1)
     weight = weight / jnp.sum(weight, axis=-1, keepdims=True) * scale
     return expert.astype(jnp.int32), weight
@@ -161,10 +175,11 @@ def sigmoid_topk_route(x, router_w, bias, top_k: int, scale: float):
 
 def expert_shard_layer(x, router_w, bias, experts_held, first_expert: int,
                        n_experts: int, top_k: int, scale: float,
-                       valid=None):
+                       valid=None, n_group: int = 1, topk_group: int = 1):
     """What the chip that holds experts [first_expert, first_expert + held)
     of `n_experts` adds to a routed expert layer: every token is routed
-    over ALL the experts, the (token, expert) pairs whose expert lives here
+    over ALL the experts (`n_group`, `topk_group`: group-limited, as
+    `sigmoid_topk_route` says), the (token, expert) pairs whose expert lives here
     are kept, and the result is the weighted sum of the held experts'
     outputs alone. No capacity: a pair is never dropped. The kept pairs are
     sorted by expert and each projection is one grouped product
@@ -185,7 +200,8 @@ def expert_shard_layer(x, router_w, bias, experts_held, first_expert: int,
     n, d = x.shape
     held = experts_held["down"].shape[0]
     with jax.named_scope("moe_route"):
-        expert, weight = sigmoid_topk_route(x, router_w, bias, top_k, scale)
+        expert, weight = sigmoid_topk_route(x, router_w, bias, top_k, scale,
+                                            n_group, topk_group)
         local = (expert >= first_expert) & (expert < first_expert + held)
         if valid is not None:
             local = local & valid[:, None]

@@ -46,7 +46,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from ray_tpu.serve.llm.kv_cache import (OutOfPagesError, PagedKVCache,
-                                        PrefixCache, scatter_arena)
+                                        PrefixCache, scatter_arena,
+                                        scatter_state)
 from ray_tpu.util import metrics as _metrics
 from ray_tpu.util import request_recorder as _rr
 from ray_tpu.util import step_profiler as _sp
@@ -109,13 +110,23 @@ class ModelFamily:
     [n_kv_head, head_dim]. `step_counts` names the module's tuple of
     counter names: its steps then take `valid=` (the rows that are tokens)
     and return an int32 vector of that length after the cache rows, which
-    the engine adds to `decode_<name>` / `prefill_<name>`."""
+    the engine adds to `decode_<name>` / `prefill_<name>`. `seq_state`
+    names the module's function from a config to what a SEQUENCE keeps
+    beside its pages (one (shape, dtype) an array: a recurrent layer's
+    state): the cache manager then keeps a slot a sequence, the chunk and
+    decode steps take the arena's arrays and the lanes' slots (`seq_state=`,
+    `slots=`), and every step returns the sequences' new states after the
+    cache rows. `paged_layers` names the module's function from a config to
+    how many of its layers leave rows in the paged arena (all of them where
+    it is not given)."""
 
     module: str
     net: str
     config: str
     cache_rows: Optional[str] = None
     step_counts: Optional[str] = None
+    seq_state: Optional[str] = None
+    paged_layers: Optional[str] = None
 
 
 MODEL_FAMILIES: Dict[str, ModelFamily] = {
@@ -123,6 +134,9 @@ MODEL_FAMILIES: Dict[str, ModelFamily] = {
     "gpt": ModelFamily("ray_tpu.models.gpt", "GPT", "GPTConfig"),
     "kimi_k2": ModelFamily("ray_tpu.models.kimi_k2", "KimiK2",
                            "KimiK2Config", "cache_rows", "STEP_COUNTS"),
+    "ling_hybrid": ModelFamily("ray_tpu.models.ling_hybrid", "LingHybrid",
+                               "LingHybridConfig", "cache_rows",
+                               "STEP_COUNTS", "seq_state", "paged_layers"),
 }
 
 
@@ -140,6 +154,12 @@ def _valid_rows(counted: bool, w_page, arena) -> dict:
     """`valid=` for the steps of a family that counts (`step_counts`): a
     row is a token's where the program is to write it."""
     return {"valid": w_page < arena[0].shape[0]} if counted else {}
+
+
+def _state_args(state, slots=None) -> dict:
+    """`seq_state=` and `slots=` for the chunk and decode steps of a family
+    that keeps sequence state; nothing for the others."""
+    return {"seq_state": state, "slots": slots} if state else {}
 
 
 def _kv_rows(cfg) -> Tuple[Tuple[int, int], ...]:
@@ -238,6 +258,10 @@ class Request:
     # -- engine side -----------------------------------------------------
 
     def _emit(self, token: int):
+        self._hand_over(self._record(token), token)
+
+    def _record(self, token: int) -> int:
+        """Note the token as generated; returns its index."""
         # per-token recorder cost: one monotonic read (TPOT = span
         # between the first and last of these stamps)
         now = time.monotonic()
@@ -245,10 +269,14 @@ class Request:
             self.first_token_ts = now
         self.last_token_ts = now
         self.tokens.append(token)
+        return len(self.tokens) - 1
+
+    def _hand_over(self, index: int, token: int):
+        """Pass a recorded token on to whoever reads the request."""
         if self.sink is not None:
-            self.sink("token", len(self.tokens) - 1, token)
+            self.sink("token", index, token)
         else:
-            self.out_q.put(("token", len(self.tokens) - 1, token))
+            self.out_q.put(("token", index, token))
 
     def _finish(self, reason: str):
         self.finish_reason = reason
@@ -275,14 +303,16 @@ class _Sequence:
     `pos` is the number of tokens in the KV cache (= prompt +
     generated - 1 in steady state: the newest token rides as the next
     dispatch's input). `prefilled`/`cached` track the chunked-prefill
-    frontier (prefilled starts at the prefix-cache hit length)."""
+    frontier (prefilled starts at the prefix-cache hit length). `slot` is
+    the sequence's row of the state arena, for a family that keeps one."""
 
-    __slots__ = ("req", "pages", "pos", "prefilled", "cached")
+    __slots__ = ("req", "pages", "pos", "prefilled", "cached", "slot")
 
     def __init__(self, req: Request, pages: List[int], pos: int,
-                 cached: int = 0):
+                 cached: int = 0, slot: Optional[int] = None):
         self.req = req
         self.pages = pages
+        self.slot = slot
         self.pos = pos  # tokens already written to the KV cache
         self.prefilled = pos or cached
         self.cached = cached
@@ -347,18 +377,32 @@ class LLMEngine:
         self._pump_phase: Optional[_tracing.Phase] = None
         self._pump_wall_ns = 0  # of pump threads that have ended
 
+        seq_state = getattr(mod, family.seq_state)(self.model_cfg) \
+            if family.seq_state else ()
+        if seq_state and cfg.prefix_cache:
+            raise ValueError(
+                f"prefix_cache=1 with the {model!r} family: its layers keep "
+                f"one state a sequence, which a page alias cannot restore "
+                f"(a prefix hit would start the suffix from a state that "
+                f"never saw the prefix); pass prefix_cache=0")
         self.kv = PagedKVCache(
-            cfg.num_pages, self.model_cfg.n_layer, cfg.block_size,
+            cfg.num_pages,
+            getattr(mod, family.paged_layers)(self.model_cfg)
+            if family.paged_layers else self.model_cfg.n_layer,
+            cfg.block_size,
             rows=self._cache_rows(self.model_cfg),
             dtype=jnp.dtype(self.model_cfg.dtype),
-            lock=_tracing.TimedLock(self._phases, threading.Lock()))
+            lock=_tracing.TimedLock(self._phases, threading.Lock()),
+            seq_state=seq_state, seq_slots=cfg.max_running)
         self.prefix = PrefixCache(self.kv) if cfg.prefix_cache else None
 
         # one compiled_step wrapper per bucket: each sees exactly one
         # abstract signature, so on_retrace="error" turns any shape
         # drift in steady-state serving into a loud failure. Every one
-        # takes the arena's arrays from argument 3 on, donated.
-        arena_args = tuple(range(3, 3 + len(self.kv.arena)))
+        # takes the arena's arrays (the pages', then the sequence states')
+        # from argument 3 on, donated.
+        arena_args = tuple(range(
+            3, 3 + len(self.kv.arena) + len(self.kv.state)))
 
         def program(fn):
             return compiled_step(fn, donate_argnums=arena_args,
@@ -377,6 +421,9 @@ class LLMEngine:
         self._waiting: List[Request] = []
         self._prefilling: List[_Sequence] = []
         self._running: List[_Sequence] = []
+        # (request, index, token) a decode pass sampled and has not yet
+        # passed on to its readers (`_hand_over_held`)
+        self._held: List[Tuple[Request, int, int]] = []
         # dispatch plane v2: (ring, sub-ring index, deployment) once a
         # replica attaches its native intake — drained by the pump
         self._intake = None
@@ -437,36 +484,46 @@ class LLMEngine:
     # new cache rows into the donated arena, which it returns after the
     # logits (and before the step's counts, where the family has any). The
     # arena's arrays are `rest[:n]`; a row is a token's where it is written.
+    # A family that keeps sequence state has its `m` arrays next and the
+    # lanes' slots last: the step's new states, which follow its cache
+    # rows, are scattered to the slots (`scatter_state`) and the arrays
+    # returned after the pages'.
 
     def _make_prefill_fn(self, bucket: int):
-        mod, n = self._mod, len(self.kv.arena)
+        mod, n, m = self._mod, len(self.kv.arena), len(self.kv.state)
         counted = bool(self._step_counts)
         cfg = self.model_cfg
 
         def fn(variables, tokens, true_len, *rest):
-            arena, (w_page, w_off) = rest[:n], rest[n:]
+            arena, state = rest[:n], rest[n:n + m]
+            w_page, w_off, *slots = rest[n + m:]
             logits, *out = mod.prefill_step(
                 variables, cfg, tokens, true_len,
                 **_valid_rows(counted, w_page[None], arena))
             return (logits,) + scatter_arena(
                 arena, [rows[0] for rows in out[:n]], w_page, w_off) \
-                + tuple(out[n:])
+                + scatter_state(state, out[n:n + m], *slots) \
+                + tuple(out[n + m:])
 
         fn.__name__ = f"llm_prefill_s{bucket}"
         return fn
 
     def _make_decode_fn(self, batch: int):
-        mod, n = self._mod, len(self.kv.arena)
+        mod, n, m = self._mod, len(self.kv.arena), len(self.kv.state)
         counted = bool(self._step_counts)
         cfg = self.model_cfg
 
         def fn(variables, tokens, positions, *rest):
-            arena, (page_table, w_page, w_off) = rest[:n], rest[n:]
+            arena, state = rest[:n], rest[n:n + m]
+            page_table, w_page, w_off, *slots = rest[n + m:]
             logits, *out = mod.decode_step(
                 variables, cfg, tokens, positions, *arena, page_table,
+                **_state_args(state, *slots),
                 **_valid_rows(counted, w_page, arena))
             return (logits,) + scatter_arena(arena, out[:n], w_page,
-                                             w_off) + tuple(out[n:])
+                                             w_off) \
+                + scatter_state(state, out[n:n + m], *slots) \
+                + tuple(out[n + m:])
 
         fn.__name__ = f"llm_decode_b{batch}"
         return fn
@@ -474,18 +531,22 @@ class LLMEngine:
     def _make_chunk_fn(self, size: int):
         """A window of `size` tokens of one sequence (chunked prefill, a
         prefix-cache suffix): `w_page` / `w_off` are [1, size]."""
-        mod, n = self._mod, len(self.kv.arena)
+        mod, n, m = self._mod, len(self.kv.arena), len(self.kv.state)
         counted = bool(self._step_counts)
         cfg = self.model_cfg
 
         def fn(variables, tokens, start, *rest):
-            arena, (page_table, w_page, w_off) = rest[:n], rest[n:]
+            arena, state = rest[:n], rest[n:n + m]
+            page_table, w_page, w_off, *slots = rest[n + m:]
             logits, *out = mod.chunk_step(
                 variables, cfg, tokens, start, *arena, page_table,
+                **_state_args(state, *slots),
                 **_valid_rows(counted, w_page, arena))
             return (logits,) + scatter_arena(
                 arena, [r.reshape((-1,) + r.shape[2:]) for r in out[:n]],
-                w_page.reshape(-1), w_off.reshape(-1)) + tuple(out[n:])
+                w_page.reshape(-1), w_off.reshape(-1)) \
+                + scatter_state(state, out[n:n + m], *slots) \
+                + tuple(out[n + m:])
 
         fn.__name__ = f"llm_chunk_c{size}"
         return fn
@@ -508,8 +569,9 @@ class LLMEngine:
         for s, fn in self._prefill_fns.items():
             self._call(fn, (
                 self.params, np.zeros((1, s), np.int32),
-                np.ones((1,), np.int32), *kv.arena,
-                np.full(s, kv.num_pages, np.int32), np.zeros(s, np.int32)))
+                np.ones((1,), np.int32), *kv.arena, *kv.state,
+                np.full(s, kv.num_pages, np.int32), np.zeros(s, np.int32),
+                *self._slots_of((), 1)))
         for b, fn in self._decode_fns.items():
             self._warm_call(fn, (b,))
         self._warm_call(self._chunk_fn, (1, self._chunk_size))
@@ -517,12 +579,26 @@ class LLMEngine:
     def _call(self, fn, args):
         """One call of a program. `args` hold the arena, donated: its
         successor, which follows the logits among the outputs, goes back
-        into `self.kv`. Returns the logits, still on the device, and what
-        the family's step counted (a tuple, empty for most families)."""
+        into `self.kv` (the pages' arrays, then the sequence states').
+        Returns the logits, still on the device, and what the family's
+        step counted (a tuple, empty for most families)."""
         out = fn(*args)
+        self._hand_over_held()
         n = len(self.kv.arena)
+        m = n + len(self.kv.state)
         self.kv.arena = tuple(out[1:1 + n])
-        return out[0], tuple(out[1 + n:])
+        self.kv.state = tuple(out[1 + n:1 + m])
+        return out[0], tuple(out[1 + m:])
+
+    def _slots_of(self, seqs, lanes: int) -> Tuple[np.ndarray, ...]:
+        """The programs' last argument for a family that keeps sequence
+        state: lane i's slot of the state arena, the scratch slot for a
+        lane past `seqs`. Nothing for the other families."""
+        if not self.kv.state:
+            return ()
+        slots = np.full(lanes, self.kv.scratch_slot, np.int32)
+        slots[:len(seqs)] = [seq.slot for seq in seqs]
+        return (slots,)
 
     def _add_step_counts(self, kind: str, counts, link: str) -> None:
         """Fetch what a step counted on the device (`step_counts` of the
@@ -540,8 +616,10 @@ class LLMEngine:
         b, kv = rows[0], self.kv
         self._call(fn, (
             self.params, np.zeros(rows, np.int32), np.zeros(b, np.int32),
-            *kv.arena, np.zeros((b, self.max_pages_per_seq), np.int32),
-            np.full(rows, kv.num_pages, np.int32), np.zeros(rows, np.int32)))
+            *kv.arena, *kv.state,
+            np.zeros((b, self.max_pages_per_seq), np.int32),
+            np.full(rows, kv.num_pages, np.int32), np.zeros(rows, np.int32),
+            *self._slots_of((), b)))
 
     # -- submission -------------------------------------------------------
 
@@ -691,9 +769,12 @@ class LLMEngine:
                     pages = self.kv.alloc(need, req)
             except OutOfPagesError:
                 return None
+            # a slot a running sequence (`seq_slots=max_running`), and the
+            # cap above has left room: one is free
+            slot = self.kv.take_slot(req) if self.kv.state else None
             req.admit_ts = time.monotonic()
             self._waiting.pop(0)
-            seq = _Sequence(req, pages, pos=0, cached=cached)
+            seq = _Sequence(req, pages, pos=0, cached=cached, slot=slot)
             self._prefilling.append(seq)
         return seq
 
@@ -774,7 +855,7 @@ class LLMEngine:
             self._count_link("prefill_link_bytes", *args)
             logits, counts = self._call(fn, args)
         with phase("prefill_device_wait"):
-            self._block_until_ready((logits, self.kv.arena))
+            self._block_until_ready((logits, self.kv.arena, self.kv.state))
             self._add_step_counts("prefill", counts, "prefill_link_bytes")
         return logits
 
@@ -794,7 +875,8 @@ class LLMEngine:
             next_logits = self._prefill_forward(
                 self._prefill_fns[bucket],
                 (self.params, toks, np.asarray([s], np.int32),
-                 *self.kv.arena, w_page, w_off))
+                 *self.kv.arena, *self.kv.state, w_page, w_off,
+                 *self._slots_of((seq,), 1)))
             with phase("prefill_kv_write"):
                 seq.prefilled = s
                 seq.pos = s
@@ -831,8 +913,8 @@ class LLMEngine:
             logits = self._prefill_forward(
                 self._chunk_fn,
                 (self.params, toks, np.asarray([seq.prefilled], np.int32),
-                 *self.kv.arena, table,
-                 w_page[None], w_off[None]))
+                 *self.kv.arena, *self.kv.state, table,
+                 w_page[None], w_off[None], *self._slots_of((seq,), 1)))
             with phase("prefill_kv_write"):
                 seq.prefilled += take
                 with self._lock:
@@ -857,7 +939,7 @@ class LLMEngine:
             logits, counts = self._call(fn, args)
         with phase("decode_device_wait"):
             # the np.asarray below would block on the logits anyway
-            self._block_until_ready((logits, self.kv.arena))
+            self._block_until_ready((logits, self.kv.arena, self.kv.state))
         with phase("decode_fetch"):
             logits = np.asarray(logits)
             self._count_link("decode_link_bytes", logits, *args)
@@ -892,17 +974,17 @@ class LLMEngine:
             logits = self._decode_forward(
                 self._decode_fns[bb],
                 (self.params, tokens, positions,
-                 *self.kv.arena, page_table,
-                 w_page, w_off))
+                 *self.kv.arena, *self.kv.state, page_table,
+                 w_page, w_off, *self._slots_of(runs, bb)))
             with phase("decode_kv_append"):
                 context = int(positions.sum())
                 for seq in runs:
                     seq.pos += 1
             finished = []
             with phase("decode_sample"):
-                for i, seq in enumerate(runs):
-                    tok = int(np.argmax(logits[i]))
-                    seq.req._emit(tok)
+                toks = np.argmax(logits[:len(runs)], axis=-1).tolist()
+                for seq, tok in zip(runs, toks):
+                    self._held.append((seq.req, seq.req._record(tok), tok))
                     if self._seq_finished(seq, tok):
                         finished.append(seq)
                 with self._lock:
@@ -929,12 +1011,27 @@ class LLMEngine:
             return True
         return False
 
+    def _hand_over_held(self):
+        """Pass the tokens of the last decode pass on to their readers.
+        Every reader that wakes wants the interpreter, so the pass holds
+        its tokens until the next call into a program is on its way (or
+        a request of it ends): the readers then run while the device
+        does, not between a step's logits and the next step's call."""
+        if self._held:
+            with self._phases.phase("decode_sample"):
+                held, self._held = self._held, []
+                for req, index, tok in held:
+                    req._hand_over(index, tok)
+
     def _finish(self, seq: _Sequence):
+        self._hand_over_held()
         with self._phases.phase("finish"):
             # refcounted free: pages the prefix cache (or a sibling
             # sequence) still aliases survive this — only the refcount
             # drops
             self.kv.free(seq.pages, seq.req)
+            if seq.slot is not None:
+                self.kv.free_slot(seq.slot, seq.req)
             with self._lock:
                 if seq in self._running:
                     self._running.remove(seq)
@@ -1107,6 +1204,7 @@ class LLMEngine:
         if self._thread is not None:
             self._thread.join(timeout=10)
             self._thread = None
+            self._hand_over_held()
             from ray_tpu._private import health as health_mod
 
             health_mod.unwatch_loop(
@@ -1192,6 +1290,10 @@ class LLMEngine:
                 tenants={t: dict(row)
                          for t, row in self.tenant_counters.items()},
             )
+        if self.kv.state:
+            out.update(state_slots_live=self.kv.live_slots,
+                       state_slots_free=self.kv.free_slots,
+                       state_arena_bytes=self.kv.state_nbytes)
         if self.prefix is not None:
             ps = self.prefix.stats()
             out.update(

@@ -31,6 +31,15 @@ page's tail is still being appended to), so a shared page is immutable
 by construction — aliasing is a page-table row edit plus a refcount,
 never a byte copy, and no writer ever touches a shared page.
 
+A family whose layers keep one state a SEQUENCE (a recurrent layer's
+matrix, overwritten every step) declares those arrays (`seq_state`), and the
+cache manager keeps a second arena of them, [slots, *shape] each, beside the
+pages: a sequence takes one slot when it takes its pages and gives it back
+with them (`take_slot`, `free_slot`); the engine's programs take the arrays
+donated after the pages, gather and scatter a lane's slot, and hand them
+back (`scatter_state`). The last slot belongs to nobody: it is where the
+padded lanes of a decode bucket read and write.
+
 A dead replica's arena dies with its process: the device memory is the
 process's own, so there is nothing for a peer to reclaim.
 """
@@ -73,6 +82,17 @@ def scatter_arena(arena, rows, w_page, w_off):
                  for pages, new in zip(arena, rows))
 
 
+def scatter_state(state, new, slots=None):
+    """Sequence n's new state (`new`: one array [N, *shape] for each array
+    of `state`, [slots, *shape]) goes to its slot `slots[n]`; returns the
+    updated arrays, a tuple. Traced inside the engine's programs with the
+    arrays donated, an update in place. Padded lanes all name the scratch
+    slot, and which of them lands there last is nobody's business. A
+    family that keeps no such state has no arrays and no `slots`."""
+    return tuple(arr.at[slots].set(x.astype(arr.dtype))
+                 for arr, x in zip(state, new))
+
+
 @functools.lru_cache(maxsize=None)
 def _scatter_arena_jit():
     """`scatter_arena` as a program of its own, for callers that hold the
@@ -101,7 +121,8 @@ class PagedKVCache:
                  n_kv_head: Optional[int] = None,
                  head_dim: Optional[int] = None, dtype=np.float32,
                  store=None, lock=None,
-                 rows: Optional[Tuple[Tuple[int, ...], ...]] = None):
+                 rows: Optional[Tuple[Tuple[int, ...], ...]] = None,
+                 seq_state=(), seq_slots: int = 0):
         import jax.numpy as jnp
 
         if num_pages <= 0 or block_size <= 0:
@@ -138,6 +159,18 @@ class PagedKVCache:
         # there stalls the pump (seconds under a profiler's Python tracer)
         self._live: Dict[int, int] = {}
         self._prefix_cache: Optional["PrefixCache"] = None
+        # what a sequence keeps beside its pages: `seq_state` is one
+        # (shape, dtype) an array, `seq_slots` how many sequences; one
+        # more slot, the last, is scratch for lanes that hold no sequence
+        state_shapes = [((seq_slots + 1,) + tuple(shape), np.dtype(dt))
+                        for shape, dt in seq_state]
+        self.state_nbytes = sum(int(np.prod(shape)) * dt.itemsize
+                                for shape, dt in state_shapes)
+        self.state = tuple(jnp.zeros(shape, dt) for shape, dt in state_shapes)
+        self.num_slots = seq_slots if seq_state else 0
+        self.scratch_slot = self.num_slots
+        self._free_slots: List[int] = list(range(self.num_slots - 1, -1, -1))
+        self._slot_owner: Dict[int, object] = {}
         self._closed = False
 
     @property
@@ -269,6 +302,42 @@ class PagedKVCache:
                 del self._holders[p]
                 self._free.append(p)
 
+    # -- sequence-state slots --------------------------------------------
+
+    @property
+    def free_slots(self) -> int:
+        with self._lock:
+            return len(self._free_slots)
+
+    @property
+    def live_slots(self) -> int:
+        with self._lock:
+            return len(self._slot_owner)
+
+    def take_slot(self, owner) -> int:
+        """A slot of the sequence-state arena for `owner`. The engine holds
+        as many slots as it runs sequences, so none free is a bug, not
+        load: KVCacheError. What the slot held is the last owner's: a
+        sequence's first prefill unit starts from zero."""
+        with self._lock:
+            self._check_open()
+            if not self._free_slots:
+                raise KVCacheError(
+                    f"no state slot free of {self.num_slots}")
+            slot = self._free_slots.pop()
+            self._slot_owner[slot] = owner
+            return slot
+
+    def free_slot(self, slot: int, owner) -> None:
+        """Give `owner`'s slot back; raises on a slot it does not hold."""
+        with self._lock:
+            self._check_open()
+            if self._slot_owner.get(slot) is not owner:
+                raise KVCacheError(
+                    f"free of state slot {slot} not held by owner")
+            del self._slot_owner[slot]
+            self._free_slots.append(slot)
+
     # -- data plane -------------------------------------------------------
 
     def write_index(self, pages: List[int], start: int, n: int,
@@ -343,21 +412,30 @@ class PagedKVCache:
                 raise KVCacheError(
                     f"free-list corrupt: {len(self._free)} free + "
                     f"{len(self._holders)} held != {self.num_pages}")
+            if self._slot_owner:
+                owners = sorted(repr(o) for o in self._slot_owner.values())
+                raise KVCacheError(
+                    f"state slot leak: {len(self._slot_owner)} live slots "
+                    f"at quiesce (owners: {owners[:4]})")
+            if len(self._free_slots) != self.num_slots:
+                raise KVCacheError(
+                    f"slot free-list corrupt: {len(self._free_slots)} free "
+                    f"of {self.num_slots}")
 
     def close(self) -> int:
-        """Drop the arena. Returns the number of pages still
-        sequence-live (0 when the engine quiesced cleanly; prefix-cache
-        holds are not leaks — `PrefixCache.drain()` first for a strict
-        zero-held close)."""
+        """Drop the arenas. Returns the number of pages and state slots
+        still sequence-live (0 when the engine quiesced cleanly;
+        prefix-cache holds are not leaks — `PrefixCache.drain()` first for
+        a strict zero-held close)."""
         with self._lock:
             if self._closed:
                 return 0
             self._closed = True
-            leaked = len(self._live)
-            for pages in self.arena:
-                if not pages.is_deleted():
-                    pages.delete()  # the device memory, now
-            self.arena = ()
+            leaked = len(self._live) + len(self._slot_owner)
+            for array in self.arena + self.state:
+                if not array.is_deleted():
+                    array.delete()  # the device memory, now
+            self.arena = self.state = ()
             return leaked
 
     def _check_open(self):

@@ -129,7 +129,7 @@ def _compile_engine_program(topo, cfg, kind, size, block=16, num_pages=2048):
                      cfg.head_dim), jnp.bfloat16)
     engine = types.SimpleNamespace(
         _mod=llama, model_cfg=cfg, _step_counts=(),
-        kv=types.SimpleNamespace(arena=(pages, pages)))
+        kv=types.SimpleNamespace(arena=(pages, pages), state=()))
     if kind == "decode":
         fn = LLMEngine._make_decode_fn(engine, size)
         args = (params, on_chip((size,)), on_chip((size,)), pages, pages,
@@ -254,7 +254,7 @@ def test_latent_arena_is_updated_in_place_at_kimi_k2_widths(topo, kind, size):
         pages = on_chip((num_pages, cfg.n_layer, block) + row, jnp.bfloat16)
         engine = types.SimpleNamespace(
             _mod=kimi_k2, model_cfg=cfg, _step_counts=kimi_k2.STEP_COUNTS,
-            kv=types.SimpleNamespace(arena=(pages,)))
+            kv=types.SimpleNamespace(arena=(pages,), state=()))
         table = on_chip((size if kind == "decode" else 1,
                          cfg.max_seq_len // block))
         if kind == "decode":
@@ -291,6 +291,67 @@ def test_latent_arena_is_updated_in_place_at_kimi_k2_widths(topo, kind, size):
             assert kimi_k2.cache_rows(cfg) == ((576,),)
             _, _, moved, _ = compile_at(cfg)
         assert moved, "a 576-wide arena is in place now: drop the padding"
+
+
+@pytest.mark.parametrize("kind, size", [("decode", 64), ("chunk", 1024)])
+def test_sequence_state_arena_is_updated_in_place_at_ling_widths(topo, kind,
+                                                                 size):
+    """The engine's decode-64 and chunk-1,024 programs of the Ling hybrid
+    cell (published widths, 7 layers of which one pages, 128 of 512 experts
+    held, 32,768 pages of 16 tokens, 65 state slots): the latent arena and
+    both sequence-state arrays alias their outputs, no operation copies or
+    re-lays out an array of the state arena's or the latent arena's shape
+    (the decode step gathers a lane's state a layer at a time and scatters
+    the lanes' new states at their slots), and the program fits the chip
+    beside its 10.5 GB of weights."""
+    import types
+
+    from ray_tpu.models import ling_hybrid
+    from ray_tpu.serve.llm.engine import LLMEngine
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    cfg = ling_hybrid.LingHybridConfig(
+        vocab_size=39296, n_layer=7, n_dense_layer=1, experts_held=128,
+        max_seq_len=8192)
+    block, num_pages, slots = 16, 32768, 65
+
+    def on_chip(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: on_chip(a.shape, a.dtype),
+        jax.eval_shape(ling_hybrid.LingHybrid(cfg).init,
+                       jax.random.PRNGKey(0), jnp.ones((1, 16), jnp.int32)))
+    (row,) = ling_hybrid.cache_rows(cfg)
+    pages = on_chip((num_pages, ling_hybrid.paged_layers(cfg), block) + row,
+                    jnp.bfloat16)
+    state = tuple(on_chip((slots,) + shape, dtype)
+                  for shape, dtype in ling_hybrid.seq_state(cfg))
+    assert pages.shape[1] == 1 and state[0].shape == (65, 6, 32, 128, 128)
+    engine = types.SimpleNamespace(
+        _mod=ling_hybrid, model_cfg=cfg,
+        _step_counts=ling_hybrid.STEP_COUNTS,
+        kv=types.SimpleNamespace(arena=(pages,), state=state))
+    lanes = size if kind == "decode" else 1
+    rows = (size,) if kind == "decode" else (1, size)
+    fn = LLMEngine._make_decode_fn(engine, size) if kind == "decode" \
+        else LLMEngine._make_chunk_fn(engine, size)
+    args = (params, on_chip(rows), on_chip((lanes,)), pages, *state,
+            on_chip((lanes, cfg.max_seq_len // block)), on_chip(rows),
+            on_chip(rows), on_chip((lanes,)))
+    compiled = jax.jit(fn, donate_argnums=(3, 4, 5)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    held = [2 * math.prod(pages.shape), 4 * math.prod(state[0].shape),
+            2 * math.prod(state[1].shape)]
+    # the tail's rows of bf16 are padded to whole tiles: 3 MB over
+    assert sum(held) <= mem.alias_size_in_bytes < sum(held) + 2**22
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    text = compiled.as_text()
+    for array, dtype in ((pages, "bf16"), (state[0], "f32")):
+        shape = dtype + "[" + ",".join(map(str, array.shape)) + "]"
+        moved = [line.strip()[:120] for line in text.splitlines()
+                 if " copy(" in line and shape in line.split(" copy(")[0]]
+        assert not moved, moved
 
 
 def test_build_mesh_on_tpu_follows_the_topology(topo):

@@ -358,6 +358,37 @@ def test_decode_lanes_beyond_the_running_set_write_nothing():
         assert eng.shutdown() == 0
 
 
+def test_a_decode_pass_hands_its_tokens_over_after_the_next_call():
+    """A decode pass records its tokens at once (the next pass feeds on
+    them) and hands them to their readers when the next call into a
+    program is on its way, or when a request of the pass ends: a reader
+    sees every token once, in order, before `done`."""
+    eng = _small_engine()
+    try:
+        short, long = eng.submit([5, 9, 3], 3), eng.submit([7, 2], 5)
+        eng.step()          # short's prefill hands its token over; a pass
+        assert (len(short.tokens), short.out_q.qsize()) == (2, 1)
+        eng.step()          # long's prefill is the next call; a pass
+        assert (len(short.tokens), short.out_q.qsize()) == (3, 4)
+        assert short.done.is_set()          # three tokens, then `done`
+        assert (len(long.tokens), long.out_q.qsize()) == (2, 2)
+        eng.step()          # a pass: its call hands over nothing new
+        assert (len(long.tokens), long.out_q.qsize()) == (3, 2)
+        eng.step()
+        assert (len(long.tokens), long.out_q.qsize()) == (4, 3)
+        eng.run_until_idle()
+        for req, n in ((short, 3), (long, 5)):
+            items = [req.out_q.get_nowait() for _ in range(n + 1)]
+            assert items == [("token", i, t)
+                             for i, t in enumerate(req.tokens)] \
+                + [("done", "length")]
+            assert req.out_q.empty()
+        assert not eng._held
+        eng.quiesce()
+    finally:
+        assert eng.shutdown() == 0
+
+
 @pytest.mark.parametrize("mode, n", [("oneshot", 5), ("chunk", 13)])
 def test_prefill_rows_past_the_true_length_write_nothing(mode, n):
     """A prompt shorter than its bucket, and a last chunk shorter than
@@ -1055,3 +1086,97 @@ def test_serve_llm_end_to_end_with_the_latent_cache_family(clean_deployments):
     assert m["model"] == "kimi_k2" and m["kv_pages_live"] == 0
     assert m["chunk_steps"] == 3
     assert m["decode_moe_pairs_routed"] > 0
+
+
+def test_serve_llm_end_to_end_with_the_sequence_state_family(
+        clean_deployments):
+    """The Ling hybrid family through the same door: `build_app(model=...)`
+    -> `serve.run` -> `handle.generate`, chunked prefill with the KDA state
+    carried and decode over the state arena and the latent pages in the
+    replica, the tokens a local engine of the same seed gives, and the slot
+    counters in the replica's metrics."""
+    from ray_tpu import serve
+    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
+
+    engine_config = {"batch_buckets": (1, 2), "prefill_buckets": (16,),
+                     "prefill_chunk": 16, "num_pages": 32, "block_size": 8,
+                     "prefix_cache": 0}
+    handle = serve.run(serve.llm.build_app(
+        name="llm", num_replicas=1, model="ling_hybrid",
+        engine_config=engine_config))
+    prompt = list(range(3, 40))                      # three chunks of 16
+    streamed = [c["token"] for c in
+                handle.generate.options(stream=True).remote(prompt, 6)]
+    local = LLMEngine(model="ling_hybrid",
+                      engine_config=EngineConfig(**engine_config))
+    try:
+        want = local.submit(prompt, 6)
+        local.run_until_idle()
+        assert streamed == want.result()
+    finally:
+        local.shutdown()
+    m = handle.engine_metrics.remote().result(timeout=60)
+    assert m["model"] == "ling_hybrid" and m["kv_pages_live"] == 0
+    assert m["chunk_steps"] == 3
+    assert (m["state_slots_live"], m["state_slots_free"]) == (0, 2)
+    assert m["decode_kda_state_rows"] == 5 * 6
+    assert m["prefill_kda_state_rows"] == 3 * 6
+
+
+# sha256 (16 hex digits) of the lowered text of the engine's programs for
+# the tiny default model of each family that was there before the Ling
+# hybrid family came (commit c564374, PR 31): a bucket of each kind. A later
+# PR that means to change one of these programs replaces its line; one that
+# adds a family, a field or an argument for another family's sake must not.
+NEIGHBOUR_PROGRAMS = {
+    "llama": {"prefill16": "431c15dfcac7aa97", "decode1": "1ff66eb474054e07",
+              "decode4": "dab4a65a92c6a4ef", "chunk16": "6a7f8549a7f8d7f8"},
+    "gpt": {"prefill16": "b2ca98029a10a332", "decode1": "7a24818982c3efcd",
+            "decode4": "dcebc3109982ecb1", "chunk16": "d8e225f431a39fd7"},
+    "kimi_k2": {"prefill16": "0742f17ca2a066b5",
+                "decode1": "38844a208fb489c7",
+                "decode4": "2e7ec0aa8c76cda3",
+                "chunk16": "dcfa5ec2cf9155a8"},
+}
+
+
+@pytest.mark.parametrize("model", sorted(NEIGHBOUR_PROGRAMS))
+def test_neighbour_programs_lower_to_the_text_they_had(model):
+    """PR 26 was refused for moving a neighbour's `setup_s` by touching its
+    programs: a family that keeps no sequence state is served by the very
+    programs it had (no state arrays, no slots, nothing new traced with one
+    routing group), so its entries of the machine's compile cache stay
+    good."""
+    import hashlib
+
+    import jax
+
+    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
+
+    eng = LLMEngine(model=model, engine_config=EngineConfig(
+        batch_buckets=(1, 4), prefill_buckets=(16,), prefill_chunk=16,
+        num_pages=16, block_size=8), seed=0)
+    try:
+        kv = eng.kv
+        assert kv.state == ()
+        donate = tuple(range(3, 3 + len(kv.arena)))
+        programs = {"prefill16": (eng._prefill_fns[16], (
+            eng.params, np.zeros((1, 16), np.int32), np.ones((1,), np.int32),
+            *kv.arena, np.full(16, kv.num_pages, np.int32),
+            np.zeros(16, np.int32)))}
+        for name, fn, shape in (("decode1", eng._decode_fns[1], (1,)),
+                                ("decode4", eng._decode_fns[4], (4,)),
+                                ("chunk16", eng._chunk_fn, (1, 16))):
+            programs[name] = (fn, (
+                eng.params, np.zeros(shape, np.int32),
+                np.zeros(shape[0], np.int32), *kv.arena,
+                np.zeros((shape[0], eng.max_pages_per_seq), np.int32),
+                np.full(shape, kv.num_pages, np.int32),
+                np.zeros(shape, np.int32)))
+        got = {name: hashlib.sha256(
+            jax.jit(fn.__wrapped__, donate_argnums=donate).lower(
+                *args).as_text().encode()).hexdigest()[:16]
+            for name, (fn, args) in programs.items()}
+        assert got == NEIGHBOUR_PROGRAMS[model]
+    finally:
+        eng.shutdown()
