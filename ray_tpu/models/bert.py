@@ -26,6 +26,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models.gpt import Block
 from ray_tpu.parallel.ring_attention import full_attention
+from ray_tpu.parallel.sharding import logical_constraint
 
 
 @dataclasses.dataclass(frozen=True)
@@ -95,7 +96,7 @@ class BertEncoder(nn.Module):
         x = wte.astype(cfg.dtype)[tokens] + wpe.astype(cfg.dtype)[None, :t]
         if token_types is not None:
             x = x + wtt.astype(cfg.dtype)[token_types]
-        x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
+        x = logical_constraint(x, ("batch", "seq", "embed"))
 
         attend = self.attention_fn or partial(full_attention,
                                               causal=False)

@@ -24,6 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.parallel.ring_attention import full_attention
+from ray_tpu.parallel.sharding import logical_constraint
 
 
 @dataclasses.dataclass(frozen=True)
@@ -69,6 +70,14 @@ def _dense(features, logical_axes, name, config, use_bias=True):
     )
 
 
+def embedding_table(wte, dtype):
+    """The tied table as the lookup and the head use it: cast, and under a
+    mesh gathered over `embed` (the weight's own ZeRO gather, one for both
+    uses), so the batch stays split through the lookup: a table split both
+    ways is looked up for every sequence on every chip."""
+    return logical_constraint(wte.astype(dtype), ("vocab", None))
+
+
 class Block(nn.Module):
     """Pre-LN transformer block."""
 
@@ -92,9 +101,9 @@ class Block(nn.Module):
         q = q.reshape(b, t, cfg.n_head, head_dim)
         k = k.reshape(b, t, cfg.n_head, head_dim)
         v = v.reshape(b, t, cfg.n_head, head_dim)
-        q = nn.with_logical_constraint(q, ("batch", "seq", "heads", None))
-        k = nn.with_logical_constraint(k, ("batch", "seq", "heads", None))
-        v = nn.with_logical_constraint(v, ("batch", "seq", "heads", None))
+        q = logical_constraint(q, ("batch", "seq", "heads", None))
+        k = logical_constraint(k, ("batch", "seq", "heads", None))
+        v = logical_constraint(v, ("batch", "seq", "heads", None))
         # decode-cache tap (serve.llm prefill); no-op unless the caller
         # passes mutable=["intermediates"]
         self.sow("intermediates", "kv_cache", (k, v))
@@ -115,7 +124,7 @@ class Block(nn.Module):
         if cfg.dropout > 0:
             h = nn.Dropout(cfg.dropout)(h, deterministic=deterministic)
         x = x + h
-        return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
+        return logical_constraint(x, ("batch", "seq", "embed"))
 
 
 class GPT(nn.Module):
@@ -149,8 +158,9 @@ class GPT(nn.Module):
             (cfg.max_seq_len, cfg.d_model),
             cfg.param_dtype,
         )
-        x = wte.astype(cfg.dtype)[tokens] + wpe.astype(cfg.dtype)[None, :t]
-        x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
+        x = embedding_table(wte, cfg.dtype)[tokens] + \
+            wpe.astype(cfg.dtype)[None, :t]
+        x = logical_constraint(x, ("batch", "seq", "embed"))
 
         block = Block
         if cfg.remat:
@@ -171,7 +181,8 @@ class GPT(nn.Module):
         if return_hidden:
             return x, wte
         # Tied LM head: logits = x @ wte^T (the vocab axis shards over tp).
-        logits = jnp.einsum("btd,vd->btv", x, wte.astype(cfg.dtype))
+        logits = jnp.einsum("btd,vd->btv", x,
+                            embedding_table(wte, cfg.dtype))
         return logits
 
 

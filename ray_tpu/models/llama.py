@@ -34,8 +34,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models.gpt import _dense as _gpt_dense
+from ray_tpu.models.gpt import _dense as _gpt_dense, embedding_table
 from ray_tpu.parallel.ring_attention import full_attention
+from ray_tpu.parallel.sharding import logical_constraint
 
 
 @dataclasses.dataclass(frozen=True)
@@ -144,9 +145,9 @@ class LlamaBlock(nn.Module):
         # (dense/ring/Ulysses via expand_kv_heads, flash via its KV
         # index map) handles the grouping itself, so the expansion is a
         # broadcast (or nothing at all), never an HBM copy
-        q = nn.with_logical_constraint(q, ("batch", "seq", "heads", None))
-        k = nn.with_logical_constraint(k, ("batch", "seq", "heads", None))
-        v = nn.with_logical_constraint(v, ("batch", "seq", "heads", None))
+        q = logical_constraint(q, ("batch", "seq", "heads", None))
+        k = logical_constraint(k, ("batch", "seq", "heads", None))
+        v = logical_constraint(v, ("batch", "seq", "heads", None))
         # post-RoPE K/V are exactly what a decode cache needs; sow is a
         # no-op unless the caller asks for mutable=["intermediates"]
         # (serve.llm prefill), so the training path is unchanged
@@ -164,7 +165,7 @@ class LlamaBlock(nn.Module):
         gate, up = jnp.split(gu, 2, axis=-1)
         h = nn.silu(gate) * up
         x = x + _dense(cfg.d_model, ("mlp", "embed"), "mlp_down", cfg)(h)
-        return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
+        return logical_constraint(x, ("batch", "seq", "embed"))
 
 
 class Llama(nn.Module):
@@ -180,8 +181,8 @@ class Llama(nn.Module):
             nn.with_partitioning(nn.initializers.normal(0.02),
                                  ("vocab", "embed")),
             (cfg.vocab_size, cfg.d_model), cfg.param_dtype)
-        x = wte.astype(cfg.dtype)[tokens]
-        x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
+        x = embedding_table(wte, cfg.dtype)[tokens]
+        x = logical_constraint(x, ("batch", "seq", "embed"))
 
         block = LlamaBlock
         if cfg.remat:
@@ -198,7 +199,7 @@ class Llama(nn.Module):
             # never materialized in HBM (same hook as GPT.return_hidden)
             return x, wte.astype(cfg.dtype)
         # tied LM head
-        return jnp.einsum("btd,vd->btv", x, wte.astype(cfg.dtype))
+        return jnp.einsum("btd,vd->btv", x, embedding_table(wte, cfg.dtype))
 
 
 # -- decode path (serve.llm) ----------------------------------------------

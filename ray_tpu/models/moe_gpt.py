@@ -21,6 +21,7 @@ import jax.numpy as jnp
 
 from ray_tpu.models.gpt import GPTConfig, _dense
 from ray_tpu.parallel.ring_attention import full_attention
+from ray_tpu.parallel.sharding import logical_constraint
 
 
 @dataclasses.dataclass(frozen=True)
@@ -130,7 +131,7 @@ class MoEBlock(nn.Module):
         h = nn.LayerNorm(dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                          name="ln_2")(x)
         x = x + MoEMLP(cfg, name="moe")(h)
-        return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
+        return logical_constraint(x, ("batch", "seq", "embed"))
 
 
 class MoEGPT(nn.Module):

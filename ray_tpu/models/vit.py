@@ -15,6 +15,8 @@ from typing import Any
 import flax.linen as nn
 import jax.numpy as jnp
 
+from ray_tpu.parallel.sharding import logical_constraint
+
 
 @dataclasses.dataclass(frozen=True)
 class ViTConfig:
@@ -97,7 +99,7 @@ class EncoderBlock(nn.Module):
         if cfg.dropout > 0:
             h = nn.Dropout(cfg.dropout)(h, deterministic=deterministic)
         x = x + h
-        return nn.with_logical_constraint(x, ("batch", "seq", "embed"))
+        return logical_constraint(x, ("batch", "seq", "embed"))
 
 
 class ViT(nn.Module):
@@ -133,7 +135,7 @@ class ViT(nn.Module):
                                  (None, "embed")),
             (cfg.num_patches + 1, cfg.d_model), cfg.param_dtype)
         x = x + pos.astype(cfg.dtype)[None]
-        x = nn.with_logical_constraint(x, ("batch", "seq", "embed"))
+        x = logical_constraint(x, ("batch", "seq", "embed"))
 
         block = EncoderBlock
         if cfg.remat:

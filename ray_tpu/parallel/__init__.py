@@ -23,8 +23,9 @@ from ray_tpu.parallel.mesh import (MeshConfig, build_hybrid_mesh,
 from ray_tpu.parallel.sharding import (
     ShardingStrategy,
     logical_axis_rules,
+    logical_constraint,
     shard_batch,
-    sharding_constraint,
+    tracing_for,
 )
 
 __all__ = [
@@ -39,8 +40,9 @@ __all__ = [
     "fold_steps",
     "global_cache",
     "logical_axis_rules",
+    "logical_constraint",
     "mesh_shape_for",
     "shard_batch",
-    "sharding_constraint",
     "stack_batches",
+    "tracing_for",
 ]

@@ -11,9 +11,13 @@ steady-state cost of a step one executable invocation:
     (function identity, argument treedefs/avals, mesh): the first call
     lowers and compiles once via ``jax.jit(...).lower(...).compile()``
     (reference: the jax AOT API), every subsequent call with the same
-    abstract signature dispatches the cached executable directly. Hits,
-    misses, and retraces are counted (`cache_stats()` — surfaced by
-    bench.py's `dispatch_overhead` phase). A *retrace* is a miss for a
+    abstract signature dispatches the cached executable directly. The
+    step is traced *for* the mesh it is given: the lowering runs inside
+    ``sharding.tracing_for(mesh)``, so the model's ``logical_constraint``
+    annotations reach the compiler (with no mesh they pass through; the
+    counts of both are in `cache_stats()`). Hits, misses, and retraces
+    are counted (`cache_stats()` — surfaced by bench.py's
+    `dispatch_overhead` phase). A *retrace* is a miss for a
     function that already has a cached executable (shape/dtype/treedef
     drift): the guard warns by default and raises with
     ``on_retrace="error"`` — the silent-retrace failure mode the
@@ -39,12 +43,13 @@ import logging
 import threading
 import time
 from dataclasses import dataclass
-from typing import Any, Callable, Dict, Optional, Tuple
+from typing import Any, Callable, Dict, List, Optional, Tuple
 
 import jax
 from jax import lax
 
 from ray_tpu._private.accelerators import configure_compile_cache
+from ray_tpu.parallel.sharding import tracing_for
 from ray_tpu.util import metrics as _metrics
 from ray_tpu.util import step_profiler as _sp
 from ray_tpu.util import tracing as _tracing
@@ -125,6 +130,10 @@ class ExecutableCache:
         self._entries: Dict[tuple, Any] = {}
         self._fn_signatures: Dict[tuple, set] = {}
         self.stats = CacheStats()
+        # one row a lowering, in order: the function, and how many of the
+        # model's `sharding.logical_constraint` calls became a constraint
+        # or passed through (no mesh): "annotated but dead" shows here
+        self.lowerings: List[Dict[str, Any]] = []
         # `cache_lookup`: every lookup's own time (the key, the probe),
         # without the compile nested in it on a miss
         self.phases = _tracing.PhaseTable(
@@ -135,6 +144,7 @@ class ExecutableCache:
             self._entries.clear()
             self._fn_signatures.clear()
             self.stats = CacheStats()
+            self.lowerings = []
         self.phases.clear()
 
     def size(self) -> int:
@@ -180,9 +190,10 @@ class ExecutableCache:
         # in-process users (no worker_main): executables that outlive
         # this cache's process go to the placed persistent cache
         configure_compile_cache()
+        fn_name = getattr(fn, "__name__", "?")
         with self.phases.phase("compiled_step.lower", attrs={
-                "fn": getattr(fn, "__name__", "?"),
-                "retrace": retraced}) as lowering:
+                "fn": fn_name, "retrace": retraced}) as lowering, \
+                tracing_for(mesh) as constraints:
             compiled = jax.jit(
                 fn, donate_argnums=donate_argnums,
                 static_argnums=static_argnums,
@@ -191,6 +202,10 @@ class ExecutableCache:
             # keep fn alive alongside its executable (id-key safety)
             self._entries[key] = (fn, compiled)
             self.stats.lowering_ms += lowering.ns / 1e6
+            self.lowerings.append({
+                "fn": fn_name,
+                "activation_constraints": constraints.emitted,
+                "activation_constraints_skipped": constraints.skipped})
         return compiled
 
 
@@ -201,14 +216,20 @@ def global_cache() -> ExecutableCache:
     return _GLOBAL_CACHE
 
 
-def cache_stats() -> Dict[str, int]:
+def cache_stats() -> Dict[str, Any]:
     """Process-wide executable-cache counters (bench `dispatch_overhead`
     and the /metrics scrape read these): hits / misses / retraces /
-    entries / cumulative lowering ms, and what the lookups themselves
-    cost (`lookup_ms` over `lookups` calls, compiles not included)."""
+    entries / cumulative lowering ms, what the lookups themselves cost
+    (`lookup_ms` over `lookups` calls, compiles not included), and the
+    model's activation constraints: `activation_constraints` emitted and
+    `activation_constraints_skipped` passed through, summed and, under
+    `lowerings`, a row for each lowering."""
     stats = _GLOBAL_CACHE.stats.as_dict()
     stats["entries"] = _GLOBAL_CACHE.size()
     stats["lowering_ms"] = round(_GLOBAL_CACHE.stats.lowering_ms, 3)
+    stats["lowerings"] = list(_GLOBAL_CACHE.lowerings)
+    for count in ("activation_constraints", "activation_constraints_skipped"):
+        stats[count] = sum(row[count] for row in stats["lowerings"])
     stats["lookup_ms"] = round(_GLOBAL_CACHE.phases.ms("cache_lookup"), 3)
     stats["lookups"] = _GLOBAL_CACHE.phases.count("cache_lookup")
     return stats
@@ -246,8 +267,12 @@ def compiled_step(fn: Optional[Callable] = None, *,
     The first call with a given abstract signature lowers and compiles
     once; later calls invoke the cached executable with no jit-layer
     dispatch. ``donate_argnums`` marks carries (params/opt-state) whose
-    buffers XLA reuses in place. The wrapper exposes ``.cache`` and
-    ``.stats`` for tests and bench counters.
+    buffers XLA reuses in place. ``mesh`` is the mesh the step is traced
+    for: part of the key, and the ``sharding.tracing_for`` scope of the
+    lowering, so ``logical_constraint`` in the model resolves against it;
+    None keeps the scope the caller is in, if any. The
+    wrapper exposes ``.cache`` and ``.stats`` for tests and bench
+    counters.
     """
     if fn is None:
         return functools.partial(

@@ -12,14 +12,24 @@ logical-axis rules mapped onto the mesh:
         attention exchanges KV blocks over ICI)
 - EP:   experts -> ep       (all_to_all dispatch)
 
-Models annotate parameters/activations with logical axis names via
-`flax.linen.Partitioned` metadata (`nn.with_partitioning`) and the trainer
-applies these rules with `flax.linen.logical_axis_rules`.
+Models annotate parameters with logical axis names through
+`flax.linen.Partitioned` metadata (`nn.with_partitioning`), which
+`param_shardings` resolves into the carry's `NamedSharding`s, and
+activations through `logical_constraint` below, which resolves the names
+when a step is traced for a mesh: inside `tracing_for(mesh)` (a
+`compiled_step` given `mesh=` enters it around its lowering) every call
+becomes a `lax.with_sharding_constraint`; with no mesh it returns its
+argument, so a one-chip program holds no trace of it.
+`nn.with_logical_constraint` is not used: under the pinned flax a
+`with mesh:` is not what it calls a global mesh, and it returned its input
+on every path this repo has (PR 34).
 """
 
 from __future__ import annotations
 
-from dataclasses import dataclass
+import contextlib
+import threading
+from dataclasses import dataclass, fields
 from typing import Dict, List, Optional, Tuple
 
 import jax
@@ -121,12 +131,76 @@ def shard_batch(batch, mesh: Mesh, strategy: ShardingStrategy):
     return jax.tree_util.tree_map(place, batch)
 
 
-def sharding_constraint(x, mesh: Mesh, spec: P):
-    """`lax.with_sharding_constraint` that is a no-op outside jit/mesh."""
+@dataclass
+class ConstraintTally:
+    """What `logical_constraint` did while one program was traced: the
+    constraints it emitted and the calls it passed through unchanged."""
+
+    emitted: int = 0
+    skipped: int = 0
+
+
+_TRACE = threading.local()  # .scope: (mesh, tally) of `tracing_for`
+
+
+@contextlib.contextmanager
+def tracing_for(mesh: Optional[Mesh]):
+    """The scope in which a step is traced for `mesh`: `logical_constraint`
+    emits against it on this thread, and the tally yielded counts what it
+    did meanwhile. None keeps the mesh of an enclosing scope, if any.
+    `compiled_step(..., mesh=mesh)` enters it around its lowering; a step
+    jitted by hand is called (the first time) inside it. It enters
+    `with mesh:` too: that is part of the key of jax's own cache of traces,
+    so a function traced before with no mesh, or for another, is traced
+    again and not taken from there without its constraints."""
+    outer = getattr(_TRACE, "scope", (None, None))
+    tally = ConstraintTally()
+    _TRACE.scope = (mesh if mesh is not None else outer[0], tally)
     try:
-        return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
-    except ValueError:
+        with mesh if mesh is not None else contextlib.nullcontext():
+            yield tally
+    finally:
+        _TRACE.scope = outer
+
+
+def _strategy_of(mesh: Mesh) -> ShardingStrategy:
+    """The strategy a mesh's own axis sizes spell:
+    `Mesh('fsdp': 2, 'tp': 2)` is `ShardingStrategy(fsdp=2, tp=2)`."""
+    known = {f.name for f in fields(ShardingStrategy)}
+    return ShardingStrategy(
+        dcn_dp=mesh.shape.get("dcn", 1),
+        **{axis: n for axis, n in mesh.shape.items() if axis in known})
+
+
+def logical_constraint(x, names: Tuple[Optional[str], ...]):
+    """Constrain the activation `x`, whose dimensions carry the logical
+    `names`, to the layout they have on the mesh the step is traced for.
+
+    The mesh is that of the enclosing `tracing_for`. The names resolve
+    through the active `nn.logical_axis_rules` or, where none are active,
+    through `logical_axis_rules` of the strategy the mesh's axis sizes
+    spell, in flax's order of precedence (in `("batch", "seq", "embed")`
+    under fsdp the batch takes `fsdp` and `embed` stays whole); a mesh axis
+    the mesh lacks, like a name no rule knows, leaves its dimension whole.
+    With no mesh, or outside a trace, it returns `x` itself."""
+    import flax.linen as nn
+
+    mesh, tally = getattr(_TRACE, "scope", (None, None))
+    if mesh is None or not isinstance(x, jax.core.Tracer):
+        if tally is not None:
+            tally.skipped += 1
         return x
+    rules = nn.get_logical_axis_rules() or logical_axis_rules(
+        _strategy_of(mesh))
+
+    def on_mesh(entry):
+        axes = tuple(a for a in ((entry,) if isinstance(entry, str)
+                                 else entry or ()) if a in mesh.shape)
+        return axes[0] if len(axes) == 1 else axes or None
+
+    spec = P(*map(on_mesh, nn.logical_to_mesh_axes(names, rules)))
+    tally.emitted += 1
+    return jax.lax.with_sharding_constraint(x, NamedSharding(mesh, spec))
 
 
 def param_shardings(mesh: Mesh, abstract_params, rules) -> "jax.tree_util.PyTreeDef":

@@ -183,7 +183,9 @@ def test_train_step_runner_equivalence_and_stats():
 def test_global_cache_stats_shape():
     before = cache_stats()
     assert set(before) == {"hits", "misses", "retraces", "entries",
-                           "lowering_ms", "lookup_ms", "lookups"}
+                           "lowering_ms", "lookup_ms", "lookups",
+                           "activation_constraints",
+                           "activation_constraints_skipped", "lowerings"}
 
     @compiled_step
     def bump(x):
@@ -201,7 +203,42 @@ def test_global_cache_stats_shape():
         after["lowering_ms"] - before["lowering_ms"]
     global_cache().clear()
     cleared = cache_stats()
-    assert cleared["entries"] == 0
+    assert cleared["entries"] == 0 and cleared["lowerings"] == []
+
+
+@pytest.mark.parametrize("folded", [False, True])
+def test_cache_stats_count_the_activation_constraints_of_each_lowering(
+        folded):
+    """A lowering for a mesh emits the model's constraints, one for no mesh
+    passes them through; `cache_stats()` has a row for each, and the sums.
+    `fold_steps` hands its mesh on, so its scan body is traced for it."""
+    from ray_tpu.parallel import build_mesh, logical_constraint
+
+    def twice(x, _batch=None):
+        x = logical_constraint(x, ("batch", "embed"))
+        x = logical_constraint(x * 2, ("batch", "embed"))
+        return (x, x.sum()) if folded else x
+
+    def wrap(**kw):
+        return fold_steps(twice, 1, **kw) if folded else \
+            compiled_step(twice, **kw)
+
+    global_cache().clear()
+    mesh = build_mesh({"fsdp": 2, "tp": 2}, jax.devices()[:4])
+    x, args = jnp.ones((4, 8)), ((jnp.zeros((1,)),) if folded else ())
+    for kw in ({"mesh": mesh}, {}):
+        out = wrap(**kw)(jnp.ones((4, 8)), *args)
+        np.testing.assert_allclose(out[0] if folded else out, 2 * x)
+    stats = cache_stats()
+    name = "fold_steps(twicex1)" if folded else "twice"
+    assert stats["lowerings"] == [
+        {"fn": name, "activation_constraints": 2,
+         "activation_constraints_skipped": 0},
+        {"fn": name, "activation_constraints": 0,
+         "activation_constraints_skipped": 2}]
+    assert stats["activation_constraints"] == 2
+    assert stats["activation_constraints_skipped"] == 2
+    global_cache().clear()
 
 
 def test_python_scalar_is_part_of_the_key():

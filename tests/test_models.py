@@ -10,7 +10,8 @@ import pytest
 
 from ray_tpu.models import GPT, GPTConfig, ResNet, ResNetConfig
 from ray_tpu.models.gpt import count_params, cross_entropy_loss
-from ray_tpu.parallel import ShardingStrategy, logical_axis_rules
+from ray_tpu.parallel import (ShardingStrategy, logical_axis_rules,
+                              tracing_for)
 
 
 def test_gpt_forward_loss():
@@ -46,7 +47,7 @@ def _run_sharded_step(strategy):
     tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0,
                                 cfg.vocab_size)
 
-    with mesh, nn.logical_axis_rules(rules):
+    with tracing_for(mesh), nn.logical_axis_rules(rules):
         params = model.init(jax.random.PRNGKey(0), tokens)
         tx = optax.adamw(1e-3)
         opt_state = tx.init(params)
@@ -165,7 +166,7 @@ def test_llama_sharded_train_step(strategy):
     rules = logical_axis_rules(strategy)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0,
                                 cfg.vocab_size)
-    with mesh, nn.logical_axis_rules(rules):
+    with tracing_for(mesh), nn.logical_axis_rules(rules):
         params = model.init(jax.random.PRNGKey(0), tokens)
         tx = optax.adamw(1e-3)
         opt_state = tx.init(params)
@@ -240,7 +241,7 @@ def test_moe_gpt_expert_sharded_train_step():
     rules = logical_axis_rules(strategy)
     tokens = jax.random.randint(jax.random.PRNGKey(1), (8, 32), 0,
                                 cfg.vocab_size)
-    with mesh, nn.logical_axis_rules(rules):
+    with tracing_for(mesh), nn.logical_axis_rules(rules):
         variables = model.init(jax.random.PRNGKey(0), tokens)
         params = variables["params"]
         tx = optax.adamw(1e-3)
@@ -354,7 +355,7 @@ def test_bert_shards_like_the_decoders():
     import flax.linen as nn
 
     from ray_tpu.models import BertConfig, BertEncoder
-    from ray_tpu.parallel import ShardingStrategy, logical_axis_rules
+    from ray_tpu.parallel import logical_axis_rules
 
     cfg = BertConfig.tiny(remat=False)
     enc = BertEncoder(cfg)
@@ -365,7 +366,8 @@ def test_bert_shards_like_the_decoders():
 
     strategy = ShardingStrategy(dp=2, tp=2)
     mesh = strategy.build_mesh(jax.devices()[:4])
-    with mesh, nn.logical_axis_rules(logical_axis_rules(strategy)):
+    with tracing_for(mesh), \
+            nn.logical_axis_rules(logical_axis_rules(strategy)):
         out, _ = jax.jit(lambda p, t: enc.apply(p, t))(params, tokens)
     # bf16 activations reassociate differently under tp sharding
     np.testing.assert_allclose(np.asarray(out, np.float32),

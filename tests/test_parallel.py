@@ -1,6 +1,8 @@
 """Parallelism primitives on the 8-device virtual CPU mesh: mesh building,
 sharding rules, collectives, ring attention, Ulysses, pipeline, MoE."""
 
+import re
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -339,3 +341,216 @@ def test_multislice_scaling_config_bundles():
 
     with _pytest.raises(ValueError):
         ScalingConfig(num_workers=3, num_slices=2).workers_per_slice
+
+
+# -- activation constraints (PR 34) ----------------------------------------
+# `gpt2-large.pretrain-fsdp2tp2` ran for eleven PRs with every activation
+# annotation dead: the partitioner split activations by the parameters'
+# `embed -> fsdp` axis and all-reduced whole-batch partial sums. These pin
+# the repair where it can be seen without a chip: in the lowered text and in
+# the collectives of the module compiled for four host devices.
+
+_COLLECTIVE = re.compile(
+    r"^\s*(?:ROOT )?%?[\w.\-]+ = (.*?) (all-reduce|all-gather|all-to-all|"
+    r"collective-permute|reduce-scatter)(?:-start)?\(", re.M)
+_DTYPE_BYTES = {"f32": 4, "bf16": 2, "f16": 2, "s32": 4, "u32": 4, "pred": 1}
+
+
+def _collectives(compiled_text):
+    """[(kind, [(dtype, dims), ...], bytes)] of a compiled module."""
+    found = []
+    for match in _COLLECTIVE.finditer(compiled_text):
+        arrays = [(d, tuple(int(n) for n in dims.split(",") if n))
+                  for d, dims in re.findall(r"([a-z]+[0-9]*)\[([0-9,]*)\]",
+                                            match.group(1))]
+        found.append((match.group(2), arrays, sum(
+            _DTYPE_BYTES[d] * int(np.prod(dims)) for d, dims in arrays)))
+    return found
+
+
+def _gpt2_large_job(devices):
+    """The cell's own builder on the cell's own file, cut to two layers."""
+    import json
+    import os
+
+    from benchmark import train_cell
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark/configs/gpt2-large.json")) as f:
+        config = json.load(f)
+    config["n_layer"] = 2
+    return train_cell.gpt2_job(config, {"batch": 8, "seq_len": 1024},
+                               devices)
+
+
+def _llama_job(devices):
+    """`train_cell.gpt2_job`'s step around `models/llama.py` at the same
+    widths (20 heads of 64, rows padded to 50,304, remat)."""
+    import flax.linen as nn
+    import optax
+
+    from ray_tpu.models import Llama, LlamaConfig
+    from ray_tpu.models.gpt import cross_entropy_loss
+    from ray_tpu.parallel import logical_axis_rules
+    from ray_tpu.parallel.sharding import param_shardings
+
+    cfg = LlamaConfig(vocab_size=50304, n_layer=2, n_head=20, n_kv_head=20,
+                      d_model=1280, max_seq_len=1024, remat=True)
+    model, tx = Llama(cfg), optax.adamw(3e-4)
+    strategy = ShardingStrategy(fsdp=2, tp=2)
+    mesh = strategy.build_mesh(list(devices))
+    rules = logical_axis_rules(strategy)
+
+    def make_carry(key):
+        params = model.init(key, jnp.zeros((8, 1024), jnp.int32))
+        return params, tx.init(params)
+
+    with mesh, nn.logical_axis_rules(rules):
+        shardings = param_shardings(
+            mesh, jax.eval_shape(make_carry, jax.random.PRNGKey(0)), rules)
+
+    def step(carry, data):
+        params, opt_state = carry
+        loss, grads = jax.value_and_grad(
+            lambda p, x, y: cross_entropy_loss(model.apply(p, x), y))(
+                params, *data)
+        updates, opt_state = tx.update(grads, opt_state, params)
+        carry = (optax.apply_updates(params, updates), opt_state)
+        return jax.lax.with_sharding_constraint(carry, shardings), loss
+
+    return {"step": step, "make_carry": make_carry, "mesh": mesh,
+            "rules": rules, "shardings": shardings}
+
+
+@pytest.mark.parametrize("family,job_of,bytes_limit", [
+    ("gpt", _gpt2_large_job, 1400e6),     # parent: 3,783 MB; now 1,178
+    ("llama", _llama_job, 1900e6),        # parent: 4,511 MB; now 1,623
+])
+def test_four_chip_step_keeps_the_batch_split(family, job_of, bytes_limit):
+    """The step of `gpt2-large.pretrain-fsdp2tp2` (two layers), lowered
+    through the executable cache for the mesh as `TrainStepRunner` does:
+    the model's annotations reach the compiler, so no collective carries
+    the whole batch of 8 (a chip holds 4 sequences) nor the logits, and a
+    step moves a third of the parent's bytes."""
+    import flax.linen as nn
+    from jax.sharding import NamedSharding
+
+    from ray_tpu.parallel import ExecutableCache, compiled_step, tracing_for
+
+    job = job_of(jax.devices()[:4])
+    mesh = job["mesh"]
+    with mesh, nn.logical_axis_rules(job["rules"]):
+        carry = jax.eval_shape(
+            jax.jit(job["make_carry"], out_shardings=job["shardings"]),
+            jax.random.PRNGKey(0))
+    data = tuple(jax.ShapeDtypeStruct(
+        (8, 1024), jnp.int32, sharding=NamedSharding(mesh, P("fsdp", None)))
+        for _ in range(2))
+    cache = ExecutableCache()
+    step = compiled_step(job["step"], donate_argnums=(0,), mesh=mesh,
+                         cache=cache)
+    compiled = cache.lookup(step.__wrapped__, (carry, data), {},
+                            donate_argnums=(0,), mesh=mesh)
+    # the embedding table at its two uses, the embedded tokens, and q, k,
+    # v and the block's output in each layer
+    assert cache.lowerings == [{
+        "fn": "step", "activation_constraints": 3 + 4 * 2,
+        "activation_constraints_skipped": 0}]
+    with tracing_for(mesh):
+        lowered = jax.jit(job["step"], donate_argnums=0).lower(
+            carry, data).as_text()
+    on_activations = re.findall(
+        r"sdy\.sharding_constraint[^\n]*: tensor<8x1024x[0-9x]+x(?:bf16|f32)>",
+        lowered)
+    assert len(on_activations) >= 1 + 4 * 2, lowered.count("sdy.shard")
+    found = _collectives(compiled.as_text())
+    whole_batch = [(kind, arrays) for kind, arrays, _ in found
+                   if any(len(dims) >= 3 and dims[0] == 8
+                          for _, dims in arrays)]
+    assert not whole_batch, whole_batch
+    logits = [(kind, arrays) for kind, arrays, _ in found
+              if any(dims[-2:] == (1024, 25152) for _, dims in arrays)]
+    assert not logits, logits
+    assert sum(nbytes for *_, nbytes in found) < bytes_limit
+
+
+def test_activation_constraint_is_the_identity_without_a_mesh():
+    from ray_tpu.parallel import logical_constraint, tracing_for
+
+    x = jnp.ones((4, 8, 16))
+    assert logical_constraint(x, ("batch", "seq", "embed")) is x
+    seen = []
+
+    def traced(x):
+        seen.append(logical_constraint(x, ("batch", "seq", "embed")) is x)
+        return x
+
+    with tracing_for(None) as tally:
+        jax.jit(traced).lower(x)
+    assert seen == [True] and (tally.emitted, tally.skipped) == (0, 1)
+    # outside a trace there is nothing to constrain, mesh or no mesh
+    with tracing_for(build_mesh({"dp": 4}, jax.devices()[:4])) as tally:
+        assert logical_constraint(x, ("batch", "seq", "embed")) is x
+    assert (tally.emitted, tally.skipped) == (0, 1)
+
+
+@pytest.mark.parametrize("program", ["gpt_train_step", "llama_prefill"])
+def test_one_chip_programs_hold_no_trace_of_the_constraints(program,
+                                                            monkeypatch):
+    """With no mesh a program lowers to the text it has with the helper
+    patched to the identity: the one-chip cells and the serving engine keep
+    the programs (and the machine's compile-cache entries) they had."""
+    from ray_tpu.models import GPT, GPTConfig, LlamaConfig, gpt, llama
+    from ray_tpu.models.gpt import cross_entropy_loss
+
+    tokens = jnp.zeros((2, 16), jnp.int32)
+    if program == "gpt_train_step":
+        model = GPT(GPTConfig.tiny())
+        args = (jax.eval_shape(model.init, jax.random.PRNGKey(0), tokens),
+                tokens)
+
+        def build():
+            return lambda params, tokens: jax.value_and_grad(
+                lambda p: cross_entropy_loss(model.apply(p, tokens),
+                                             tokens))(params)
+    else:
+        cfg = LlamaConfig.tiny()
+        args = (jax.eval_shape(llama.Llama(cfg).init, jax.random.PRNGKey(0),
+                               tokens), tokens, jnp.ones((2,), jnp.int32))
+
+        def build():
+            return lambda v, tokens, n: llama.prefill_step(v, cfg, tokens, n)
+
+    # a new function each time: jax keeps traces by function
+    with_helper = jax.jit(build()).lower(*args).as_text()
+    for module in (gpt, llama):     # llama's table is `gpt.embedding_table`
+        monkeypatch.setattr(module, "logical_constraint",
+                            lambda x, names: x)
+    without = jax.jit(build()).lower(*args).as_text()
+    assert "sharding_constraint" not in with_helper
+    assert with_helper == without
+
+
+@pytest.mark.parametrize("rules_from", ["the_mesh", "flax"])
+def test_a_mesh_axis_the_mesh_lacks_leaves_the_dimension_whole(rules_from):
+    """A `dp`-only mesh under rules that send `heads` to `tp`: unsharded,
+    not an error; with no rules active the mesh's own axes spell them."""
+    import contextlib
+
+    import flax.linen as nn
+
+    from ray_tpu.parallel import (logical_axis_rules, logical_constraint,
+                                  tracing_for)
+
+    mesh = build_mesh({"dp": 4}, jax.devices()[:4])
+    rules = nn.logical_axis_rules(logical_axis_rules(
+        ShardingStrategy(dp=2, tp=2))) if rules_from == "flax" \
+        else contextlib.nullcontext()
+    x = jnp.ones((8, 16, 4, 8))
+    with tracing_for(mesh) as tally, rules:
+        text = jax.jit(lambda x: logical_constraint(
+            x, ("batch", "seq", "heads", None))).lower(x).as_text()
+    assert (tally.emitted, tally.skipped) == (1, 0)
+    assert re.search(
+        r'sdy\.sharding_constraint %\w+ <@mesh, \[\{"dp"\}, \{\}, \{\}, \{\}\]>',
+        text), text
