@@ -7,7 +7,9 @@ RoPE/SwiGLU/GQA (`llama`), MoE decoders (`moe_gpt`), latent-attention
 decoders with sigmoid-routed experts (`kimi_k2`, serving only; imported
 when first asked for), hybrid decoders of Kimi-Delta-Attention layers (one
 state a sequence) beside latent attention with group-limited routing
-(`ling_hybrid`, serving only; imported when first asked for), ResNet
+(`ling_hybrid`, serving only; imported when first asked for), decoders
+that generate by diffusion over blocks with softmax-routed experts
+(`sdar_moe`, serving only; imported when first asked for), ResNet
 convnets (`resnet`), Vision Transformers (`vit`).
 """
 
@@ -24,6 +26,7 @@ __all__ = [
     "GPT", "GPTConfig", "Llama", "LlamaConfig", "MoEGPT", "MoEGPTConfig",
     "ResNet", "ResNetConfig", "ViT", "ViTConfig",
     "KimiK2", "KimiK2Config", "LingHybrid", "LingHybridConfig",
+    "SdarMoe", "SdarMoeConfig",
 ]
 
 
@@ -32,7 +35,8 @@ def __getattr__(name):
     # families it does not run cost it nothing
     for family, names in (("kimi_k2", ("KimiK2", "KimiK2Config")),
                           ("ling_hybrid", ("LingHybrid",
-                                           "LingHybridConfig"))):
+                                           "LingHybridConfig")),
+                          ("sdar_moe", ("SdarMoe", "SdarMoeConfig"))):
         if name == family or name in names:
             import importlib
 

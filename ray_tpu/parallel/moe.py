@@ -173,13 +173,32 @@ def sigmoid_topk_route(x, router_w, bias, top_k: int, scale: float,
     return expert.astype(jnp.int32), weight
 
 
+def softmax_topk_route(x, router_w, top_k: int):
+    """Softmax-scored top-k routing with the weights renormalised over the
+    chosen (`norm_topk_prob`; Qwen3-MoE's router, no bias, no scale). x
+    [N, d]; router_w [d, n_experts]. The experts are the top-k of
+    `softmax(x W)`; their weights are those probabilities over their sum.
+    Float32 at the highest matmul precision, as `sigmoid_topk_route`.
+    Returns (expert ids [N, top_k] int32, weights [N, top_k] float32)."""
+    probs = jax.nn.softmax(jnp.dot(
+        x.astype(jnp.float32), router_w.astype(jnp.float32),
+        precision=lax.Precision.HIGHEST), axis=-1)
+    weight, expert = lax.top_k(probs, top_k)
+    weight = weight / jnp.sum(weight, axis=-1, keepdims=True)
+    return expert.astype(jnp.int32), weight
+
+
 def expert_shard_layer(x, router_w, bias, experts_held, first_expert: int,
                        n_experts: int, top_k: int, scale: float,
-                       valid=None, n_group: int = 1, topk_group: int = 1):
+                       valid=None, n_group: int = 1, topk_group: int = 1,
+                       route=None):
     """What the chip that holds experts [first_expert, first_expert + held)
     of `n_experts` adds to a routed expert layer: every token is routed
     over ALL the experts (`n_group`, `topk_group`: group-limited, as
-    `sigmoid_topk_route` says), the (token, expert) pairs whose expert lives here
+    `sigmoid_topk_route` says; or by `route`, a function of (x, router_w,
+    top_k) to expert ids and weights such as `softmax_topk_route`, which
+    then is the layer's router and leaves `bias`, `scale` and the groups
+    unread), the (token, expert) pairs whose expert lives here
     are kept, and the result is the weighted sum of the held experts'
     outputs alone. No capacity: a pair is never dropped. The kept pairs are
     sorted by expert and each projection is one grouped product
@@ -200,8 +219,11 @@ def expert_shard_layer(x, router_w, bias, experts_held, first_expert: int,
     n, d = x.shape
     held = experts_held["down"].shape[0]
     with jax.named_scope("moe_route"):
-        expert, weight = sigmoid_topk_route(x, router_w, bias, top_k, scale,
-                                            n_group, topk_group)
+        if route is None:
+            expert, weight = sigmoid_topk_route(
+                x, router_w, bias, top_k, scale, n_group, topk_group)
+        else:
+            expert, weight = route(x, router_w, top_k)
         local = (expert >= first_expert) & (expert < first_expert + held)
         if valid is not None:
             local = local & valid[:, None]

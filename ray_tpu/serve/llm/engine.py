@@ -118,7 +118,15 @@ class ModelFamily:
     `slots=`), and every step returns the sequences' new states after the
     cache rows. `paged_layers` names the module's function from a config to
     how many of its layers leave rows in the paged arena (all of them where
-    it is not given)."""
+    it is not given). `block_schedule` names the module's function from a
+    config to (positions a block, positions a pass reveals, the mask token)
+    of a family that generates by diffusion over blocks: its prefill steps
+    cover the whole blocks of a prompt and return None for logits (a
+    prefill yields no token), and its decode step takes one block a lane
+    ([lanes, block] tokens, the block's first position) and returns, first,
+    the (token, probability) it chose at every position, then the block's
+    cache rows, which the engine writes for the lanes whose block is whole
+    (`_decode_blocks`)."""
 
     module: str
     net: str
@@ -127,6 +135,7 @@ class ModelFamily:
     step_counts: Optional[str] = None
     seq_state: Optional[str] = None
     paged_layers: Optional[str] = None
+    block_schedule: Optional[str] = None
 
 
 MODEL_FAMILIES: Dict[str, ModelFamily] = {
@@ -137,6 +146,9 @@ MODEL_FAMILIES: Dict[str, ModelFamily] = {
     "ling_hybrid": ModelFamily("ray_tpu.models.ling_hybrid", "LingHybrid",
                                "LingHybridConfig", "cache_rows",
                                "STEP_COUNTS", "seq_state", "paged_layers"),
+    "sdar_moe": ModelFamily("ray_tpu.models.sdar_moe", "SdarMoe",
+                            "SdarMoeConfig", "cache_rows", "STEP_COUNTS",
+                            block_schedule="block_schedule"),
 }
 
 
@@ -304,18 +316,32 @@ class _Sequence:
     generated - 1 in steady state: the newest token rides as the next
     dispatch's input). `prefilled`/`cached` track the chunked-prefill
     frontier (prefilled starts at the prefix-cache hit length). `slot` is
-    the sequence's row of the state arena, for a family that keeps one."""
+    the sequence's row of the state arena, for a family that keeps one.
+    `prefill_len` is how much of the prompt the prefill covers: all of it,
+    or its whole blocks for a family that generates by blocks. Such a
+    sequence also keeps its open block, positions [pos, pos + block):
+    `block` the token ids (the mask token where nothing is revealed),
+    `revealed` a flag a position (never read off the ids: a prompt or a
+    choice may hold the mask token's id), `handed` how many leading
+    positions are the prompt's or already recorded as generated."""
 
-    __slots__ = ("req", "pages", "pos", "prefilled", "cached", "slot")
+    __slots__ = ("req", "pages", "pos", "prefilled", "cached", "slot",
+                 "prefill_len", "block", "revealed", "handed")
 
     def __init__(self, req: Request, pages: List[int], pos: int,
-                 cached: int = 0, slot: Optional[int] = None):
+                 cached: int = 0, slot: Optional[int] = None,
+                 prefill_len: Optional[int] = None):
         self.req = req
         self.pages = pages
         self.slot = slot
         self.pos = pos  # tokens already written to the KV cache
         self.prefilled = pos or cached
         self.cached = cached
+        self.prefill_len = len(req.prompt) if prefill_len is None \
+            else prefill_len
+        self.block: List[int] = []
+        self.revealed: List[bool] = []
+        self.handed = 0
 
     @property
     def last_token(self) -> int:
@@ -360,6 +386,13 @@ class LLMEngine:
         cfg = (engine_config or EngineConfig()).resolved(
             self.model_cfg.max_seq_len)
         self.config = cfg
+        # (positions a block, positions a pass reveals, the mask token) of
+        # a family that generates by diffusion over blocks, else None
+        self._block: Optional[Tuple[int, int, int]] = getattr(
+            mod, family.block_schedule)(self.model_cfg) \
+            if family.block_schedule else None
+        if self._block is not None:
+            self._check_block_config(model, cfg)
         self.max_pages_per_seq = -(-self.model_cfg.max_seq_len
                                    // cfg.block_size)
 
@@ -410,7 +443,9 @@ class LLMEngine:
 
         self._prefill_fns = {s: program(self._make_prefill_fn(s))
                              for s in cfg.prefill_buckets}
-        self._decode_fns = {b: program(self._make_decode_fn(b))
+        make_decode = self._make_decode_fn if self._block is None \
+            else self._make_block_decode_fn
+        self._decode_fns = {b: program(make_decode(b))
                             for b in cfg.batch_buckets}
         # one chunk executable (B=1, C=_chunk_size) covers both chunked
         # prefill windows and prefix-cache-hit suffixes: every window
@@ -461,6 +496,14 @@ class LLMEngine:
         for name in self._step_counts:
             self.counters[f"decode_{name}"] = 0
             self.counters[f"prefill_{name}"] = 0
+        if self._block is not None:
+            # a pass over the running set yields what its lanes' blocks
+            # reveal: live lanes summed over the passes, those of them that
+            # did nothing but write a whole block's rows, positions
+            # revealed, blocks whose rows were written
+            for name in ("lane_passes", "lane_commits", "tokens_revealed",
+                         "blocks_committed"):
+                self.counters[f"decode_{name}"] = 0
         if self._key_trips is not None:
             # the key slots a decode step's query rows were scored
             # against, padding included, over lanes and layers: the
@@ -528,6 +571,46 @@ class LLMEngine:
         fn.__name__ = f"llm_decode_b{batch}"
         return fn
 
+    def _check_block_config(self, model: str, cfg: EngineConfig) -> None:
+        """What generation by blocks asks of the options: a block never
+        straddles a page or a chunk, and no page is aliased."""
+        length = self._block[0]
+        chunk = cfg.prefill_chunk or max(cfg.prefill_buckets)
+        if cfg.block_size % length or chunk % length:
+            raise ValueError(
+                f"the {model!r} family generates by blocks of {length}: "
+                f"block_size={cfg.block_size} and the prefill chunk "
+                f"({chunk}) have to be multiples of it")
+        if cfg.prefix_cache:
+            raise ValueError(
+                f"prefix_cache=1 with the {model!r} family: a prefill "
+                f"covers a prompt's whole blocks alone, and the prefix "
+                f"cache's admission and insertion count whole prompts; "
+                f"no test has shown the pair right; pass prefix_cache=0")
+
+    def _make_block_decode_fn(self, batch: int):
+        """One pass over one block a lane (`block_schedule` families):
+        tokens, `w_page` and `w_off` are [batch, block]; `live` [batch]
+        marks the lanes that hold a sequence. A lane whose block is not
+        whole names the dropped page id in all its rows and writes
+        nothing. The first output is the step's (token, probability), each
+        [batch, block]."""
+        mod, n = self._mod, len(self.kv.arena)
+        cfg = self.model_cfg
+
+        def fn(variables, tokens, positions, *rest):
+            arena = rest[:n]
+            page_table, w_page, w_off, live = rest[n:]
+            chosen, *out = mod.decode_step(
+                variables, cfg, tokens, positions, *arena, page_table,
+                valid=live)
+            return (chosen,) + scatter_arena(
+                arena, [r.reshape((-1,) + r.shape[2:]) for r in out[:n]],
+                w_page.reshape(-1), w_off.reshape(-1)) + tuple(out[n:])
+
+        fn.__name__ = f"llm_decode_b{batch}"
+        return fn
+
     def _make_chunk_fn(self, size: int):
         """A window of `size` tokens of one sequence (chunked prefill, a
         prefix-cache suffix): `w_page` / `w_off` are [1, size]."""
@@ -573,7 +656,11 @@ class LLMEngine:
                 np.full(s, kv.num_pages, np.int32), np.zeros(s, np.int32),
                 *self._slots_of((), 1)))
         for b, fn in self._decode_fns.items():
-            self._warm_call(fn, (b,))
+            if self._block is None:
+                self._warm_call(fn, (b,))
+            else:
+                self._warm_call(fn, (b, self._block[0]),
+                                np.zeros(b, bool))
         self._warm_call(self._chunk_fn, (1, self._chunk_size))
 
     def _call(self, fn, args):
@@ -610,16 +697,17 @@ class LLMEngine:
                 for name, n in zip(self._step_counts, vector.tolist()):
                     self.counters[f"{kind}_{name}"] += n
 
-    def _warm_call(self, fn, rows: Tuple[int, ...]):
+    def _warm_call(self, fn, rows: Tuple[int, ...], *last):
         """One decode- or chunk-shaped call: tokens and write coordinates
-        are `rows`-shaped, positions and the page table one a lane."""
+        are `rows`-shaped, positions and the page table one a lane; `last`
+        is what a block pass takes after them."""
         b, kv = rows[0], self.kv
         self._call(fn, (
             self.params, np.zeros(rows, np.int32), np.zeros(b, np.int32),
             *kv.arena, *kv.state,
             np.zeros((b, self.max_pages_per_seq), np.int32),
             np.full(rows, kv.num_pages, np.int32), np.zeros(rows, np.int32),
-            *self._slots_of((), b)))
+            *self._slots_of((), b), *last))
 
     # -- submission -------------------------------------------------------
 
@@ -706,6 +794,9 @@ class LLMEngine:
             if self._running:
                 mark = phases.total_ns()
                 tokens_out += self._decode_once()
+                # work, whatever it yields: a pass over blocks may reveal
+                # nothing that can be streamed yet
+                advanced = True
                 decode_ns += phases.total_ns() - mark
             did = bool(tokens_out) or advanced
             if did:
@@ -774,9 +865,31 @@ class LLMEngine:
             slot = self.kv.take_slot(req) if self.kv.state else None
             req.admit_ts = time.monotonic()
             self._waiting.pop(0)
-            seq = _Sequence(req, pages, pos=0, cached=cached, slot=slot)
-            self._prefilling.append(seq)
+            seq = _Sequence(req, pages, pos=0, cached=cached, slot=slot,
+                            prefill_len=self._prefill_len(req))
+            if seq.prefill_len:
+                self._prefilling.append(seq)
+            else:
+                # a prompt shorter than a block: nothing to prefill
+                self._open_block(seq)
+                self._running.append(seq)
         return seq
+
+    def _prefill_len(self, req: Request) -> int:
+        """How much of the prompt the prefill covers: all of it, or, for a
+        family that generates by blocks, its whole blocks (the rest opens
+        the first block as revealed positions)."""
+        s = len(req.prompt)
+        return s if self._block is None else s - s % self._block[0]
+
+    def _open_block(self, seq: _Sequence) -> None:
+        """The block at `seq.pos`: what is left of the prompt past its
+        whole blocks as revealed positions, the mask token elsewhere."""
+        length, _, mask = self._block
+        known = seq.req.prompt[seq.pos:]
+        seq.block = list(known) + [mask] * (length - len(known))
+        seq.revealed = [True] * len(known) + [False] * (length - len(known))
+        seq.handed = len(known)
 
     # -- prefill (one-shot bucket / chunked / prefix-cache suffix) --------
 
@@ -791,7 +904,7 @@ class LLMEngine:
         with self._phases.phase("prefill_assemble"):
             seq = self._prefilling[0]
             req = seq.req
-            s = len(req.prompt)
+            s = seq.prefill_len
             mark = self._phases.total_ns()
             oneshot = (seq.prefilled == 0
                        and s <= max(self.config.prefill_buckets)
@@ -810,6 +923,8 @@ class LLMEngine:
                     if seq in self._prefilling:
                         self._prefilling.remove(seq)
                 if not seq.req.done.is_set():
+                    if self._block is not None:
+                        self._open_block(seq)
                     with self._lock:
                         self._running.append(seq)
             return emitted
@@ -861,14 +976,14 @@ class LLMEngine:
 
     def _prefill_oneshot(self, seq: _Sequence) -> int:
         req = seq.req
-        s = len(req.prompt)
+        s = seq.prefill_len
         bucket = min(b for b in self.config.prefill_buckets if b >= s)
         phase = self._phases.phase
         with self._request_phase("llm.prefill", req,
                                  {"bucket": bucket, "tokens_in": s}):
             with phase("prefill_assemble"):
                 toks = np.zeros((1, bucket), np.int32)
-                toks[0, :s] = req.prompt
+                toks[0, :s] = req.prompt[:s]
                 self._note_call("prefill", bucket)
             with phase("prefill_kv_write"):
                 w_page, w_off = self.kv.write_index(seq.pages, 0, s, bucket)
@@ -885,7 +1000,10 @@ class LLMEngine:
                 with self._lock:
                     self.counters["prefill_steps"] += 1
             with phase("prefill_sample"):
-                return self._emit_first(seq, next_logits[0])
+                # no logits: a family that generates by blocks, whose
+                # prefill yields no token
+                return 0 if next_logits is None \
+                    else self._emit_first(seq, next_logits[0])
 
     def _chunk_advance(self, seq: _Sequence) -> int:
         """One chunk: forward the next `_chunk_size` prompt tokens
@@ -893,7 +1011,7 @@ class LLMEngine:
         with `prefilled == cached > 0`, so the cached pages are attended
         but never recomputed)."""
         req = seq.req
-        s = len(req.prompt)
+        s = seq.prefill_len
         c = self._chunk_size
         take = min(c, s - seq.prefilled)
         phase = self._phases.phase
@@ -928,11 +1046,13 @@ class LLMEngine:
                 with self._lock:
                     self.counters["prefill_steps"] += 1
             with phase("prefill_sample"):
-                return self._emit_first(seq, logits[0, take - 1])
+                return 0 if logits is None \
+                    else self._emit_first(seq, logits[0, take - 1])
 
-    def _decode_forward(self, fn, args) -> np.ndarray:
+    def _decode_forward(self, fn, args):
         """One decode call: the call, which leaves the written rows' K and
-        V in their pages, the wait, the logits to the host. `args` hold
+        V in their pages, the wait, the logits (a block pass's two arrays)
+        to the host. `args` hold
         the arena, donated: its successor goes back into `self.kv`."""
         phase = self._phases.phase
         with phase("decode_dispatch"):
@@ -941,12 +1061,17 @@ class LLMEngine:
             # the np.asarray below would block on the logits anyway
             self._block_until_ready((logits, self.kv.arena, self.kv.state))
         with phase("decode_fetch"):
-            logits = np.asarray(logits)
-            self._count_link("decode_link_bytes", logits, *args)
+            # a block pass's (token, probability), else the logits
+            pair = isinstance(logits, tuple)
+            fetched = tuple(np.asarray(a)
+                            for a in (logits if pair else (logits,)))
+            self._count_link("decode_link_bytes", *fetched, *args)
             self._add_step_counts("decode", counts, "decode_link_bytes")
-            return logits
+            return fetched if pair else fetched[0]
 
     def _decode_once(self) -> int:
+        if self._block is not None:
+            return self._decode_blocks()
         phase = self._phases.phase
         # the pass's own time (batch assembly, what lies between the
         # phases below) is `decode_assemble`
@@ -1000,6 +1125,94 @@ class LLMEngine:
             for seq in finished:
                 self._finish(seq)
             return len(runs)
+
+    def _decode_blocks(self) -> int:
+        """One pass over the running set of a family that generates by
+        diffusion over blocks: every lane's open block goes through the
+        program as it stands. A lane whose block is whole COMMITS: its
+        rows are written (the other lanes name the dropped page id) and the
+        next block opens, all masks. Any other lane reveals the positions
+        the program was surest of, `reveal` of them or all that are left
+        (the lowest index on a tie); a revealed token is final. What grows
+        the revealed prefix of a block is recorded as generated, in
+        position order, and handed over as a token pass's are. Returns the
+        tokens recorded: 0 to a block's length a lane."""
+        phase = self._phases.phase
+        length, reveal, mask = self._block
+        with phase("decode_assemble"):
+            with self._lock:
+                runs = list(self._running)
+            bb = min(b for b in self.config.batch_buckets
+                     if b >= len(runs))
+            tokens = np.full((bb, length), mask, np.int32)
+            shown = np.ones((bb, length), bool)
+            positions = np.zeros(bb, np.int32)
+            live = np.arange(bb) < len(runs)
+            page_table = np.zeros((bb, self.max_pages_per_seq), np.int32)
+            for i, seq in enumerate(runs):
+                tokens[i] = seq.block
+                shown[i] = seq.revealed
+                positions[i] = seq.pos
+                page_table[i, :len(seq.pages)] = seq.pages
+            hidden = ~shown         # nothing is hidden in a lane past `runs`
+            commits = live & shown.all(axis=1)
+            with phase("decode_kv_append"):
+                # a block never straddles a page: one page id a lane, the
+                # block's offsets in it
+                w_page = np.full((bb, length), self.kv.num_pages, np.int32)
+                w_off = np.zeros((bb, length), np.int32)
+                for i in np.flatnonzero(commits):
+                    slot, first = divmod(runs[i].pos, self.kv.block_size)
+                    w_page[i] = runs[i].pages[slot]
+                    w_off[i] = first
+                w_off += np.arange(length, dtype=np.int32)
+            self._note_call("decode", bb)
+            chosen, prob = self._decode_forward(
+                self._decode_fns[bb],
+                (self.params, tokens, positions, *self.kv.arena,
+                 page_table, w_page, w_off, live))
+            with phase("decode_kv_append"):
+                context = int(positions.sum())
+                for i in np.flatnonzero(commits):
+                    runs[i].pos += length
+                    self._open_block(runs[i])
+            finished = []
+            emitted = 0
+            with phase("decode_sample"):
+                # the hidden positions by falling probability, a stable
+                # sort so that the lowest index wins a tie; `reveal` of
+                # them, or all that are left (a NaN counts as 0: it must
+                # not sort behind the revealed and stall its lane)
+                order = np.argsort(
+                    np.where(hidden, -np.nan_to_num(prob), np.inf), axis=1,
+                    kind="stable")
+                take = np.minimum(hidden.sum(axis=1), reveal)
+                for i in np.flatnonzero(take):
+                    seq = runs[i]
+                    for j in order[i, :take[i]].tolist():
+                        seq.block[j] = int(chosen[i, j])
+                        seq.revealed[j] = True
+                    while seq.handed < length and seq.revealed[seq.handed]:
+                        tok = seq.block[seq.handed]
+                        seq.handed += 1
+                        self._held.append(
+                            (seq.req, seq.req._record(tok), tok))
+                        emitted += 1
+                        if self._seq_finished(seq, tok):
+                            finished.append(seq)
+                            break
+                n_commits = int(commits.sum())
+                with self._lock:
+                    self.counters["decode_steps"] += 1
+                    self.counters["decode_context_tokens"] += context
+                    self.counters["decode_lane_passes"] += len(runs)
+                    self.counters["decode_lane_commits"] += n_commits
+                    self.counters["decode_blocks_committed"] += n_commits
+                    self.counters["decode_tokens_revealed"] += \
+                        int(take.sum())
+            for seq in finished:
+                self._finish(seq)
+            return emitted
 
     def _seq_finished(self, seq: _Sequence, tok: int) -> bool:
         if seq.n_generated >= seq.req.max_new_tokens:

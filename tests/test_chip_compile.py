@@ -354,6 +354,73 @@ def test_sequence_state_arena_is_updated_in_place_at_ling_widths(topo, kind,
         assert not moved, moved
 
 
+@pytest.mark.parametrize("kind, size", [("decode", 64), ("prefill", 1024),
+                                        ("chunk", 1024)])
+def test_block_programs_fit_the_chip_at_sdar_widths(topo, kind, size):
+    """The engine's decode-64 (one block of 4 a lane), prefill-1,024 and
+    chunk-1,024 programs of the SDAR-MoE cell (published widths, 7 layers,
+    all 128 experts, the whole vocabulary, 8,256 pages of 16 tokens): the
+    K/V arena aliases its outputs and no operation copies an array of its
+    shape, the program fits the chip beside its 9.97 GB of weights, the
+    block pass's logits are float32 [lanes, 4, vocabulary] inside the
+    program (it returns two [lanes, 4] arrays), and a prefill computes no
+    head at all."""
+    import types
+
+    from ray_tpu.models import sdar_moe
+    from ray_tpu.serve.llm.engine import LLMEngine
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    cfg = sdar_moe.SdarMoeConfig(n_layer=7, max_seq_len=4096)
+    block, num_pages = 16, 8256
+
+    def on_chip(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: on_chip(a.shape, a.dtype),
+        jax.eval_shape(sdar_moe.SdarMoe(cfg).init, jax.random.PRNGKey(0),
+                       jnp.ones((1, 16), jnp.int32)))
+    arena = tuple(on_chip((num_pages, cfg.n_layer, block) + row, jnp.bfloat16)
+                  for row in sdar_moe.cache_rows(cfg))
+    engine = types.SimpleNamespace(
+        _mod=sdar_moe, model_cfg=cfg, _step_counts=sdar_moe.STEP_COUNTS,
+        kv=types.SimpleNamespace(arena=arena, state=()))
+    table = on_chip((size if kind == "decode" else 1,
+                     cfg.max_seq_len // block))
+    if kind == "decode":
+        rows = (size, cfg.block_length)
+        fn = LLMEngine._make_block_decode_fn(engine, size)
+        args = (params, on_chip(rows), on_chip((size,)), *arena, table,
+                on_chip(rows), on_chip(rows), on_chip((size,), jnp.bool_))
+    elif kind == "prefill":
+        fn = LLMEngine._make_prefill_fn(engine, size)
+        args = (params, on_chip((1, size)), on_chip((1,)), *arena,
+                on_chip((size,)), on_chip((size,)))
+    else:
+        fn = LLMEngine._make_chunk_fn(engine, size)
+        args = (params, on_chip((1, size)), on_chip((1,)), *arena, table,
+                on_chip((1, size)), on_chip((1, size)))
+    compiled = jax.jit(fn, donate_argnums=(3, 4)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    held = sum(2 * math.prod(a.shape) for a in arena)
+    assert held <= mem.alias_size_in_bytes < held + 2**22
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    text = compiled.as_text()
+    shape = "bf16[" + ",".join(map(str, arena[0].shape)) + "]"
+    moved = [line.strip()[:120] for line in text.splitlines()
+             if " copy(" in line and shape in line.split(" copy(")[0]]
+    assert not moved, moved
+    if kind == "decode":
+        logits = f"[{size},{cfg.block_length},{cfg.vocab_size}]"
+        assert "f32" + logits in text and "bf16" + logits not in text
+    else:
+        # no array as wide as the vocabulary but the embedding
+        import re
+        assert set(re.findall(rf"\w+\[[\d,]*{cfg.vocab_size}[\d,]*\]", text)) \
+            == {f"bf16[{cfg.vocab_size},{cfg.d_model}]"}
+
+
 def test_build_mesh_on_tpu_follows_the_topology(topo):
     """On TPU devices `build_mesh` takes `create_device_mesh`'s assignment
     (a 2x2 torus orders the ring 0,1,3,2), never a plain reshape."""

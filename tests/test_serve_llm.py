@@ -1123,6 +1123,128 @@ def test_serve_llm_end_to_end_with_the_sequence_state_family(
     assert m["prefill_kda_state_rows"] == 3 * 6
 
 
+def test_serve_llm_end_to_end_with_the_block_diffusion_family(
+        clean_deployments):
+    """The SDAR-MoE family through the same door: `build_app(model=...)` ->
+    `serve.run` -> `handle.generate`: chunked prefill of the prompt's whole
+    blocks, denoising and commit passes in the replica, tokens streamed in
+    position order with their indices, the tokens a local engine of the
+    same seed gives, and the block counters in the replica's metrics."""
+    from ray_tpu import serve
+    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
+
+    engine_config = {"batch_buckets": (1, 2), "prefill_buckets": (16,),
+                     "prefill_chunk": 16, "num_pages": 32, "block_size": 8,
+                     "prefix_cache": 0}
+    handle = serve.run(serve.llm.build_app(
+        name="llm", num_replicas=1, model="sdar_moe",
+        engine_config=engine_config))
+    prompt = list(range(3, 40))          # 36 in three chunks, 1 opens a block
+    chunks = list(handle.generate.options(stream=True).remote(prompt, 6))
+    assert [c["index"] for c in chunks] == list(range(6))
+    streamed = [c["token"] for c in chunks]
+    local = LLMEngine(model="sdar_moe",
+                      engine_config=EngineConfig(**engine_config))
+    try:
+        want = local.submit(prompt, 6)
+        local.run_until_idle()
+        assert streamed == want.result()
+    finally:
+        local.shutdown()
+    m = handle.engine_metrics.remote().result(timeout=60)
+    assert m["model"] == "sdar_moe" and m["kv_pages_live"] == 0
+    assert (m["chunk_steps"], m["prefill_steps"]) == (3, 1)
+    # [p, ., ., .] two passes and a commit, then [., ., ., .] until the
+    # sixth token shows: two passes, no commit
+    assert (m["decode_lane_passes"], m["decode_lane_commits"],
+            m["decode_blocks_committed"]) == (5, 1, 1)
+    assert m["decode_tokens_revealed"] == 3 + 4
+    assert m["tokens_generated"] == 6
+    assert m["decode_moe_pairs_routed"] > 0
+
+
+@pytest.mark.parametrize("model", ["sdar_moe"])
+def test_chunked_prefill_matches_oneshot(model):
+    """A family that generates by blocks: the whole blocks of a prompt
+    leave the same K and V rows and lead to the same tokens whether they
+    go through the chunk program (three windows of 8, block-causal against
+    the pages) or one shot (a bucket of 32); the rest of the prompt opens
+    the first block either way."""
+    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
+
+    prompt = [int(t) for t in
+              np.random.RandomState(11).randint(1, 500, size=22)]
+    got = {}
+    for name, cfg in (("chunked", dict(prefill_buckets=(8,),
+                                       prefill_chunk=8)),
+                      ("oneshot", dict(prefill_buckets=(32,),
+                                       prefill_chunk=32))):
+        eng = LLMEngine(model=model, seed=0, engine_config=EngineConfig(
+            batch_buckets=(1,), block_size=4, num_pages=16, prefix_cache=0,
+            **cfg))
+        try:
+            req = eng.submit(prompt, 7)
+            while not eng._running:
+                eng.step()
+            seq = eng._running[0]
+            # the step that ends the prefill also makes the first pass
+            assert (seq.pos, seq.block[:2], seq.revealed[:2]) == \
+                (20, prompt[20:], [True, True])
+            rows = [np.asarray(pages)[seq.pages[:5]] for pages in eng.kv.arena]
+            eng.run_until_idle(timeout=120)
+            m = eng.metrics()
+            got[name] = (req.result(timeout=5), rows, m["chunk_steps"])
+            eng.quiesce()
+        finally:
+            assert eng.shutdown() == 0
+    assert (got["chunked"][2], got["oneshot"][2]) == (3, 0)
+    assert got["chunked"][0] == got["oneshot"][0]
+    for a, b in zip(got["chunked"][1], got["oneshot"][1]):
+        assert np.abs(a).max() > 0
+        np.testing.assert_allclose(a, b, atol=2e-6, rtol=1e-5)
+
+
+def test_block_family_streams_in_position_order_and_stops_inside_a_block():
+    """Through the pump thread: a reader sees every token once, in
+    position order with its index, though a pass may reveal a block's later
+    position first; the engine has no call that cancels a running request
+    (of any family), so the cut is the pump's: stopped between a lane's
+    passes, once the last pass's tokens are handed over (`stop` does it) the
+    reader has every token recorded so far, the cut sequence's pages are
+    what `shutdown` reports, and nothing else is lost."""
+    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
+
+    eng = LLMEngine(model="sdar_moe", seed=0, engine_config=EngineConfig(
+        batch_buckets=(1, 2), prefill_buckets=(8, 16), prefill_chunk=8,
+        block_size=8, num_pages=32, prefix_cache=0))
+    try:
+        eng.start()
+        reqs = [eng.submit(list(range(5, 5 + n)), new)
+                for n, new in ((13, 9), (6, 5))]
+        for req, new in zip(reqs, (9, 5)):
+            seen = list(req.stream(timeout=60))
+            assert seen == req.tokens and len(seen) == new
+        eng.quiesce()
+        eng.stop()
+        # by hand now: a request left inside its second block
+        req = eng.submit(list(range(7, 20)), 12)
+        while not (eng._running and eng._running[0].pos == 16
+                   and eng._running[0].revealed.count(True) == 2):
+            eng.step()
+        held = len(req.tokens)
+        assert 3 <= held < 12 and not req.done.is_set()
+        eng._hand_over_held()       # what a running pump's `stop` does
+        got = []
+        while not req.out_q.empty():
+            got.append(req.out_q.get_nowait())
+        assert [(k, i) for k, i, _ in got] == \
+            [("token", i) for i in range(held)]
+        assert [t for _, _, t in got] == req.tokens
+    finally:
+        # 13 + 12 tokens reserve 4 pages of 8: the cut sequence's, no more
+        assert eng.shutdown() == 4
+
+
 # sha256 (16 hex digits) of the lowered text of the engine's programs for
 # the tiny default model of each family that was there before the Ling
 # hybrid family came (commit c564374, PR 31): a bucket of each kind. A later
