@@ -199,7 +199,7 @@ def result_line(bench, cell, obs, trace: bool) -> dict:
     line["notes"] = {k: obs[k] for k in (
         "steps", "whole_laps", "check", "loss_first", "loss_last",
         "warmup_s", "compiled_step_calls", "memory", "tokens_in_window",
-        "window_s") if k in obs}
+        "window_s", "trace_tries") if k in obs}
     return line
 
 
@@ -242,23 +242,35 @@ def main(argv=None) -> int:
     parser.add_argument("--seconds", type=float, default=10.0)
     parser.add_argument("--trace", type=int, choices=(0, 1), default=0)
     args = parser.parse_args(argv)
+    from benchmark import stage
+
+    def failed(what: str) -> int:
+        """The last line of standard error of a run that prints no result:
+        the driver keeps a failed run's exit code and the end of its
+        standard error, so this line is all a later reader has."""
+        print(f"benchmark failed: workload={args.workload} "
+              f"trace={args.trace} stage={stage.current()} {what}",
+              file=sys.stderr, flush=True)
+        return 1
+
     try:
         line = run(args)
-    except BaseException:
+    except BaseException as e:
         traceback.print_exc()
         try:
             keep_logs(args.workload)
         except OSError:
             pass
-        kill_leftovers()
-        return 1
+        left = kill_leftovers()
+        first = (str(e).strip().splitlines() or [""])[0][:400]
+        return failed(f"{type(e).__name__}: {first}" + (
+            f" (left running, killed: {left})" if left else ""))
+    stage.enter("leftovers")
     left = kill_leftovers()
     if left:
-        print(f"left running after shutdown, killed: {left}", file=sys.stderr)
-        return 1
+        return failed(f"LeftRunning: after shutdown, killed: {left}")
     if "jax" in sys.modules:
-        print("the benchmark's process imported jax", file=sys.stderr)
-        return 1
+        return failed("ImportedJax: the benchmark's process imported jax")
     print(json.dumps(line), flush=True)
     return 0
 

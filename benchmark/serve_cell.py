@@ -11,7 +11,6 @@ from __future__ import annotations
 
 import importlib
 import random
-import shutil
 import tempfile
 import threading
 import time
@@ -139,24 +138,21 @@ class BenchLLMDeployment(LLMDeployment):
                 if r.role == "engine" and r.ttft_ms is not None]
 
     def bench_trace_start(self) -> bool:
-        import jax
-
-        self._trace_dir = tempfile.mkdtemp(prefix="bench_trace_")
-        jax.profiler.start_trace(self._trace_dir)
-        return True
-
-    def bench_trace_stop(self) -> dict:
-        import jax
-
         from benchmark import trace_reduce
 
-        jax.profiler.stop_trace()
-        try:
-            return trace_reduce.reduce_trace(
-                trace_reduce.load_xplane(self._trace_dir))
-        finally:
-            shutil.rmtree(self._trace_dir, ignore_errors=True)
-            self._trace_dir = None
+        self._trace_dir = tempfile.mkdtemp(prefix="bench_trace_")
+        trace_reduce.start_session(self._trace_dir)
+        return True
+
+    def bench_trace_stop(self) -> str:
+        """Ends the session and names the directory it wrote: the replica
+        and `run.py` share the machine and its `TMPDIR`, and the reduction
+        is `run.py`'s, in a child, so this interpreter (the pump's and the
+        readers') is held for the profiler's own write and no longer."""
+        from benchmark import trace_reduce
+
+        trace_reduce.stop_session()
+        return self._trace_dir
 
     def bench_check(self, prompts: List[List[int]],
                     streamed: List[List[int]]) -> dict:
@@ -213,6 +209,23 @@ def _wait_streams_started(samples, callers: int, deadline: float) -> None:
     raise RuntimeError("ramp-up: not every caller got a first token")
 
 
+def _slice_taker(handle, seconds: float):
+    """The traced slice of a serving cell: the session is the replica's,
+    the judging `run.py`'s (a child), the two reached through the handle."""
+    from benchmark import stage, trace_reduce
+
+    def start():
+        handle.bench_trace_start.remote().result(timeout=60)
+
+    def stop():
+        stage.enter("trace_stop")
+        trace_dir = handle.bench_trace_stop.remote().result(timeout=120)
+        stage.enter("window")
+        return trace_dir
+
+    return trace_reduce.SliceTaker(start, stop, seconds, serving=True)
+
+
 def run_serve_cell(cell: dict, config: dict, traffic: dict, seed: int,
                    seconds: float, trace: bool, t_start_wall: float,
                    require_tpu: bool = True,
@@ -220,6 +233,7 @@ def run_serve_cell(cell: dict, config: dict, traffic: dict, seed: int,
     import ray_tpu
     from ray_tpu import serve
 
+    from benchmark import stage
     from benchmark import traffic as tg
     from benchmark.device_memory import over_limit
 
@@ -231,6 +245,7 @@ def run_serve_cell(cell: dict, config: dict, traffic: dict, seed: int,
         warm[min(b for b in buckets if b >= length)] = length
     warm_prompts = [warm[b] for b in sorted(warm)]
     cluster = dict(config.get("cluster", {}))
+    stage.enter("setup")
     ray_tpu.init(**cluster)
     try:
         deco = serve.deployment(
@@ -258,6 +273,7 @@ def run_serve_cell(cell: dict, config: dict, traffic: dict, seed: int,
         if traffic["kind"] not in ("closed-loop", "open-loop"):
             raise ValueError(f"unknown traffic kind {traffic['kind']!r}")
         ramp = traffic.get("ramp")
+        stage.enter("ramp")
         if ramp == "all_callers_streaming":
             # ramp-up is set-up: the window opens on a full running set
             samples, threads = tg.run_closed_loop(
@@ -276,6 +292,7 @@ def run_serve_cell(cell: dict, config: dict, traffic: dict, seed: int,
         info1 = handle.bench_info.remote().result(timeout=60)
         t_open = time.perf_counter()
         obs["setup_s"] = time.time() - t_start_wall
+        stage.enter("window")
         if traffic["kind"] == "open-loop":
             samples, threads = tg.run_open_loop(
                 stream, feeder, tg.arrival_times(traffic, seed, seconds),
@@ -284,14 +301,16 @@ def run_serve_cell(cell: dict, config: dict, traffic: dict, seed: int,
             samples, threads = tg.run_closed_loop(
                 stream, feeder, traffic["callers"], stop)
         if trace:
-            time.sleep(min(3.0, seconds / 4))
-            handle.bench_trace_start.remote().result(timeout=60)
-            time.sleep(min(4.0, seconds / 4))
-            obs["trace"] = handle.bench_trace_stop.remote().result(
-                timeout=300)
+            taker = _slice_taker(handle, seconds)
+            while not taker.idle and \
+                    time.perf_counter() < t_open + seconds - 0.1:
+                taker.poll(time.perf_counter() - t_open)
+                time.sleep(0.05)
         time.sleep(max(0.0, t_open + seconds - time.perf_counter()))
         t_close = time.perf_counter()
         info2 = handle.bench_info.remote().result(timeout=60)
+        if trace:
+            taken = taker.close(t_close - t_open)
         stop.set()
         for t in threads:
             t.join(timeout=180)
@@ -302,6 +321,7 @@ def run_serve_cell(cell: dict, config: dict, traffic: dict, seed: int,
         info3 = handle.bench_info.remote().result(timeout=60)
         # `correct`, through the path the callers used: greedy answers to a
         # few seeded prompts, judged by the reference in the replica
+        stage.enter("check")
         rng = random.Random(seed * 1000003 + 41)
         prompts = [[rng.randrange(config["vocab_size"]) for _ in range(n)]
                    for n in traffic.get("check_prompts", [40, 200])]
@@ -311,9 +331,13 @@ def run_serve_cell(cell: dict, config: dict, traffic: dict, seed: int,
             timeout=900)
         if any(len(answer) != new for answer in streamed):
             check["failures"].append("a check prompt's answer came short")
+        stage.enter("shutdown")
         serve.shutdown()
     finally:
         ray_tpu.shutdown()
+    if trace:
+        from benchmark import trace_reduce
+        trace_reduce.reduce_taken(obs, taken, serving=True)
     obs["failures"] += check["failures"]
     obs["check"] = check
     obs["samples"] = samples

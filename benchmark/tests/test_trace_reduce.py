@@ -86,8 +86,73 @@ def test_an_idle_gap_is_named_by_the_innermost_host_span_over_it():
     # [6,8] has only the step's span over it; the thread's outermost span
     # covers every gap and names none
     assert gaps["engine.py:563 step"] == pytest.approx(0.002)
+    assert gaps[tr.HOLES] == 0.0 and gaps[tr.UNCOVERED] == 0.0
     r = tr.reduce_trace(_planes(ops))
-    assert dict(r["idle_gaps"]) == {"no_host_span": pytest.approx(0.006)}
+    assert dict(r["idle_gaps"]) == {tr.HOLES: 0.0,
+                                    tr.UNCOVERED: pytest.approx(0.006)}
+    assert r["idle_s"] == pytest.approx(0.006)
+
+
+def test_the_programs_span_names_a_gap_before_any_other_host_event():
+    """With the Python tracer off (PR 41) the host events are the `rt/`
+    phases and the runtime's own; a gap's time goes to the innermost phase
+    open at each instant of it, however long the phase, and to a runtime
+    event only where no phase reaches."""
+    ops = [("%fusion.1 = f32[4]{0} fusion(", 0, 1 * MS),
+           ("%fusion.2 = f32[4]{0} fusion(", 1.1 * MS, 0.9 * MS),
+           ("%fusion.3 = f32[4]{0} fusion(", 5 * MS, 1 * MS),
+           ("%fusion.4 = f32[4]{0} fusion(", 8 * MS, 1 * MS),
+           ("%fusion.5 = f32[4]{0} fusion(", 9.01 * MS, 0.99 * MS)]
+    host = [("rt/decode_device_wait", 0, 2.5 * MS),    # 25 x the hole in it
+            ("rt/decode_sample", 2.5 * MS, 2.6 * MS),
+            ("XlaDelinearize", 2.2 * MS, 2.7 * MS),
+            ("TpuExecute", 6.1 * MS, 1.8 * MS)]
+    r = tr.reduce_trace(_planes(ops, host))
+    gaps = dict(r["idle_gaps"])
+    # [1, 1.1] and the first half millisecond of [2, 5], then the sample's
+    assert gaps["rt/decode_device_wait"] == pytest.approx(0.0006)
+    assert gaps["rt/decode_sample"] == pytest.approx(0.0025)
+    assert gaps["TpuExecute"] == pytest.approx(0.002)
+    assert gaps[tr.HOLES] == pytest.approx(0.00001)    # [9, 9.01]
+    assert "XlaDelinearize" not in gaps
+    assert sum(gaps.values()) == pytest.approx(r["idle_s"])
+    assert r["span_events"] == 2 and r["host_events"] == 4
+    assert r["device_events"] == 5
+
+
+def test_load_keeps_the_lines_the_reduction_reads(monkeypatch, tmp_path):
+    """`load_xplane` lists the `XLA Ops` line of a device plane and the
+    timed host events, and builds nothing of the other lines."""
+    import types
+
+    def ev(name, start, dur):
+        return types.SimpleNamespace(name=name, start_ns=start,
+                                     duration_ns=dur)
+
+    def line(name, events):
+        return types.SimpleNamespace(name=name, events=events)
+
+    data = types.SimpleNamespace(planes=[
+        types.SimpleNamespace(name="/device:TPU:0", lines=[
+            line("XLA Modules", [ev("jit_step(1)", 0, 10)]),
+            line("XLA Ops", [ev("%fusion.1 = f32[4]{0} fusion(", 0, 5)]),
+            line("Async XLA Ops", [ev("%copy-start", 0, 5)])]),
+        types.SimpleNamespace(name="/host:CPU", lines=[
+            line("python3", [ev("rt/admit", 1, 3), ev("instant", 2, 0)])]),
+        types.SimpleNamespace(name="/host:metadata", lines=[])])
+    import jax.profiler
+    monkeypatch.setattr(jax.profiler.ProfileData, "from_file",
+                        staticmethod(lambda path: data))
+    folder = tmp_path / "plugins" / "profile" / "2026_01_01"
+    folder.mkdir(parents=True)
+    (folder / "host.xplane.pb").write_bytes(b"")
+    assert tr.load_xplane(str(tmp_path)) == [
+        {"name": "/device:TPU:0", "lines": [{"name": "XLA Ops", "events": [
+            ["%fusion.1 = f32[4]{0} fusion(", 0.0, 5.0]]}]},
+        {"name": "/host:CPU", "lines": [{"name": "python3", "events": [
+            ["rt/admit", 1.0, 3.0]]}]}]
+    with pytest.raises(FileNotFoundError):
+        tr.load_xplane(str(tmp_path / "plugins"))
 
 
 def test_no_device_plane_reduces_to_nothing():
@@ -117,8 +182,9 @@ def test_recorded_trace_from_the_chip():
         want = json.load(f)
     got = tr.reduce_trace(planes)
     for key in ("devices", "window_s", "busy_s", "collective_s",
-                "collective_exposed_s"):
+                "collective_exposed_s", "idle_s"):
         assert got[key] == pytest.approx(want[key]), key
+    assert sum(v for _, v in got["idle_gaps"]) == pytest.approx(got["idle_s"])
     assert [k for k, _ in got["device_ops"]] == \
         [k for k, _ in want["device_ops"]]
     assert [k for k, _ in got["idle_gaps"]] == \

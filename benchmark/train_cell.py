@@ -10,6 +10,7 @@ in `train_loop`; it hands one dict of observations back through
 from __future__ import annotations
 
 import math
+import os
 import shutil
 import tempfile
 import time
@@ -187,10 +188,13 @@ def train_loop(loop_config):
     can see: the device, its memory, the trace, `correct`."""
     import jax
 
+    from benchmark import stage
     from benchmark.device_memory import PeakSampler, over_limit
     from ray_tpu import parallel, train
     from ray_tpu.util import step_profiler
 
+    stage.write_to(loop_config["stage_file"])
+    stage.enter("setup")
     config, traffic = loop_config["config"], loop_config["traffic"]
     seed, seconds = loop_config["seed"], loop_config["seconds"]
     devices = jax.devices()
@@ -226,33 +230,24 @@ def train_loop(loop_config):
     jax.block_until_ready((carry, loss))
     stats0 = parallel.cache_stats()
     step_profiler.clear()
-    trace_dir = None
-    # the traced slice: 4 s on one chip, 2 s on four (four planes to reduce)
-    slice_s = 4.0 / max(1, config["chips"] // 2)
-    trace_at = (min(3.0, seconds / 4), min(3.0 + slice_s, seconds / 2)) \
-        if loop_config["trace"] else None
-    tracing = False
     losses, step_ms = [], []
+    taker = _slice_taker(losses, seconds) if loop_config["trace"] else None
+    stage.enter("window")
     t_open_wall = time.time()
     t0 = time.perf_counter()
     while True:
+        if taker is not None:
+            # between steps: opens the slice, or fences the last step and
+            # ends it, or looks whether its check has come back
+            taker.poll(time.perf_counter() - t0)
         t1 = time.perf_counter()
         if t1 - t0 >= seconds:
             break
-        if trace_at and not tracing and trace_dir is None \
-                and t1 - t0 >= trace_at[0]:
-            trace_dir = tempfile.mkdtemp(prefix="bench_trace_")
-            jax.profiler.start_trace(trace_dir)
-            tracing = True
         carry, loss = runner.run(carry, batches)
         losses.append(loss)
-        if tracing and time.perf_counter() - t0 >= trace_at[1]:
-            jax.block_until_ready(loss)
-            jax.profiler.stop_trace()
-            tracing = False
         step_ms.append((time.perf_counter() - t1) * 1e3)
-    if tracing:
-        jax.profiler.stop_trace()
+    if taker is not None:
+        taker.stop_open_slice()
     jax.block_until_ready((carry, loss))     # the fence on the last step
     window_s = time.perf_counter() - t0
 
@@ -272,13 +267,11 @@ def train_loop(loop_config):
         "cache_stats": stats1,
         "memory_peak_bytes": obs["memory"]["memory_peak_bytes"],
     })
-    if trace_dir is not None:
-        from benchmark import trace_reduce
-        try:
-            obs["trace"] = trace_reduce.reduce_trace(
-                trace_reduce.load_xplane(trace_dir))
-        finally:
-            shutil.rmtree(trace_dir, ignore_errors=True)
+    if taker is not None:
+        # the worker held the session; the reduction is the driver's, in a
+        # child, once `fit()` has returned (`run_train_cell`)
+        obs["trace_taken"] = taker.close(window_s)
+    stage.enter("check")
     losses = [float(x) for x in losses]
     obs["loss_first"], obs["loss_last"] = losses[0], losses[-1]
     failures, obs["check"] = _check(job, carry, config, traffic, seed,
@@ -293,6 +286,30 @@ def train_loop(loop_config):
         failures += _check_spread(carry)
     obs["failures"] += failures
     train.report({"bench": obs})
+
+
+def _slice_taker(losses: list, seconds: float):
+    """The traced slice of a training cell: the worker holds the session,
+    so it starts and stops it between steps; the stop fences the last step
+    so that the slice ends on whole steps."""
+    import jax
+
+    from benchmark import stage, trace_reduce
+
+    dirs = []
+
+    def start():
+        dirs.append(tempfile.mkdtemp(prefix="bench_trace_"))
+        trace_reduce.start_session(dirs[-1])
+
+    def stop():
+        stage.enter("trace_stop")
+        jax.block_until_ready(losses[-1])
+        trace_reduce.stop_session()
+        stage.enter("window")
+        return dirs[-1]
+
+    return trace_reduce.SliceTaker(start, stop, seconds, serving=False)
 
 
 def _check_spread(carry) -> list:
@@ -316,7 +333,14 @@ def run_train_cell(cell: dict, config: dict, traffic: dict, seed: int,
     from ray_tpu import train
     from ray_tpu.air.config import RunConfig, ScalingConfig
 
+    from benchmark import stage
+
     storage = tempfile.mkdtemp(prefix="bench_train_")
+    # the worker's stage, for a failed run's last line; kept when it fails
+    stage_file = os.path.join(tempfile.gettempdir(),
+                              f"bench_stage_{os.getpid()}")
+    stage.read_from(stage_file)
+    stage.enter("setup")
     ray_tpu.init()
     try:
         result = train.JaxTrainer(
@@ -324,7 +348,7 @@ def run_train_cell(cell: dict, config: dict, traffic: dict, seed: int,
             train_loop_config={
                 "config": config, "traffic": traffic, "seed": seed,
                 "seconds": seconds, "trace": trace,
-                "require_tpu": require_tpu},
+                "require_tpu": require_tpu, "stage_file": stage_file},
             scaling_config=ScalingConfig(
                 num_workers=1, use_tpu=require_tpu,
                 tpus_per_worker=cell["chips"] if require_tpu else 0),
@@ -333,7 +357,15 @@ def run_train_cell(cell: dict, config: dict, traffic: dict, seed: int,
     finally:
         ray_tpu.shutdown()
         shutil.rmtree(storage, ignore_errors=True)
+    stage.enter("shutdown")
+    stage.read_from(None)
+    if os.path.exists(stage_file):
+        os.remove(stage_file)
     obs = result.metrics["bench"]
+    taken = obs.pop("trace_taken", None)
+    if taken is not None:
+        from benchmark import trace_reduce
+        trace_reduce.reduce_taken(obs, taken, serving=False)
     if "window_open_wall" in obs:
         obs["setup_s"] = obs["window_open_wall"] - t_start_wall
         obs["worker_ready_s"] = obs["worker_ready_wall"] - t_start_wall
