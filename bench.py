@@ -1288,7 +1288,7 @@ def bench_serve_llm():
                 break
             req = eng.submit(prompts[cid % len(prompts)], max_new)
             req.result(timeout=300)
-            mine.append(req.finish_ts - req.submit_ts)
+            mine.append((req.finish_ns - req.submit_ns) / 1e9)
         with lat_lock:
             latencies.extend(mine)
 

@@ -116,14 +116,16 @@ async function refresh() {
             `  tpot p50=${esc(s.tpot_ms_p50??'-')} ms</p>`;
     html += '<table><tr><th>req</th><th>deploy</th><th>job</th>' +
             '<th>total ms</th><th>queue</th><th>admit</th>' +
-            '<th>prefill</th><th>decode</th><th>ttft</th><th>tpot</th>' +
+            '<th>prefill span</th><th>own prefill</th><th>first hold</th>' +
+            '<th>decode</th><th>ttft</th><th>tpot</th>' +
             '<th>tok</th><th>outcome</th></tr>';
     for (const r of (reqs.slowest || []).slice(0, 10)) {
       const f = v => (v == null) ? '-' : Number(v).toFixed(2);
       html += `<tr><td>${esc((r.req_id||'?').slice(0,8))}</td>` +
               `<td>${esc(r.deployment||'')}</td><td>${esc(r.job||'')}</td>` +
               `<td>${f(r.total_ms)}</td><td>${f(r.queue_ms)}</td>` +
-              `<td>${f(r.admission_ms)}</td><td>${f(r.prefill_ms)}</td>` +
+              `<td>${f(r.admission_ms)}</td><td>${f(r.prefill_span_ms)}</td>` +
+              `<td>${f(r.prefill_ms)}</td><td>${f(r.first_hold_ms)}</td>` +
               `<td>${f(r.decode_ms)}</td><td>${f(r.ttft_ms)}</td>` +
               `<td>${f(r.tpot_ms)}</td><td>${esc(r.tokens_out||0)}</td>` +
               `<td>${esc(r.outcome||'ok')}</td></tr>`;

@@ -94,6 +94,8 @@ class ServeController:
         self._rings: Dict[str, Any] = {}
         self._router_wakes: Dict[str, Any] = {}
         self._ring_attached: Dict[str, set] = {}
+        # replicas (and proxies) killed, by why: `_kill`'s reasons
+        self._kills: Dict[str, int] = {}
         _metrics.DEFAULT_REGISTRY.register_callback(
             "serve_controller", self._metrics_text)
 
@@ -130,7 +132,7 @@ class ServeController:
             st = self._deployments.pop(name, None)
             victims = list(st.replicas) if st else []
         for r in victims:
-            self._kill(r)
+            self._kill(r, name, "deleted")
         self._teardown_dispatch(name)
 
     def get_replicas(self, name: str) -> Dict[str, Any]:
@@ -168,16 +170,16 @@ class ServeController:
     def shutdown(self) -> None:
         self._running = False
         with self._lock:
-            victims: List[Any] = []
-            for st in self._deployments.values():
-                victims.extend(st.replicas)
+            victims: List[Tuple[Any, str]] = []
+            for name, st in self._deployments.items():
+                victims.extend((r, name) for r in st.replicas)
             self._deployments.clear()
-            victims.extend(r for r, _ in self._draining)
+            victims.extend((r, "(draining)") for r, _ in self._draining)
             self._draining = []
-            victims.extend(self._proxies)
+            victims.extend((p, "(proxy)") for p in self._proxies)
             self._proxies = []
-        for v in victims:
-            self._kill(v)
+        for v, name in victims:
+            self._kill(v, name, "shutdown")
         for name in list(self._rings) + list(self._router_wakes):
             self._teardown_dispatch(name)
 
@@ -205,21 +207,22 @@ class ServeController:
         with self._lock:
             entries, self._draining = self._draining, []
         keep: List[Tuple[Any, float]] = []
-        victims: List[Any] = []
+        victims: List[Tuple[Any, str]] = []
         now = time.monotonic()
         # One concurrent poll round, same shape as _poll_replicas.
         polls = [(r, deadline, r.get_metrics.remote())
                  for r, deadline in entries if now < deadline]
-        victims.extend(r for r, deadline in entries if now >= deadline)
+        victims.extend((r, "drain_deadline") for r, deadline in entries
+                       if now >= deadline)
         for r, deadline, ref in polls:
             try:
                 m = ray_tpu.get(ref, timeout=10)
                 if m["ongoing"] <= 0:
-                    victims.append(r)
+                    victims.append((r, "drained"))
                 else:
                     keep.append((r, deadline))
             except Exception:
-                victims.append(r)
+                victims.append((r, "health_check"))
         stranded: List[Any] = []
         with self._lock:
             self._draining = keep + self._draining
@@ -228,8 +231,8 @@ class ServeController:
                 # this again, so don't strand the survivors.
                 stranded = [r for r, _ in self._draining]
                 self._draining = []
-        for r in victims + stranded:
-            self._kill(r)
+        for r, reason in victims + [(r, "shutdown") for r in stranded]:
+            self._kill(r, "(draining)", reason)
 
     def reconcile_now(self) -> None:
         self._process_draining()
@@ -263,7 +266,7 @@ class ServeController:
                                 now - born > grace:
                             dead.append(r)
                 for r in dead:
-                    self._kill(r)
+                    self._kill(r, name, "health_check")
                 with self._lock:
                     self._replica_metrics.update(polled)
                     for r in dead:
@@ -409,11 +412,15 @@ class ServeController:
             deployments = len(self._deployments)
             draining = len(self._draining)
             rings = dict(self._rings)
+            kills = sorted(self._kills.items())
         out = "\n".join([
             "# TYPE serve_controller_deployments gauge",
             f"serve_controller_deployments {deployments}",
             "# TYPE serve_controller_draining_replicas gauge",
             f"serve_controller_draining_replicas {draining}",
+            "# TYPE serve_replica_kills_total counter",
+            *(f'serve_replica_kills_total{{reason="{reason}"}} {n}'
+              for reason, n in kills),
         ]) + "\n"
         # dispatch plane v2: native-ring counters join the same scrape
         for name, ring in rings.items():
@@ -469,7 +476,7 @@ class ServeController:
                     # replicas belong to nobody
                     orphans = started
             for r in orphans:
-                self._kill(r)
+                self._kill(r, name, "orphaned")
 
     def _autoscale(self, st: _DeploymentState,
                    total_ongoing: float) -> None:
@@ -498,8 +505,16 @@ class ServeController:
             st.upscale_pending_since = None
             st.downscale_pending_since = None
 
-    @staticmethod
-    def _kill(replica) -> None:
+    def _kill(self, replica, deployment: str, reason: str) -> None:
+        """Kill a replica (or a proxy) and say why: `deleted` with its
+        deployment, `shutdown`, `drained` (no request left), `drain_deadline`,
+        `health_check` (its actor is gone, or a replica past its start-up
+        did not answer `get_metrics` in time), `orphaned` (its deployment
+        went while it was starting). One log line and one count a kill."""
+        logger.warning("killing replica %r of deployment %s: %s",
+                       replica, deployment, reason)
+        with self._lock:
+            self._kills[reason] = self._kills.get(reason, 0) + 1
         try:
             ray_tpu.kill(replica)
         except Exception:

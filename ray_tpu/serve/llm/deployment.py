@@ -158,11 +158,13 @@ class LLMDeployment:
     def device_trace(self, seconds: float,
                      log_dir: Optional[str] = None) -> str:
         """Profile this replica's process for `seconds`
-        (`handle.device_trace.remote(4.0).result()`): only the process
-        that holds a chip can trace it. Returns the directory, on this
-        replica's node, under which `jax.profiler` wrote the
-        `.xplane.pb`; the engine's `rt/` phases are host events of the
-        same file, on the clock of the device's."""
+        (`handle.device_trace.remote(1.0).result()`; a second of a busy
+        device is what stopping the session affords, see
+        `tracing.device_trace`): only the process that holds a chip can
+        trace it. Returns the directory, on this replica's node, under
+        which `jax.profiler` wrote the `.xplane.pb`; the engine's `rt/`
+        phases are host events of the same file, on the clock of the
+        device's."""
         from ray_tpu.util import tracing
 
         with tracing.device_trace(log_dir) as path:

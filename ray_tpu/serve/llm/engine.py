@@ -35,6 +35,7 @@ replay both lean on.
 
 from __future__ import annotations
 
+import bisect
 import dataclasses
 import importlib
 import itertools
@@ -50,7 +51,6 @@ from ray_tpu.serve.llm.kv_cache import (OutOfPagesError, PagedKVCache,
                                         scatter_state)
 from ray_tpu.util import metrics as _metrics
 from ray_tpu.util import request_recorder as _rr
-from ray_tpu.util import step_profiler as _sp
 from ray_tpu.util import tracing as _tracing
 
 
@@ -187,18 +187,29 @@ def _kv_rows(cfg) -> Tuple[Tuple[int, int], ...]:
 # and stop() every nanosecond of the thread is one of these names' self
 # time. `pump_loop` and `engine_step` are the loop's and step()'s own
 # bookkeeping; `llm.prefill*` what lies between the phases of one request's
-# prefill (the spans a request's flow arrow ends on). Nothing enters
-# `prefill_kv_fetch` since the arena lives on the device, and
-# `prefill_kv_write` / `decode_kv_append` hold the host's share of a write
-# (the rows' arena coordinates, positions, the prefix cache); the names stay
-# because the benchmark's metric files read them.
+# prefill (the spans a request's flow arrow ends on). `prefill_kv_write` /
+# `decode_kv_append` hold the host's share of a write (the rows' arena
+# coordinates, positions, the prefix cache).
 PUMP_PHASES = (
     "pump_loop", "pump_idle", "intake", "lock_wait", "engine_step", "admit",
     "llm.prefill", "llm.prefill_chunk",
     "prefill_assemble", "prefill_dispatch", "prefill_device_wait",
-    "prefill_kv_fetch", "prefill_kv_write", "prefill_sample",
+    "prefill_kv_write", "prefill_sample",
     "decode_assemble", "decode_dispatch", "decode_device_wait",
     "decode_fetch", "decode_kv_append", "decode_sample", "finish")
+
+# Upper edges, in ms, of the histogram of a stream's gaps (the time from one
+# hand-over of tokens to a request to the next): 1 ms to 4 s in 38 steps of
+# x1.244, each bucket (lower, upper]; `stream_gap_le_<edge>` in
+# `engine.metrics()`, what is longer under `stream_gap_le_inf`.
+STREAM_GAP_EDGES_MS = tuple(
+    float(f"{4000.0 ** (i / 38):.4g}") for i in range(39))
+_GAP_EDGES_NS = tuple(round(e * 1e6) for e in STREAM_GAP_EDGES_MS)
+_GAP_KEYS = tuple(f"stream_gap_le_{e:g}" for e in STREAM_GAP_EDGES_MS) \
+    + ("stream_gap_le_inf",)
+# the counters of a request's stages, in the order of `Request.stages_ns`
+_STAGE_KEYS = ("req_queue_ms", "req_admission_ms", "req_prefill_span_ms",
+               "req_first_hold_ms")
 
 
 _req_counter = itertools.count(1)
@@ -228,19 +239,27 @@ class Request:
         self.done = threading.Event()
         self.error: Optional[str] = None
         self.finish_reason: Optional[str] = None
-        self.submit_ts = time.monotonic()
-        self.finish_ts: Optional[float] = None
-        # request-recorder plane: phase stamps (monotonic) + the
-        # propagated request context captured at submit() — the pump
+        # the propagated request context captured at submit(): the pump
         # thread can't see the submitter's contextvars, so the ctx must
         # ride the Request object
         self.ctx: Optional[dict] = None
         self.submit_wall = time.time()
-        self.first_consider_ts: Optional[float] = None
-        self.admit_ts: Optional[float] = None
-        self.prefill_ms = 0.0
-        self.first_token_ts: Optional[float] = None
-        self.last_token_ts: Optional[float] = None
+        # Stamps on the phase ledger's clock (`perf_counter_ns`), each
+        # taken once, by the pump, at a transition it makes anyway and,
+        # but for the first and the last, at the edge of an `rt/` phase:
+        # `considered_ns` the begin of the `admit` phase that first looked
+        # at the request, `admitted_ns` the end of the one that gave it
+        # pages, `logits_ready_ns` the end of its last unit's
+        # `prefill_device_wait`, `first_handed_ns` / `last_handed_ns` the
+        # first and the newest hand-over of its tokens to their reader.
+        self.submit_ns = time.perf_counter_ns()
+        self.considered_ns: Optional[int] = None
+        self.admitted_ns: Optional[int] = None
+        self.logits_ready_ns: Optional[int] = None
+        self.first_handed_ns: Optional[int] = None
+        self.last_handed_ns: Optional[int] = None
+        self.finish_ns: Optional[int] = None
+        self.prefill_ms = 0.0   # the ledger's time of its own prefill units
 
     def __repr__(self):
         return f"Request({self.id})"
@@ -267,19 +286,28 @@ class Request:
             else:
                 raise RequestRejected(rest[0])
 
-    # -- engine side -----------------------------------------------------
+    def stages_ns(self) -> Tuple[int, int, int, int]:
+        """(queue, admission, prefill_span, first_hold): submit ->
+        considered (waiting behind others) -> admitted (pages, lane, slot,
+        the prefix hash) -> logits ready (its own prefill units and the
+        decode passes between its chunks) -> first token handed over
+        (`prefix.insert`, the fetch, the argmax; a block family's first
+        passes). They tile `first_handed_ns - submit_ns` to the
+        nanosecond. A stamp that a request ended before reads as its end,
+        so the stages of one that never was handed a token tile
+        `finish_ns - submit_ns`."""
+        end = self.first_handed_ns or self.finish_ns \
+            or time.perf_counter_ns()
+        edges = [self.submit_ns]
+        edges += [end if stamp is None else stamp for stamp in (
+            self.considered_ns, self.admitted_ns, self.logits_ready_ns)]
+        edges.append(end)
+        return tuple(b - a for a, b in zip(edges, edges[1:]))
 
-    def _emit(self, token: int):
-        self._hand_over(self._record(token), token)
+    # -- engine side -----------------------------------------------------
 
     def _record(self, token: int) -> int:
         """Note the token as generated; returns its index."""
-        # per-token recorder cost: one monotonic read (TPOT = span
-        # between the first and last of these stamps)
-        now = time.monotonic()
-        if self.first_token_ts is None:
-            self.first_token_ts = now
-        self.last_token_ts = now
         self.tokens.append(token)
         return len(self.tokens) - 1
 
@@ -292,7 +320,7 @@ class Request:
 
     def _finish(self, reason: str):
         self.finish_reason = reason
-        self.finish_ts = time.monotonic()
+        self.finish_ns = time.perf_counter_ns()
         if self.sink is not None:
             self.sink("done", reason)
         else:
@@ -301,7 +329,7 @@ class Request:
 
     def _fail(self, msg: str):
         self.error = msg
-        self.finish_ts = time.monotonic()
+        self.finish_ns = time.perf_counter_ns()
         if self.sink is not None:
             self.sink("error", msg)
         else:
@@ -490,7 +518,21 @@ class LLMEngine:
             # the same for the chunks: a chunk's own tokens and the cached
             # positions before them (the keys its causal attention needs)
             "chunk_context_tokens": 0,
+            # A request's stages (`Request.stages_ns`), added when its
+            # first token is handed over, over `req_first_tokens`: their
+            # sum a request is what a freed lane waits for its successor.
+            "req_first_tokens": 0, **dict.fromkeys(_STAGE_KEYS, 0.0),
+            # A stream's gaps: every hand-over of one or more tokens to a
+            # request that was handed tokens before is one gap since the
+            # hand-over before (a block's tokens in one burst are one gap);
+            # their histogram is `_GAP_KEYS`, below. What the gaps were
+            # spent on beside the decode passes: the ledger time of a
+            # step's prefill unit, and of its `admit` phases, each times
+            # the sequences running meanwhile.
+            "stream_gaps": 0, "stream_gap_ms": 0.0,
+            "stall_prefill_lane_ms": 0.0, "stall_admit_lane_ms": 0.0,
         }
+        self.counters.update(dict.fromkeys(_GAP_KEYS, 0))
         # what the family's steps count on the device (`step_counts`),
         # fetched with the logits: decode steps and prefill units apart
         for name in self._step_counts:
@@ -772,18 +814,29 @@ class LLMEngine:
         with self._step_lock, phases.phase(
                 "engine_step",
                 step=self._step_no + 1 if self._waiting or self._prefilling
-                or self._running else None) as whole:
+                or self._running else None):
             # a stretch's wall time is what the ledger charged
             # meanwhile: its phases, and any lock_wait inside them
             prefill_ns = decode_ns = 0
             tokens_out = 0
             advanced = False
-            with phases.phase("admit"):
+            # what the running streams wait for before this step's decode
+            # pass: the admission and the prefill unit, a lane each
+            stall_admit = stall_prefill = 0
+            lanes = len(self._running)
+            with phases.phase("admit") as admit:
                 self._shed_expired()
+            stall_admit += admit.ns * lanes
             if not self._prefilling:
-                with phases.phase("admit"):
-                    self._admit_one()
+                with phases.phase("admit") as admit:
+                    seq = self._admit_one(admit.begin_ns)
+                if seq is not None:
+                    seq.req.admitted_ns = admit.end_ns
+                    if not seq.prefill_len:     # nothing to prefill
+                        seq.req.logits_ready_ns = admit.end_ns
+                stall_admit += admit.ns * lanes
             if self._prefilling:
+                lanes = len(self._running)
                 mark = phases.total_ns()
                 # ONE chunk (or one-shot bucket prefill) per step: a long
                 # prompt spreads across steps while decode below keeps
@@ -791,6 +844,7 @@ class LLMEngine:
                 tokens_out += self._advance_prefill()
                 advanced = True
                 prefill_ns += phases.total_ns() - mark
+                stall_prefill = prefill_ns * lanes
             if self._running:
                 mark = phases.total_ns()
                 tokens_out += self._decode_once()
@@ -806,12 +860,10 @@ class LLMEngine:
                     self.counters["prefill_ms"] += prefill_ms
                     self.counters["decode_ms"] += decode_ms
                     self.counters["tokens_generated"] += tokens_out
-                if _sp.enabled():
-                    _sp.record_step(
-                        self._step_no, whole.elapsed_ns() / 1e6,
-                        tokens=tokens_out, prefill_ms=prefill_ms,
-                        decode_ms=decode_ms,
-                        running=len(self._running))
+                    self.counters["stall_prefill_lane_ms"] += \
+                        stall_prefill / 1e6
+                    self.counters["stall_admit_lane_ms"] += \
+                        stall_admit / 1e6
             return did
 
     def _shed_expired(self):
@@ -831,13 +883,14 @@ class LLMEngine:
             req._fail("deadline passed before admission")
             self._emit_request_record(req, "timed_out")
 
-    def _admit_one(self) -> Optional[_Sequence]:
+    def _admit_one(self, now_ns: int) -> Optional[_Sequence]:
         """Pop the oldest waiting request whose worst-case page demand
         fits right now (pages reserved up front: a running sequence can
         never hit OutOfPages mid-decode). With the prefix cache on,
         admission aliases the longest cached full-page prefix into the
         new page table atomically with the remainder allocation — the
-        sequence then prefills only the uncached suffix."""
+        sequence then prefills only the uncached suffix. `now_ns` is the
+        begin of the caller's `admit` phase."""
         with self._lock:
             if not self._waiting or \
                     len(self._running) + len(self._prefilling) >= \
@@ -847,8 +900,8 @@ class LLMEngine:
             # queue phase ends at the FIRST admission consideration —
             # time spent retrying page reservation after this point is
             # admission wait, not queue wait
-            if req.first_consider_ts is None:
-                req.first_consider_ts = time.monotonic()
+            if req.considered_ns is None:
+                req.considered_ns = max(now_ns, req.submit_ns)
             need = self.kv.pages_for_tokens(
                 len(req.prompt) + req.max_new_tokens)
             cached = 0
@@ -863,7 +916,6 @@ class LLMEngine:
             # a slot a running sequence (`seq_slots=max_running`), and the
             # cap above has left room: one is free
             slot = self.kv.take_slot(req) if self.kv.state else None
-            req.admit_ts = time.monotonic()
             self._waiting.pop(0)
             seq = _Sequence(req, pages, pos=0, cached=cached, slot=slot,
                             prefill_len=self._prefill_len(req))
@@ -935,7 +987,8 @@ class LLMEngine:
         row = np.asarray(next_logits_row)
         self._count_link("prefill_link_bytes", row)
         tok = int(np.argmax(row))
-        seq.req._emit(tok)
+        self._hand_over(((seq.req, seq.req._record(tok), tok),),
+                        time.perf_counter_ns())
         if self._seq_finished(seq, tok):
             self._finish(seq)
         return 1
@@ -960,18 +1013,20 @@ class LLMEngine:
         with self._lock:
             self.counters[counter] += n
 
-    def _prefill_forward(self, fn, args):
+    def _prefill_forward(self, req: Request, fn, args):
         """What every prefill shares (one-shot, chunk): the call, which
-        leaves the rows' K and V in their pages, and the wait. `args` hold
-        the arena, donated: its successor goes back into `self.kv`.
-        Returns the logits, still on the device."""
+        leaves the rows' K and V in their pages, and the wait, whose end
+        is when the request's logits are ready (its last unit's stands).
+        `args` hold the arena, donated: its successor goes back into
+        `self.kv`. Returns the logits, still on the device."""
         phase = self._phases.phase
         with phase("prefill_dispatch"):
             self._count_link("prefill_link_bytes", *args)
             logits, counts = self._call(fn, args)
-        with phase("prefill_device_wait"):
+        with phase("prefill_device_wait") as wait:
             self._block_until_ready((logits, self.kv.arena, self.kv.state))
             self._add_step_counts("prefill", counts, "prefill_link_bytes")
+        req.logits_ready_ns = wait.end_ns
         return logits
 
     def _prefill_oneshot(self, seq: _Sequence) -> int:
@@ -988,7 +1043,7 @@ class LLMEngine:
             with phase("prefill_kv_write"):
                 w_page, w_off = self.kv.write_index(seq.pages, 0, s, bucket)
             next_logits = self._prefill_forward(
-                self._prefill_fns[bucket],
+                req, self._prefill_fns[bucket],
                 (self.params, toks, np.asarray([s], np.int32),
                  *self.kv.arena, *self.kv.state, w_page, w_off,
                  *self._slots_of((seq,), 1)))
@@ -1029,7 +1084,7 @@ class LLMEngine:
                 w_page, w_off = self.kv.write_index(
                     seq.pages, seq.prefilled, take, c)
             logits = self._prefill_forward(
-                self._chunk_fn,
+                req, self._chunk_fn,
                 (self.params, toks, np.asarray([seq.prefilled], np.int32),
                  *self.kv.arena, *self.kv.state, table,
                  w_page[None], w_off[None], *self._slots_of((seq,), 1)))
@@ -1231,10 +1286,40 @@ class LLMEngine:
         a request of it ends): the readers then run while the device
         does, not between a step's logits and the next step's call."""
         if self._held:
-            with self._phases.phase("decode_sample"):
+            with self._phases.phase("decode_sample") as sample:
                 held, self._held = self._held, []
-                for req, index, tok in held:
-                    req._hand_over(index, tok)
+                self._hand_over(held, sample.begin_ns)
+
+    def _hand_over(self, held, now: int):
+        """Pass recorded tokens, (request, index, token) each, on to their
+        readers, all at `now` on the ledger's clock: a request's first
+        hand-over closes its stages, any later one is a gap of its stream
+        since the one before, however many tokens it brings. The lanes a
+        pass handed over before share one instant, so the gaps are counted
+        by that instant and folded once a distinct one."""
+        firsts: List[Request] = []
+        since: Dict[int, int] = {}
+        for req, index, tok in held:
+            last = req.last_handed_ns
+            if last != now:
+                req.last_handed_ns = now
+                if last is None:
+                    firsts.append(req)
+                else:
+                    since[last] = since.get(last, 0) + 1
+            req._hand_over(index, tok)
+        with self._lock:
+            count = self.counters
+            for last, n in since.items():
+                count["stream_gaps"] += n
+                count["stream_gap_ms"] += n * (now - last) / 1e6
+                count[_GAP_KEYS[bisect.bisect_left(
+                    _GAP_EDGES_NS, now - last)]] += n
+            for req in firsts:
+                req.first_handed_ns = now
+                count["req_first_tokens"] += 1
+                for key, ns in zip(_STAGE_KEYS, req.stages_ns()):
+                    count[key] += ns / 1e6
 
     def _finish(self, seq: _Sequence):
         self._hand_over_held()
@@ -1257,31 +1342,33 @@ class LLMEngine:
 
     def _emit_request_record(self, req: Request, outcome: str):
         """Fold one finished request into the flight recorder: engine
-        role, authoritative phase split. Monotonic stamp geometry —
-        submit → first_consider (queue) → admit (admission) →
-        first_token (prefill) → last_token (decode) → finish — tiles the
-        end-to-end time, so the bench can assert phase-sum ≈ total."""
+        role, authoritative phase split, from the request's stamps. The
+        four stages (`Request.stages_ns`), the decode span (first to last
+        hand-over) and the finish (last hand-over to the end) tile the
+        end-to-end time; TTFT and TPOT are taken where a token is handed
+        to its reader; `prefill_ms` is the part of the prefill span that
+        was the request's own units."""
         if not _rr.enabled():
             return
-        end = req.finish_ts or time.monotonic()
-        first_consider = req.first_consider_ts or end
-        admit = req.admit_ts or first_consider
+        end = req.finish_ns or time.perf_counter_ns()
+        queue, admission, span, hold = (ns / 1e6 for ns in req.stages_ns())
+        first, last = req.first_handed_ns, req.last_handed_ns
         n = len(req.tokens)
-        ttft_ms = decode_ms = None
-        tpot_ms = None
-        if req.first_token_ts is not None:
-            ttft_ms = (req.first_token_ts - req.submit_ts) * 1e3
-            decode_ms = (req.last_token_ts - req.first_token_ts) * 1e3
+        ttft_ms = tpot_ms = None
+        decode_ms = 0.0
+        if first is not None:
+            ttft_ms = (first - req.submit_ns) / 1e6
+            decode_ms = (last - first) / 1e6
             if n > 1 and decode_ms > 0:
                 tpot_ms = decode_ms / (n - 1)
         _rr.record_engine(
             req.ctx,
             ts=req.submit_wall,
-            total_ms=(end - req.submit_ts) * 1e3,
-            queue_ms=(first_consider - req.submit_ts) * 1e3,
-            admission_ms=max(0.0, (admit - first_consider) * 1e3),
-            prefill_ms=req.prefill_ms,
-            decode_ms=decode_ms or 0.0,
+            total_ms=(end - req.submit_ns) / 1e6,
+            queue_ms=queue, admission_ms=admission,
+            prefill_span_ms=span, first_hold_ms=hold,
+            prefill_ms=req.prefill_ms, decode_ms=decode_ms,
+            finish_ms=(end - (last or end)) / 1e6,
             ttft_ms=ttft_ms, tpot_ms=tpot_ms,
             tokens_in=len(req.prompt), tokens_out=n,
             outcome=outcome, job=req.tenant,

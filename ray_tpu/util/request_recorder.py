@@ -16,8 +16,10 @@ gets a ``RequestRecord`` that follows it end to end:
   role carries what the caller observed (queue wait, TTFT, per-token
   TPOT over tokens the client actually waited on — failover replay
   chunks are marked, never timed), the *engine* role carries the
-  server-side phase split (queue-wait, admission-wait for KV page
-  reservation, prefill ms, decode span).
+  server-side phase split, from the stamps the engine's pump takes on
+  its phase ledger's clock (queue, admission, the prefill span, the hold
+  of the first token, the decode span, the finish: they tile the total;
+  TTFT and TPOT where a token is handed to its reader).
 
 Three export surfaces, mirroring the step profiler:
 
@@ -35,9 +37,9 @@ Three export surfaces, mirroring the step profiler:
   ``/api/timeseries`` read the histogram families through
   ``util/tsdb.py``.
 
-Recording never raises and never blocks the token path: per-token cost
-is two monotonic reads; the histogram fold happens once per request
-under a short module lock.
+Recording never raises and never blocks the token path: the engine reads
+one clock a hand-over of a pass's tokens, none a token; the histogram
+fold happens once per request under a short module lock.
 """
 
 from __future__ import annotations
@@ -104,7 +106,18 @@ def refresh() -> None:
 
 # -- the per-request record ----------------------------------------------
 
-PHASES = ("queue_ms", "admission_ms", "prefill_ms", "decode_ms")
+# what tiles a record's total, in order: waiting behind others, admission
+# (pages, lane, the prefix hash), admitted -> logits ready (the request's own
+# prefill units and the decode passes between its chunks), logits ready ->
+# first token handed over, first -> last hand-over, last hand-over -> end
+PHASES = ("queue_ms", "admission_ms", "prefill_span_ms", "first_hold_ms",
+          "decode_ms", "finish_ms")
+# shown beside them: `prefill_ms`, the part of the prefill span that was the
+# request's own units
+SHOWN = PHASES + ("prefill_ms",)
+# what `serve_request_phase_ms{phase=}` keeps a histogram of: those but the
+# finish (microseconds; a histogram is 15 series a phase, deployment and job)
+HISTOGRAMMED = tuple(ph for ph in SHOWN if ph != "finish_ms")
 
 OUTCOMES = ("ok", "timed_out", "failed", "failed_over")
 
@@ -119,8 +132,11 @@ class RequestRecord:
     total_ms: float = 0.0         # end-to-end as this role observed it
     queue_ms: float = 0.0         # waiting before any work started
     admission_ms: float = 0.0     # KV page reservation wait (engine)
-    prefill_ms: float = 0.0
-    decode_ms: float = 0.0        # first-token -> last-token span
+    prefill_span_ms: float = 0.0  # admitted -> logits ready
+    first_hold_ms: float = 0.0    # logits ready -> first token handed over
+    decode_ms: float = 0.0        # first -> last hand-over of tokens
+    finish_ms: float = 0.0        # last hand-over -> end
+    prefill_ms: float = 0.0       # the request's own prefill units
     ttft_ms: Optional[float] = None
     tpot_ms: Optional[float] = None   # per-token decode latency
     tokens_in: int = 0
@@ -130,8 +146,7 @@ class RequestRecord:
     attrs: Dict[str, Any] = field(default_factory=dict)
 
     def phase_sum_ms(self) -> float:
-        return (self.queue_ms + self.admission_ms + self.prefill_ms
-                + self.decode_ms)
+        return sum(getattr(self, ph) for ph in PHASES)
 
     def as_dict(self) -> Dict[str, Any]:
         d: Dict[str, Any] = {
@@ -141,7 +156,7 @@ class RequestRecord:
             "tokens_in": self.tokens_in, "tokens_out": self.tokens_out,
             "outcome": self.outcome,
         }
-        for ph in PHASES:
+        for ph in SHOWN:
             d[ph] = round(getattr(self, ph), 3)
         if self.ttft_ms is not None:
             d["ttft_ms"] = round(self.ttft_ms, 3)
@@ -299,7 +314,7 @@ def _fold_record(rec: RequestRecord) -> None:
     # a bare-engine run (bench) still fills every family.
     base = (rec.deployment, rec.job)
     if rec.role == "engine":
-        for ph in PHASES:
+        for ph in HISTOGRAMMED:
             _fold("serve_request_phase_ms",
                   (ph[:-3],) + base, getattr(rec, ph))
     if rec.ttft_ms is not None:
@@ -348,7 +363,9 @@ def record_client(ctx: dict, *, ts: float, total_ms: float,
 
 def record_engine(ctx: Optional[dict], *, ts: float, total_ms: float,
                   queue_ms: float = 0.0, admission_ms: float = 0.0,
-                  prefill_ms: float = 0.0, decode_ms: float = 0.0,
+                  prefill_span_ms: float = 0.0, first_hold_ms: float = 0.0,
+                  decode_ms: float = 0.0, finish_ms: float = 0.0,
+                  prefill_ms: float = 0.0,
                   ttft_ms: Optional[float] = None,
                   tpot_ms: Optional[float] = None,
                   tokens_in: int = 0, tokens_out: int = 0,
@@ -373,7 +390,9 @@ def record_engine(ctx: Optional[dict], *, ts: float, total_ms: float,
         deployment=ctx.get("deployment", "engine"),
         job=ctx.get("job", "none"), ts=ts, total_ms=total_ms,
         queue_ms=queue_ms, admission_ms=admission_ms,
-        prefill_ms=prefill_ms, decode_ms=decode_ms, ttft_ms=ttft_ms,
+        prefill_span_ms=prefill_span_ms, first_hold_ms=first_hold_ms,
+        decode_ms=decode_ms, finish_ms=finish_ms, prefill_ms=prefill_ms,
+        ttft_ms=ttft_ms,
         tpot_ms=tpot_ms, tokens_in=tokens_in, tokens_out=tokens_out,
         outcome=outcome, attrs=attrs))
 
@@ -431,10 +450,12 @@ def metrics_text() -> str:
                 lines.append(
                     f'serve_request_outcomes_total{{outcome="{outcome}"}}'
                     f" {n}")
-        _render_hist("serve_request_phase_ms",
-                     ("phase", "deployment", "job"), lines)
+        # TTFT and TPOT first: a reader that caps its series (`util/tsdb`)
+        # keeps what comes first, and the phases are the larger family
         _render_hist("serve_ttft_ms", ("deployment", "job"), lines)
         _render_hist("serve_tpot_ms", ("deployment", "job"), lines)
+        _render_hist("serve_request_phase_ms",
+                     ("phase", "deployment", "job"), lines)
     return "\n".join(lines) + "\n"
 
 
@@ -514,7 +535,7 @@ def merge_by_request(records: List[Dict[str, Any]]
         role = r.get("role", "engine")
         m[role] = r
         if role == "engine":
-            for ph in PHASES:
+            for ph in SHOWN:
                 m[ph] = r.get(ph, 0.0)
             m.setdefault("deployment", r.get("deployment", ""))
             m.setdefault("job", r.get("job", "none"))
@@ -556,7 +577,7 @@ def to_chrome(records: List[Dict[str, Any]]) -> List[dict]:
         args = {k: r[k] for k in
                 ("req_id", "outcome", "tokens_out", "ttft_ms",
                  "tpot_ms") if r.get(k) is not None}
-        for ph in PHASES:
+        for ph in SHOWN:
             if r.get(ph):
                 args[ph] = r[ph]
         events.append({
@@ -619,8 +640,10 @@ def format_table(records: List[Dict[str, Any]], last: int = 20) -> str:
     if not recs:
         return ("no request records (serve traffic with the request "
                 "recorder enabled?)")
+    # `own`: of the prefill span, the request's own units (`prefill_ms`)
     header = (f"{'req_id':>16} {'deploy':>10} {'job':>8} "
-              f"{'total':>8} {'queue':>7} {'admit':>7} {'prefill':>8} "
+              f"{'total':>8} {'queue':>7} {'admit':>7} {'span':>8} "
+              f"{'own':>8} {'hold':>7} "
               f"{'decode':>8} {'ttft':>7} {'tpot':>6} {'tok':>5} "
               f"{'outcome':>11}")
     rows = [header, "-" * len(header)]
@@ -634,7 +657,9 @@ def format_table(records: List[Dict[str, Any]], last: int = 20) -> str:
             f"{r.get('total_ms', 0.0):>8.2f} "
             f"{r.get('queue_ms', 0.0):>7.2f} "
             f"{r.get('admission_ms', 0.0):>7.2f} "
+            f"{r.get('prefill_span_ms', 0.0):>8.2f} "
             f"{r.get('prefill_ms', 0.0):>8.2f} "
+            f"{r.get('first_hold_ms', 0.0):>7.2f} "
             f"{r.get('decode_ms', 0.0):>8.2f} "
             f"{'-' if ttft is None else f'{ttft:.1f}':>7} "
             f"{'-' if tpot is None else f'{tpot:.2f}':>6} "

@@ -81,7 +81,9 @@ def test_serving_region_carries_context_to_engine_role():
         assert rr.current() is ctx
         rec = rr.record_engine(rr.current(), ts=1.0, total_ms=10.0,
                                queue_ms=1.0, admission_ms=2.0,
-                               prefill_ms=3.0, decode_ms=4.0,
+                               prefill_span_ms=2.5, first_hold_ms=0.5,
+                               prefill_ms=2.0, decode_ms=3.5,
+                               finish_ms=0.5,
                                ttft_ms=6.0, tpot_ms=1.0,
                                tokens_in=8, tokens_out=5)
     assert rr.current() is None
@@ -114,7 +116,8 @@ def test_merge_by_request_joins_client_and_engine_rows():
 def test_summary_and_slowest():
     for i in range(10):
         rr.record_engine(None, ts=float(i), total_ms=10.0 * (i + 1),
-                         prefill_ms=6.0 * (i + 1),
+                         prefill_span_ms=6.0 * (i + 1),
+                         prefill_ms=5.0 * (i + 1),
                          decode_ms=4.0 * (i + 1), ttft_ms=7.0,
                          tpot_ms=1.5)
     s = rr.summary()
@@ -160,6 +163,80 @@ def test_engine_phase_sum_matches_e2e():
         assert rec.tpot_ms is not None  # 4 tokens -> 3 decode gaps
         ratio = rec.phase_sum_ms() / rec.total_ms
         assert 0.95 <= ratio <= 1.05, rec.as_dict()
+        # built from one clock's stamps, the phases tile the total whole
+        assert rec.phase_sum_ms() == pytest.approx(rec.total_ms, abs=1e-6)
+        assert rec.tpot_ms == pytest.approx(rec.decode_ms / 3)
+        # the request's own units: the span and the hold but for the
+        # other streams' passes between its chunks (none here) and the
+        # unit's tail past the hand-over
+        assert 0 < rec.prefill_ms <= rec.total_ms
+        assert rec.ttft_ms == pytest.approx(
+            rec.queue_ms + rec.admission_ms + rec.prefill_span_ms
+            + rec.first_hold_ms, abs=1e-6)
+    # TTFT is taken where the first token is handed to its reader
+    assert sorted(rec.ttft_ms for rec in recs) == pytest.approx(sorted(
+        (r.first_handed_ns - r.submit_ns) / 1e6 for r in reqs))
+
+
+def test_a_shed_requests_record_tiles_too():
+    """A request that ends before its first token: its record's phases
+    still tile its total (the stamps it never took read as its end), with
+    no TTFT and no decode span."""
+    from ray_tpu.serve.llm import EngineConfig, LLMEngine
+
+    eng = LLMEngine(model="llama",
+                    engine_config=EngineConfig(batch_buckets=(1,),
+                                               prefill_buckets=(8,)),
+                    seed=0)
+    try:
+        req = eng.submit([4, 4], 4, timeout_s=0.001)
+        time.sleep(0.02)
+        eng.run_until_idle()
+        assert req.error and req.first_handed_ns is None
+    finally:
+        assert eng.shutdown() == 0
+    (rec,) = [r for r in rr.ring().recent() if r.role == "engine"]
+    assert rec.outcome == "timed_out" and rec.ttft_ms is None
+    assert rec.total_ms == pytest.approx(
+        (req.finish_ns - req.submit_ns) / 1e6)
+    assert rec.queue_ms == pytest.approx(rec.total_ms)
+    assert rec.phase_sum_ms() == pytest.approx(rec.total_ms, abs=1e-6)
+    assert rec.decode_ms == rec.prefill_ms == rec.finish_ms == 0.0
+
+
+def test_the_records_surfaces_show_the_span_the_hold_and_the_own_prefill():
+    ctx = rr.new_context("chat", job="tenant-a")
+    rec = rr.record_engine(ctx, ts=0.0, total_ms=20.0, queue_ms=1.0,
+                           admission_ms=2.0, prefill_span_ms=8.0,
+                           first_hold_ms=3.0, prefill_ms=6.0,
+                           decode_ms=5.5, finish_ms=0.5, ttft_ms=14.0,
+                           tpot_ms=1.8, tokens_out=4)
+    assert rec.phase_sum_ms() == pytest.approx(20.0)
+    d = rec.as_dict()
+    assert (d["prefill_span_ms"], d["first_hold_ms"], d["prefill_ms"],
+            d["finish_ms"]) == (8.0, 3.0, 6.0, 0.5)
+    text = metrics_mod.DEFAULT_REGISTRY.prometheus_text()
+    for phase, le in (("prefill_span", "10.0"), ("first_hold", "5.0"),
+                      ("prefill", "10.0")):
+        assert (f'serve_request_phase_ms_bucket{{phase="{phase}",'
+                f'deployment="chat",job="tenant-a",le="{le}"}} 1') in text
+    assert 'phase="finish"' not in text     # microseconds: no histogram
+    # TTFT and TPOT come before the larger family: a capped reader keeps
+    # what comes first
+    assert text.index("serve_ttft_ms_bucket") \
+        < text.index("serve_request_phase_ms_bucket")
+    table = rr.format_table([d])
+    header = table.splitlines()[0].split()
+    assert header[3:10] == ["total", "queue", "admit", "span", "own",
+                            "hold", "decode"]
+    assert table.splitlines()[2].split()[3:10] == \
+        ["20.00", "1.00", "2.00", "8.00", "6.00", "3.00", "5.50"]
+    # the attribution is over what tiles the total: the own prefill, a
+    # part of the span, is not counted a second time
+    assert sum(rr.summary([d])["attribution"].values()) == \
+        pytest.approx(1.0)
+    merged = rr.merge_by_request([d])[0]
+    assert merged["prefill_span_ms"] == 8.0 and merged["prefill_ms"] == 6.0
 
 
 # ---------------------------------------------------------------------------

@@ -529,3 +529,43 @@ def test_asgi_lifespan_and_blocking_receive():
             as r:
         assert r.headers["x-ready"] == "yes"  # lifespan startup ran
         assert r.read() == b"s0;s1;s2;"  # stream survived the listener
+
+
+def test_controller_says_why_it_kills_a_replica(monkeypatch, caplog):
+    """A replica that fails its health check is killed with one log line
+    (the replica, its deployment, the reason) and counted once under
+    `serve_replica_kills_total{reason=}`; no cluster: the poll's verdict
+    and the kill itself are stood in for."""
+    import logging
+
+    from ray_tpu.serve import controller as ctl
+
+    ctrl = ctl.ServeController()
+    st = ctl._DeploymentState("chat", object, (), {},
+                              ctl.DeploymentConfig(), None)
+    sick, well = object(), object()
+    st.replicas = [sick, well]
+    ctrl._deployments["chat"] = st
+    killed = []
+    monkeypatch.setattr(ctl.ray_tpu, "kill", killed.append)
+    monkeypatch.setattr(
+        ctrl, "_poll_replicas",
+        lambda replicas: ([well], [sick], [], 0.0, {id(well): {}}))
+    monkeypatch.setattr(ctrl, "_scale_to_target", lambda name, st: None)
+    monkeypatch.setattr(ctrl, "_sync_dispatch", lambda name, st: None)
+    with caplog.at_level(logging.WARNING, logger=ctl.__name__):
+        ctrl.reconcile_now()
+    assert killed == [sick] and st.replicas == [well]
+    lines = [r.getMessage() for r in caplog.records
+             if "killing replica" in r.getMessage()]
+    assert len(lines) == 1
+    assert repr(sick) in lines[0] and "chat" in lines[0] \
+        and lines[0].endswith("health_check")
+    text = ctrl._metrics_text()
+    assert "# TYPE serve_replica_kills_total counter" in text
+    assert 'serve_replica_kills_total{reason="health_check"} 1' in text
+    # every other path names its reason too
+    ctrl.delete_deployment("chat")
+    assert killed == [sick, well]
+    assert 'serve_replica_kills_total{reason="deleted"} 1' \
+        in ctrl._metrics_text()

@@ -389,6 +389,160 @@ def test_a_decode_pass_hands_its_tokens_over_after_the_next_call():
         assert eng.shutdown() == 0
 
 
+# ---------------------------------------------------------------------------
+# a request's stages and a stream's gaps, on the phase ledger's clock
+# ---------------------------------------------------------------------------
+
+STAGES = ("queue", "admission", "prefill_span", "first_hold")
+
+
+def _assert_stages_tile(reqs):
+    """Each request's four stages are >= 0 and tile submit -> its first
+    hand-over (-> its end, where it never was handed a token) to the
+    nanosecond; returns their sums in ms over the requests that were handed
+    a first token, as the `req_*` counters keep them."""
+    sums = dict.fromkeys(STAGES, 0.0)
+    for req in reqs:
+        assert req.done.is_set() and req.finish_ns is not None
+        stages = req.stages_ns()
+        assert all(ns >= 0 for ns in stages), (req, stages)
+        end = req.first_handed_ns or req.finish_ns
+        assert sum(stages) == end - req.submit_ns, (req, stages)
+        if req.first_handed_ns is None:
+            assert not req.tokens and req.last_handed_ns is None
+            continue
+        stamps = [req.submit_ns, req.considered_ns, req.admitted_ns,
+                  req.logits_ready_ns, req.first_handed_ns,
+                  req.last_handed_ns, req.finish_ns]
+        assert stamps == sorted(stamps), (req, stamps)
+        for name, ns in zip(STAGES, stages):
+            sums[name] += ns / 1e6
+    return sums
+
+
+def _assert_gap_histogram(delta):
+    """The histogram's counts are the gaps, and its edges bound their sum."""
+    from ray_tpu.serve.llm.engine import _GAP_KEYS, STREAM_GAP_EDGES_MS
+
+    counts = [delta[k] for k in _GAP_KEYS]
+    assert sum(counts) == delta["stream_gaps"]
+    lower = (0.0,) + STREAM_GAP_EDGES_MS
+    assert sum(n * e for n, e in zip(counts, lower)) \
+        <= delta["stream_gap_ms"] + 1e-6
+    if not counts[-1]:
+        assert delta["stream_gap_ms"] <= 1e-6 + sum(
+            n * e for n, e in zip(counts, STREAM_GAP_EDGES_MS))
+
+
+@pytest.mark.parametrize("family", ["tokens", "blocks"])
+def test_a_requests_stages_tile_its_way_to_the_first_token(family):
+    """Every finished request, whatever became of it: one that streams, one
+    of a single token, one shed at its deadline before admission, one the
+    shutdown fails while it waits and, for the block family, a prompt
+    shorter than a block (nothing to prefill). The `req_*` counters are the
+    sums over those that were handed a first token; the gaps' histogram
+    holds every later hand-over."""
+    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
+
+    if family == "tokens":
+        eng = _small_engine(batch_buckets=(2,), prefill_chunk=8)
+        jobs = [([5, 9, 3], 6), ([7, 2], 1), (list(range(3, 16)), 5),
+                ([4, 4, 8], 3)]
+    else:
+        eng = LLMEngine(model="sdar_moe", seed=0, engine_config=EngineConfig(
+            batch_buckets=(1, 2), prefill_buckets=(8, 16), prefill_chunk=8,
+            block_size=8, num_pages=32, prefix_cache=0))
+        eng.warmup()
+        # 3 < a block of 4: admitted straight into the running set
+        jobs = [(list(range(5, 18)), 9), ([5, 6, 7], 6), ([9] * 6, 5)]
+    leaked = None
+    try:
+        before = eng.metrics()
+        reqs = [eng.submit(p, n) for p, n in jobs]
+        shed = eng.submit([4, 4], 4, timeout_s=0.001)
+        time.sleep(0.01)
+        eng.run_until_idle()
+        eng.quiesce()
+        delta = _delta(eng.metrics(), before)
+        left = eng.submit([6, 6, 6], 2)     # never stepped: fails below
+        leaked = eng.shutdown()
+        assert shed.error and left.error and not left.tokens
+        assert [len(r.tokens) for r in reqs] == [n for _, n in jobs]
+        sums = _assert_stages_tile(reqs + [shed, left])
+        assert shed.considered_ns is None       # shed before admission
+        assert delta["req_first_tokens"] == len(reqs)
+        for name in STAGES:
+            assert delta[f"req_{name}_ms"] == pytest.approx(
+                sums[name], rel=1e-9, abs=1e-9)
+        _assert_gap_histogram(delta)
+        bursts = delta["stream_gaps"] + delta["req_first_tokens"]
+        if family == "tokens":
+            # a token a hand-over: every token after a request's first
+            assert delta["stream_gaps"] == \
+                delta["tokens_generated"] - len(reqs)
+            # a prefill's logits wait for nothing but the fetch and argmax
+            assert all(r.logits_ready_ns < r.first_handed_ns for r in reqs)
+        else:
+            # four tokens reach their reader in at most two bursts, and a
+            # burst is one gap however many tokens it brings
+            blocks = sum(-(-n // 4) for _, n in jobs)
+            assert blocks <= bursts <= 2 * blocks
+            assert bursts < delta["tokens_generated"]
+            short = reqs[1]                     # nothing to prefill
+            assert short.logits_ready_ns == short.admitted_ns
+    finally:
+        assert (eng.shutdown() if leaked is None else leaked) == 0
+
+
+def test_a_pass_is_handed_over_at_one_instant_and_stalls_are_counted():
+    """Two streams run while a third request's prompt goes through in
+    chunks: the lanes of a pass share one hand-over stamp (one clock read
+    a hand-over, none a token), each step's prefill unit is charged to
+    `stall_prefill_lane_ms` once a running lane and its admission to
+    `stall_admit_lane_ms` the same way."""
+    eng = _small_engine(prefill_chunk=8)
+    try:
+        a, b = eng.submit([5, 9, 3], 12), eng.submit([7, 2], 12)
+        eng.step()
+        eng.step()                      # both prefilled, two lanes running
+        eng.step()
+        assert len(eng._running) == 2
+        assert a.last_handed_ns == b.last_handed_ns > a.first_handed_ns
+        before = eng.metrics()
+        assert before["stall_prefill_lane_ms"] > 0      # b's prefill, 1 lane
+        long = eng.submit(list(range(3, 23)), 4)        # 20 tokens: 3 chunks
+        eng.step()                      # admits it, one chunk, a pass
+        d = _delta(eng.metrics(), before)
+        assert d["chunk_steps"] == 1 and d["prefill_steps"] == 0
+        assert d["stall_prefill_lane_ms"] == pytest.approx(
+            2 * d["prefill_ms"], rel=1e-9)
+        assert d["stall_admit_lane_ms"] == pytest.approx(
+            2 * d["ph_admit_ms"], rel=1e-6)
+        assert d["stall_admit_lane_ms"] >= \
+            2 * (long.admitted_ns - long.considered_ns) / 1e6
+        assert d["stream_gaps"] == 2                    # a pass, two lanes
+        # the gap of that step holds the chunk and the admission
+        assert d["stream_gap_ms"] >= d["stall_prefill_lane_ms"] \
+            + d["stall_admit_lane_ms"]
+        eng.step()
+        two_chunks = long.prefill_ms
+        eng.step()      # the last chunk: its first token, then a pass
+        assert long.logits_ready_ns is not None and len(long.tokens) == 2
+        # the pass's token is recorded and held: not handed over yet
+        assert long.last_handed_ns == long.first_handed_ns
+        # between its chunks the other streams' passes ran: the span holds
+        # more than the request's own units up to the last one's logits,
+        # and span and hold together all of them
+        _, _, span, hold = (ns / 1e6 for ns in long.stages_ns())
+        assert span > two_chunks > 0
+        assert span + hold > long.prefill_ms > two_chunks
+        eng.run_until_idle()
+        _assert_stages_tile([a, b, long])
+        eng.quiesce()
+    finally:
+        assert eng.shutdown() == 0
+
+
 @pytest.mark.parametrize("mode, n", [("oneshot", 5), ("chunk", 13)])
 def test_prefill_rows_past_the_true_length_write_nothing(mode, n):
     """A prompt shorter than its bucket, and a last chunk shorter than
@@ -736,9 +890,10 @@ def test_pump_ledger_sums_to_the_pump_wall_time(ledger_engine):
                 "ph_prefill_kv_write_ms",
                 "ph_pump_idle_ms", "ph_admit_ms", "ph_finish_ms"):
         assert d[key] > 0, key
-    # K and V stay on the device: nothing is fetched, and what the host
-    # still does for a write (the rows' coordinates, positions) is small
-    assert d["ph_prefill_kv_fetch_ms"] == 0
+    # K and V stay on the device: the phase that fetched them is gone, and
+    # what the host still does for a write (the rows' coordinates,
+    # positions) is small
+    assert "ph_prefill_kv_fetch_ms" not in after
     assert d["ph_prefill_kv_write_ms"] <= 0.02 * d["prefill_ms"]
     assert d["ph_decode_kv_append_ms"] <= 0.02 * d["decode_ms"]
     # metrics() times itself: calls that had finished when it was read

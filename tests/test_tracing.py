@@ -121,6 +121,14 @@ def test_nested_phases_self_times_sum_to_the_outer_duration():
     assert 10_000_000 <= ns["outer"] < outer.ns - inner.ns
     assert table.count("inner") == 2 and table.count("outer") == 1
     assert table.ms("leaf") == ns["leaf"] / 1e6
+    # a phase's edges are stamps on the ledger's clock, at no read of
+    # their own: whoever owns the phase marks a transition with them
+    for ph in (outer, inner, leaf):
+        assert ph.end_ns - ph.begin_ns == ph.ns
+    assert outer.begin_ns < inner.begin_ns < leaf.begin_ns \
+        < leaf.end_ns <= inner.end_ns < outer.end_ns
+    with table.phase("outer") as still_open:
+        assert still_open.end_ns == still_open.begin_ns
     assert not table.in_phase()
     table.clear()
     assert sum(table.snapshot_ns().values()) == 0
