@@ -1603,6 +1603,7 @@ class LLMEngine:
                 prefix_cache_misses=ps["misses"],
                 prefix_cache_entries=ps["entries"],
                 prefix_cache_evicted=ps["evicted"],
+                prefix_cache_key_tokens=ps["key_tokens"],
             )
         for name, ns in self._phases.snapshot_ns().items():
             out[f"ph_{name.replace('.', '_')}_ms"] = ns / 1e6

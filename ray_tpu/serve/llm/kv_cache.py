@@ -444,53 +444,95 @@ class PagedKVCache:
 
 
 class _PrefixEntry:
-    """One cached full-page-aligned prompt prefix: the holder token for
-    its pages' prefix-cache refs."""
+    """One cached full page of one prompt prefix, and the holder token of
+    the cache's one hold on it. `key` is (the node of the page before it,
+    None at a prompt's first page; this page's own token ids)."""
 
-    __slots__ = ("key", "pages", "hits")
+    __slots__ = ("key", "page", "children")
 
-    def __init__(self, key: Tuple[int, ...], pages: List[int]):
+    def __init__(self, key: Tuple[Optional["_PrefixEntry"], Tuple[int, ...]],
+                 page: int):
         self.key = key
-        self.pages = pages
-        self.hits = 0
+        self.page = page
+        self.children = 0
 
     def __repr__(self):
-        return f"PrefixEntry({len(self.pages)}p, hits={self.hits})"
+        return f"PrefixEntry(page {self.page}, {self.children} children)"
 
 
 class PrefixCache:
     """Copy-on-write shared-prefix page cache over a `PagedKVCache`.
 
-    Maps full-page-aligned prompt prefixes (keyed by the exact token
-    tuple — no hash collisions) to the page ids that hold their K/V.
-    Admission (`acquire`) aliases the longest matching cached prefix
-    into the new sequence's page table (incref, zero bytes copied) and
-    allocates only the pages the uncached suffix needs, so prefill
-    runs only past the cached boundary. The last prompt token is never
-    aliased (the engine needs its forward pass for next-token logits),
-    and a partial page is never cached (its tail is still appended to).
+    One node a cached page, chained to the node of the page before it: a
+    full-page-aligned prompt prefix is a path from the root, and its node
+    is keyed by (the parent node itself, the page's own `block_size` token
+    ids). Keys are exact (the ids are compared, a parent by identity: no
+    hash collisions), and a walk copies and hashes each token of a prompt
+    once (`counters["key_tokens"]`), a miss at the first page one page's.
+    A page's K/V depend on the token prefix alone, so a chain may hold
+    pages that different sequences filled: of two requests with one prompt
+    that both missed, the second to insert finds the first's nodes and
+    adds only what is missing. A page has ONE cache holder, its node.
+
+    Admission (`acquire`) aliases the longest cached path into the new
+    sequence's page table (incref, zero bytes copied) and allocates only
+    the pages the uncached suffix needs, so prefill runs only past the
+    cached boundary. The last prompt token is never aliased (the engine
+    needs its forward pass for next-token logits), and a partial page is
+    never cached (its tail is still appended to).
 
     The lookup, the alias (incref), and the remainder allocation happen
-    under ONE lock hold — check-then-alias across a lock release would
+    under ONE lock hold: check-then-alias across a lock release would
     race eviction (the raylint-pinned TOCTOU; see the fixture pair in
-    tests/test_raylint.py). Eviction is LRU and only triggered by arena
-    pressure: `PagedKVCache._alloc_locked` calls back into
-    `_evict_for_locked` on shortfall, releasing cold entries until the
-    allocation fits — pages another sequence still holds survive their
-    entry's eviction (refcounts, not force-frees).
+    tests/test_raylint.py). Eviction is LRU, leaves only, and only
+    triggered by arena pressure: `PagedKVCache._alloc_locked` calls back
+    into `_evict_for_locked` on shortfall, which releases the oldest node
+    until the allocation fits (a page another holder still has survives
+    its node: refcounts, not force-frees). `_path_locked` keeps the
+    invariant that makes the oldest node a leaf: whatever touches a path
+    (a hit, an insert) moves it to the young end leaf first and root last,
+    so no child is ever younger than its parent, and a prompt falling out
+    of use loses its tail before its head.
     """
 
     def __init__(self, kv: PagedKVCache):
         self.kv = kv
         # ONE lock with the allocator: atomic lookup+alias+alloc
         self._lock = kv._lock
-        self._entries: "OrderedDict[Tuple[int, ...], _PrefixEntry]" = \
-            OrderedDict()
+        self._entries: "OrderedDict[tuple, _PrefixEntry]" = OrderedDict()
         self.counters: Dict[str, int] = {
             "hits": 0, "misses": 0, "hit_tokens": 0, "miss_tokens": 0,
-            "inserted": 0, "evicted": 0,
+            "inserted": 0, "evicted": 0, "key_tokens": 0,
         }
         kv._prefix_cache = self
+
+    def _path_locked(self, prompt: List[int], n: int,
+                     pages: Optional[List[int]] = None
+                     ) -> List[_PrefixEntry]:
+        """The nodes of `prompt`'s first `n` pages, root first, as far as
+        they are cached; given the sequence's `pages`, the missing ones are
+        made. The path is touched leaf first (caller holds the kv lock)."""
+        block = self.kv.block_size
+        path: List[_PrefixEntry] = []
+        parent = None
+        for i in range(n):
+            key = (parent, tuple(prompt[i * block:(i + 1) * block]))
+            self.counters["key_tokens"] += block
+            node = self._entries.get(key)
+            if node is None:
+                if pages is None:
+                    break
+                node = _PrefixEntry(key, pages[i])
+                self.kv._share_locked([node.page], node)
+                self._entries[key] = node
+                if parent is not None:
+                    parent.children += 1
+                self.counters["inserted"] += 1
+            path.append(node)
+            parent = node
+        for node in reversed(path):
+            self._entries.move_to_end(node.key)
+        return path
 
     # -- admission --------------------------------------------------------
 
@@ -499,85 +541,64 @@ class PrefixCache:
         """Atomically: find the longest cached full-page prefix of
         `prompt`, alias its pages to `owner`, and allocate the
         remaining `total_pages - cached` fresh pages (evicting cold
-        entries on shortfall). Returns (page list, cached token count).
+        nodes on shortfall). Returns (page list, cached token count).
         Raises OutOfPagesError leaving no partial state."""
         block = self.kv.block_size
         with self._lock:
             # never alias the page holding the last prompt token: at
-            # least one suffix token must run prefill for next-logits
-            kmax = (len(prompt) - 1) // block
-            entry = None
-            k = 0
-            for kk in range(kmax, 0, -1):
-                e = self._entries.get(tuple(prompt[:kk * block]))
-                if e is not None:
-                    entry, k = e, kk
-                    break
-            cached = list(entry.pages) if entry is not None else []
+            # least one suffix token must run prefill for next-logits.
+            # The hit is touched before room is made for the remainder:
+            # eviction reaches it last, and frees nothing by it (`owner`
+            # holds its pages).
+            path = self._path_locked(prompt, (len(prompt) - 1) // block)
+            cached = [node.page for node in path]
             # alias under the SAME hold as the lookup: a release here
-            # would let eviction free the entry before the incref lands
+            # would let eviction free the pages before the incref lands
             self.kv._share_locked(cached, owner)
             try:
-                fresh = self.kv._alloc_locked(total_pages - k, owner)
+                fresh = self.kv._alloc_locked(total_pages - len(path), owner)
             except OutOfPagesError:
                 self.kv._free_locked(cached, owner)
                 raise
-            if entry is not None:
-                entry.hits += 1
-                # making room for the remainder may have evicted the very
-                # entry that was hit (its pages live on under `owner`)
-                if entry.key in self._entries:
-                    self._entries.move_to_end(entry.key)
-                self.counters["hits"] += 1
-                self.counters["hit_tokens"] += k * block
-            else:
-                self.counters["misses"] += 1
-            self.counters["miss_tokens"] += len(prompt) - k * block
-            return cached + fresh, k * block
+            self.counters["hits" if path else "misses"] += 1
+            self.counters["hit_tokens"] += len(path) * block
+            self.counters["miss_tokens"] += len(prompt) - len(path) * block
+            return cached + fresh, len(path) * block
 
     def insert(self, prompt: List[int], pages: List[int]) -> None:
-        """Register every full-page-aligned prefix of a just-prefilled
-        prompt (each becomes independently hittable/evictable). Only
+        """Register every full page of a just-prefilled prompt that is not
+        cached yet (each full-page-aligned prefix becomes hittable). Only
         FULL pages are cached — they are immutable from here on (decode
         appends land in later pages), which is the whole copy-on-write
         guarantee."""
-        block = self.kv.block_size
         with self._lock:
-            if self.kv._closed:
-                return
-            kfull = len(prompt) // block
-            for kk in range(1, kfull + 1):
-                key = tuple(prompt[:kk * block])
-                if key in self._entries:
-                    continue
-                e = _PrefixEntry(key, list(pages[:kk]))
-                self.kv._share_locked(e.pages, e)
-                self._entries[key] = e
-                self.counters["inserted"] += 1
+            if not self.kv._closed:
+                self._path_locked(prompt, len(prompt) // self.kv.block_size,
+                                  pages)
 
     # -- eviction / lifecycle ---------------------------------------------
 
     def _evict_for_locked(self, shortfall: int) -> None:
-        """Release cold entries LRU-first until `shortfall` pages came
-        free or nothing evictable remains (caller holds kv lock).
-        Releasing an entry frees only pages with no other holder."""
-        freed = 0
-        for key in list(self._entries):
-            if freed >= shortfall:
-                break
-            e = self._entries.pop(key)
-            before = len(self.kv._free)
-            self.kv._free_locked(e.pages, e)
-            freed += len(self.kv._free) - before
+        """Release the oldest node, always a leaf, until `shortfall` pages
+        came free or the cache is empty (caller holds kv lock). Releasing
+        a node frees its page only if no one else holds it."""
+        before = len(self.kv._free)
+        while self._entries and len(self.kv._free) - before < shortfall:
+            (parent, _), node = self._entries.popitem(last=False)
+            if node.children:
+                raise KVCacheError(f"prefix cache: oldest is no leaf: {node}")
+            if parent is not None:
+                parent.children -= 1
+            self.kv._free_locked([node.page], node)
             self.counters["evicted"] += 1
 
     def drain(self) -> None:
         """Release every cached prefix (shutdown path: after drain, a
         quiesced cache closes with zero held pages)."""
         with self._lock:
-            for key in list(self._entries):
-                e = self._entries.pop(key)
-                self.kv._free_locked(e.pages, e)
+            while self._entries:
+                _, node = self._entries.popitem()
+                self.kv._free_locked([node.page], node)
 
     @property
     def entries(self) -> int:

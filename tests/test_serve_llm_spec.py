@@ -57,9 +57,9 @@ def test_prefix_cache_hit_and_miss():
     assert cached == 8
     assert pages_b[:2] == pages_a[:2]      # aliased page ids
     assert pages_b[2] != pages_a[2]        # private tail page
-    # page 0 backs BOTH registered sub-prefixes (4- and 8-token) plus
-    # the two sequences — every hold is an independent refcount
-    assert kv.page_refcount(pages_a[0]) == 4
+    # page 0 has ONE cache holder (its node, which both registered
+    # prefixes pass through) plus the two sequences
+    assert kv.page_refcount(pages_a[0]) == 3
     # a different prompt with the same first page: 1-page hit
     other = prompt[:4] + [999] * 6
     c = object()
@@ -126,7 +126,7 @@ def test_aliased_free_keeps_shared_pages():
         kv.free(pages_a, a)
     assert kv.free_pages == free_before + 1
     kv.free(pages_b, b)
-    assert kv.page_refcount(shared[0]) == 2  # the 2 cache entries pin it
+    assert kv.page_refcount(shared[0]) == 1  # its one cache node pins it
     kv.assert_quiesced()  # cached pages are not leaks
     pc.drain()
     assert kv.free_pages == kv.num_pages
@@ -201,6 +201,219 @@ def test_assert_quiesced_with_cached_prefixes():
     assert kv.cached_pages == 3 and kv.live_pages == 0
     pc.drain()
     assert kv.close() == 0
+
+
+def _small(num_pages, block_size=4):
+    return _cache(num_pages=num_pages, block_size=block_size, n_layer=1,
+                  n_kv_head=1, head_dim=1)
+
+
+def _fill(pc, prompt, answer=1):
+    """One request's life as the engine drives the cache: pages for the
+    prompt and its answer, the prefill's insert. Returns (pages, cached,
+    owner); the caller frees."""
+    owner = object()
+    pages, cached = pc.acquire(
+        prompt, owner, pc.kv.pages_for_tokens(len(prompt) + answer))
+    pc.insert(prompt, pages)
+    return pages, cached, owner
+
+
+def test_prefix_cache_work_is_a_prompts_tokens_once():
+    """One node a page: admission and insert of an 8,000-token prompt
+    copy and hash each token id once (the entry-a-prefix structure copied
+    16 K^2 of them and scanned K^3 / 6 holders at K = 500 pages), and a
+    page has one cache holder however many prefixes pass through it."""
+    kv = _small(1100, block_size=16)
+    pc = _prefix(kv)
+    prompt = np.random.RandomState(0).randint(0, 1000, 8000).tolist()
+    pages, cached, a = _fill(pc, prompt)
+    assert cached == 0 and pc.entries == 500
+    assert pc.stats()["inserted"] == 500
+    assert pc.stats()["key_tokens"] <= 2 * len(prompt)
+    assert max(kv.page_refcount(p) for p in pages) == 2   # `a` and a node
+    # a hit walks the path once more and the insert after it once more
+    before = pc.stats()["key_tokens"]
+    pages_b, cached, b = _fill(pc, prompt)
+    assert cached == 499 * 16 and pages_b[:499] == pages[:499]
+    assert pc.stats()["key_tokens"] - before <= 2 * len(prompt)
+    assert pc.entries == 500
+    assert max(kv.page_refcount(p) for p in pages) == 3
+    # a miss at the first page costs one look-up
+    before = pc.stats()["key_tokens"]
+    c = object()
+    pc.acquire([7] * 8000, c, 501)
+    assert pc.stats()["key_tokens"] - before == 16
+
+
+def test_prefix_cache_hits_what_an_entry_a_prefix_hit():
+    """The chain of nodes answers every admission as the structure it
+    replaced did, one entry for each full-page prefix keyed by the whole
+    token tuple: the same `cached` at every `acquire` and the same
+    `entries`, over a stream of prompts that share stems (no arena
+    pressure, so nothing is evicted on either side)."""
+    block = 4
+    kv = _small(4096, block)
+    pc = _prefix(kv)
+    rng = np.random.RandomState(7)
+    stems = [rng.randint(0, 30, 48).tolist() for _ in range(6)]
+    old_rule = set()
+    hits = 0
+    for _ in range(200):
+        stem = stems[rng.randint(6)]
+        prompt = stem[:rng.randint(1, 49)] \
+            + rng.randint(30, 60, rng.randint(0, 9)).tolist()
+        want = next((k * block
+                     for k in range((len(prompt) - 1) // block, 0, -1)
+                     if tuple(prompt[:k * block]) in old_rule), 0)
+        pages, cached, owner = _fill(pc, prompt)
+        assert cached == want
+        hits += bool(cached)
+        old_rule.update(tuple(prompt[:k * block])
+                        for k in range(1, len(prompt) // block + 1))
+        assert pc.entries == len(old_rule)
+        kv.free(pages, owner)
+    assert 100 < hits == pc.stats()["hits"]
+    assert pc.stats()["evicted"] == 0
+    assert pc.stats()["inserted"] == len(old_rule) == kv.cached_pages
+    kv.assert_quiesced()
+
+
+def _check_tree(pc):
+    """Every node's parent is cached (no page unreachable but held), a
+    node's `children` is what the keys say, and no child is younger than
+    its parent."""
+    nodes = list(pc._entries.values())
+    age = {id(n): i for i, n in enumerate(nodes)}
+    children = {}
+    for n in nodes:
+        parent = n.key[0]
+        if parent is not None:
+            assert pc._entries.get(parent.key) is parent
+            assert age[id(n)] < age[id(parent)]
+            children[id(parent)] = children.get(id(parent), 0) + 1
+    assert all(n.children == children.get(id(n), 0) for n in nodes)
+
+
+def _checked_eviction(pc):
+    """Wrap `_evict_for_locked`: whatever it pops had no child."""
+    evict = pc._evict_for_locked
+
+    def checked(shortfall):
+        before = list(pc._entries.values())
+        evict(shortfall)
+        for n in before:
+            if pc._entries.get(n.key) is not n:
+                assert n.children == 0
+        _check_tree(pc)
+
+    pc._evict_for_locked = checked
+
+
+def test_eviction_takes_leaves_oldest_first():
+    """Under arena pressure a prompt that fell out of use loses its tail
+    and keeps its head hittable; a path just hit is the last to go; no
+    node with a child is ever released."""
+    kv = _small(16)
+    pc = _prefix(kv)
+    _checked_eviction(pc)
+    a = list(range(0, 25))        # 6 full pages, cached first: the oldest
+    b = list(range(100, 117))     # 4 full pages
+    for prompt in (a, b):
+        pages, _, owner = _fill(pc, prompt, answer=0)
+        kv.free(pages, owner)
+    assert pc.entries == 10 and kv.free_pages == 6
+    big = kv.alloc(8, "big")      # two short: a's two last pages go
+    assert pc.stats()["evicted"] == 2 and pc.entries == 8
+    kv.free(big, "big")
+    pages, cached, owner = _fill(pc, a[:17] + [999])   # no new full page
+    assert cached == 16           # a's head is hittable, and now young
+    kv.free(pages, owner)
+    pages, cached, owner = _fill(pc, b, answer=0)
+    assert cached == 16           # b hit after it: the youngest path
+    kv.free(pages, owner)
+    big = kv.alloc(11, "big")     # three short: a's tail again, not b
+    assert pc.stats()["evicted"] == 5 and pc.entries == 5
+    kv.free(big, "big")
+    _, cached_b = pc.acquire(b, object(), 5)
+    _, cached_a = pc.acquire(a, object(), 7)
+    assert (cached_b, cached_a) == (16, 4)
+    _check_tree(pc)
+
+
+def test_two_fills_of_one_prompt_share_one_chain():
+    """A page's K/V depend on the token prefix alone, so a chain may hold
+    pages of several sequences: two requests that both missed both insert,
+    the second adds only what the first left out, and a third request
+    hits a chain of mixed pages. Every hold is given back."""
+    kv = _small(32)
+    pc = _prefix(kv)
+    short = list(range(13))                     # 3 full pages
+    long = short[:12] + list(range(50, 59))     # the same 3, then 2 more
+    oa, ob = object(), object()
+    pages_a, cached_a = pc.acquire(short, oa, 4)
+    pages_b, cached_b = pc.acquire(long, ob, 6)
+    assert cached_a == cached_b == 0            # both miss
+    pc.insert(short, pages_a)
+    pc.insert(long, pages_b)                    # finds 3 nodes, adds 2
+    assert pc.entries == 5 and pc.stats()["inserted"] == 5
+    assert [kv.page_refcount(p) for p in pages_b[:5]] == [1, 1, 1, 2, 2]
+    pc.insert(long, pages_b)                    # again: nothing to add
+    assert pc.entries == 5 and pc.stats()["inserted"] == 5
+    pages_c, cached_c, oc = _fill(pc, long)
+    assert cached_c == 20
+    assert pages_c[:5] == pages_a[:3] + pages_b[3:5]    # mixed pages
+    # one prompt, two fills: the second's own pages stay its own
+    od = object()
+    pages_d, cached_d = pc.acquire(long, od, 6)
+    assert cached_d == 20 and pages_d[:5] == pages_c[:5]
+    for pages, owner in ((pages_a, oa), (pages_b, ob), (pages_c, oc),
+                         (pages_d, od)):
+        kv.free(pages, owner)
+    assert kv.live_pages == 0 and kv.cached_pages == 5
+    kv.assert_quiesced()
+    pc.drain()
+    assert kv.free_pages == kv.num_pages
+    assert kv.close() == 0
+
+
+def test_a_hit_whose_remainder_evicts_is_left_whole():
+    """Making room for a hit's remainder takes the nodes past the hit
+    first (their pages come free, the hit's live on under the owner and
+    the cache); when it reaches the hit itself there was nothing else to
+    take, the allocation fails, and nothing stays taken."""
+    from ray_tpu.serve.llm import OutOfPagesError
+    kv = _small(8)
+    pc = _prefix(kv)
+    _checked_eviction(pc)
+    a = list(range(13))                         # 3 full pages
+    pages_a, _, owner = _fill(pc, a, answer=0)
+    kv.free(pages_a, owner)
+    assert (kv.live_pages, kv.cached_pages, kv.free_pages) == (0, 3, 5)
+    # hits a's first two pages, needs six more of five free: a's third
+    # page, past the hit, is the oldest leaf
+    fork = a[:8] + [70, 71]
+    ob = object()
+    pages_b, cached = pc.acquire(fork, ob, 8)
+    assert cached == 8 and pages_b[:2] == pages_a[:2]
+    assert pages_a[2] in pages_b[2:]            # freed, and taken again
+    assert pc.stats()["evicted"] == 1 and pc.entries == 2
+    assert (kv.live_pages, kv.cached_pages, kv.free_pages) == (8, 0, 0)
+    kv.free(pages_b[4:], ob)                    # keeps the hit and two more
+    # the same hit again, five more wanted of four free: the hit's own
+    # nodes are all there is to evict, and they free nothing
+    oc = object()
+    with pytest.raises(OutOfPagesError):
+        pc.acquire(fork, oc, 7)
+    assert pc.entries == 0 and pc.stats()["evicted"] == 3
+    assert (kv.live_pages, kv.cached_pages, kv.free_pages) == (4, 0, 4)
+    assert [kv.page_refcount(p) for p in pages_b[:4]] == [1] * 4
+    pages_c, cached = pc.acquire(fork, oc, 4)   # what fits is a plain miss
+    assert cached == 0
+    kv.free(pages_c, oc)
+    kv.free(pages_b[:4], ob)
+    kv.assert_quiesced()
+    assert kv.free_pages == kv.num_pages
 
 
 # ---------------------------------------------------------------------------
@@ -296,6 +509,27 @@ def test_prefix_cache_reuse_in_engine():
         assert "serve_llm_compiled_step_calls_total" in text
     finally:
         assert eng.shutdown() == 0          # drain happens here
+
+
+def test_engine_metrics_carry_the_prefix_caches_key_tokens():
+    """`prefix_cache_key_tokens` is the cache's own count of the token ids
+    it copied into keys, at most a prompt's tokens a call: two calls a
+    request (`acquire` at admission, `insert` after the prefill)."""
+    eng = _engine(prefix_cache=1)
+    try:
+        prompts = [list(range(1, 14)), list(range(1, 10)) + [77] * 6]
+        for p in prompts:
+            eng.submit(p, 3)
+        eng.run_until_idle(timeout=120)
+        m = eng.metrics()
+        assert m["prefix_cache_key_tokens"] \
+            == eng.prefix.counters["key_tokens"] > 0
+        assert m["prefix_cache_key_tokens"] \
+            <= 2 * sum(len(p) for p in prompts)
+        assert m["prefix_cache_entries"] == 3 + 1   # two pages are shared
+        eng.quiesce()
+    finally:
+        assert eng.shutdown() == 0
 
 
 @pytest.mark.parametrize("model", ["llama", "gpt"])
