@@ -47,8 +47,8 @@ from typing import Any, Dict, List, Optional, Tuple
 import numpy as np
 
 from ray_tpu.serve.llm.kv_cache import (OutOfPagesError, PagedKVCache,
-                                        PrefixCache, scatter_arena,
-                                        scatter_state)
+                                        PageKind, PrefixCache,
+                                        scatter_arena, scatter_state)
 from ray_tpu.util import metrics as _metrics
 from ray_tpu.util import request_recorder as _rr
 from ray_tpu.util import tracing as _tracing
@@ -126,7 +126,13 @@ class ModelFamily:
     ([lanes, block] tokens, the block's first position) and returns, first,
     the (token, probability) it chose at every position, then the block's
     cache rows, which the engine writes for the lanes whose block is whole
-    (`_decode_blocks`)."""
+    (`_decode_blocks`). `page_kinds` names the module's function from a
+    config to the kinds of its paged layers where they differ in how much
+    of a sequence they read (the fields of `kv_cache.PageKind`, a tuple a
+    kind: name, layers, rows, window): the arena then holds each kind's
+    arrays in that order, the chunk and decode steps take a page table a
+    kind after all the arrays, every step returns its cache rows in the
+    arrays' order, and `cache_rows` / `paged_layers` are not read."""
 
     module: str
     net: str
@@ -136,6 +142,7 @@ class ModelFamily:
     seq_state: Optional[str] = None
     paged_layers: Optional[str] = None
     block_schedule: Optional[str] = None
+    page_kinds: Optional[str] = None
 
 
 MODEL_FAMILIES: Dict[str, ModelFamily] = {
@@ -149,6 +156,8 @@ MODEL_FAMILIES: Dict[str, ModelFamily] = {
     "sdar_moe": ModelFamily("ray_tpu.models.sdar_moe", "SdarMoe",
                             "SdarMoeConfig", "cache_rows", "STEP_COUNTS",
                             block_schedule="block_schedule"),
+    "afmoe": ModelFamily("ray_tpu.models.afmoe", "Afmoe", "AfmoeConfig",
+                         step_counts="STEP_COUNTS", page_kinds="page_kinds"),
 }
 
 
@@ -162,10 +171,35 @@ def model_family(model: str):
     return family, importlib.import_module(family.module)
 
 
-def _valid_rows(counted: bool, w_page, arena) -> dict:
+def _valid_rows(counted: bool, pools, arena, w_pages) -> dict:
     """`valid=` for the steps of a family that counts (`step_counts`): a
-    row is a token's where the program is to write it."""
-    return {"valid": w_page < arena[0].shape[0]} if counted else {}
+    row is a token's where the program is to write it, in any kind of page
+    (`w_pages`: a kind's w_page each; a ring drops the rows of a write that
+    the same write overruns, and they are tokens all the same)."""
+    if not counted:
+        return {}
+    valid = None
+    for pool, w_page in zip(pools, w_pages):
+        here = w_page < arena[pool.arrays][0].shape[0]
+        valid = here if valid is None else valid | here
+    return {"valid": valid}
+
+
+def _scatter_kinds(pools, arena, rows, w_pages, w_offs) -> tuple:
+    """`scatter_arena` a kind: the step's new `rows` (one array for each of
+    `arena`) go to each kind's own arrays at the kind's own coordinates
+    (`w_pages`, `w_offs`: one a kind). Returns the updated arena."""
+    out = ()
+    for pool, w_page, w_off in zip(pools, w_pages, w_offs):
+        out += scatter_arena(arena[pool.arrays], rows[pool.arrays], w_page,
+                             w_off)
+    return out
+
+
+def _flat(arrays) -> list:
+    """Each of `arrays` with its leading two axes as one: a window's or a
+    block's [lanes, positions, ...] rows and coordinates, a row each."""
+    return [a.reshape((-1,) + a.shape[2:]) for a in arrays]
 
 
 def _state_args(state, slots=None) -> dict:
@@ -340,7 +374,8 @@ class Request:
 class _Sequence:
     """A running request's decode state.
 
-    `pos` is the number of tokens in the KV cache (= prompt +
+    `pages` is a page list for each kind of page the cache has (one, for
+    most families). `pos` is the number of tokens in the KV cache (= prompt +
     generated - 1 in steady state: the newest token rides as the next
     dispatch's input). `prefilled`/`cached` track the chunked-prefill
     frontier (prefilled starts at the prefix-cache hit length). `slot` is
@@ -356,7 +391,7 @@ class _Sequence:
     __slots__ = ("req", "pages", "pos", "prefilled", "cached", "slot",
                  "prefill_len", "block", "revealed", "handed")
 
-    def __init__(self, req: Request, pages: List[int], pos: int,
+    def __init__(self, req: Request, pages: Tuple[List[int], ...], pos: int,
                  cached: int = 0, slot: Optional[int] = None,
                  prefill_len: Optional[int] = None):
         self.req = req
@@ -446,15 +481,27 @@ class LLMEngine:
                 f"one state a sequence, which a page alias cannot restore "
                 f"(a prefix hit would start the suffix from a state that "
                 f"never saw the prefix); pass prefix_cache=0")
+        if family.page_kinds:
+            kinds = tuple(PageKind(*kind) for kind in getattr(
+                mod, family.page_kinds)(self.model_cfg))
+        else:
+            kinds = (PageKind(
+                "full", getattr(mod, family.paged_layers)(self.model_cfg)
+                if family.paged_layers else self.model_cfg.n_layer,
+                self._cache_rows(self.model_cfg)),)
+        if cfg.prefix_cache and any(kind.window for kind in kinds):
+            raise ValueError(
+                f"prefix_cache=1 with the {model!r} family: its window "
+                f"layers keep a ring of pages a sequence, which a page "
+                f"alias cannot restore (a prefix's window pages are "
+                f"overwritten as the sequence that filled them goes on); "
+                f"pass prefix_cache=0")
         self.kv = PagedKVCache(
-            cfg.num_pages,
-            getattr(mod, family.paged_layers)(self.model_cfg)
-            if family.paged_layers else self.model_cfg.n_layer,
-            cfg.block_size,
-            rows=self._cache_rows(self.model_cfg),
+            cfg.num_pages, 0, cfg.block_size, kinds=kinds,
             dtype=jnp.dtype(self.model_cfg.dtype),
             lock=_tracing.TimedLock(self._phases, threading.Lock()),
-            seq_state=seq_state, seq_slots=cfg.max_running)
+            seq_state=seq_state, seq_slots=cfg.max_running,
+            max_seq_len=self.model_cfg.max_seq_len)
         self.prefix = PrefixCache(self.kv) if cfg.prefix_cache else None
 
         # one compiled_step wrapper per bucket: each sees exactly one
@@ -546,6 +593,14 @@ class LLMEngine:
             for name in ("lane_passes", "lane_commits", "tokens_revealed",
                          "blocks_committed"):
                 self.counters[f"decode_{name}"] = 0
+        if len(self.kv.pools) > 1:
+            for pool in self.kv.pools:      # see `_count_kinds`
+                self.counters[f"decode_kv_pages_{pool.kind.name}"] = 0
+                if pool.kind.window is not None:
+                    self.counters[
+                        f"decode_kv_pages_{pool.kind.name}_lane_max"] = 0
+                    self.counters[
+                        f"decode_context_tokens_{pool.kind.name}"] = 0
         if self._key_trips is not None:
             # the key slots a decode step's query rows were scored
             # against, padding included, over lanes and layers: the
@@ -572,21 +627,27 @@ class LLMEngine:
     # A family that keeps sequence state has its `m` arrays next and the
     # lanes' slots last: the step's new states, which follow its cache
     # rows, are scattered to the slots (`scatter_state`) and the arrays
-    # returned after the pages'.
+    # returned after the pages'. What follows the arrays is, a kind of page
+    # (`kv.pools`; most families have one), the sequences' page table of the
+    # kind (not in a prefill) and the rows' coordinates in it, w_page and
+    # w_off; then the slots.
 
     def _make_prefill_fn(self, bucket: int):
         mod, n, m = self._mod, len(self.kv.arena), len(self.kv.state)
         counted = bool(self._step_counts)
-        cfg = self.model_cfg
+        cfg, pools = self.model_cfg, self.kv.pools
+        end = n + m + 2 * len(pools)
 
         def fn(variables, tokens, true_len, *rest):
             arena, state = rest[:n], rest[n:n + m]
-            w_page, w_off, *slots = rest[n + m:]
+            coords, slots = rest[n + m:end], rest[end:]
             logits, *out = mod.prefill_step(
                 variables, cfg, tokens, true_len,
-                **_valid_rows(counted, w_page[None], arena))
-            return (logits,) + scatter_arena(
-                arena, [rows[0] for rows in out[:n]], w_page, w_off) \
+                **_valid_rows(counted, pools, arena,
+                              [w[None] for w in coords[0::2]]))
+            return (logits,) + _scatter_kinds(
+                pools, arena, [rows[0] for rows in out[:n]],
+                coords[0::2], coords[1::2]) \
                 + scatter_state(state, out[n:n + m], *slots) \
                 + tuple(out[n + m:])
 
@@ -596,17 +657,18 @@ class LLMEngine:
     def _make_decode_fn(self, batch: int):
         mod, n, m = self._mod, len(self.kv.arena), len(self.kv.state)
         counted = bool(self._step_counts)
-        cfg = self.model_cfg
+        cfg, pools = self.model_cfg, self.kv.pools
+        end = n + m + 3 * len(pools)
 
         def fn(variables, tokens, positions, *rest):
             arena, state = rest[:n], rest[n:n + m]
-            page_table, w_page, w_off, *slots = rest[n + m:]
+            coords, slots = rest[n + m:end], rest[end:]
             logits, *out = mod.decode_step(
-                variables, cfg, tokens, positions, *arena, page_table,
+                variables, cfg, tokens, positions, *arena, *coords[0::3],
                 **_state_args(state, *slots),
-                **_valid_rows(counted, w_page, arena))
-            return (logits,) + scatter_arena(arena, out[:n], w_page,
-                                             w_off) \
+                **_valid_rows(counted, pools, arena, coords[1::3]))
+            return (logits,) + _scatter_kinds(
+                pools, arena, out[:n], coords[1::3], coords[2::3]) \
                 + scatter_state(state, out[n:n + m], *slots) \
                 + tuple(out[n + m:])
 
@@ -638,17 +700,18 @@ class LLMEngine:
         nothing. The first output is the step's (token, probability), each
         [batch, block]."""
         mod, n = self._mod, len(self.kv.arena)
-        cfg = self.model_cfg
+        cfg, pools = self.model_cfg, self.kv.pools
 
         def fn(variables, tokens, positions, *rest):
             arena = rest[:n]
-            page_table, w_page, w_off, live = rest[n:]
+            *coords, live = rest[n:]
             chosen, *out = mod.decode_step(
-                variables, cfg, tokens, positions, *arena, page_table,
+                variables, cfg, tokens, positions, *arena, *coords[0::3],
                 valid=live)
-            return (chosen,) + scatter_arena(
-                arena, [r.reshape((-1,) + r.shape[2:]) for r in out[:n]],
-                w_page.reshape(-1), w_off.reshape(-1)) + tuple(out[n:])
+            return (chosen,) + _scatter_kinds(
+                pools, arena, _flat(out[:n]), _flat(coords[1::3]),
+                _flat(coords[2::3])) \
+                + tuple(out[n:])
 
         fn.__name__ = f"llm_decode_b{batch}"
         return fn
@@ -658,18 +721,19 @@ class LLMEngine:
         prefix-cache suffix): `w_page` / `w_off` are [1, size]."""
         mod, n, m = self._mod, len(self.kv.arena), len(self.kv.state)
         counted = bool(self._step_counts)
-        cfg = self.model_cfg
+        cfg, pools = self.model_cfg, self.kv.pools
+        end = n + m + 3 * len(pools)
 
         def fn(variables, tokens, start, *rest):
             arena, state = rest[:n], rest[n:n + m]
-            page_table, w_page, w_off, *slots = rest[n + m:]
+            coords, slots = rest[n + m:end], rest[end:]
             logits, *out = mod.chunk_step(
-                variables, cfg, tokens, start, *arena, page_table,
+                variables, cfg, tokens, start, *arena, *coords[0::3],
                 **_state_args(state, *slots),
-                **_valid_rows(counted, w_page, arena))
-            return (logits,) + scatter_arena(
-                arena, [r.reshape((-1,) + r.shape[2:]) for r in out[:n]],
-                w_page.reshape(-1), w_off.reshape(-1)) \
+                **_valid_rows(counted, pools, arena, coords[1::3]))
+            return (logits,) + _scatter_kinds(
+                pools, arena, _flat(out[:n]), _flat(coords[1::3]),
+                _flat(coords[2::3])) \
                 + scatter_state(state, out[n:n + m], *slots) \
                 + tuple(out[n + m:])
 
@@ -695,8 +759,7 @@ class LLMEngine:
             self._call(fn, (
                 self.params, np.zeros((1, s), np.int32),
                 np.ones((1,), np.int32), *kv.arena, *kv.state,
-                np.full(s, kv.num_pages, np.int32), np.zeros(s, np.int32),
-                *self._slots_of((), 1)))
+                *self._no_rows((s,), None), *self._slots_of((), 1)))
         for b, fn in self._decode_fns.items():
             if self._block is None:
                 self._warm_call(fn, (b,))
@@ -746,10 +809,22 @@ class LLMEngine:
         b, kv = rows[0], self.kv
         self._call(fn, (
             self.params, np.zeros(rows, np.int32), np.zeros(b, np.int32),
-            *kv.arena, *kv.state,
-            np.zeros((b, self.max_pages_per_seq), np.int32),
-            np.full(rows, kv.num_pages, np.int32), np.zeros(rows, np.int32),
+            *kv.arena, *kv.state, *self._no_rows(rows, b),
             *self._slots_of((), b), *last))
+
+    def _no_rows(self, rows: Tuple[int, ...], lanes: Optional[int]) -> list:
+        """What a program takes after the arrays, for every kind of page,
+        before any sequence is filled in: a page table of zeros for `lanes`
+        sequences (none for a prefill, which takes no table) and `rows`-
+        shaped coordinates that write nowhere, every page id the kind's
+        dropped one."""
+        out = []
+        for pool in self.kv.pools:
+            if lanes is not None:
+                out.append(np.zeros((lanes, pool.width), np.int32))
+            out += [np.full(rows, pool.num_pages, np.int32),
+                    np.zeros(rows, np.int32)]
+        return out
 
     # -- submission -------------------------------------------------------
 
@@ -886,7 +961,8 @@ class LLMEngine:
     def _admit_one(self, now_ns: int) -> Optional[_Sequence]:
         """Pop the oldest waiting request whose worst-case page demand
         fits right now (pages reserved up front: a running sequence can
-        never hit OutOfPages mid-decode). With the prefix cache on,
+        never hit OutOfPages mid-decode; a family with several kinds of
+        page gets all of them or none, `kv.reserve`). With the prefix cache on,
         admission aliases the longest cached full-page prefix into the
         new page table atomically with the remainder allocation — the
         sequence then prefills only the uncached suffix. `now_ns` is the
@@ -902,15 +978,16 @@ class LLMEngine:
             # admission wait, not queue wait
             if req.considered_ns is None:
                 req.considered_ns = max(now_ns, req.submit_ns)
-            need = self.kv.pages_for_tokens(
-                len(req.prompt) + req.max_new_tokens)
+            total = len(req.prompt) + req.max_new_tokens
             cached = 0
             try:
                 if self.prefix is not None:
-                    pages, cached = self.prefix.acquire(
-                        req.prompt, req, need)
+                    held, cached = self.prefix.acquire(
+                        req.prompt, req, self.kv.pages_for_tokens(total))
+                    pages = (held,)
                 else:
-                    pages = self.kv.alloc(need, req)
+                    # every kind's pages, or none
+                    pages = self.kv.reserve(total, req)
             except OutOfPagesError:
                 return None
             # a slot a running sequence (`seq_slots=max_running`), and the
@@ -1041,17 +1118,19 @@ class LLMEngine:
                 toks[0, :s] = req.prompt[:s]
                 self._note_call("prefill", bucket)
             with phase("prefill_kv_write"):
-                w_page, w_off = self.kv.write_index(seq.pages, 0, s, bucket)
+                coords = [c for kind, held in enumerate(seq.pages)
+                          for c in self.kv.write_index(held, 0, s, bucket,
+                                                       kind=kind)]
             next_logits = self._prefill_forward(
                 req, self._prefill_fns[bucket],
                 (self.params, toks, np.asarray([s], np.int32),
-                 *self.kv.arena, *self.kv.state, w_page, w_off,
+                 *self.kv.arena, *self.kv.state, *coords,
                  *self._slots_of((seq,), 1)))
             with phase("prefill_kv_write"):
                 seq.prefilled = s
                 seq.pos = s
                 if self.prefix is not None:
-                    self.prefix.insert(req.prompt, seq.pages)
+                    self.prefix.insert(req.prompt, seq.pages[0])
                 with self._lock:
                     self.counters["prefill_steps"] += 1
             with phase("prefill_sample"):
@@ -1077,17 +1156,20 @@ class LLMEngine:
                 toks = np.zeros((1, c), np.int32)
                 toks[0, :take] = \
                     req.prompt[seq.prefilled:seq.prefilled + take]
-                table = np.zeros((1, self.max_pages_per_seq), np.int32)
-                table[0, :len(seq.pages)] = seq.pages
+                coords = self._no_rows((1, c), 1)
+                for table, held in zip(coords[0::3], seq.pages):
+                    table[0, :len(held)] = held
                 self._note_call("chunk", c)
             with phase("prefill_kv_write"):
-                w_page, w_off = self.kv.write_index(
-                    seq.pages, seq.prefilled, take, c)
+                for kind, held in enumerate(seq.pages):
+                    coords[3 * kind + 1][0], coords[3 * kind + 2][0] = \
+                        self.kv.write_index(held, seq.prefilled, take, c,
+                                            kind=kind)
             logits = self._prefill_forward(
                 req, self._chunk_fn,
                 (self.params, toks, np.asarray([seq.prefilled], np.int32),
-                 *self.kv.arena, *self.kv.state, table,
-                 w_page[None], w_off[None], *self._slots_of((seq,), 1)))
+                 *self.kv.arena, *self.kv.state, *coords,
+                 *self._slots_of((seq,), 1)))
             with phase("prefill_kv_write"):
                 seq.prefilled += take
                 with self._lock:
@@ -1097,7 +1179,7 @@ class LLMEngine:
                     return 0
                 seq.pos = s
                 if self.prefix is not None:
-                    self.prefix.insert(req.prompt, seq.pages)
+                    self.prefix.insert(req.prompt, seq.pages[0])
                 with self._lock:
                     self.counters["prefill_steps"] += 1
             with phase("prefill_sample"):
@@ -1137,27 +1219,25 @@ class LLMEngine:
                      if b >= len(runs))
             tokens = np.zeros(bb, np.int32)
             positions = np.zeros(bb, np.int32)
-            page_table = np.zeros((bb, self.max_pages_per_seq), np.int32)
+            # a lane beyond the running set computes on a page table
+            # of zeros and writes nowhere: its page id is dropped
+            coords = self._no_rows((bb,), bb)
             for i, seq in enumerate(runs):
                 tokens[i] = seq.last_token
                 positions[i] = seq.pos
-                page_table[i, :len(seq.pages)] = seq.pages
+                for table, held in zip(coords[0::3], seq.pages):
+                    table[i, :len(held)] = held
             with phase("decode_kv_append"):
-                # a lane beyond the running set computes on a page table
-                # of zeros and writes nowhere: its page id is dropped
-                w_page = np.full(bb, self.kv.num_pages, np.int32)
-                w_off = np.zeros(bb, np.int32)
-                for i, seq in enumerate(runs):
-                    slot, w_off[i] = divmod(seq.pos, self.kv.block_size)
-                    w_page[i] = seq.pages[slot]
+                self._write_coords(runs, coords)
             self._note_call("decode", bb)
             logits = self._decode_forward(
                 self._decode_fns[bb],
                 (self.params, tokens, positions,
-                 *self.kv.arena, *self.kv.state, page_table,
-                 w_page, w_off, *self._slots_of(runs, bb)))
+                 *self.kv.arena, *self.kv.state, *coords,
+                 *self._slots_of(runs, bb)))
             with phase("decode_kv_append"):
                 context = int(positions.sum())
+                self._count_kinds(runs, positions)
                 for seq in runs:
                     seq.pos += 1
             finished = []
@@ -1181,6 +1261,41 @@ class LLMEngine:
                 self._finish(seq)
             return len(runs)
 
+    def _write_coords(self, runs, coords, lanes=None) -> None:
+        """Where the decode pass's new rows go, a kind of page: into
+        `coords` (`_no_rows`), for `lanes` of `runs` (all of them where none
+        are named), the page that holds the lane's position `pos` (in a
+        ring, (pos // block_size) mod the ring) and the offset in it."""
+        block = self.kv.block_size
+        for kind, pool in enumerate(self.kv.pools):
+            w_page, w_off = coords[3 * kind + 1], coords[3 * kind + 2]
+            for i in range(len(runs)) if lanes is None else lanes:
+                slot, w_off[i] = divmod(runs[i].pos, block)
+                w_page[i] = runs[i].pages[kind][slot % pool.width]
+
+    def _count_kinds(self, runs, positions) -> None:
+        """A decode pass's counts a kind of page, where the cache has more
+        than one, each summed over the passes: the kind's pages that
+        sequences hold now (`decode_kv_pages_<kind>`) and, for a kind with
+        a window, the most of them that one lane of the pass holds
+        (`decode_kv_pages_<kind>_lane_max`) and the cached positions the
+        pass had to read of it (`decode_context_tokens_<kind>`: no lane
+        more than the window's)."""
+        if len(self.kv.pools) == 1:
+            return
+        add = {}
+        for kind, pool in enumerate(self.kv.pools):
+            name, window = pool.kind.name, pool.kind.window
+            add[f"decode_kv_pages_{name}"] = self.kv.live_pages_of(kind)
+            if window is not None:
+                add[f"decode_kv_pages_{name}_lane_max"] = max(
+                    len(seq.pages[kind]) for seq in runs)
+                add[f"decode_context_tokens_{name}"] = int(
+                    np.minimum(positions, window - 1).sum())
+        with self._lock:
+            for name, n in add.items():
+                self.counters[name] += n
+
     def _decode_blocks(self) -> int:
         """One pass over the running set of a family that generates by
         diffusion over blocks: every lane's open block goes through the
@@ -1203,31 +1318,29 @@ class LLMEngine:
             shown = np.ones((bb, length), bool)
             positions = np.zeros(bb, np.int32)
             live = np.arange(bb) < len(runs)
-            page_table = np.zeros((bb, self.max_pages_per_seq), np.int32)
+            coords = self._no_rows((bb, length), bb)
             for i, seq in enumerate(runs):
                 tokens[i] = seq.block
                 shown[i] = seq.revealed
                 positions[i] = seq.pos
-                page_table[i, :len(seq.pages)] = seq.pages
+                for table, held in zip(coords[0::3], seq.pages):
+                    table[i, :len(held)] = held
             hidden = ~shown         # nothing is hidden in a lane past `runs`
             commits = live & shown.all(axis=1)
             with phase("decode_kv_append"):
                 # a block never straddles a page: one page id a lane, the
                 # block's offsets in it
-                w_page = np.full((bb, length), self.kv.num_pages, np.int32)
-                w_off = np.zeros((bb, length), np.int32)
-                for i in np.flatnonzero(commits):
-                    slot, first = divmod(runs[i].pos, self.kv.block_size)
-                    w_page[i] = runs[i].pages[slot]
-                    w_off[i] = first
-                w_off += np.arange(length, dtype=np.int32)
+                self._write_coords(runs, coords, np.flatnonzero(commits))
+                for w_off in coords[2::3]:
+                    w_off += np.arange(length, dtype=np.int32)
             self._note_call("decode", bb)
             chosen, prob = self._decode_forward(
                 self._decode_fns[bb],
                 (self.params, tokens, positions, *self.kv.arena,
-                 page_table, w_page, w_off, live))
+                 *coords, live))
             with phase("decode_kv_append"):
                 context = int(positions.sum())
+                self._count_kinds(runs, positions)
                 for i in np.flatnonzero(commits):
                     runs[i].pos += length
                     self._open_block(runs[i])
@@ -1327,7 +1440,7 @@ class LLMEngine:
             # refcounted free: pages the prefix cache (or a sibling
             # sequence) still aliases survive this — only the refcount
             # drops
-            self.kv.free(seq.pages, seq.req)
+            self.kv.release(seq.pages, seq.req)
             if seq.slot is not None:
                 self.kv.free_slot(seq.slot, seq.req)
             with self._lock:
@@ -1581,6 +1694,8 @@ class LLMEngine:
                 kv_pages_live=self.kv.live_pages,
                 kv_pages_cached=self.kv.cached_pages,
                 kv_pages_total=self.kv.num_pages,
+                kv_tokens_live=sum(seq.pos for seq in self._running)
+                + sum(seq.prefilled for seq in self._prefilling),
                 kv_page_utilization=self.kv.utilization(),
                 model=self.model_name,
                 compiled_step_calls={
@@ -1590,6 +1705,13 @@ class LLMEngine:
                 tenants={t: dict(row)
                          for t, row in self.tenant_counters.items()},
             )
+        if len(self.kv.pools) > 1:
+            for kind, pool in enumerate(self.kv.pools):
+                name = pool.kind.name
+                out[f"kv_pages_live_{name}"] = self.kv.live_pages_of(kind)
+                out[f"kv_pages_total_{name}"] = pool.num_pages
+                # the most pages of the kind one sequence was given
+                out[f"kv_pages_{name}_seq_max"] = pool.seq_max
         if self.kv.state:
             out.update(state_slots_live=self.kv.live_slots,
                        state_slots_free=self.kv.free_slots,

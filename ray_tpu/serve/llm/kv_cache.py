@@ -40,16 +40,33 @@ donated after the pages, gather and scatter a lane's slot, and hand them
 back (`scatter_state`). The last slot belongs to nobody: it is where the
 padded lanes of a decode bucket read and write.
 
+A family whose layers differ in how much of a sequence they read declares
+KINDS of paged layer (`PageKind`): each kind has its own arrays [pages of
+the kind, layers of the kind, block_size, *row], its own free list and
+holders, and a sequence holds a page list a kind. A kind with a `window`
+(its queries read the last `window` cached positions and nothing before
+them) is a RING: a sequence holds at most `ring` = pages_for_tokens(window)
++ 1 pages of it, position p lies in the sequence's page (p // block_size)
+mod ring, and a page is overwritten when the window has passed it, so a
+window layer holds a window and not a context. A sequence shorter than
+the ring holds what its length needs, and nothing wraps. One more page than
+the window's own lets a program read the window's oldest page from the
+arena it was given while its new rows go to the page after the newest: a
+program's writes land after its reads. Reservation (`reserve`) takes every
+kind's pages under one hold of the lock, or none. A family that declares
+nothing has one kind, unbounded, through the same code.
+
 A dead replica's arena dies with its process: the device memory is the
 process's own, so there is nothing for a peer to reclaim.
 """
 
 from __future__ import annotations
 
+import dataclasses
 import functools
 import threading
 from collections import OrderedDict
-from typing import Dict, List, Optional, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -104,6 +121,48 @@ def _scatter_arena_jit():
     return jax.jit(scatter_arena, donate_argnums=(0,))
 
 
+@dataclasses.dataclass(frozen=True)
+class PageKind:
+    """One kind of paged layer of a model family: its name (counters carry
+    it), how many of the model's layers are of it, what a token leaves in
+    each (`rows`: one shape an arena array), and `window`: the most cached
+    positions before a query that a layer of the kind reads (a sliding
+    window of W keys: W - 1 and the query's own), None where a layer
+    reads them all."""
+
+    name: str
+    n_layer: int
+    rows: Tuple[Tuple[int, ...], ...]
+    window: Optional[int] = None
+
+
+class _Pool:
+    """The pages of one kind: the allocator's maps (guarded by the cache's
+    lock) and where the kind's arrays lie in `PagedKVCache.arena`."""
+
+    def __init__(self, kind: PageKind, num_pages: int, ring: Optional[int],
+                 width: int, first_array: int):
+        self.kind = kind
+        self.num_pages = num_pages
+        # a bounded kind: the most pages a sequence holds; and the length
+        # of a sequence's row of the kind's page table, either way
+        self.ring = ring
+        self.width = width
+        self.arrays = slice(first_array, first_array + len(kind.rows))
+        # LIFO free list: recently-freed pages are re-used first (warm)
+        self.free: List[int] = list(range(num_pages - 1, -1, -1))
+        # page -> holder list (refcount == len). A holder is a request/
+        # sequence object, or a _PrefixEntry when the prefix cache
+        # pinned the page for reuse.
+        self.holders: Dict[int, List[object]] = {}
+        # page -> how many of its holders are not the prefix cache, for
+        # the pages that have one; kept as the holders change: `metrics()`
+        # asks under the engine's lock, and a walk over 8,192 holder lists
+        # there stalls the pump (seconds under a profiler's Python tracer)
+        self.live: Dict[int, int] = {}
+        self.seq_max = 0    # the most pages one reservation took
+
+
 class PagedKVCache:
     """Fixed-size page allocator over an arena on the device.
 
@@ -115,6 +174,14 @@ class PagedKVCache:
     stores those back here before the next one; the old handles are dead
     from the call on. `store` is accepted for callers of the host arena
     this replaced and backs nothing.
+
+    `kinds` (of `PageKind`) instead of `n_layer` and `rows`: the arena then
+    holds every kind's arrays, in the kinds' order, and every method that
+    names pages takes the kind's index (`kind=`, the first where it is not
+    given). `num_pages` counts the pages of a kind without a window; a kind
+    with one gets `seq_slots` (the sequences that run at once) times its
+    ring, and no more than `num_pages`. `max_seq_len` is the longest
+    sequence, for the width of an unbounded kind's page table.
     """
 
     def __init__(self, num_pages: int, n_layer: int, block_size: int,
@@ -122,17 +189,24 @@ class PagedKVCache:
                  head_dim: Optional[int] = None, dtype=np.float32,
                  store=None, lock=None,
                  rows: Optional[Tuple[Tuple[int, ...], ...]] = None,
-                 seq_state=(), seq_slots: int = 0):
+                 seq_state=(), seq_slots: int = 0,
+                 kinds: Optional[Sequence[PageKind]] = None,
+                 max_seq_len: int = 0):
         import jax.numpy as jnp
 
         if num_pages <= 0 or block_size <= 0:
             raise KVCacheError("num_pages and block_size must be positive")
-        if rows is None:
-            rows = ((n_kv_head, head_dim),) * 2
+        if kinds is None:
+            if rows is None:
+                rows = ((n_kv_head, head_dim),) * 2
+            kinds = (PageKind("full", n_layer, rows),)
+        self.kinds = tuple(dataclasses.replace(
+            kind, rows=tuple(tuple(int(n) for n in row) for row in kind.rows))
+            for kind in kinds)
         self.num_pages = num_pages
-        self.n_layer = n_layer
+        self.n_layer = sum(kind.n_layer for kind in self.kinds)
         self.block_size = block_size
-        self.rows = tuple(tuple(int(n) for n in row) for row in rows)
+        self.rows = self.kinds[0].rows
         if n_kv_head is None and len(self.rows) == 2 \
                 and self.rows[0] == self.rows[1] and len(self.rows[0]) == 2:
             n_kv_head, head_dim = self.rows[0]      # an arena of K and V
@@ -141,23 +215,26 @@ class PagedKVCache:
         self.dtype = np.dtype(dtype)
         # the engine passes a lock whose waits show in its time ledger
         self._lock = lock if lock is not None else threading.Lock()
-        shapes = [(num_pages, n_layer, block_size) + row
-                  for row in self.rows]
+        table = self.pages_for_tokens(max_seq_len) or num_pages
+        self.pools: Tuple[_Pool, ...] = ()
+        shapes = []
+        for kind in self.kinds:
+            ring, pages = None, num_pages
+            if kind.window is not None:
+                if seq_slots <= 0:
+                    raise KVCacheError(
+                        f"the {kind.name!r} kind keeps a window a sequence: "
+                        f"seq_slots says how many sequences")
+                ring = self.pages_for_tokens(kind.window) + 1
+                pages = min(num_pages, seq_slots * ring)
+            self.pools += (_Pool(kind, pages, ring, ring or table,
+                                 len(shapes)),)
+            shapes += [(pages, kind.n_layer, block_size) + row
+                       for row in kind.rows]
         # from the shape: other threads ask while a step holds the handles
         self.arena_nbytes = sum(int(np.prod(shape)) for shape in shapes) \
             * self.dtype.itemsize
         self.arena = tuple(jnp.zeros(shape, self.dtype) for shape in shapes)
-        # LIFO free list: recently-freed pages are re-used first (warm)
-        self._free: List[int] = list(range(num_pages - 1, -1, -1))
-        # page -> holder list (refcount == len). A holder is a request/
-        # sequence object, or a _PrefixEntry when the prefix cache
-        # pinned the page for reuse.
-        self._holders: Dict[int, List[object]] = {}
-        # page -> how many of its holders are not the prefix cache, for
-        # the pages that have one; kept as the holders change: `metrics()`
-        # asks under the engine's lock, and a walk over 8,192 holder lists
-        # there stalls the pump (seconds under a profiler's Python tracer)
-        self._live: Dict[int, int] = {}
         self._prefix_cache: Optional["PrefixCache"] = None
         # what a sequence keeps beside its pages: `seq_state` is one
         # (shape, dtype) an array, `seq_slots` how many sequences; one
@@ -193,72 +270,122 @@ class PagedKVCache:
         # raylint: disable=lock-discipline
         self.arena = self.arena[:1] + (pages,) + self.arena[2:]
 
+    # the first kind's maps under their old names (the prefix cache, which
+    # serves a cache of one kind, and tests that look inside)
+    @property
+    def _free(self) -> List[int]:
+        return self.pools[0].free
+
+    @property
+    def _holders(self) -> Dict[int, List[object]]:
+        return self.pools[0].holders
+
     # -- allocation -------------------------------------------------------
+
+    def free_pages_of(self, kind: int = 0) -> int:
+        with self._lock:
+            return len(self.pools[kind].free)
 
     @property
     def free_pages(self) -> int:
+        return self.free_pages_of()
+
+    def live_pages_of(self, kind: int) -> int:
         with self._lock:
-            return len(self._free)
+            return len(self.pools[kind].live)
 
     @property
     def live_pages(self) -> int:
-        """Pages held by at least one sequence (prefix-cache-only pages
-        are reusable state, not live work — see `cached_pages`)."""
+        """Pages held by at least one sequence, over the kinds
+        (prefix-cache-only pages are reusable state, not live work — see
+        `cached_pages`)."""
         with self._lock:
-            return len(self._live)
+            return sum(len(pool.live) for pool in self.pools)
 
     @property
     def cached_pages(self) -> int:
         """Pages held ONLY by the prefix cache (reusable on hit,
         evictable under pressure)."""
         with self._lock:
-            return len(self._holders) - len(self._live)
+            return sum(len(pool.holders) - len(pool.live)
+                       for pool in self.pools)
 
     def utilization(self) -> float:
         with self._lock:
-            return len(self._holders) / self.num_pages
+            return sum(len(pool.holders) for pool in self.pools) \
+                / sum(pool.num_pages for pool in self.pools)
 
-    def page_refcount(self, page: int) -> int:
+    def page_refcount(self, page: int, kind: int = 0) -> int:
         with self._lock:
-            return len(self._holders.get(page, ()))
+            return len(self.pools[kind].holders.get(page, ()))
 
     def pages_for_tokens(self, n_tokens: int) -> int:
         return -(-n_tokens // self.block_size)  # ceil div
 
-    def alloc(self, n: int, owner) -> List[int]:
+    def pages_by_kind(self, n_tokens: int) -> Tuple[int, ...]:
+        """Pages a sequence of `n_tokens` holds, a kind: what its length
+        needs, and in a ring no more than the ring."""
+        need = self.pages_for_tokens(n_tokens)
+        return tuple(need if pool.ring is None else min(need, pool.ring)
+                     for pool in self.pools)
+
+    def reserve(self, n_tokens: int, owner) -> Tuple[List[int], ...]:
+        """Every kind's pages for a sequence of `n_tokens` (`pages_by_kind`),
+        for `owner`: all of them or, with OutOfPagesError, none."""
+        with self._lock:
+            taken: List[List[int]] = []
+            try:
+                for kind, n in enumerate(self.pages_by_kind(n_tokens)):
+                    taken.append(self._alloc_locked(n, owner, kind))
+            except OutOfPagesError:
+                for kind, pages in enumerate(taken):
+                    self._free_locked(pages, owner, kind)
+                raise
+            return tuple(taken)
+
+    def release(self, pages: Sequence[List[int]], owner) -> None:
+        """Give back what `reserve` took (a page list a kind)."""
+        with self._lock:
+            for kind, held in enumerate(pages):
+                self._free_locked(held, owner, kind)
+
+    def alloc(self, n: int, owner, kind: int = 0) -> List[int]:
         """Take `n` pages for `owner`; raises OutOfPagesError when the
         arena can't satisfy the request (nothing is partially taken).
         On shortfall, cold prefix-cache entries are evicted LRU-first
         before giving up — cached prefixes never crowd out live work."""
         with self._lock:
-            return self._alloc_locked(n, owner)
+            return self._alloc_locked(n, owner, kind)
 
-    def _alloc_locked(self, n: int, owner) -> List[int]:
+    def _alloc_locked(self, n: int, owner, kind: int = 0) -> List[int]:
         self._check_open()
-        if n > len(self._free) and self._prefix_cache is not None:
-            self._prefix_cache._evict_for_locked(n - len(self._free))
-        if n > len(self._free):
+        pool = self.pools[kind]
+        if n > len(pool.free) and self._prefix_cache is not None:
+            self._prefix_cache._evict_for_locked(n - len(pool.free))
+        if n > len(pool.free):
             raise OutOfPagesError(
-                f"need {n} pages, {len(self._free)} free "
-                f"of {self.num_pages}")
-        pages = [self._free.pop() for _ in range(n)]
+                f"need {n} pages, {len(pool.free)} free "
+                f"of {pool.num_pages} ({pool.kind.name})")
+        pages = [pool.free.pop() for _ in range(n)]
         for p in pages:
-            self._holders[p] = [owner]
+            pool.holders[p] = [owner]
         if not isinstance(owner, _PrefixEntry):
-            self._live.update((p, 1) for p in pages)
+            pool.live.update((p, 1) for p in pages)
+            pool.seq_max = max(pool.seq_max, n)
         return pages
 
-    def share(self, pages: List[int], owner) -> None:
+    def share(self, pages: List[int], owner, kind: int = 0) -> None:
         """Alias already-allocated pages into `owner`'s page table
         (incref). The pages must be live; the same owner may not hold a
         page twice (accounting bugs fail loudly)."""
         with self._lock:
-            self._share_locked(pages, owner)
+            self._share_locked(pages, owner, kind)
 
-    def _share_locked(self, pages: List[int], owner) -> None:
+    def _share_locked(self, pages: List[int], owner, kind: int = 0) -> None:
         self._check_open()
+        pool = self.pools[kind]
         for p in pages:
-            hs = self._holders.get(p)
+            hs = pool.holders.get(p)
             if hs is None:
                 raise KVCacheError(f"share of free page {p}")
             if any(h is owner for h in hs):
@@ -266,41 +393,42 @@ class PagedKVCache:
                     f"share of page {p} already held by this owner")
         sequence = not isinstance(owner, _PrefixEntry)
         for p in pages:
-            self._holders[p].append(owner)
+            pool.holders[p].append(owner)
             if sequence:
-                self._live[p] = self._live.get(p, 0) + 1
+                pool.live[p] = pool.live.get(p, 0) + 1
 
-    def free(self, pages: List[int], owner) -> None:
+    def free(self, pages: List[int], owner, kind: int = 0) -> None:
         """Release `owner`'s hold on each page; a page returns to the
         free list only at refcount zero — a page still aliased by the
         prefix cache or another running sequence survives the free.
         Raises on double-free or a page the owner doesn't hold."""
         with self._lock:
-            self._free_locked(pages, owner)
+            self._free_locked(pages, owner, kind)
 
-    def _free_locked(self, pages: List[int], owner) -> None:
+    def _free_locked(self, pages: List[int], owner, kind: int = 0) -> None:
         self._check_open()
+        pool = self.pools[kind]
         for p in pages:
-            hs = self._holders.get(p)
+            hs = pool.holders.get(p)
             if hs is None or not any(h is owner for h in hs):
                 held = "free" if hs is None else f"held by {hs!r}"
                 raise KVCacheError(
                     f"free of page {p} not held by owner ({held})")
         sequence = not isinstance(owner, _PrefixEntry)
         for p in pages:
-            hs = self._holders[p]
+            hs = pool.holders[p]
             for i, h in enumerate(hs):
                 if h is owner:
                     del hs[i]
                     break
             if sequence:
-                if self._live[p] > 1:
-                    self._live[p] -= 1
+                if pool.live[p] > 1:
+                    pool.live[p] -= 1
                 else:
-                    del self._live[p]
+                    del pool.live[p]
             if not hs:
-                del self._holders[p]
-                self._free.append(p)
+                del pool.holders[p]
+                pool.free.append(p)
 
     # -- sequence-state slots --------------------------------------------
 
@@ -341,47 +469,60 @@ class PagedKVCache:
     # -- data plane -------------------------------------------------------
 
     def write_index(self, pages: List[int], start: int, n: int,
-                    rows: Optional[int] = None
+                    rows: Optional[int] = None, kind: int = 0
                     ) -> Tuple[np.ndarray, np.ndarray]:
         """Arena coordinates (page id, offset in the page; int32, one per
         row) of positions [start, start + n) of a sequence that holds
-        `pages`, padded to `rows` rows. A padding row, and a position
-        past the last of `pages`, gets the page id `num_pages`, which
-        `scatter_arena` drops."""
+        `pages` of the kind, padded to `rows` rows. A padding row, and a
+        position past the last of `pages`, gets the kind's `num_pages` for
+        a page id, which `scatter_arena` drops. In a ring the page is
+        (position // block_size) mod the ring, and a position that a later
+        one of the same write lands on is dropped too: the window has
+        passed it before anything could read it."""
+        pool = self.pools[kind]
         rows = n if rows is None else rows
         pos = start + np.arange(rows)
         slot = pos // self.block_size
+        own = np.arange(rows) < n
+        if pool.ring is not None:
+            own &= pos >= start + n - pool.ring * self.block_size
+            slot %= pool.ring
         held = np.asarray(pages, np.int32)
-        own = (np.arange(rows) < n) & (slot < len(held))
-        w_page = np.full(rows, self.num_pages, np.int32)
+        own &= slot < len(held)
+        w_page = np.full(rows, pool.num_pages, np.int32)
         w_page[own] = held[slot[own]]
         return w_page, (pos % self.block_size).astype(np.int32)
 
-    def _scatter(self, rows, w_page, w_off) -> None:
+    def _scatter(self, rows, w_page, w_off, kind: int = 0) -> None:
         # data-plane writes are lock-free by design: the engine's step
         # thread is the single writer, and an appendable (tail) page
         # belongs to exactly one sequence — shared prefix pages are
         # always full, so no write ever lands on an aliased page (the
         # lock guards only the allocator maps)
+        arrays = self.pools[kind].arrays
+        arena = list(self.arena)
+        arena[arrays] = _scatter_arena_jit()(
+            tuple(arena[arrays]), tuple(rows), w_page, w_off)
         # raylint: disable=lock-discipline
-        self.arena = _scatter_arena_jit()(self.arena, tuple(rows), w_page,
-                                          w_off)
+        self.arena = tuple(arena)
 
-    def append(self, pages: List[int], pos: int, *rows) -> None:
+    def append(self, pages: List[int], pos: int, *rows,
+               kind: int = 0) -> None:
         """Write one token's rows (each [n_layer, *row]; K then V for an
         arena of those) at logical position `pos` of a sequence holding
         `pages`."""
         self._scatter([r[None] for r in rows],
-                      *self.write_index(pages, pos, 1))
+                      *self.write_index(pages, pos, 1, kind=kind), kind=kind)
 
     def write_rows(self, pages: List[int], rows, n: int,
-                   start: int = 0) -> None:
+                   start: int = 0, kind: int = 0) -> None:
         """Bulk-write a prefill's rows (one array [n, n_layer, *row] for
-        each array of the arena) for positions [start, start+n) across the
+        each array of the kind) for positions [start, start+n) across the
         sequence's pages (chunked prefill passes start > 0, which need not
         be page-aligned)."""
         self._scatter([r[:n] for r in rows],
-                      *self.write_index(pages, start, n))
+                      *self.write_index(pages, start, n, kind=kind),
+                      kind=kind)
 
     def write_prefill(self, pages: List[int], k_seq, v_seq, n: int,
                       start: int = 0) -> None:
@@ -391,27 +532,30 @@ class PagedKVCache:
     # -- lifecycle --------------------------------------------------------
 
     def assert_quiesced(self) -> None:
-        """Prove zero sequence-live pages. Pages held only by the
-        prefix cache are quiesced state (drain the cache to release
+        """Prove zero sequence-live pages, of every kind. Pages held only
+        by the prefix cache are quiesced state (drain the cache to release
         them); any other holder is a leak."""
         with self._lock:
-            live = {p: hs for p, hs in self._holders.items()
-                    if any(not isinstance(h, _PrefixEntry) for h in hs)}
-            if set(live) != set(self._live):
-                raise KVCacheError(
-                    f"live-page count out of step: {len(self._live)} "
-                    f"counted, {len(live)} held by a sequence")
-            if live:
-                owners = sorted({repr(h) for hs in live.values()
-                                 for h in hs
-                                 if not isinstance(h, _PrefixEntry)})
-                raise KVCacheError(
-                    f"KV page leak: {len(live)} live pages at "
-                    f"quiesce (owners: {owners[:4]})")
-            if len(self._free) + len(self._holders) != self.num_pages:
-                raise KVCacheError(
-                    f"free-list corrupt: {len(self._free)} free + "
-                    f"{len(self._holders)} held != {self.num_pages}")
+            for pool in self.pools:
+                name = pool.kind.name
+                live = {p: hs for p, hs in pool.holders.items()
+                        if any(not isinstance(h, _PrefixEntry) for h in hs)}
+                if set(live) != set(pool.live):
+                    raise KVCacheError(
+                        f"live-page count out of step ({name}): "
+                        f"{len(pool.live)} counted, {len(live)} held by a "
+                        f"sequence")
+                if live:
+                    owners = sorted({repr(h) for hs in live.values()
+                                     for h in hs
+                                     if not isinstance(h, _PrefixEntry)})
+                    raise KVCacheError(
+                        f"KV page leak ({name}): {len(live)} live pages at "
+                        f"quiesce (owners: {owners[:4]})")
+                if len(pool.free) + len(pool.holders) != pool.num_pages:
+                    raise KVCacheError(
+                        f"free-list corrupt ({name}): {len(pool.free)} free "
+                        f"+ {len(pool.holders)} held != {pool.num_pages}")
             if self._slot_owner:
                 owners = sorted(repr(o) for o in self._slot_owner.values())
                 raise KVCacheError(
@@ -423,15 +567,16 @@ class PagedKVCache:
                     f"of {self.num_slots}")
 
     def close(self) -> int:
-        """Drop the arenas. Returns the number of pages and state slots
-        still sequence-live (0 when the engine quiesced cleanly;
-        prefix-cache holds are not leaks — `PrefixCache.drain()` first for
-        a strict zero-held close)."""
+        """Drop the arenas. Returns the number of pages (of every kind) and
+        state slots still sequence-live (0 when the engine quiesced
+        cleanly; prefix-cache holds are not leaks — `PrefixCache.drain()`
+        first for a strict zero-held close)."""
         with self._lock:
             if self._closed:
                 return 0
             self._closed = True
-            leaked = len(self._live) + len(self._slot_owner)
+            leaked = sum(len(pool.live) for pool in self.pools) \
+                + len(self._slot_owner)
             for array in self.arena + self.state:
                 if not array.is_deleted():
                     array.delete()  # the device memory, now
@@ -496,6 +641,10 @@ class PrefixCache:
     """
 
     def __init__(self, kv: PagedKVCache):
+        if len(kv.pools) != 1 or kv.pools[0].ring is not None:
+            raise KVCacheError(
+                "the prefix cache aliases pages of one kind: a ring's pages "
+                "are overwritten as the window passes them")
         self.kv = kv
         # ONE lock with the allocator: atomic lookup+alias+alloc
         self._lock = kv._lock

@@ -729,6 +729,73 @@ def test_chaos_llm_replica_kill_midstream_prefix_chunked():
         ray_tpu.shutdown()
 
 
+def test_chaos_llm_replica_kill_midstream_window_family():
+    """Mid-stream replica kill with pages of two kinds (the AFMoE family:
+    window layers keep a ring of pages a sequence, full layers the whole
+    length). The dead replica's arena went with its process; the survivor
+    replays the stream from the prompt, through chunked prefill and a ring
+    that wraps (36 + 24 tokens against a window of 8), to the SAME stream,
+    and ends with no page of either kind live."""
+    from ray_tpu import serve
+    from ray_tpu.serve.llm import LLMDeployment
+
+    ray_tpu.init(num_cpus=8, num_tpus=0,
+                 object_store_memory=256 * 1024 * 1024)
+    try:
+        class SlowLLM(LLMDeployment):
+            def generate(self, prompt, max_new_tokens=16,
+                         timeout_s=None):
+                for chunk in LLMDeployment.generate(
+                        self, prompt, max_new_tokens, timeout_s):
+                    time.sleep(0.05)
+                    yield chunk
+
+        app = serve.deployment(name="llm", num_replicas=2)(
+            SlowLLM).bind(
+                model="afmoe", seed=0,
+                engine_config={"prefix_cache": 0,
+                               "prefill_chunk": 8, "block_size": 4,
+                               "batch_buckets": (1, 2),
+                               "prefill_buckets": (8, 16)})
+        handle = serve.run(app)
+        ctrl = ray_tpu.get_actor("SERVE_CONTROLLER")
+        ray_tpu.get(ctrl.reconcile_now.remote(), timeout=60)
+
+        rng = np.random.RandomState(18)
+        prompt = [int(t) for t in rng.randint(1, 500, size=36)]
+        n_tokens = 24
+        gen = handle.generate.options(stream=True).remote(
+            prompt, n_tokens)
+        tokens = [next(gen)["token"] for _ in range(4)]
+
+        info = ray_tpu.get(ctrl.get_replicas.remote("llm"), timeout=30)
+        serving = None
+        for r in info["replicas"]:
+            m = ray_tpu.get(r.get_metrics.remote(), timeout=30)
+            if m["ongoing"] >= 1 and serving is None:
+                serving = r
+        assert serving is not None
+        ray_tpu.kill(serving)
+
+        for chunk in gen:                  # survivor replays + resumes
+            tokens.append(chunk["token"])
+        assert len(tokens) == n_tokens
+
+        rerun = handle.generate_once.remote(prompt, n_tokens).result(
+            timeout=120)
+        assert tokens == rerun             # failed-over stream lost nothing
+
+        # the leak gate, both kinds: whoever answers now is alive, has
+        # served a whole stream through a ring that wrapped, holds nothing
+        m = handle.engine_metrics.remote().result(timeout=60)
+        assert m["model"] == "afmoe" and m["kv_pages_window_seq_max"] == 3
+        assert (m["kv_pages_live_window"], m["kv_pages_live_full"],
+                m["kv_pages_live"]) == (0, 0, 0)
+    finally:
+        serve.shutdown()
+        ray_tpu.shutdown()
+
+
 # ---------------------------------------------------------------------------
 # timed wall-clock fault schedules (`at=` grammar) + post-mortem replay
 # ---------------------------------------------------------------------------

@@ -108,6 +108,16 @@ MISTRAL_7B_L20 = dict(
     max_seq_len=2048)
 
 
+def _fake_kv(arena, state=(), pools=None):
+    """What the engine's program builders read of a `PagedKVCache`: the
+    arrays, and where each kind of page has its own (one kind, here)."""
+    import types
+
+    return types.SimpleNamespace(
+        arena=arena, state=state,
+        pools=pools or (types.SimpleNamespace(arrays=slice(0, len(arena))),))
+
+
 def _compile_engine_program(topo, cfg, kind, size, block=16, num_pages=2048):
     """The engine's own `llama` program of one kind and bucket (the model's
     step, then the scatter of its new K/V into the donated arena), compiled
@@ -129,7 +139,7 @@ def _compile_engine_program(topo, cfg, kind, size, block=16, num_pages=2048):
                      cfg.head_dim), jnp.bfloat16)
     engine = types.SimpleNamespace(
         _mod=llama, model_cfg=cfg, _step_counts=(),
-        kv=types.SimpleNamespace(arena=(pages, pages), state=()))
+        kv=_fake_kv((pages, pages)))
     if kind == "decode":
         fn = LLMEngine._make_decode_fn(engine, size)
         args = (params, on_chip((size,)), on_chip((size,)), pages, pages,
@@ -254,7 +264,7 @@ def test_latent_arena_is_updated_in_place_at_kimi_k2_widths(topo, kind, size):
         pages = on_chip((num_pages, cfg.n_layer, block) + row, jnp.bfloat16)
         engine = types.SimpleNamespace(
             _mod=kimi_k2, model_cfg=cfg, _step_counts=kimi_k2.STEP_COUNTS,
-            kv=types.SimpleNamespace(arena=(pages,), state=()))
+            kv=_fake_kv((pages,)))
         table = on_chip((size if kind == "decode" else 1,
                          cfg.max_seq_len // block))
         if kind == "decode":
@@ -331,7 +341,7 @@ def test_sequence_state_arena_is_updated_in_place_at_ling_widths(topo, kind,
     engine = types.SimpleNamespace(
         _mod=ling_hybrid, model_cfg=cfg,
         _step_counts=ling_hybrid.STEP_COUNTS,
-        kv=types.SimpleNamespace(arena=(pages,), state=state))
+        kv=_fake_kv((pages,), state))
     lanes = size if kind == "decode" else 1
     rows = (size,) if kind == "decode" else (1, size)
     fn = LLMEngine._make_decode_fn(engine, size) if kind == "decode" \
@@ -385,7 +395,7 @@ def test_block_programs_fit_the_chip_at_sdar_widths(topo, kind, size):
                   for row in sdar_moe.cache_rows(cfg))
     engine = types.SimpleNamespace(
         _mod=sdar_moe, model_cfg=cfg, _step_counts=sdar_moe.STEP_COUNTS,
-        kv=types.SimpleNamespace(arena=arena, state=()))
+        kv=_fake_kv(arena))
     table = on_chip((size if kind == "decode" else 1,
                      cfg.max_seq_len // block))
     if kind == "decode":
@@ -419,6 +429,79 @@ def test_block_programs_fit_the_chip_at_sdar_widths(topo, kind, size):
         import re
         assert set(re.findall(rf"\w+\[[\d,]*{cfg.vocab_size}[\d,]*\]", text)) \
             == {f"bf16[{cfg.vocab_size},{cfg.d_model}]"}
+
+
+@pytest.mark.parametrize("kind, size", [("decode", 32), ("prefill", 1024),
+                                        ("chunk", 1024)])
+def test_window_and_full_programs_fit_the_chip_at_trinity_widths(topo, kind,
+                                                                 size):
+    """The engine's decode-32, prefill-1,024 and chunk-1,024 programs of the
+    Trinity cell (published widths, 9 layers, 8 of 256 experts, an eighth of
+    the vocabulary; the window kind's 8,224 pages of 7 layers and the full
+    kind's 16,384 of 2): both kinds' K/V arrays alias their outputs and no
+    operation copies an array of their shapes, and the program fits the
+    chip beside its 5.76 GB of weights."""
+    import types
+
+    from benchmark.trinity_cell import afmoe_engine
+    from ray_tpu.models import afmoe
+    from ray_tpu.serve.llm.engine import LLMEngine
+
+    import json
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "trinity-large-ep32-l9.json")) as f:
+        config = json.load(f)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    cfg = afmoe_engine(config)["model_cfg"]
+    block, lanes_max = config["engine"]["block_size"], 32
+
+    def on_chip(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: on_chip(a.shape, a.dtype),
+        jax.eval_shape(afmoe.Afmoe(cfg).init, jax.random.PRNGKey(0),
+                       jnp.ones((1, 16), jnp.int32)))
+    ring = cfg.window // block + 1
+    pages = {"window": lanes_max * ring,
+             "full": config["engine"]["num_pages"]}
+    width = {"window": ring, "full": cfg.max_seq_len // block}
+    arena, pools, kinds = (), (), afmoe.page_kinds(cfg)
+    for name, layers, rows, _ in kinds:
+        pools += (types.SimpleNamespace(
+            arrays=slice(len(arena), len(arena) + len(rows))),)
+        arena += tuple(on_chip((pages[name], layers, block) + row,
+                               jnp.bfloat16) for row in rows)
+    assert [a.shape for a in arena] == [(8224, 7, 16, 8, 128)] * 2 \
+        + [(16384, 2, 16, 8, 128)] * 2
+    engine = types.SimpleNamespace(
+        _mod=afmoe, model_cfg=cfg, _step_counts=afmoe.STEP_COUNTS,
+        kv=_fake_kv(arena, pools=pools))
+    lanes = size if kind == "decode" else 1
+    rows = (size,) if kind == "decode" else (1, size)
+    if kind == "prefill":
+        fn = LLMEngine._make_prefill_fn(engine, size)
+        coords = [on_chip((size,)) for _ in kinds for _ in range(2)]
+    else:
+        fn = LLMEngine._make_decode_fn(engine, size) if kind == "decode" \
+            else LLMEngine._make_chunk_fn(engine, size)
+        coords = [c for name, *_ in kinds for c in (
+            on_chip((lanes, width[name])), on_chip(rows), on_chip(rows))]
+    args = (params, on_chip(rows if kind != "prefill" else (1, size)),
+            on_chip((lanes,)), *arena, *coords)
+    compiled = jax.jit(fn, donate_argnums=(3, 4, 5, 6)).lower(
+        *args).compile()
+    mem = compiled.memory_analysis()
+    held = sum(2 * math.prod(a.shape) for a in arena)
+    assert held <= mem.alias_size_in_bytes < held + 2**22
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    text = compiled.as_text()
+    for array in (arena[0], arena[2]):
+        shape = "bf16[" + ",".join(map(str, array.shape)) + "]"
+        moved = [line.strip()[:120] for line in text.splitlines()
+                 if " copy(" in line and shape in line.split(" copy(")[0]]
+        assert not moved, moved
 
 
 def test_build_mesh_on_tpu_follows_the_topology(topo):

@@ -316,7 +316,7 @@ def _commit_before_whole(monkeypatch):
             left = seq.revealed.count(False)
             if 0 < left <= reveal:
                 w_page[i], w_off[i] = eng.kv.write_index(
-                    seq.pages, seq.pos, length)
+                    seq.pages[0], seq.pos, length)
             elif not left:
                 w_page[i] = eng.kv.num_pages
         args[-3], args[-2] = w_page, w_off

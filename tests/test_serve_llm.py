@@ -345,7 +345,7 @@ def test_decode_lanes_beyond_the_running_set_write_nothing():
         eng.warmup()                       # writes nothing either
         req = eng.submit([5, 9, 3], 6)
         eng.step()                         # the prefill and a decode step
-        pages = list(eng._running[0].pages)
+        pages = list(eng._running[0].pages[0])
         eng.run_until_idle()
         assert len(req.tokens) == 6 and 0 not in pages and len(pages) == 3
         # 3 prompt rows, then the 5 tokens that were fed back
@@ -1345,7 +1345,7 @@ def test_chunked_prefill_matches_oneshot(model):
             # the step that ends the prefill also makes the first pass
             assert (seq.pos, seq.block[:2], seq.revealed[:2]) == \
                 (20, prompt[20:], [True, True])
-            rows = [np.asarray(pages)[seq.pages[:5]] for pages in eng.kv.arena]
+            rows = [np.asarray(pages)[seq.pages[0][:5]] for pages in eng.kv.arena]
             eng.run_until_idle(timeout=120)
             m = eng.metrics()
             got[name] = (req.result(timeout=5), rows, m["chunk_steps"])
@@ -1457,3 +1457,246 @@ def test_neighbour_programs_lower_to_the_text_they_had(model):
         assert got == NEIGHBOUR_PROGRAMS[model]
     finally:
         eng.shutdown()
+
+
+# -- pages by layer kind (the AFMoE family: window and full layers) ---------
+
+def _window_engine(**cfg_kw):
+    """The AFMoE family's tiny model (3 sliding layers of window 8 and a
+    full one, float32) behind an engine of block 4, chunk 8: the window
+    kind's ring is 8 / 4 + 1 = 3 pages a sequence."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.afmoe import AfmoeConfig
+    from ray_tpu.serve.llm import EngineConfig, LLMEngine
+    base = dict(batch_buckets=(1, 2, 4), prefill_buckets=(8,),
+                prefill_chunk=8, block_size=4, num_pages=64, prefix_cache=0)
+    base.update(cfg_kw)
+    cfg = AfmoeConfig.tiny(dtype=jnp.float32, param_dtype=jnp.float32)
+    return LLMEngine(model="afmoe", model_cfg=cfg,
+                     engine_config=EngineConfig(**base), seed=0)
+
+
+def _afmoe_reference_rows(eng, prompt, tokens):
+    """The plain reference's logits rows from which `tokens` were chosen."""
+    import jax
+
+    from benchmark.references import afmoe as ref
+    cfg = eng.model_cfg
+    config = {
+        "num_hidden_layers": cfg.n_layer, "rms_norm_eps": cfg.norm_eps,
+        "hidden_size": cfg.d_model, "mup_enabled": cfg.mup,
+        "num_attention_heads": cfg.n_head,
+        "num_key_value_heads": cfg.n_kv_head, "head_dim": cfg.head_dim,
+        "rope_theta": cfg.rope_theta, "sliding_window": cfg.window,
+        "layer_types": [t + "_attention" for t in cfg.types],
+        "num_experts_per_tok": cfg.top_k, "route_scale": cfg.routed_scale,
+        "score_func": "sigmoid", "route_norm": True, "n_group": 1}
+    ids = np.asarray(list(prompt) + list(tokens[:-1]))
+    with jax.default_matmul_precision("highest"):
+        want = np.asarray(ref.full_logits(eng.params["params"], config, ids))
+    return want[len(prompt) - 1:]
+
+
+def test_short_and_long_in_one_batch_through_pages_of_two_kinds():
+    """Prompts of 5, 13, 30 and 70 tokens in one running set (one-shot and
+    chunked prefill, then decode, 3 to 9 windows of context): every token is
+    the reference's, no sequence ever holds more than the ring's 3 pages of
+    the window kind while the full kind holds its whole length, the counters
+    by kind add up, and both kinds are quiesced at the end."""
+    eng = _window_engine()
+    try:
+        kv = eng.kv
+        assert [(p.kind.name, p.num_pages, p.ring, p.width)
+                for p in kv.pools] == [("window", 12, 3, 3),
+                                       ("full", 64, None, 32)]
+        assert [a.shape[:2] for a in kv.arena] == [(12, 3)] * 2 \
+            + [(64, 1)] * 2
+        rng = np.random.default_rng(0)
+        prompts = [rng.integers(0, 512, n).tolist() for n in (5, 30, 70, 13)]
+        news = (12, 20, 30, 9)
+        reqs = [eng.submit(p, n) for p, n in zip(prompts, news)]
+        held = {}
+        while eng.has_work():
+            eng.step()
+            for seq in eng._running + eng._prefilling:
+                held[seq.req.id] = tuple(len(p) for p in seq.pages)
+            m = eng.metrics()
+            assert m["kv_pages_live"] == m["kv_pages_live_window"] \
+                + m["kv_pages_live_full"]
+        for req, prompt, new in zip(reqs, prompts, news):
+            assert len(req.tokens) == new
+            rows = _afmoe_reference_rows(eng, prompt, req.tokens)
+            assert [int(r.argmax()) for r in rows] == req.tokens
+            total = len(prompt) + new
+            assert held[req.id] == (min(3, -(-total // 4)), -(-total // 4))
+        eng.quiesce()
+        m = eng.metrics()
+        assert m["kv_pages_window_seq_max"] == 3
+        assert m["kv_pages_full_seq_max"] == 25
+        assert (m["kv_pages_live"], m["kv_tokens_live"]) == (0, 0)
+        steps = m["decode_steps"]
+        # no lane's window reaches past 7 cached positions
+        assert 0 < m["decode_context_tokens_window"] <= 7 * 4 * steps
+        assert m["decode_context_tokens_window"] < m["decode_context_tokens"]
+        assert m["decode_kv_pages_window_lane_max"] == 3 * steps
+        assert m["decode_kv_pages_window"] <= 3 * 4 * steps
+        # a sliding layer's walk ends at the ring: its own key and 12 slots
+        assert m["decode_key_slots_window"] <= 3 * 13 * 4 * steps
+        assert m["decode_key_slots_full"] > m["decode_key_slots_window"] / 3
+    finally:
+        assert eng.shutdown() == 0
+
+
+def test_admission_takes_pages_of_both_kinds_or_neither():
+    """A full kind of 12 pages: a request of 40 tokens takes 10 and a ring
+    of 3; the next one of 20 (5 pages) finds 2 and is given nothing, of
+    either kind, until the first ends; `reserve` itself leaves no page
+    behind when a kind runs out."""
+    from ray_tpu.serve.llm.kv_cache import OutOfPagesError
+    eng = _window_engine(num_pages=12, batch_buckets=(1, 2))
+    try:
+        kv = eng.kv
+        assert [p.num_pages for p in kv.pools] == [6, 12]
+        first = eng.submit(list(range(1, 31)), 10)
+        second = eng.submit(list(range(40, 52)), 8)
+        eng.step()
+        assert (kv.free_pages_of(0), kv.free_pages_of(1)) == (3, 2)
+        for _ in range(6):
+            eng.step()
+        # the second request waits, considered and holding nothing
+        assert second.considered_ns is not None and second.admitted_ns is None
+        assert (kv.live_pages_of(0), kv.live_pages_of(1)) == (3, 10)
+        with pytest.raises(OutOfPagesError, match="full"):
+            kv.reserve(20, "other")
+        assert (kv.free_pages_of(0), kv.free_pages_of(1)) == (3, 2)
+        eng.run_until_idle()
+        assert len(first.tokens) == 10 and len(second.tokens) == 8
+        eng.quiesce()
+    finally:
+        assert eng.shutdown() == 0
+
+
+def test_a_cut_sequence_and_a_shed_request_are_counted_in_both_kinds():
+    """What the replica-kill leak gate reads: an engine shut down while a
+    sequence decodes reports that sequence's pages of BOTH kinds (3 of the
+    ring and 10 of the full kind), `assert_quiesced` names the kind that
+    leaks, and a request shed at its deadline before admission held none."""
+    from ray_tpu.serve.llm.kv_cache import KVCacheError
+    eng = _window_engine(batch_buckets=(1,))
+    try:
+        req = eng.submit(list(range(1, 31)), 10)
+        late = eng.submit([7, 8, 9], 4, timeout_s=1e-6)
+        for _ in range(6):
+            eng.step()
+        assert late.error == "deadline passed before admission"
+        assert 0 < len(req.tokens) < 10
+        with pytest.raises(KVCacheError, match=r"KV page leak \(window\)"):
+            eng.kv.assert_quiesced()
+        m = eng.metrics()
+        assert (m["kv_pages_live_window"], m["kv_pages_live_full"]) == (3, 10)
+    finally:
+        assert eng.shutdown() == 13
+
+
+def test_prefix_cache_is_refused_for_a_family_with_window_pages():
+    with pytest.raises(ValueError, match="ring of pages a sequence"):
+        _window_engine(prefix_cache=1)
+    from ray_tpu.serve.llm.kv_cache import (KVCacheError, PagedKVCache,
+                                            PageKind, PrefixCache)
+    kv = PagedKVCache(8, 0, 4, seq_slots=2, kinds=(
+        PageKind("window", 1, ((2, 8),) * 2, 8),))
+    with pytest.raises(KVCacheError, match="one kind"):
+        PrefixCache(kv)
+
+
+@pytest.mark.parametrize("start, n, want_dropped", [
+    (0, 5, 0), (0, 12, 0), (0, 20, 8), (9, 8, 0), (30, 16, 4)])
+def test_a_rings_write_index_wraps_and_drops_what_the_write_overruns(
+        start, n, want_dropped):
+    """Window 8, block 4: a ring of 3 pages (12 rows). Position p goes to
+    the sequence's page (p // 4) mod 3; of a write longer than the ring the
+    oldest positions, which a later one of the same write lands on, are
+    dropped, so no two rows share a place."""
+    from ray_tpu.serve.llm.kv_cache import PagedKVCache, PageKind
+    kv = PagedKVCache(16, 0, 4, seq_slots=2, kinds=(
+        PageKind("window", 1, ((2, 8),) * 2, 8),
+        PageKind("full", 1, ((2, 8),) * 2)), max_seq_len=64)
+    owner = object()
+    held = kv.reserve(start + n, owner)
+    assert len(held[0]) == min(3, -(-(start + n) // 4))
+    w_page, w_off = kv.write_index(held[0], start, n, n + 3, kind=0)
+    kept = w_page[:n] < kv.pools[0].num_pages
+    assert (w_page[n:] == kv.pools[0].num_pages).all()
+    assert int((~kept).sum()) == want_dropped
+    assert kept[want_dropped:].all()
+    pos = start + np.arange(n)
+    np.testing.assert_array_equal(
+        w_page[:n][kept], np.asarray(held[0])[(pos[kept] // 4) % 3])
+    np.testing.assert_array_equal(w_off[:n], pos % 4)
+    places = set(zip(w_page[:n][kept].tolist(), w_off[:n][kept].tolist()))
+    assert len(places) == int(kept.sum())
+    # the full kind writes every position to its own page
+    f_page, _ = kv.write_index(held[1], start, n, kind=1)
+    np.testing.assert_array_equal(f_page, np.asarray(held[1])[pos // 4])
+    kv.release(held, owner)
+    kv.assert_quiesced()
+
+
+def test_serve_llm_end_to_end_with_the_window_family(clean_deployments):
+    """The AFMoE family through the same door: `build_app(model=...)` ->
+    `serve.run` -> `handle.generate`: chunked prefill into pages of two
+    kinds and decode past the window in the replica, the tokens a local
+    engine of the same seed gives, and the counters by kind in the
+    replica's metrics."""
+    from ray_tpu import serve
+    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
+
+    engine_config = {"batch_buckets": (1, 2), "prefill_buckets": (16,),
+                     "prefill_chunk": 16, "num_pages": 32, "block_size": 4,
+                     "prefix_cache": 0}
+    handle = serve.run(serve.llm.build_app(
+        name="llm", num_replicas=1, model="afmoe",
+        engine_config=engine_config))
+    prompt = list(range(3, 40))                      # three chunks of 16
+    streamed = [c["token"] for c in
+                handle.generate.options(stream=True).remote(prompt, 6)]
+    local = LLMEngine(model="afmoe",
+                      engine_config=EngineConfig(**engine_config))
+    try:
+        want = local.submit(prompt, 6)
+        local.run_until_idle()
+        assert streamed == want.result()
+    finally:
+        local.shutdown()
+    m = handle.engine_metrics.remote().result(timeout=60)
+    assert m["model"] == "afmoe" and m["kv_pages_live"] == 0
+    assert m["chunk_steps"] == 3
+    # window 8 in pages of 4: a ring of 3; 43 tokens: 11 pages of the other
+    assert (m["kv_pages_window_seq_max"], m["kv_pages_full_seq_max"]) \
+        == (3, 11)
+    assert (m["kv_pages_live_window"], m["kv_pages_live_full"]) == (0, 0)
+    assert m["decode_moe_pairs_routed"] > 0
+    assert m["decode_key_slots_full"] > 0
+
+
+@pytest.mark.parametrize("n", [30, 50], ids=["oneshot", "chunked"])
+def test_a_write_longer_than_the_ring_still_counts_every_row_a_token(n):
+    """A prefill bucket and a chunk of 32 against a ring of 12 rows: the
+    window kind drops the rows that the same write overruns, the full kind
+    writes them all, and every one of them is a token to the step (routed
+    to its experts, counted): the answer is the reference's."""
+    eng = _window_engine(prefill_buckets=(32,), prefill_chunk=32,
+                         batch_buckets=(1,))
+    try:
+        prompt = np.random.default_rng(n).integers(0, 512, n).tolist()
+        req = eng.submit(prompt, 6)
+        eng.run_until_idle()
+        rows = _afmoe_reference_rows(eng, prompt, req.tokens)
+        assert [int(r.argmax()) for r in rows] == req.tokens
+        m = eng.metrics()
+        # 3 expert layers x 4 experts a token, every prompt token once
+        assert m["prefill_moe_pairs_routed"] == n * 12
+        eng.quiesce()
+    finally:
+        assert eng.shutdown() == 0
