@@ -25,9 +25,11 @@ What it asks of the system that no other family does:
 - Rope turns the query and key of the window layers only; a full layer's
   scores carry no position.
 
-Shared with `llama.py`: `_rms`, the rotation, and the bound of the walk
-over cached key blocks (`key_block_trips`: blocks of `KEY_BLOCK` slots as
-far as the batch's longest lane). Shared with `kimi_k2.py`:
+Shared with `llama.py`: `_rms`, the rotation, and the walk over cached key
+blocks of `KEY_BLOCK` slots: a batch of lanes walks a work list of its live
+(lane, block) pairs, each lane its own blocks and no lane another's
+(`key_block_pairs`); one lane alone (a chunk, the bucket of one) walks as
+far as its own last (`key_block_trips`). Shared with `kimi_k2.py`:
 `parallel.moe.expert_shard_layer` under `sigmoid_topk_route` (the chip's
 share of an expert-parallel layer: `experts_held` of `n_experts` from
 `first_expert` on, the router at its whole width) and `MOE_COUNTS`.
@@ -60,8 +62,10 @@ SLIDING, FULL = "sliding", "full"
 # what each step returns after the cache rows, an int32 vector summed over
 # the layers: the engine adds it to `decode_<name>` / `prefill_<name>`.
 # `key_slots_<kind>` is the key slots a query row of each sequence was
-# scored against in the layers of the kind (the step's own and the cached
-# slots the walk visited, padding among them)
+# scored against in the layers of the kind: the step's own and the cached
+# blocks the walk visited, whole blocks, so a lane's last one counts to its
+# end. In a batch of lanes a pad lane of the bucket counts nothing and no
+# lane counts another's blocks
 STEP_COUNTS = tuple(f"moe_{name}" for name in MOE_COUNTS) \
     + ("key_slots_window", "key_slots_full")
 
@@ -219,6 +223,32 @@ def _fold(state, s, v, dtype):
             preferred_element_type=jnp.float32)
 
 
+def _fold_pairs(state, s, v, lane, live, dtype):
+    """`_fold` for a trip of the work list: pair t's scores s[t] and values
+    v[t] ([T, ...] where `_fold` has [B, ...]) are of lane `lane[t]` where
+    `live[t]`, and of nobody where not. A trip may hold several blocks of
+    one lane and none of another, so the pairs are combined a lane: the
+    lanes' new maxima first (a lane with no pair here keeps its own), every
+    pair's exponentials against its lane's, then the sums and accumulators
+    added a lane through the one-hot [B, T] of `lane` (at `highest`: 1.0 x
+    a float32 must come out that float32)."""
+    m, l, acc = state
+    hot = live[None, :] & (lane[None, :] == jnp.arange(m.shape[0])[:, None])
+    m_new = jnp.maximum(m, jnp.max(jnp.where(
+        hot[:, :, None, None, None], jnp.max(s, axis=-1)[None], NEG_INF),
+        axis=1))
+    p = jnp.exp(s - m_new[lane][..., None])
+    alpha = jnp.exp(m - m_new)
+    hot = hot.astype(jnp.float32)
+    highest = jax.lax.Precision.HIGHEST
+    return m_new, l * alpha + jnp.einsum(
+        "bt,tgrc->bgrc", hot, jnp.sum(p, axis=-1), precision=highest), \
+        acc * alpha[..., None] + jnp.einsum(
+            "bt,tgrcd->bgrcd", hot, jnp.einsum(
+                "tgrck,tkgd->tgrcd", p.astype(dtype), v.astype(dtype),
+                preferred_element_type=jnp.float32), precision=highest)
+
+
 @partial(jax.jit, static_argnames=("window", "scale"))
 def window_attend(q, k_new, v_new, pages, layer, page_table, start,
                   window: Optional[int], scale: float):
@@ -232,8 +262,16 @@ def window_attend(q, k_new, v_new, pages, layer, page_table, start,
     page_table [B, n_pages] of that kind; start [B]. The query's H heads are
     KVH groups of H // KVH scored against K and V as they lie (no repeat).
     One running softmax (`_fold`): the step's own keys first, where a row's
-    own key gives it a real maximum, then `llama.key_block_trips` blocks of
-    the table's slots, gathered from the arena by (page, layer).
+    own key gives it a real maximum, then blocks of the table's slots,
+    gathered from the arena by (page, layer). One lane (a chunk, the bucket
+    of one) walks `llama.key_block_trips` blocks. B lanes walk
+    `llama.key_block_pairs`' list of their live (lane, block) pairs, B
+    pairs a trip whatever their lanes, so that a short lane beside a long
+    one is gathered and scored as far as its own last block and a lane that
+    holds nothing not at all; every array of a trip has the shape it would
+    have with lane t in the place of pair t, and `_fold_pairs` adds the
+    pairs of one lane together before they meet the lane's state: the same
+    sums in another order, no key left out.
 
     Without a window the table's slot s is the sequence's page s. With one
     the table is a ring of n_pages slots: the sequence's page p was written
@@ -246,7 +284,8 @@ def window_attend(q, k_new, v_new, pages, layer, page_table, start,
     see is still there (`kv_cache.py`).
 
     Returns ([B, C, H * D] in q's dtype, the key slots a query row was
-    scored against: int32, C + the loop's trips x the block)."""
+    scored against: int32, C + the walk's blocks x the block; one number
+    where there is one walk, [B] where each lane has its own)."""
     with jax.named_scope("attn_full" if window is None else "attn_window"):
         b, c, h, d = q.shape
         kvh = k_new.shape[2]
@@ -295,8 +334,46 @@ def window_attend(q, k_new, v_new, pages, layer, page_table, start,
                 s = jnp.where(seen[:, None, None], s, NEG_INF)
                 return _fold(state, s, v, q.dtype)
 
-            state = jax.lax.fori_loop(0, trips, cached, state)
-            slots = slots + trips * keys
+            def paired(j, state):
+                # `cached` with pair j * B + t in the place of lane t's
+                # block j: the pair's lane's pages, query, start and last
+                # (a body of its own, for `cached` lowers to the text the
+                # chunk and the bucket of one had: the machine's cache)
+                lane, at, live, begun, newest = (
+                    jax.lax.dynamic_slice_in_dim(a, j * b, b) for a in pairs)
+                ids = table[lane[:, None], at[:, None] * per_block
+                            + jnp.arange(per_block)[None, :]]
+                k = k_pages[ids, layer].reshape(b, keys, kvh, d)
+                v = v_pages[ids, layer].reshape(b, keys, kvh, d)
+                s = jnp.einsum("bcgrd,bkgd->bgrck", qg[lane],
+                               k.astype(q.dtype),
+                               preferred_element_type=f32) * scale
+                slot = at[:, None] * per_block \
+                    + (jnp.arange(keys) // page)[None, :]        # [T, K]
+                held = slot
+                if window is not None:
+                    held = newest[:, None] \
+                        - (newest[:, None] - held) % n_pages
+                pos = held * page + jnp.arange(keys) % page      # [T, K]
+                seen = live[:, None] & (slot < n_pages) & (pos >= 0) \
+                    & (pos < begun[:, None])
+                seen = jnp.broadcast_to(seen[:, None, :], (b, c, keys))
+                if window is not None:
+                    seen &= (begun[:, None] + i[None, :])[:, :, None] \
+                        - pos[:, None, :] < window
+                s = jnp.where(seen[:, None, None], s, NEG_INF)
+                return _fold_pairs(state, s, v, lane, live, q.dtype)
+
+            if b == 1:      # a chunk, the bucket of one: nothing to pair
+                state = jax.lax.fori_loop(0, trips, cached, state)
+                slots = slots + trips * keys
+            else:
+                blocks, lane, at, live, _ = _llama.key_block_pairs(
+                    start, n_pages, page)
+                pairs = (lane, at, live, start[lane], last[lane])
+                state = jax.lax.fori_loop(0, -(-jnp.sum(blocks) // b), paired,
+                                          state)
+                slots = slots + blocks * keys                   # [B]
         _, l, acc = state
         out = acc / jnp.maximum(l, 1e-20)[..., None]   # [B, KVH, R, C, D]
         return out.transpose(0, 3, 1, 2, 4).reshape(b, c, h * d).astype(
@@ -378,7 +455,12 @@ def _window_forward(p, cfg: AfmoeConfig, tokens, start, cache, valid_rows):
         x = x + _rms(y.reshape(b, c, -1), lp["post_mlp_norm"], cfg.norm_eps,
                      dtype)
         counts = counts + n
-        key_slots[name] = key_slots[name] + b * slots
+        if slots.ndim:      # the work list's, a lane: a pad lane's are none
+            if valid_rows is not None:
+                slots = jnp.where(valid_rows.any(axis=1), slots, 0)
+            key_slots[name] = key_slots[name] + jnp.sum(slots)
+        else:
+            key_slots[name] = key_slots[name] + b * slots
         rows[kind][0].append(k)
         rows[kind][1].append(v)
     counts = jnp.concatenate([counts, jnp.stack(

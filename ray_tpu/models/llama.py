@@ -266,6 +266,30 @@ def key_block_trips(positions, n_pages: int, page: int, xp=jnp):
     return trips, keys
 
 
+def key_block_pairs(positions, n_pages: int, page: int, xp=jnp):
+    """The walk of `key_block_trips` as a work list, each lane its own
+    blocks and no lane another's: (blocks [B], lane [W], block [W], live
+    [W], keys a block). Lane b holds `blocks[b]` = `min(ceil(positions[b] /
+    keys), the table's)` blocks of cached keys, none where it holds nothing
+    (a pad lane of the bucket); the live (lane, block) pairs are numbered
+    lane by lane and block by block from 0, `blocks.sum()` of them in a
+    list of W = B x the table's blocks, the most there can be; what lies
+    past them reads lane 0's block 0 and is not live. Pair w is of the
+    first lane whose blocks end past w (`searchsorted(ends, w, "right")`,
+    as W x B compares). `xp` as in `key_block_trips`: the host counts with
+    the program's function."""
+    per_block = max(1, min(KEY_BLOCK // page, n_pages))
+    keys = per_block * page
+    most = -(-n_pages // per_block)
+    blocks = xp.minimum(-(-positions // keys), most)
+    ends = xp.cumsum(blocks)
+    w = xp.arange(positions.shape[0] * most)
+    live = w < ends[-1]
+    lane = xp.where(live, xp.sum(w[:, None] >= ends[None, :], axis=1), 0)
+    block = xp.where(live, w - (ends[lane] - blocks[lane]), 0)
+    return blocks, lane, block, live, keys
+
+
 @partial(jax.jit, static_argnames="scale")
 def paged_attend(q, k_new, v_new, k_pages, v_pages, layer, page_table,
                  positions, scale):

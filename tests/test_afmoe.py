@@ -22,7 +22,8 @@ import pytest
 
 from benchmark.references import afmoe as ref
 from ray_tpu.models import afmoe as A
-from ray_tpu.models.llama import key_block_trips
+from ray_tpu.models.llama import (KEY_BLOCK, key_block_pairs,
+                                  key_block_trips)
 from ray_tpu.parallel.moe import MOE_COUNTS
 from ray_tpu.serve.llm.kv_cache import PagedKVCache, PageKind
 
@@ -182,6 +183,96 @@ def test_prefill_then_paged_decode_match_the_reference(case, how):
         1 + int(trips) * keys)
     kv.release(held, owner)
     kv.assert_quiesced()
+
+
+# cached positions of the lanes of one decode call (pages of 4, so a key
+# block is 64 pages): none (a pad lane of the bucket), under a block, a
+# block to its last slot, past the wrap of a ring of 524 positions, and one
+# three blocks longer than any other
+LANES = (0, 100, KEY_BLOCK, 600, 1400)
+
+
+@pytest.mark.parametrize("window", [None, 8, 520])
+def test_lanes_of_mixed_lengths_walk_their_own_blocks(window):
+    """One decode call over `LANES`: every lane's logits are those of the
+    lane decoded alone (the bucket of one: the loop as far as the longest,
+    which is its own) and the reference's, and the step counts each live
+    lane's own key and its own blocks, not every lane as far as the
+    longest. Without a window every layer is a full one; a window of 8 is a
+    ring of 3 pages, one block; of 520 a ring of 131 pages, three blocks,
+    the last of 3 pages."""
+    cfg = tiny(max_seq_len=2048, window=window or 8,
+               layer_types=() if window else (A.FULL,) * 4)
+    longest = max(LANES)
+    variables, ids, want = make(cfg, n=longest + 1)
+    kv = cache_of(cfg, pages=sum(-(-(n + 1) // BLOCK) for n in LANES),
+                  seqs=len(LANES))
+    live = np.asarray(LANES) > 0
+    tables = [np.zeros((len(LANES), pool.width), np.int32)
+              for pool in kv.pools]
+    with jax.default_matmul_precision("highest"):
+        toks = np.zeros((1, longest + 8), np.int32)
+        toks[0, :longest] = ids[:longest]
+        _, *rows, _ = A.prefill_step(variables, cfg, toks,
+                                     np.asarray([longest], np.int32))
+        for lane, n in enumerate(LANES):
+            if n:       # a position's K and V follow from the tokens before
+                held = kv.reserve(n + 1, lane)
+                write(kv, held, rows, n)
+                for table, pages in zip(tables, held):
+                    table[lane, :len(pages)] = pages
+        positions = np.asarray(LANES, np.int32)
+        tokens = np.where(live, ids[positions], 0).astype(np.int32)
+        logits, *_, counts = A.decode_step(
+            variables, cfg, tokens, positions, *kv.arena, *tables,
+            valid=live)
+        for lane in np.flatnonzero(live):
+            alone, *_ = A.decode_step(
+                variables, cfg, tokens[lane:lane + 1],
+                positions[lane:lane + 1], *kv.arena,
+                *[t[lane:lane + 1] for t in tables],
+                valid=np.ones(1, bool))
+            np.testing.assert_allclose(logits[lane], alone[0], atol=ATOL)
+            np.testing.assert_allclose(logits[lane], want[LANES[lane]],
+                                       atol=ATOL)
+    got = dict(zip(A.STEP_COUNTS, np.asarray(counts).tolist()))
+    for pool in kv.pools:
+        blocks, *_, keys = key_block_pairs(positions, pool.width, BLOCK, np)
+        layers = pool.kind.n_layer
+        assert got[f"key_slots_{pool.kind.name}"] == layers * (
+            live.sum() + blocks.sum() * keys)
+        if pool.kind.window is None:
+            assert blocks.tolist() == [0, 1, 1, 3, 6] and keys == KEY_BLOCK
+            assert got["key_slots_full"] < layers * len(LANES) * (
+                1 + blocks.max() * keys) / 2
+        else:
+            assert blocks.tolist() == {8: [0, 1, 1, 1, 1],
+                                       520: [0, 1, 1, 3, 3]}[window]
+    if not window:
+        assert got["key_slots_window"] == 0
+
+
+@pytest.mark.parametrize("lengths", [(0, 0, 0), (5, 0, 700, 256, 257),
+                                     (1, 1, 1, 1), (9000,), (0, 513, 0, 90)])
+def test_every_live_pair_is_in_the_work_list_once(lengths):
+    """`key_block_pairs`: lane by lane, each lane's blocks in order, each
+    once and first in the list; what is past them is not live and reads
+    lane 0's block 0; trips of B pairs are never more than the walk as far
+    as the longest; the program's form gives the host's list."""
+    positions = np.asarray(lengths, np.int32)
+    b = len(lengths)
+    blocks, lane, at, live, keys = key_block_pairs(positions, 512, 16, np)
+    assert keys == KEY_BLOCK and len(lane) == b * (512 * 16 // KEY_BLOCK)
+    assert blocks.tolist() == [min(-(-n // KEY_BLOCK), 32) for n in lengths]
+    assert -(-int(blocks.sum()) // b) <= int(
+        key_block_trips(positions, 512, 16, np)[0])
+    assert live.tolist() == (np.arange(len(lane)) < blocks.sum()).tolist()
+    assert not (lane[~live].any() or at[~live].any())
+    assert list(zip(lane[live].tolist(), at[live].tolist())) == [
+        (i, j) for i in range(b) for j in range(blocks[i])]
+    for mine, theirs in zip(key_block_pairs(jnp.asarray(positions), 512, 16),
+                            (blocks, lane, at, live, keys)):
+        np.testing.assert_array_equal(mine, theirs)
 
 
 @pytest.mark.parametrize("window", [None, 8])

@@ -1461,7 +1461,7 @@ def test_neighbour_programs_lower_to_the_text_they_had(model):
 
 # -- pages by layer kind (the AFMoE family: window and full layers) ---------
 
-def _window_engine(**cfg_kw):
+def _window_engine(model_kw=None, **cfg_kw):
     """The AFMoE family's tiny model (3 sliding layers of window 8 and a
     full one, float32) behind an engine of block 4, chunk 8: the window
     kind's ring is 8 / 4 + 1 = 3 pages a sequence."""
@@ -1472,7 +1472,8 @@ def _window_engine(**cfg_kw):
     base = dict(batch_buckets=(1, 2, 4), prefill_buckets=(8,),
                 prefill_chunk=8, block_size=4, num_pages=64, prefix_cache=0)
     base.update(cfg_kw)
-    cfg = AfmoeConfig.tiny(dtype=jnp.float32, param_dtype=jnp.float32)
+    cfg = AfmoeConfig.tiny(dtype=jnp.float32, param_dtype=jnp.float32,
+                           **(model_kw or {}))
     return LLMEngine(model="afmoe", model_cfg=cfg,
                      engine_config=EngineConfig(**base), seed=0)
 
@@ -1544,6 +1545,74 @@ def test_short_and_long_in_one_batch_through_pages_of_two_kinds():
         # a sliding layer's walk ends at the ring: its own key and 12 slots
         assert m["decode_key_slots_window"] <= 3 * 13 * 4 * steps
         assert m["decode_key_slots_full"] > m["decode_key_slots_window"] / 3
+    finally:
+        assert eng.shutdown() == 0
+
+
+# The AFMoE family's tiny programs that PR 47 left alone when the decode
+# buckets of two lanes and more took a work list of (lane, key block) pairs
+# (hashes taken on commit ce75fa1, in the manner of `NEIGHBOUR_PROGRAMS`):
+# the one-shot prefill has no cache, the chunk and the bucket of one have
+# one lane, whose own blocks are the batch's longest's.
+AFMOE_ONE_LANE_PROGRAMS = {"prefill8": "f1b725340a027ffd",
+                           "decode1": "54351eb492be0906",
+                           "chunk8": "b820bd39b1a363c3"}
+
+
+@pytest.mark.parametrize("program", sorted(AFMOE_ONE_LANE_PROGRAMS))
+def test_afmoe_one_lane_programs_lower_to_the_text_they_had(program):
+    import hashlib
+
+    import jax
+
+    eng = _window_engine()
+    try:
+        kv = eng.kv
+        if program == "prefill8":
+            fn, args = eng._prefill_fns[8], (
+                np.zeros((1, 8), np.int32), np.ones((1,), np.int32),
+                *kv.arena, *eng._no_rows((8,), None))
+        else:
+            fn, shape = {"decode1": (eng._decode_fns[1], (1,)),
+                         "chunk8": (eng._chunk_fn, (1, 8))}[program]
+            args = (np.zeros(shape, np.int32), np.zeros(1, np.int32),
+                    *kv.arena, *eng._no_rows(shape, 1))
+        text = jax.jit(fn.__wrapped__, donate_argnums=tuple(
+            range(3, 3 + len(kv.arena)))).lower(eng.params, *args).as_text()
+        assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+            == AFMOE_ONE_LANE_PROGRAMS[program]
+    finally:
+        eng.shutdown()
+
+
+def test_one_long_lane_does_not_make_the_short_ones_walk_its_blocks():
+    """A prompt of 600 tokens (three key blocks of 64 pages in the full
+    layer) decoding beside three of a block each: the step scores each
+    lane's own blocks, so `decode_key_slots_full` stays far under lanes x
+    the longest's walk x steps, which is what it read when every lane
+    walked as far as the batch's longest; the tokens are the reference's."""
+    from ray_tpu.models.llama import KEY_BLOCK
+    eng = _window_engine(model_kw={"max_seq_len": 1024}, num_pages=256,
+                         prefill_buckets=(16,), prefill_chunk=64)
+    try:
+        rng = np.random.default_rng(1)
+        prompts = [rng.integers(0, 512, n).tolist() for n in (600, 9, 14, 11)]
+        reqs = [eng.submit(p, 24 if len(p) > 100 else 60) for p in prompts]
+        eng.run_until_idle()
+        for req, prompt in zip(reqs, prompts):
+            rows = _afmoe_reference_rows(eng, prompt, req.tokens)
+            assert [int(r.argmax()) for r in rows] == req.tokens
+        eng.quiesce()
+        m = eng.metrics()
+        # a lane's first token is its prefill's; each decode step scores
+        # its own key and its own blocks (one full layer): the long lane's
+        # three, a short lane's one
+        long_steps, short_steps = 24 - 1, 3 * (60 - 1)
+        longest = -(-600 // KEY_BLOCK)
+        assert m["decode_key_slots_full"] == long_steps + short_steps + (
+            longest * long_steps + short_steps) * KEY_BLOCK
+        assert m["decode_key_slots_full"] \
+            < 4 * (1 + longest * KEY_BLOCK) * m["decode_steps"]
     finally:
         assert eng.shutdown() == 0
 
