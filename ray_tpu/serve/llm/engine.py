@@ -22,11 +22,12 @@ per-sequence page-table rows, scatters its new K/V rows into their pages
 at coordinates the host computed, and returns the arena, which the engine
 stores back for the next call: no K or V crosses the host link. The host
 keeps the allocator, the page tables and the positions. A decode program
-of the `llama` and `gpt` families reads, a layer, the (page, layer) rows
-of the key blocks up to the longest position among its lanes, once, and
-nothing of the rest of the table (`models/llama.py` `paged_attend`); what
-it scored is counted here, on the host, from the positions handed to it
-(`decode_attn_key_slots`, beside `decode_context_tokens`, what it had to).
+of the `llama`, `gpt` and `ouro` families reads, a page layer, the (page,
+layer) rows of the key blocks up to the longest position among its lanes,
+once, and nothing of the rest of the table (`models/llama.py`
+`paged_attend`); what it scored is counted here, on the host, from the
+positions handed to it (`decode_attn_key_slots`, over the arena's layers,
+beside `decode_context_tokens`, what it had to).
 
 Greedy (argmax) sampling keeps generation deterministic — the property
 the continuous-batching equivalence test and the mid-stream chaos
@@ -117,8 +118,10 @@ class ModelFamily:
     decode steps take the arena's arrays and the lanes' slots (`seq_state=`,
     `slots=`), and every step returns the sequences' new states after the
     cache rows. `paged_layers` names the module's function from a config to
-    how many of its layers leave rows in the paged arena (all of them where
-    it is not given). `block_schedule` names the module's function from a
+    how many layers of rows a token leaves in the paged arena (the model's
+    layers where it is not given; fewer where some keep a state instead,
+    more where the stack runs several times a token and each pass keeps its
+    own). `block_schedule` names the module's function from a
     config to (positions a block, positions a pass reveals, the mask token)
     of a family that generates by diffusion over blocks: its prefill steps
     cover the whole blocks of a prompt and return None for logits (a
@@ -158,6 +161,9 @@ MODEL_FAMILIES: Dict[str, ModelFamily] = {
                             block_schedule="block_schedule"),
     "afmoe": ModelFamily("ray_tpu.models.afmoe", "Afmoe", "AfmoeConfig",
                          step_counts="STEP_COUNTS", page_kinds="page_kinds"),
+    "ouro": ModelFamily("ray_tpu.models.ouro", "Ouro", "OuroConfig",
+                        step_counts="STEP_COUNTS",
+                        paged_layers="paged_layers"),
 }
 
 
@@ -1255,8 +1261,7 @@ class LLMEngine:
                             positions, self.max_pages_per_seq,
                             self.kv.block_size, np)
                         self.counters["decode_attn_key_slots"] += \
-                            bb * self.model_cfg.n_layer \
-                            * (int(trips) * keys + 1)
+                            bb * self.kv.n_layer * (int(trips) * keys + 1)
             for seq in finished:
                 self._finish(seq)
             return len(runs)

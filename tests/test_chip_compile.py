@@ -504,6 +504,71 @@ def test_window_and_full_programs_fit_the_chip_at_trinity_widths(topo, kind,
         assert not moved, moved
 
 
+@pytest.mark.parametrize("kind, size", [("decode", 16), ("prefill", 384),
+                                        ("chunk", 1024)])
+def test_looped_programs_fit_the_chip_at_ouro_widths(topo, kind, size):
+    """The engine's decode-16, prefill-384 and chunk-1,024 programs of the
+    Ouro cell (published widths, all 48 layers and the whole vocabulary, four
+    passes a token; the file's pages of 192 page layers): K and V alias
+    their outputs, no operation copies an array of the arena's shape (a
+    slice of it by a traced page layer did, twice 3.9 GB, in the chunk's
+    first draft), and the program fits the chip beside its 5.34 GB of
+    weights (the chunk's 2.9 GB of temporaries are 1.6 GB of new rows and
+    their way into the scatter: it is the tightest of the three)."""
+    import json
+    import types
+
+    from benchmark.ouro_cell import ouro_engine
+    from ray_tpu.models import ouro
+    from ray_tpu.serve.llm.engine import LLMEngine
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "ouro-2.6b.json")) as f:
+        config = json.load(f)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    cfg = ouro_engine(config)["model_cfg"]
+    block = config["engine"]["block_size"]
+
+    def on_chip(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: on_chip(a.shape, a.dtype),
+        jax.eval_shape(ouro.Ouro(cfg).init, jax.random.PRNGKey(0),
+                       jnp.ones((1, 16), jnp.int32)))
+    pages = on_chip((config["engine"]["num_pages"], ouro.paged_layers(cfg),
+                     block, cfg.n_kv_head, cfg.head_dim), jnp.bfloat16)
+    assert pages.shape[1:] == (192, 16, 16, 128)
+    engine = types.SimpleNamespace(
+        _mod=ouro, model_cfg=cfg, _step_counts=ouro.STEP_COUNTS,
+        kv=_fake_kv((pages, pages)))
+    if kind == "prefill":
+        fn = LLMEngine._make_prefill_fn(engine, size)
+        args = (params, on_chip((1, size)), on_chip((1,)), pages, pages,
+                on_chip((size,)), on_chip((size,)))
+    else:
+        lanes = size if kind == "decode" else 1
+        rows = (size,) if kind == "decode" else (1, size)
+        fn = LLMEngine._make_decode_fn(engine, size) if kind == "decode" \
+            else LLMEngine._make_chunk_fn(engine, size)
+        args = (params, on_chip(rows), on_chip((lanes,)), pages, pages,
+                on_chip((lanes, cfg.max_seq_len // block)), on_chip(rows),
+                on_chip(rows))
+    compiled = jax.jit(fn, donate_argnums=(3, 4)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    held = 2 * 2 * math.prod(pages.shape)
+    assert held <= mem.alias_size_in_bytes < held + 2**22
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
+    text = compiled.as_text()
+    shape = "bf16[" + ",".join(map(str, pages.shape)) + "]"
+    moved = [line.strip()[:120] for line in text.splitlines()
+             if " copy(" in line and shape in line.split(" copy(")[0]]
+    assert not moved, moved
+    if kind == "decode":    # a step's own temporaries: the gathered block
+        assert mem.temp_size_in_bytes < 256 * 2**20
+
+
 def test_build_mesh_on_tpu_follows_the_topology(topo):
     """On TPU devices `build_mesh` takes `create_device_mesh`'s assignment
     (a 2x2 torus orders the ring 0,1,3,2), never a plain reshape."""

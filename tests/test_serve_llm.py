@@ -636,7 +636,7 @@ def test_link_counters_hold_ids_tables_and_logits_only(model):
         assert eng.shutdown() == 0
 
 
-@pytest.mark.parametrize("model", ["llama", "kimi_k2"])
+@pytest.mark.parametrize("model", ["llama", "kimi_k2", "ouro"])
 def test_chunk_context_and_key_slot_counters(model):
     """Three chunks of 16 over a prompt of 39: `chunk_context_tokens` is
     the keys the causal mathematics needs (16 + 32 + 39), counted on the
@@ -654,11 +654,19 @@ def test_chunk_context_and_key_slot_counters(model):
         m = eng.metrics()
         assert m["chunk_steps"] == 3 and m["chunk_context_tokens"] == 87
         slots = eng.max_pages_per_seq * eng.kv.block_size
-        layers, lanes = eng.model_cfg.n_layer, 4
+        # the arena's layers: the model's, but for a looped stack, whose
+        # every pass keeps rows of its own (`ouro`: 2 passes)
+        layers, lanes = eng.kv.n_layer, 4
+        assert layers == eng.model_cfg.n_layer * (2 if model == "ouro"
+                                                  else 1)
         assert slots == 128
         if model == "llama":
             # the host counts the decode steps' slots, nothing of prefill
             assert "prefill_attn_key_slots" not in m
+        elif model == "ouro":
+            # its steps count passes and the gate, no slots: the host's
+            # count below is the one there is
+            assert "prefill_key_slots" not in m
         else:
             assert m["prefill_attn_key_slots"] \
                 == layers * (16 + 2 * (slots + 16))
@@ -1769,3 +1777,141 @@ def test_a_write_longer_than_the_ring_still_counts_every_row_a_token(n):
         eng.quiesce()
     finally:
         assert eng.shutdown() == 0
+
+
+# -- more page layers than weight layers (the Ouro family: a looped stack) ----
+
+def _loop_engine(**cfg_kw):
+    """The Ouro family's tiny model (3 layers run 2 times a token, float32)
+    behind an engine of block 4: a token leaves rows in 6 page layers."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models.ouro import OuroConfig
+    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
+
+    base = dict(batch_buckets=(1, 2, 4), prefill_buckets=(16, 32),
+                prefill_chunk=32, block_size=4, num_pages=64)
+    base.update(cfg_kw)
+    return LLMEngine(model="ouro",
+                     model_cfg=OuroConfig.tiny(dtype=jnp.float32,
+                                               param_dtype=jnp.float32),
+                     engine_config=EngineConfig(**base), seed=0)
+
+
+def _loop_reference_rows(eng, prompt, tokens):
+    """The plain reference's logit rows from which `tokens` were chosen."""
+    import flax.linen as nn
+    import jax
+
+    from benchmark.references import ouro as ref
+
+    cfg = eng.model_cfg
+    file = {"num_hidden_layers": cfg.n_layer, "rms_norm_eps": cfg.norm_eps,
+            "num_attention_heads": cfg.n_head,
+            "num_key_value_heads": cfg.n_kv_head, "head_dim": cfg.head_dim,
+            "rope_theta": cfg.rope_theta, "total_ut_steps": cfg.n_pass,
+            "early_exit_threshold": 1}
+    ids = np.asarray(list(prompt) + list(tokens[:-1]))
+    with jax.default_matmul_precision("highest"):
+        rows, _ = ref.full_logits(nn.meta.unbox(eng.params)["params"], file,
+                                  ids)
+    return np.asarray(rows)[len(prompt) - 1:]
+
+
+def test_a_prefix_hit_reuses_every_passes_rows_of_a_shared_page():
+    """Two prompts that share their first 12 tokens (three pages of 4)
+    through the looped family with the prefix cache on: the second aliases
+    the first's three pages, and a page holds the rows of ALL page layers
+    (both passes' of every layer), so its suffix alone is computed (one
+    chunk call) and its tokens are the reference's full pass's."""
+    eng = _loop_engine()
+    try:
+        assert eng.kv.n_layer == 6 and eng.kv.arena[0].shape[1] == 6
+        shared = list(range(5, 17))
+        first = eng.submit(shared + [40, 41, 42], 5)
+        eng.run_until_idle()
+        m0 = eng.metrics()
+        assert (m0["prefill_steps"], m0["chunk_steps"]) == (1, 0)
+        second = eng.submit(shared + [50, 51], 5)
+        eng.run_until_idle()
+        m1 = eng.metrics()
+        # nothing but the suffix went through a program: its prefill was one
+        # chunk window
+        assert (m1["prefill_steps"], m1["chunk_steps"]) == (2, 1)
+        assert m1["prefix_cache_hit_tokens"] - m0["prefix_cache_hit_tokens"] \
+            == 12
+        assert m1["prefill_layer_passes"] - m0["prefill_layer_passes"] \
+            == 2 * 6        # two suffix tokens x 6 page layers
+        for req in (first, second):
+            rows = _loop_reference_rows(eng, req.prompt, req.tokens)
+            assert [int(r.argmax()) for r in rows] == req.tokens
+        eng.quiesce()
+    finally:
+        assert eng.shutdown() == 0
+
+
+def test_admission_is_held_back_by_pages_and_not_by_lanes():
+    """A token costs `n_pass` times a one-pass model's cache, so the page
+    count bounds the batch before `max_running` does: with 12 pages and
+    requests of 20 tokens (5 pages each) two run and the third waits for
+    pages while two of the four lanes stay empty; it runs when the first
+    gives its pages back."""
+    eng = _loop_engine(num_pages=12, prefix_cache=0)
+    try:
+        reqs = [eng.submit(list(range(3 + i, 15 + i)), 8) for i in range(3)]
+        for _ in range(5):
+            eng.step()
+        assert len(eng._running) + len(eng._prefilling) == 2
+        assert len(eng._waiting) == 1 and eng.config.max_running == 4
+        assert eng.kv.free_pages == 2
+        assert reqs[2].considered_ns is not None      # looked at, no pages
+        eng.run_until_idle()
+        assert [len(r.tokens) for r in reqs] == [8, 8, 8]
+        for req in reqs:
+            rows = _loop_reference_rows(eng, req.prompt, req.tokens)
+            assert [int(r.argmax()) for r in rows] == req.tokens
+        m = eng.metrics()
+        assert m["decode_layer_passes"] == 6 * (m["tokens_generated"] - 3)
+        eng.quiesce()
+    finally:
+        assert eng.shutdown() == 0
+
+
+def test_serve_llm_end_to_end_with_the_looped_family(clean_deployments):
+    """The Ouro family through the same door: `build_app(model=...)` ->
+    `serve.run` -> `handle.generate`: prefill and decode over `n_pass *
+    n_layer` page layers in the replica with the prefix cache on, the
+    tokens a local engine of the same seed gives, and the loop's counters in
+    the replica's metrics."""
+    from ray_tpu import serve
+    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
+
+    engine_config = {"batch_buckets": (1, 2), "prefill_buckets": (16, 64),
+                     "prefill_chunk": 64, "num_pages": 32, "block_size": 4}
+    handle = serve.run(serve.llm.build_app(
+        name="llm", num_replicas=1, model="ouro",
+        engine_config=engine_config))
+    prompt = list(range(3, 40))
+    streamed = [c["token"] for c in
+                handle.generate.options(stream=True).remote(prompt, 6)]
+    again = [c["token"] for c in
+             handle.generate.options(stream=True).remote(prompt, 6)]
+    local = LLMEngine(model="ouro",
+                      engine_config=EngineConfig(**engine_config))
+    try:
+        want = local.submit(prompt, 6)
+        local.run_until_idle()
+        assert streamed == again == want.result()
+        layers = local.kv.n_layer
+        assert layers == 2 * local.model_cfg.n_layer
+    finally:
+        local.shutdown()
+    m = handle.engine_metrics.remote().result(timeout=60)
+    assert m["model"] == "ouro" and m["kv_pages_live"] == 0
+    # the second request hit the first's nine full pages (36 tokens) and
+    # computed its last prompt token alone
+    assert m["prefix_cache_hit_tokens"] == 36 and m["chunk_steps"] == 1
+    assert m["prefill_layer_passes"] == layers * (37 + 1)
+    assert m["decode_layer_passes"] == layers * 2 * 5
+    assert 1000 * 10 <= m["decode_exit_pass_milli"] <= 4000 * 10
+    assert m["decode_attn_key_slots"] > 0
