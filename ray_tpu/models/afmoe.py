@@ -223,32 +223,6 @@ def _fold(state, s, v, dtype):
             preferred_element_type=jnp.float32)
 
 
-def _fold_pairs(state, s, v, lane, live, dtype):
-    """`_fold` for a trip of the work list: pair t's scores s[t] and values
-    v[t] ([T, ...] where `_fold` has [B, ...]) are of lane `lane[t]` where
-    `live[t]`, and of nobody where not. A trip may hold several blocks of
-    one lane and none of another, so the pairs are combined a lane: the
-    lanes' new maxima first (a lane with no pair here keeps its own), every
-    pair's exponentials against its lane's, then the sums and accumulators
-    added a lane through the one-hot [B, T] of `lane` (at `highest`: 1.0 x
-    a float32 must come out that float32)."""
-    m, l, acc = state
-    hot = live[None, :] & (lane[None, :] == jnp.arange(m.shape[0])[:, None])
-    m_new = jnp.maximum(m, jnp.max(jnp.where(
-        hot[:, :, None, None, None], jnp.max(s, axis=-1)[None], NEG_INF),
-        axis=1))
-    p = jnp.exp(s - m_new[lane][..., None])
-    alpha = jnp.exp(m - m_new)
-    hot = hot.astype(jnp.float32)
-    highest = jax.lax.Precision.HIGHEST
-    return m_new, l * alpha + jnp.einsum(
-        "bt,tgrc->bgrc", hot, jnp.sum(p, axis=-1), precision=highest), \
-        acc * alpha[..., None] + jnp.einsum(
-            "bt,tgrcd->bgrcd", hot, jnp.einsum(
-                "tgrck,tkgd->tgrcd", p.astype(dtype), v.astype(dtype),
-                preferred_element_type=jnp.float32), precision=highest)
-
-
 @partial(jax.jit, static_argnames=("window", "scale"))
 def window_attend(q, k_new, v_new, pages, layer, page_table, start,
                   window: Optional[int], scale: float):
@@ -269,7 +243,7 @@ def window_attend(q, k_new, v_new, pages, layer, page_table, start,
     pairs a trip whatever their lanes, so that a short lane beside a long
     one is gathered and scored as far as its own last block and a lane that
     holds nothing not at all; every array of a trip has the shape it would
-    have with lane t in the place of pair t, and `_fold_pairs` adds the
+    have with lane t in the place of pair t, and `llama.fold_pairs` adds the
     pairs of one lane together before they meet the lane's state: the same
     sums in another order, no key left out.
 
@@ -362,7 +336,7 @@ def window_attend(q, k_new, v_new, pages, layer, page_table, start,
                     seen &= (begun[:, None] + i[None, :])[:, :, None] \
                         - pos[:, None, :] < window
                 s = jnp.where(seen[:, None, None], s, NEG_INF)
-                return _fold_pairs(state, s, v, lane, live, q.dtype)
+                return _llama.fold_pairs(state, s, v, lane, live, q.dtype)
 
             if b == 1:      # a chunk, the bucket of one: nothing to pair
                 state = jax.lax.fori_loop(0, trips, cached, state)

@@ -279,12 +279,12 @@ def prefill_step(variables, cfg: GPTConfig, tokens, true_len):
     return next_logits, k, v
 
 
-def key_block_trips(positions, n_pages: int, page: int, xp=jnp):
-    """`llama.key_block_trips`: this family's decode step walks the cached
-    keys with `llama.paged_attend` too."""
-    from ray_tpu.models.llama import key_block_trips as trips  # import cycle
+def decode_key_walk(cfg, positions, n_pages: int, page: int, xp=jnp):
+    """`llama.key_block_walk` of a group of one: this family's decode step
+    walks the cached keys with `llama.paged_attend` too."""
+    from ray_tpu.models.llama import key_block_walk  # import cycle
 
-    return trips(positions, n_pages, page, xp)
+    return key_block_walk(positions, n_pages, page, 1, xp)
 
 
 def decode_step(variables, cfg: GPTConfig, tokens, positions,

@@ -213,9 +213,9 @@ class Llama(nn.Module):
 #     attention (`paged_attend`) takes the whole page arena, the layer's
 #     index and the per-sequence page-table rows, and reads the K and V of
 #     the live pages once: the token's own key, then the cached slots a key
-#     block at a time as far as the batch's longest sequence reaches. No
-#     layer of the arena is sliced out, no contiguous or repeated KV copy
-#     is made.
+#     block at a time, each lane as far as its own last block (a work list
+#     of (lane, block) pairs; one lane alone walks in a loop). No layer of
+#     the arena is sliced out, no contiguous or repeated KV copy is made.
 
 NEG_INF = -1e30
 
@@ -266,7 +266,8 @@ def key_block_trips(positions, n_pages: int, page: int, xp=jnp):
     return trips, keys
 
 
-def key_block_pairs(positions, n_pages: int, page: int, xp=jnp):
+def key_block_pairs(positions, n_pages: int, page: int, xp=jnp,
+                    key_block: int = KEY_BLOCK):
     """The walk of `key_block_trips` as a work list, each lane its own
     blocks and no lane another's: (blocks [B], lane [W], block [W], live
     [W], keys a block). Lane b holds `blocks[b]` = `min(ceil(positions[b] /
@@ -276,9 +277,11 @@ def key_block_pairs(positions, n_pages: int, page: int, xp=jnp):
     list of W = B x the table's blocks, the most there can be; what lies
     past them reads lane 0's block 0 and is not live. Pair w is of the
     first lane whose blocks end past w (`searchsorted(ends, w, "right")`,
-    as W x B compares). `xp` as in `key_block_trips`: the host counts with
-    the program's function."""
-    per_block = max(1, min(KEY_BLOCK // page, n_pages))
+    as W x B compares). `key_block`: the list's block, `KEY_BLOCK` unless
+    the caller settled another for its trips (`paged_attend`'s
+    `pair_block`). `xp` as in `key_block_trips`: the host counts with the
+    program's function."""
+    per_block = max(1, min(key_block // page, n_pages))
     keys = per_block * page
     most = -(-n_pages // per_block)
     blocks = xp.minimum(-(-positions // keys), most)
@@ -288,6 +291,89 @@ def key_block_pairs(positions, n_pages: int, page: int, xp=jnp):
     lane = xp.where(live, xp.sum(w[:, None] >= ends[None, :], axis=1), 0)
     block = xp.where(live, w - (ends[lane] - blocks[lane]), 0)
     return blocks, lane, block, live, keys
+
+
+# `paged_attend`'s work list: a trip holds `PAIRS_A_LANE` (live or dead) pairs
+# a lane of the bucket, of `pair_block` cached keys each. A list bounds a
+# lane's walk only where a trip is no wider than the lanes have pairs to fill
+# it: B pairs of `KEY_BLOCK` are, for sixteen lanes of one or two hundred
+# keys, the walk to the batch's longest over again. Settled on the chip with
+# `paged_attend` alone at Ouro's and at Mistral's widths, 16 lanes of their
+# cells' decks (PERF.md §6, PR 49): the time goes with the slots scored, a
+# trip's own cost is 5-10 us
+PAIRS_A_LANE = 4
+
+
+def pair_block(group: int) -> int:
+    """Cached keys a block of the list, by the query heads a K/V head.
+    Ungrouped heads (`group` 1: Ouro, GPT-2) are scored without the MXU, at
+    the same cost a slot whatever the block, so the block is a page of 16
+    and a lane's last block wastes 8 slots on average; a group's product
+    wants 64 keys and more a block (Mistral's 4 x 128 by 128 x keys: blocks
+    of 16 cost what the walk to the longest did, 64 and 128 two thirds)."""
+    return 16 if group == 1 else 64
+
+
+def key_block_walk(positions, n_pages: int, page: int, group: int, xp=jnp):
+    """What `paged_attend` walks of the cached keys, for `positions` [B] and
+    `group` query heads a K/V head: (trips, blocks a trip, keys a block, the
+    work list or None). One lane walks `key_block_trips` blocks of
+    `KEY_BLOCK`, one a trip, and has no list. Two lanes and more walk the
+    list of their live (lane, block) pairs (`key_block_pairs` at
+    `pair_block(group)`: lane, block, live, each a whole number of trips
+    long), `PAIRS_A_LANE` x B of them a trip whatever their lanes, `ceil(
+    pairs / that)` trips: the last trip's pairs past the list's end are dead
+    and are scored all the same. So a layer scores the lanes' own keys +
+    trips x blocks a trip x keys a block slots, which is what the engine
+    counts on the host through `decode_key_walk` (`decode_attn_key_slots`)."""
+    lanes = positions.shape[0]
+    if lanes == 1:
+        trips, keys = key_block_trips(positions, n_pages, page, xp)
+        return trips, 1, keys, None
+    blocks, *pairs, keys = key_block_pairs(positions, n_pages, page, xp,
+                                           pair_block(group))
+    width = PAIRS_A_LANE * lanes
+    short = -len(pairs[0]) % width
+    if short:
+        pairs = [xp.pad(a, (0, short)) for a in pairs]
+    return -(-xp.sum(blocks) // width), width, keys, tuple(pairs)
+
+
+def decode_key_walk(cfg, positions, n_pages: int, page: int, xp=jnp):
+    """`key_block_walk` of this family's decode step (`ouro`'s too), by the
+    name under which the engine asks a family's module for it."""
+    return key_block_walk(positions, n_pages, page,
+                          cfg.n_head // cfg.n_kv_head, xp)
+
+
+def fold_pairs(state, s, v, lane, live, dtype):
+    """A trip of a work list folded into the lanes' running softmax state =
+    (m, l [B, G, R, ...], acc [B, G, R, ..., D]), float32: pair t's scores
+    s[t] ([T, G, R, ..., K], masked keys at NEG_INF) and values v[t]
+    ([T, K, G, D]) are of lane `lane[t]` where `live[t]`, and of nobody
+    where not. A trip may hold several blocks of one lane and none of
+    another, so the pairs are combined a lane: the lanes' new maxima first
+    (a lane with no pair here keeps its own), every pair's exponentials
+    against its lane's, then the sums and accumulators added a lane through
+    the one-hot [B, T] of `lane` (at `highest`: 1.0 x a float32 must come
+    out that float32). Once a row's maximum is a real score, a masked key
+    weighs exp(NEG_INF - m) = 0 exactly. (`...`: the chunk's positions in
+    `afmoe.window_attend`, nothing in `paged_attend`.)"""
+    m, l, acc = state
+    hot = live[None, :] & (lane[None, :] == jnp.arange(m.shape[0])[:, None])
+    m_new = jnp.maximum(m, jnp.max(jnp.where(
+        hot[(...,) + (None,) * (m.ndim - 1)], jnp.max(s, axis=-1)[None],
+        NEG_INF), axis=1))
+    p = jnp.exp(s - m_new[lane][..., None])
+    alpha = jnp.exp(m - m_new)
+    hot = hot.astype(jnp.float32)
+    highest = jax.lax.Precision.HIGHEST
+    return m_new, l * alpha + jnp.einsum(
+        "bt,t...->b...", hot, jnp.sum(p, axis=-1), precision=highest), \
+        acc * alpha[..., None] + jnp.einsum(
+            "bt,t...->b...", hot, jnp.einsum(
+                "tgr...k,tkgd->tgr...d", p.astype(dtype), v.astype(dtype),
+                preferred_element_type=jnp.float32), precision=highest)
 
 
 @partial(jax.jit, static_argnames="scale")
@@ -304,11 +390,17 @@ def paged_attend(q, k_new, v_new, k_pages, v_pages, layer, page_table,
     pages (no repeat). One running softmax (maximum, sum, accumulator in
     float32) over blocks of keys: the token's own key first, which gives
     every row a real maximum, so a masked key weighs exp(NEG_INF - m) = 0
-    exactly; then `key_block_trips` blocks of cached slots, each gathered
-    from the arena by (page, layer) and masked by each row's own position.
-    Same mathematics as `full_attention` (NEG_INF mask, maximum
-    subtracted, 1e-20 sum floor), so decode logits track the full forward
-    to float tolerance. Returns [B, H, D] in q's dtype.
+    exactly; then the trips of `key_block_walk`, each block gathered from
+    the arena by (page, layer) and masked by its own lane's position. One
+    lane (the bucket of one) walks its blocks of `KEY_BLOCK` in a loop. B
+    lanes walk the work list of their live (lane, block) pairs, a trip's
+    pairs in the place of the lanes, so that a short lane beside a long one
+    is gathered and scored as far as its own last block and a lane that
+    holds nothing not at all; `fold_pairs` adds the pairs of one lane
+    together before they meet the lane's state: the same sums in another
+    order, no key left out. Same mathematics as `full_attention` (NEG_INF
+    mask, maximum subtracted, 1e-20 sum floor), so decode logits track the
+    full forward to float tolerance. Returns [B, H, D] in q's dtype.
 
     Jitted on its own, with the layer index as an operand, so that a
     decode step traces it once for all its layers: XLA inlines the calls
@@ -325,7 +417,8 @@ def paged_attend(q, k_new, v_new, k_pages, v_pages, layer, page_table,
                      preferred_element_type=f32) * scale
     state = (own, jnp.ones_like(own), jnp.broadcast_to(
         v_new.astype(f32)[:, :, None], qg.shape))
-    trips, keys = key_block_trips(positions, n_pages, page)
+    trips, width, keys, pairs = key_block_walk(positions, n_pages, page,
+                                               h // kvh)
     per_block = keys // page
     table = jnp.pad(page_table, ((0, 0), (0, -n_pages % per_block)))
 
@@ -348,7 +441,33 @@ def paged_attend(q, k_new, v_new, k_pages, v_pages, layer, page_table,
             preferred_element_type=f32)
         return m_new, l, acc
 
-    _, l, acc = jax.lax.fori_loop(0, trips, cached, state)
+    if pairs is not None:
+        # a row a pair, made here and not a trip (it is the same in every
+        # layer of a step, and a trip's body, one a layer in the program,
+        # stays small): its lane, the keys its lane holds from the block's
+        # first slot on (0 for a dead pair), its page ids
+        lane, at, live = pairs
+        listed = jnp.concatenate([
+            lane[:, None],
+            jnp.where(live, positions[lane] - at * keys, 0)[:, None],
+            table[lane[:, None], at[:, None] * per_block
+                  + jnp.arange(per_block)[None, :]]], axis=1)
+
+    def paired(j, state):
+        # `cached` with pair j * width + t in the place of lane t's block j:
+        # the pair's lane's query, pages and mask
+        rows = jax.lax.dynamic_slice_in_dim(listed, j * width, width)
+        lane, held, ids = rows[:, 0], rows[:, 1], rows[:, 2:]
+        k = k_pages[ids, layer].reshape(width, keys, kvh, d).astype(q.dtype)
+        v = v_pages[ids, layer].reshape(width, keys, kvh, d).astype(q.dtype)
+        s = jnp.einsum("tgrd,tkgd->tgrk", qg[lane], k,
+                       preferred_element_type=f32) * scale
+        seen = jnp.arange(keys)[None, :] < held[:, None]
+        s = jnp.where(seen[:, None, None, :], s, NEG_INF)
+        return fold_pairs(state, s, v, lane, held > 0, q.dtype)
+
+    _, l, acc = jax.lax.fori_loop(
+        0, trips, cached if pairs is None else paired, state)
     out = acc / jnp.maximum(l, 1e-20)[..., None]
     return out.reshape(b, h, d).astype(q.dtype)
 
@@ -505,9 +624,9 @@ def decode_step(variables, cfg: LlamaConfig, tokens, positions,
     page_table: [B, n_pages] page ids per logical block (rows padded with
     any valid page id — masked). What a layer reads of the cache, in order
     (`paged_attend`): nothing for the token's own key and value, which are
-    scored first; then `key_block_trips(positions)` blocks of `KEY_BLOCK`
-    slots, the (page, layer) rows of those pages alone, whatever the
-    table's length. Returns (logits [B, V], new_k [B, L, KVH, D], new_v
+    scored first; then the trips of `key_block_walk(positions)`, the (page,
+    layer) rows of each lane's own key blocks alone, whatever the table's
+    length. Returns (logits [B, V], new_k [B, L, KVH, D], new_v
     [B, L, KVH, D]); the caller appends new_k/new_v into each sequence's
     tail page.
     """

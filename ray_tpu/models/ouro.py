@@ -47,10 +47,10 @@ import jax.numpy as jnp
 from flax.linen.initializers import constant, ones
 
 from ray_tpu.models.kimi_k2 import _swiglu, unboxed_params
-# `key_block_trips` by its own name: the engine asks a family's module for it
+# `decode_key_walk` by its own name: the engine asks a family's module for it
 # and counts `decode_attn_key_slots` on the host with the program's function
 from ray_tpu.models.llama import (_rms, _rope_chunk,
-                                  chunk_valid_mask, key_block_trips,
+                                  chunk_valid_mask, decode_key_walk,
                                   paged_attend, paged_attend_chunk,
                                   rope_tables)
 from ray_tpu.parallel.ring_attention import full_attention
@@ -60,7 +60,7 @@ from ray_tpu.parallel.ring_attention import full_attention
 # tokens: `layer_passes` the layer applications (`n_pass * n_layer` a token),
 # `exit_pass_milli` 1,000 x the gate's expected exit pass `sum_t (t+1) p_t`.
 # (The key slots a decode step scores are the host's `decode_attn_key_slots`,
-# counted over the arena's layers with `key_block_trips`.)
+# counted over the arena's layers with `decode_key_walk`.)
 STEP_COUNTS = ("layer_passes", "exit_pass_milli")
 
 

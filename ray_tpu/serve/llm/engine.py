@@ -23,11 +23,12 @@ at coordinates the host computed, and returns the arena, which the engine
 stores back for the next call: no K or V crosses the host link. The host
 keeps the allocator, the page tables and the positions. A decode program
 of the `llama`, `gpt` and `ouro` families reads, a page layer, the (page,
-layer) rows of the key blocks up to the longest position among its lanes,
-once, and nothing of the rest of the table (`models/llama.py`
-`paged_attend`); what it scored is counted here, on the host, from the
-positions handed to it (`decode_attn_key_slots`, over the arena's layers,
-beside `decode_context_tokens`, what it had to).
+layer) rows of each lane's own key blocks, once, in trips of a work list of
+(lane, block) pairs, and nothing of the rest of the table (`models/llama.py`
+`paged_attend`; the bucket of one walks its one lane's blocks in a loop);
+what it scored is counted here, on the host, from the positions handed to
+it (`decode_attn_key_slots`, over the arena's layers, beside
+`decode_context_tokens`, what it had to).
 
 Greedy (argmax) sampling keeps generation deterministic — the property
 the continuous-batching equivalence test and the mid-stream chaos
@@ -447,9 +448,9 @@ class LLMEngine:
         self._step_counts: Tuple[str, ...] = tuple(
             getattr(mod, family.step_counts)) if family.step_counts else ()
         # a family whose decode step is `llama.paged_attend` names the
-        # function that bounds its walk over the cached keys: the engine
+        # function that lays out its walk over the cached keys: the engine
         # calls it on the host to count what the program scored
-        self._key_trips = getattr(mod, "key_block_trips", None)
+        self._key_walk = getattr(mod, "decode_key_walk", None)
         self.model_name = model
         self._mod = mod
         cfg = (engine_config or EngineConfig()).resolved(
@@ -607,10 +608,11 @@ class LLMEngine:
                         f"decode_kv_pages_{pool.kind.name}_lane_max"] = 0
                     self.counters[
                         f"decode_context_tokens_{pool.kind.name}"] = 0
-        if self._key_trips is not None:
+        if self._key_walk is not None:
             # the key slots a decode step's query rows were scored
             # against, padding included, over lanes and layers: the
-            # token's own and the key blocks the program walked
+            # running lanes' own and every (lane, key block) pair of every
+            # trip the program ran, the last trip's dead pairs too
             self.counters["decode_attn_key_slots"] = 0
         # per-bucket compiled_step dispatch counts: (kind, bucket) ->
         # calls. Every entry maps 1:1 onto one AOT executable, so the
@@ -1253,15 +1255,18 @@ class LLMEngine:
                     self._held.append((seq.req, seq.req._record(tok), tok))
                     if self._seq_finished(seq, tok):
                         finished.append(seq)
+                key_slots = 0
+                if self._key_walk is not None:
+                    trips, width, keys, _ = self._key_walk(
+                        self.model_cfg, positions, self.max_pages_per_seq,
+                        self.kv.block_size, np)
+                    key_slots = self.kv.n_layer * (
+                        len(runs) + int(trips) * width * keys)
                 with self._lock:
                     self.counters["decode_steps"] += 1
                     self.counters["decode_context_tokens"] += context
-                    if self._key_trips is not None:
-                        trips, keys = self._key_trips(
-                            positions, self.max_pages_per_seq,
-                            self.kv.block_size, np)
-                        self.counters["decode_attn_key_slots"] += \
-                            bb * self.kv.n_layer * (int(trips) * keys + 1)
+                    if key_slots:
+                        self.counters["decode_attn_key_slots"] += key_slots
             for seq in finished:
                 self._finish(seq)
             return len(runs)

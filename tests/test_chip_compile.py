@@ -201,10 +201,12 @@ def test_decode_program_reads_live_pages_once(topo, widths):
     they lie there, so no array is one layer of the whole arena
     (`[2048, 16, KVH, D]`: the per-layer slice cost a sixth of the device's
     time, at heads of 64 too), none carries a repeated head axis over
-    cached keys (`[16, keys, KVH, H/KVH, D]`, `[16, keys, H, D]`), and the
-    largest over keys is one block's gather. At Mistral's widths the
-    program's temporaries are 0.04 GB (2.5 GB before: every slot of every
-    table row gathered, repeated four times and read twice)."""
+    cached keys (`[16, keys, KVH, H/KVH, D]`, `[16, keys, H, D]`, nor with a
+    trip's 64 pairs in the lanes' place), and the largest over keys is one
+    trip's gather: 64 (lane, key block) pairs of the work list, a block of
+    64 keys. At Mistral's widths the program's temporaries are 0.04 GB (2.5
+    GB before: every slot of every table row gathered, repeated four times
+    and read twice)."""
     cfg = llama.LlamaConfig(**MISTRAL_7B_L20) if widths == "mistral_7b_l20" \
         else llama.LlamaConfig.llama_125m(dtype=jnp.bfloat16,
                                           param_dtype=jnp.bfloat16)
@@ -217,13 +219,15 @@ def test_decode_program_reads_live_pages_once(topo, widths):
     assert not [a for a in held if a[1] == layer_slice]
     # [B, G, R, D] is the accumulator: a key count is what is left of 16
     # and of the head counts
-    repeated = [a for a in held if a[1][0] == size and (
+    width = llama.PAIRS_A_LANE * size
+    repeated = [a for a in held if a[1][0] in (size, width) and (
         (len(a[1]) == 5 and a[1][2:] == (kvh, h // kvh, d))
         or (len(a[1]) == 4 and a[1][2:] == (h, d)))]
     assert not repeated, repeated
-    over_keys = [a for a in held if a[1][0] == size and len(a[1]) >= 4
+    over_keys = [a for a in held if a[1][0] == width and len(a[1]) >= 4
                  and a[1][-2:] == (kvh, d)]
-    assert over_keys and max(a[1][1] for a in over_keys) == llama.KEY_BLOCK
+    assert over_keys and max(a[1][1] for a in over_keys) \
+        == llama.pair_block(h // kvh)
     if widths == "mistral_7b_l20":
         assert compiled.memory_analysis().temp_size_in_bytes < 128 * 2**20
 
