@@ -32,7 +32,8 @@ it (`decode_attn_key_slots`, over the arena's layers, beside
 
 Greedy (argmax) sampling keeps generation deterministic — the property
 the continuous-batching equivalence test and the mid-stream chaos
-replay both lean on.
+replay both lean on. A decode program ends in that choice and returns a
+token id a lane; a prefill returns its last row of logits, one a request.
 """
 
 from __future__ import annotations
@@ -588,7 +589,7 @@ class LLMEngine:
         }
         self.counters.update(dict.fromkeys(_GAP_KEYS, 0))
         # what the family's steps count on the device (`step_counts`),
-        # fetched with the logits: decode steps and prefill units apart
+        # fetched with the first output: decode steps and prefill units apart
         for name in self._step_counts:
             self.counters[f"decode_{name}"] = 0
             self.counters[f"prefill_{name}"] = 0
@@ -630,7 +631,8 @@ class LLMEngine:
 
     # Each program is the model's step, then `scatter_arena` of the step's
     # new cache rows into the donated arena, which it returns after the
-    # logits (and before the step's counts, where the family has any). The
+    # logits (a decode program: after the tokens chosen from them) and
+    # before the step's counts, where the family has any. The
     # arena's arrays are `rest[:n]`; a row is a token's where it is written.
     # A family that keeps sequence state has its `m` arrays next and the
     # lanes' slots last: the step's new states, which follow its cache
@@ -663,6 +665,11 @@ class LLMEngine:
         return fn
 
     def _make_decode_fn(self, batch: int):
+        """A token family's decode step. Its first output is the tokens it
+        chose, int32 [batch], and not the logits they were chosen from:
+        greedy sampling needs no more of a step on the host."""
+        import jax.numpy as jnp
+
         mod, n, m = self._mod, len(self.kv.arena), len(self.kv.state)
         counted = bool(self._step_counts)
         cfg, pools = self.model_cfg, self.kv.pools
@@ -675,7 +682,10 @@ class LLMEngine:
                 variables, cfg, tokens, positions, *arena, *coords[0::3],
                 **_state_args(state, *slots),
                 **_valid_rows(counted, pools, arena, coords[1::3]))
-            return (logits,) + _scatter_kinds(
+            # the greedy choice, in the logits' own dtype: the lowest index
+            # wins a tie and a NaN counts as the largest, as in np.argmax
+            chosen = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            return (chosen,) + _scatter_kinds(
                 pools, arena, out[:n], coords[1::3], coords[2::3]) \
                 + scatter_state(state, out[n:n + m], *slots) \
                 + tuple(out[n + m:])
@@ -778,10 +788,11 @@ class LLMEngine:
 
     def _call(self, fn, args):
         """One call of a program. `args` hold the arena, donated: its
-        successor, which follows the logits among the outputs, goes back
-        into `self.kv` (the pages' arrays, then the sequence states').
-        Returns the logits, still on the device, and what the family's
-        step counted (a tuple, empty for most families)."""
+        successor, which follows the first output, goes back into `self.kv`
+        (the pages' arrays, then the sequence states'). Returns the first
+        output (a prefill's logits, a decode step's chosen tokens), still on
+        the device, and what the family's step counted (a tuple, empty for
+        most families)."""
         out = fn(*args)
         self._hand_over_held()
         n = len(self.kv.arena)
@@ -1196,20 +1207,24 @@ class LLMEngine:
 
     def _decode_forward(self, fn, args):
         """One decode call: the call, which leaves the written rows' K and
-        V in their pages, the wait, the logits (a block pass's two arrays)
-        to the host. `args` hold
+        V in their pages, the wait, the chosen tokens (a block pass's two
+        arrays) to the host. `args` hold
         the arena, donated: its successor goes back into `self.kv`."""
         phase = self._phases.phase
         with phase("decode_dispatch"):
-            logits, counts = self._call(fn, args)
+            chosen, counts = self._call(fn, args)
+            # a block pass's (token, probability), else a token a lane
+            pair = isinstance(chosen, tuple)
+            first = chosen if pair else (chosen,)
+            # small arrays: their way to the host starts with the call, so
+            # the fetch below finds them there once the wait is over
+            for out in (*first, *counts):
+                out.copy_to_host_async()
         with phase("decode_device_wait"):
-            # the np.asarray below would block on the logits anyway
-            self._block_until_ready((logits, self.kv.arena, self.kv.state))
+            # the np.asarray below would block on the tokens anyway
+            self._block_until_ready((chosen, self.kv.arena, self.kv.state))
         with phase("decode_fetch"):
-            # a block pass's (token, probability), else the logits
-            pair = isinstance(logits, tuple)
-            fetched = tuple(np.asarray(a)
-                            for a in (logits if pair else (logits,)))
+            fetched = tuple(np.asarray(a) for a in first)
             self._count_link("decode_link_bytes", *fetched, *args)
             self._add_step_counts("decode", counts, "decode_link_bytes")
             return fetched if pair else fetched[0]
@@ -1238,7 +1253,7 @@ class LLMEngine:
             with phase("decode_kv_append"):
                 self._write_coords(runs, coords)
             self._note_call("decode", bb)
-            logits = self._decode_forward(
+            chosen = self._decode_forward(
                 self._decode_fns[bb],
                 (self.params, tokens, positions,
                  *self.kv.arena, *self.kv.state, *coords,
@@ -1250,7 +1265,7 @@ class LLMEngine:
                     seq.pos += 1
             finished = []
             with phase("decode_sample"):
-                toks = np.argmax(logits[:len(runs)], axis=-1).tolist()
+                toks = chosen[:len(runs)].tolist()
                 for seq, tok in zip(runs, toks):
                     self._held.append((seq.req, seq.req._record(tok), tok))
                     if self._seq_finished(seq, tok):

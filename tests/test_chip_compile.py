@@ -118,6 +118,15 @@ def _fake_kv(arena, state=(), pools=None):
         pools=pools or (types.SimpleNamespace(arrays=slice(0, len(arena))),))
 
 
+def _assert_returns_a_token_a_lane(compiled, lanes):
+    """A decode program's first output is the tokens it chose, int32
+    [lanes], and no row of logits is among its outputs."""
+    first, *rest = jax.tree_util.tree_leaves(compiled.out_info)
+    assert (first.dtype, first.shape) == (jnp.int32, (lanes,))
+    assert not [a for a in rest if jnp.issubdtype(a.dtype, jnp.floating)
+                and a.shape[:1] == (lanes,) and a.ndim == 2]
+
+
 def _compile_engine_program(topo, cfg, kind, size, block=16, num_pages=2048):
     """The engine's own `llama` program of one kind and bucket (the model's
     step, then the scatter of its new K/V into the donated arena), compiled
@@ -170,6 +179,8 @@ def test_engine_program_updates_the_donated_arena_in_place(topo, kind, size):
     moved = [line.strip()[:120] for line in compiled.as_text().splitlines()
              if " copy(" in line and shape in line.split(" copy(")[0]]
     assert not moved, moved
+    if kind == "decode":
+        _assert_returns_a_token_a_lane(compiled, size)
 
 
 def _materialised(hlo_text):
@@ -280,6 +291,8 @@ def test_latent_arena_is_updated_in_place_at_kimi_k2_widths(topo, kind, size):
             args = (params, on_chip((1, size)), on_chip((1,)), pages, table,
                     on_chip((1, size)), on_chip((1, size)))
         compiled = jax.jit(fn, donate_argnums=(3,)).lower(*args).compile()
+        if kind == "decode":
+            _assert_returns_a_token_a_lane(compiled, size)
         shape = "bf16[" + ",".join(map(str, pages.shape)) + "]"
         text = compiled.as_text()
         moved = [line.strip()[:120] for line in text.splitlines()
@@ -366,6 +379,8 @@ def test_sequence_state_arena_is_updated_in_place_at_ling_widths(topo, kind,
         moved = [line.strip()[:120] for line in text.splitlines()
                  if " copy(" in line and shape in line.split(" copy(")[0]]
         assert not moved, moved
+    if kind == "decode":
+        _assert_returns_a_token_a_lane(compiled, size)
 
 
 @pytest.mark.parametrize("kind, size", [("decode", 64), ("prefill", 1024),
@@ -506,6 +521,8 @@ def test_window_and_full_programs_fit_the_chip_at_trinity_widths(topo, kind,
         moved = [line.strip()[:120] for line in text.splitlines()
                  if " copy(" in line and shape in line.split(" copy(")[0]]
         assert not moved, moved
+    if kind == "decode":
+        _assert_returns_a_token_a_lane(compiled, size)
 
 
 @pytest.mark.parametrize("kind, size", [("decode", 16), ("prefill", 384),
@@ -571,6 +588,7 @@ def test_looped_programs_fit_the_chip_at_ouro_widths(topo, kind, size):
     assert not moved, moved
     if kind == "decode":    # a step's own temporaries: the gathered block
         assert mem.temp_size_in_bytes < 256 * 2**20
+        _assert_returns_a_token_a_lane(compiled, size)
 
 
 def test_build_mesh_on_tpu_follows_the_topology(topo):

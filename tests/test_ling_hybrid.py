@@ -286,21 +286,46 @@ def _reference_logits(eng, ids):
 
 
 def _engine_logits(eng, prompt, steps):
-    """The logits rows the engine sampled from (the prefill's last row,
-    then a decode step's one live row a token), caught at `np.argmax`."""
+    """The logits rows the engine's tokens were chosen from: the prefill's
+    last row, caught at `np.argmax` (`_emit_first`), then a decode step's
+    one live row a token. A decode program returns the token it chose and
+    not the logits (PR 51), so a step's row is the family's `decode_step`'s
+    on the arguments and the arena the engine is about to hand its own
+    program of the bucket of one: the engine's maker over a module whose
+    step returns its logits a second time, as its last output (a program
+    passes on what a step returns after its rows and states). The engine's
+    program chose that row's largest."""
+    import types
+
+    def decode_step(*args, **kwargs):
+        logits, *out = lh.decode_step(*args, **kwargs)
+        return (logits, *out, logits)
+
     rows = []
-    real = np.argmax
+    real, forward, mod = np.argmax, eng._decode_forward, eng._mod
+    eng._mod = types.SimpleNamespace(decode_step=decode_step)
+    try:
+        probe = jax.jit(eng._make_decode_fn(1))
+    finally:
+        eng._mod = mod
 
     def spy(row, *a, **kw):
         rows.extend(np.atleast_2d(np.array(row, np.float32)))
         return real(row, *a, **kw)
 
-    np.argmax = spy
+    def step_spy(fn, args):
+        _, *_, logits = probe(*args)        # before the call: it donates
+        chosen = forward(fn, args)
+        rows.extend(np.array(logits, np.float32))
+        assert chosen.tolist() == [int(real(np.asarray(logits)[0]))]
+        return chosen
+
+    np.argmax, eng._decode_forward = spy, step_spy
     try:
         req = eng.submit(prompt, steps)
         eng.run_until_idle()
     finally:
-        np.argmax = real
+        np.argmax, eng._decode_forward = real, forward
     return req.result(), np.stack(rows)
 
 

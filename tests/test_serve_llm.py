@@ -601,10 +601,12 @@ def test_steady_state_compiles_nothing_and_donates_the_arena(llama_engine):
 
 @pytest.mark.parametrize("model", ["llama", "kimi_k2"])
 def test_link_counters_hold_ids_tables_and_logits_only(model):
-    """`decode_link_bytes` a step and `prefill_link_bytes` a prefill are
-    the host arguments' bytes plus the fetched logits', to the byte: no
-    K or V is among them. A family that counts on the device
-    (`step_counts`) fetches its vector of int32 counts with them."""
+    """`decode_link_bytes` a step is the host arguments' bytes plus the
+    fetched token ids' (one int32 a lane: the program chose them), and
+    `prefill_link_bytes` a prefill the host arguments' plus the fetched
+    logits' (one row a request), to the byte: no K or V is among them. A
+    family that counts on the device (`step_counts`) fetches its vector of
+    int32 counts with them."""
     eng = _small_engine(model, prefill_buckets=(16,))
     try:
         m0 = eng.metrics()
@@ -618,9 +620,10 @@ def test_link_counters_hold_ids_tables_and_logits_only(model):
         counts = int32 * len(eng._step_counts)
         assert counts == {"llama": 0, "kimi_k2": 20}[model]
         # token ids, positions, the page table, a page id and an offset a
-        # lane; the lanes' logits (and the step's counts) back
+        # lane; the lanes' chosen ids (and the step's counts) back
         a_step = lanes * int32 * (4 + eng.max_pages_per_seq) \
-            + lanes * vocab_row + counts
+            + lanes * int32 + counts
+        assert a_step < vocab_row
         # token ids, the true length, a page id and an offset a row; one
         # row of logits back
         a_prefill = bucket * int32 * 3 + int32 + vocab_row + counts
@@ -634,6 +637,240 @@ def test_link_counters_hold_ids_tables_and_logits_only(model):
         eng.quiesce()
     finally:
         assert eng.shutdown() == 0
+
+
+# ---------------------------------------------------------------------------
+# a decode program ends in the greedy choice (PR 51): it returns the token
+# ids, int32 [batch], and not the logits; the families' `decode_step` still
+# return logits, and a prefill or a chunk its rows of them
+# ---------------------------------------------------------------------------
+
+TOKEN_FAMILIES = ("llama", "gpt", "kimi_k2", "ling_hybrid", "afmoe", "ouro")
+_TOKEN_ENGINE = dict(batch_buckets=(1, 2, 4), prefill_buckets=(8,),
+                     prefill_chunk=8, block_size=4, num_pages=64,
+                     prefix_cache=0)
+
+
+@pytest.fixture(scope="module")
+def token_engines():
+    """`get(name)`: the tiny engine of a token family (float32; `llama_bf16`
+    is `llama` with bfloat16 activations and logits), built and warmed when
+    first asked for and shared by the tests below; the programs its
+    construction and warm-up compiled; and a place for the tests' probes of
+    it."""
+    import jax.numpy as jnp
+    from ray_tpu import parallel
+    from ray_tpu.models.llama import LlamaConfig
+    from ray_tpu.serve.llm import EngineConfig, LLMEngine
+
+    built = {}
+
+    def get(name):
+        if name not in built:
+            before = parallel.cache_stats()["misses"]
+            eng = LLMEngine(
+                model=name.removesuffix("_bf16"),
+                engine_config=EngineConfig(**_TOKEN_ENGINE),
+                model_cfg=LlamaConfig.tiny(dtype=jnp.bfloat16)
+                if name == "llama_bf16" else None, seed=0)
+            eng.warmup()
+            built[name] = (eng, parallel.cache_stats()["misses"] - before, {})
+        return built[name]
+
+    yield get
+    for eng, *_ in built.values():
+        assert eng.shutdown() == 0
+
+
+def _mixed_batch(eng, lengths=(5, 13, 3, 7, 20), news=(6, 9, 4, 7, 5)):
+    """Requests of one-shot and chunked prompts that join and leave the
+    running set at different steps, one more than the lanes; the ids are a
+    fixed draw, so a family's token lists are a property of its seed."""
+    rng = np.random.default_rng(7)
+    vocab = eng.model_cfg.vocab_size
+    prompts = [rng.integers(0, vocab, n).tolist() for n in lengths]
+    return [eng.submit(p, n) for p, n in zip(prompts, news)]
+
+
+def _decode_probe(eng, batch, plant=None):
+    """The engine's own maker's decode program of a bucket, over a module
+    whose `decode_step` hands its logits on a second time, as the step's
+    last output, once `plant` has had them: a program passes on what a step
+    returns after its rows and states, as it does the step's counts. Not
+    donating, so it can run before the engine's own program on the same
+    arguments."""
+    import types
+
+    import jax
+
+    def decode_step(*args, **kwargs):
+        logits, *out = mod.decode_step(*args, **kwargs)
+        if plant is not None:
+            logits = plant(logits)
+        return (logits, *out, logits)
+
+    mod = eng._mod
+    eng._mod = types.SimpleNamespace(decode_step=decode_step)
+    try:
+        return jax.jit(eng._make_decode_fn(batch))
+    finally:
+        eng._mod = mod
+
+
+def _plant_tie_and_nan(logits):
+    """Columns 5 and 9 of every lane above the row's largest, by the same
+    amount; a NaN in lane 1."""
+    import jax.numpy as jnp
+    top = logits.max(axis=-1) + 1
+    return logits.at[:, 9].set(top).at[:, 5].set(top).at[1, 7].set(jnp.nan)
+
+
+@pytest.mark.parametrize("case", ["live", "dead_lane", "tie"])
+@pytest.mark.parametrize("family", TOKEN_FAMILIES + ("llama_bf16",))
+def test_decode_program_returns_the_argmax_of_the_family_step(
+        token_engines, monkeypatch, family, case):
+    """The ids a decode program returns are `np.argmax` of the logits the
+    family's `decode_step` gives on the same arena and arguments, lane for
+    lane, a lane past the running set included, in the logits' own dtype:
+    the lowest index wins a tie and a NaN counts as the largest, in the
+    program as on the host. The program is the engine's own maker's over a
+    module that also returns the logits; without a planted tie, the engine's
+    own program of the bucket returns the same ids."""
+    eng, _, probes = token_engines(family)
+    plant = _plant_tie_and_nan if case == "tie" else None
+    if plant not in probes:
+        probes[plant] = _decode_probe(eng, 4, plant)
+    probe, seen, forward = probes[plant], [], eng._decode_forward
+
+    def spy(fn, args):
+        if len(args[1]) != 4:
+            return forward(fn, args)
+        # before the engine's own call, which consumes the arena
+        ids, *_, logits = probe(*args)
+        live = len(eng._running)
+        got = forward(fn, args)
+        seen.append((live, np.asarray(ids), np.asarray(logits), got))
+        return got
+
+    monkeypatch.setattr(eng, "_decode_forward", spy)
+    reqs = _mixed_batch(eng, (5, 13, 3), (9, 9, 8)) \
+        if case == "dead_lane" else _mixed_batch(eng)
+    eng.run_until_idle()
+    assert all(len(r.tokens) == r.max_new_tokens for r in reqs)
+    eng.quiesce()
+    assert len(seen) >= 3
+    # three requests leave the fourth lane dead in every step of the bucket
+    assert (max(live for live, *_ in seen) == 3) == (case == "dead_lane")
+    for _, ids, logits, got in seen:
+        assert ids.dtype == got.dtype == np.int32
+        assert ids.shape == got.shape == (4,)
+        assert logits.shape == (4, eng.model_cfg.vocab_size)
+        assert logits.dtype == np.dtype(eng.model_cfg.dtype)
+        np.testing.assert_array_equal(ids, np.argmax(logits, axis=-1))
+        if plant is not None:
+            assert ids.tolist() == [5, 7, 5, 5]
+        else:
+            np.testing.assert_array_equal(got, ids)
+
+
+# What the parent of PR 51 (commit bd22068: the logits fetched, `np.argmax`
+# on the host) streamed for `_mixed_batch` from each family's tiny engine of
+# seed 0, recorded there.
+PARENT_TOKENS = {
+    "llama": [[296] * 6, [408] * 9, [418] * 4, [227] * 7, [22] * 5],
+    "gpt": [[296, 283, 283, 283, 283, 283],
+            [408, 408, 408, 408, 408, 408, 408, 6, 6], [418] * 4, [227] * 7,
+            [461] * 5],
+    "kimi_k2": [[12, 447, 357, 176, 441, 35],
+                [423, 485, 42, 381, 407, 349, 347, 277, 399],
+                [276, 420, 14, 250], [202, 260, 412, 63, 351, 446, 395],
+                [140, 503, 335, 211, 122]],
+    "ling_hybrid": [[475, 305, 344, 354, 500, 47],
+                    [289, 451, 324, 134, 342, 134, 376, 495, 50],
+                    [148, 409, 327, 344],
+                    [339, 366, 298, 289, 156, 454, 376],
+                    [244, 218, 459, 332, 410]],
+    "afmoe": [[38, 277, 434, 434, 434, 277],
+              [327, 447, 447, 276, 433, 475, 45, 258, 415],
+              [260, 260, 219, 134], [452, 373, 452, 452, 452, 276, 13],
+              [277, 194, 63, 129, 301]],
+    "ouro": [[453, 119, 265, 265, 47, 163],
+             [173, 173, 173, 229, 173, 229, 173, 229, 173],
+             [26, 357, 74, 432], [103, 510, 283, 283, 283, 283, 283],
+             [246, 403, 283, 246, 403]],
+}
+
+
+@pytest.mark.parametrize("family", TOKEN_FAMILIES)
+def test_streamed_tokens_are_the_parents(token_engines, family):
+    """A mixed batch through `LLMEngine.submit` (one-shot and chunked
+    prefills, buckets of one, two and four, a request that waits for a
+    lane) streams, request for request, the tokens it streamed when the host
+    took the argmax."""
+    eng, *_ = token_engines(family)
+    m0 = eng.metrics()["compiled_step_calls"]
+    reqs = _mixed_batch(eng)
+    eng.run_until_idle()
+    assert [list(r.stream(timeout=30)) for r in reqs] \
+        == PARENT_TOKENS[family]
+    calls = _delta(eng.metrics()["compiled_step_calls"], m0)
+    assert calls == {"chunk:8": 5, "decode:1": 3, "decode:2": 2,
+                     "decode:4": 6, "prefill:8": 3}
+    eng.quiesce()
+
+
+@pytest.mark.parametrize("family", TOKEN_FAMILIES)
+def test_decode_fetch_is_ids_not_logits(token_engines, monkeypatch, family):
+    """What a decode step moves over the host link is its host arguments
+    and, back, one int32 a lane of its bucket (and the family's vector of
+    counts, where it has one), to the byte: under a tenth of one lane's row
+    of logits."""
+    eng, *_ = token_engines(family)
+    steps, forward = [], eng._decode_forward
+
+    def spy(fn, args):
+        steps.append((len(args[1]), sum(
+            a.nbytes for a in args if isinstance(a, np.ndarray))))
+        return forward(fn, args)
+
+    monkeypatch.setattr(eng, "_decode_forward", spy)
+    m0 = eng.metrics()
+    reqs = _mixed_batch(eng)
+    eng.run_until_idle()
+    assert all(len(r.tokens) == r.max_new_tokens for r in reqs)
+    m = _delta(eng.metrics(), m0)
+    eng.quiesce()
+    counts = 4 * len(eng._step_counts)
+    assert len(steps) == m["decode_steps"] == 11
+    assert m["decode_link_bytes"] == sum(
+        host + 4 * batch + counts for batch, host in steps)
+    assert 4 * 4 + counts < eng.model_cfg.vocab_size * 4 / 10
+
+
+@pytest.mark.parametrize("family", TOKEN_FAMILIES)
+def test_engine_builds_one_program_a_bucket_and_the_chunk(token_engines,
+                                                          family):
+    """`len(batch_buckets) + len(prefill_buckets) + 1` programs, the ones
+    there were: a decode bucket is one program, which returns ids, with no
+    second one beside it that still returns logits; warm-up compiles each
+    once and serving compiles nothing more."""
+    from ray_tpu import parallel
+
+    eng, compiled, _ = token_engines(family)
+    want = len(eng.config.batch_buckets) + len(eng.config.prefill_buckets) + 1
+    assert sorted(eng._decode_fns) == [1, 2, 4]
+    assert sorted(eng._prefill_fns) == [8] and callable(eng._chunk_fn)
+    fns = [*eng._decode_fns.values(), *eng._prefill_fns.values(),
+           eng._chunk_fn]
+    assert len({id(fn) for fn in fns}) == want == compiled == 5
+    before = parallel.cache_stats()
+    reqs = _mixed_batch(eng)
+    eng.run_until_idle()
+    assert all(len(r.tokens) == r.max_new_tokens for r in reqs)
+    after = parallel.cache_stats()
+    assert (after["misses"], after["retraces"]) \
+        == (before["misses"], before["retraces"])
+    eng.quiesce()
 
 
 @pytest.mark.parametrize("model", ["llama", "kimi_k2", "ouro"])
@@ -1574,15 +1811,19 @@ def test_block_family_streams_in_position_order_and_stops_inside_a_block():
 # adds a family, a field or an argument for another family's sake must not.
 # (`decode4` of `llama` and of `gpt` changed on purpose in PR 49: a bucket of
 # two lanes and more walks `llama.paged_attend`'s work list of (lane, key
-# block) pairs; `decode1`, the kept loop, and the others stand.)
+# block) pairs; `decode1`, the kept loop, and the others stood. `decode1` and
+# `decode4` of all three changed on purpose in PR 51: a decode program ends in
+# the greedy argmax and returns int32 [batch] where it returned the logits;
+# its inputs and the outputs after the first are as they were. `prefill16` and
+# `chunk16` stand: they are the parent's programs, and its cache entries.)
 NEIGHBOUR_PROGRAMS = {
-    "llama": {"prefill16": "431c15dfcac7aa97", "decode1": "1ff66eb474054e07",
-              "decode4": "f803f35d0c36146e", "chunk16": "6a7f8549a7f8d7f8"},
-    "gpt": {"prefill16": "b2ca98029a10a332", "decode1": "7a24818982c3efcd",
-            "decode4": "1e4d0e553be70d2a", "chunk16": "d8e225f431a39fd7"},
+    "llama": {"prefill16": "431c15dfcac7aa97", "decode1": "6bf27b12ae6cc48a",
+              "decode4": "a673da1cf2b122ee", "chunk16": "6a7f8549a7f8d7f8"},
+    "gpt": {"prefill16": "b2ca98029a10a332", "decode1": "910442728ae47e50",
+            "decode4": "56f63d1e86807bca", "chunk16": "d8e225f431a39fd7"},
     "kimi_k2": {"prefill16": "0742f17ca2a066b5",
-                "decode1": "38844a208fb489c7",
-                "decode4": "2e7ec0aa8c76cda3",
+                "decode1": "8f724381a7abeb37",
+                "decode4": "530e3a868ca209d2",
                 "chunk16": "dcfa5ec2cf9155a8"},
 }
 
@@ -1723,14 +1964,17 @@ def test_short_and_long_in_one_batch_through_pages_of_two_kinds():
 # buckets of two lanes and more took a work list of (lane, key block) pairs
 # (hashes taken on commit ce75fa1, in the manner of `NEIGHBOUR_PROGRAMS`):
 # the one-shot prefill has no cache, the chunk and the bucket of one have
-# one lane, whose own blocks are the batch's longest's.
+# one lane, whose own blocks are the batch's longest's. (`decode1` changed on
+# purpose in PR 51, as `NEIGHBOUR_PROGRAMS` says: it returns the token it
+# chose. `prefill8` and `chunk8` stand.)
 AFMOE_ONE_LANE_PROGRAMS = {"prefill8": "f1b725340a027ffd",
-                           "decode1": "54351eb492be0906",
+                           "decode1": "7716a13c6e3130c1",
                            "chunk8": "b820bd39b1a363c3"}
-# and the bucket of four, which walks the list (hash taken on commit d73b325,
-# before PR 49 gave `llama.paged_attend` a list of its own and moved the fold
-# of a trip's pairs to `llama.py`: the helper's default and the fold are held)
-AFMOE_PROGRAMS = {**AFMOE_ONE_LANE_PROGRAMS, "decode4": "1b61c16ab0819417"}
+# and the bucket of four, which walks the list (PR 49 gave `llama.paged_attend`
+# a list of its own and moved the fold of a trip's pairs to `llama.py`: the
+# helper's default and the fold were held; changed on purpose in PR 51 with
+# `decode1`: the walk is the one it was, the first output the chosen tokens)
+AFMOE_PROGRAMS = {**AFMOE_ONE_LANE_PROGRAMS, "decode4": "572dcee861767fc7"}
 
 
 @pytest.mark.parametrize("program", sorted(AFMOE_PROGRAMS))
