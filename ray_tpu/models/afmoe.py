@@ -25,14 +25,15 @@ What it asks of the system that no other family does:
 - Rope turns the query and key of the window layers only; a full layer's
   scores carry no position.
 
-Shared with `llama.py`: `_rms`, the rotation, and the walk over cached key
+From `layers.py`: `rms`, the rotation, the head, the weights' declaration
+and `routed_feed_forward` (Kimi's too: `parallel.moe.expert_shard_layer`
+under `sigmoid_topk_route`, the chip's share of an expert-parallel layer:
+`experts_held` of `n_experts` from `first_expert` on, the router at its
+whole width; `MOE_COUNTS`). Shared with `llama.py`: the walk over cached key
 blocks of `KEY_BLOCK` slots: a batch of lanes walks a work list of its live
 (lane, block) pairs, each lane its own blocks and no lane another's
 (`key_block_pairs`); one lane alone (a chunk, the bucket of one) walks as
-far as its own last (`key_block_trips`). Shared with `kimi_k2.py`:
-`parallel.moe.expert_shard_layer` under `sigmoid_topk_route` (the chip's
-share of an expert-parallel layer: `experts_held` of `n_experts` from
-`first_expert` on, the router at its whole width) and `MOE_COUNTS`.
+far as its own last (`key_block_trips`).
 
 Parameters: `wte`, `layer<i>/{attn_norm, attn_qkvg, q_norm, k_norm,
 attn_out, post_attn_norm, mlp_norm, post_mlp_norm, ...}`, `final_norm`,
@@ -54,9 +55,10 @@ import jax.numpy as jnp
 import numpy as np
 
 from ray_tpu.models import llama as _llama
-from ray_tpu.models.kimi_k2 import _Weights, _swiglu, unboxed_params
-from ray_tpu.models.llama import NEG_INF, _rms, _rope_chunk
-from ray_tpu.parallel.moe import MOE_COUNTS, expert_shard_layer
+from ray_tpu.models.layers import (A_HEAD, NEG_INF, declare_weights, head,
+                                   last_row, rms, rope, routed_feed_forward,
+                                   top_shapes, unboxed_params)
+from ray_tpu.parallel.moe import MOE_COUNTS
 
 SLIDING, FULL = "sliding", "full"
 # what each step returns after the cache rows, an int32 vector summed over
@@ -147,7 +149,7 @@ def layer_slots(cfg: AfmoeConfig) -> Tuple[Tuple[int, int], ...]:
 # -- the weights --------------------------------------------------------------
 
 def layer_shapes(cfg: AfmoeConfig, i: int) -> dict:
-    """name -> (shape, kind) of layer i's parameters."""
+    """name -> (shape, kind of `layers.INITS`) of layer i's parameters."""
     d, hd, h = cfg.d_model, cfg.head_dim, cfg.n_head
     shapes = {
         "attn_norm": ((d,), "ones"),
@@ -183,13 +185,8 @@ class Afmoe(nn.Module):
     @nn.compact
     def __call__(self, tokens):
         cfg = self.config
-        top = {"wte": ((cfg.vocab_size, cfg.d_model), "w"),
-               "final_norm": ((cfg.d_model,), "ones"),
-               "lm_head": ((cfg.d_model, cfg.vocab_size), "w")}
-        p = _Weights(top, cfg.param_dtype, name="top")()
-        for i in range(cfg.n_layer):
-            p[f"layer{i}"] = _Weights(layer_shapes(cfg, i), cfg.param_dtype,
-                                      name=f"layer{i}")()
+        p = declare_weights(top_shapes(cfg), (
+            layer_shapes(cfg, i) for i in range(cfg.n_layer)), cfg.param_dtype)
         logits, _, _ = _window_forward(
             p, cfg, tokens, jnp.zeros(tokens.shape[:1], jnp.int32), None,
             None)
@@ -354,32 +351,6 @@ def window_attend(q, k_new, v_new, pages, layer, page_table, start,
             q.dtype), slots
 
 
-def feed_forward(lp, cfg: AfmoeConfig, i: int, h, valid):
-    """Layer i's feed-forward of h [N, d]: the dense SwiGLU, or this chip's
-    experts' part of the routed sum (`route_norm`, `route_scale`, one group)
-    plus the shared expert. Returns (result [N, d], counts int32 as
-    `MOE_COUNTS`)."""
-    if i < cfg.n_dense_layer:
-        with jax.named_scope("dense_mlp"):
-            return _swiglu(h, lp["mlp_gate_up"], lp["mlp_down"],
-                           cfg.dtype), jnp.zeros(len(MOE_COUNTS), jnp.int32)
-    routed, counts = expert_shard_layer(
-        h, lp["router"], lp["router_bias"],
-        {"gate_up": lp["experts_gate_up"], "down": lp["experts_down"]},
-        cfg.first_expert, cfg.n_experts, cfg.top_k, cfg.routed_scale,
-        valid=valid)
-    with jax.named_scope("moe_shared"):
-        shared = _swiglu(h, lp["shared_gate_up"], lp["shared_down"],
-                         cfg.dtype)
-    return routed + shared, counts
-
-
-def _head(p, cfg: AfmoeConfig, x):
-    with jax.named_scope("lm_head"):
-        x = _rms(x, p["final_norm"], cfg.norm_eps, cfg.dtype)
-        return x @ p["lm_head"].astype(cfg.dtype)
-
-
 # -- the three steps ----------------------------------------------------------
 
 def _window_forward(p, cfg: AfmoeConfig, tokens, start, cache, valid_rows):
@@ -404,16 +375,16 @@ def _window_forward(p, cfg: AfmoeConfig, tokens, start, cache, valid_rows):
     for i, (kind, at) in enumerate(layer_slots(cfg)):
         lp = p[f"layer{i}"]
         name, window = kinds[kind][0], kinds[kind][3]
-        h = _rms(x, lp["attn_norm"], cfg.norm_eps, dtype)
+        h = rms(x, lp["attn_norm"], cfg.norm_eps, dtype)
         q, k, v, gate = jnp.split(
             h @ lp["attn_qkvg"].astype(dtype),
             [n_q, n_q + n_kv, n_q + 2 * n_kv], axis=-1)
-        q = _rms(q.reshape(b, c, cfg.n_head, hd), lp["q_norm"],
+        q = rms(q.reshape(b, c, cfg.n_head, hd), lp["q_norm"],
                  cfg.norm_eps, dtype)
-        k = _rms(k.reshape(b, c, cfg.n_kv_head, hd), lp["k_norm"],
+        k = rms(k.reshape(b, c, cfg.n_kv_head, hd), lp["k_norm"],
                  cfg.norm_eps, dtype)
         if window is not None:      # a full layer's scores carry no position
-            q, k = _rope_chunk(q, cos, sin), _rope_chunk(k, cos, sin)
+            q, k = rope(q, cos, sin, A_HEAD), rope(k, cos, sin, A_HEAD)
         v = v.reshape(b, c, cfg.n_kv_head, hd)
         pages = table = None
         if cache is not None:
@@ -422,11 +393,12 @@ def _window_forward(p, cfg: AfmoeConfig, tokens, start, cache, valid_rows):
         att, slots = window_attend(q, k, v, pages, at, table, start,
                                    window=window, scale=hd ** -0.5)
         att = att * jax.nn.sigmoid(gate)
-        x = x + _rms(att @ lp["attn_out"].astype(dtype),
+        x = x + rms(att @ lp["attn_out"].astype(dtype),
                      lp["post_attn_norm"], cfg.norm_eps, dtype)
-        h = _rms(x, lp["mlp_norm"], cfg.norm_eps, dtype)
-        y, n = feed_forward(lp, cfg, i, h.reshape(b * c, -1), flat_valid)
-        x = x + _rms(y.reshape(b, c, -1), lp["post_mlp_norm"], cfg.norm_eps,
+        h = rms(x, lp["mlp_norm"], cfg.norm_eps, dtype)
+        y, n = routed_feed_forward(lp, cfg, i, h.reshape(b * c, -1),
+                                   flat_valid)
+        x = x + rms(y.reshape(b, c, -1), lp["post_mlp_norm"], cfg.norm_eps,
                      dtype)
         counts = counts + n
         if slots.ndim:      # the work list's, a lane: a pad lane's are none
@@ -439,7 +411,7 @@ def _window_forward(p, cfg: AfmoeConfig, tokens, start, cache, valid_rows):
         rows[kind][1].append(v)
     counts = jnp.concatenate([counts, jnp.stack(
         [key_slots["window"], key_slots["full"]]).astype(jnp.int32)])
-    return _head(p, cfg, x), \
+    return head(p, cfg, x), \
         [jnp.stack(r, axis=2) for pair in rows for r in pair], counts
 
 
@@ -453,10 +425,7 @@ def prefill_step(variables, cfg: AfmoeConfig, tokens, true_len, valid=None):
     logits, rows, counts = _window_forward(
         unboxed_params(variables), cfg, tokens, jnp.zeros((b,), jnp.int32),
         None, valid)
-    idx = jnp.maximum(true_len - 1, 0)
-    next_logits = jnp.take_along_axis(
-        logits, idx[:, None, None], axis=1)[:, 0]
-    return (next_logits, *rows, counts)
+    return (last_row(logits, true_len), *rows, counts)
 
 
 def chunk_step(variables, cfg: AfmoeConfig, tokens, start, *cache,

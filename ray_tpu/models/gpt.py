@@ -54,7 +54,7 @@ class GPTConfig:
         return cls(n_layer=2, n_head=2, d_model=64, **kw)
 
 
-def _dense(features, logical_axes, name, config, use_bias=True):
+def dense(features, logical_axes, name, config, use_bias=True):
     return nn.Dense(
         features,
         use_bias=use_bias,
@@ -95,7 +95,7 @@ class Block(nn.Module):
                          bias_init=nn.with_partitioning(
                              nn.initializers.zeros, ("norm",)),
                          name="ln_1")(x)
-        qkv = _dense(3 * cfg.d_model, ("embed", "qkv"), "attn_qkv", cfg)(h)
+        qkv = dense(3 * cfg.d_model, ("embed", "qkv"), "attn_qkv", cfg)(h)
         q, k, v = jnp.split(qkv, 3, axis=-1)
         b, t = q.shape[0], q.shape[1]
         q = q.reshape(b, t, cfg.n_head, head_dim)
@@ -109,7 +109,7 @@ class Block(nn.Module):
         self.sow("intermediates", "kv_cache", (k, v))
         attend = self.attention_fn or partial(full_attention, causal=True)
         att = attend(q, k, v).reshape(b, t, cfg.d_model)
-        att = _dense(cfg.d_model, ("heads", "embed"), "attn_out", cfg)(att)
+        att = dense(cfg.d_model, ("heads", "embed"), "attn_out", cfg)(att)
         x = x + att
 
         h = nn.LayerNorm(dtype=cfg.dtype, param_dtype=cfg.param_dtype,
@@ -118,9 +118,9 @@ class Block(nn.Module):
                          bias_init=nn.with_partitioning(
                              nn.initializers.zeros, ("norm",)),
                          name="ln_2")(x)
-        h = _dense(4 * cfg.d_model, ("embed", "mlp"), "mlp_up", cfg)(h)
+        h = dense(4 * cfg.d_model, ("embed", "mlp"), "mlp_up", cfg)(h)
         h = nn.gelu(h)
-        h = _dense(cfg.d_model, ("mlp", "embed"), "mlp_down", cfg)(h)
+        h = dense(cfg.d_model, ("mlp", "embed"), "mlp_down", cfg)(h)
         if cfg.dropout > 0:
             h = nn.Dropout(cfg.dropout)(h, deterministic=deterministic)
         x = x + h
@@ -244,12 +244,16 @@ def chunked_cross_entropy(hidden, wte, targets, ignore_index: int = -1,
 # Same two-function split as `llama.py` (see the note there): prefill is
 # the flax module itself (kv sown per block), decode is a pure paged
 # single-token forward sharing `paged_attend` with Llama.
+#
+# `layers` is imported here, below the training forward, and `dense` stayed in
+# this file (public, where `_dense` stood, line for line): a Pallas kernel's
+# serialized body carries the file and LINE of every Python frame that reaches
+# it (`Block.__call__`, `GPT.__call__`), that body is part of the training
+# step's text and so of its compile-cache key, and a line more or fewer above
+# those call sites gives `gpt2-medium.pretrain` (the cell with the flash
+# kernel) a new step to compile (PERF.md §7, PR 52).
 
-
-def unboxed_params(variables):
-    p = variables["params"] if "params" in variables else variables
-    return nn.meta.unbox(p)
-
+from ray_tpu.models.layers import last_row, unboxed_params  # noqa: E402
 
 def _ln(x, scale, bias, dtype, eps=1e-6):
     # mirrors flax LayerNorm (f32 stats, fast-variance, eps 1e-6)
@@ -273,10 +277,7 @@ def prefill_step(variables, cfg: GPTConfig, tokens, true_len):
                    for i in range(cfg.n_layer)], axis=2)
     v = jnp.stack([inter[f"h{i}"]["kv_cache"][0][1]
                    for i in range(cfg.n_layer)], axis=2)
-    idx = jnp.maximum(true_len - 1, 0)
-    next_logits = jnp.take_along_axis(
-        logits, idx[:, None, None], axis=1)[:, 0]
-    return next_logits, k, v
+    return last_row(logits, true_len), k, v
 
 
 def decode_key_walk(cfg, positions, n_pages: int, page: int, xp=jnp):

@@ -40,9 +40,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.parallel.moe import MOE_COUNTS, expert_shard_layer
+from ray_tpu.models.layers import (NEG_INF, declare_weights, gather_pages,
+                                   head, last_row, rms, rope,
+                                   routed_feed_forward, top_shapes,
+                                   unboxed_params)
+from ray_tpu.parallel.moe import MOE_COUNTS
 
-NEG_INF = -1e30
 # what each step returns after the cache rows, an int32 vector summed over
 # the layers: the engine adds it to `decode_<name>` / `prefill_<name>`.
 # `attn_key_slots` is the key slots a query row of each sequence was scored
@@ -166,26 +169,10 @@ def softmax_scale(cfg: KimiK2Config) -> float:
     return (cfg.qk_nope_dim + cfg.qk_rope_dim) ** -0.5 * m * m
 
 
-def _rope(x, cos, sin):
-    """Rotate the halves of the last axis; cos/sin broadcast against
-    x[..., :D/2]."""
-    x32 = x.astype(jnp.float32)
-    x1, x2 = jnp.split(x32, 2, axis=-1)
-    return jnp.concatenate(
-        [x1 * cos - x2 * sin, x2 * cos + x1 * sin], axis=-1).astype(x.dtype)
-
-
-def _rms(x, scale, eps, dtype):
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-    y = x32 * jax.lax.rsqrt(var + eps)
-    return (y * scale.astype(jnp.float32)).astype(dtype)
-
-
 # -- the weights --------------------------------------------------------------
 
 def layer_shapes(cfg: KimiK2Config, i: int) -> dict:
-    """name -> (shape, kind) of layer i's parameters."""
+    """name -> (shape, kind of `layers.INITS`) of layer i's parameters."""
     d, h = cfg.d_model, cfg.n_head
     shapes = {
         "attn_norm": ((d,), "ones"),
@@ -216,29 +203,6 @@ def layer_shapes(cfg: KimiK2Config, i: int) -> dict:
     return shapes
 
 
-class _Weights(nn.Module):
-    """Declares one group of parameters and returns them as a dict."""
-    shapes: Any
-    param_dtype: Any
-
-    @nn.compact
-    def __call__(self):
-        inits = {
-            "w": (nn.initializers.normal(0.02), self.param_dtype),
-            "ones": (nn.initializers.ones, self.param_dtype),
-            # the selection bias is float32 in the checkpoint. A trained
-            # one balances the experts' load; under a random router a
-            # deviation of 0.01 already changes three in ten tokens' sets
-            # of experts and leaves the load's spread as it is, and 0.1
-            # sends most pairs to the dozen experts with the largest bias
-            # (the top sigmoid scores lie within 0.03 of each other)
-            "bias": (nn.initializers.normal(0.01), jnp.float32),
-        }
-        return {name: self.param(name, inits[kind][0], shape,
-                                 inits[kind][1])
-                for name, (shape, kind) in self.shapes.items()}
-
-
 class KimiK2(nn.Module):
     """`net.init` makes the weights; `apply` is the full causal forward
     (no cache), tokens [B, T] -> logits [B, T, V]."""
@@ -247,25 +211,12 @@ class KimiK2(nn.Module):
     @nn.compact
     def __call__(self, tokens):
         cfg = self.config
-        top = {"wte": ((cfg.vocab_size, cfg.d_model), "w"),
-               "final_norm": ((cfg.d_model,), "ones"),
-               "lm_head": ((cfg.d_model, cfg.vocab_size), "w")}
-        p = _Weights(top, cfg.param_dtype, name="top")()
-        for i in range(cfg.n_layer):
-            p[f"layer{i}"] = _Weights(layer_shapes(cfg, i), cfg.param_dtype,
-                                      name=f"layer{i}")()
+        p = declare_weights(top_shapes(cfg), (
+            layer_shapes(cfg, i) for i in range(cfg.n_layer)), cfg.param_dtype)
         logits, _, _ = _window_forward(
             p, cfg, tokens, jnp.zeros(tokens.shape[:1], jnp.int32), None,
             None, None)
         return logits
-
-
-def unboxed_params(variables):
-    p = nn.meta.unbox(variables)
-    p = p.get("params", p)
-    if "top" in p:
-        p = {**{k: v for k, v in p.items() if k != "top"}, **p["top"]}
-    return p
 
 
 # -- the layer's parts --------------------------------------------------------
@@ -277,16 +228,16 @@ def _project(lp, cfg: KimiK2Config, h, cos, sin):
     cos/sin [..., rope/2]."""
     dtype = cfg.dtype
     with jax.named_scope("mla_project"):
-        c_q = _rms(h @ lp["q_a"].astype(dtype), lp["q_a_norm"],
+        c_q = rms(h @ lp["q_a"].astype(dtype), lp["q_a_norm"],
                    cfg.norm_eps, dtype)
         q = (c_q @ lp["q_b"].astype(dtype)).reshape(
             h.shape[:-1] + (cfg.n_head, cfg.qk_nope_dim + cfg.qk_rope_dim))
         q_nope, q_rope = jnp.split(q, [cfg.qk_nope_dim], axis=-1)
-        q_rope = _rope(q_rope, cos[..., None, :], sin[..., None, :])
+        q_rope = rope(q_rope, cos[..., None, :], sin[..., None, :])
         kv = h @ lp["kv_a"].astype(dtype)
         c_kv, k_rope = jnp.split(kv, [cfg.kv_lora_rank], axis=-1)
-        c_kv = _rms(c_kv, lp["kv_a_norm"], cfg.norm_eps, dtype)
-        parts = [c_kv, _rope(k_rope, cos, sin)]
+        c_kv = rms(c_kv, lp["kv_a_norm"], cfg.norm_eps, dtype)
+        parts = [c_kv, rope(k_rope, cos, sin)]
         if cfg.row_dim > cfg.latent_dim:
             parts.append(jnp.zeros(
                 c_kv.shape[:-1] + (cfg.row_dim - cfg.latent_dim,), dtype))
@@ -433,45 +384,6 @@ def attend_expanded(lp, cfg: KimiK2Config, q_nope, q_rope, lat, start,
     return out.reshape(b, c, h * cfg.v_head_dim), slots
 
 
-def _swiglu(x, gate_up, down, dtype):
-    gate, up = jnp.split(x @ gate_up.astype(dtype), 2, axis=-1)
-    return (nn.silu(gate) * up) @ down.astype(dtype)
-
-
-def feed_forward(lp, cfg: KimiK2Config, i: int, h, valid):
-    """Layer i's feed-forward of h [N, d]: the dense SwiGLU, or this
-    chip's experts' part of the routed sum plus the shared expert.
-    Returns (result [N, d], counts int32[len(MOE_COUNTS)])."""
-    if i < cfg.n_dense_layer:
-        with jax.named_scope("dense_mlp"):
-            return _swiglu(h, lp["mlp_gate_up"], lp["mlp_down"],
-                           cfg.dtype), jnp.zeros(len(MOE_COUNTS), jnp.int32)
-    routed, counts = expert_shard_layer(
-        h, lp["router"], lp["router_bias"],
-        {"gate_up": lp["experts_gate_up"], "down": lp["experts_down"]},
-        cfg.first_expert, cfg.n_experts, cfg.top_k, cfg.routed_scale,
-        valid=valid)
-    with jax.named_scope("moe_shared"):
-        shared = _swiglu(h, lp["shared_gate_up"], lp["shared_down"],
-                         cfg.dtype)
-    return routed + shared, counts
-
-
-def _head(p, cfg: KimiK2Config, x):
-    with jax.named_scope("lm_head"):
-        x = _rms(x, p["final_norm"], cfg.norm_eps, cfg.dtype)
-        return x @ p["lm_head"].astype(cfg.dtype)
-
-
-def _gather_pages(pages, page_table, i: int):
-    """Layer i's rows of the sequences' pages: [B, n_pages * block, row].
-    Page and layer are indexed together, so only the sequences' own rows
-    are read (a slice of the layer first would copy a seventh of the
-    arena a layer)."""
-    got = pages[page_table, i]
-    return got.reshape(got.shape[0], -1, got.shape[-1])
-
-
 # -- the three steps ----------------------------------------------------------
 
 def _step_counts(moe_counts, key_slots):
@@ -498,18 +410,19 @@ def _window_forward(p, cfg: KimiK2Config, tokens, start, pages, page_table,
     key_slots = jnp.int32(0)
     for i in range(cfg.n_layer):
         lp = p[f"layer{i}"]
-        h = _rms(x, lp["attn_norm"], cfg.norm_eps, dtype)
+        h = rms(x, lp["attn_norm"], cfg.norm_eps, dtype)
         q_nope, q_rope, lat = _project(lp, cfg, h, cos, sin)
         att, slots = attend_expanded(lp, cfg, q_nope, q_rope, lat, start,
                                      pages, page_table, i)
         x = x + att @ lp["attn_out"].astype(dtype)
-        h = _rms(x, lp["mlp_norm"], cfg.norm_eps, dtype)
-        y, n = feed_forward(lp, cfg, i, h.reshape(b * c, -1), flat_valid)
+        h = rms(x, lp["mlp_norm"], cfg.norm_eps, dtype)
+        y, n = routed_feed_forward(lp, cfg, i, h.reshape(b * c, -1),
+                                   flat_valid)
         x = x + y.reshape(b, c, -1)
         counts = counts + n
         key_slots = key_slots + b * slots
         latents.append(lat)
-    return _head(p, cfg, x), jnp.stack(latents, axis=2), \
+    return head(p, cfg, x), jnp.stack(latents, axis=2), \
         _step_counts(counts, key_slots)
 
 
@@ -524,10 +437,7 @@ def prefill_step(variables, cfg: KimiK2Config, tokens, true_len,
     b = tokens.shape[0]
     logits, latents, counts = _window_forward(
         p, cfg, tokens, jnp.zeros((b,), jnp.int32), None, None, valid)
-    idx = jnp.maximum(true_len - 1, 0)
-    next_logits = jnp.take_along_axis(
-        logits, idx[:, None, None], axis=1)[:, 0]
-    return next_logits, latents, counts
+    return last_row(logits, true_len), latents, counts
 
 
 def chunk_step(variables, cfg: KimiK2Config, tokens, start, pages,
@@ -558,17 +468,17 @@ def decode_step(variables, cfg: KimiK2Config, tokens, positions, pages,
     latents, counts = [], jnp.zeros(len(MOE_COUNTS), jnp.int32)
     for i in range(cfg.n_layer):
         lp = p[f"layer{i}"]
-        h = _rms(x, lp["attn_norm"], cfg.norm_eps, dtype)
+        h = rms(x, lp["attn_norm"], cfg.norm_eps, dtype)
         q_nope, q_rope, lat = _project(lp, cfg, h, cos, sin)
         att = attend_absorbed(
             lp, cfg, q_nope, q_rope,
-            _gather_pages(pages, page_table, i).astype(dtype), lat, seen)
+            gather_pages(pages, page_table, i).astype(dtype), lat, seen)
         x = x + att @ lp["attn_out"].astype(dtype)
-        h = _rms(x, lp["mlp_norm"], cfg.norm_eps, dtype)
-        y, n = feed_forward(lp, cfg, i, h, valid)
+        h = rms(x, lp["mlp_norm"], cfg.norm_eps, dtype)
+        y, n = routed_feed_forward(lp, cfg, i, h, valid)
         x = x + y
         counts = counts + n
         latents.append(lat)
     # every lane of the bucket scores all of its table's slots and itself
-    return _head(p, cfg, x), jnp.stack(latents, axis=1), \
+    return head(p, cfg, x), jnp.stack(latents, axis=1), \
         _step_counts(counts, cfg.n_layer * x.shape[0] * (t_max + 1))

@@ -50,11 +50,12 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.kimi_k2 import (KimiK2Config, _gather_pages, _head, _rms,
-                                    _rope, _swiglu, attend_absorbed,
-                                    attend_expanded, unboxed_params,
-                                    yarn_tables)
-from ray_tpu.parallel.moe import MOE_COUNTS, expert_shard_layer
+from ray_tpu.models.kimi_k2 import (KimiK2Config, attend_absorbed,
+                                    attend_expanded, yarn_tables)
+from ray_tpu.models.layers import (declare_weights, gather_pages, head,
+                                   last_row, rms, rope, routed_feed_forward,
+                                   top_shapes, unboxed_params)
+from ray_tpu.parallel.moe import MOE_COUNTS
 
 # what each step returns last, an int32 vector summed over the layers:
 # Kimi's expert counts and key slots (the MLA layers), then the KDA states
@@ -177,7 +178,7 @@ def _tail_rows(cfg: LingHybridConfig, tail):
 # -- the weights --------------------------------------------------------------
 
 def layer_shapes(cfg: LingHybridConfig, i: int) -> dict:
-    """name -> (shape, kind) of layer i's parameters."""
+    """name -> (shape, kind of `layers.INITS`) of layer i's parameters."""
     d, h, dk = cfg.d_model, cfg.n_head, cfg.head_dim
     shapes = {"attn_norm": ((d,), "ones"), "mlp_norm": ((d,), "ones")}
     if cfg.is_mla(i):
@@ -219,41 +220,6 @@ def layer_shapes(cfg: LingHybridConfig, i: int) -> dict:
     return shapes
 
 
-def _uniform(lo: float, hi: float, log: bool = False):
-    def init(key, shape, dtype):
-        x = jax.random.uniform(key, shape, jnp.float32, lo, hi)
-        return (jnp.log(x) if log else x).astype(dtype)
-    return init
-
-
-class _Weights(nn.Module):
-    """Declares one group of parameters and returns them as a dict."""
-    shapes: Any
-    param_dtype: Any
-
-    @nn.compact
-    def __call__(self):
-        inits = {
-            "w": (nn.initializers.normal(0.02), self.param_dtype),
-            "ones": (nn.initializers.ones, self.param_dtype),
-            # the selection bias, float32 as in the checkpoint: see
-            # `kimi_k2._Weights`
-            "bias": (nn.initializers.normal(0.01), jnp.float32),
-            # four taps a channel, the depth-wise convolution's usual
-            # uniform(+-1/sqrt(width)): activations of order one
-            "conv": (_uniform(-0.5, 0.5), self.param_dtype),
-            # trained tensors that place the decays; drawn so that the
-            # channels spread over both ends of (exp(lower), 1): exp(A_log)
-            # in (1, 16) as Kimi Linear initialises it, the gate's bias in
-            # (-4, 1) around projections of deviation one
-            "a_log": (_uniform(1.0, 16.0, log=True), jnp.float32),
-            "dt_bias": (_uniform(-4.0, 1.0), jnp.float32),
-        }
-        return {name: self.param(name, inits[kind][0], shape,
-                                 inits[kind][1])
-                for name, (shape, kind) in self.shapes.items()}
-
-
 class LingHybrid(nn.Module):
     """`net.init` makes the weights; `apply` is the full causal forward
     (no cache, every state from zero), tokens [B, T] -> logits [B, T, V]."""
@@ -262,13 +228,8 @@ class LingHybrid(nn.Module):
     @nn.compact
     def __call__(self, tokens):
         cfg = self.config
-        top = {"wte": ((cfg.vocab_size, cfg.d_model), "w"),
-               "final_norm": ((cfg.d_model,), "ones"),
-               "lm_head": ((cfg.d_model, cfg.vocab_size), "w")}
-        p = _Weights(top, cfg.param_dtype, name="top")()
-        for i in range(cfg.n_layer):
-            p[f"layer{i}"] = _Weights(layer_shapes(cfg, i), cfg.param_dtype,
-                                      name=f"layer{i}")()
+        p = declare_weights(top_shapes(cfg), (
+            layer_shapes(cfg, i) for i in range(cfg.n_layer)), cfg.param_dtype)
         logits, *_ = _window_forward(
             p, cfg, tokens, jnp.zeros(tokens.shape[:1], jnp.int32), None,
             None, None, None)
@@ -437,7 +398,7 @@ def _kda_heads(cfg: LingHybridConfig, y):
 def _kda_out(lp, cfg: LingHybridConfig, o, gate):
     """Heads' outputs o [..., H, dv] float32 -> [..., d]: RMSNorm a head
     (one scale for all heads), the sigmoid gate, the output matrix."""
-    y = _rms(o, lp["kda_o_norm"], cfg.norm_eps, jnp.float32) * gate
+    y = rms(o, lp["kda_o_norm"], cfg.norm_eps, jnp.float32) * gate
     y = y.astype(cfg.dtype).reshape(o.shape[:-2] + (-1,))
     return y @ lp["attn_out"].astype(cfg.dtype)
 
@@ -451,14 +412,14 @@ def _mla_project(lp, cfg: LingHybridConfig, h, cos, sin):
     m, dtype = cfg.mla, cfg.dtype
     q = (h @ lp["q"].astype(dtype)).reshape(
         h.shape[:-1] + (m.n_head, m.qk_nope_dim + m.qk_rope_dim))
-    q = _rms(q, lp["q_norm"], cfg.norm_eps, dtype)
+    q = rms(q, lp["q_norm"], cfg.norm_eps, dtype)
     q_nope, q_rope = jnp.split(q, [m.qk_nope_dim], axis=-1)
-    q_rope = _rope(q_rope, cos[..., None, :], sin[..., None, :])
+    q_rope = rope(q_rope, cos[..., None, :], sin[..., None, :])
     kv = h @ lp["kv_a"].astype(dtype)
     c_kv, k_rope = jnp.split(kv, [m.kv_lora_rank], axis=-1)
-    c_kv = _rms(c_kv, lp["kv_a_norm"], cfg.norm_eps, dtype)
+    c_kv = rms(c_kv, lp["kv_a_norm"], cfg.norm_eps, dtype)
     latent = jnp.concatenate(
-        [c_kv, _rope(k_rope, cos, sin),
+        [c_kv, rope(k_rope, cos, sin),
          jnp.zeros(c_kv.shape[:-1] + (m.row_dim - m.latent_dim,), dtype)],
         axis=-1)
     gate = jax.nn.sigmoid(jnp.dot(h, lp["attn_gate"].astype(dtype),
@@ -476,27 +437,6 @@ def _mla_out(lp, cfg: LingHybridConfig, att, gate):
 def _rope_tables(cfg: LingHybridConfig, positions):
     cos, sin = yarn_tables(cfg.mla)
     return jnp.asarray(cos)[positions], jnp.asarray(sin)[positions]
-
-
-# -- the feed-forward ---------------------------------------------------------
-
-def feed_forward(lp, cfg: LingHybridConfig, i: int, h, valid):
-    """Layer i's feed-forward of h [N, d]: the dense SwiGLU, or this
-    chip's experts' part of the routed sum plus the shared expert.
-    Returns (result [N, d], counts int32[len(MOE_COUNTS)])."""
-    if i < cfg.n_dense_layer:
-        with jax.named_scope("dense_mlp"):
-            return _swiglu(h, lp["mlp_gate_up"], lp["mlp_down"],
-                           cfg.dtype), jnp.zeros(len(MOE_COUNTS), jnp.int32)
-    routed, counts = expert_shard_layer(
-        h, lp["router"], lp["router_bias"],
-        {"gate_up": lp["experts_gate_up"], "down": lp["experts_down"]},
-        cfg.first_expert, cfg.n_experts, cfg.top_k, cfg.routed_scale,
-        valid=valid, n_group=cfg.n_group, topk_group=cfg.topk_group)
-    with jax.named_scope("moe_shared"):
-        shared = _swiglu(h, lp["shared_gate_up"], lp["shared_down"],
-                         cfg.dtype)
-    return routed + shared, counts
 
 
 # -- the three steps ----------------------------------------------------------
@@ -534,7 +474,7 @@ def _window_forward(p, cfg: LingHybridConfig, tokens, start, pages,
     counts, key_slots = jnp.zeros(len(MOE_COUNTS), jnp.int32), jnp.int32(0)
     for i in range(cfg.n_layer):
         lp = p[f"layer{i}"]
-        h = _rms(x, lp["attn_norm"], cfg.norm_eps, dtype)
+        h = rms(x, lp["attn_norm"], cfg.norm_eps, dtype)
         if cfg.is_mla(i):
             q_nope, q_rope, lat, gate = _mla_project(lp, cfg, h, cos, sin)
             with jax.named_scope("mla_expanded"):
@@ -559,11 +499,12 @@ def _window_forward(p, cfg: LingHybridConfig, tokens, start, pages,
             # tail's where the window holds fewer
             tails.append(jnp.take_along_axis(
                 seen, tail_rows[..., None], axis=1).reshape(b, -1))
-        h = _rms(x, lp["mlp_norm"], cfg.norm_eps, dtype)
-        y, n = feed_forward(lp, cfg, i, h.reshape(b * c, -1), flat_valid)
+        h = rms(x, lp["mlp_norm"], cfg.norm_eps, dtype)
+        y, n = routed_feed_forward(lp, cfg, i, h.reshape(b * c, -1),
+                                   flat_valid)
         x = x + y.reshape(b, c, -1)
         counts = counts + n
-    return _head(p, cfg, x), jnp.stack(latents, axis=2), \
+    return head(p, cfg, x), jnp.stack(latents, axis=2), \
         (jnp.stack(states, axis=1), jnp.stack(tails, axis=1)), \
         _step_counts(counts, key_slots, b * len(states))
 
@@ -582,10 +523,7 @@ def prefill_step(variables, cfg: LingHybridConfig, tokens, true_len,
         valid = jnp.arange(s)[None, :] < true_len[:, None]
     logits, latents, state, counts = _window_forward(
         p, cfg, tokens, jnp.zeros((b,), jnp.int32), None, None, valid, None)
-    idx = jnp.maximum(true_len - 1, 0)
-    next_logits = jnp.take_along_axis(
-        logits, idx[:, None, None], axis=1)[:, 0]
-    return (next_logits, latents) + state + (counts,)
+    return (last_row(logits, true_len), latents) + state + (counts,)
 
 
 def chunk_step(variables, cfg: LingHybridConfig, tokens, start, pages,
@@ -628,14 +566,14 @@ def decode_step(variables, cfg: LingHybridConfig, tokens, positions, pages,
     counts = jnp.zeros(len(MOE_COUNTS), jnp.int32)
     for i in range(cfg.n_layer):
         lp = p[f"layer{i}"]
-        h = _rms(x, lp["attn_norm"], cfg.norm_eps, dtype)
+        h = rms(x, lp["attn_norm"], cfg.norm_eps, dtype)
         if cfg.is_mla(i):
             q_nope, q_rope, lat, gate = _mla_project(lp, cfg, h, cos, sin)
             with jax.named_scope("mla_absorbed"):
                 att = attend_absorbed(
                     lp, cfg.mla, q_nope, q_rope,
-                    _gather_pages(pages, page_table,
-                                  len(latents)).astype(dtype),
+                    gather_pages(pages, page_table,
+                                 len(latents)).astype(dtype),
                     lat, seen_keys)
             x = x + _mla_out(lp, cfg, att, gate)
             latents.append(lat)
@@ -649,13 +587,13 @@ def decode_step(variables, cfg: LingHybridConfig, tokens, positions, pages,
             x = x + _kda_out(lp, cfg, o, gate)
             states.append(new)
             tails.append(seen[:, 1:].reshape(b, -1))
-        h = _rms(x, lp["mlp_norm"], cfg.norm_eps, dtype)
-        y, n = feed_forward(lp, cfg, i, h, valid)
+        h = rms(x, lp["mlp_norm"], cfg.norm_eps, dtype)
+        y, n = routed_feed_forward(lp, cfg, i, h, valid)
         x = x + y
         counts = counts + n
     lanes = b if valid is None else jnp.sum(valid.astype(jnp.int32))
     # every lane of the bucket scores all of its table's slots and itself
-    return _head(p, cfg, x), jnp.stack(latents, axis=1), \
+    return head(p, cfg, x), jnp.stack(latents, axis=1), \
         jnp.stack(states, axis=1), jnp.stack(tails, axis=1), \
         _step_counts(counts, len(latents) * b * (t_max + 1),
                      lanes * len(states))

@@ -34,7 +34,9 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models.gpt import _dense as _gpt_dense, embedding_table
+from ray_tpu.models.gpt import dense, embedding_table
+from ray_tpu.models.layers import (A_HEAD, NEG_INF, last_row, rms, rope,
+                                   swiglu, unboxed_params)
 from ray_tpu.parallel.ring_attention import full_attention
 from ray_tpu.parallel.sharding import logical_constraint
 
@@ -87,10 +89,7 @@ class RMSNorm(nn.Module):
             "scale",
             nn.with_partitioning(nn.initializers.ones, ("norm",)),
             (x.shape[-1],), self.param_dtype)
-        x32 = x.astype(jnp.float32)
-        var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-        y = x32 * jax.lax.rsqrt(var + self.eps)
-        return (y * scale.astype(jnp.float32)).astype(self.dtype)
+        return rms(x, scale, self.eps, self.dtype)
 
 
 def rope_tables(seq_len: int, head_dim: int, theta: float):
@@ -103,18 +102,14 @@ def rope_tables(seq_len: int, head_dim: int, theta: float):
 
 
 def apply_rope(x, cos, sin):
-    """Rotate pairs of channels; x: [B, T, H, D] with D even."""
-    x32 = x.astype(jnp.float32)
-    x1, x2 = jnp.split(x32, 2, axis=-1)
-    c = cos[None, :x.shape[1], None, :]
-    s = sin[None, :x.shape[1], None, :]
-    return jnp.concatenate(
-        [x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(x.dtype)
+    """Rotate pairs of channels; x: [B, T, H, D] with D even; cos/sin the
+    tables' first T rows."""
+    return rope(x, cos, sin, np.s_[None, :x.shape[1], None, :])
 
 
 def _dense(features, logical_axes, name, cfg):
     # Llama uses bias-free projections throughout
-    return _gpt_dense(features, logical_axes, name, cfg, use_bias=False)
+    return dense(features, logical_axes, name, cfg, use_bias=False)
 
 
 class LlamaBlock(nn.Module):
@@ -216,34 +211,6 @@ class Llama(nn.Module):
 #     block at a time, each lane as far as its own last block (a work list
 #     of (lane, block) pairs; one lane alone walks in a loop). No layer of
 #     the arena is sliced out, no contiguous or repeated KV copy is made.
-
-NEG_INF = -1e30
-
-
-def unboxed_params(variables):
-    """Strip the {"params": ...} wrapper and nn.Partitioned boxes."""
-    p = variables["params"] if "params" in variables else variables
-    return nn.meta.unbox(p)
-
-
-def _rms(x, scale, eps, dtype):
-    # mirrors RMSNorm.__call__ op-for-op (float32 internals)
-    x32 = x.astype(jnp.float32)
-    var = jnp.mean(jnp.square(x32), axis=-1, keepdims=True)
-    y = x32 * jax.lax.rsqrt(var + eps)
-    return (y * scale.astype(jnp.float32)).astype(dtype)
-
-
-def _rope_at(x, cos_p, sin_p):
-    """apply_rope for a single position per sequence; x: [B, H, D],
-    cos_p/sin_p: [B, D/2] rows gathered at each sequence's position."""
-    x32 = x.astype(jnp.float32)
-    x1, x2 = jnp.split(x32, 2, axis=-1)
-    c = cos_p[:, None, :]
-    s = sin_p[:, None, :]
-    return jnp.concatenate(
-        [x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(x.dtype)
-
 
 # cached keys a trip of `paged_attend`'s loop, whole pages. Settled on the
 # chip at Mistral's widths (PERF.md §6, PR 31): 256 is the quickest of
@@ -491,22 +458,7 @@ def prefill_step(variables, cfg: LlamaConfig, tokens, true_len):
           for i in range(cfg.n_layer)]
     k = jnp.stack(ks, axis=2)  # [B, S, L, KVH, D]
     v = jnp.stack(vs, axis=2)
-    idx = jnp.maximum(true_len - 1, 0)
-    next_logits = jnp.take_along_axis(
-        logits, idx[:, None, None], axis=1)[:, 0]
-    return next_logits, k, v
-
-
-def _rope_chunk(x, cos_p, sin_p):
-    """apply_rope for a window of positions per sequence; x:
-    [B, C, H, D], cos_p/sin_p: [B, C, D/2] rows gathered at each
-    sequence's window positions."""
-    x32 = x.astype(jnp.float32)
-    x1, x2 = jnp.split(x32, 2, axis=-1)
-    c = cos_p[:, :, None, :]
-    s = sin_p[:, :, None, :]
-    return jnp.concatenate(
-        [x1 * c - x2 * s, x2 * c + x1 * s], axis=-1).astype(x.dtype)
+    return last_row(logits, true_len), k, v
 
 
 def paged_attend_chunk(q, k_new, v_new, k_pages_l, v_pages_l, page_table,
@@ -590,26 +542,24 @@ def chunk_step(variables, cfg: LlamaConfig, tokens, start,
     new_ks, new_vs = [], []
     for i in range(cfg.n_layer):
         lp = p[f"layer{i}"]
-        h = _rms(x, lp["attn_norm"]["scale"], cfg.norm_eps, dtype)
+        h = rms(x, lp["attn_norm"]["scale"], cfg.norm_eps, dtype)
         fused = h @ lp["attn_qkv"]["kernel"].astype(dtype)
         q, k, v = jnp.split(
             fused, [cfg.n_head * hd, (cfg.n_head + cfg.n_kv_head) * hd],
             axis=-1)
-        q = _rope_chunk(q.reshape(b, c, cfg.n_head, hd), cos_p, sin_p)
-        k = _rope_chunk(k.reshape(b, c, cfg.n_kv_head, hd), cos_p, sin_p)
+        q = rope(q.reshape(b, c, cfg.n_head, hd), cos_p, sin_p, A_HEAD)
+        k = rope(k.reshape(b, c, cfg.n_kv_head, hd), cos_p, sin_p, A_HEAD)
         v = v.reshape(b, c, cfg.n_kv_head, hd)
         att = paged_attend_chunk(q, k, v, k_pages[:, i], v_pages[:, i],
                                  page_table, valid, scale)
         x = x + att.reshape(b, c, cfg.d_model) @ \
             lp["attn_out"]["kernel"].astype(dtype)
-        h = _rms(x, lp["mlp_norm"]["scale"], cfg.norm_eps, dtype)
-        gu = h @ lp["mlp_gate_up"]["kernel"].astype(dtype)
-        gate, up = jnp.split(gu, 2, axis=-1)
-        x = x + (nn.silu(gate) * up) @ \
-            lp["mlp_down"]["kernel"].astype(dtype)
+        h = rms(x, lp["mlp_norm"]["scale"], cfg.norm_eps, dtype)
+        x = x + swiglu(h, lp["mlp_gate_up"]["kernel"],
+                       lp["mlp_down"]["kernel"], dtype)
         new_ks.append(k)
         new_vs.append(v)
-    x = _rms(x, p["final_norm"]["scale"], cfg.norm_eps, dtype)
+    x = rms(x, p["final_norm"]["scale"], cfg.norm_eps, dtype)
     logits = jnp.einsum("bcd,vd->bcv", x, wte)
     return logits, jnp.stack(new_ks, axis=2), jnp.stack(new_vs, axis=2)
 
@@ -642,26 +592,24 @@ def decode_step(variables, cfg: LlamaConfig, tokens, positions,
     new_ks, new_vs = [], []
     for i in range(cfg.n_layer):
         lp = p[f"layer{i}"]
-        h = _rms(x, lp["attn_norm"]["scale"], cfg.norm_eps, dtype)
+        h = rms(x, lp["attn_norm"]["scale"], cfg.norm_eps, dtype)
         fused = h @ lp["attn_qkv"]["kernel"].astype(dtype)
         q, k, v = jnp.split(
             fused, [cfg.n_head * hd, (cfg.n_head + cfg.n_kv_head) * hd],
             axis=-1)
-        q = _rope_at(q.reshape(b, cfg.n_head, hd), cos_p, sin_p)
-        k = _rope_at(k.reshape(b, cfg.n_kv_head, hd), cos_p, sin_p)
+        q = rope(q.reshape(b, cfg.n_head, hd), cos_p, sin_p, A_HEAD)
+        k = rope(k.reshape(b, cfg.n_kv_head, hd), cos_p, sin_p, A_HEAD)
         v = v.reshape(b, cfg.n_kv_head, hd)
         att = paged_attend(q, k, v, k_pages, v_pages, i, page_table,
                            positions, scale)
         x = x + att.reshape(b, cfg.d_model) @ \
             lp["attn_out"]["kernel"].astype(dtype)
-        h = _rms(x, lp["mlp_norm"]["scale"], cfg.norm_eps, dtype)
-        gu = h @ lp["mlp_gate_up"]["kernel"].astype(dtype)
-        gate, up = jnp.split(gu, 2, axis=-1)
-        x = x + (nn.silu(gate) * up) @ \
-            lp["mlp_down"]["kernel"].astype(dtype)
+        h = rms(x, lp["mlp_norm"]["scale"], cfg.norm_eps, dtype)
+        x = x + swiglu(h, lp["mlp_gate_up"]["kernel"],
+                       lp["mlp_down"]["kernel"], dtype)
         new_ks.append(k)
         new_vs.append(v)
-    x = _rms(x, p["final_norm"]["scale"], cfg.norm_eps, dtype)
+    x = rms(x, p["final_norm"]["scale"], cfg.norm_eps, dtype)
     logits = jnp.einsum("bd,vd->bv", x, wte)
     return logits, jnp.stack(new_ks, axis=1), jnp.stack(new_vs, axis=1)
 

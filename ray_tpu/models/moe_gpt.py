@@ -19,7 +19,7 @@ import flax.linen as nn
 import jax
 import jax.numpy as jnp
 
-from ray_tpu.models.gpt import GPTConfig, _dense
+from ray_tpu.models.gpt import GPTConfig, dense
 from ray_tpu.parallel.ring_attention import full_attention
 from ray_tpu.parallel.sharding import logical_constraint
 
@@ -117,7 +117,7 @@ class MoEBlock(nn.Module):
         head_dim = cfg.d_model // cfg.n_head
         h = nn.LayerNorm(dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                          name="ln_1")(x)
-        qkv = _dense(3 * cfg.d_model, ("embed", "qkv"), "attn_qkv",
+        qkv = dense(3 * cfg.d_model, ("embed", "qkv"), "attn_qkv",
                      cfg)(h)
         q, k, v = jnp.split(qkv, 3, axis=-1)
         b, t = q.shape[0], q.shape[1]
@@ -126,7 +126,7 @@ class MoEBlock(nn.Module):
         v = v.reshape(b, t, cfg.n_head, head_dim)
         attend = self.attention_fn or partial(full_attention, causal=True)
         att = attend(q, k, v).reshape(b, t, cfg.d_model)
-        x = x + _dense(cfg.d_model, ("heads", "embed"), "attn_out",
+        x = x + dense(cfg.d_model, ("heads", "embed"), "attn_out",
                        cfg)(att)
         h = nn.LayerNorm(dtype=cfg.dtype, param_dtype=cfg.param_dtype,
                          name="ln_2")(x)

@@ -27,9 +27,9 @@ What it asks of the system that no other family does:
   counted and does not reach the logits. Any other threshold is refused: a
   pass count a lane is the scheduler's work (ROADMAP R26).
 
-Shared with `llama.py`: `_rms`, the rotation, `paged_attend` (the decode
-step's walk over cached key blocks) and `paged_attend_chunk`; with
-`kimi_k2.py`: `_swiglu`, `unboxed_params`.
+From `layers.py`: `rms`, the rotation, `swiglu`, the weights' declaration.
+Shared with `llama.py`: `paged_attend` (the decode step's walk over cached
+key blocks) and `paged_attend_chunk`.
 
 Parameters: `wte`, `layer<i>/{attn_norm, attn_qkv, attn_out, post_attn_norm,
 mlp_norm, mlp_gate_up, mlp_down, post_mlp_norm}`, `final_norm`, `exit_gate`,
@@ -46,11 +46,11 @@ import jax
 import jax.numpy as jnp
 from flax.linen.initializers import constant, ones
 
-from ray_tpu.models.kimi_k2 import _swiglu, unboxed_params
+from ray_tpu.models.layers import (A_HEAD, declare_weights, last_row, rms,
+                                   rope, swiglu, unboxed_params)
 # `decode_key_walk` by its own name: the engine asks a family's module for it
 # and counts `decode_attn_key_slots` on the host with the program's function
-from ray_tpu.models.llama import (_rms, _rope_chunk,
-                                  chunk_valid_mask, decode_key_walk,
+from ray_tpu.models.llama import (chunk_valid_mask, decode_key_walk,
                                   paged_attend, paged_attend_chunk,
                                   rope_tables)
 from ray_tpu.parallel.ring_attention import full_attention
@@ -174,19 +174,6 @@ def top_shapes(cfg: OuroConfig) -> dict:
             "lm_head": ((d, cfg.vocab_size), _normal(0.02))}
 
 
-class _Weights(nn.Module):
-    """Declares one group of parameters and returns them as a dict. (Kimi's
-    class of this name takes a KIND a name and looks its initializer up in a
-    table of its own; here a name brings its initializer.)"""
-    shapes: Any
-    param_dtype: Any
-
-    @nn.compact
-    def __call__(self):
-        return {name: self.param(name, init, shape, self.param_dtype)
-                for name, (shape, init) in self.shapes.items()}
-
-
 class Ouro(nn.Module):
     """`net.init` makes the weights; `apply` is the full causal forward (no
     cache), tokens [B, T] -> logits [B, T, V]."""
@@ -195,10 +182,9 @@ class Ouro(nn.Module):
     @nn.compact
     def __call__(self, tokens):
         cfg = self.config
-        p = _Weights(top_shapes(cfg), cfg.param_dtype, name="top")()
-        for i in range(cfg.n_layer):
-            p[f"layer{i}"] = _Weights(layer_shapes(cfg), cfg.param_dtype,
-                                      name=f"layer{i}")()
+        p = declare_weights(top_shapes(cfg),
+                            [layer_shapes(cfg)] * cfg.n_layer,
+                            cfg.param_dtype)
         b, t = tokens.shape
         x, _, _ = _loop_forward(
             p, cfg, tokens, jnp.broadcast_to(jnp.arange(t), (b, t)),
@@ -250,24 +236,25 @@ def _loop_forward(p, cfg: OuroConfig, tokens, positions, attend, valid_rows):
         with jax.named_scope("loop_pass"):
             for i in range(cfg.n_layer):
                 lp = p[f"layer{i}"]
-                h = _rms(x, lp["attn_norm"], eps, dtype)
+                h = rms(x, lp["attn_norm"], eps, dtype)
                 q, k, v = jnp.split(h @ lp["attn_qkv"].astype(dtype),
                                     [n_q, n_q + n_kv], axis=-1)
-                q = _rope_chunk(q.reshape(b, c, cfg.n_head, hd), cos, sin)
-                k = _rope_chunk(k.reshape(b, c, cfg.n_kv_head, hd), cos, sin)
+                q = rope(q.reshape(b, c, cfg.n_head, hd), cos, sin, A_HEAD)
+                k = rope(k.reshape(b, c, cfg.n_kv_head, hd), cos, sin,
+                         A_HEAD)
                 v = v.reshape(b, c, cfg.n_kv_head, hd)
                 with jax.named_scope("attn_full"):
                     att = attend(q, k, v, t * cfg.n_layer + i)
                 att = att.reshape(b, c, n_q).astype(dtype)
-                x = x + _rms(att @ lp["attn_out"].astype(dtype),
+                x = x + rms(att @ lp["attn_out"].astype(dtype),
                              lp["post_attn_norm"], eps, jnp.float32)
-                h = _rms(x, lp["mlp_norm"], eps, dtype)
-                x = x + _rms(_swiglu(h, lp["mlp_gate_up"], lp["mlp_down"],
+                h = rms(x, lp["mlp_norm"], eps, dtype)
+                x = x + rms(swiglu(h, lp["mlp_gate_up"], lp["mlp_down"],
                                      dtype), lp["post_mlp_norm"], eps,
                              jnp.float32)
                 ks.append(k)
                 vs.append(v)
-            x = _rms(x, p["final_norm"], eps, jnp.float32)
+            x = rms(x, p["final_norm"], eps, jnp.float32)
             with jax.named_scope("exit_gate"):
                 gate = jax.nn.sigmoid(x @ gate_w + gate_b)
         return x, (gate, jnp.stack(ks, axis=2), jnp.stack(vs, axis=2))
@@ -311,9 +298,7 @@ def prefill_step(variables, cfg: OuroConfig, tokens, true_len, valid=None):
     x, rows, counts = _loop_forward(
         p, cfg, tokens, jnp.broadcast_to(jnp.arange(s), (b, s)),
         _attend_prefill, valid)
-    idx = jnp.maximum(true_len - 1, 0)
-    last = jnp.take_along_axis(x, idx[:, None, None], axis=1)[:, 0]
-    return (_head(p, cfg, last), *rows, counts)
+    return (_head(p, cfg, last_row(x, true_len)), *rows, counts)
 
 
 def chunk_step(variables, cfg: OuroConfig, tokens, start, k_pages, v_pages,

@@ -23,10 +23,11 @@ What it asks of the system that `llama.py` does not:
 - A prefill yields no token: `prefill_step` and `chunk_step` return None
   where the other families return logits, and compute no head.
 
-Shared with `llama.py`: `rope_tables`, the rotation, `_rms`, and the walk
-over cached key blocks (`key_block_trips`: blocks of `KEY_BLOCK` slots as
-far as the batch's longest lane). Shared with `kimi_k2.py` /
-`ling_hybrid.py`: `parallel.moe.expert_shard_layer` (here told to route by
+From `layers.py`: `rms`, the rotation, the weights' declaration. Shared with
+`llama.py`: `rope_tables` and the walk over cached key blocks
+(`key_block_trips`: blocks of `KEY_BLOCK` slots as far as the batch's
+longest lane). Shared with `kimi_k2.py` / `ling_hybrid.py`:
+`parallel.moe.expert_shard_layer` (here told to route by
 `softmax_topk_route`) and `MOE_COUNTS`.
 
 Parameters: `wte`, `layer<i>/{attn_norm, attn_qkv, q_norm, k_norm,
@@ -45,8 +46,9 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import llama as _llama
-from ray_tpu.models.kimi_k2 import _Weights, unboxed_params
-from ray_tpu.models.llama import NEG_INF, _rms, _rope_chunk, rope_tables
+from ray_tpu.models.layers import (A_HEAD, NEG_INF, declare_weights, rms,
+                                   rope, top_shapes, unboxed_params)
+from ray_tpu.models.llama import rope_tables
 from ray_tpu.parallel.moe import (MOE_COUNTS, expert_shard_layer,
                                   softmax_topk_route)
 
@@ -109,7 +111,7 @@ def block_schedule(cfg: SdarMoeConfig) -> Tuple[int, int, int]:
 # -- the weights --------------------------------------------------------------
 
 def layer_shapes(cfg: SdarMoeConfig) -> dict:
-    """name -> (shape, kind) of a layer's parameters."""
+    """name -> (shape, kind of `layers.INITS`) of a layer's parameters."""
     d, hd, f = cfg.d_model, cfg.head_dim, cfg.moe_ffn_dim
     return {
         "attn_norm": ((d,), "ones"),
@@ -133,13 +135,9 @@ class SdarMoe(nn.Module):
     @nn.compact
     def __call__(self, tokens):
         cfg = self.config
-        top = {"wte": ((cfg.vocab_size, cfg.d_model), "w"),
-               "final_norm": ((cfg.d_model,), "ones"),
-               "lm_head": ((cfg.d_model, cfg.vocab_size), "w")}
-        p = _Weights(top, cfg.param_dtype, name="top")()
-        for i in range(cfg.n_layer):
-            p[f"layer{i}"] = _Weights(layer_shapes(cfg), cfg.param_dtype,
-                                      name=f"layer{i}")()
+        p = declare_weights(top_shapes(cfg),
+                            [layer_shapes(cfg)] * cfg.n_layer,
+                            cfg.param_dtype)
         x, _, _, _ = _window_forward(
             p, cfg, tokens, jnp.zeros(tokens.shape[:1], jnp.int32), None,
             None, None)
@@ -230,7 +228,7 @@ def feed_forward(lp, cfg: SdarMoeConfig, h, valid):
 
 def head_logits(p, cfg: SdarMoeConfig, x):
     """x [..., d] (the last layer's output) -> logits [..., V] float32."""
-    h = _rms(x, p["final_norm"], cfg.norm_eps, cfg.dtype)
+    h = rms(x, p["final_norm"], cfg.norm_eps, cfg.dtype)
     return jnp.dot(h, p["lm_head"].astype(cfg.dtype),
                    preferred_element_type=jnp.float32)
 
@@ -274,19 +272,19 @@ def _window_forward(p, cfg: SdarMoeConfig, tokens, start, pages, page_table,
     n_q, n_kv = cfg.n_head * hd, cfg.n_kv_head * hd
     for i in range(cfg.n_layer):
         lp = p[f"layer{i}"]
-        h = _rms(x, lp["attn_norm"], cfg.norm_eps, dtype)
+        h = rms(x, lp["attn_norm"], cfg.norm_eps, dtype)
         q, k, v = jnp.split(h @ lp["attn_qkv"].astype(dtype),
                             [n_q, n_q + n_kv], axis=-1)
-        q = _rms(q.reshape(b, c, cfg.n_head, hd), lp["q_norm"],
+        q = rms(q.reshape(b, c, cfg.n_head, hd), lp["q_norm"],
                  cfg.norm_eps, dtype)
-        k = _rms(k.reshape(b, c, cfg.n_kv_head, hd), lp["k_norm"],
+        k = rms(k.reshape(b, c, cfg.n_kv_head, hd), lp["k_norm"],
                  cfg.norm_eps, dtype)
-        q, k = _rope_chunk(q, cos, sin), _rope_chunk(k, cos, sin)
+        q, k = rope(q, cos, sin, A_HEAD), rope(k, cos, sin, A_HEAD)
         v = v.reshape(b, c, cfg.n_kv_head, hd)
         att = block_attend(q, k, v, pages, i, page_table, start,
                            block_length=cfg.block_length, scale=hd ** -0.5)
         x = x + att @ lp["attn_out"].astype(dtype)
-        h = _rms(x, lp["mlp_norm"], cfg.norm_eps, dtype)
+        h = rms(x, lp["mlp_norm"], cfg.norm_eps, dtype)
         y, n = feed_forward(lp, cfg, h.reshape(b * c, -1), flat_valid)
         x = x + y.reshape(b, c, -1)
         counts = counts + n

@@ -104,68 +104,28 @@ class RequestRejected(RuntimeError):
 
 @dataclasses.dataclass(frozen=True)
 class ModelFamily:
-    """What the engine needs to know of a model family: the module under
-    `ray_tpu.models` with its three step functions (imported when the
-    family is selected, never before), the flax module that makes weights
-    and its config class (`.tiny()` is the default model), and what a token
-    leaves in the cache. `cache_rows` names the module's function from a
-    config to the row shapes, one arena array each; None is K and V of
-    [n_kv_head, head_dim]. `step_counts` names the module's tuple of
-    counter names: its steps then take `valid=` (the rows that are tokens)
-    and return an int32 vector of that length after the cache rows, which
-    the engine adds to `decode_<name>` / `prefill_<name>`. `seq_state`
-    names the module's function from a config to what a SEQUENCE keeps
-    beside its pages (one (shape, dtype) an array: a recurrent layer's
-    state): the cache manager then keeps a slot a sequence, the chunk and
-    decode steps take the arena's arrays and the lanes' slots (`seq_state=`,
-    `slots=`), and every step returns the sequences' new states after the
-    cache rows. `paged_layers` names the module's function from a config to
-    how many layers of rows a token leaves in the paged arena (the model's
-    layers where it is not given; fewer where some keep a state instead,
-    more where the stack runs several times a token and each pass keeps its
-    own). `block_schedule` names the module's function from a
-    config to (positions a block, positions a pass reveals, the mask token)
-    of a family that generates by diffusion over blocks: its prefill steps
-    cover the whole blocks of a prompt and return None for logits (a
-    prefill yields no token), and its decode step takes one block a lane
-    ([lanes, block] tokens, the block's first position) and returns, first,
-    the (token, probability) it chose at every position, then the block's
-    cache rows, which the engine writes for the lanes whose block is whole
-    (`_decode_blocks`). `page_kinds` names the module's function from a
-    config to the kinds of its paged layers where they differ in how much
-    of a sequence they read (the fields of `kv_cache.PageKind`, a tuple a
-    kind: name, layers, rows, window): the arena then holds each kind's
-    arrays in that order, the chunk and decode steps take a page table a
-    kind after all the arrays, every step returns its cache rows in the
-    arrays' order, and `cache_rows` / `paged_layers` are not read."""
+    """A row of the registry: the module under `ray_tpu.models` with its
+    three step functions (imported when the family is selected, never
+    before), the flax module that makes weights and its config class
+    (`.tiny()` is the default model). What a token leaves in the cache is the
+    module's own to say, under the names `_family_cache` reads."""
 
     module: str
     net: str
     config: str
-    cache_rows: Optional[str] = None
-    step_counts: Optional[str] = None
-    seq_state: Optional[str] = None
-    paged_layers: Optional[str] = None
-    block_schedule: Optional[str] = None
-    page_kinds: Optional[str] = None
 
 
 MODEL_FAMILIES: Dict[str, ModelFamily] = {
     "llama": ModelFamily("ray_tpu.models.llama", "Llama", "LlamaConfig"),
     "gpt": ModelFamily("ray_tpu.models.gpt", "GPT", "GPTConfig"),
     "kimi_k2": ModelFamily("ray_tpu.models.kimi_k2", "KimiK2",
-                           "KimiK2Config", "cache_rows", "STEP_COUNTS"),
+                           "KimiK2Config"),
     "ling_hybrid": ModelFamily("ray_tpu.models.ling_hybrid", "LingHybrid",
-                               "LingHybridConfig", "cache_rows",
-                               "STEP_COUNTS", "seq_state", "paged_layers"),
+                               "LingHybridConfig"),
     "sdar_moe": ModelFamily("ray_tpu.models.sdar_moe", "SdarMoe",
-                            "SdarMoeConfig", "cache_rows", "STEP_COUNTS",
-                            block_schedule="block_schedule"),
-    "afmoe": ModelFamily("ray_tpu.models.afmoe", "Afmoe", "AfmoeConfig",
-                         step_counts="STEP_COUNTS", page_kinds="page_kinds"),
-    "ouro": ModelFamily("ray_tpu.models.ouro", "Ouro", "OuroConfig",
-                        step_counts="STEP_COUNTS",
-                        paged_layers="paged_layers"),
+                            "SdarMoeConfig"),
+    "afmoe": ModelFamily("ray_tpu.models.afmoe", "Afmoe", "AfmoeConfig"),
+    "ouro": ModelFamily("ray_tpu.models.ouro", "Ouro", "OuroConfig"),
 }
 
 
@@ -223,6 +183,54 @@ def _kv_rows(cfg) -> Tuple[Tuple[int, int], ...]:
     if n_kv_head is None:
         n_kv_head = cfg.n_head
     return ((n_kv_head, cfg.d_model // cfg.n_head),) * 2
+
+
+def _family_cache(mod, cfg):
+    """What a family's module `mod` declares of its cache for the model
+    `cfg`, read here and nowhere else: (page kinds, sequence state, step
+    counters, block schedule, key walk). The names a family file may define
+    beside `prefill_step`, `chunk_step` and `decode_step`, each optional:
+
+    - `page_kinds(cfg)` -> a tuple of `kv_cache.PageKind`'s fields (name,
+      layers, rows, window) a kind, where the paged layers differ in how much
+      of a sequence they read: the arena holds each kind's arrays in that
+      order, the chunk and decode steps take a page table a kind after all
+      the arrays, and every step returns its cache rows in the arrays' order.
+      Where it is not given there is one kind, `full`, of:
+    - `paged_layers(cfg)` -> int, the layers of rows a token leaves in the
+      arena (default `cfg.n_layer`; fewer where some layers keep a state
+      instead, more where the stack runs several times a token), and
+    - `cache_rows(cfg)` -> the row shapes, one arena array each (default K
+      and V of [n_kv_head, d_model // n_head]).
+    - `seq_state(cfg)` -> one (shape, dtype) an array of what a SEQUENCE
+      keeps beside its pages (a recurrent layer's state): the cache manager
+      keeps a slot a sequence, the chunk and decode steps take the arena's
+      arrays and the lanes' slots (`seq_state=`, `slots=`), and every step
+      returns the sequences' new states after the cache rows.
+    - `STEP_COUNTS` -> a tuple of counter names: the steps take `valid=` (the
+      rows that are tokens) and return an int32 vector of that length last,
+      which the engine adds to `decode_<name>` / `prefill_<name>`.
+    - `block_schedule(cfg)` -> (positions a block, positions a pass reveals,
+      the mask token) of a family that generates by diffusion over blocks:
+      its prefill steps cover a prompt's whole blocks and return None for
+      logits, and its decode step takes one block a lane and returns, first,
+      the (token, probability) it chose at every position, then the block's
+      cache rows (`_decode_blocks`).
+    - `decode_key_walk(cfg, positions, n_pages, page, xp)` -> (trips, blocks
+      a trip, keys a block, the work list) of a decode step that walks
+      `llama.paged_attend`: the engine calls it on the host to count what
+      the program scored (`decode_attn_key_slots`)."""
+    def declared(name, default=None):
+        fn = getattr(mod, name, None)
+        return default if fn is None else fn(cfg)
+
+    kinds = declared("page_kinds")
+    if kinds is None:
+        kinds = (("full", declared("paged_layers", cfg.n_layer),
+                  declared("cache_rows") or _kv_rows(cfg)),)
+    return (tuple(PageKind(*kind) for kind in kinds),
+            declared("seq_state", ()), tuple(getattr(mod, "STEP_COUNTS", ())),
+            declared("block_schedule"), getattr(mod, "decode_key_walk", None))
 
 
 # The pump thread's time ledger (util/tracing.PhaseTable): between start()
@@ -444,24 +452,17 @@ class LLMEngine:
         family, mod = model_family(model)
         self.model_cfg = model_cfg or getattr(mod, family.config).tiny(
             dtype=jnp.float32)
-        self._cache_rows = getattr(mod, family.cache_rows) \
-            if family.cache_rows else _kv_rows
-        self._step_counts: Tuple[str, ...] = tuple(
-            getattr(mod, family.step_counts)) if family.step_counts else ()
-        # a family whose decode step is `llama.paged_attend` names the
-        # function that lays out its walk over the cached keys: the engine
-        # calls it on the host to count what the program scored
-        self._key_walk = getattr(mod, "decode_key_walk", None)
+        # `_block`: (positions a block, positions a pass reveals, the mask
+        # token) of a family that generates by diffusion over blocks, else
+        # None; `_key_walk`: the layout of a `llama.paged_attend` decode
+        # step's walk over the cached keys, for the host's count, else None
+        kinds, seq_state, self._step_counts, self._block, self._key_walk = \
+            _family_cache(mod, self.model_cfg)
         self.model_name = model
         self._mod = mod
         cfg = (engine_config or EngineConfig()).resolved(
             self.model_cfg.max_seq_len)
         self.config = cfg
-        # (positions a block, positions a pass reveals, the mask token) of
-        # a family that generates by diffusion over blocks, else None
-        self._block: Optional[Tuple[int, int, int]] = getattr(
-            mod, family.block_schedule)(self.model_cfg) \
-            if family.block_schedule else None
         if self._block is not None:
             self._check_block_config(model, cfg)
         self.max_pages_per_seq = -(-self.model_cfg.max_seq_len
@@ -481,22 +482,12 @@ class LLMEngine:
         self._pump_phase: Optional[_tracing.Phase] = None
         self._pump_wall_ns = 0  # of pump threads that have ended
 
-        seq_state = getattr(mod, family.seq_state)(self.model_cfg) \
-            if family.seq_state else ()
         if seq_state and cfg.prefix_cache:
             raise ValueError(
                 f"prefix_cache=1 with the {model!r} family: its layers keep "
                 f"one state a sequence, which a page alias cannot restore "
                 f"(a prefix hit would start the suffix from a state that "
                 f"never saw the prefix); pass prefix_cache=0")
-        if family.page_kinds:
-            kinds = tuple(PageKind(*kind) for kind in getattr(
-                mod, family.page_kinds)(self.model_cfg))
-        else:
-            kinds = (PageKind(
-                "full", getattr(mod, family.paged_layers)(self.model_cfg)
-                if family.paged_layers else self.model_cfg.n_layer,
-                self._cache_rows(self.model_cfg)),)
         if cfg.prefix_cache and any(kind.window for kind in kinds):
             raise ValueError(
                 f"prefix_cache=1 with the {model!r} family: its window "

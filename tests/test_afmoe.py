@@ -22,6 +22,7 @@ import pytest
 
 from benchmark.references import afmoe as ref
 from ray_tpu.models import afmoe as A
+from ray_tpu.models import layers
 from ray_tpu.models.llama import (KEY_BLOCK, key_block_pairs,
                                   key_block_trips)
 from ray_tpu.parallel.moe import MOE_COUNTS
@@ -357,9 +358,9 @@ def test_the_shares_of_all_chips_add_up_to_the_uncut_layer():
     h = jnp.asarray(np.random.default_rng(2).normal(size=(24, cfg.d_model)),
                     jnp.float32)
     with jax.default_matmul_precision("highest"):
-        whole, counts = A.feed_forward(lp, cfg, 2, h, None)
-        shared = A._swiglu(h, lp["shared_gate_up"], lp["shared_down"],
-                           cfg.dtype)
+        whole, counts = layers.routed_feed_forward(lp, cfg, 2, h, None)
+        shared = layers.swiglu(h, lp["shared_gate_up"], lp["shared_down"],
+                               cfg.dtype)
         total, local = shared, 0
         for first in range(0, 16, 4):
             share = dataclasses.replace(cfg, experts_held=4,
@@ -367,7 +368,7 @@ def test_the_shares_of_all_chips_add_up_to_the_uncut_layer():
             part = {**lp, "experts_gate_up": lp["experts_gate_up"][
                 first:first + 4], "experts_down": lp["experts_down"][
                 first:first + 4]}
-            y, n = A.feed_forward(part, share, 2, h, None)
+            y, n = layers.routed_feed_forward(part, share, 2, h, None)
             total = total + (y - shared)
             local += int(n[MOE_COUNTS.index("pairs_local")])
     np.testing.assert_allclose(total, whole, atol=1e-5)

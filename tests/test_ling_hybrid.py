@@ -17,8 +17,7 @@ import pytest
 
 from ray_tpu.models import ling_hybrid as lh
 from ray_tpu.parallel import moe
-from ray_tpu.serve.llm.engine import (MODEL_FAMILIES, EngineConfig,
-                                      LLMEngine)
+from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
 
 LOWER = -5.0
 
@@ -465,7 +464,11 @@ def test_slots_are_taken_with_the_pages_and_freed_at_the_end():
     from ray_tpu.serve.llm import KVCacheError
 
     eng = _engine()
-    assert MODEL_FAMILIES["ling_hybrid"].seq_state == "seq_state"
+    # the family's module declares what a sequence keeps beside its pages,
+    # and the engine built its state arena from that: a row a slot
+    assert [(a.shape[1:], a.dtype) for a in eng.kv.state] == [
+        (shape, jnp.dtype(dtype))
+        for shape, dtype in lh.seq_state(eng.model_cfg)]
     assert eng.kv.num_slots == 4 and eng.kv.scratch_slot == 4
     assert [a.shape[0] for a in eng.kv.state] == [5, 5]
     assert eng.kv.arena[0].shape[1] == 1            # one paged layer of 7

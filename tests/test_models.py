@@ -373,3 +373,79 @@ def test_bert_shards_like_the_decoders():
     np.testing.assert_allclose(np.asarray(out, np.float32),
                                np.asarray(ref, np.float32),
                                atol=5e-2, rtol=5e-2)
+
+
+def test_families_share_layers_by_public_names_alone():
+    """A family file composes `models/layers.py`; what it takes from another
+    family it takes by a public name (`from ...<family> import _name` and
+    `<family>._name` made one family everyone's library, PR 52), `layers.py`
+    imports no family, the families that have nothing of Kimi's do not
+    import it, and a registry row stays (module, net, config): what a family
+    leaves in the cache is its module's own names, read by
+    `engine._family_cache`."""
+    import ast
+    import dataclasses
+    import pathlib
+
+    import ray_tpu.models
+    from ray_tpu.serve.llm.engine import MODEL_FAMILIES, ModelFamily
+
+    root = pathlib.Path(ray_tpu.models.__file__).parent
+    package = "ray_tpu.models"
+    bad, imports = [], {}
+    for path in sorted(root.glob("*.py")):
+        tree = ast.parse(path.read_text())
+        siblings = {}       # local name -> the sibling module it stands for
+        for node in ast.walk(tree):
+            if isinstance(node, ast.ImportFrom) and node.module \
+                    and node.module.startswith(package):
+                if node.module == package:      # from ray_tpu.models import x
+                    siblings.update({a.asname or a.name: a.name
+                                     for a in node.names})
+                    continue
+                imports.setdefault(path.stem, set()).add(
+                    node.module[len(package) + 1:])
+                bad += [f"{path.name}:{node.lineno} from {node.module} "
+                        f"import {a.name}" for a in node.names
+                        if a.name.startswith("_")]
+            elif isinstance(node, ast.Import):
+                siblings.update({
+                    a.asname: a.name[len(package) + 1:] for a in node.names
+                    if a.asname and a.name.startswith(package + ".")})
+        imports.setdefault(path.stem, set()).update(siblings.values())
+        bad += [f"{path.name}:{node.lineno} {node.value.id}.{node.attr}"
+                for node in ast.walk(tree)
+                if isinstance(node, ast.Attribute)
+                and isinstance(node.value, ast.Name)
+                and node.value.id in siblings and node.attr.startswith("_")]
+    assert not bad, bad
+    assert imports["layers"] == set()
+    for family in ("sdar_moe", "afmoe", "ouro"):
+        assert "kimi_k2" not in imports[family], (family, imports[family])
+    assert {row.module.rsplit(".", 1)[1] for row in MODEL_FAMILIES.values()} \
+        <= set(imports)
+    assert [f.name for f in dataclasses.fields(ModelFamily)] \
+        == ["module", "net", "config"]
+
+
+def test_gpt_call_sites_above_the_flash_kernel_keep_their_lines():
+    """A Pallas kernel's serialized body carries the file and LINE of every
+    Python frame that reaches it, and that body is part of the training
+    step's lowered text, so of its compile-cache key (found in PR 52: with
+    `_dense` moved out of `gpt.py` the step of `gpt2-medium.pretrain`, the
+    cell with the flash kernel, took a new key though no op had moved). The
+    two frames of `models/gpt.py` on the way to `ops.flash_attention` stand
+    where they stood on bdee1a2; a PR that moves them recompiles that cell
+    (78 MB, 40 s of its first `setup_s`) and changes these numbers on
+    purpose."""
+    import inspect
+
+    from ray_tpu.models import gpt
+
+    def line_of(fn, text):
+        lines, first = inspect.getsourcelines(fn)
+        (at,) = [i for i, line in enumerate(lines) if text in line]
+        return first + at
+
+    assert line_of(gpt.Block.__call__, "attend(q, k, v)") == 111
+    assert line_of(gpt.GPT.__call__, "(x, deterministic)") == 173

@@ -275,8 +275,11 @@ def test_lineage_cap_eviction_marks_unreconstructable():
                  _system_config={"max_lineage_bytes": 2048})
     try:
         cw = get_core_worker()
+        # hard affinity: a soft one lets a busy run place some of the 16
+        # on the other node, and an object made there outlives the victim
+        # (the flake of PR 25, 29, 44, 47 and 50: `DID NOT RAISE`)
         affinity = ray_tpu.NodeAffinitySchedulingStrategy(
-            victim.node_id_hex, soft=True)
+            victim.node_id_hex, soft=False)
 
         @ray_tpu.remote(scheduling_strategy=affinity)
         def produce(i):
@@ -285,19 +288,33 @@ def test_lineage_cap_eviction_marks_unreconstructable():
         refs = [produce.remote(i) for i in range(16)]
         ready, _ = ray_tpu.wait(refs, num_returns=len(refs), timeout=90)
         assert len(ready) == len(refs)
+        # what the test is about to lose lives on the victim and nowhere else
+        for ref in refs:
+            assert cw.memory_store.locations.get(ref.binary()) \
+                == [victim.raylet_addr], ref
         assert cw._stats_lineage_evictions > 0, \
             "16 specs against a 2KB cap must evict"
         assert cw._lineage_bytes <= 2048
+        # a spec's lineage is retained when its reply arrives, so "oldest"
+        # is by completion: under load a worker that is slow to start can
+        # hand back task 0 after eight others, and then `refs[0]` is one of
+        # the young (the other half of the flake). Ask the owner which went
+        evicted = [i for i, ref in enumerate(refs)
+                   if ref.binary() in cw._lineage_evicted]
+        kept = [i for i, ref in enumerate(refs)
+                if ref.binary() in cw._lineage_oids]
+        assert len(evicted) == cw._stats_lineage_evictions and kept
+        assert sorted(evicted + kept) == list(range(16))
 
         cluster.remove_node(victim)
         time.sleep(1.0)
 
-        # oldest spec was evicted → permanent loss, named as such
+        # an evicted spec → permanent loss, named as such
         with pytest.raises(ray_tpu.ObjectLostError, match="evicted"):
-            ray_tpu.get(refs[0], timeout=120)
-        # youngest still has lineage → full recovery
-        out = ray_tpu.get(refs[-1], timeout=180)
-        assert out[0] == 15 and out.shape == (200_000,)
+            ray_tpu.get(refs[evicted[0]], timeout=120)
+        # one that still has lineage → full recovery
+        out = ray_tpu.get(refs[kept[-1]], timeout=180)
+        assert out[0] == kept[-1] and out.shape == (200_000,)
     finally:
         ray_tpu.shutdown()
         cluster.shutdown()

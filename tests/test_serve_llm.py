@@ -2004,6 +2004,113 @@ def test_afmoe_programs_lower_to_the_text_they_had(program):
         eng.shutdown()
 
 
+# The programs of the three families that came after `NEIGHBOUR_PROGRAMS` was
+# taken and had no line of their own: the Ling hybrid (KDA states and slots
+# in the arguments), SDAR (a decode pass carries a block a lane and returns
+# what it chose; a prefill returns no logits) and Ouro (the rolled loop over
+# the passes). Hashes taken on commit bdee1a2 (PR 51), before `models/layers.py`
+# gathered the layer math these files had been importing from each other by
+# underscore names: a PR that moves shared layer code shows here, on a CPU,
+# that it moved no program, before it asks for a chip. A later PR that means
+# to change one of these programs replaces its line.
+STATEFUL_AND_LOOP_PROGRAMS = {
+    "ling_hybrid": {"prefill16": "c91f4b86aa216e0d", "decode1": "5a475ecb22902493",
+                    "decode4": "980fcae547b11523", "chunk16": "fe958055961ef601"},
+    "sdar_moe": {"prefill16": "3ea45d58a29c9578", "decode1": "bc96e43f03cfa200",
+                 "decode4": "c566215fa23a97b9", "chunk16": "503185b24e877291"},
+    "ouro": {"prefill16": "4484f00252d4386c", "decode1": "e10c4608e3b11682",
+             "decode4": "2e2e7b24963ce4c9", "chunk16": "948472f3189d1356"},
+}
+
+
+def _program_hash(eng, program: str) -> str:
+    """sha256 (16 hex digits) of the lowered text of one of `eng`'s programs,
+    `prefill<s>`, `decode<b>` or `chunk<c>`, with the arguments `warmup`
+    hands it (the arena and the sequence states donated)."""
+    import hashlib
+
+    import jax
+
+    kv = eng.kv
+    kind = program.rstrip("0123456789")
+    size = int(program[len(kind):])
+    if kind == "prefill":
+        fn, args = eng._prefill_fns[size], (
+            np.zeros((1, size), np.int32), np.ones((1,), np.int32),
+            *kv.arena, *kv.state, *eng._no_rows((size,), None),
+            *eng._slots_of((), 1))
+    else:
+        fn, rows, last = eng._chunk_fn, (1, size), ()
+        if kind == "decode":
+            fn, rows = eng._decode_fns[size], (size,)
+            if eng._block is not None:      # a block a lane, and `live`
+                rows, last = (size, eng._block[0]), (np.zeros(size, bool),)
+        args = (np.zeros(rows, np.int32), np.zeros(rows[0], np.int32),
+                *kv.arena, *kv.state, *eng._no_rows(rows, rows[0]),
+                *eng._slots_of((), rows[0]), *last)
+    donate = tuple(range(3, 3 + len(kv.arena) + len(kv.state)))
+    text = jax.jit(fn.__wrapped__, donate_argnums=donate).lower(
+        eng.params, *args).as_text()
+    return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+@pytest.mark.parametrize("model,program", [
+    (model, program) for model in sorted(STATEFUL_AND_LOOP_PROGRAMS)
+    for program in sorted(STATEFUL_AND_LOOP_PROGRAMS[model])])
+def test_stateful_and_loop_programs_lower_to_the_text_they_had(model,
+                                                               program):
+    from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
+
+    eng = LLMEngine(model=model, engine_config=EngineConfig(
+        batch_buckets=(1, 4), prefill_buckets=(16,), prefill_chunk=16,
+        num_pages=16, block_size=8, prefix_cache=0), seed=0)
+    try:
+        assert bool(eng.kv.state) == (model == "ling_hybrid")
+        assert _program_hash(eng, program) \
+            == STATEFUL_AND_LOOP_PROGRAMS[model][program]
+    finally:
+        eng.shutdown()
+
+
+# sha256 (16 hex digits) over the sorted (path, dtype, shape, bytes) of the
+# weights `net.init` makes for each family's tiny config at seed 0, taken on
+# commit bdee1a2 (PR 51). Flax draws a parameter from its path and its place
+# among its module's parameters, not from the class that declares it: the
+# benchmark's cells serve seeded weights and compare tokens with a reference
+# fed the same, so a refactoring of the declaring classes has to leave every
+# array as it was.
+SEEDED_WEIGHTS = {
+    "llama": "b0cdb511189bdd12", "gpt": "73bcb0e11354d63e", "kimi_k2": "4d03b6f9054bc62b",
+    "ling_hybrid": "ef2c74e1429d131d", "sdar_moe": "59ef927d1cc8ae71",
+    "afmoe": "336dd938d5385478", "ouro": "8c778830b7b0fe3d",
+}
+
+
+@pytest.mark.parametrize("model", sorted(SEEDED_WEIGHTS))
+def test_seeded_weights_are_the_parents(model):
+    import hashlib
+
+    import flax.linen as nn
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.serve.llm.engine import model_family
+
+    family, mod = model_family(model)
+    cfg = getattr(mod, family.config).tiny()
+    variables = nn.meta.unbox(getattr(mod, family.net)(cfg).init(
+        jax.random.PRNGKey(0), jnp.ones((1, 16), jnp.int32)))
+    leaves = sorted(
+        (jax.tree_util.keystr(path), str(leaf.dtype), tuple(leaf.shape),
+         np.asarray(leaf).tobytes())
+        for path, leaf in jax.tree_util.tree_flatten_with_path(variables)[0])
+    digest = hashlib.sha256()
+    for path, dtype, shape, data in leaves:
+        digest.update(f"{path} {dtype} {shape} ".encode())
+        digest.update(data)
+    assert digest.hexdigest()[:16] == SEEDED_WEIGHTS[model]
+
+
 def test_one_long_lane_does_not_make_the_short_ones_walk_its_blocks():
     """A prompt of 600 tokens (three key blocks of 64 pages in the full
     layer) decoding beside three of a block each: the step scores each
