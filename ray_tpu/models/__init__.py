@@ -9,7 +9,9 @@ when first asked for), hybrid decoders of Kimi-Delta-Attention layers (one
 state a sequence) beside latent attention with group-limited routing
 (`ling_hybrid`, serving only; imported when first asked for), decoders
 that generate by diffusion over blocks with softmax-routed experts
-(`sdar_moe`, serving only; imported when first asked for), ResNet
+(`sdar_moe`, serving only; imported when first asked for), decoders of
+power-retention layers that keep one large state a sequence and no paged
+layer (`brumby`, serving only; imported when first asked for), ResNet
 convnets (`resnet`), Vision Transformers (`vit`).
 """
 
@@ -26,7 +28,7 @@ __all__ = [
     "GPT", "GPTConfig", "Llama", "LlamaConfig", "MoEGPT", "MoEGPTConfig",
     "ResNet", "ResNetConfig", "ViT", "ViTConfig",
     "KimiK2", "KimiK2Config", "LingHybrid", "LingHybridConfig",
-    "SdarMoe", "SdarMoeConfig",
+    "SdarMoe", "SdarMoeConfig", "Brumby", "BrumbyConfig",
 ]
 
 
@@ -36,7 +38,8 @@ def __getattr__(name):
     for family, names in (("kimi_k2", ("KimiK2", "KimiK2Config")),
                           ("ling_hybrid", ("LingHybrid",
                                            "LingHybridConfig")),
-                          ("sdar_moe", ("SdarMoe", "SdarMoeConfig"))):
+                          ("sdar_moe", ("SdarMoe", "SdarMoeConfig")),
+                          ("brumby", ("Brumby", "BrumbyConfig"))):
         if name == family or name in names:
             import importlib
 

@@ -28,7 +28,13 @@ layer) rows of each lane's own key blocks, once, in trips of a work list of
 `paged_attend`; the bucket of one walks its one lane's blocks in a loop);
 what it scored is counted here, on the host, from the positions handed to
 it (`decode_attn_key_slots`, over the arena's layers, beside
-`decode_context_tokens`, what it had to).
+`decode_context_tokens`, what it had to). A family whose layers keep one
+state a sequence has a second arena of those, a slot a sequence; where the
+states are too large to stand twice the family's steps update that arena
+themselves and hand it back (`STATE_IN_PLACE`), and a family may have no
+kind of page at all: its cache is the state arena alone, admission takes a
+slot and nothing else, and the host tells the programs which rows are
+tokens where no page coordinate does.
 
 Greedy (argmax) sampling keeps generation deterministic — the property
 the continuous-batching equivalence test and the mid-stream chaos
@@ -126,6 +132,7 @@ MODEL_FAMILIES: Dict[str, ModelFamily] = {
                             "SdarMoeConfig"),
     "afmoe": ModelFamily("ray_tpu.models.afmoe", "Afmoe", "AfmoeConfig"),
     "ouro": ModelFamily("ray_tpu.models.ouro", "Ouro", "OuroConfig"),
+    "brumby": ModelFamily("ray_tpu.models.brumby", "Brumby", "BrumbyConfig"),
 }
 
 
@@ -139,13 +146,16 @@ def model_family(model: str):
     return family, importlib.import_module(family.module)
 
 
-def _valid_rows(counted: bool, pools, arena, w_pages) -> dict:
+def _valid_rows(counted: bool, pools, arena, w_pages, live=()) -> dict:
     """`valid=` for the steps of a family that counts (`step_counts`): a
     row is a token's where the program is to write it, in any kind of page
     (`w_pages`: a kind's w_page each; a ring drops the rows of a write that
-    the same write overruns, and they are tokens all the same)."""
+    the same write overruns, and they are tokens all the same). Where the
+    cache has no kind of page the host says which rows are (`live`)."""
     if not counted:
         return {}
+    if not pools:
+        return {"valid": live[0]}
     valid = None
     for pool, w_page in zip(pools, w_pages):
         here = w_page < arena[pool.arrays][0].shape[0]
@@ -164,6 +174,17 @@ def _scatter_kinds(pools, arena, rows, w_pages, w_offs) -> tuple:
     return out
 
 
+def _by_kind(pools, coords, each: int) -> list:
+    """What a program takes after the arena's arrays, sorted: `each` tuples
+    with an entry a kind of page ((page tables,) w_pages, w_offs), then
+    `live`. A cache with no kind of page has no coordinates: one bool array
+    stands there, rows-shaped, true where a row is a token (`live`, a tuple
+    of it; empty for every other cache)."""
+    if pools:
+        return [coords[i::each] for i in range(each)] + [()]
+    return [()] * each + [coords]
+
+
 def _flat(arrays) -> list:
     """Each of `arrays` with its leading two axes as one: a window's or a
     block's [lanes, positions, ...] rows and coordinates, a row each."""
@@ -174,6 +195,13 @@ def _state_args(state, slots=None) -> dict:
     """`seq_state=` and `slots=` for the chunk and decode steps of a family
     that keeps sequence state; nothing for the others."""
     return {"seq_state": state, "slots": slots} if state else {}
+
+
+def _new_state(in_place: bool, state, out, slots) -> tuple:
+    """The state arena's arrays after a step: what the step returned where
+    the family updates them in place, else the sequences' new states `out`
+    scattered to their slots."""
+    return tuple(out) if in_place else scatter_state(state, out, *slots)
 
 
 def _kv_rows(cfg) -> Tuple[Tuple[int, int], ...]:
@@ -188,7 +216,8 @@ def _kv_rows(cfg) -> Tuple[Tuple[int, int], ...]:
 def _family_cache(mod, cfg):
     """What a family's module `mod` declares of its cache for the model
     `cfg`, read here and nowhere else: (page kinds, sequence state, step
-    counters, block schedule, key walk). The names a family file may define
+    counters, block schedule, key walk, whether the state is updated in
+    place). The names a family file may define
     beside `prefill_step`, `chunk_step` and `decode_step`, each optional:
 
     - `page_kinds(cfg)` -> a tuple of `kv_cache.PageKind`'s fields (name,
@@ -207,6 +236,13 @@ def _family_cache(mod, cfg):
       keeps a slot a sequence, the chunk and decode steps take the arena's
       arrays and the lanes' slots (`seq_state=`, `slots=`), and every step
       returns the sequences' new states after the cache rows.
+    - `STATE_IN_PLACE` -> True where a state is too large to stand twice:
+      every step, the prefill too, takes `seq_state=` and `slots=` and returns
+      THE ARENA'S ARRAYS after the cache rows, each layer's lanes read and
+      written at their slots (the arrays are donated: an update in place, and
+      no array of [lanes, layers, ...] beside the arena). A chunk of such a
+      family returns one row of logits a sequence, its last token's, as a
+      prefill does.
     - `STEP_COUNTS` -> a tuple of counter names: the steps take `valid=` (the
       rows that are tokens) and return an int32 vector of that length last,
       which the engine adds to `decode_<name>` / `prefill_<name>`.
@@ -230,7 +266,8 @@ def _family_cache(mod, cfg):
                   declared("cache_rows") or _kv_rows(cfg)),)
     return (tuple(PageKind(*kind) for kind in kinds),
             declared("seq_state", ()), tuple(getattr(mod, "STEP_COUNTS", ())),
-            declared("block_schedule"), getattr(mod, "decode_key_walk", None))
+            declared("block_schedule"), getattr(mod, "decode_key_walk", None),
+            bool(getattr(mod, "STATE_IN_PLACE", False)))
 
 
 # The pump thread's time ledger (util/tracing.PhaseTable): between start()
@@ -456,8 +493,10 @@ class LLMEngine:
         # token) of a family that generates by diffusion over blocks, else
         # None; `_key_walk`: the layout of a `llama.paged_attend` decode
         # step's walk over the cached keys, for the host's count, else None
-        kinds, seq_state, self._step_counts, self._block, self._key_walk = \
-            _family_cache(mod, self.model_cfg)
+        # `_state_in_place`: the family's steps update the state arena's
+        # arrays themselves and hand them back
+        kinds, seq_state, self._step_counts, self._block, self._key_walk, \
+            self._state_in_place = _family_cache(mod, self.model_cfg)
         self.model_name = model
         self._mod = mod
         cfg = (engine_config or EngineConfig()).resolved(
@@ -628,28 +667,33 @@ class LLMEngine:
     # A family that keeps sequence state has its `m` arrays next and the
     # lanes' slots last: the step's new states, which follow its cache
     # rows, are scattered to the slots (`scatter_state`) and the arrays
-    # returned after the pages'. What follows the arrays is, a kind of page
+    # returned after the pages' (a `STATE_IN_PLACE` family's steps return the
+    # arrays themselves). What follows the arrays is, a kind of page
     # (`kv.pools`; most families have one), the sequences' page table of the
     # kind (not in a prefill) and the rows' coordinates in it, w_page and
-    # w_off; then the slots.
+    # w_off; where the cache has no kind of page, one bool array in their
+    # place, true for the rows that are tokens (`_by_kind`); then the slots.
 
     def _make_prefill_fn(self, bucket: int):
         mod, n, m = self._mod, len(self.kv.arena), len(self.kv.state)
-        counted = bool(self._step_counts)
+        counted, in_place = bool(self._step_counts), self._state_in_place
         cfg, pools = self.model_cfg, self.kv.pools
-        end = n + m + 2 * len(pools)
+        end = n + m + (2 * len(pools) or 1)
 
         def fn(variables, tokens, true_len, *rest):
             arena, state = rest[:n], rest[n:n + m]
             coords, slots = rest[n + m:end], rest[end:]
+            w_pages, w_offs, live = _by_kind(pools, coords, 2)
             logits, *out = mod.prefill_step(
                 variables, cfg, tokens, true_len,
+                **(_state_args(state, *slots) if in_place else {}),
                 **_valid_rows(counted, pools, arena,
-                              [w[None] for w in coords[0::2]]))
+                              [w[None] for w in w_pages],
+                              [w[None] for w in live]))
             return (logits,) + _scatter_kinds(
                 pools, arena, [rows[0] for rows in out[:n]],
-                coords[0::2], coords[1::2]) \
-                + scatter_state(state, out[n:n + m], *slots) \
+                w_pages, w_offs) \
+                + _new_state(in_place, state, out[n:n + m], slots) \
                 + tuple(out[n + m:])
 
         fn.__name__ = f"llm_prefill_s{bucket}"
@@ -662,23 +706,24 @@ class LLMEngine:
         import jax.numpy as jnp
 
         mod, n, m = self._mod, len(self.kv.arena), len(self.kv.state)
-        counted = bool(self._step_counts)
+        counted, in_place = bool(self._step_counts), self._state_in_place
         cfg, pools = self.model_cfg, self.kv.pools
-        end = n + m + 3 * len(pools)
+        end = n + m + (3 * len(pools) or 1)
 
         def fn(variables, tokens, positions, *rest):
             arena, state = rest[:n], rest[n:n + m]
             coords, slots = rest[n + m:end], rest[end:]
+            tables, w_pages, w_offs, live = _by_kind(pools, coords, 3)
             logits, *out = mod.decode_step(
-                variables, cfg, tokens, positions, *arena, *coords[0::3],
+                variables, cfg, tokens, positions, *arena, *tables,
                 **_state_args(state, *slots),
-                **_valid_rows(counted, pools, arena, coords[1::3]))
+                **_valid_rows(counted, pools, arena, w_pages, live))
             # the greedy choice, in the logits' own dtype: the lowest index
             # wins a tie and a NaN counts as the largest, as in np.argmax
             chosen = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             return (chosen,) + _scatter_kinds(
-                pools, arena, out[:n], coords[1::3], coords[2::3]) \
-                + scatter_state(state, out[n:n + m], *slots) \
+                pools, arena, out[:n], w_pages, w_offs) \
+                + _new_state(in_place, state, out[n:n + m], slots) \
                 + tuple(out[n + m:])
 
         fn.__name__ = f"llm_decode_b{batch}"
@@ -729,21 +774,22 @@ class LLMEngine:
         """A window of `size` tokens of one sequence (chunked prefill, a
         prefix-cache suffix): `w_page` / `w_off` are [1, size]."""
         mod, n, m = self._mod, len(self.kv.arena), len(self.kv.state)
-        counted = bool(self._step_counts)
+        counted, in_place = bool(self._step_counts), self._state_in_place
         cfg, pools = self.model_cfg, self.kv.pools
-        end = n + m + 3 * len(pools)
+        end = n + m + (3 * len(pools) or 1)
 
         def fn(variables, tokens, start, *rest):
             arena, state = rest[:n], rest[n:n + m]
             coords, slots = rest[n + m:end], rest[end:]
+            tables, w_pages, w_offs, live = _by_kind(pools, coords, 3)
             logits, *out = mod.chunk_step(
-                variables, cfg, tokens, start, *arena, *coords[0::3],
+                variables, cfg, tokens, start, *arena, *tables,
                 **_state_args(state, *slots),
-                **_valid_rows(counted, pools, arena, coords[1::3]))
+                **_valid_rows(counted, pools, arena, w_pages, live))
             return (logits,) + _scatter_kinds(
-                pools, arena, _flat(out[:n]), _flat(coords[1::3]),
-                _flat(coords[2::3])) \
-                + scatter_state(state, out[n:n + m], *slots) \
+                pools, arena, _flat(out[:n]), _flat(w_pages),
+                _flat(w_offs)) \
+                + _new_state(in_place, state, out[n:n + m], slots) \
                 + tuple(out[n + m:])
 
         fn.__name__ = f"llm_chunk_c{size}"
@@ -827,7 +873,10 @@ class LLMEngine:
         before any sequence is filled in: a page table of zeros for `lanes`
         sequences (none for a prefill, which takes no table) and `rows`-
         shaped coordinates that write nowhere, every page id the kind's
-        dropped one."""
+        dropped one. Where the cache has no kind of page: the `rows`-shaped
+        flags of the rows that are tokens, none of them yet (`_mark_live`)."""
+        if not self.kv.pools:
+            return [np.zeros(rows, bool)]
         out = []
         for pool in self.kv.pools:
             if lanes is not None:
@@ -835,6 +884,13 @@ class LLMEngine:
             out += [np.full(rows, pool.num_pages, np.int32),
                     np.zeros(rows, np.int32)]
         return out
+
+    def _mark_live(self, coords, rows) -> None:
+        """Where the cache has no kind of page, no coordinate says which
+        rows of a call are tokens: the host does, `rows` an index into the
+        flags `_no_rows` made."""
+        if not self.kv.pools:
+            coords[0][rows] = True
 
     # -- submission -------------------------------------------------------
 
@@ -1130,7 +1186,9 @@ class LLMEngine:
             with phase("prefill_kv_write"):
                 coords = [c for kind, held in enumerate(seq.pages)
                           for c in self.kv.write_index(held, 0, s, bucket,
-                                                       kind=kind)]
+                                                       kind=kind)] \
+                    or self._no_rows((bucket,), None)
+                self._mark_live(coords, np.s_[:s])
             next_logits = self._prefill_forward(
                 req, self._prefill_fns[bucket],
                 (self.params, toks, np.asarray([s], np.int32),
@@ -1169,6 +1227,7 @@ class LLMEngine:
                 coords = self._no_rows((1, c), 1)
                 for table, held in zip(coords[0::3], seq.pages):
                     table[0, :len(held)] = held
+                self._mark_live(coords, np.s_[0, :take])
                 self._note_call("chunk", c)
             with phase("prefill_kv_write"):
                 for kind, held in enumerate(seq.pages):
@@ -1184,7 +1243,9 @@ class LLMEngine:
                 seq.prefilled += take
                 with self._lock:
                     self.counters["chunk_steps"] += 1
-                    self.counters["chunk_context_tokens"] += seq.prefilled
+                    if self.kv.pools:   # no page, no cached position
+                        self.counters["chunk_context_tokens"] += \
+                            seq.prefilled
                 if seq.prefilled < s:
                     return 0
                 seq.pos = s
@@ -1193,8 +1254,12 @@ class LLMEngine:
                 with self._lock:
                     self.counters["prefill_steps"] += 1
             with phase("prefill_sample"):
-                return 0 if logits is None \
-                    else self._emit_first(seq, logits[0, take - 1])
+                if logits is None:
+                    return 0
+                # a window's rows of logits, or its last token's alone
+                return self._emit_first(
+                    seq, logits[0] if self._state_in_place
+                    else logits[0, take - 1])
 
     def _decode_forward(self, fn, args):
         """One decode call: the call, which leaves the written rows' K and
@@ -1241,6 +1306,7 @@ class LLMEngine:
                 positions[i] = seq.pos
                 for table, held in zip(coords[0::3], seq.pages):
                     table[i, :len(held)] = held
+            self._mark_live(coords, np.s_[:len(runs)])
             with phase("decode_kv_append"):
                 self._write_coords(runs, coords)
             self._note_call("decode", bb)
@@ -1250,7 +1316,8 @@ class LLMEngine:
                  *self.kv.arena, *self.kv.state, *coords,
                  *self._slots_of(runs, bb)))
             with phase("decode_kv_append"):
-                context = int(positions.sum())
+                # cached positions the step read: none where nothing pages
+                context = int(positions.sum()) if self.kv.pools else 0
                 self._count_kinds(runs, positions)
                 for seq in runs:
                     seq.pos += 1
@@ -1297,7 +1364,7 @@ class LLMEngine:
         (`decode_kv_pages_<kind>_lane_max`) and the cached positions the
         pass had to read of it (`decode_context_tokens_<kind>`: no lane
         more than the window's)."""
-        if len(self.kv.pools) == 1:
+        if len(self.kv.pools) <= 1:
             return
         add = {}
         for kind, pool in enumerate(self.kv.pools):

@@ -54,7 +54,11 @@ the window's own lets a program read the window's oldest page from the
 arena it was given while its new rows go to the page after the newest: a
 program's writes land after its reads. Reservation (`reserve`) takes every
 kind's pages under one hold of the lock, or none. A family that declares
-nothing has one kind, unbounded, through the same code.
+nothing has one kind, unbounded, through the same code. A family that
+declares NO kind (every layer keeps a state a sequence) has no pages at all:
+the arena is the state arena alone, a sequence costs its slot whatever its
+length, a reservation is empty and cannot fail, and whatever counts pages
+reads 0.
 
 A dead replica's arena dies with its process: the device memory is the
 process's own, so there is nothing for a peer to reclaim.
@@ -181,7 +185,8 @@ class PagedKVCache:
     given). `num_pages` counts the pages of a kind without a window; a kind
     with one gets `seq_slots` (the sequences that run at once) times its
     ring, and no more than `num_pages`. `max_seq_len` is the longest
-    sequence, for the width of an unbounded kind's page table.
+    sequence, for the width of an unbounded kind's page table. With `kinds`
+    empty there is no page: `num_pages` is not read and reads 0.
     """
 
     def __init__(self, num_pages: int, n_layer: int, block_size: int,
@@ -194,7 +199,9 @@ class PagedKVCache:
                  max_seq_len: int = 0):
         import jax.numpy as jnp
 
-        if num_pages <= 0 or block_size <= 0:
+        if kinds is not None and not kinds:
+            num_pages = 0           # no kind of page: nothing to count
+        elif num_pages <= 0 or block_size <= 0:
             raise KVCacheError("num_pages and block_size must be positive")
         if kinds is None:
             if rows is None:
@@ -206,7 +213,7 @@ class PagedKVCache:
         self.num_pages = num_pages
         self.n_layer = sum(kind.n_layer for kind in self.kinds)
         self.block_size = block_size
-        self.rows = self.kinds[0].rows
+        self.rows = self.kinds[0].rows if self.kinds else ()
         if n_kv_head is None and len(self.rows) == 2 \
                 and self.rows[0] == self.rows[1] and len(self.rows[0]) == 2:
             n_kv_head, head_dim = self.rows[0]      # an arena of K and V
@@ -313,7 +320,7 @@ class PagedKVCache:
     def utilization(self) -> float:
         with self._lock:
             return sum(len(pool.holders) for pool in self.pools) \
-                / sum(pool.num_pages for pool in self.pools)
+                / (sum(pool.num_pages for pool in self.pools) or 1)
 
     def page_refcount(self, page: int, kind: int = 0) -> int:
         with self._lock:

@@ -115,7 +115,8 @@ def _fake_kv(arena, state=(), pools=None):
 
     return types.SimpleNamespace(
         arena=arena, state=state,
-        pools=pools or (types.SimpleNamespace(arrays=slice(0, len(arena))),))
+        pools=(types.SimpleNamespace(arrays=slice(0, len(arena))),)
+        if pools is None else pools)
 
 
 def _assert_returns_a_token_a_lane(compiled, lanes):
@@ -148,7 +149,7 @@ def _compile_engine_program(topo, cfg, kind, size, block=16, num_pages=2048):
                      cfg.head_dim), jnp.bfloat16)
     engine = types.SimpleNamespace(
         _mod=llama, model_cfg=cfg, _step_counts=(),
-        kv=_fake_kv((pages, pages)))
+        _state_in_place=False, kv=_fake_kv((pages, pages)))
     if kind == "decode":
         fn = LLMEngine._make_decode_fn(engine, size)
         args = (params, on_chip((size,)), on_chip((size,)), pages, pages,
@@ -279,7 +280,7 @@ def test_latent_arena_is_updated_in_place_at_kimi_k2_widths(topo, kind, size):
         pages = on_chip((num_pages, cfg.n_layer, block) + row, jnp.bfloat16)
         engine = types.SimpleNamespace(
             _mod=kimi_k2, model_cfg=cfg, _step_counts=kimi_k2.STEP_COUNTS,
-            kv=_fake_kv((pages,)))
+            _state_in_place=False, kv=_fake_kv((pages,)))
         table = on_chip((size if kind == "decode" else 1,
                          cfg.max_seq_len // block))
         if kind == "decode":
@@ -358,7 +359,7 @@ def test_sequence_state_arena_is_updated_in_place_at_ling_widths(topo, kind,
     engine = types.SimpleNamespace(
         _mod=ling_hybrid, model_cfg=cfg,
         _step_counts=ling_hybrid.STEP_COUNTS,
-        kv=_fake_kv((pages,), state))
+        _state_in_place=False, kv=_fake_kv((pages,), state))
     lanes = size if kind == "decode" else 1
     rows = (size,) if kind == "decode" else (1, size)
     fn = LLMEngine._make_decode_fn(engine, size) if kind == "decode" \
@@ -414,7 +415,7 @@ def test_block_programs_fit_the_chip_at_sdar_widths(topo, kind, size):
                   for row in sdar_moe.cache_rows(cfg))
     engine = types.SimpleNamespace(
         _mod=sdar_moe, model_cfg=cfg, _step_counts=sdar_moe.STEP_COUNTS,
-        kv=_fake_kv(arena))
+        _state_in_place=False, kv=_fake_kv(arena))
     table = on_chip((size if kind == "decode" else 1,
                      cfg.max_seq_len // block))
     if kind == "decode":
@@ -496,7 +497,7 @@ def test_window_and_full_programs_fit_the_chip_at_trinity_widths(topo, kind,
         + [(16384, 2, 16, 8, 128)] * 2
     engine = types.SimpleNamespace(
         _mod=afmoe, model_cfg=cfg, _step_counts=afmoe.STEP_COUNTS,
-        kv=_fake_kv(arena, pools=pools))
+        _state_in_place=False, kv=_fake_kv(arena, pools=pools))
     lanes = size if kind == "decode" else 1
     rows = (size,) if kind == "decode" else (1, size)
     if kind == "prefill":
@@ -563,7 +564,7 @@ def test_looped_programs_fit_the_chip_at_ouro_widths(topo, kind, size):
     assert pages.shape[1:] == (192, 16, 16, 128)
     engine = types.SimpleNamespace(
         _mod=ouro, model_cfg=cfg, _step_counts=ouro.STEP_COUNTS,
-        kv=_fake_kv((pages, pages)))
+        _state_in_place=False, kv=_fake_kv((pages, pages)))
     if kind == "prefill":
         fn = LLMEngine._make_prefill_fn(engine, size)
         args = (params, on_chip((1, size)), on_chip((1,)), pages, pages,
@@ -589,6 +590,78 @@ def test_looped_programs_fit_the_chip_at_ouro_widths(topo, kind, size):
     if kind == "decode":    # a step's own temporaries: the gathered block
         assert mem.temp_size_in_bytes < 256 * 2**20
         _assert_returns_a_token_a_lane(compiled, size)
+
+
+@pytest.mark.parametrize("kind, size", [("decode", 16), ("chunk", 1024),
+                                        ("prefill", 512)])
+def test_retention_state_arena_is_updated_in_place_at_brumby_widths(topo,
+                                                                    kind,
+                                                                    size):
+    """The engine's decode-16, chunk-1,024 and prefill-512 programs of the
+    Brumby cell (published widths, 8 layers, the whole vocabulary, no page
+    kind, 17 state slots of 273 MB): both arrays of the state arena alias
+    their outputs, no operation copies an array of the arena's shape and NO
+    TEMPORARY IS OF ITS SIZE (the steps return the arena itself; the decode
+    step slices a lane's state out of it and writes its successor back, a
+    lane at a time: 9 MB of temporaries beside 4.6 GB of states), and the
+    program with its arguments is under 15.0 GB beside 8.4 GB of weights."""
+    import json
+    import types
+
+    from benchmark.brumby_cell import brumby_engine
+    from ray_tpu.models import brumby
+    from ray_tpu.serve.llm.engine import LLMEngine
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "brumby-14b-l8.json")) as f:
+        config = json.load(f)
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    cfg = brumby_engine(config)["model_cfg"]
+    slots = config["engine"]["max_running"] + 1
+
+    def on_chip(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    params = jax.tree_util.tree_map(
+        lambda a: on_chip(a.shape, a.dtype),
+        jax.eval_shape(brumby.Brumby(cfg).init, jax.random.PRNGKey(0),
+                       jnp.ones((1, 16), jnp.int32)))
+    state = tuple(on_chip((slots,) + shape, dtype)
+                  for shape, dtype in brumby.seq_state(cfg))
+    assert brumby.page_kinds(cfg) == ()
+    assert state[0].shape == (17, 8, 8, 8256, 128)
+    engine = types.SimpleNamespace(
+        _mod=brumby, model_cfg=cfg, _step_counts=brumby.STEP_COUNTS,
+        _state_in_place=brumby.STATE_IN_PLACE,
+        kv=_fake_kv((), state, pools=()))
+    lanes = size if kind == "decode" else 1
+    rows = {"decode": (size,), "chunk": (1, size), "prefill": (size,)}[kind]
+    fn = getattr(LLMEngine, f"_make_{kind}_fn")(engine, size)
+    tokens = on_chip((size,) if kind == "decode" else (1, size))
+    # after the arena: the rows that are tokens (no page says), the slots
+    args = (params, tokens, on_chip((lanes,)), *state,
+            on_chip(rows, jnp.bool_), on_chip((lanes,)))
+    compiled = jax.jit(fn, donate_argnums=(3, 4)).lower(*args).compile()
+    mem = compiled.memory_analysis()
+    held = sum(4 * math.prod(a.shape) for a in state)
+    assert 4.6e9 < held <= mem.alias_size_in_bytes < held + 2**22
+    assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < 15.0e9
+    # a lane's state, a block's phi(q): nothing near a fifth of the arena
+    assert mem.temp_size_in_bytes < held / 5
+    text = compiled.as_text()
+    shape = "f32[" + ",".join(map(str, state[0].shape)) + "]"
+    moved = [line.strip()[:120] for line in text.splitlines()
+             if " copy(" in line and shape in line.split(" copy(")[0]]
+    assert not moved, moved
+    # no array of [lanes, layers, ...] states beside the arena
+    assert f"f32[{lanes},8,8,8256,128]" not in text
+    if kind == "decode":
+        assert mem.temp_size_in_bytes < 64 * 2**20
+        _assert_returns_a_token_a_lane(compiled, size)
+    else:       # one row of logits a sequence, not a window's
+        first = jax.tree_util.tree_leaves(compiled.out_info)[0]
+        assert first.shape == (1, cfg.vocab_size)
 
 
 def test_build_mesh_on_tpu_follows_the_topology(topo):

@@ -2012,7 +2012,10 @@ def test_afmoe_programs_lower_to_the_text_they_had(program):
 # gathered the layer math these files had been importing from each other by
 # underscore names: a PR that moves shared layer code shows here, on a CPU,
 # that it moved no program, before it asks for a chip. A later PR that means
-# to change one of these programs replaces its line.
+# to change one of these programs replaces its line. (`brumby`, PR 53: no page
+# kind, so one bool array of the rows that are tokens where the others have
+# page tables and coordinates, and the state arena returned by the step
+# itself; its hashes were taken on the PR that brought it.)
 STATEFUL_AND_LOOP_PROGRAMS = {
     "ling_hybrid": {"prefill16": "c91f4b86aa216e0d", "decode1": "5a475ecb22902493",
                     "decode4": "980fcae547b11523", "chunk16": "fe958055961ef601"},
@@ -2020,6 +2023,8 @@ STATEFUL_AND_LOOP_PROGRAMS = {
                  "decode4": "c566215fa23a97b9", "chunk16": "503185b24e877291"},
     "ouro": {"prefill16": "4484f00252d4386c", "decode1": "e10c4608e3b11682",
              "decode4": "2e2e7b24963ce4c9", "chunk16": "948472f3189d1356"},
+    "brumby": {"prefill16": "ac59851bf3eb37c4", "decode1": "721ba2cd9f168cb3",
+               "decode4": "0abbdbb37c879b25", "chunk16": "f782efea551a9e1d"},
 }
 
 
@@ -2065,7 +2070,8 @@ def test_stateful_and_loop_programs_lower_to_the_text_they_had(model,
         batch_buckets=(1, 4), prefill_buckets=(16,), prefill_chunk=16,
         num_pages=16, block_size=8, prefix_cache=0), seed=0)
     try:
-        assert bool(eng.kv.state) == (model == "ling_hybrid")
+        assert bool(eng.kv.state) == (model in ("ling_hybrid", "brumby"))
+        assert bool(eng.kv.pools) == (model != "brumby")
         assert _program_hash(eng, program) \
             == STATEFUL_AND_LOOP_PROGRAMS[model][program]
     finally:
@@ -2083,6 +2089,7 @@ SEEDED_WEIGHTS = {
     "llama": "b0cdb511189bdd12", "gpt": "73bcb0e11354d63e", "kimi_k2": "4d03b6f9054bc62b",
     "ling_hybrid": "ef2c74e1429d131d", "sdar_moe": "59ef927d1cc8ae71",
     "afmoe": "336dd938d5385478", "ouro": "8c778830b7b0fe3d",
+    "brumby": "68293459d8ec1493",
 }
 
 
