@@ -139,7 +139,44 @@ def apply_moe(
 # -- one chip's share of an expert-parallel layer -----------------------------
 
 MOE_COUNTS = ("pairs_routed", "pairs_local", "pairs_computed",
-              "expert_calls")
+              "expert_calls", "tile_visits")
+
+
+# rows of one visit of the grouped product: the MXU's width, so a visit's
+# multiply costs what loading its weights into the array costs and no more.
+# A product of fewer rows is XLA's: such a bucket is met on a ramp and
+# seldom after, and each program that holds the kernel costs its start
+# half a second, compile cache hit or not (PERF.md §6, PR 54)
+ROW_TILE = 128
+
+
+def group_tiles(group_sizes, rows: int, tm: int = ROW_TILE):
+    """Each group's first row, end row and how many row tiles of `tm` rows it
+    touches, of `rows` rows sorted by group. The sum of the last is the (row
+    tile, group) pairs that hold a row: `MOE_COUNTS`' `tile_visits`, at most
+    `ceil(rows / tm) + groups - 1`."""
+    ends = jnp.minimum(jnp.cumsum(group_sizes.astype(jnp.int32)), rows)
+    starts = jnp.concatenate([jnp.zeros(1, jnp.int32), ends[:-1]])
+    tiles = jnp.where(ends > starts, (ends - 1) // tm - starts // tm + 1, 0)
+    return starts, ends, tiles
+
+
+def tile_walk(starts, ends, tiles, rows: int, tm: int = ROW_TILE):
+    """The walk `ops.grouped_matmul` runs, from `group_tiles`: visit v is
+    (group[v], tile[v]), groups in order and each group's tiles in order, so
+    the visits of one row tile are consecutive; the worst case's length, of
+    which the first `visits` count. A compare and a sum, not a search: a
+    handful of operations to lower and to run, once a layer."""
+    max_visits = -(-rows // tm) + tiles.shape[0] - 1
+    visit_ends = jnp.cumsum(tiles)
+    v = jnp.arange(max_visits, dtype=jnp.int32)
+    group = jnp.minimum(
+        jnp.sum(v[:, None] >= visit_ends[None, :], axis=1, dtype=jnp.int32),
+        tiles.shape[0] - 1)
+    # a group's first tile less its first visit: add the visit for the tile
+    tile = (starts // tm - (visit_ends - tiles))[group] + v
+    tile = jnp.clip(tile, 0, -(-rows // tm) - 1).astype(jnp.int32)
+    return group, tile, starts, ends, visit_ends[-1]
 
 
 def sigmoid_topk_route(x, router_w, bias, top_k: int, scale: float,
@@ -201,18 +238,21 @@ def expert_shard_layer(x, router_w, bias, experts_held, first_expert: int,
     unread), the (token, expert) pairs whose expert lives here
     are kept, and the result is the weighted sum of the held experts'
     outputs alone. No capacity: a pair is never dropped. The kept pairs are
-    sorted by expert and each projection is one grouped product
-    (`lax.ragged_dot`) over `N * min(top_k, held)` rows, the most that can
-    be local. On one chip this is the whole layer's local half; under
-    expert parallelism the partial results of the shards add up to the
-    layer (the caller adds what every chip computes alike, a shared
+    sorted by expert and each projection is one grouped product over
+    `N * min(top_k, held)` rows, the most that can be local: that is the
+    buffer's size, and on the TPU, from `ROW_TILE` rows up, not the work
+    (`ops.grouped_matmul` walks the (row tile, expert) pairs that hold a
+    row, `tile_visits` of them, by one `tile_walk` for both products;
+    elsewhere `lax.ragged_dot`). On one chip this is the whole layer's local
+    half; under expert parallelism the partial results of the shards add up
+    to the layer (the caller adds what every chip computes alike, a shared
     expert, once).
 
     x [N, d]; router_w [d, n_experts]; bias [n_experts]; `experts_held`
     {"gate_up" [held, d, 2f], "down" [held, f, d]} (SwiGLU, gate | up along
     the last axis); `valid` [N] bool marks the rows that are tokens (a
     padded lane routes nowhere and is not counted). Returns (partial
-    result [N, d] in x's type, counts int32[4] as `MOE_COUNTS`)."""
+    result [N, d] in x's type, counts int32[5] as `MOE_COUNTS`)."""
     if router_w.shape[1] != n_experts:
         raise ValueError(f"the router has {router_w.shape[1]} outputs for "
                          f"{n_experts} experts")
@@ -239,12 +279,22 @@ def expert_shard_layer(x, router_w, bias, experts_held, first_expert: int,
             dtype=jnp.int32)
         n_local = jnp.sum(group_sizes)
     with jax.named_scope("moe_experts"):
+        starts, ends, tiles = group_tiles(group_sizes, rows)
+        if jax.default_backend() == "tpu" and rows >= ROW_TILE:
+            # here and not at the top: Pallas is a second of imports, which
+            # a process that runs no expert layer on a chip never pays
+            from ray_tpu.ops.grouped_matmul import grouped_matmul
+            product = functools.partial(
+                grouped_matmul, walk=tile_walk(starts, ends, tiles, rows),
+                tm=ROW_TILE)
+        else:
+            product = functools.partial(lax.ragged_dot,
+                                        group_sizes=group_sizes)
         xs = x[take // top_k]                                    # [rows, d]
-        gu = lax.ragged_dot(xs, experts_held["gate_up"].astype(x.dtype),
-                            group_sizes)
+        gu = product(xs, experts_held["gate_up"].astype(x.dtype))
         gate, up = jnp.split(gu, 2, axis=-1)
-        ys = lax.ragged_dot(jax.nn.silu(gate) * up,
-                            experts_held["down"].astype(x.dtype), group_sizes)
+        ys = product(jax.nn.silu(gate) * up,
+                     experts_held["down"].astype(x.dtype))
         # back to token order: pair j lies at row `where[j]` of the sorted
         # rows; a row past the last group belongs to no expert, and whatever
         # the grouped product left there is not a result
@@ -257,5 +307,6 @@ def expert_shard_layer(x, router_w, bias, experts_held, first_expert: int,
                       axis=1)
     counts = jnp.stack([
         n_valid * top_k, jnp.sum(local.astype(jnp.int32)), n_computed,
-        jnp.sum((group_sizes > 0).astype(jnp.int32))]).astype(jnp.int32)
+        jnp.sum((group_sizes > 0).astype(jnp.int32)),
+        jnp.sum(tiles)]).astype(jnp.int32)
     return out.astype(x.dtype), counts

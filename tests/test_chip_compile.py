@@ -26,6 +26,14 @@ from ray_tpu.parallel import ShardingStrategy
 HBM_BYTES = 16 * 1024**3
 
 
+def _on_the_tpu():
+    """Code that asks `jax.default_backend()` sees cpu here and would take
+    its CPU branch (`parallel.moe.expert_shard_layer`: `lax.ragged_dot`):
+    inside this the expert families' programs are lowered as on the chip,
+    with `ops.grouped_matmul`'s kernel."""
+    return mock.patch.object(jax, "default_backend", lambda: "tpu")
+
+
 @pytest.fixture(scope="module")
 def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
@@ -73,6 +81,39 @@ def _compile_flash(topo, q_shape, kv_heads, **blocks):
 ], ids=["mha_16x1024", "gqa_12to4_16x1024", "chunked_4x4096"])
 def test_flash_kernel_compiles_for_v5e(topo, q_shape, kv_heads, blocks):
     _compile_flash(topo, q_shape, kv_heads, **blocks)
+
+
+@pytest.mark.parametrize("rows, d, f, held", [
+    (2048, 2048, 768, 128), (8192, 2048, 768, 128),     # SDAR pass, prefill
+    (512, 2560, 768, 128), (8192, 2560, 768, 128),      # Ling decode, chunk
+    (128, 7168, 2048, 12), (8192, 7168, 2048, 12),      # Kimi decode, chunk
+    (128, 3072, 3072, 8), (4096, 3072, 3072, 8),        # Trinity
+    (8, 7168, 2048, 12),                                # a bucket of one lane
+], ids=["sdar_pass", "sdar_prefill", "ling_decode", "ling_chunk",
+        "kimi_decode", "kimi_chunk", "trinity_decode", "trinity_chunk",
+        "kimi_one_lane"])
+def test_grouped_matmul_compiles_for_v5e(topo, rows, d, f, held):
+    """Both grouped products of an expert layer, gate|up `[rows, d] x
+    [held, d, 2f]` and down `[rows, f] x [held, f, d]`, at the shapes the
+    four expert cells run: the kernel's blocks fit VMEM and its slices the
+    tiling, with the grid's length a value of the program."""
+    from ray_tpu.ops.grouped_matmul import grouped_matmul
+    from ray_tpu.parallel import moe
+
+    def product(lhs, rhs, group_sizes):
+        walk = moe.tile_walk(*moe.group_tiles(group_sizes, rows), rows)
+        return grouped_matmul(lhs, rhs, walk, tm=moe.ROW_TILE)
+
+    one_chip = SingleDeviceSharding(topo.devices[0])
+    for k, n in ((d, 2 * f), (f, d)):
+        compiled = jax.jit(product).lower(
+            jax.ShapeDtypeStruct((rows, k), jnp.bfloat16, sharding=one_chip),
+            jax.ShapeDtypeStruct((held, k, n), jnp.bfloat16,
+                                 sharding=one_chip),
+            jax.ShapeDtypeStruct((held,), jnp.int32,
+                                 sharding=one_chip)).compile()
+        assert "tpu_custom_call" in compiled.as_text()
+        assert f"bf16[{rows},{n}]" in compiled.as_text()
 
 
 def test_llama_125m_decode_step_compiles_for_v5e(topo):
@@ -291,11 +332,14 @@ def test_latent_arena_is_updated_in_place_at_kimi_k2_widths(topo, kind, size):
             fn = LLMEngine._make_chunk_fn(engine, size)
             args = (params, on_chip((1, size)), on_chip((1,)), pages, table,
                     on_chip((1, size)), on_chip((1, size)))
-        compiled = jax.jit(fn, donate_argnums=(3,)).lower(*args).compile()
+        with _on_the_tpu():
+            compiled = jax.jit(fn, donate_argnums=(3,)).lower(*args).compile()
         if kind == "decode":
             _assert_returns_a_token_a_lane(compiled, size)
         shape = "bf16[" + ",".join(map(str, pages.shape)) + "]"
         text = compiled.as_text()
+        assert text.count("tpu_custom_call") >= 2 * (cfg.n_layer
+                                                     - cfg.n_dense_layer)
         moved = [line.strip()[:120] for line in text.splitlines()
                  if " copy(" in line and shape in line.split(" copy(")[0]]
         return compiled.memory_analysis(), 2 * math.prod(pages.shape), \
@@ -367,7 +411,12 @@ def test_sequence_state_arena_is_updated_in_place_at_ling_widths(topo, kind,
     args = (params, on_chip(rows), on_chip((lanes,)), pages, *state,
             on_chip((lanes, cfg.max_seq_len // block)), on_chip(rows),
             on_chip(rows), on_chip((lanes,)))
-    compiled = jax.jit(fn, donate_argnums=(3, 4, 5)).lower(*args).compile()
+    with _on_the_tpu():
+        compiled = jax.jit(fn, donate_argnums=(3, 4, 5)).lower(
+            *args).compile()
+    # both grouped products of the six expert layers are the kernel
+    assert compiled.as_text().count("tpu_custom_call") \
+        >= 2 * (cfg.n_layer - cfg.n_dense_layer)
     mem = compiled.memory_analysis()
     held = [2 * math.prod(pages.shape), 4 * math.prod(state[0].shape),
             2 * math.prod(state[1].shape)]
@@ -431,12 +480,17 @@ def test_block_programs_fit_the_chip_at_sdar_widths(topo, kind, size):
         fn = LLMEngine._make_chunk_fn(engine, size)
         args = (params, on_chip((1, size)), on_chip((1,)), *arena, table,
                 on_chip((1, size)), on_chip((1, size)))
-    compiled = jax.jit(fn, donate_argnums=(3, 4)).lower(*args).compile()
+    with _on_the_tpu():
+        compiled = jax.jit(fn, donate_argnums=(3, 4)).lower(*args).compile()
     mem = compiled.memory_analysis()
     held = sum(2 * math.prod(a.shape) for a in arena)
     assert held <= mem.alias_size_in_bytes < held + 2**22
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
     text = compiled.as_text()
+    # both grouped products of every layer are the kernel (a prefill and a
+    # chunk compute no head: the last layer keeps its K and V and no expert)
+    assert text.count("tpu_custom_call") \
+        >= 2 * (cfg.n_layer - (kind != "decode"))
     shape = "bf16[" + ",".join(map(str, arena[0].shape)) + "]"
     moved = [line.strip()[:120] for line in text.splitlines()
              if " copy(" in line and shape in line.split(" copy(")[0]]
@@ -510,13 +564,15 @@ def test_window_and_full_programs_fit_the_chip_at_trinity_widths(topo, kind,
             on_chip((lanes, width[name])), on_chip(rows), on_chip(rows))]
     args = (params, on_chip(rows if kind != "prefill" else (1, size)),
             on_chip((lanes,)), *arena, *coords)
-    compiled = jax.jit(fn, donate_argnums=(3, 4, 5, 6)).lower(
-        *args).compile()
+    with _on_the_tpu():
+        compiled = jax.jit(fn, donate_argnums=(3, 4, 5, 6)).lower(
+            *args).compile()
     mem = compiled.memory_analysis()
     held = sum(2 * math.prod(a.shape) for a in arena)
     assert held <= mem.alias_size_in_bytes < held + 2**22
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
     text = compiled.as_text()
+    assert "tpu_custom_call" in text    # the grouped products' kernel
     for array in (arena[0], arena[2]):
         shape = "bf16[" + ",".join(map(str, array.shape)) + "]"
         moved = [line.strip()[:120] for line in text.splitlines()

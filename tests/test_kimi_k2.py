@@ -331,7 +331,7 @@ def test_the_shares_of_all_chips_add_up_to_the_uncut_layer():
             total = total + part
             counts += np.asarray(c)
     np.testing.assert_allclose(total, want, atol=ATOL, rtol=1e-4)
-    routed, local, computed, _ = counts
+    routed, local, computed = counts[:3]
     assert routed == 4 * n * top_k and local == computed == n * top_k
 
 
@@ -360,7 +360,9 @@ def test_no_pair_is_dropped_under_a_skewed_router():
     assert set(np.asarray(expert).ravel()) == {0, 1, 2, 3}
     counts = dict(zip(MOE_COUNTS, np.asarray(counts).tolist()))
     assert counts == {"pairs_routed": 29 * top_k, "pairs_local": 29 * top_k,
-                      "pairs_computed": 29 * top_k, "expert_calls": 4}
+                      "pairs_computed": 29 * top_k, "expert_calls": 4,
+                      # 116 sorted rows over four experts, all in one tile
+                      "tile_visits": 4}
     np.testing.assert_allclose(got[:29], want[:29], atol=ATOL, rtol=1e-4)
     np.testing.assert_array_equal(got[29:], 0)
 

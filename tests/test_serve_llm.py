@@ -618,7 +618,9 @@ def test_link_counters_hold_ids_tables_and_logits_only(model):
         vocab_row = eng.model_cfg.vocab_size * 4          # float32 logits
         lanes, bucket, int32 = 4, 16, 4
         counts = int32 * len(eng._step_counts)
-        assert counts == {"llama": 0, "kimi_k2": 20}[model]
+        # kimi_k2: the five `MOE_COUNTS` (`tile_visits` since PR 54) and
+        # `attn_key_slots`
+        assert counts == {"llama": 0, "kimi_k2": 24}[model]
         # token ids, positions, the page table, a page id and an offset a
         # lane; the lanes' chosen ids (and the step's counts) back
         a_step = lanes * int32 * (4 + eng.max_pages_per_seq) \
@@ -1815,16 +1817,20 @@ def test_block_family_streams_in_position_order_and_stops_inside_a_block():
 # `decode4` of all three changed on purpose in PR 51: a decode program ends in
 # the greedy argmax and returns int32 [batch] where it returned the logits;
 # its inputs and the outputs after the first are as they were. `prefill16` and
-# `chunk16` stand: they are the parent's programs, and its cache entries.)
+# `chunk16` stand: they are the parent's programs, and its cache entries.
+# All four of `kimi_k2` changed on purpose in PR 54: `MOE_COUNTS` gained
+# `tile_visits`, the (row tile, expert) pairs the grouped product walks on the
+# TPU, counted from the group sizes on every backend (`moe.group_tiles`,
+# before the products); the products here are still `lax.ragged_dot`. `llama` and `gpt` held to the digit.)
 NEIGHBOUR_PROGRAMS = {
     "llama": {"prefill16": "431c15dfcac7aa97", "decode1": "6bf27b12ae6cc48a",
               "decode4": "a673da1cf2b122ee", "chunk16": "6a7f8549a7f8d7f8"},
     "gpt": {"prefill16": "b2ca98029a10a332", "decode1": "910442728ae47e50",
             "decode4": "56f63d1e86807bca", "chunk16": "d8e225f431a39fd7"},
-    "kimi_k2": {"prefill16": "0742f17ca2a066b5",
-                "decode1": "8f724381a7abeb37",
-                "decode4": "530e3a868ca209d2",
-                "chunk16": "dcfa5ec2cf9155a8"},
+    "kimi_k2": {"prefill16": "38d0038cacae10d6",
+                "decode1": "5adea4b67ad990fa",
+                "decode4": "518bdc7dfbe06d92",
+                "chunk16": "f032712f3eae8ada"},
 }
 
 
@@ -1966,15 +1972,16 @@ def test_short_and_long_in_one_batch_through_pages_of_two_kinds():
 # the one-shot prefill has no cache, the chunk and the bucket of one have
 # one lane, whose own blocks are the batch's longest's. (`decode1` changed on
 # purpose in PR 51, as `NEIGHBOUR_PROGRAMS` says: it returns the token it
-# chose. `prefill8` and `chunk8` stand.)
-AFMOE_ONE_LANE_PROGRAMS = {"prefill8": "f1b725340a027ffd",
-                           "decode1": "7716a13c6e3130c1",
-                           "chunk8": "b820bd39b1a363c3"}
+# chose. All of them, `decode4` too, changed on purpose in PR 54 for the
+# `tile_visits` count, as `NEIGHBOUR_PROGRAMS` says of `kimi_k2`.)
+AFMOE_ONE_LANE_PROGRAMS = {"prefill8": "fa3577f6c60f909a",
+                           "decode1": "31c3535cba267799",
+                           "chunk8": "c03001617a7afb2b"}
 # and the bucket of four, which walks the list (PR 49 gave `llama.paged_attend`
 # a list of its own and moved the fold of a trip's pairs to `llama.py`: the
 # helper's default and the fold were held; changed on purpose in PR 51 with
 # `decode1`: the walk is the one it was, the first output the chosen tokens)
-AFMOE_PROGRAMS = {**AFMOE_ONE_LANE_PROGRAMS, "decode4": "572dcee861767fc7"}
+AFMOE_PROGRAMS = {**AFMOE_ONE_LANE_PROGRAMS, "decode4": "c1d82c3af190020b"}
 
 
 @pytest.mark.parametrize("program", sorted(AFMOE_PROGRAMS))
@@ -2015,12 +2022,15 @@ def test_afmoe_programs_lower_to_the_text_they_had(program):
 # to change one of these programs replaces its line. (`brumby`, PR 53: no page
 # kind, so one bool array of the rows that are tokens where the others have
 # page tables and coordinates, and the state arena returned by the step
-# itself; its hashes were taken on the PR that brought it.)
+# itself; its hashes were taken on the PR that brought it. `ling_hybrid` and
+# `sdar_moe` changed on purpose in PR 54 for the `tile_visits` count, as
+# `NEIGHBOUR_PROGRAMS` says of `kimi_k2`; `ouro` and `brumby` held to the
+# digit.)
 STATEFUL_AND_LOOP_PROGRAMS = {
-    "ling_hybrid": {"prefill16": "c91f4b86aa216e0d", "decode1": "5a475ecb22902493",
-                    "decode4": "980fcae547b11523", "chunk16": "fe958055961ef601"},
-    "sdar_moe": {"prefill16": "3ea45d58a29c9578", "decode1": "bc96e43f03cfa200",
-                 "decode4": "c566215fa23a97b9", "chunk16": "503185b24e877291"},
+    "ling_hybrid": {"prefill16": "5cd1bcacaad57370", "decode1": "b6836e0d3eb10ad5",
+                    "decode4": "c5ebaf91f131b868", "chunk16": "ab875e35f73cd17e"},
+    "sdar_moe": {"prefill16": "f764035387a6f05d", "decode1": "98e070ae0b42068f",
+                 "decode4": "9503a567e5f4273b", "chunk16": "36c8461ffeb5fe4c"},
     "ouro": {"prefill16": "4484f00252d4386c", "decode1": "e10c4608e3b11682",
              "decode4": "2e2e7b24963ce4c9", "chunk16": "948472f3189d1356"},
     "brumby": {"prefill16": "ac59851bf3eb37c4", "decode1": "721ba2cd9f168cb3",
