@@ -58,7 +58,8 @@ from flax.linen.initializers import ones
 
 from ray_tpu.models.afmoe import rope_angles
 from ray_tpu.models.layers import (A_HEAD, declare_weights, head, last_row,
-                                   rms, rope, swiglu, unboxed_params)
+                                   put_slot_state, rms, rope, slot_state,
+                                   swiglu, unboxed_params)
 
 # what each step returns last, an int32 vector: the states the step read and
 # wrote (live sequences x layers, padded lanes not counted) and the tokens it
@@ -403,22 +404,6 @@ def _close_layer(lp, cfg: BrumbyConfig, x, o):
     return x + swiglu(m, lp["mlp_gate_up"], lp["mlp_down"], dtype)
 
 
-def _slot_state(arena, slot, layer: int):
-    """Layer `layer` of slot `slot` (traced) of an arena array [slots, L,
-    ...], as a float32 batch of one: a slice, not a gather."""
-    at = (slot, layer) + (0,) * (arena.ndim - 2)
-    return jax.lax.dynamic_slice(
-        arena, at, (1, 1) + arena.shape[2:])[0].astype(jnp.float32)
-
-
-def _put_slot_state(arena, new, slot, layer: int):
-    """`new` ([1, ...], as `_slot_state` gives it) written where it was
-    read: an update of the donated arena in place."""
-    at = (slot, layer) + (0,) * (arena.ndim - 2)
-    return jax.lax.dynamic_update_slice(arena, new[None].astype(arena.dtype),
-                                        at)
-
-
 def _step_counts(cfg: BrumbyConfig, sequences, tokens):
     return jnp.stack([jnp.asarray(sequences, jnp.int32) * cfg.n_layer,
                       jnp.asarray(tokens, jnp.int32)])
@@ -521,10 +506,10 @@ def decode_step(variables, cfg: BrumbyConfig, tokens, positions,
             s_arena, z_arena, out = carry
             o, s, z = retention_update(
                 phi_q[j][None], phi_k[j][None], v[j][None], log_g[j][None],
-                _slot_state(s_arena, slots[j], i),
-                _slot_state(z_arena, slots[j], i), cfg.retention_eps)
-            return (_put_slot_state(s_arena, s, slots[j], i),
-                    _put_slot_state(z_arena, z, slots[j], i),
+                slot_state(s_arena, slots[j], i),
+                slot_state(z_arena, slots[j], i), cfg.retention_eps)
+            return (put_slot_state(s_arena, s, slots[j], i),
+                    put_slot_state(z_arena, z, slots[j], i),
                     out.at[j].set(o[0]))
 
         s_arena, z_arena, o = jax.lax.fori_loop(

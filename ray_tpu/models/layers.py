@@ -185,6 +185,22 @@ def gather_pages(pages, page_table, i: int):
     return got.reshape(got.shape[0], -1, got.shape[-1])
 
 
+def slot_state(arena, slot, layer: int):
+    """Layer `layer` of slot `slot` (traced) of a sequence-state arena array
+    [slots, L, ...], as a float32 batch of one: a slice, not a gather."""
+    at = (slot, layer) + (0,) * (arena.ndim - 2)
+    return jax.lax.dynamic_slice(
+        arena, at, (1, 1) + arena.shape[2:])[0].astype(jnp.float32)
+
+
+def put_slot_state(arena, new, slot, layer: int):
+    """`new` ([1, ...], as `slot_state` gives it) written where it was read:
+    an update of the donated arena in place."""
+    at = (slot, layer) + (0,) * (arena.ndim - 2)
+    return jax.lax.dynamic_update_slice(arena, new[None].astype(arena.dtype),
+                                        at)
+
+
 def last_row(rows, true_len):
     """rows [B, S, ...] of a padded prompt batch from position 0 on -> each
     sequence's last real row [B, ...] (row 0 of an empty one)."""

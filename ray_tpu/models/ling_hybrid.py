@@ -12,8 +12,15 @@ What it asks of the system that `kimi_k2.py` does not:
   step: a float32 `[n_head, d_k, d_v]` matrix a head and the last
   `conv_width - 1` inputs of its short convolution. `seq_state(cfg)`
   declares those arrays; the cache manager keeps them a slot a sequence,
-  the steps take the arena's arrays and the lanes' slots (`seq_state=`,
-  `slots=`) and return each sequence's new state after the cache rows.
+  and every step, the prefill too, takes the donated arena's arrays and the
+  lanes' slots (`seq_state=`, `slots=`) and returns THE ARENA'S ARRAYS after
+  the cache rows (`STATE_IN_PLACE`, the contract `brumby.py` runs): a KDA
+  layer reads its lanes' states from the arena and writes their successors
+  back where they lay. A decode step moves 2 MB a lane a layer, so nothing
+  of [lanes, layers, ...] or [lanes, ...] stands beside the arena there: no
+  gather of the lanes' states, no stack of new ones, no scatter
+  (`decode_step`). A chunk and a prefill return one row of logits a
+  sequence, its last token's.
 - A KDA layer in two forms with the same numbers: `kda_chunk`, a blocked
   evaluation of the recurrence for prefill and chunks, and `kda_step`, the
   recurrence itself for one token.
@@ -23,8 +30,12 @@ The recurrence, a head, state S [d_k, d_v] (zero before the first token),
 decay alpha_t = exp(g_t) a channel of d_k, beta_t a scalar:
 
     S' = diag(alpha_t) S_{t-1}
-    S_t = S' + beta_t k_t (v_t - S'^T k_t)^T
-    o_t = S_t^T q_t
+    u_t = beta_t (v_t - S'^T k_t)
+    S_t = S' + k_t u_t^T
+    o_t = S_t^T q_t = S'^T q_t + (k_t . q_t) u_t
+
+(the last form needs nothing of S_t: a step that reads S' once has both
+S'^T k_t and S'^T q_t, and writes S_t over it: `kda_step`.)
 
 The MLA layer is DeepSeek's without the query bottleneck, with RMSNorm over
 each query head and a per-head sigmoid gate on the output; it calls
@@ -44,6 +55,7 @@ attn_out, mlp_norm}` with, in a KDA layer, `kda_qkv` ([q | k | v]),
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any, Tuple
 
 import flax.linen as nn
@@ -53,15 +65,22 @@ import jax.numpy as jnp
 from ray_tpu.models.kimi_k2 import (KimiK2Config, attend_absorbed,
                                     attend_expanded, yarn_tables)
 from ray_tpu.models.layers import (declare_weights, gather_pages, head,
-                                   last_row, rms, rope, routed_feed_forward,
+                                   last_row, put_slot_state, rms, rope,
+                                   routed_feed_forward, slot_state,
                                    top_shapes, unboxed_params)
 from ray_tpu.parallel.moe import MOE_COUNTS
 
 # what each step returns last, an int32 vector summed over the layers:
 # Kimi's expert counts and key slots (the MLA layers), then the KDA states
-# the step read and wrote (sequences x KDA layers, padded lanes not counted)
+# the step had to read and write (live sequences x KDA layers, padded lanes
+# not counted), then the (slot, KDA layer) states it did read and write,
+# idle slots and padded lanes included: a decode step in slot order walks
+# every slot of the arena, one in lane order its bucket's lanes
 STEP_COUNTS = tuple(f"moe_{name}" for name in MOE_COUNTS) \
-    + ("attn_key_slots", "kda_state_rows")
+    + ("attn_key_slots", "kda_state_rows", "kda_slot_rows")
+# the steps take the state arena (`seq_state=`, `slots=`; the prefill too)
+# and return the arena's arrays (`engine._family_cache`)
+STATE_IN_PLACE = True
 # tokens the blocked scan folds into the state at a time, and the
 # sub-blocks within which decays are taken against one reference point
 KDA_BLOCK = 64
@@ -230,10 +249,10 @@ class LingHybrid(nn.Module):
         cfg = self.config
         p = declare_weights(top_shapes(cfg), (
             layer_shapes(cfg, i) for i in range(cfg.n_layer)), cfg.param_dtype)
-        logits, *_ = _window_forward(
+        x, *_ = _window_forward(
             p, cfg, tokens, jnp.zeros(tokens.shape[:1], jnp.int32), None,
             None, None, None)
-        return logits
+        return head(p, cfg, x)
 
 
 # -- Kimi Delta Attention -----------------------------------------------------
@@ -340,14 +359,66 @@ def kda_chunk(q, k, v, g, beta, state):
 def kda_step(q, k, v, g, beta, state):
     """One token a sequence: the recurrence itself. q, k, g [B, H, dk];
     v [B, H, dv]; beta [B, H]; state [B, H, dk, dv]; float32. Returns
-    (o [B, H, dv], the new state)."""
+    (o [B, H, dv], the new state). The old state is read once: both
+    reductions S'^T k and S'^T q come off one product, and the output by
+    `o = S'^T q + (k . q) u` needs nothing of the new state, so a caller
+    may write the new state over the old (`kda_slots`, `kda_lanes`) and
+    nothing tempts the compiler to keep a copy."""
+    decayed = jnp.exp(g)[..., None] * state
+    both = jnp.einsum("bhkv,bhnk->bhnv", decayed, jnp.stack([k, q], 2),
+                      precision=HIGHEST)
+    u = beta[..., None] * (v - both[:, :, 0])
+    o = both[:, :, 1] + jnp.sum(k * q, -1, keepdims=True) * u
+    return o, decayed + k[..., None] * u[..., None, :]
+
+
+def slot_lanes(slots, n: int):
+    """The lane that names each of the arena's n slots, -1 where none does:
+    a decode step's `slots` [B] (lane i's slot; a padded lane names the
+    scratch slot, n) turned about, once a step for all its KDA layers."""
+    return jnp.full((n,), -1, jnp.int32).at[slots].set(
+        jnp.arange(slots.shape[0], dtype=jnp.int32), mode="drop")
+
+
+def kda_slots(s_arena, j: int, slots, q, k, v, g, beta, *, lanes):
+    """`kda_step` of layer j for every lane on the state arena where it
+    lies, in SLOT ORDER: for a bucket that covers the arena. s_arena
+    [n + 1, n_kda, H, dk, dv], its last slot the padded lanes' scratch;
+    slots [B], lane i's slot; `lanes` [n] is `slot_lanes(slots, n)`; the
+    rest as `kda_step` takes them. Each slot's row of the small inputs is
+    its lane's, and `kda_step` runs over the static slice `s_arena[:n, j]`:
+    one read of the old states, one read and write for the update, which
+    lands where the old state lay. A slot that no lane names (idle, or held
+    by a sequence that is mid-prefill) keeps its state bit for bit; a padded
+    lane reads some slot's output, and nobody reads the lane's. Returns
+    (o [B, H, dv], the arena)."""
+    n = s_arena.shape[0] - 1
     with jax.named_scope("kda_step"):
-        decayed = jnp.exp(g)[..., None] * state
-        seen = jnp.einsum("bhkv,bhk->bhv", decayed, k, precision=HIGHEST)
-        u = beta[..., None] * (v - seen)
-        state = decayed + k[..., None] * u[..., None, :]
-        o = jnp.einsum("bhkv,bhk->bhv", state, q, precision=HIGHEST)
-    return o, state
+        old, at = s_arena[:n, j], jnp.maximum(lanes, 0)
+        o, new = kda_step(q[at], k[at], v[at], g[at], beta[at], old)
+        new = jnp.where((lanes >= 0)[:, None, None, None], new, old)
+        s_arena = jax.lax.dynamic_update_slice(s_arena, new[:, None],
+                                               (0, j, 0, 0, 0))
+        return o[jnp.minimum(slots, n - 1)], s_arena
+
+
+def kda_lanes(s_arena, j: int, slots, q, k, v, g, beta):
+    """`kda_step` of layer j on the state arena in LANE ORDER, for a bucket
+    smaller than the arena: a loop over the lanes that slices a lane's state
+    out of its slot (a slice, not a gather) and writes its successor back.
+    A padded lane names the scratch slot, and what lands there is nobody's.
+    Arguments and results as `kda_slots`. (Both walks lie whole under the
+    scope `kda_step`: what a profile reads the path by.)"""
+    def lane(i, carry):
+        s_arena, out = carry
+        o, new = kda_step(q[i][None], k[i][None], v[i][None], g[i][None],
+                          beta[i][None], slot_state(s_arena, slots[i], j))
+        return put_slot_state(s_arena, new, slots[i], j), out.at[i].set(o[0])
+
+    with jax.named_scope("kda_step"):
+        s_arena, o = jax.lax.fori_loop(0, q.shape[0], lane,
+                                       (s_arena, jnp.zeros_like(v)))
+    return o, s_arena
 
 
 def _short_conv(x, weight):
@@ -441,10 +512,10 @@ def _rope_tables(cfg: LingHybridConfig, positions):
 
 # -- the three steps ----------------------------------------------------------
 
-def _step_counts(moe_counts, key_slots, state_rows):
+def _step_counts(moe_counts, key_slots, state_rows, slot_rows):
     return jnp.concatenate([moe_counts, jnp.stack([
-        jnp.asarray(key_slots, jnp.int32),
-        jnp.asarray(state_rows, jnp.int32)])])
+        jnp.asarray(n, jnp.int32)
+        for n in (key_slots, state_rows, slot_rows)])])
 
 
 def _window_forward(p, cfg: LingHybridConfig, tokens, start, pages,
@@ -454,8 +525,9 @@ def _window_forward(p, cfg: LingHybridConfig, tokens, start, pages,
     the KDA layers from `state` = (states [B, n_kda, H, dk, dv], tails
     [B, n_kda, (W - 1) * ch]), or from zero when None. The tokens are the
     leading rows of the window; `valid_rows` [B, C] marks them (None:
-    all), and the rows after them change no state. Returns (logits
-    [B, C, V], latents [B, C, n_mla, row], (states, tails), counts)."""
+    all), and the rows after them change no state. Returns (the stream
+    [B, C, d_model] after the last layer, latents [B, C, n_mla, row],
+    (states, tails), counts)."""
     dtype = cfg.dtype
     b, c = tokens.shape
     x = p["wte"].astype(dtype)[tokens]
@@ -504,54 +576,77 @@ def _window_forward(p, cfg: LingHybridConfig, tokens, start, pages,
                                    flat_valid)
         x = x + y.reshape(b, c, -1)
         counts = counts + n
-    return head(p, cfg, x), jnp.stack(latents, axis=2), \
+    return x, jnp.stack(latents, axis=2), \
         (jnp.stack(states, axis=1), jnp.stack(tails, axis=1)), \
-        _step_counts(counts, key_slots, b * len(states))
+        _step_counts(counts, key_slots, b * len(states), b * len(states))
+
+
+def _put_window_state(seq_state, slots, new):
+    """The window's sequences' new states (`new`: [B, n_kda, ...] an array
+    of the arena) written to their slots of the donated arena: a window is
+    one sequence or a few, so what stands beside the arena is theirs."""
+    return tuple(arr.at[slots].set(x.astype(arr.dtype))
+                 for arr, x in zip(seq_state, new))
 
 
 def prefill_step(variables, cfg: LingHybridConfig, tokens, true_len,
-                 valid=None):
-    """Full forward over a padded prompt batch, every state from zero.
-    tokens [B, S]; true_len [B]; `valid` [B, S] marks the rows that are
-    tokens (None: the first `true_len`). Returns (next_logits [B, V],
-    latents [B, S, n_mla, row], states, tails, counts); latent rows past
-    true_len are garbage the caller must not cache, the states are those
-    after the last token."""
+                 seq_state=None, slots=None, valid=None):
+    """Full forward over a padded prompt batch, every state from zero,
+    whatever the sequences' slots held. tokens [B, S]; true_len [B];
+    `seq_state` the arena's arrays ([slots + 1, n_kda, ...]), `slots` [B];
+    `valid` [B, S] marks the rows that are tokens (None: the first
+    `true_len`). Returns (next_logits [B, V], latents [B, S, n_mla, row],
+    the arena's arrays with the states after the last token in the slots,
+    counts); latent rows past true_len are garbage the caller must not
+    cache."""
     p = unboxed_params(variables)
     b, s = tokens.shape
     if valid is None:
         valid = jnp.arange(s)[None, :] < true_len[:, None]
-    logits, latents, state, counts = _window_forward(
+    x, latents, state, counts = _window_forward(
         p, cfg, tokens, jnp.zeros((b,), jnp.int32), None, None, valid, None)
-    return (last_row(logits, true_len), latents) + state + (counts,)
+    return (head(p, cfg, last_row(x, true_len)), latents) \
+        + _put_window_state(seq_state, slots, state) + (counts,)
 
 
 def chunk_step(variables, cfg: LingHybridConfig, tokens, start, pages,
                page_table, seq_state=None, slots=None, valid=None):
     """C tokens a sequence against a paged cache that holds its first
     `start` positions and the state arena's slot that holds its KDA state
-    after them. `seq_state` the arena's arrays ([slots, n_kda, ...]),
+    after them. `seq_state` the arena's arrays ([slots + 1, n_kda, ...]),
     `slots` [B]. A sequence's first window (`start` 0) starts from zero
-    whatever its slot held. Returns (logits [B, C, V], latents, states,
-    tails, counts)."""
+    whatever its slot held. Returns (the logits of each sequence's last
+    token [B, V], latents, the arena's arrays, counts)."""
+    p = unboxed_params(variables)
+    if valid is None:
+        valid = jnp.ones(tokens.shape, bool)
     first = start == 0
     state = tuple(jnp.where(first.reshape((-1,) + (1,) * (a.ndim - 1)),
                             jnp.zeros((), a.dtype), a[slots])
                   for a in seq_state)
-    logits, latents, state, counts = _window_forward(
-        unboxed_params(variables), cfg, tokens, start, pages, page_table,
-        valid, state)
-    return (logits, latents) + state + (counts,)
+    x, latents, state, counts = _window_forward(
+        p, cfg, tokens, start, pages, page_table, valid, state)
+    n_valid = jnp.sum(valid.astype(jnp.int32), axis=1)
+    return (head(p, cfg, last_row(x, n_valid)), latents) \
+        + _put_window_state(seq_state, slots, state) + (counts,)
 
 
 def decode_step(variables, cfg: LingHybridConfig, tokens, positions, pages,
                 page_table, seq_state=None, slots=None, valid=None):
     """One token a sequence: the MLA layers' absorbed path over the paged
-    cache, the KDA layers' recurrence on the lanes' slots of the state
-    arena (`seq_state`, gathered a layer at a time). tokens [B]; positions
-    [B]; `valid` [B] marks the lanes that hold a sequence. Returns (logits
-    [B, V], latents [B, n_mla, row], states [B, n_kda, H, dk, dv], tails
-    [B, n_kda, (W - 1) * ch], counts)."""
+    cache, the KDA layers' recurrence ON the state arena (`seq_state`, the
+    donated arrays [slots + 1, n_kda, ...]; `slots` [B] names lane i's):
+    each layer reads its lanes' states from the arena and writes their
+    successors back where they lay. One recurrence (`kda_step`) walked two
+    ways, by a shape the program sees: a bucket that covers the arena (lanes
+    >= slots, the scratch slot apart) walks it in slot order, densely
+    (`kda_slots`, the slots' lanes found once a step: `slot_lanes`); a
+    smaller one walks its lanes (`kda_lanes`: slot order would cost the
+    bucket of one a walk of every slot, lane order the bucket of 64 a few
+    hundred trips of a loop). tokens [B]; positions [B]; `valid` [B] marks
+    the lanes that hold a sequence (the others name the scratch slot).
+    Returns (logits [B, V], latents [B, n_mla, row], the arena's arrays,
+    counts)."""
     p = unboxed_params(variables)
     dtype = cfg.dtype
     b = tokens.shape[0]
@@ -562,7 +657,13 @@ def decode_step(variables, cfg: LingHybridConfig, tokens, positions, pages,
     seen_keys = (key_idx[None, :] < positions[:, None]) | \
         (key_idx[None, :] == t_max)
     s_arena, tail_arena = seq_state
-    latents, states, tails = [], [], []
+    n_slots = s_arena.shape[0] - 1
+    if b >= n_slots:
+        walk, walked = functools.partial(
+            kda_slots, lanes=slot_lanes(slots, n_slots)), n_slots
+    else:
+        walk, walked = kda_lanes, b
+    latents, tails = [], []
     counts = jnp.zeros(len(MOE_COUNTS), jnp.int32)
     for i in range(cfg.n_layer):
         lp = p[f"layer{i}"]
@@ -578,22 +679,23 @@ def decode_step(variables, cfg: LingHybridConfig, tokens, positions, pages,
             x = x + _mla_out(lp, cfg, att, gate)
             latents.append(lat)
         else:
-            j = len(states)
+            j = len(tails)
             u, g, beta, gate = _kda_project(lp, cfg, h)
             seen = jnp.concatenate(
                 [_tail_rows(cfg, tail_arena[slots, j]), u[:, None]], axis=1)
             q, k, v = _kda_heads(cfg, _short_conv(seen, lp["kda_conv"])[:, 0])
-            o, new = kda_step(q, k, v, g, beta, s_arena[slots, j])
+            o, s_arena = walk(s_arena, j, slots, q, k, v, g, beta)
             x = x + _kda_out(lp, cfg, o, gate)
-            states.append(new)
             tails.append(seen[:, 1:].reshape(b, -1))
         h = rms(x, lp["mlp_norm"], cfg.norm_eps, dtype)
         y, n = routed_feed_forward(lp, cfg, i, h, valid)
         x = x + y
         counts = counts + n
+    # a tail is 72 KB a lane a layer: the lanes' rows, written at their slots
+    tail_arena = tail_arena.at[slots].set(
+        jnp.stack(tails, axis=1).astype(tail_arena.dtype))
     lanes = b if valid is None else jnp.sum(valid.astype(jnp.int32))
     # every lane of the bucket scores all of its table's slots and itself
-    return head(p, cfg, x), jnp.stack(latents, axis=1), \
-        jnp.stack(states, axis=1), jnp.stack(tails, axis=1), \
+    return head(p, cfg, x), jnp.stack(latents, axis=1), s_arena, tail_arena, \
         _step_counts(counts, len(latents) * b * (t_max + 1),
-                     lanes * len(states))
+                     lanes * len(tails), walked * len(tails))

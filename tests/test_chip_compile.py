@@ -225,12 +225,12 @@ def test_engine_program_updates_the_donated_arena_in_place(topo, kind, size):
         _assert_returns_a_token_a_lane(compiled, size)
 
 
-def _materialised(hlo_text):
-    """(dtype, dims) of every array that an instruction outside a fused
-    computation defines: what the program holds in memory, not what a
-    fusion computes on the way."""
+def _scheduled(hlo_text):
+    """The instructions outside every fused computation, one line each: the
+    operations the device runs (and a trace names), not what a fusion
+    computes on the way."""
     fused = set(re.findall(r"fusion\([^\n]*calls=(%[\w.\-]+)", hlo_text))
-    out, comp = [], None
+    comp = None
     for line in hlo_text.splitlines():
         opened = re.match(r"(?:ENTRY )?(%[\w.\-]+) \(.*\{\s*$", line)
         if opened:
@@ -238,11 +238,19 @@ def _materialised(hlo_text):
         elif line.startswith("}"):
             comp = None
         elif comp is not None and comp not in fused and " = " in line:
-            head = line.split(" = ", 1)[1]
-            head = head[:head.index(")") + 1] if head.startswith("(") \
-                else head.split(" ", 1)[0]
-            out += [(m.group(1), tuple(map(int, m.group(2).split(","))))
-                    for m in re.finditer(r"(\w+)\[([0-9,]+)\]", head)]
+            yield line.strip()
+
+
+def _materialised(hlo_text):
+    """(dtype, dims) of every array that an instruction outside a fused
+    computation defines: what the program holds in memory."""
+    out = []
+    for line in _scheduled(hlo_text):
+        head = line.split(" = ", 1)[1]
+        head = head[:head.index(")") + 1] if head.startswith("(") \
+            else head.split(" ", 1)[0]
+        out += [(m.group(1), tuple(map(int, m.group(2).split(","))))
+                for m in re.finditer(r"(\w+)\[([0-9,]+)\]", head)]
     return out
 
 
@@ -372,10 +380,16 @@ def test_sequence_state_arena_is_updated_in_place_at_ling_widths(topo, kind,
     cell (published widths, 7 layers of which one pages, 128 of 512 experts
     held, 32,768 pages of 16 tokens, 65 state slots): the latent arena and
     both sequence-state arrays alias their outputs, no operation copies or
-    re-lays out an array of the state arena's or the latent arena's shape
-    (the decode step gathers a lane's state a layer at a time and scatters
-    the lanes' new states at their slots), and the program fits the chip
-    beside its 10.5 GB of weights."""
+    re-lays out an array of the state arena's or the latent arena's shape,
+    and the program fits the chip beside its 10.5 GB of weights. The family
+    updates the state arena itself (`STATE_IN_PLACE`, PR 55), and the
+    decode-64 program walks it in slot order: nothing of the lanes' states'
+    size stands beside the arena (no gather, no stack: 1.48 GB of
+    temporaries fewer than the parent's program), and a KDA layer's update
+    is ONE operation with ONE result, of the arena's type, which the
+    benchmark's trace readers find by that type (a tuple there would turn
+    them dark); both reductions are one more, and the `kda_path_*` readers'
+    pattern takes exactly those two a layer."""
     import types
 
     from ray_tpu.models import ling_hybrid
@@ -403,7 +417,7 @@ def test_sequence_state_arena_is_updated_in_place_at_ling_widths(topo, kind,
     engine = types.SimpleNamespace(
         _mod=ling_hybrid, model_cfg=cfg,
         _step_counts=ling_hybrid.STEP_COUNTS,
-        _state_in_place=False, kv=_fake_kv((pages,), state))
+        _state_in_place=True, kv=_fake_kv((pages,), state))
     lanes = size if kind == "decode" else 1
     rows = (size,) if kind == "decode" else (1, size)
     fn = LLMEngine._make_decode_fn(engine, size) if kind == "decode" \
@@ -429,8 +443,61 @@ def test_sequence_state_arena_is_updated_in_place_at_ling_widths(topo, kind,
         moved = [line.strip()[:120] for line in text.splitlines()
                  if " copy(" in line and shape in line.split(" copy(")[0]]
         assert not moved, moved
-    if kind == "decode":
-        _assert_returns_a_token_a_lane(compiled, size)
+    if kind == "chunk":
+        return
+    _assert_returns_a_token_a_lane(compiled, size)
+    held = _materialised(text)
+    assert not [a for a in held if a[0] == "f32" and a[1] in (
+        (size, 6, 32, 128, 128), (size, 32, 128, 128))]
+    print(f"temp_size_in_bytes {mem.temp_size_in_bytes} "
+          f"(the parent's program: {LING_DECODE_64_TEMP_BYTES_AT_PR_54})")
+    assert mem.temp_size_in_bytes \
+        <= LING_DECODE_64_TEMP_BYTES_AT_PR_54 - 10**9
+    # the operations the KDA trace readers match, as the chip names them:
+    # `kda_step_*` / `kda_device_pct` (the accepted pair: the arena's type)
+    # and `kda_path_*` (PR 55: the whole path, update and reductions)
+    import json
+
+    from benchmark import trace_reduce
+
+    def pattern_of(metric):
+        with open(os.path.join(os.path.dirname(os.path.dirname(
+                os.path.abspath(__file__))), "benchmark", "layer_metrics",
+                metric + ".reason-wide.json")) as f:
+            return re.compile(json.load(f)["args"]["pattern"])
+
+    by_type = pattern_of("kda_step_hbm_roofline_pct")
+    path = pattern_of("kda_path_hbm_roofline_pct")
+    assert path.pattern == pattern_of("kda_path_device_pct").pattern
+    arena_type = "f32[" + ",".join(map(str, state[0].shape)) + "]"
+    made = [line for line in _scheduled(text)
+            if re.search(r"\) fusion\(|\} fusion\(", line)]
+    updates = [line for line in made
+               if arena_type in line.split(" fusion(")[0]]
+    assert len(updates) == len(cfg.kda_layers), updates
+    for line in updates:
+        kind_of = trace_reduce.op_name(line)
+        # one result, and every reader sees it
+        assert kind_of.endswith(" " + arena_type) and by_type.match(kind_of) \
+            and path.match(kind_of), kind_of
+    # the reductions S'^T k and S'^T q, off one read of the old state: one
+    # operation a layer, which the path's readers see (the accepted pair's
+    # pattern, written for the parent's program, does not: PERF.md section 7)
+    reductions = [trace_reduce.op_name(line) for line in made
+                  if "dot_general" in line and "kda_step" in line]
+    print("kda_step reductions:", sorted(set(reductions)))
+    assert len(reductions) == len(cfg.kda_layers), reductions
+    assert all(path.match(k) for k in reductions), reductions
+    # and nothing else of the program is the path's: not the 1 MB rows that
+    # put the lanes' inputs in slot order
+    seen = [k for k in map(trace_reduce.op_name, made) if path.match(k)]
+    assert len(seen) == 2 * len(cfg.kda_layers), seen
+
+
+# `temp_size_in_bytes` of the decode-64 program above on the parent of PR 55
+# (a4d277e: the lanes' states gathered [64,6,32,128,128], the new ones
+# stacked, the stack scattered by the engine), compiled here the same way
+LING_DECODE_64_TEMP_BYTES_AT_PR_54 = 2_458_232_832
 
 
 @pytest.mark.parametrize("kind, size", [("decode", 64), ("prefill", 1024),

@@ -7,6 +7,7 @@ sequence beside latent pages) against its plain reference.
 CPU, tiny sizes, seeded weights; float32 unless a test says otherwise.
 """
 
+import functools
 import subprocess
 import sys
 
@@ -130,6 +131,177 @@ def test_decode_update_is_one_step_of_the_recurrence(decays):
                                 jnp.asarray(state)[None])
     _close(np.asarray(o), want_o, 1e-5)
     _close(np.asarray(s[0]), want_s, 1e-5)
+
+
+# -- the recurrence on the state arena where it lies --------------------------
+
+def _by_gather(s_arena, j, slots, q, k, v, g, beta, lanes=None):
+    """What the in-place walks replace: `kda_step` on the lanes' states
+    gathered from their slots, the new ones scattered back."""
+    o, new = lh.kda_step(q, k, v, g, beta, s_arena[slots, j])
+    return o, s_arena.at[slots, j].set(new)
+
+
+# (slots of the arena beside the scratch slot, the slot each of four lanes
+# names; the scratch slot is the padded lanes'). A bucket of four lanes
+# covers an arena of up to four slots and walks it in slot order
+# (`kda_slots`); against a larger arena it walks its lanes (`kda_lanes`)
+ARENAS = {
+    "slots-permuted": (4, [2, 0, 3, 1]),
+    "slots-holes": (4, [3, 4, 1, 4]),       # slots 0 and 2 are nobody's
+    "slots-padded": (3, [2, 0, 1, 3]),
+    "lanes-permuted": (6, [5, 0, 3, 1]),
+    "lanes-holes": (8, [6, 2, 0, 4]),
+    "lanes-padded": (6, [2, 6, 0, 6]),
+}
+
+
+def _tiny_arena(cfg, n_slots, seed):
+    """A state arena of `n_slots` and the scratch slot with every entry
+    drawn: states of order one, tails of order one in the model's type."""
+    rng = np.random.default_rng(seed)
+    return tuple(jnp.asarray(rng.normal(size=(n_slots + 1,) + shape), dtype)
+                 for shape, dtype in lh.seq_state(cfg))
+
+
+@pytest.mark.parametrize("case", sorted(ARENAS))
+def test_a_walk_updates_its_layer_of_the_named_slots_and_nothing_else(case):
+    """`kda_slots` and `kda_lanes` on one layer of an arena, against
+    `kda_step` on the gathered states: the lanes' outputs and the named
+    slots' new states within the step's tolerance; every other layer of
+    every slot, and every slot that no lane names, bit for bit as it was
+    (a slot may be held by a sequence that is mid-prefill)."""
+    n_slots, slots = ARENAS[case]
+    layer, slots = 2, jnp.asarray(slots, jnp.int32)
+    walk = functools.partial(lh.kda_slots,
+                             lanes=lh.slot_lanes(slots, n_slots)) \
+        if case.startswith("slots") else lh.kda_lanes
+    q, k, v, g, beta, _ = _kda_inputs(4, "mixed", seed=n_slots, h=4)
+    rng = np.random.default_rng(7)
+    arena = jnp.asarray(rng.normal(size=(n_slots + 1, 6, 4, 16, 16)),
+                        jnp.float32)
+    lanes = tuple(jnp.asarray(x) for x in (q, k, v, g, beta))
+    got_o, got = jax.jit(walk, static_argnums=1)(arena, layer, slots, *lanes)
+    want_o, want = _by_gather(arena, layer, slots, *lanes)
+    live = np.asarray(slots) < n_slots
+    named = np.asarray(slots)[live]
+    _close(np.asarray(got_o)[live], np.asarray(want_o)[live], 1e-5)
+    _close(np.asarray(got)[named, layer], np.asarray(want)[named, layer],
+           1e-5)
+    # the named slots did change, and nothing else did
+    assert not np.array_equal(np.asarray(got)[named, layer],
+                              np.asarray(arena)[named, layer])
+    others = np.ones(arena.shape[:2], bool)
+    others[named, layer] = False
+    others[n_slots, layer] = False      # the scratch slot is nobody's
+    np.testing.assert_array_equal(np.asarray(got)[others],
+                                  np.asarray(arena)[others])
+
+
+def _tiny_step_inputs(cfg, lanes, seed):
+    """Weights, a paged latent arena of four pages with every row drawn,
+    and a decode step's tokens and positions for `lanes` lanes."""
+    rng = np.random.default_rng(seed)
+    variables = lh.LingHybrid(cfg).init(jax.random.PRNGKey(seed),
+                                        jnp.ones((1, 8), jnp.int32))
+    (row,) = lh.cache_rows(cfg)
+    pages = jnp.asarray(rng.normal(size=(4, lh.paged_layers(cfg), 8) + row),
+                        cfg.dtype)
+    table = jnp.asarray(rng.permutation(4)[None, :2].repeat(lanes, 0),
+                        jnp.int32)
+    tokens = jnp.asarray(rng.integers(0, cfg.vocab_size, lanes), jnp.int32)
+    positions = jnp.asarray(rng.integers(0, 16, lanes), jnp.int32)
+    return variables, pages, table, tokens, positions
+
+
+@pytest.mark.parametrize("case", sorted(ARENAS))
+def test_decode_step_on_the_arena_equals_kda_step_on_gathered_states(
+        case, monkeypatch):
+    """`decode_step` on an arena whose lanes name their slots in a permuted
+    order, leave holes, or are padded lanes on the scratch slot, in both
+    walks: the live lanes' logits and the named slots' states are what
+    `kda_step` on the gathered states gives; the convolution's tails are
+    the same numbers; every slot that no lane names keeps both its arrays
+    bit for bit; and the step counts the states it had to move and the
+    (slot, layer) states it walked."""
+    n_slots, slots = ARENAS[case]
+    cfg = lh.LingHybridConfig.tiny(dtype=jnp.float32,
+                                   param_dtype=jnp.float32)
+    variables, pages, table, tokens, positions = _tiny_step_inputs(cfg, 4, 3)
+    arena = _tiny_arena(cfg, n_slots, seed=5)
+    slots = jnp.asarray(slots, jnp.int32)
+    live = np.asarray(slots) < n_slots
+    step = jax.jit(lambda *a: lh.decode_step(
+        variables, cfg, tokens, positions, pages, table, seq_state=a[:2],
+        slots=a[2], valid=a[3]))
+    logits, latents, states, tails, counts = step(*arena, slots,
+                                                  jnp.asarray(live))
+    monkeypatch.setattr(lh, "kda_slots", _by_gather)
+    monkeypatch.setattr(lh, "kda_lanes", _by_gather)
+    want_logits, want_latents, want_states, want_tails, _ = lh.decode_step(
+        variables, cfg, tokens, positions, pages, table, seq_state=arena,
+        slots=slots, valid=jnp.asarray(live))
+    named = np.asarray(slots)[live]
+    _close(np.asarray(logits)[live], np.asarray(want_logits)[live], 1e-5)
+    _close(np.asarray(latents)[live], np.asarray(want_latents)[live], 1e-5)
+    _close(np.asarray(states)[named], np.asarray(want_states)[named], 1e-5)
+    _close(np.asarray(tails)[named], np.asarray(want_tails)[named], 1e-5)
+    unnamed = np.setdiff1d(np.arange(n_slots), named)
+    for got, was in zip((states, tails), arena):
+        assert got.shape == was.shape and got.dtype == was.dtype
+        np.testing.assert_array_equal(np.asarray(got)[unnamed],
+                                      np.asarray(was)[unnamed])
+    walked = n_slots if case.startswith("slots") else 4
+    by_name = dict(zip(lh.STEP_COUNTS, np.asarray(counts).tolist()))
+    assert by_name["kda_state_rows"] == 6 * int(live.sum())
+    assert by_name["kda_slot_rows"] == 6 * walked
+
+
+@pytest.mark.parametrize("n_slots", [1, 3], ids=["slots", "lanes"])
+def test_a_chunk_then_a_decode_on_one_slot_is_one_scan_over_all_tokens(
+        n_slots):
+    """A window of 21 tokens through `chunk_step` into a slot that held
+    another sequence's state, then the 22nd through `decode_step` on that
+    slot, in both walks (a bucket of one against an arena of one slot, and
+    of three): the slot's state is the state after ONE `chunk_step` over
+    all 22 tokens (`kda_chunk` over the lot), the tail its last three
+    inputs, the logits the 22nd row's, and the other slots are as they
+    were."""
+    from ray_tpu.serve.llm.kv_cache import scatter_arena
+
+    cfg = lh.LingHybridConfig.tiny(dtype=jnp.float32,
+                                   param_dtype=jnp.float32)
+    variables, pages, _, _, _ = _tiny_step_inputs(cfg, 1, 4)
+    arena = _tiny_arena(cfg, n_slots, seed=6)
+    rng = np.random.default_rng(8)
+    ids = jnp.asarray(rng.integers(0, cfg.vocab_size, (1, 32)), jnp.int32)
+    table = jnp.asarray([[2, 0, 3, 1]], jnp.int32)
+    slot = jnp.asarray([n_slots - 1], jnp.int32)
+    start = jnp.zeros((1,), jnp.int32)
+
+    def chunk(n, pages, arena):
+        logits, latents, *state, _ = lh.chunk_step(
+            variables, cfg, ids, start, pages, table, seq_state=arena,
+            slots=slot, valid=jnp.arange(32)[None, :] < n)
+        at = np.arange(n)
+        (pages,) = scatter_arena(
+            (pages,), (latents[0, :n],),
+            jnp.asarray(np.asarray(table)[0][at // 8]), jnp.asarray(at % 8))
+        return logits, pages, tuple(state)
+
+    want_logits, _, want = chunk(22, pages, arena)
+    _, pages, state = chunk(21, pages, arena)
+    logits, _, *state, counts = jax.jit(
+        lambda pages, *a: lh.decode_step(
+            variables, cfg, ids[0, 21:22], jnp.asarray([21], jnp.int32),
+            pages, table, seq_state=a, slots=slot))(pages, *state)
+    _close(np.asarray(logits), np.asarray(want_logits), 1e-4)
+    for got, wanted, was in zip(state, want, arena):
+        _close(np.asarray(got)[n_slots - 1], np.asarray(wanted)[n_slots - 1])
+        np.testing.assert_array_equal(np.asarray(got)[:n_slots - 1],
+                                      np.asarray(was)[:n_slots - 1])
+    by_name = dict(zip(lh.STEP_COUNTS, np.asarray(counts).tolist()))
+    assert (by_name["kda_state_rows"], by_name["kda_slot_rows"]) == (6, 6)
 
 
 def test_exponents_stay_in_float32s_range():
@@ -390,8 +562,8 @@ def test_a_reused_slot_gives_what_a_fresh_engine_gives():
 def test_three_sequences_of_different_lengths_share_a_decode_bucket():
     """Three running sequences (one-shot and chunked prompts) in the bucket
     of four, one lane padded: each streams what it streams alone, the
-    padded lane's scratch slot apart, and the counters count the live
-    lanes only."""
+    padded lane's scratch slot apart, `kda_state_rows` counts the live
+    lanes only and `kda_slot_rows` the slots or lanes a step walked."""
     rng = np.random.default_rng(11)
     prompts = [[int(x) for x in rng.integers(0, 512, n)]
                for n in (9, 40, 70)]
@@ -411,6 +583,11 @@ def test_three_sequences_of_different_lengths_share_a_decode_bucket():
         assert m["compiled_step_calls"]["decode:4"] >= 3
         assert m["decode_kda_state_rows"] == 6 * (
             m["tokens_generated"] - 3)      # a live lane a token, 6 layers
+        # the bucket of four covers the arena's four slots and walks them
+        # all, idle ones too; the bucket of one walks its lane
+        calls = m["compiled_step_calls"]
+        assert m["decode_kda_slot_rows"] == 6 * (
+            4 * calls["decode:4"] + calls.get("decode:1", 0))
         eng.quiesce()
         assert (m["state_slots_live"], m["state_slots_free"]) == (0, 4)
     finally:

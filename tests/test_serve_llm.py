@@ -2025,10 +2025,16 @@ def test_afmoe_programs_lower_to_the_text_they_had(program):
 # itself; its hashes were taken on the PR that brought it. `ling_hybrid` and
 # `sdar_moe` changed on purpose in PR 54 for the `tile_visits` count, as
 # `NEIGHBOUR_PROGRAMS` says of `kimi_k2`; `ouro` and `brumby` held to the
-# digit.)
+# digit. `ling_hybrid` changed on purpose in PR 55: the family declares
+# `STATE_IN_PLACE`, its steps update the state arena themselves (the decode
+# step in slot order or lane order, no gather, stack or scatter of states,
+# `kda_step` reading the old state once), a chunk and a prefill compute one
+# row of logits, and the steps count `kda_slot_rows`; `brumby`, whose two
+# slot helpers moved into `models/layers.py` in that PR, `ouro` and
+# `sdar_moe` held to the digit.)
 STATEFUL_AND_LOOP_PROGRAMS = {
-    "ling_hybrid": {"prefill16": "5cd1bcacaad57370", "decode1": "b6836e0d3eb10ad5",
-                    "decode4": "c5ebaf91f131b868", "chunk16": "ab875e35f73cd17e"},
+    "ling_hybrid": {"prefill16": "5552f28192e60bc7", "decode1": "1e26b795095a4534",
+                    "decode4": "5ab7be559d5b6b07", "chunk16": "6ba55ad58f9eea4d"},
     "sdar_moe": {"prefill16": "f764035387a6f05d", "decode1": "98e070ae0b42068f",
                  "decode4": "9503a567e5f4273b", "chunk16": "36c8461ffeb5fe4c"},
     "ouro": {"prefill16": "4484f00252d4386c", "decode1": "e10c4608e3b11682",
