@@ -11,7 +11,12 @@ state a sequence) beside latent attention with group-limited routing
 that generate by diffusion over blocks with softmax-routed experts
 (`sdar_moe`, serving only; imported when first asked for), decoders of
 power-retention layers that keep one large state a sequence and no paged
-layer (`brumby`, serving only; imported when first asked for), ResNet
+layer (`brumby`, serving only; imported when first asked for), decoders
+of window layers with a learned sink beside full layers, the two kinds with
+K/V head counts of their own and key rows wider than value rows, and
+sigmoid-routed experts with no shared expert (`mimo_v2`, serving only;
+imported when first asked for; `afmoe`, its neighbour with window and full
+kinds of one shape, is reached through the engine's registry alone), ResNet
 convnets (`resnet`), Vision Transformers (`vit`).
 """
 
@@ -29,6 +34,7 @@ __all__ = [
     "ResNet", "ResNetConfig", "ViT", "ViTConfig",
     "KimiK2", "KimiK2Config", "LingHybrid", "LingHybridConfig",
     "SdarMoe", "SdarMoeConfig", "Brumby", "BrumbyConfig",
+    "MimoV2", "MimoV2Config",
 ]
 
 
@@ -39,7 +45,8 @@ def __getattr__(name):
                           ("ling_hybrid", ("LingHybrid",
                                            "LingHybridConfig")),
                           ("sdar_moe", ("SdarMoe", "SdarMoeConfig")),
-                          ("brumby", ("Brumby", "BrumbyConfig"))):
+                          ("brumby", ("Brumby", "BrumbyConfig")),
+                          ("mimo_v2", ("MimoV2", "MimoV2Config"))):
         if name == family or name in names:
             import importlib
 

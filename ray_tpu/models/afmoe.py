@@ -220,18 +220,29 @@ def _fold(state, s, v, dtype):
             preferred_element_type=jnp.float32)
 
 
-@partial(jax.jit, static_argnames=("window", "scale"))
+@partial(jax.jit, static_argnames=("window", "scale", "value_scale"))
 def window_attend(q, k_new, v_new, pages, layer, page_table, start,
-                  window: Optional[int], scale: float):
+                  window: Optional[int], scale: float, sink=None,
+                  value_scale: Optional[float] = None):
     """C tokens a sequence, positions `start` to `start + C - 1`, attending
     causally over themselves and over the sequence's cached positions; with
-    a `window`, query i sees key j only where i - j < window.
+    a `window`, query i sees key j only where i - j < window. The walk of
+    every family whose paged layers are of a window kind and a full kind
+    (`afmoe`, `mimo_v2`); what one of them does not have is None and adds no
+    operation.
 
-    q [B, C, H, D]; k_new/v_new [B, C, KVH, D] (the step's own); pages
-    (k_pages, v_pages) of the layer's kind, each [P, L, block, KVH, D], or
-    None for no cache; `layer` the layer's index among its kind's;
-    page_table [B, n_pages] of that kind; start [B]. The query's H heads are
-    KVH groups of H // KVH scored against K and V as they lie (no repeat).
+    q [B, C, H, D]; k_new [B, C, KVH, D] and v_new [B, C, KVH, DV] (the
+    step's own; V's width is its own); pages (k_pages [P, L, block, KVH, D],
+    v_pages [P, L, block, KVH, DV], or each with its row flat, [P, L, block,
+    KVH * D]: a gathered block is given the step's own shape) of the layer's
+    kind, whose K/V head count is the kind's own, or None for no cache;
+    `layer` the layer's index among its kind's; page_table [B, n_pages] of
+    that kind; start [B]. The query's H heads are KVH groups of H // KVH
+    scored against K and V as they lie (no repeat). `sink` [H] float32: a learned score a query head that joins
+    every row's denominator and brings no value. It is no key: it is the
+    running softmax's state before any key (m = sink, l = 1, acc = 0),
+    which the step's own keys are folded into, exactly. `value_scale`
+    multiplies the weighted sum of the values.
     One running softmax (`_fold`): the step's own keys first, where a row's
     own key gives it a real maximum, then blocks of the table's slots,
     gathered from the arena by (page, layer). One lane (a chunk, the bucket
@@ -254,12 +265,14 @@ def window_attend(q, k_new, v_new, pages, layer, page_table, start,
     ring has one page more than a window's, so every position a query may
     see is still there (`kv_cache.py`).
 
-    Returns ([B, C, H * D] in q's dtype, the key slots a query row was
+    Returns ([B, C, H * DV] in q's dtype; the key slots a query row was
     scored against: int32, C + the walk's blocks x the block; one number
-    where there is one walk, [B] where each lane has its own)."""
+    where there is one walk, [B] where each lane has its own; and, with a
+    sink, the share of each row's softmax mass that the sink took, float32
+    [B, C, H], else None)."""
     with jax.named_scope("attn_full" if window is None else "attn_window"):
         b, c, h, d = q.shape
-        kvh = k_new.shape[2]
+        kvh, dv = k_new.shape[2], v_new.shape[3]
         f32 = jnp.float32
         qg = q.reshape(b, c, kvh, h // kvh, d)
         # the step's own keys: causal, and inside the window
@@ -271,8 +284,14 @@ def window_attend(q, k_new, v_new, pages, layer, page_table, start,
                        preferred_element_type=f32) * scale
         s = jnp.where(seen, s, NEG_INF)
         m = jnp.max(s, axis=-1)
+        if sink is not None:
+            sink = sink.astype(f32).reshape(1, kvh, h // kvh, 1)
+            m = jnp.maximum(m, sink)
         p = jnp.exp(s - m[..., None])
-        state = (m, jnp.sum(p, axis=-1), jnp.einsum(
+        l = jnp.sum(p, axis=-1)
+        if sink is not None:
+            l = l + jnp.exp(sink - m)
+        state = (m, l, jnp.einsum(
             "bgrck,bkgd->bgrcd", p.astype(q.dtype), v_new,
             preferred_element_type=f32))
         slots = jnp.int32(c)
@@ -289,7 +308,7 @@ def window_attend(q, k_new, v_new, pages, layer, page_table, start,
                 ids = jax.lax.dynamic_slice_in_dim(
                     table, j * per_block, per_block, axis=1)
                 k = k_pages[ids, layer].reshape(b, keys, kvh, d)
-                v = v_pages[ids, layer].reshape(b, keys, kvh, d)
+                v = v_pages[ids, layer].reshape(b, keys, kvh, dv)
                 s = jnp.einsum("bcgrd,bkgd->bgrck", qg, k.astype(q.dtype),
                                preferred_element_type=f32) * scale
                 slot = j * per_block + jnp.arange(keys) // page  # [K]
@@ -315,7 +334,7 @@ def window_attend(q, k_new, v_new, pages, layer, page_table, start,
                 ids = table[lane[:, None], at[:, None] * per_block
                             + jnp.arange(per_block)[None, :]]
                 k = k_pages[ids, layer].reshape(b, keys, kvh, d)
-                v = v_pages[ids, layer].reshape(b, keys, kvh, d)
+                v = v_pages[ids, layer].reshape(b, keys, kvh, dv)
                 s = jnp.einsum("bcgrd,bkgd->bgrck", qg[lane],
                                k.astype(q.dtype),
                                preferred_element_type=f32) * scale
@@ -345,10 +364,27 @@ def window_attend(q, k_new, v_new, pages, layer, page_table, start,
                 state = jax.lax.fori_loop(0, -(-jnp.sum(blocks) // b), paired,
                                           state)
                 slots = slots + blocks * keys                   # [B]
-        _, l, acc = state
-        out = acc / jnp.maximum(l, 1e-20)[..., None]   # [B, KVH, R, C, D]
-        return out.transpose(0, 3, 1, 2, 4).reshape(b, c, h * d).astype(
-            q.dtype), slots
+        m, l, acc = state
+        out = acc / jnp.maximum(l, 1e-20)[..., None]   # [B, KVH, R, C, DV]
+        if value_scale is not None:
+            out = out * value_scale
+        mass = None
+        if sink is not None:
+            mass = (jnp.exp(sink - m) / l).transpose(0, 3, 1, 2).reshape(
+                b, c, h)
+        return out.transpose(0, 3, 1, 2, 4).reshape(b, c, h * dv).astype(
+            q.dtype), slots, mass
+
+
+def batch_key_slots(slots, b: int, valid_rows):
+    """`window_attend`'s key slots, summed over a batch of `b` lanes: the
+    one walk's count a lane, or the work list's own a lane, where a pad lane
+    of the bucket (no row of `valid_rows` [B, C] a token) counts nothing."""
+    if not slots.ndim:
+        return b * slots
+    if valid_rows is not None:
+        slots = jnp.where(valid_rows.any(axis=1), slots, 0)
+    return jnp.sum(slots)
 
 
 # -- the three steps ----------------------------------------------------------
@@ -390,8 +426,8 @@ def _window_forward(p, cfg: AfmoeConfig, tokens, start, cache, valid_rows):
         if cache is not None:
             pages = cache[2 * kind:2 * kind + 2]
             table = cache[2 * len(kinds) + kind]
-        att, slots = window_attend(q, k, v, pages, at, table, start,
-                                   window=window, scale=hd ** -0.5)
+        att, slots, _ = window_attend(q, k, v, pages, at, table, start,
+                                      window=window, scale=hd ** -0.5)
         att = att * jax.nn.sigmoid(gate)
         x = x + rms(att @ lp["attn_out"].astype(dtype),
                      lp["post_attn_norm"], cfg.norm_eps, dtype)
@@ -401,12 +437,8 @@ def _window_forward(p, cfg: AfmoeConfig, tokens, start, cache, valid_rows):
         x = x + rms(y.reshape(b, c, -1), lp["post_mlp_norm"], cfg.norm_eps,
                      dtype)
         counts = counts + n
-        if slots.ndim:      # the work list's, a lane: a pad lane's are none
-            if valid_rows is not None:
-                slots = jnp.where(valid_rows.any(axis=1), slots, 0)
-            key_slots[name] = key_slots[name] + jnp.sum(slots)
-        else:
-            key_slots[name] = key_slots[name] + b * slots
+        key_slots[name] = key_slots[name] + batch_key_slots(slots, b,
+                                                            valid_rows)
         rows[kind][0].append(k)
         rows[kind][1].append(v)
     counts = jnp.concatenate([counts, jnp.stack(

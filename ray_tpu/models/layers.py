@@ -1,7 +1,8 @@
 """The layer math the model families share, once and under public names.
 
 A family file (`llama.py`, `gpt.py`, `kimi_k2.py`, `ling_hybrid.py`,
-`sdar_moe.py`, `afmoe.py`, `ouro.py`, `moe_gpt.py`) composes these and keeps
+`sdar_moe.py`, `afmoe.py`, `ouro.py`, `brumby.py`, `mimo_v2.py`,
+`moe_gpt.py`) composes these and keeps
 what is its own: its config, its attention, its step functions, and the
 names the serving engine reads from a family's module
 (`serve/llm/engine.py`, `_family_cache`). This module imports no family, and
@@ -105,6 +106,14 @@ INITS = {
     # projections of deviation one
     "a_log": (_uniform(1.0, 16.0, log=True), jnp.float32),
     "dt_bias": (_uniform(-4.0, 1.0), jnp.float32),
+    # a window layer's learned sinks, one score a query head, drawn so that
+    # a check can see them: with weights of deviation 0.02 every score is
+    # near 0 and a full window's 128 keys weigh about 128, so a sink around
+    # ln 128 with deviation 1 holds a fifth to four fifths of a row's
+    # softmax mass; drawn at zero it would hold under 1%
+    "sink": (lambda key, shape, dtype: (
+        np.log(128.0) + jax.random.normal(key, shape, jnp.float32)
+    ).astype(dtype), jnp.float32),
 }
 
 
@@ -151,8 +160,10 @@ def routed_feed_forward(lp, cfg, i: int, h, valid):
     """Layer i's feed-forward of h [N, d]: the dense SwiGLU below
     `cfg.n_dense_layer`, else this chip's experts' part of the sigmoid-routed
     sum (`expert_shard_layer`; group-limited where the config has `n_group`
-    and `topk_group`, one group where not) plus the shared expert. Returns
-    (result [N, d], counts int32[len(MOE_COUNTS)])."""
+    and `topk_group`, one group where not), plus the shared expert where the
+    config has one (`n_shared`; a model without has no such weights, and the
+    term is left out, not computed at width 0). Returns (result [N, d],
+    counts int32[len(MOE_COUNTS)])."""
     if i < cfg.n_dense_layer:
         with jax.named_scope("dense_mlp"):
             return swiglu(h, lp["mlp_gate_up"], lp["mlp_down"],
@@ -163,6 +174,8 @@ def routed_feed_forward(lp, cfg, i: int, h, valid):
         cfg.first_expert, cfg.n_experts, cfg.top_k, cfg.routed_scale,
         valid=valid, n_group=getattr(cfg, "n_group", 1),
         topk_group=getattr(cfg, "topk_group", 1))
+    if not cfg.n_shared:
+        return routed, counts
     with jax.named_scope("moe_shared"):
         shared = swiglu(h, lp["shared_gate_up"], lp["shared_down"],
                         cfg.dtype)

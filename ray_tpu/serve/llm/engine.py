@@ -133,6 +133,8 @@ MODEL_FAMILIES: Dict[str, ModelFamily] = {
     "afmoe": ModelFamily("ray_tpu.models.afmoe", "Afmoe", "AfmoeConfig"),
     "ouro": ModelFamily("ray_tpu.models.ouro", "Ouro", "OuroConfig"),
     "brumby": ModelFamily("ray_tpu.models.brumby", "Brumby", "BrumbyConfig"),
+    "mimo_v2": ModelFamily("ray_tpu.models.mimo_v2", "MimoV2",
+                           "MimoV2Config"),
 }
 
 

@@ -43,7 +43,10 @@ padded lanes of a decode bucket read and write.
 A family whose layers differ in how much of a sequence they read declares
 KINDS of paged layer (`PageKind`): each kind has its own arrays [pages of
 the kind, layers of the kind, block_size, *row], its own free list and
-holders, and a sequence holds a page list a kind. A kind with a `window`
+holders, and a sequence holds a page list a kind. A row's shape is its
+array's own: two kinds need not agree on it (`mimo_v2`: a window layer's K
+and V rows are 1,536 and 1,024 wide, a full layer's 768 and 512), nor K and V
+of one kind, and every byte count follows the arrays' shapes. A kind with a `window`
 (its queries read the last `window` cached positions and nothing before
 them) is a RING: a sequence holds at most `ring` = pages_for_tokens(window)
 + 1 pages of it, position p lies in the sequence's page (p // block_size)
@@ -213,12 +216,6 @@ class PagedKVCache:
         self.num_pages = num_pages
         self.n_layer = sum(kind.n_layer for kind in self.kinds)
         self.block_size = block_size
-        self.rows = self.kinds[0].rows if self.kinds else ()
-        if n_kv_head is None and len(self.rows) == 2 \
-                and self.rows[0] == self.rows[1] and len(self.rows[0]) == 2:
-            n_kv_head, head_dim = self.rows[0]      # an arena of K and V
-        self.n_kv_head = n_kv_head
-        self.head_dim = head_dim
         self.dtype = np.dtype(dtype)
         # the engine passes a lock whose waits show in its time ledger
         self._lock = lock if lock is not None else threading.Lock()

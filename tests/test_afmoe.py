@@ -306,7 +306,7 @@ def test_mask_is_exact_at_both_edges_of_the_window(window):
             # a large score for this one key, and a value that marks it
             k_pages[page, 0, j % BLOCK, 0] = np.asarray(q[0, 0, 0]) * 5
             v_pages[page, 0, j % BLOCK, 0] = 1.0
-            out, slots = A.window_attend(
+            out, slots, _ = A.window_attend(
                 q, k_new, v_new, (jnp.asarray(k_pages), jnp.asarray(v_pages)),
                 0, jnp.asarray(table), jnp.asarray([start], jnp.int32),
                 window=window, scale=1.0)
@@ -336,7 +336,7 @@ def test_a_ring_never_shows_an_older_lap():
     for start, want_rows in ((5, [(0, o) for o in range(4)] + [(1, 0)]),
                              (14, [(1, 3)] + [(2, o) for o in range(4)]
                               + [(0, 0), (0, 1)])):
-        out, _ = A.window_attend(
+        out, _, _ = A.window_attend(
             q, zero, zero, (k_pages, jnp.asarray(v[..., :d])), 0, table,
             jnp.asarray([start], jnp.int32), window=window, scale=1.0)
         # all scores are 0: the softmax is uniform over the seen keys and

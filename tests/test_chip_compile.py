@@ -572,52 +572,37 @@ def test_block_programs_fit_the_chip_at_sdar_widths(topo, kind, size):
             == {f"bf16[{cfg.vocab_size},{cfg.d_model}]"}
 
 
-@pytest.mark.parametrize("kind, size", [("decode", 32), ("prefill", 1024),
-                                        ("chunk", 1024)])
-def test_window_and_full_programs_fit_the_chip_at_trinity_widths(topo, kind,
-                                                                 size):
-    """The engine's decode-32, prefill-1,024 and chunk-1,024 programs of the
-    Trinity cell (published widths, 9 layers, 8 of 256 experts, an eighth of
-    the vocabulary; the window kind's 8,224 pages of 7 layers and the full
-    kind's 16,384 of 2): both kinds' K/V arrays alias their outputs and no
-    operation copies an array of their shapes, and the program fits the
-    chip beside its 5.76 GB of weights."""
+def _compile_program_by_kinds(topo, mod, net, cfg, kind, size, block,
+                              num_pages, lanes_max=32):
+    """The engine's program of one kind and bucket for a family whose pages
+    are of a window kind and a full kind (`mod.page_kinds`): the full kind's
+    `num_pages`, the window kind's `lanes_max` rings, every array bfloat16
+    and donated, lowered as on the chip and compiled for the described one.
+    Returns (compiled, the arena's shapes)."""
     import types
 
-    from benchmark.trinity_cell import afmoe_engine
-    from ray_tpu.models import afmoe
     from ray_tpu.serve.llm.engine import LLMEngine
 
-    import json
-    with open(os.path.join(os.path.dirname(os.path.dirname(
-            os.path.abspath(__file__))), "benchmark", "configs",
-            "trinity-large-ep32-l9.json")) as f:
-        config = json.load(f)
     one_chip = SingleDeviceSharding(topo.devices[0])
-    cfg = afmoe_engine(config)["model_cfg"]
-    block, lanes_max = config["engine"]["block_size"], 32
 
     def on_chip(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
 
     params = jax.tree_util.tree_map(
         lambda a: on_chip(a.shape, a.dtype),
-        jax.eval_shape(afmoe.Afmoe(cfg).init, jax.random.PRNGKey(0),
+        jax.eval_shape(net(cfg).init, jax.random.PRNGKey(0),
                        jnp.ones((1, 16), jnp.int32)))
     ring = cfg.window // block + 1
-    pages = {"window": lanes_max * ring,
-             "full": config["engine"]["num_pages"]}
+    pages = {"window": lanes_max * ring, "full": num_pages}
     width = {"window": ring, "full": cfg.max_seq_len // block}
-    arena, pools, kinds = (), (), afmoe.page_kinds(cfg)
+    arena, pools, kinds = (), (), mod.page_kinds(cfg)
     for name, layers, rows, _ in kinds:
         pools += (types.SimpleNamespace(
             arrays=slice(len(arena), len(arena) + len(rows))),)
         arena += tuple(on_chip((pages[name], layers, block) + row,
                                jnp.bfloat16) for row in rows)
-    assert [a.shape for a in arena] == [(8224, 7, 16, 8, 128)] * 2 \
-        + [(16384, 2, 16, 8, 128)] * 2
     engine = types.SimpleNamespace(
-        _mod=afmoe, model_cfg=cfg, _step_counts=afmoe.STEP_COUNTS,
+        _mod=mod, model_cfg=cfg, _step_counts=mod.STEP_COUNTS,
         _state_in_place=False, kv=_fake_kv(arena, pools=pools))
     lanes = size if kind == "decode" else 1
     rows = (size,) if kind == "decode" else (1, size)
@@ -632,19 +617,55 @@ def test_window_and_full_programs_fit_the_chip_at_trinity_widths(topo, kind,
     args = (params, on_chip(rows if kind != "prefill" else (1, size)),
             on_chip((lanes,)), *arena, *coords)
     with _on_the_tpu():
-        compiled = jax.jit(fn, donate_argnums=(3, 4, 5, 6)).lower(
-            *args).compile()
+        compiled = jax.jit(fn, donate_argnums=tuple(
+            range(3, 3 + len(arena)))).lower(*args).compile()
+    return compiled, [a.shape for a in arena]
+
+
+def _assert_arena_in_place(compiled, shapes):
+    """Every array of the arena (bfloat16, `shapes`) aliases its output, no
+    operation copies one, and the program fits the chip. Returns the
+    program's text."""
     mem = compiled.memory_analysis()
-    held = sum(2 * math.prod(a.shape) for a in arena)
+    held = sum(2 * math.prod(shape) for shape in shapes)
     assert held <= mem.alias_size_in_bytes < held + 2**22
     assert mem.argument_size_in_bytes + mem.temp_size_in_bytes < HBM_BYTES
     text = compiled.as_text()
     assert "tpu_custom_call" in text    # the grouped products' kernel
-    for array in (arena[0], arena[2]):
-        shape = "bf16[" + ",".join(map(str, array.shape)) + "]"
+    for shape in set(shapes):
+        shape = "bf16[" + ",".join(map(str, shape)) + "]"
         moved = [line.strip()[:120] for line in text.splitlines()
                  if " copy(" in line and shape in line.split(" copy(")[0]]
         assert not moved, moved
+    return text
+
+
+@pytest.mark.parametrize("kind, size", [("decode", 32), ("prefill", 1024),
+                                        ("chunk", 1024)])
+def test_window_and_full_programs_fit_the_chip_at_trinity_widths(topo, kind,
+                                                                 size):
+    """The engine's decode-32, prefill-1,024 and chunk-1,024 programs of the
+    Trinity cell (published widths, 9 layers, 8 of 256 experts, an eighth of
+    the vocabulary; the window kind's 8,224 pages of 7 layers and the full
+    kind's 16,384 of 2): both kinds' K/V arrays alias their outputs and no
+    operation copies an array of their shapes, and the program fits the
+    chip beside its 5.76 GB of weights."""
+    import json
+
+    from benchmark.trinity_cell import afmoe_engine
+    from ray_tpu.models import afmoe
+
+    with open(os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "benchmark", "configs",
+            "trinity-large-ep32-l9.json")) as f:
+        config = json.load(f)
+    cfg = afmoe_engine(config)["model_cfg"]
+    compiled, shapes = _compile_program_by_kinds(
+        topo, afmoe, afmoe.Afmoe, cfg, kind, size,
+        config["engine"]["block_size"], config["engine"]["num_pages"])
+    assert shapes == [(8224, 7, 16, 8, 128)] * 2 \
+        + [(16384, 2, 16, 8, 128)] * 2
+    _assert_arena_in_place(compiled, shapes)
     if kind == "decode":
         _assert_returns_a_token_a_lane(compiled, size)
 
@@ -785,6 +806,89 @@ def test_retention_state_arena_is_updated_in_place_at_brumby_widths(topo,
     else:       # one row of logits a sequence, not a window's
         first = jax.tree_util.tree_leaves(compiled.out_info)[0]
         assert first.shape == (1, cfg.vocab_size)
+
+
+@pytest.mark.parametrize("kind, size", [("decode", 32), ("prefill", 1024),
+                                        ("chunk", 1024)])
+def test_two_kinds_of_rows_fit_the_chip_at_mimo_widths(topo, kind, size):
+    """The engine's decode-32, prefill-1,024 and chunk-1,024 programs of the
+    MiMo cell (published widths, 7 layers, 16 of 256 experts, an eighth of
+    the vocabulary; the window kind's 288 pages of 5 layers at rows of 1,536
+    and 1,024, the full kind's 32,768 of 2 at rows of 768 and 512): all four
+    arrays alias their outputs, no operation copies an array of their shapes
+    and NO TEMPORARY IS OF AN ARRAY'S SIZE (with rows of [4, 192], which the
+    chip's tiles of (8, 128) do not hold, every program re-laid the full
+    kind's K array out and back: 2.2 GB of temporaries a decode step, 4.3 GB
+    a chunk; `mimo_v2.cache_row`), and the program fits the chip beside its
+    6.86 GB of weights. The patterns of the cell's three device-share
+    metrics match every float operation under their scope, as the chip
+    names it, and no operation under another scope."""
+    import json
+
+    from benchmark import trace_reduce
+    from benchmark.mimo_cell import mimo_engine
+    from ray_tpu.models import mimo_v2
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "benchmark", "configs",
+                           "mimo-v2.5-ep16-l7.json")) as f:
+        config = json.load(f)
+    cfg = mimo_engine(config)["model_cfg"]
+    compiled, shapes = _compile_program_by_kinds(
+        topo, mimo_v2, mimo_v2.MimoV2, cfg, kind, size,
+        config["engine"]["block_size"], config["engine"]["num_pages"])
+    assert shapes == [(288, 5, 16, 1536), (288, 5, 16, 1024),
+                      (32768, 2, 16, 768), (32768, 2, 16, 512)]
+    text = _assert_arena_in_place(compiled, shapes)
+    # the smallest of the full kind's arrays is 1.07 GB
+    assert compiled.memory_analysis().temp_size_in_bytes < 0.5e9
+    if kind == "decode":
+        _assert_returns_a_token_a_lane(compiled, size)
+    # the trace readers' patterns against the operations as the chip names
+    # them, sorted by the named scope their metadata carries
+
+    def pattern_of(metric):
+        with open(os.path.join(root, "benchmark", "layer_metrics",
+                               metric + ".long-agent.json")) as f:
+            return re.compile(json.load(f)["args"]["pattern"])
+
+    patterns = {"attn_window": pattern_of("attn_window_device_pct"),
+                "attn_full": pattern_of("attn_full_device_pct"),
+                "moe_experts": pattern_of("moe_experts_device_pct")}
+    assert patterns["attn_full"].pattern \
+        == pattern_of("attn_full_hbm_roofline_pct").pattern
+    scopes = ("attn_window", "attn_full", "moe_experts", "moe_route",
+              "dense_mlp", "lm_head")
+    by_scope = {scope: set() for scope in scopes}
+    kernels = set()
+    for line in _scheduled(text):
+        if not re.search(r"[)}\]] (fusion|copy|custom-call|reshape|transpose)\(",
+                         line):
+            continue
+        name = trace_reduce.op_name(line)
+        if 'custom_call_target="tpu_custom_call"' in line:
+            kernels.add(name)
+        where = re.search(r'op_name="([^"]*)"', line)
+        for scope in scopes:
+            if where and f"/{scope}/" in where.group(1) + "/":
+                by_scope[scope].add(name)
+    assert kernels and all(patterns["moe_experts"].search(k)
+                           for k in kernels), kernels
+    for scope in ("attn_window", "attn_full"):
+        # what the walk computes: float arrays; a row of [lanes, 64] (the
+        # sinks' and the output's, a shape the projections share) is not
+        # told from theirs and is left to them, as is a fusion of several
+        # results (a tuple), which holds another scope's work too
+        floats = {k for k in by_scope[scope]
+                  if re.search(r"^\S+ (bf16|f32)\[", k)
+                  and not re.search(r"f32\[(\d+,)?64\]$", k)}
+        assert floats, scope
+        unseen = {k for k in floats if not patterns[scope].search(k)}
+        assert not unseen, (scope, unseen)
+    for scope, pattern in patterns.items():
+        for other in scopes:
+            stray = {k for k in by_scope[other] if pattern.search(k)}
+            assert other == scope or not stray, (scope, other, stray)
 
 
 def test_build_mesh_on_tpu_follows_the_topology(topo):

@@ -647,7 +647,8 @@ def test_link_counters_hold_ids_tables_and_logits_only(model):
 # return logits, and a prefill or a chunk its rows of them
 # ---------------------------------------------------------------------------
 
-TOKEN_FAMILIES = ("llama", "gpt", "kimi_k2", "ling_hybrid", "afmoe", "ouro")
+TOKEN_FAMILIES = ("llama", "gpt", "kimi_k2", "ling_hybrid", "afmoe", "ouro",
+                  "mimo_v2")
 _TOKEN_ENGINE = dict(batch_buckets=(1, 2, 4), prefill_buckets=(8,),
                      prefill_chunk=8, block_size=4, num_pages=64,
                      prefix_cache=0)
@@ -800,6 +801,12 @@ PARENT_TOKENS = {
              [173, 173, 173, 229, 173, 229, 173, 229, 173],
              [26, 357, 74, 432], [103, 510, 283, 283, 283, 283, 283],
              [246, 403, 283, 246, 403]],
+    # (PR 57 brought the family: recorded on that PR, from programs that
+    # always chose the tokens themselves)
+    "mimo_v2": [[211, 13, 102, 211, 13, 278],
+                [423, 58, 456, 272, 497, 322, 278, 295, 108],
+                [337, 232, 454, 410], [4, 320, 4, 320, 366, 366, 366],
+                [177, 49, 489, 269, 292]],
 }
 
 
@@ -2031,7 +2038,12 @@ def test_afmoe_programs_lower_to_the_text_they_had(program):
 # `kda_step` reading the old state once), a chunk and a prefill compute one
 # row of logits, and the steps count `kda_slot_rows`; `brumby`, whose two
 # slot helpers moved into `models/layers.py` in that PR, `ouro` and
-# `sdar_moe` held to the digit.)
+# `sdar_moe` held to the digit. `mimo_v2`, PR 57: two kinds of page whose
+# arrays differ in row shape, so four arena arrays of four shapes, and a count
+# of the sink's mass in the steps' vector; its hashes were taken on the PR that
+# brought it, which left every other line here, `NEIGHBOUR_PROGRAMS` and
+# `AFMOE_PROGRAMS` to the digit: `afmoe.window_attend` serves both families,
+# and an argument that is None adds no operation.)
 STATEFUL_AND_LOOP_PROGRAMS = {
     "ling_hybrid": {"prefill16": "5552f28192e60bc7", "decode1": "1e26b795095a4534",
                     "decode4": "5ab7be559d5b6b07", "chunk16": "6ba55ad58f9eea4d"},
@@ -2041,6 +2053,8 @@ STATEFUL_AND_LOOP_PROGRAMS = {
              "decode4": "2e2e7b24963ce4c9", "chunk16": "948472f3189d1356"},
     "brumby": {"prefill16": "ac59851bf3eb37c4", "decode1": "721ba2cd9f168cb3",
                "decode4": "0abbdbb37c879b25", "chunk16": "f782efea551a9e1d"},
+    "mimo_v2": {"prefill16": "92c28963b0794606", "decode1": "674b8cfe5f4bbdb1",
+                "decode4": "2bc4de8300632f41", "chunk16": "06cba1096c035204"},
 }
 
 
@@ -2105,7 +2119,7 @@ SEEDED_WEIGHTS = {
     "llama": "b0cdb511189bdd12", "gpt": "73bcb0e11354d63e", "kimi_k2": "4d03b6f9054bc62b",
     "ling_hybrid": "ef2c74e1429d131d", "sdar_moe": "59ef927d1cc8ae71",
     "afmoe": "336dd938d5385478", "ouro": "8c778830b7b0fe3d",
-    "brumby": "68293459d8ec1493",
+    "brumby": "68293459d8ec1493", "mimo_v2": "49e3b6f1352886f2",
 }
 
 
