@@ -25,8 +25,10 @@ What it asks of the system that `llama.py` does not:
 
 From `layers.py`: `rms`, the rotation, the weights' declaration. Shared with
 `llama.py`: `rope_tables` and the walk over cached key blocks
-(`key_block_trips`: blocks of `KEY_BLOCK` slots as far as the batch's
-longest lane). Shared with `kimi_k2.py` / `ling_hybrid.py`:
+(`key_walk` below: one lane walks `key_block_trips` blocks of `KEY_BLOCK`
+slots in a loop, two lanes and more `key_block_pairs`' work list of their
+live (lane, block) pairs, each lane as far as its own last block, folded by
+`fold_pairs`). Shared with `kimi_k2.py` / `ling_hybrid.py`:
 `parallel.moe.expert_shard_layer` (here told to route by
 `softmax_topk_route`) and `MOE_COUNTS`.
 
@@ -144,6 +146,54 @@ class SdarMoe(nn.Module):
         return head_logits(p, cfg, x)
 
 
+# -- the walk over the cached keys --------------------------------------------
+
+# cached keys a (lane, block) pair of `block_attend`'s work list, one pair a
+# lane of the bucket a trip. A pair here brings its lane's C = 4 query
+# positions: four times `llama.paged_attend`'s gathered queries and float32
+# partial sums beside the same K and V, so a pair's own cost asks for a wider
+# block than a token step's 64 and for fewer pairs a trip than its 4 a lane.
+# Settled on the chip with `block_attend` alone at the cell's shapes (64
+# lanes, 7 layers, the deck's lengths; PERF.md §6, PR 59)
+PAIR_BLOCK = 192
+
+
+def key_walk(start, n_pages: int, page: int, xp=jnp, listed: bool = True):
+    """What `block_attend` walks of the cached keys, for `start` [B]: (trips,
+    blocks a trip, keys a block, the work list or None), as
+    `llama.key_block_walk` lays a token step's out. One lane walks
+    `llama.key_block_trips` blocks of `KEY_BLOCK`, one a trip, and has no
+    list. Two lanes and more walk `llama.key_block_pairs`' list of their live
+    (lane, block) pairs at `PAIR_BLOCK` keys a block (lane, block, live, each
+    B x the table's blocks long, so whole trips), B of them a trip whatever
+    their lanes, `ceil(pairs / B)` trips: the last trip's pairs past the
+    list's end are dead and are scored all the same. Not `listed`: the same
+    trips, width and keys from the lanes' blocks alone (`key_block_pairs`'
+    first lines) and None for the list, for the host, which counts a pass of
+    64 lanes and reads no pair (`tests/test_sdar_moe.py` holds the two to
+    each other)."""
+    lanes = start.shape[0]
+    if lanes == 1:
+        trips, keys = _llama.key_block_trips(start, n_pages, page, xp)
+        return trips, 1, keys, None
+    if listed:
+        blocks, *pairs, keys = _llama.key_block_pairs(start, n_pages, page,
+                                                      xp, PAIR_BLOCK)
+        return -(-xp.sum(blocks) // lanes), lanes, keys, tuple(pairs)
+    per_block = max(1, min(PAIR_BLOCK // page, n_pages))
+    keys = per_block * page
+    blocks = xp.minimum(-(-start // keys), -(-n_pages // per_block))
+    return -(-xp.sum(blocks) // lanes), lanes, keys, None
+
+
+def decode_key_walk(cfg: SdarMoeConfig, positions, n_pages: int, page: int,
+                    xp=jnp):
+    """`key_walk` of a decode pass, by the name under which the engine asks
+    a family's module for it: the host (`xp=np`) counts what the program
+    scored (`decode_attn_key_slots`) and makes no list."""
+    return key_walk(positions, n_pages, page, xp, listed=xp is jnp)
+
+
 # -- the layer's parts --------------------------------------------------------
 
 @partial(jax.jit, static_argnames=("block_length", "scale"))
@@ -159,11 +209,18 @@ def block_attend(q, k_new, v_new, pages, layer, page_table, start,
     heads are KVH groups of H // KVH scored against K and V as they lie (no
     repeat). One running softmax (maximum, sum, accumulator in float32): the
     window first, where a row's own key gives it a real maximum, so a masked
-    key weighs exp(NEG_INF - m) = 0 exactly; then `llama.key_block_trips`
-    blocks of cached slots, each gathered from the arena by (page, layer)
-    and masked by each row's own `start`. A decode pass is the window of
-    one block (every key of it visible); `llama.paged_attend` is this at
-    one query a lane. Returns [B, C, H * D] in q's dtype.
+    key weighs exp(NEG_INF - m) = 0 exactly; then the trips of `key_walk`,
+    each block gathered from the arena by (page, layer) and masked by its
+    own lane's `start`. One lane (a chunk, the bucket of one) walks its
+    blocks of `KEY_BLOCK` in a loop. B lanes walk the work list of their
+    live (lane, block) pairs, a trip's pairs in the place of the lanes,
+    each pair with its lane's C query rows, so that a short lane beside a
+    long one is gathered and scored as far as its own last block and a lane
+    that holds nothing not at all; `llama.fold_pairs` adds the pairs of one
+    lane together before they meet the lane's state: the same sums in
+    another order, no key left out. A decode pass is the window of one block
+    (every key of it visible); `llama.paged_attend` is this at one query a
+    lane. Returns [B, C, H * D] in q's dtype.
 
     Jitted on its own with the layer as an operand, so that a step traces
     it once for all its layers, as `llama.paged_attend` is."""
@@ -184,7 +241,7 @@ def block_attend(q, k_new, v_new, pages, layer, page_table, start,
         if pages is not None:
             k_pages, v_pages = pages
             n_pages, page = page_table.shape[1], k_pages.shape[2]
-            trips, keys = _llama.key_block_trips(start, n_pages, page)
+            trips, width, keys, pairs = key_walk(start, n_pages, page)
             per_block = keys // page
             table = jnp.pad(page_table, ((0, 0), (0, -n_pages % per_block)))
 
@@ -208,7 +265,42 @@ def block_attend(q, k_new, v_new, pages, layer, page_table, start,
                     v.astype(q.dtype), preferred_element_type=f32)
                 return m_new, l, acc
 
-            state = jax.lax.fori_loop(0, trips, cached, state)
+            if pairs is not None:
+                # a row a pair, as `llama.paged_attend` lays it out (made
+                # here and not a trip: it is the same in every layer): its
+                # lane, the keys its lane holds from the block's first slot
+                # on (0 for a dead pair), its pages' first rows in the
+                # arena seen as below
+                lane, at, live = pairs
+                layers = k_pages.shape[1]
+                listed = jnp.concatenate([
+                    lane[:, None],
+                    jnp.where(live, start[lane] - at * keys, 0)[:, None],
+                    layers * table[lane[:, None], at[:, None] * per_block
+                                   + jnp.arange(per_block)[None, :]]], axis=1)
+                # the arena as [pages x layers, block x KVH, D]: the same
+                # bytes, a (page, layer) one row of whole (8, 128) tiles,
+                # which the chip gathers quicker than a [block, KVH, D] of
+                # (4, 128) tiles (PERF.md §6, PR 59)
+                k_rows = k_pages.reshape(-1, page * kvh, d)
+                v_rows = v_pages.reshape(-1, page * kvh, d)
+
+            def paired(j, state):
+                # `cached` with pair j * width + t in the place of lane t's
+                # block j: the pair's lane's C queries, pages and mask
+                rows = jax.lax.dynamic_slice_in_dim(listed, j * width, width)
+                lane, held, ids = rows[:, 0], rows[:, 1], rows[:, 2:] + layer
+                k = k_rows[ids].reshape(width, keys, kvh, d)
+                v = v_rows[ids].reshape(width, keys, kvh, d)
+                s = jnp.einsum("tcgrd,tkgd->tgrck", qg[lane],
+                               k.astype(q.dtype),
+                               preferred_element_type=f32) * scale
+                seen = jnp.arange(keys)[None, :] < held[:, None]
+                s = jnp.where(seen[:, None, None, None, :], s, NEG_INF)
+                return _llama.fold_pairs(state, s, v, lane, held > 0, q.dtype)
+
+            state = jax.lax.fori_loop(
+                0, trips, cached if pairs is None else paired, state)
         _, l, acc = state
         out = acc / jnp.maximum(l, 1e-20)[..., None]   # [B, KVH, R, C, D]
         return out.transpose(0, 3, 1, 2, 4).reshape(b, c, h * d).astype(

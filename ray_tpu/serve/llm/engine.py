@@ -255,9 +255,9 @@ def _family_cache(mod, cfg):
       the (token, probability) it chose at every position, then the block's
       cache rows (`_decode_blocks`).
     - `decode_key_walk(cfg, positions, n_pages, page, xp)` -> (trips, blocks
-      a trip, keys a block, the work list) of a decode step that walks
-      `llama.paged_attend`: the engine calls it on the host to count what
-      the program scored (`decode_attn_key_slots`)."""
+      a trip, keys a block, the work list) of a decode step's or a block
+      pass's walk over the cached keys: the engine calls it on the host to
+      count what the program scored (`decode_attn_key_slots`)."""
     def declared(name, default=None):
         fn = getattr(mod, name, None)
         return default if fn is None else fn(cfg)
@@ -1455,9 +1455,21 @@ class LLMEngine:
                             finished.append(seq)
                             break
                 n_commits = int(commits.sum())
+                key_slots = 0
+                if self._key_walk is not None:
+                    # as `_decode_once` counts a token step's (its lines
+                    # stay where they are: the compile cache's keys), a
+                    # lane's own keys here the block's
+                    trips, width, keys, _ = self._key_walk(
+                        self.model_cfg, positions, self.max_pages_per_seq,
+                        self.kv.block_size, np)
+                    key_slots = self.kv.n_layer * (
+                        len(runs) * length + int(trips) * width * keys)
                 with self._lock:
                     self.counters["decode_steps"] += 1
                     self.counters["decode_context_tokens"] += context
+                    if key_slots:
+                        self.counters["decode_attn_key_slots"] += key_slots
                     self.counters["decode_lane_passes"] += len(runs)
                     self.counters["decode_lane_commits"] += n_commits
                     self.counters["decode_blocks_committed"] += n_commits

@@ -508,9 +508,10 @@ def test_block_programs_fit_the_chip_at_sdar_widths(topo, kind, size):
     all 128 experts, the whole vocabulary, 8,256 pages of 16 tokens): the
     K/V arena aliases its outputs and no operation copies an array of its
     shape, the program fits the chip beside its 9.97 GB of weights, the
-    block pass's logits are float32 [lanes, 4, vocabulary] inside the
-    program (it returns two [lanes, 4] arrays), and a prefill computes no
-    head at all."""
+    block pass gathers a trip of its work list at a time and holds no
+    temporary of an arena array's size, its logits are float32 [lanes, 4,
+    vocabulary] inside the program (it returns two [lanes, 4] arrays), and a
+    prefill computes no head at all."""
     import types
 
     from ray_tpu.models import sdar_moe
@@ -562,12 +563,24 @@ def test_block_programs_fit_the_chip_at_sdar_widths(topo, kind, size):
     moved = [line.strip()[:120] for line in text.splitlines()
              if " copy(" in line and shape in line.split(" copy(")[0]]
     assert not moved, moved
+    import re
     if kind == "decode":
         logits = f"[{size},{cfg.block_length},{cfg.vocab_size}]"
         assert "f32" + logits in text and "bf16" + logits not in text
+        # the walk over the cached keys (`block_attend`'s work list, PR 59):
+        # the arena is read as rows of [block x K/V heads, head] (a bitcast,
+        # or `moved` above would show, and no temporary of an arena array's
+        # size is held), and a trip gathers its 64 pairs' 12 pages each,
+        # never a lane's whole table of 256
+        assert mem.temp_size_in_bytes < held // 2
+        row = f"{block * cfg.n_kv_head},{cfg.head_dim}"
+        assert f"bf16[{num_pages * cfg.n_layer},{row}]" in text
+        gathered = {int(n) for n in re.findall(
+            rf"bf16\[(\d+),(?:{row}|{block},{cfg.n_kv_head},"
+            rf"{cfg.head_dim})\]", text)} - {num_pages * cfg.n_layer}
+        assert gathered and max(gathered) == 64 * 12, gathered
     else:
         # no array as wide as the vocabulary but the embedding
-        import re
         assert set(re.findall(rf"\w+\[[\d,]*{cfg.vocab_size}[\d,]*\]", text)) \
             == {f"bf16[{cfg.vocab_size},{cfg.d_model}]"}
 

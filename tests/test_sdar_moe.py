@@ -292,6 +292,165 @@ def test_lanes_at_different_passes_share_a_program_call(monkeypatch, lanes):
         assert eng.shutdown() == 0
 
 
+# -- the walk over cached keys: each lane as far as its own last block --------
+
+def _lanes_of(kind, n_pages, page):
+    """`start` a lane, whole blocks of 4: a lane that holds nothing, a
+    single page, the table's end, a pad lane of the bucket (nothing, and a
+    page table of zeros), then lengths in between."""
+    end = n_pages * page
+    return {2: [page, end], 4: [0, page, end, 0],
+            6: [end, end - 3 * page, 0, 320, page, 388]}[kind]
+
+
+def _attend_plainly(q, k_new, v_new, k_pages, v_pages, layer, table, start,
+                    scale):
+    """One lane's block in float64: one softmax over its `start` cached
+    keys and the block's own, every key of the block visible."""
+    kvh, d = k_new.shape[1:]
+    k = np.concatenate([k_pages[table, layer].reshape(-1, kvh, d)[:start],
+                        k_new]).astype(np.float64)
+    v = np.concatenate([v_pages[table, layer].reshape(-1, kvh, d)[:start],
+                        v_new]).astype(np.float64)
+    c, h, _ = q.shape
+    qg = q.astype(np.float64).reshape(c, kvh, h // kvh, d)
+    s = np.einsum("cgrd,kgd->cgrk", qg, k) * scale
+    p = np.exp(s - s.max(-1, keepdims=True))
+    p /= p.sum(-1, keepdims=True)
+    return np.einsum("cgrk,kgd->cgrd", p, v).reshape(c, h * d)
+
+
+@pytest.mark.parametrize("dtype, tolerance", [
+    (jnp.float32, 2e-5), (jnp.bfloat16, 2e-2)])
+@pytest.mark.parametrize("lanes", [2, 4, 6])
+def test_unequal_lanes_walk_their_own_blocks_and_lose_no_key(lanes, dtype,
+                                                             tolerance):
+    """`block_attend` over lanes of unequal `start` (blocks of 192 keys, a
+    pair a lane a trip. 2 lanes: 1 + 3 pairs, two trips; 4: 0 + 1 + 3 + 0,
+    one trip; 6: 3 + 3 + 0 + 2 + 1 + 3 = 12 pairs, two full trips) is each
+    lane through the bucket of one's loop and the plain softmax."""
+    page, n_pages, layers, kvh, group, d, c = 4, 128, 2, 2, 4, 16, 4
+    start = np.asarray(_lanes_of(lanes, n_pages, page), np.int32)
+    walk = sdar.key_walk(start, n_pages, page, np)
+    assert walk[1:3] == (lanes, 192)
+    assert int(walk[0]) == {2: 2, 4: 1, 6: 2}[lanes]
+    rng = np.random.default_rng(lanes)
+    n_arena = lanes * n_pages + 1
+    k_pages, v_pages = (
+        jnp.asarray(rng.normal(size=(n_arena, layers, page, kvh, d)), dtype)
+        for _ in range(2))
+    q = jnp.asarray(rng.normal(size=(lanes, c, kvh * group, d)), dtype)
+    k_new, v_new = (jnp.asarray(rng.normal(size=(lanes, c, kvh, d)), dtype)
+                    for _ in range(2))
+    table = rng.permutation(n_arena - 1)[:lanes * n_pages].reshape(
+        lanes, n_pages).astype(np.int32) + 1
+    table[start == 0] = 0           # a lane without a sequence
+    layer, scale = 1, d ** -0.5
+    got = np.asarray(sdar.block_attend(
+        q, k_new, v_new, (k_pages, v_pages), layer, jnp.asarray(table),
+        jnp.asarray(start), block_length=4, scale=scale), np.float32)
+    assert got.shape == (lanes, c, kvh * group * d)
+    for i in range(lanes):
+        alone = sdar.block_attend(
+            q[i:i + 1], k_new[i:i + 1], v_new[i:i + 1], (k_pages, v_pages),
+            layer, jnp.asarray(table[i:i + 1]), jnp.asarray(start[i:i + 1]),
+            block_length=4, scale=scale)
+        np.testing.assert_allclose(got[i], np.asarray(alone[0], np.float32),
+                                   atol=tolerance, rtol=tolerance)
+        want = _attend_plainly(
+            *(np.asarray(a[i], np.float32) for a in (q, k_new, v_new)),
+            np.asarray(k_pages, np.float32), np.asarray(v_pages, np.float32),
+            layer, table[i], int(start[i]), scale)
+        np.testing.assert_allclose(got[i], want, atol=tolerance,
+                                   rtol=tolerance)
+
+
+@pytest.mark.parametrize("lanes", [1, 2, 5, 8])
+def test_the_host_and_the_program_count_one_walk(lanes):
+    """`decode_key_walk` with `xp=np` (the engine's count) and with `xp=jnp`
+    (what `block_attend` runs): the same trips, width, block and list."""
+    cfg = sdar.SdarMoeConfig.tiny()
+    rng = np.random.default_rng(lanes)
+    positions = (rng.integers(0, 33, lanes) * 4).astype(np.int32)
+    positions[-1] = 0 if lanes > 1 else 100
+    host = sdar.decode_key_walk(cfg, positions, 16, 8, np)
+    program = sdar.decode_key_walk(cfg, jnp.asarray(positions), 16, 8, jnp)
+    assert (int(host[0]), host[1], host[2]) \
+        == (int(program[0]), program[1], program[2])
+    assert host[3] is None      # the host reads no pair
+    if lanes == 1:      # the loop: one block a trip, the whole table's 128
+        assert host[1:] == program[1:] == (1, 128, None)
+        assert int(host[0]) == 1
+    else:               # blocks of 128 too: the table is shorter than 192
+        assert host[1:3] == (lanes, 128)
+        listed = sdar.key_walk(positions, 16, 8, np)
+        assert int(listed[0]) == int(host[0]) == -(-int(np.sum(
+            -(-positions // 128))) // lanes) == -(-listed[3][2].sum()
+                                                  // lanes)
+        for mine, theirs in zip(listed[3], program[3]):
+            assert len(mine) % lanes == 0
+            assert (np.asarray(mine) == np.asarray(theirs)).all()
+
+
+def test_lanes_of_unequal_length_replay_the_reference(monkeypatch):
+    """Five requests of 3 to 391 prompt tokens (one to three key blocks of
+    192), the short ones first, so that they decode while the long ones are
+    chunked in: the buckets of one, two and eight (three pad lanes). Every
+    request's rows, reveals and tokens are the reference's replay, and after
+    every pass `decode_attn_key_slots` has grown by the host's count of the
+    walk the program ran."""
+    spy = _Spy(monkeypatch)
+    eng = LLMEngine(
+        model="sdar_moe", seed=0, model_cfg=sdar.SdarMoeConfig.tiny(
+            dtype=jnp.float32, max_seq_len=512),
+        engine_config=EngineConfig(**{
+            **ENGINE, "prefill_buckets": (8, 64), "prefill_chunk": 64,
+            "num_pages": 200}))
+    try:
+        spy.attach(eng)
+        forward, passes = eng._decode_forward, []
+
+        def counted(fn, args):
+            before = eng.metrics()["decode_attn_key_slots"]
+            out = forward(fn, args)
+            passes.append((len(eng._running), np.array(args[2]), before))
+            return out
+
+        eng._decode_forward = counted
+        sizes = [(3, 60), (100, 48), (203, 40), (250, 36), (391, 28)]
+        prompts = [_prompt(n, 31 + n) for n, _ in sizes]
+        reqs = [eng.submit(p, new) for p, (_, new) in zip(prompts, sizes)]
+        logs = spy.drive(eng, reqs)
+        cfg = eng.model_cfg
+        for req, log, prompt, (n, new) in zip(reqs, logs, prompts, sizes):
+            tokens = req.result(timeout=5)
+            want = ref.replay(_ref_params(eng), _ref_config(cfg), prompt,
+                              tokens, new)
+            worst, same = _distance(log, want, n, n + new - 1)
+            assert same and worst < ROW_TOLERANCE
+            assert tokens == want["tokens"]
+        after = [before for _, _, before in passes[1:]] \
+            + [eng.metrics()["decode_attn_key_slots"]]
+        most = 0
+        for (running, positions, before), now in zip(passes, after):
+            trips, width, keys, _ = sdar.decode_key_walk(
+                cfg, positions, eng.max_pages_per_seq, 8, np)
+            # the bucket of one loops in blocks of 256; eight lanes walk
+            # whole trips of 8 pairs of 192, dead pairs included
+            assert (width, keys) == (len(positions),
+                                     256 if len(positions) == 1 else 192)
+            most = max(most, int(trips))
+            assert now - before == cfg.n_layer * (
+                running * 4 + int(trips) * width * keys) > 0
+        assert {len(p) for _, p, _ in passes} == {1, 2, 8} and most > 1
+        m = eng.metrics()
+        assert m["decode_attn_key_slots"] > cfg.n_layer * \
+            m["decode_context_tokens"]
+        eng.quiesce()
+    finally:
+        assert eng.shutdown() == 0
+
+
 # -- three wrong programs, each of which the comparison has to refuse ---------
 
 def _causal_in_block(monkeypatch):
