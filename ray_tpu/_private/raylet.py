@@ -774,6 +774,9 @@ class Raylet:
         )
         logfile = await asyncio.get_running_loop().run_in_executor(
             None, lambda: open(log_path, "ab"))
+        # where the worker's `worker_spawn` row begins (start-up ledger:
+        # one monotonic clock for the processes of a host)
+        env["RAY_TPU_SPAWN_NS"] = str(time.perf_counter_ns())
         proc = await asyncio.create_subprocess_exec(
             python_exe, "-m", "ray_tpu._private.worker_main",
             "--raylet-addr", self.server.address,

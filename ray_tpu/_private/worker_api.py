@@ -88,6 +88,7 @@ def init(
     concurrently held CPU/memory and shm-store bytes (see README
     "Multi-tenancy")."""
     global _global_state
+    called_ns = time.perf_counter_ns()
     with _global_lock:
         if _global_state is not None:
             if ignore_reinit_error:
@@ -162,6 +163,8 @@ def init(
                 object_store_memory=object_store_memory,
             )
             owns = True
+            # the start-up ledger's shards lie with the session's logs
+            tracing.set_startup_dir(cluster.session_dir)
             node_tpus = node_resources["TPU"]
             gcs_addr = cluster.gcs_addr
             head = cluster.head_node
@@ -208,6 +211,8 @@ def init(
             cw.job_runtime_env = renv_mod.prepare(cw, runtime_env)
         _global_state = GlobalState(cluster, cw, owns, node_tpus=node_tpus)
         atexit.register(shutdown)
+        tracing.startup_row("cluster_up", called_ns,
+                            attrs={"owns_cluster": owns}, flush=True)
         return _global_state
 
 
@@ -267,6 +272,9 @@ def shutdown():
         state.core_worker.shutdown()
         if state.owns_cluster and state.cluster is not None:
             state.cluster.shutdown()
+            # the session is over: later rows wait for the next one
+            from ray_tpu.util import tracing
+            tracing.set_startup_dir(None)
         global _exported_config_env
         for key, prior in _exported_config_env:
             if prior is None:

@@ -12,9 +12,11 @@ import asyncio
 import logging
 import os
 import sys
+import time
 
 
 def main():
+    entered_ns = time.perf_counter_ns()
     parser = argparse.ArgumentParser()
     parser.add_argument("--raylet-addr", required=True)
     parser.add_argument("--gcs-addr", required=True)
@@ -37,13 +39,24 @@ def main():
 
     configure_compile_cache()
 
+    # the start-up ledger: the raylet's spawn to this function's first
+    # line (the interpreter's start and the package's imports), and from
+    # there to a worker the raylet can lease
+    from ray_tpu.util import tracing
+
+    chips = tuple(int(c) for c in args.tpu_chips.split(",") if c != "")
+    tracing.set_startup_dir(args.session_dir)
+    spawned_ns = os.environ.pop("RAY_TPU_SPAWN_NS", "")
+    if spawned_ns.isdigit():
+        tracing.startup_row("worker_spawn", int(spawned_ns), entered_ns,
+                            attrs={"tpu_chips": list(chips)})
+
     from ray_tpu._private import fault_injection as _fi
     from ray_tpu._private.core_worker import CoreWorker
     from ray_tpu._private.ids import JobID
     from ray_tpu._private.object_store import ObjectStore
 
     _fi.set_role("worker")  # arm worker-scoped timed faults
-    chips = tuple(int(c) for c in args.tpu_chips.split(",") if c != "")
     store = ObjectStore.attach(args.store_name)
     cw = CoreWorker(
         mode="worker",
@@ -93,6 +106,8 @@ def main():
         })
 
     cw._run_sync(register())
+    tracing.startup_row("worker_boot", entered_ns,
+                        attrs={"tpu_chips": list(chips)}, flush=True)
 
     async def raylet_watchdog():
         # Exit if the raylet disappears (reference: workers die with their

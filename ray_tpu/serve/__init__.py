@@ -30,6 +30,7 @@ from ray_tpu.serve.multiplex import (
     get_multiplexed_model_id,
     multiplexed,
 )
+from ray_tpu.util import tracing as _tracing
 
 
 def __getattr__(name: str):
@@ -63,6 +64,7 @@ def run(app: Application, *, name: str = "default",
         http_port: int = 0, grpc_port: int = 0) -> DeploymentHandle:
     """Deploy an application graph; returns the ingress handle
     (reference `python/ray/serve/api.py:545`)."""
+    _tracing.startup_mark("deploy_call", {"entry": "serve.run"})
     ctrl = _get_or_start_controller()
     nodes = app._flatten()
     handles: Dict[int, DeploymentHandle] = {}

@@ -139,6 +139,7 @@ class LLMDeployment:
         import jax
 
         from ray_tpu import parallel
+        from ray_tpu.util import tracing
 
         dev = jax.devices()[0]
         m = self.engine.metrics()
@@ -153,6 +154,9 @@ class LLMDeployment:
             "kv_pages_live": m["kv_pages_live"],
             "requests_completed": m["requests_completed"],
             "cache_stats": parallel.cache_stats(),
+            # what this process did before it served: the start-up
+            # ledger's rows (`tracing.collect_startup` has every process's)
+            "startup": tracing.startup_rows(),
         }
 
     def device_trace(self, seconds: float,

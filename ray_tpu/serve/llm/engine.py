@@ -552,21 +552,21 @@ class LLMEngine:
         arena_args = tuple(range(
             3, 3 + len(self.kv.arena) + len(self.kv.state)))
 
-        def program(fn):
+        def program(kind, bucket, fn):      # named as `compiled_step_calls` is
             return compiled_step(fn, donate_argnums=arena_args,
-                                 on_retrace="error")
+                                 on_retrace="error", name=f"{kind}:{bucket}")
 
-        self._prefill_fns = {s: program(self._make_prefill_fn(s))
+        self._prefill_fns = {s: program("prefill", s, self._make_prefill_fn(s))
                              for s in cfg.prefill_buckets}
         make_decode = self._make_decode_fn if self._block is None \
             else self._make_block_decode_fn
-        self._decode_fns = {b: program(make_decode(b))
+        self._decode_fns = {b: program("decode", b, make_decode(b))
                             for b in cfg.batch_buckets}
         # one chunk executable (B=1, C=_chunk_size) covers both chunked
         # prefill windows and prefix-cache-hit suffixes: every window
         # pads to the same width, so a chunk is a bucket by construction
-        self._chunk_size = cfg.prefill_chunk or max(cfg.prefill_buckets)
-        self._chunk_fn = program(self._make_chunk_fn(self._chunk_size))
+        self._chunk_size = size = cfg.prefill_chunk or max(cfg.prefill_buckets)
+        self._chunk_fn = program("chunk", size, self._make_chunk_fn(size))
 
         self._waiting: List[Request] = []
         self._prefilling: List[_Sequence] = []
@@ -1891,3 +1891,25 @@ class LLMEngine:
                     f'serve_llm_{key}_total{{job="{tenant}"}} '
                     f"{int(row[key])}")
         return "\n".join(lines) + "\n"
+
+
+def _as_engine_build_stage(init):
+    """`LLMEngine.__init__` as the `engine_build` row of the start-up
+    ledger: the family, the arena and the sequence states allocated, the
+    programs' makers (none compiled). Applied here, at the file's end, and
+    not as a decorator's line above: the lines of the methods that call the
+    compiled steps are frames of every program's trace, and their positions
+    are part of a kernel-bearing program's cache key (`PERF.md` §7)."""
+    import functools
+
+    @functools.wraps(init)
+    def __init__(self, model: str = "llama", *args, **kwargs):
+        with _tracing.startup_stage("engine_build", {"model": model},
+                                    flush=True) as built:
+            init(self, model, *args, **kwargs)
+            built["kv_arena_bytes"] = self.kv.arena_nbytes
+
+    return __init__
+
+
+LLMEngine.__init__ = _as_engine_build_stage(LLMEngine.__init__)

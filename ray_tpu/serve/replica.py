@@ -35,6 +35,11 @@ class Replica:
         if self._is_function:
             self._callable = func_or_class
         else:
+            # start-up ledger: up to here a start is the runtime's, from
+            # here the deployment's own
+            _tracing.startup_mark("user_entered", {
+                "deployment": getattr(func_or_class, "__name__", "?")},
+                flush=True)
             self._callable = func_or_class(*init_args, **init_kwargs)
             if user_config is not None and \
                     hasattr(self._callable, "reconfigure"):

@@ -28,6 +28,7 @@ from typing import Any, Dict, Optional
 
 from ray_tpu._private import fault_injection as _fi
 from ray_tpu.air.checkpoint import Checkpoint
+from ray_tpu.util import tracing
 
 
 @dataclasses.dataclass
@@ -123,6 +124,9 @@ class _TrainSession:
         }
         if needs_commit:
             item["gang_commit"] = True
+        if index == 0:
+            # what this process did before it trained, once
+            item["startup"] = tracing.startup_rows()
         self._report_index += 1
         # Blocks until the controller drained the previous report — keeps
         # workers in lockstep the way the reference's session does.

@@ -76,6 +76,13 @@ class TrainWorker:
             finally:
                 sess.finished.set()
 
+        # start-up ledger: up to here a start is the runtime's, from here the
+        # training loop's own (stamped here and not in `run`, whose lines are
+        # frames of the step's trace and so part of its cache key)
+        from ray_tpu.util import tracing
+
+        tracing.startup_mark("user_entered", {
+            "world_rank": sess.config.world_rank}, flush=True)
         self._thread = threading.Thread(target=run, daemon=True,
                                         name="train_fn")
         self._thread.start()

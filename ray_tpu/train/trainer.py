@@ -194,6 +194,9 @@ class BaseTrainer:
         (reference `BaseTrainer.fit` raises TrainingFailedError,
         `base_trainer.py:567`).
         """
+        from ray_tpu.util import tracing
+
+        tracing.startup_mark("deploy_call", {"entry": "JaxTrainer.fit"})
         try:
             from ray_tpu.tune.tuner import Tuner
         except ImportError:

@@ -25,10 +25,20 @@ spans in the `.xplane.pb`, on the clock of the device events. With
 `RAY_TPU_TRACE=1` it is written to the JSONL shard like any `span`. This
 module never imports jax itself while it is imported: daemons and drivers
 that must not touch the chip import it.
+
+Start-up rows (`startup_stage`, `startup_mark`, `startup_row`) are the
+ledger of what a process did before it served or trained: a span-shaped
+dict that also carries `begin_ns` / `end_ns` of `time.perf_counter_ns()`,
+the phases' clock and one clock for every process of a host. They are
+recorded whether `RAY_TPU_TRACE` is set or not, since a process writes
+some dozens of them and none inside a loop that runs a step: kept in a
+bounded list (`startup_rows()`) and appended to
+`<session_dir>/logs/startup-<pid>.jsonl`, which `collect_startup` merges.
 """
 
 from __future__ import annotations
 
+import atexit
 import contextlib
 import contextvars
 import json
@@ -43,24 +53,117 @@ from typing import Any, Dict, Iterable, Iterator, List, Optional
 _current: contextvars.ContextVar[Optional[dict]] = contextvars.ContextVar(
     "ray_tpu_trace_span", default=None)
 
-_lock = threading.Lock()
-_file = None
+_FLUSH_ROWS = 512
+_FLUSH_SECONDS = 1.0
+
+
+class _Shard:
+    """One JSONL file of this process, written in blocks: a row joins a
+    buffer that is serialized and goes to the file when it holds
+    `_FLUSH_ROWS`, a second after its first row (on a timer's thread),
+    when a caller asks (`flush=True`: the rows a reader waits for) and at
+    exit, so a crash loses a second at most and a hot loop's span costs
+    an append. `path()` names the file when the first row comes; while it
+    returns None the rows wait."""
+
+    def __init__(self, path):
+        self._path = path
+        self._lock = threading.Lock()
+        self._rows: List[dict] = []
+        self._file = None
+        self._named: Optional[str] = None
+        self._timer: Optional[threading.Timer] = None
+
+    def write(self, row: dict, flush: bool = False) -> None:
+        with self._lock:
+            if self._named is None:
+                self._named = self._path()
+            self._rows.append(row)
+            if flush or len(self._rows) >= _FLUSH_ROWS:
+                self._flush_locked()
+            elif self._timer is None:
+                self._timer = threading.Timer(_FLUSH_SECONDS, self.flush)
+                self._timer.daemon = True
+                self._timer.start()
+
+    def flush(self) -> None:
+        with self._lock:
+            self._flush_locked()
+
+    def _flush_locked(self) -> None:
+        timer, self._timer = self._timer, None
+        if timer is not None:
+            timer.cancel()
+        if not self._rows:
+            return
+        try:
+            if self._file is None:
+                path = self._named = self._named or self._path()
+                if path is None:
+                    return
+                os.makedirs(os.path.dirname(path), exist_ok=True)
+                # opened once a process at the first block; a block is a
+                # local write a second at most, so span exits inside
+                # async executors stay loop-safe
+                self._file = open(  # raylint: disable=async-blocking
+                    path, "a")
+            self._file.write("".join(
+                json.dumps(row) + "\n" for row in self._rows))
+            self._file.flush()
+        except (OSError, TypeError, ValueError):
+            pass    # tracing must never break the task path
+        self._rows = []
+
+    def close(self) -> None:
+        """What is buffered goes out and the file is closed: the next
+        line opens whatever `path()` names then."""
+        with self._lock:
+            self._flush_locked()
+            if self._file is not None:
+                self._file.close()
+            self._file = self._named = None
+
+    def discard(self) -> None:
+        """Drops the rows that still wait for a file."""
+        with self._lock:
+            self._rows = []
+
+    def forget(self) -> None:
+        """Fork safety: a child inheriting the parent's handle and rows
+        would append them to the PARENT's pid-named shard (and interleave
+        writes on a shared file offset). Daemons fork workers, so both are
+        dropped in the child; its next line opens the child's own shard.
+        Runs in the just-forked child, which is single-threaded -- taking
+        the fork-inherited lock here could deadlock on a holder that no
+        longer exists in the child."""
+        self.__init__(self._path)
+
+
+_trace_shard = _Shard(
+    lambda: os.path.join(trace_dir(), f"trace-{os.getpid()}.jsonl"))
+_startup_shard = _Shard(lambda: _startup_dir and os.path.join(
+    _startup_dir, "logs", f"startup-{os.getpid()}.jsonl"))
+atexit.register(_trace_shard.flush)
+atexit.register(_startup_shard.flush)
 
 
 def _reset_writer() -> None:
-    """Fork safety: a child inheriting the parent's cached handle would
-    append its spans to the PARENT's pid-named shard (and interleave
-    writes on a shared file offset). Daemons fork workers, so the cached
-    handle is dropped in the child; the next span opens the child's own
-    shard. Runs in the just-forked child, which is single-threaded —
-    taking the fork-inherited lock here could deadlock on a holder that
-    no longer exists in the child."""
-    global _file
-    _file = None  # raylint: disable=lock-discipline
+    """Closes this process's span shard (tests move `RAY_TPU_TRACE_DIR`
+    between runs): what was buffered is written first."""
+    _trace_shard.close()
+
+
+def _after_fork() -> None:
+    # runs in the just-forked child, which is single-threaded
+    global _startup_rows, _startup_dropped
+    _trace_shard.forget()
+    _startup_shard.forget()
+    _startup_rows = []
+    _startup_dropped = 0  # raylint: disable=lock-discipline
 
 
 if hasattr(os, "register_at_fork"):
-    os.register_at_fork(after_in_child=_reset_writer)
+    os.register_at_fork(after_in_child=_after_fork)
 
 
 def _env_enabled() -> bool:
@@ -83,21 +186,6 @@ def refresh() -> None:
 
 def trace_dir() -> str:
     return os.environ.get("RAY_TPU_TRACE_DIR", "/tmp/ray_tpu/traces")
-
-
-def _writer():
-    global _file
-    if _file is None:
-        with _lock:
-            if _file is None:
-                os.makedirs(trace_dir(), exist_ok=True)
-                # opened once per process at the first span; per-span
-                # appends are line-buffered local writes (µs-scale), so
-                # span exits inside async executors stay loop-safe
-                _file = open(  # raylint: disable=async-blocking
-                    os.path.join(trace_dir(), f"trace-{os.getpid()}.jsonl"),
-                    "a", buffering=1)  # line-buffered: crash-safe
-    return _file
 
 
 def _new_id() -> str:
@@ -135,10 +223,7 @@ def span(name: str, kind: str = "internal",
     finally:
         _current.reset(token)
         s["end"] = time.time()
-        try:
-            _writer().write(json.dumps(s) + "\n")
-        except OSError:  # tracing must never break the task path
-            pass
+        _trace_shard.write(s)
 
 
 def current_context() -> Optional[Dict[str, str]]:
@@ -432,20 +517,133 @@ def device_trace(log_dir: Optional[str] = None) -> Iterator[str]:
         jax.profiler.stop_trace()
 
 
+# -- the start-up ledger -------------------------------------------------
+
+STARTUP_CAP = 4096      # rows a process keeps and writes; more are counted
+_startup_lock = threading.Lock()
+_startup_rows: List[dict] = []
+_startup_dropped = 0
+_startup_dir: Optional[str] = None
+
+
+def set_startup_dir(session_dir: Optional[str]) -> None:
+    """The session whose `logs/` this process's start-up shard lies in
+    (a worker's `--session-dir`, the cluster a driver starts). Rows made
+    before it is known wait for it; rows of a session this process had
+    before are that session's, and leave the list."""
+    global _startup_dir, _startup_dropped
+    with _startup_lock:
+        if session_dir == _startup_dir:
+            return
+        _startup_shard.close()
+        if _startup_dir is not None:
+            _startup_rows.clear()
+            _startup_dropped = 0
+        _startup_dir = session_dir
+
+
+def startup_row(name: str, begin_ns: int, end_ns: Optional[int] = None,
+                attrs: Optional[Dict[str, Any]] = None,
+                flush: bool = False) -> dict:
+    """Records one row that lasted from `begin_ns` to `end_ns` (now, when
+    left out) on `time.perf_counter_ns()`, which may have begun in another
+    process of the host. `flush` writes the shard through: the rows a
+    reader of a start that hangs would want."""
+    global _startup_dropped
+    now_ns, now = time.perf_counter_ns(), time.time()
+    if end_ns is None:
+        end_ns = now_ns
+    end = now - (now_ns - end_ns) / 1e9
+    row = {"name": name, "pid": os.getpid(), "attrs": dict(attrs or {}),
+           "begin_ns": begin_ns, "end_ns": end_ns,
+           "start": end - (end_ns - begin_ns) / 1e9, "end": end}
+    with _startup_lock:
+        if len(_startup_rows) >= STARTUP_CAP:
+            _startup_dropped += 1
+            return row
+        _startup_rows.append(row)
+    _startup_shard.write(row, flush=flush)
+    return row
+
+
+def startup_mark(name: str, attrs: Optional[Dict[str, Any]] = None,
+                 flush: bool = False) -> dict:
+    """A row of no length: an instant the stages are measured between."""
+    now_ns = time.perf_counter_ns()
+    return startup_row(name, now_ns, now_ns, attrs=attrs, flush=flush)
+
+
+@contextlib.contextmanager
+def startup_stage(name: str, attrs: Optional[Dict[str, Any]] = None,
+                  flush: bool = False) -> Iterator[Dict[str, Any]]:
+    """A start-up row around the block, and `rt/<name>` in a profiler
+    session where jax is imported. Yields the row's `attrs`, for what is
+    known only at the end."""
+    attrs = dict(attrs or {})
+    annotations = _annotations()
+    ann = annotations[0](f"rt/{name}") if annotations is not None else None
+    begin_ns = time.perf_counter_ns()
+    if ann is not None:
+        ann.__enter__()
+    try:
+        yield attrs
+    except BaseException as e:
+        attrs["error"] = type(e).__name__
+        raise
+    finally:
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        startup_row(name, begin_ns, attrs=attrs, flush=flush)
+
+
+def startup_rows() -> List[dict]:
+    """This process's rows, in the order they ended."""
+    with _startup_lock:
+        return list(_startup_rows)
+
+
+def clear_startup() -> None:
+    """Forgets this process's rows; its shard keeps what was written."""
+    global _startup_dropped
+    with _startup_lock:
+        _startup_rows.clear()
+        _startup_dropped = 0
+        _startup_shard.discard()
+
+
+def startup_dropped() -> int:
+    """Rows past `STARTUP_CAP`, which were counted and not kept."""
+    return _startup_dropped
+
+
+def collect_startup(session_dir: str) -> List[dict]:
+    """Every process's start-up rows of one session, by `begin_ns`: one
+    clock for the processes of a host."""
+    _startup_shard.flush()
+    rows = _read_shards(os.path.join(session_dir, "logs", "startup-*.jsonl"))
+    rows.sort(key=lambda r: r["begin_ns"])
+    return rows
+
+
 # -- aggregation ---------------------------------------------------------
 
-def collect(path: Optional[str] = None) -> List[dict]:
-    """Merge every process's span shard (sorted by start time)."""
+def _read_shards(pattern: str) -> List[dict]:
     import glob
 
-    spans = []
-    for fn in sorted(glob.glob(os.path.join(path or trace_dir(),
-                                            "trace-*.jsonl"))):
+    rows = []
+    for fn in sorted(glob.glob(pattern)):
         with open(fn) as f:
             for line in f:
                 line = line.strip()
                 if line:
-                    spans.append(json.loads(line))
+                    rows.append(json.loads(line))
+    return rows
+
+
+def collect(path: Optional[str] = None) -> List[dict]:
+    """Merge every process's span shard (sorted by start time)."""
+    _trace_shard.flush()
+    spans = _read_shards(os.path.join(path or trace_dir(), "trace-*.jsonl"))
     spans.sort(key=lambda s: s["start"])
     return spans
 

@@ -7,6 +7,7 @@ loss-trajectory equivalence of one folded K-step dispatch vs K single
 steps.
 """
 
+import os
 import warnings
 
 import jax
@@ -185,7 +186,11 @@ def test_global_cache_stats_shape():
     assert set(before) == {"hits", "misses", "retraces", "entries",
                            "lowering_ms", "lookup_ms", "lookups",
                            "activation_constraints",
-                           "activation_constraints_skipped", "lowerings"}
+                           "activation_constraints_skipped", "lowerings",
+                           "programs", "program_trace_ms",
+                           "program_lower_ms", "program_load_ms",
+                           "program_compile_ms", "persistent_hits",
+                           "persistent_misses"}
 
     @compiled_step
     def bump(x):
@@ -231,10 +236,12 @@ def test_cache_stats_count_the_activation_constraints_of_each_lowering(
         np.testing.assert_allclose(out[0] if folded else out, 2 * x)
     stats = cache_stats()
     name = "fold_steps(twicex1)" if folded else "twice"
-    assert stats["lowerings"] == [
-        {"fn": name, "activation_constraints": 2,
+    keys = ("fn", "door", "activation_constraints",
+            "activation_constraints_skipped")
+    assert [{k: row[k] for k in keys} for row in stats["lowerings"]] == [
+        {"fn": name, "door": "compiled_step", "activation_constraints": 2,
          "activation_constraints_skipped": 0},
-        {"fn": name, "activation_constraints": 0,
+        {"fn": name, "door": "compiled_step", "activation_constraints": 0,
          "activation_constraints_skipped": 2}]
     assert stats["activation_constraints"] == 2
     assert stats["activation_constraints_skipped"] == 2
@@ -279,3 +286,181 @@ def test_sharded_carry_steps_twice_without_a_retrace():
     moved = jax.device_put(carry[0], NamedSharding(mesh, P(None, "y")))
     with pytest.raises(RetraceError):
         run((moved, carry[1]), jnp.float32(0.5))
+
+
+# -- a row for every program the process starts -----------------------------
+
+def _program_rows(since):
+    from ray_tpu.util import tracing
+
+    return [r for r in tracing.startup_rows()[since:]
+            if r["name"] == "program"]
+
+
+@pytest.mark.parametrize("door", ["compiled_step", "jit"])
+def test_a_program_leaves_one_row_with_its_door_and_stages(door):
+    from ray_tpu.parallel import compile_cache
+    from ray_tpu.util import tracing
+
+    tracing.clear_startup()
+
+    def poly(x):
+        return jnp.tanh(x @ x.T).sum() + 41.5
+
+    x = jnp.ones((8, 8))
+    jax.block_until_ready(x)
+    before = cache_stats()
+    since = len(tracing.startup_rows())
+    if door == "compiled_step":
+        cache = ExecutableCache()
+        step = compiled_step(poly, cache=cache, name="poly:8")
+        step(x)
+    else:
+        jax.jit(poly)(x)
+    (row,) = _program_rows(since)
+    attrs = row["attrs"]
+    assert attrs["door"] == door
+    assert attrs["fn"] == ("poly:8" if door == "compiled_step"
+                           else "jit(poly)")
+    parts = [attrs[k] for k in ("trace_s", "lower_s", "load_s", "compile_s")]
+    assert all(p >= 0 for p in parts)
+    assert attrs["trace_s"] > 0 and attrs["lower_s"] > 0
+    assert sum(parts) <= (row["end_ns"] - row["begin_ns"]) / 1e9
+    assert attrs["backend_s"] >= attrs["load_s"] + attrs["compile_s"] - 1e-9
+    # either the persistent cache held it or the backend compiled it
+    assert (attrs["load_s"] > 0) == bool(attrs["persistent_hit"])
+    assert (attrs["compile_s"] > 0) == (not attrs["persistent_hit"])
+    after = cache_stats()
+    assert after["programs"] == before["programs"] + 1
+    for stage in ("trace", "lower", "load", "compile"):
+        assert after[f"program_{stage}_ms"] - before[f"program_{stage}_ms"] \
+            == pytest.approx(attrs[f"{stage}_s"] * 1e3, abs=2e-3)
+    if door == "compiled_step":
+        # the row is the `compiled_step.lower` phase, and the lowering's
+        (lowering,) = cache.lowerings
+        assert {k: lowering[k] for k in attrs} == attrs
+        assert (row["end_ns"] - row["begin_ns"]) / 1e6 == pytest.approx(
+            cache.phases.ms("compiled_step.lower"), abs=1e-3)
+        calls = compile_cache.listener_calls
+        step(x)                         # a cached executable: no listener
+        assert compile_cache.listener_calls == calls
+        assert _program_rows(since) == [row]
+
+
+def test_an_eager_program_inside_a_steps_trace_is_a_row_of_its_own():
+    """Values computed eagerly while the step is traced start programs of
+    their own: rows of the `jit` door inside the step's row, whose trace
+    time is the step's own."""
+    from ray_tpu.util import tracing
+
+    tracing.clear_startup()
+
+    def step(x):
+        with jax.ensure_compile_time_eval():
+            table = jnp.cumsum(jnp.arange(7.0)) * 3.25
+        return x * table.sum()
+
+    x = jnp.ones(7)
+    jax.block_until_ready(x)
+    since = len(tracing.startup_rows())
+    compiled_step(step, cache=ExecutableCache(), name="step:7")(x)
+    rows = _program_rows(since)
+    own = rows[-1]
+    assert own["attrs"]["fn"] == "step:7"
+    assert own["attrs"]["door"] == "compiled_step"
+    inner = rows[:-1]
+    assert inner and all(r["attrs"]["door"] == "jit" for r in inner)
+    assert all(own["begin_ns"] <= r["begin_ns"] and r["end_ns"]
+               <= own["end_ns"] for r in inner)
+    inside = sum(r["end_ns"] - r["begin_ns"] for r in inner) / 1e9
+    parts = sum(own["attrs"][k] for k in ("trace_s", "lower_s", "load_s",
+                                          "compile_s"))
+    assert parts + inside <= (own["end_ns"] - own["begin_ns"]) / 1e9 + 5e-3
+
+
+def test_a_second_process_reads_the_program_from_the_persistent_cache(
+        tmp_path):
+    import json
+    import subprocess
+    import sys
+
+    code = (
+        "import json, jax, jax.numpy as jnp\n"
+        "from ray_tpu.parallel import compiled_step, cache_stats\n"
+        "from ray_tpu.util import tracing\n"
+        "def f(x):\n"
+        "    return jnp.sin(x) @ jnp.cos(x).T * 0.8125\n"
+        "x = jnp.ones((16, 16)); jax.block_until_ready(x)\n"
+        "tracing.clear_startup()\n"
+        "compiled_step(f, name='f:16')(x)\n"
+        "jax.jit(lambda x: (x * 1.625).sum())(x)\n"
+        "rows = [r['attrs'] for r in tracing.startup_rows()\n"
+        "        if r['name'] == 'program']\n"
+        "s = cache_stats()\n"
+        "print(json.dumps([rows, s['persistent_hits'],\n"
+        "                  s['persistent_misses']]))\n")
+    env = dict(os.environ, JAX_COMPILATION_CACHE_DIR=str(tmp_path / "cache"),
+               JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS="0",
+               JAX_PERSISTENT_CACHE_MIN_ENTRY_SIZE_BYTES="0",
+               JAX_PLATFORMS="cpu")
+    runs = []
+    for _ in range(2):
+        out = subprocess.run([sys.executable, "-c", code], env=env,
+                             capture_output=True, text=True, timeout=300,
+                             cwd=os.path.dirname(os.path.dirname(
+                                 os.path.abspath(__file__))))
+        assert out.returncode == 0, out.stderr[-2000:]
+        runs.append(json.loads(out.stdout.strip().splitlines()[-1]))
+    (cold, cold_hits, cold_misses), (warm, warm_hits, warm_misses) = runs
+    assert [r["fn"] for r in cold] == [r["fn"] for r in warm]
+    assert {r["door"] for r in cold} == {"compiled_step", "jit"}
+    assert all(r["persistent_hit"] is False and r["load_s"] == 0
+               and r["compile_s"] > 0 for r in cold)
+    assert all(r["persistent_hit"] is True and r["load_s"] > 0
+               and r["compile_s"] == 0 for r in warm)
+    assert (cold_hits, warm_misses) == (0, 0)
+    # the totals count the programs from before the rows were cleared too
+    assert cold_misses == warm_hits >= len(cold) >= 2
+
+
+def test_the_metrics_text_prints_the_programs():
+    from ray_tpu.parallel import compile_cache
+
+    jax.jit(lambda x: x * 2.125)(jnp.ones(3))
+    text = compile_cache._metrics_text()
+    s = cache_stats()
+    assert s["programs"] >= 1
+    assert f"compile_cache_programs_total {s['programs']}" in text
+    for stage in ("trace", "lower", "load", "compile"):
+        assert f'compile_cache_program_ms_total{{stage="{stage}"}} ' in text
+    assert "compile_cache_persistent_hits_total " in text
+    assert "compile_cache_persistent_misses_total " in text
+
+
+def test_the_frames_of_every_programs_trace_keep_their_lines():
+    """`lookup`, `_lookup`'s lowering and the wrapper's calls are frames
+    above every traced program: their positions are in a Pallas kernel's
+    locations and so in the persistent cache's key. An edit that moves
+    them sends every kernel-bearing program of every cell through a cold
+    start once (and `setup_s` with it): move them on purpose or not at
+    all, and put what the file gains at its end."""
+    import ast
+    import inspect
+
+    from ray_tpu.parallel import compile_cache
+
+    tree = ast.parse(inspect.getsource(compile_cache))
+    calls = {}
+    for node in ast.walk(tree):
+        if isinstance(node, ast.FunctionDef) and node.name in (
+                "lookup", "_lookup", "wrapper"):
+            for call in ast.walk(node):
+                if isinstance(call, ast.Call) and isinstance(
+                        call.func, ast.Attribute) and call.func.attr in (
+                        "_lookup", "lower", "lookup"):
+                    calls.setdefault(node.name, []).append(
+                        (call.lineno, call.col_offset,
+                         call.end_lineno, call.end_col_offset))
+    assert {k: sorted(v) for k, v in calls.items()} == {
+        "lookup": [(161, 19, 162, 65)], "_lookup": [(197, 23, 200, 36)],
+        "wrapper": [(294, 23, 297, 38), (302, 19, 305, 34)]}

@@ -5,6 +5,7 @@ actor-method calls produce `.remote` (producer) and `.execute`
 (consumer) spans that share a trace id across processes.
 """
 
+import json
 import os
 
 import pytest
@@ -41,7 +42,7 @@ def test_task_and_actor_spans(tmp_path):
         ray_tpu.kill(a)
         import time
 
-        time.sleep(0.5)  # line-buffered shard flush
+        time.sleep(1.5)  # the shards are flushed a second after a line
     finally:
         ray_tpu.shutdown()
         os.environ.pop("RAY_TPU_TRACE", None)
@@ -308,3 +309,157 @@ def test_device_trace_holds_the_phases_and_the_train_step(tmp_path):
     total = sum(runner.phases.snapshot_ns().values()) / 1e6
     rows = step_profiler.recent()[-4:]
     assert abs(total - sum(r["total_ms"] for r in rows)) < 0.05 * total + 0.5
+
+
+# -- the start-up ledger's rows ---------------------------------------------
+
+@pytest.fixture
+def fresh_ledger():
+    """The process's ledger, empty and with no session, then as it was."""
+    from ray_tpu.util import tracing
+
+    was = tracing._startup_dir
+    tracing.set_startup_dir(None)
+    tracing.clear_startup()
+    yield tracing
+    tracing.set_startup_dir(was)
+    tracing.clear_startup()
+
+
+def test_rows_and_marks_round_trip_through_the_shard(tmp_path, fresh_ledger):
+    import time
+
+    tracing = fresh_ledger
+    session = str(tmp_path / "session")
+    t0 = time.perf_counter_ns()
+    with tracing.startup_stage("boot", {"chips": [0]}) as attrs:
+        time.sleep(0.01)
+        attrs["bytes"] = 7          # known only at the end
+    mark = tracing.startup_mark("entered", {"who": "me"})
+    # a row that began in another process of the host, on the one clock
+    far = tracing.startup_row("spawn", t0 - 5_000_000, t0, {"n": 1})
+    assert not os.path.exists(session)      # no session yet: the rows wait
+    tracing.set_startup_dir(session)
+    last = tracing.startup_mark("late", flush=True)
+    shard = os.path.join(session, "logs", f"startup-{os.getpid()}.jsonl")
+    with open(shard) as f:                  # written through, all four
+        assert len(f.read().splitlines()) == 4
+    rows = tracing.collect_startup(session)
+    assert [r["name"] for r in rows] == ["spawn", "boot", "entered", "late"]
+    assert rows == sorted(tracing.startup_rows(),
+                          key=lambda r: r["begin_ns"])
+    boot = rows[1]
+    assert boot["attrs"] == {"chips": [0], "bytes": 7}
+    assert boot["pid"] == os.getpid()
+    assert boot["end_ns"] - boot["begin_ns"] >= 10_000_000
+    assert boot["end"] - boot["start"] == pytest.approx(
+        (boot["end_ns"] - boot["begin_ns"]) / 1e9, abs=1e-5)
+    assert rows[0] == far and far["end_ns"] - far["begin_ns"] == 5_000_000
+    assert rows[2] == mark and mark["begin_ns"] == mark["end_ns"]
+    assert mark["start"] == mark["end"]
+    assert rows[3] == last
+    # wall and ledger clocks tell one story
+    assert boot["start"] - far["start"] == pytest.approx(
+        (boot["begin_ns"] - far["begin_ns"]) / 1e9, abs=1e-3)
+
+
+def test_a_failed_stage_is_a_row_that_names_the_error(fresh_ledger):
+    tracing = fresh_ledger
+    with pytest.raises(KeyError):
+        with tracing.startup_stage("build"):
+            raise KeyError("x")
+    (row,) = tracing.startup_rows()
+    assert row["name"] == "build" and row["attrs"] == {"error": "KeyError"}
+
+
+def test_the_ledger_keeps_a_bounded_list_and_counts_the_rest(
+        fresh_ledger, monkeypatch, tmp_path):
+    tracing = fresh_ledger
+    monkeypatch.setattr(tracing, "STARTUP_CAP", 3)
+    tracing.set_startup_dir(str(tmp_path))
+    for i in range(5):
+        tracing.startup_mark(f"m{i}")
+    assert [r["name"] for r in tracing.startup_rows()] == ["m0", "m1", "m2"]
+    assert tracing.startup_dropped() == 2
+    assert len(tracing.collect_startup(str(tmp_path))) == 3
+    # a new session: the rows written to the last one are that one's
+    tracing.set_startup_dir(str(tmp_path / "next"))
+    assert tracing.startup_rows() == [] and tracing.startup_dropped() == 0
+    tracing.startup_mark("again")
+    assert [r["name"] for r in tracing.collect_startup(
+        str(tmp_path / "next"))] == ["again"]
+    assert len(tracing.collect_startup(str(tmp_path))) == 3
+
+
+def test_a_stage_is_an_annotation_where_jax_is_imported(fresh_ledger):
+    import jax  # noqa: F401
+
+    tracing = fresh_ledger
+    seen = []
+
+    class Spy:
+        def __init__(self, name, **kw):
+            seen.append(name)
+
+        def __enter__(self):
+            seen.append("in")
+
+        def __exit__(self, *exc):
+            seen.append("out")
+
+    was = tracing._ANNOTATIONS
+    tracing._ANNOTATIONS = (Spy, Spy)
+    try:
+        with tracing.startup_stage("engine_build"):
+            pass
+    finally:
+        tracing._ANNOTATIONS = was
+    assert seen == ["rt/engine_build", "in", "out"]
+
+
+def test_the_shard_writes_in_blocks_and_loses_a_second_at_most(tmp_path):
+    import time
+
+    from ray_tpu.util import tracing
+
+    path = str(tmp_path / "deep" / "shard.jsonl")
+    def names():
+        with open(path) as f:
+            return [json.loads(line)["n"] for line in f]
+
+    shard = tracing._Shard(lambda: path)
+    shard.write({"n": "a"})
+    assert not os.path.exists(path)             # buffered, not serialized
+    deadline = time.time() + 5
+    while not os.path.exists(path) and time.time() < deadline:
+        time.sleep(0.05)                        # the timer's flush
+    assert names() == ["a"]
+    shard.write({"n": "b"}, flush=True)         # written through
+    assert names() == ["a", "b"]
+    for i in range(tracing._FLUSH_ROWS):        # a full block goes at once
+        shard.write({"n": i})
+    assert len(names()) == 2 + tracing._FLUSH_ROWS
+    shard.write({"n": "c"})
+    shard.forget()                              # a forked child's view
+    shard.close()
+    assert names()[-1] != "c"
+
+
+def test_span_lines_wait_in_the_buffer_until_collect(tmp_path):
+    from ray_tpu.util import tracing
+
+    trace_dir = str(tmp_path / "traces")
+    os.environ["RAY_TPU_TRACE"] = "1"
+    os.environ["RAY_TPU_TRACE_DIR"] = trace_dir
+    tracing.refresh()
+    tracing._reset_writer()
+    try:
+        with tracing.span("one"):
+            pass
+        assert not os.path.exists(trace_dir)    # no write inside the span
+        assert [s["name"] for s in tracing.collect(trace_dir)] == ["one"]
+    finally:
+        os.environ.pop("RAY_TPU_TRACE", None)
+        os.environ.pop("RAY_TPU_TRACE_DIR", None)
+        tracing.refresh()
+        tracing._reset_writer()
