@@ -9,13 +9,13 @@ What it asks of the system that `llama.py` does not:
   qk_rope_dim` values (the normed compressed key/value and the shared rope
   key) padded to whole lane tiles, not K and V of `[n_kv_head, head_dim]`:
   `cache_rows(cfg)` says so and the engine builds its arena from it.
-- Two attention paths for one layer, the same numbers: `decode_step` scores
-  the query against the cached latents themselves (the absorbed form:
-  `q_nope W_uk^T` against `c_kv`, the output `(P c_kv) W_uv`), so nothing of
-  width heads x 256 is made per cached position; `prefill_step` and
-  `chunk_step` expand latents to per-head keys and values, a group of heads
-  and a block of keys at a time under one running softmax: the sequence's
-  cached pages block by block as far as `start` reaches, then the window.
+- Two attention paths for one layer, the same numbers. `decode_step` scores
+  the query against the cached latents themselves (absorbed: `q_nope W_uk^T`
+  against `c_kv`, the output `(P c_kv) W_uv`; nothing of width heads x 256 a
+  cached position), each lane's as far as its own last key block, on a work
+  list of live (lane, key block) pairs. `prefill_step` and `chunk_step` expand
+  latents to per-head keys and values, a group of heads and a block of keys at
+  a time: the cached pages as far as `start` reaches, then the window.
 - The expert layers hold `experts_held` of `n_experts` experts, starting at
   `first_expert`: one chip's share of an expert-parallel deployment
   (`parallel.moe.expert_shard_layer`). The router keeps all its outputs.
@@ -40,10 +40,10 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models.layers import (NEG_INF, declare_weights, gather_pages,
-                                   head, last_row, rms, rope,
-                                   routed_feed_forward, top_shapes,
+from ray_tpu.models.layers import (NEG_INF, declare_weights, head, last_row,
+                                   rms, rope, routed_feed_forward, top_shapes,
                                    unboxed_params)
+from ray_tpu.models.llama import fold_pairs, key_block_pairs
 from ray_tpu.parallel.moe import MOE_COUNTS
 
 # what each step returns after the cache rows, an int32 vector summed over
@@ -251,43 +251,43 @@ def _kv_b(lp, cfg: KimiK2Config):
         cfg.kv_lora_rank, cfg.n_head, cfg.qk_nope_dim + cfg.v_head_dim)
 
 
-def _softmax(scores, valid):
-    scores = jnp.where(valid, scores, NEG_INF)
-    p = jnp.exp(scores - jnp.max(scores, axis=-1, keepdims=True))
-    return p / jnp.maximum(jnp.sum(p, axis=-1, keepdims=True), 1e-20)
+# the absorbed path's work list (`absorbed_walk`, at this file's end): cached
+# keys a (lane, block) pair, and pairs a lane of the bucket a trip. One latent
+# feeds all the heads, so a wide block pays; a trip wider than the lanes have
+# pairs to fill costs Ling half as much again. Settled on the chip with the
+# attention alone at both cells' shapes (16 lanes x 64 heads, 64 x 32): the
+# quickest at both of 128-1,024 keys x 1, 2, 4 pairs (PERF.md section 6, PR 61)
+LATENT_BLOCK = 640
+PAIRS_A_LANE = 1
 
 
-def attend_absorbed(lp, cfg: KimiK2Config, q_nope, q_rope, lat_cached,
-                    lat_new, valid):
+def attend_absorbed(lp, cfg: KimiK2Config, q_nope, q_rope, lat_new, pages,
+                    layer, walk):
     """One token a sequence against its cached latents and itself.
-    q_nope [B, H, nope], q_rope [B, H, rope]; lat_cached [B, T, row]
-    (the sequence's pages, gathered); lat_new [B, row]; valid [B, T + 1]
-    (cached slots below the position, then the token itself). The query is
-    carried into the latent space (`q_nope W_uk^T`), scored against the
-    latents as they lie in the cache, and the weighted latent is expanded
-    once a head (`W_uv`). Returns [B, H * v]."""
+    q_nope [B, H, nope], q_rope [B, H, rope]; lat_new [B, row] (the token's
+    own); pages [P, L, block, row] (the whole arena) and `layer`, the page
+    layer to read; `walk` what `listed_walk` made of the step's positions and
+    page table, once for all the step's layers. The query is carried into the
+    latent space once (`q_nope W_uk^T`, beside `q_rope`, padded to the row)
+    and scored against the latents as they lie in the cache: the one latent
+    "head" is the K/V head and the H query heads its group, so `walk_latents`
+    (at this file's end) reads every live (lane, key block) pair once under
+    one running softmax, and no [B, table slots, row] array is made. The
+    weighted latent is cut to `kv_lora_rank` and expanded once a head
+    (`W_uv`). Returns [B, H * v]."""
     with jax.named_scope("mla_attend"):
         w = _kv_b(lp, cfg)
         w_uk, w_uv = jnp.split(w, [cfg.qk_nope_dim], axis=-1)
-        f32 = jnp.float32
         q_lat = jnp.einsum("bhn,chn->bhc", q_nope, w_uk)
         q_cat = jnp.concatenate([q_lat, q_rope], axis=-1)
         # the row's padding scores nothing: [B, H, row]
         q_cat = jnp.pad(q_cat, ((0, 0), (0, 0),
                                 (0, cfg.row_dim - cfg.latent_dim)))
-        s_cached = jnp.einsum("bhl,btl->bht", q_cat, lat_cached,
-                              preferred_element_type=f32)
-        s_new = jnp.einsum("bhl,bl->bh", q_cat, lat_new,
-                           preferred_element_type=f32)
-        scores = jnp.concatenate([s_cached, s_new[..., None]], axis=-1)
-        p = _softmax(scores * softmax_scale(cfg), valid[:, None, :])
-        p = p.astype(cfg.dtype)
-        t = lat_cached.shape[1]
-        # over the whole latent and cut afterwards: a slice of the gathered
-        # pages would be another copy of them
-        o_lat = jnp.einsum("bht,btl->bhl", p[..., :t], lat_cached,
-                           preferred_element_type=f32) \
-            + p[..., t:].astype(f32) * lat_new[:, None, :].astype(f32)
+        trips, width, _, rows = walk
+        # over the whole latent and cut afterwards: a slice of a gathered
+        # block would be another copy of it
+        o_lat = walk_latents(q_cat, lat_new, pages, layer, trips, rows,
+                             width=width, scale=softmax_scale(cfg))
         o_lat = o_lat[..., :cfg.kv_lora_rank].astype(cfg.dtype)
         out = jnp.einsum("bhc,chv->bhv", o_lat, w_uv)
     return out.reshape(out.shape[0], cfg.n_head * cfg.v_head_dim)
@@ -452,33 +452,121 @@ def chunk_step(variables, cfg: KimiK2Config, tokens, start, pages,
 
 def decode_step(variables, cfg: KimiK2Config, tokens, positions, pages,
                 page_table, valid=None):
-    """One token a sequence on a paged cache: the absorbed path. tokens
-    [B]; positions [B] (= tokens already cached); `valid` [B] marks the
-    lanes that hold a sequence. Returns (logits [B, V], latents
-    [B, L, row], counts)."""
+    """One token a sequence on a paged cache: the absorbed path, each lane's
+    cached latents read as far as its own last key block (`listed_walk`, made
+    once for all the layers). tokens [B]; positions [B] (= tokens already
+    cached); `valid` [B] marks the lanes that hold a sequence. Returns
+    (logits [B, V], latents [B, L, row], counts)."""
     p = unboxed_params(variables)
     dtype = cfg.dtype
     x = p["wte"].astype(dtype)[tokens]
     cos_t, sin_t = yarn_tables(cfg)
     cos, sin = jnp.asarray(cos_t)[positions], jnp.asarray(sin_t)[positions]
-    t_max = page_table.shape[1] * pages.shape[2]
-    key_idx = jnp.arange(t_max + 1)
-    seen = (key_idx[None, :] < positions[:, None]) | \
-        (key_idx[None, :] == t_max)
+    walk = listed_walk(positions, page_table, pages.shape[2])
     latents, counts = [], jnp.zeros(len(MOE_COUNTS), jnp.int32)
     for i in range(cfg.n_layer):
         lp = p[f"layer{i}"]
         h = rms(x, lp["attn_norm"], cfg.norm_eps, dtype)
         q_nope, q_rope, lat = _project(lp, cfg, h, cos, sin)
-        att = attend_absorbed(
-            lp, cfg, q_nope, q_rope,
-            gather_pages(pages, page_table, i).astype(dtype), lat, seen)
+        att = attend_absorbed(lp, cfg, q_nope, q_rope, lat, pages, i, walk)
         x = x + att @ lp["attn_out"].astype(dtype)
         h = rms(x, lp["mlp_norm"], cfg.norm_eps, dtype)
         y, n = routed_feed_forward(lp, cfg, i, h, valid)
         x = x + y
         counts = counts + n
         latents.append(lat)
-    # every lane of the bucket scores all of its table's slots and itself
-    return head(p, cfg, x), jnp.stack(latents, axis=1), \
-        _step_counts(counts, cfg.n_layer * x.shape[0] * (t_max + 1))
+    # what the program scored: every lane's own latent, then whole trips,
+    # dead pairs and the last blocks' padding included
+    trips, width, keys, _ = walk
+    return head(p, cfg, x), jnp.stack(latents, axis=1), _step_counts(
+        counts, cfg.n_layer * (x.shape[0] + trips * width * keys))
+
+
+# -- the absorbed path's walk over the cached latents -------------------------
+# (below the steps: the chunk and prefill programs hold a Pallas kernel whose
+# compile-cache key carries the lines of `_window_forward`'s frames, which
+# stand where they stood before the walk came, PR 61)
+
+def absorbed_walk(positions, n_pages: int, page: int, xp=jnp):
+    """What a decode step walks of the cached latents, for `positions` [B]:
+    (trips, pairs a trip, keys a block, the work list), as
+    `llama.key_block_walk` lays a K/V step's out, for every bucket, the bucket
+    of one too. The list is `llama.key_block_pairs`' at `LATENT_BLOCK` keys a
+    block (lane, block, live, each a whole number of trips long):
+    `PAIRS_A_LANE` x B pairs a trip whatever their lanes, `ceil(pairs / that)`
+    trips; the last trip's pairs past the list's end are dead and are scored
+    all the same. So a layer scores the lanes' own latents + trips x pairs a
+    trip x keys a block slots, which the steps count (`attn_key_slots`).
+    `xp=np` on the host, where a test counts with the program's function."""
+    lanes = positions.shape[0]
+    blocks, *pairs, keys = key_block_pairs(positions, n_pages, page, xp,
+                                           LATENT_BLOCK)
+    width = PAIRS_A_LANE * lanes
+    short = -len(pairs[0]) % width
+    if short:
+        pairs = [xp.pad(a, (0, short)) for a in pairs]
+    return -(-xp.sum(blocks) // width), width, keys, tuple(pairs)
+
+
+def listed_walk(positions, page_table, page: int):
+    """`absorbed_walk` of a step, with a row a pair in the place of the list
+    (made once a step: it is the same in every layer, and a trip's body, one a
+    layer in the program, stays small): (trips, pairs a trip, keys a block,
+    rows int32 [pairs, 2 + pages a block]), a row its pair's lane, the keys
+    its lane holds from the block's first slot on (0 for a dead pair), its
+    page ids. positions [B]; page_table [B, n_pages]."""
+    n_pages = page_table.shape[1]
+    trips, width, keys, (lane, at, live) = absorbed_walk(positions, n_pages,
+                                                         page)
+    per_block = keys // page
+    table = jnp.pad(page_table, ((0, 0), (0, -n_pages % per_block)))
+    return trips, width, keys, jnp.concatenate([
+        lane[:, None],
+        jnp.where(live, positions[lane] - at * keys, 0)[:, None],
+        table[lane[:, None], at[:, None] * per_block
+              + jnp.arange(per_block)[None, :]]], axis=1)
+
+
+def _walk_latents(q_cat, lat_new, pages, layer, trips, rows, width: int,
+                  scale: float):
+    """The running softmax of `attend_absorbed` in the latent space. q_cat
+    [B, H, row] (the query in the latent space); lat_new [B, row]; pages
+    [P, L, block, row]; `trips`, `rows`, `width` as `listed_walk` gives them.
+    State = (maximum, sum [B, 1, H], accumulator [B, 1, H, row]) in float32.
+    The token's own latent first, which gives every row a real maximum, so a
+    masked key weighs exp(NEG_INF - m) = 0 exactly; then a trip of the list
+    at a time: its pairs' pages gathered from the arena by (page, layer),
+    scored against their own lanes' queries (the lane's H heads the rows of
+    the product), masked by their own lanes' positions and folded
+    (`llama.fold_pairs`, which adds the pairs of one lane together before
+    they meet the lane's state; the gathered block is key and value both).
+    A short lane beside a long one is read as far as its own last block and a
+    lane that holds nothing not at all: the same sums as one softmax over
+    each lane's latents, in another order, no key left out. Returns the
+    weighted latent [B, H, row] float32.
+
+    Jitted on its own with the layer as an operand, so that a step traces it
+    once for all its layers, as `llama.paged_attend` is."""
+    b, h, row = q_cat.shape
+    f32 = jnp.float32
+    own = jnp.einsum("bhl,bl->bh", q_cat, lat_new,
+                     preferred_element_type=f32)[:, None] * scale
+    state = (own, jnp.ones_like(own), jnp.broadcast_to(
+        lat_new.astype(f32)[:, None, None], (b, 1, h, row)))
+
+    def paired(j, state):
+        pairs = jax.lax.dynamic_slice_in_dim(rows, j * width, width)
+        lane, held, ids = pairs[:, 0], pairs[:, 1], pairs[:, 2:]
+        blk = pages[ids, layer].reshape(width, -1, row).astype(q_cat.dtype)
+        s = jnp.einsum("thl,tkl->thk", q_cat[lane], blk,
+                       preferred_element_type=f32) * scale
+        seen = jnp.arange(blk.shape[1])[None, :] < held[:, None]
+        s = jnp.where(seen[:, None, :], s, NEG_INF)
+        return fold_pairs(state, s[:, None], blk[:, :, None], lane, held > 0,
+                          q_cat.dtype)
+
+    _, l, acc = jax.lax.fori_loop(0, trips, paired, state)
+    return (acc / jnp.maximum(l, 1e-20)[..., None])[:, 0]
+
+
+walk_latents = jax.jit(_walk_latents, static_argnames=("width", "scale"))

@@ -189,15 +189,6 @@ def head(p, cfg, x):
         return x @ p["lm_head"].astype(cfg.dtype)
 
 
-def gather_pages(pages, page_table, i: int):
-    """Layer i's rows of the sequences' pages: [B, n_pages * block, row].
-    Page and layer are indexed together, so only the sequences' own rows
-    are read (a slice of the layer first would copy a seventh of the
-    arena a layer)."""
-    got = pages[page_table, i]
-    return got.reshape(got.shape[0], -1, got.shape[-1])
-
-
 def slot_state(arena, slot, layer: int):
     """Layer `layer` of slot `slot` (traced) of a sequence-state arena array
     [slots, L, ...], as a float32 batch of one: a slice, not a gather."""

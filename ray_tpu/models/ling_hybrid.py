@@ -39,9 +39,9 @@ S'^T k_t and S'^T q_t, and writes S_t over it: `kda_step`.)
 
 The MLA layer is DeepSeek's without the query bottleneck, with RMSNorm over
 each query head and a per-head sigmoid gate on the output; it calls
-`kimi_k2.attend_absorbed` / `attend_expanded` with its own shapes (`cfg.mla`
-is the `KimiK2Config` they read). Rope: the half-rotation form on the rope
-channels, no scaling.
+`kimi_k2.attend_absorbed` (over `listed_walk`'s work list of live (lane, key
+block) pairs) / `attend_expanded` with its own shapes (`cfg.mla` is their
+`KimiK2Config`). Rope: the half-rotation form on the rope channels, no scaling.
 
 Parameters: `top/{wte, final_norm, lm_head}`; `layer<i>/{attn_norm,
 attn_out, mlp_norm}` with, in a KDA layer, `kda_qkv` ([q | k | v]),
@@ -63,9 +63,9 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models.kimi_k2 import (KimiK2Config, attend_absorbed,
-                                    attend_expanded, yarn_tables)
-from ray_tpu.models.layers import (declare_weights, gather_pages, head,
-                                   last_row, put_slot_state, rms, rope,
+                                    attend_expanded, listed_walk, yarn_tables)
+from ray_tpu.models.layers import (declare_weights, head, last_row,
+                                   put_slot_state, rms, rope,
                                    routed_feed_forward, slot_state,
                                    top_shapes, unboxed_params)
 from ray_tpu.parallel.moe import MOE_COUNTS
@@ -634,28 +634,26 @@ def chunk_step(variables, cfg: LingHybridConfig, tokens, start, pages,
 def decode_step(variables, cfg: LingHybridConfig, tokens, positions, pages,
                 page_table, seq_state=None, slots=None, valid=None):
     """One token a sequence: the MLA layers' absorbed path over the paged
-    cache, the KDA layers' recurrence ON the state arena (`seq_state`, the
-    donated arrays [slots + 1, n_kda, ...]; `slots` [B] names lane i's):
-    each layer reads its lanes' states from the arena and writes their
-    successors back where they lay. One recurrence (`kda_step`) walked two
-    ways, by a shape the program sees: a bucket that covers the arena (lanes
-    >= slots, the scratch slot apart) walks it in slot order, densely
-    (`kda_slots`, the slots' lanes found once a step: `slot_lanes`); a
-    smaller one walks its lanes (`kda_lanes`: slot order would cost the
-    bucket of one a walk of every slot, lane order the bucket of 64 a few
-    hundred trips of a loop). tokens [B]; positions [B]; `valid` [B] marks
-    the lanes that hold a sequence (the others name the scratch slot).
-    Returns (logits [B, V], latents [B, n_mla, row], the arena's arrays,
-    counts)."""
+    cache (each lane's latents as far as its own last key block: Kimi's
+    `listed_walk`, made once a step), the KDA layers' recurrence ON the state
+    arena (`seq_state`, the donated arrays [slots + 1, n_kda, ...]; `slots`
+    [B] names lane i's): each layer reads its lanes' states from the arena
+    and writes their successors back where they lay. One recurrence
+    (`kda_step`) walked two ways, by a shape the program sees: a bucket that
+    covers the arena (lanes >= slots, the scratch slot apart) walks it in slot
+    order, densely (`kda_slots`, the slots' lanes found once a step:
+    `slot_lanes`); a smaller one walks its lanes (`kda_lanes`: slot order
+    would cost the bucket of one a walk of every slot, lane order the bucket
+    of 64 a few hundred trips of a loop). tokens [B]; positions [B]; `valid`
+    [B] marks the lanes that hold a sequence (the others name the scratch
+    slot). Returns (logits [B, V], latents [B, n_mla, row], the arena's
+    arrays, counts)."""
     p = unboxed_params(variables)
     dtype = cfg.dtype
     b = tokens.shape[0]
     x = p["wte"].astype(dtype)[tokens]
     cos, sin = _rope_tables(cfg, positions)
-    t_max = page_table.shape[1] * pages.shape[2]
-    key_idx = jnp.arange(t_max + 1)
-    seen_keys = (key_idx[None, :] < positions[:, None]) | \
-        (key_idx[None, :] == t_max)
+    key_walk = listed_walk(positions, page_table, pages.shape[2])
     s_arena, tail_arena = seq_state
     n_slots = s_arena.shape[0] - 1
     if b >= n_slots:
@@ -671,11 +669,8 @@ def decode_step(variables, cfg: LingHybridConfig, tokens, positions, pages,
         if cfg.is_mla(i):
             q_nope, q_rope, lat, gate = _mla_project(lp, cfg, h, cos, sin)
             with jax.named_scope("mla_absorbed"):
-                att = attend_absorbed(
-                    lp, cfg.mla, q_nope, q_rope,
-                    gather_pages(pages, page_table,
-                                 len(latents)).astype(dtype),
-                    lat, seen_keys)
+                att = attend_absorbed(lp, cfg.mla, q_nope, q_rope, lat,
+                                      pages, len(latents), key_walk)
             x = x + _mla_out(lp, cfg, att, gate)
             latents.append(lat)
         else:
@@ -695,7 +690,9 @@ def decode_step(variables, cfg: LingHybridConfig, tokens, positions, pages,
     tail_arena = tail_arena.at[slots].set(
         jnp.stack(tails, axis=1).astype(tail_arena.dtype))
     lanes = b if valid is None else jnp.sum(valid.astype(jnp.int32))
-    # every lane of the bucket scores all of its table's slots and itself
+    # what an MLA layer scored: every lane's own latent, then whole trips of
+    # the list, dead pairs and the last blocks' padding included
+    trips, width, keys, _ = key_walk
     return head(p, cfg, x), jnp.stack(latents, axis=1), s_arena, tail_arena, \
-        _step_counts(counts, len(latents) * b * (t_max + 1),
+        _step_counts(counts, len(latents) * (b + trips * width * keys),
                      lanes * len(tails), walked * len(tails))

@@ -254,6 +254,34 @@ def _materialised(hlo_text):
     return out
 
 
+def _assert_a_trip_is_gathered_never_a_table(text, mem, lanes, table_pages,
+                                             arena):
+    """The absorbed path's walk over the cached latents (`kimi_k2.walk_latents`
+    on `listed_walk`'s work list, PR 61) in a compiled decode program of
+    `lanes` lanes over `arena` [pages, layers, block, row]: no array holds
+    every slot of every lane's table (the parent's gathered latents `[lanes,
+    slots, row]`, its scores and weights `[lanes, H, slots (+ 1)]`), the
+    largest gather of pages is one trip's pairs' (the arena's own count of
+    pages apart: where it has one layer, the program sees it as `[pages,
+    block, row]`, a bitcast), and the program's temporaries are a small part
+    of the arena, which the parent's gather was the size of."""
+    from ray_tpu.models import kimi_k2
+
+    num_pages, _, block, row = arena
+    held = _materialised(text)
+    slots = table_pages * block
+    table_wide = [a for a in held if a[1] == (lanes, slots, row)
+                  or (a[1][0] == lanes and a[1][-1] in (slots, slots + 1))]
+    assert not table_wide, table_wide
+    gathered = {a[1][0] for a in held if a[0] == "bf16"
+                and a[1][1:] == (block, row)} - {num_pages}
+    assert gathered and max(gathered) == kimi_k2.PAIRS_A_LANE * lanes * (
+        kimi_k2.LATENT_BLOCK // block), gathered
+    print(f"temp_size_in_bytes {mem.temp_size_in_bytes}, gathers of pages "
+          f"{sorted(gathered)}")
+    assert mem.temp_size_in_bytes < 2 * math.prod(arena) // 4
+
+
 @pytest.mark.parametrize("widths", ["mistral_7b_l20", "llama_125m"])
 def test_decode_program_reads_live_pages_once(topo, widths):
     """What the engine's decode-16 program holds in memory (the arena of
@@ -305,7 +333,9 @@ def test_latent_arena_is_updated_in_place_at_kimi_k2_widths(topo, kind, size):
     back around the scatter, which a tile of 1 shows. The chunk program
     walks the cached keys a block at a time: it holds no array over the
     table's 8,192 slots and the chunk's 1,024 together, and no float32
-    array larger than a head group's scores against one key block."""
+    array larger than a head group's scores against one key block. The
+    decode program gathers a trip of its work list at a time (16 pairs of 40
+    pages), never the lanes' tables (`[8192,16,640]` before PR 61)."""
     import types
 
     from ray_tpu.models import kimi_k2
@@ -367,6 +397,9 @@ def test_latent_arena_is_updated_in_place_at_kimi_k2_widths(topo, kind, size):
         assert not [a for a in held
                     if a[0] == "f32" and math.prod(a[1]) > scores]
     if kind == "decode":
+        _assert_a_trip_is_gathered_never_a_table(
+            text, mem, size, cfg.max_seq_len // block,
+            (num_pages, cfg.n_layer, block, cfg.row_dim))
         with mock.patch.object(kimi_k2, "LANE_TILE", 1):
             assert kimi_k2.cache_rows(cfg) == ((576,),)
             _, _, moved, _ = compile_at(cfg)
@@ -389,7 +422,9 @@ def test_sequence_state_arena_is_updated_in_place_at_ling_widths(topo, kind,
     is ONE operation with ONE result, of the arena's type, which the
     benchmark's trace readers find by that type (a tuple there would turn
     them dark); both reductions are one more, and the `kda_path_*` readers'
-    pattern takes exactly those two a layer."""
+    pattern takes exactly those two a layer. Its MLA layer gathers a trip of
+    Kimi's work list at a time (64 pairs of 40 pages), never the lanes'
+    tables (`[32768,16,640]` before PR 61)."""
     import types
 
     from ray_tpu.models import ling_hybrid
@@ -446,6 +481,8 @@ def test_sequence_state_arena_is_updated_in_place_at_ling_widths(topo, kind,
     if kind == "chunk":
         return
     _assert_returns_a_token_a_lane(compiled, size)
+    _assert_a_trip_is_gathered_never_a_table(
+        text, mem, size, cfg.max_seq_len // block, pages.shape)
     held = _materialised(text)
     assert not [a for a in held if a[0] == "f32" and a[1] in (
         (size, 6, 32, 128, 128), (size, 32, 128, 128))]

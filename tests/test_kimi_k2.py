@@ -174,31 +174,174 @@ def one_layer():
 
 def test_absorbed_attention_is_the_expanded_attention(one_layer):
     """One layer's attention of one new token over a paged cache of up to
-    20 latents: scored in the latent space (decode) and with expanded keys
-    and values (prefill, chunks), the same numbers."""
+    20 latents: scored in the latent space (decode, through the pages and the
+    work list) and with expanded keys and values (prefill, chunks), the same
+    numbers."""
     cfg, lp = one_layer
     rng = np.random.default_rng(1)
     lengths = [20, 7, 0]
     b, block = len(lengths), 4
-    pages, table, lat_cached = _paged(rng, cfg, lengths, block, 5, fill=0.0)
+    pages, table, _ = _paged(rng, cfg, lengths, block, 5, fill=0.0)
     lat_new = rng.normal(size=(b, cfg.row_dim)).astype(np.float32)
     lat_new[..., cfg.latent_dim:] = 0
     q_nope = rng.normal(size=(b, cfg.n_head, cfg.qk_nope_dim)) \
         .astype(np.float32)
     q_rope = rng.normal(size=(b, cfg.n_head, cfg.qk_rope_dim)) \
         .astype(np.float32)
-    seen = np.concatenate(
-        [np.arange(20)[None] < np.asarray(lengths)[:, None],
-         np.ones((b, 1), bool)], axis=1)
+    positions = np.asarray(lengths, np.int32)
     with jax.default_matmul_precision("highest"):
-        absorbed = K.attend_absorbed(lp, cfg, q_nope, q_rope, lat_cached,
-                                     lat_new, seen)
+        absorbed = K.attend_absorbed(
+            lp, cfg, q_nope, q_rope, lat_new, jnp.asarray(pages), 1,
+            K.listed_walk(positions, table, block))
         expanded, slots = K.attend_expanded(
             lp, cfg, q_nope[:, None], q_rope[:, None], lat_new[:, None],
-            np.asarray(lengths, np.int32), jnp.asarray(pages), table, 1)
+            positions, jnp.asarray(pages), table, 1)
     np.testing.assert_allclose(absorbed, expanded[:, 0], atol=1e-5,
                                rtol=1e-4)
     assert int(slots) == 20 + 1
+
+
+# -- the absorbed path's walk: each lane as far as its own last block ---------
+
+def lanes_of(lanes: int, end: int, keys: int):
+    """Cached positions a lane, unequal: past several key blocks, nothing
+    (a pad lane of the bucket), the table's end, a few keys, then lengths in
+    between. Never nothing first: the last trip's dead pairs read lane 0's
+    first block, masked, and it has to hold numbers."""
+    first = [2 * keys + 37, 0, end, 5]
+    rest = [keys, keys + 1, end - keys - 3, 3 * keys, 1, 0, keys - 1, 700,
+            end - 1, 2 * keys, 16, 0]
+    return np.asarray((first + rest)[:lanes], np.int32)
+
+
+def absorbed_case(cfg, lp, positions, dtype, block=16, seed=0):
+    """Inputs of `attend_absorbed` for lanes of `positions` cached latents,
+    rounded to `dtype`: the arena holds numbers where a lane's positions
+    reach, large finite garbage in the rest of each lane's last visited key
+    block (masked before it weighs; a NaN there would poison the sum under
+    its zero weight) and NaN everywhere else, the pages of no lane too (never
+    read), page 0 apart: a table that is no whole number of key blocks is
+    padded with it, and the last block of a lane at the table's end reads it,
+    masked. Returns (lp, q_nope, q_rope, lat_new, pages, table)."""
+    rng = np.random.default_rng(seed + len(positions))
+    n_pages = cfg.max_seq_len // block
+    pages, table, _ = _paged(rng, cfg, positions, block, n_pages)
+    keys = K.absorbed_walk(positions, n_pages, block, np)[2]
+    for i, n in enumerate(positions):
+        visited = table[i, :-(-int(n) // keys) * keys // block]
+        pages[visited, 1] = np.nan_to_num(pages[visited, 1], nan=1e6)
+    pages[0, 1] = np.nan_to_num(pages[0, 1], nan=1e6)
+    b = len(positions)
+    lat_new = rng.normal(size=(b, cfg.row_dim))
+    lat_new[..., cfg.latent_dim:] = 0
+    q_nope = rng.normal(size=(b, cfg.n_head, cfg.qk_nope_dim))
+    q_rope = rng.normal(size=(b, cfg.n_head, cfg.qk_rope_dim))
+    return ({**lp, "kv_b": jnp.asarray(lp["kv_b"], dtype)},
+            *(jnp.asarray(a, dtype) for a in (q_nope, q_rope, lat_new,
+                                              pages)), table)
+
+
+def absorbed_in_float64(lp, cfg, q_nope, q_rope, lat_new, pages, table,
+                        positions):
+    """The absorbed attention a lane at a time in float64: ONE softmax over
+    the lane's own `positions[i]` cached latents (layer 1's) and its own."""
+    f64 = lambda a: np.asarray(a, np.float32).astype(np.float64)  # noqa: E731
+    w_uk, w_uv = np.split(f64(K._kv_b(lp, cfg)), [cfg.qk_nope_dim], axis=-1)
+    rows = f64(pages)[:, 1]
+    out = []
+    for i, n in enumerate(positions):
+        lat = np.concatenate([rows[table[i]].reshape(-1, cfg.row_dim)[:n],
+                              f64(lat_new[i:i + 1])])
+        q = np.concatenate([np.einsum("hn,chn->hc", f64(q_nope[i]), w_uk),
+                            f64(q_rope[i])], axis=-1)
+        s = q @ lat[:, :cfg.latent_dim].T * K.softmax_scale(cfg)
+        p = np.exp(s - s.max(-1, keepdims=True))
+        p /= p.sum(-1, keepdims=True)
+        out.append(np.einsum("hc,chv->hv", p @ lat[:, :cfg.kv_lora_rank],
+                             w_uv).reshape(-1))
+    assert np.isfinite(out).all()
+    return np.stack(out)
+
+
+# the walk against the float64 softmax, as a share of the output's rms:
+# float32 differs in the order of its sums; bfloat16 rounds the query in the
+# latent space, the weights and the weighted latent (2^-8 each)
+WALK_TOLERANCE = {jnp.float32: 1e-5, jnp.bfloat16: 3e-2}
+WALK_CASES = [(1, 0), (1, 1), (1, 2), (1, 3), (2, 0), (4, 0), (16, 0)]
+
+
+def check_the_walk(cfg, lp, lanes, shift, dtype):
+    """`attend_absorbed` for `lanes` lanes of `lanes_of` (rotated by `shift`
+    where there is one lane, so that each kind of lane stands alone once)
+    against `absorbed_in_float64`."""
+    block = 16
+    # two layers of pages: the arena's size goes with the config's layers
+    cfg = dataclasses.replace(cfg, dtype=dtype, n_layer=2)
+    n_pages = cfg.max_seq_len // block
+    keys = K.absorbed_walk(np.zeros(lanes, np.int32), n_pages, block, np)[2]
+    positions = lanes_of(4, cfg.max_seq_len, keys)[shift:shift + 1] \
+        if lanes == 1 else lanes_of(lanes, cfg.max_seq_len, keys)
+    lp, q_nope, q_rope, lat_new, pages, table = absorbed_case(
+        cfg, lp, positions, dtype, block)
+    with jax.default_matmul_precision("highest"):
+        got = K.attend_absorbed(
+            lp, cfg, q_nope, q_rope, lat_new, pages, 1,
+            K.listed_walk(jnp.asarray(positions), jnp.asarray(table), block))
+    want = absorbed_in_float64(lp, cfg, q_nope, q_rope, lat_new, pages, table,
+                               positions)
+    rms = float(np.sqrt(np.mean(want ** 2)))
+    assert rms > 0.05
+    worst = np.max(np.abs(np.asarray(got, np.float32) - want), axis=-1) / rms
+    assert (worst < WALK_TOLERANCE[dtype]).all(), worst
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("lanes, shift", WALK_CASES, ids=str)
+def test_the_walk_is_one_softmax_over_each_lanes_own_latents(
+        one_layer, lanes, shift, dtype):
+    """Lanes of unequal positions in one bucket (one past several key
+    blocks, one that holds nothing, one at the table's end, one of a few
+    keys; each of them alone in the bucket of one) at 1, 2, 4 and 16 lanes:
+    every lane's output is one dense float64 softmax over its own latents,
+    whatever the others hold, with every slot past a lane's position garbage
+    or NaN."""
+    check_the_walk(*one_layer, lanes, shift, dtype)
+
+
+@pytest.mark.parametrize("lanes", [1, 2, 4, 16])
+def test_the_host_and_the_program_count_one_walk(one_layer, lanes):
+    """`absorbed_walk` with `xp=np` and with `xp=jnp` are one walk, its trips
+    are the live pairs of `llama.key_block_pairs` in whole trips, and
+    `decode_step` counts lanes + trips x pairs a trip x keys a block slots a
+    layer."""
+    from ray_tpu.models import llama
+
+    cfg, _ = one_layer
+    block, n_pages = 16, cfg.max_seq_len // 16
+    positions = lanes_of(lanes, cfg.max_seq_len, K.LATENT_BLOCK)
+    trips, width, keys, listed = K.absorbed_walk(positions, n_pages, block,
+                                                 np)
+    blocks, lane, at, live, _ = llama.key_block_pairs(
+        positions, n_pages, block, np, K.LATENT_BLOCK)
+    assert (width, keys) == (K.PAIRS_A_LANE * lanes, K.LATENT_BLOCK)
+    assert int(trips) == -(-int(live.sum()) // width) \
+        == -(-int(np.sum(-(-positions // keys))) // width)
+    program = K.absorbed_walk(jnp.asarray(positions), n_pages, block)
+    assert (int(program[0]),) + program[1:3] == (int(trips), width, keys)
+    for mine, theirs, plain in zip(listed, program[3], (lane, at, live)):
+        assert len(mine) % width == 0
+        assert (np.asarray(mine) == np.asarray(theirs)).all()
+        assert (np.asarray(mine)[:len(plain)] == plain).all()
+    variables = K.KimiK2(cfg).init(jax.random.PRNGKey(0),
+                                   jnp.ones((1, 8), jnp.int32))
+    counts = jax.jit(K.decode_step, static_argnums=1)(
+        variables, cfg, np.zeros(lanes, np.int32), positions,
+        np.zeros((lanes * n_pages, cfg.n_layer, block, cfg.row_dim),
+                 np.float32),
+        np.arange(lanes * n_pages, dtype=np.int32).reshape(lanes, n_pages))[2]
+    assert int(counts[K.STEP_COUNTS.index("attn_key_slots")]) \
+        == cfg.n_layer * (lanes + int(trips) * width * keys)
 
 
 @pytest.mark.parametrize("starts", [
@@ -245,8 +388,9 @@ def test_blocked_attention_is_the_dense_attention(one_layer, starts):
 
 
 def test_key_slots_count_blocks_and_nothing_past_start_is_read(case):
-    """`chunk_step`'s `attn_key_slots` grows with `start` a key block at a
-    time, and the logits do not depend on what lies past `start`: large
+    """`chunk_step`'s and `decode_step`'s `attn_key_slots` grow with `start`
+    a key block at a time (1,024 keys and `LATENT_BLOCK`), and the logits do
+    not depend on what lies past `start`: large
     finite garbage in the rest of the last visited block (masked; a NaN
     there would poison the sum under its zero weight), NaN in every page
     of the blocks never visited and every page of no sequence."""
@@ -284,6 +428,20 @@ def test_key_slots_count_blocks_and_nothing_past_start_is_read(case):
     assert slots == {start: cfg.n_layer * (-(-start // K.KEY_BLOCK)
                                            * K.KEY_BLOCK + c)
                      for start in slots}
+    # the decode step the same, a block of `LATENT_BLOCK` at a time: its own
+    # latent, then its lane's blocks as far as the one that holds `positions`
+    step = jax.jit(K.decode_step, static_argnums=1)
+    with jax.default_matmul_precision("highest"):
+        logits, _, _ = step(variables, cfg, ids[n:n + 1].astype(np.int32),
+                            np.asarray([n], np.int32), *kv.arena, table)
+        np.testing.assert_allclose(logits[0], want[n], atol=ATOL)
+        slots = {at: int(step(
+            variables, cfg, np.zeros(1, np.int32), np.asarray([at], np.int32),
+            *kv.arena, table)[2][K.STEP_COUNTS.index("attn_key_slots")])
+            for at in (0, 1, 640, 641, 1280, 4095, 4096)}
+    assert slots == {at: cfg.n_layer * (-(-at // K.LATENT_BLOCK)
+                                        * K.LATENT_BLOCK + 1)
+                     for at in slots}
     kv.free(pages, owner)
 
 
@@ -469,6 +627,63 @@ def test_engine_serves_the_family_and_counts_its_experts():
                 * moe_layers * cfg.experts_held
         # the counts came over the link with the logits
         assert m["decode_link_bytes"] % 4 == 0 and m["kv_pages_live"] == 0
+    finally:
+        eng.shutdown()
+
+
+def test_lanes_of_unequal_length_give_the_references_tokens():
+    """Five requests of 30 to 1,950 prompt tokens (one to four key blocks
+    of 640), the short ones first, so that they decode while the long ones
+    are chunked in: the buckets of one, two and eight (three pad lanes).
+    Every request's tokens are the reference's greedy ones, and after every
+    step `decode_attn_key_slots` has grown by the count of the walk the
+    program ran, made on the host with the program's function."""
+    eng = LLMEngine(
+        model="kimi_k2", seed=0, model_cfg=tiny(max_seq_len=2048),
+        engine_config=EngineConfig(
+            batch_buckets=(1, 2, 8), prefill_buckets=(64,), prefill_chunk=256,
+            num_pages=760, block_size=8))
+    try:
+        forward, steps = eng._decode_forward, []
+
+        def counted(fn, args):
+            before = eng.metrics()["decode_attn_key_slots"]
+            out = forward(fn, args)
+            steps.append((np.array(args[2]),
+                          eng.metrics()["decode_attn_key_slots"] - before))
+            return out
+
+        eng._decode_forward = counted
+        rng = np.random.default_rng(12)
+        sizes = [(30, 44), (700, 38), (1300, 30), (1400, 14), (1950, 6)]
+        prompts = [rng.integers(1, 500, n).tolist() for n, _ in sizes]
+        reqs = [eng.submit(p, new) for p, (_, new) in zip(prompts, sizes)]
+        eng.run_until_idle()
+        cfg, most = eng.model_cfg, 0
+        for req, prompt, (n, new) in zip(reqs, prompts, sizes):
+            # one full pass over the prompt and the answer: every token is
+            # the largest logit of the row before it, so the answer is the
+            # reference's greedy one
+            tokens = req.result()
+            with jax.default_matmul_precision("highest"):
+                rows = np.asarray(ref.logits(
+                    eng.params["params"], file_of(cfg),
+                    jnp.asarray(prompt + tokens[:-1], jnp.int32)))[n - 1:]
+            assert len(tokens) == new
+            assert tokens == np.argmax(rows, axis=-1).tolist()
+        for positions, grown in steps:
+            trips, width, keys, _ = K.absorbed_walk(
+                positions, eng.max_pages_per_seq, 8, np)
+            assert (width, keys) == (K.PAIRS_A_LANE * len(positions),
+                                     K.LATENT_BLOCK)
+            most = max(most, int(trips))
+            assert grown == cfg.n_layer * (
+                len(positions) + int(trips) * width * keys) > 0
+        assert {len(p) for p, _ in steps} == {1, 2, 8} and most > 1
+        m = eng.metrics()
+        assert m["decode_attn_key_slots"] > cfg.n_layer \
+            * m["decode_context_tokens"]
+        eng.quiesce()
     finally:
         eng.shutdown()
 

@@ -16,9 +16,11 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+from ray_tpu.models import kimi_k2
 from ray_tpu.models import ling_hybrid as lh
 from ray_tpu.parallel import moe
 from ray_tpu.serve.llm.engine import EngineConfig, LLMEngine
+from test_kimi_k2 import WALK_CASES, check_the_walk
 
 LOWER = -5.0
 
@@ -632,6 +634,88 @@ def test_a_request_beside_live_lanes_in_a_slot_just_freed(n):
     assert tokens == want
     rows = _reference_logits(eng, prompt + tokens[:-1])[n - 1:]
     assert tokens == [int(r.argmax()) for r in rows]
+
+
+# -- the MLA layer's walk: each lane as far as its own last key block ---------
+
+@pytest.fixture(scope="module")
+def mla_layer():
+    """(the MLA layer's shapes as Kimi's functions read them: eight heads a
+    latent here, rope without scaling; its `kv_b`, scaled up from its
+    initial 0.02 as Kimi's `one_layer`)."""
+    cfg = lh.LingHybridConfig.tiny(n_head=8, max_seq_len=4096,
+                                   dtype=jnp.float32,
+                                   param_dtype=jnp.float32)
+    variables = lh.LingHybrid(cfg).init(jax.random.PRNGKey(0),
+                                        jnp.ones((1, 8), jnp.int32))
+    lp = lh.unboxed_params(variables)[f"layer{cfg.mla_layers[0]}"]
+    assert cfg.mla.n_head == 8 and cfg.mla.rope_factor == 1.0
+    return cfg.mla, {"kv_b": lp["kv_b"] * 20}
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+@pytest.mark.parametrize("lanes, shift", WALK_CASES, ids=str)
+def test_the_mla_walk_is_one_softmax_over_each_lanes_own_latents(
+        mla_layer, lanes, shift, dtype):
+    """Kimi's absorbed attention at this family's shapes (`cfg.mla`), lanes
+    of unequal positions at 1, 2, 4 and 16 lanes against one dense float64
+    softmax a lane (`tests/test_kimi_k2.py` `check_the_walk`): a lane that
+    holds nothing, a few keys, several key blocks, the whole table; what
+    lies past a lane's position is garbage or NaN."""
+    check_the_walk(*mla_layer, lanes, shift, dtype)
+
+
+def test_lanes_of_unequal_length_give_the_references_tokens():
+    """Five requests of 30 to 1,950 prompt tokens (one to four key blocks
+    of 640), the short ones first, so that they decode while the long ones
+    are chunked in: the buckets of one, four and eight. Every request's
+    tokens are the reference's greedy ones, and after every step
+    `decode_attn_key_slots` has grown by the count of the walk the program
+    ran (one MLA layer), made on the host with the program's function."""
+    cfg = lh.LingHybridConfig.tiny(max_seq_len=2048, dtype=jnp.float32,
+                                   param_dtype=jnp.float32)
+    eng = LLMEngine(
+        model="ling_hybrid", model_cfg=cfg, engine_config=EngineConfig(
+            batch_buckets=(1, 4, 8), prefill_buckets=(64,), prefill_chunk=256,
+            num_pages=760, block_size=8, prefix_cache=0))
+    try:
+        forward, steps = eng._decode_forward, []
+
+        def counted(fn, args):
+            before = eng.metrics()["decode_attn_key_slots"]
+            out = forward(fn, args)
+            steps.append((np.array(args[2]),
+                          eng.metrics()["decode_attn_key_slots"] - before))
+            return out
+
+        eng._decode_forward = counted
+        rng = np.random.default_rng(12)
+        sizes = [(30, 44), (700, 38), (1300, 30), (1400, 14), (1950, 6)]
+        prompts = [[int(x) for x in rng.integers(1, 500, n)]
+                   for n, _ in sizes]
+        reqs = [eng.submit(p, new) for p, (_, new) in zip(prompts, sizes)]
+        eng.run_until_idle()
+        for req, prompt, (n, new) in zip(reqs, prompts, sizes):
+            # one full pass over the prompt and the answer: every token is
+            # the largest logit of the row before it
+            tokens = req.result()
+            rows = _reference_logits(eng, prompt + tokens[:-1])[n - 1:]
+            assert len(tokens) == new
+            assert tokens == np.argmax(rows, axis=-1).tolist()
+        most = 0
+        for positions, grown in steps:
+            trips, width, keys, _ = kimi_k2.absorbed_walk(
+                positions, eng.max_pages_per_seq, 8, np)
+            assert (width, keys) == (
+                kimi_k2.PAIRS_A_LANE * len(positions), kimi_k2.LATENT_BLOCK)
+            most = max(most, int(trips))
+            assert grown == len(cfg.mla_layers) * (
+                len(positions) + int(trips) * width * keys) > 0
+        assert {len(p) for p, _ in steps} == {1, 4, 8} and most > 1
+        eng.quiesce()
+    finally:
+        assert eng.shutdown() == 0
 
 
 def test_slots_are_taken_with_the_pages_and_freed_at_the_end():

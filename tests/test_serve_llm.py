@@ -1828,15 +1828,21 @@ def test_block_family_streams_in_position_order_and_stops_inside_a_block():
 # All four of `kimi_k2` changed on purpose in PR 54: `MOE_COUNTS` gained
 # `tile_visits`, the (row tile, expert) pairs the grouped product walks on the
 # TPU, counted from the group sizes on every backend (`moe.group_tiles`,
-# before the products); the products here are still `lax.ragged_dot`. `llama` and `gpt` held to the digit.)
+# before the products); the products here are still `lax.ragged_dot`. `llama` and `gpt` held to the digit.
+# `decode1` and `decode4` of `kimi_k2` changed on purpose in PR 61: the absorbed
+# attention reads each lane's cached latents on the work list of its live
+# (lane, key block) pairs (`kimi_k2.walk_latents` over `listed_walk`, every
+# bucket, the bucket of one too) where it gathered and scored every slot of
+# every lane's page table, and `attn_key_slots` is counted from the walk's
+# trips; its `prefill16` and `chunk16`, `llama` and `gpt` held to the digit.)
 NEIGHBOUR_PROGRAMS = {
     "llama": {"prefill16": "431c15dfcac7aa97", "decode1": "6bf27b12ae6cc48a",
               "decode4": "a673da1cf2b122ee", "chunk16": "6a7f8549a7f8d7f8"},
     "gpt": {"prefill16": "b2ca98029a10a332", "decode1": "910442728ae47e50",
             "decode4": "56f63d1e86807bca", "chunk16": "d8e225f431a39fd7"},
     "kimi_k2": {"prefill16": "38d0038cacae10d6",
-                "decode1": "5adea4b67ad990fa",
-                "decode4": "518bdc7dfbe06d92",
+                "decode1": "983bafa7642e516a",
+                "decode4": "4b2dd7aac3e9dd18",
                 "chunk16": "f032712f3eae8ada"},
 }
 
@@ -2048,10 +2054,15 @@ def test_afmoe_programs_lower_to_the_text_they_had(program):
 # work list of its lanes' live (lane, key block) pairs, each lane as far as
 # its own last block (`sdar_moe.block_attend`, `key_walk`); its `prefill16`,
 # `decode1` and `chunk16`, one lane each, keep the loop and held to the digit,
-# as did every other line here, `NEIGHBOUR_PROGRAMS` and `AFMOE_PROGRAMS`.)
+# as did every other line here, `NEIGHBOUR_PROGRAMS` and `AFMOE_PROGRAMS`.
+# `ling_hybrid`'s `decode1` and `decode4` changed on purpose in PR 61: its MLA
+# layer calls Kimi's absorbed attention, which walks the work list of live
+# (lane, key block) pairs where it gathered every slot of every lane's table
+# (`NEIGHBOUR_PROGRAMS` says the same of `kimi_k2`); its `prefill16` and
+# `chunk16` and every other family's lines held to the digit.)
 STATEFUL_AND_LOOP_PROGRAMS = {
-    "ling_hybrid": {"prefill16": "5552f28192e60bc7", "decode1": "1e26b795095a4534",
-                    "decode4": "5ab7be559d5b6b07", "chunk16": "6ba55ad58f9eea4d"},
+    "ling_hybrid": {"prefill16": "5552f28192e60bc7", "decode1": "3e3f9a768a968c99",
+                    "decode4": "595d664083082613", "chunk16": "6ba55ad58f9eea4d"},
     "sdar_moe": {"prefill16": "f764035387a6f05d", "decode1": "98e070ae0b42068f",
                  "decode4": "0b4d536aaa2d8ab6", "chunk16": "36c8461ffeb5fe4c"},
     "ouro": {"prefill16": "4484f00252d4386c", "decode1": "e10c4608e3b11682",
